@@ -9,8 +9,10 @@ TPU_VISIBLE_CHIPS-style isolation (tpu.py:37), and advertise node labels
 (slice name, worker id, pod type) plus the ``TPU-{pod}-head`` gang-resource
 on worker 0 (tpu.py:224 reserve_tpu_slice).
 
-No GCE metadata server is assumed here: detection is env-first, with a JAX
-fallback on real TPU hosts. This module must stay importable without jax.
+No GCE metadata server is assumed here: detection reads the environment and
+counts the chip device files. It never asks JAX: initialising a backend is
+what claims a chip, and the daemon that advertises chips must leave them to
+its workers. This module must stay importable without jax.
 """
 from __future__ import annotations
 
@@ -88,6 +90,16 @@ def get_tpu_pod_type() -> Optional[str]:
     return _accelerator_type()
 
 
+def chip_device_files() -> list[str]:
+    """This host's TPU chips as the kernel exposes them, one file a chip:
+    ``/dev/accel<N>`` on older hosts, ``/dev/vfio/<N>`` on v5e and newer.
+    Listing them claims nothing; a process that has initialised the TPU
+    backend holds them open."""
+    import glob
+
+    return sorted(glob.glob("/dev/accel[0-9]*") + glob.glob("/dev/vfio/[0-9]*"))
+
+
 def get_visible_chips() -> Optional[list[str]]:
     raw = os.environ.get(VISIBLE_CHIPS_ENV)
     if raw is None:
@@ -141,34 +153,30 @@ class TPUAcceleratorManager:
 
 
 def detect_tpu_resources() -> tuple[dict, dict]:
-    """Returns (resources, labels) the node daemon should advertise.
-
-    Env-first (works in tests and GKE); falls back to asking JAX only when a
-    TPU runtime is plainly present (JAX_PLATFORMS mentions tpu).
-    """
+    """Returns (resources, labels) the node daemon should advertise, from
+    the TPU runtime's environment variables (set on TPU VMs and GKE) and the
+    chip device files. A host without the variables advertises no chips;
+    pass ``resources={"TPU": n}``."""
     resources: dict = {}
     labels: dict = {}
     acc_type = _accelerator_type()
     num_chips = 0
     if acc_type:
         try:
+            # What is present beats what the environment describes: a host
+            # may carry a slice's variables and expose fewer chips.
             visible = get_visible_chips()
-            num_chips = len(visible) if visible is not None else get_chips_per_host(acc_type)
+            files = chip_device_files()
+            num_chips = (
+                len(visible) if visible is not None
+                else len(files) if files
+                else get_chips_per_host(acc_type)
+            )
             labels[TPU_POD_TYPE_LABEL] = acc_type
             gen, _ = parse_accelerator_type(acc_type)
             labels[TPU_VERSION_LABEL] = gen
         except ValueError:
             return {}, {}
-    elif "tpu" in os.environ.get("JAX_PLATFORMS", "").lower():
-        try:
-            import jax
-
-            devs = [d for d in jax.devices() if d.platform == "tpu"]
-            num_chips = len(devs)
-            if devs:
-                labels[TPU_VERSION_LABEL] = getattr(devs[0], "device_kind", "tpu")
-        except Exception:
-            num_chips = 0
     if num_chips <= 0:
         return {}, {}
     resources["TPU"] = float(num_chips)

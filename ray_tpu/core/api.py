@@ -588,6 +588,11 @@ class RuntimeContext:
         rt = self._core._actor_runtime
         return rt.spec.name if rt else None
 
+    def get_assigned_resources(self) -> dict:
+        """Resources the scheduler reserved for this actor ({} outside one)."""
+        rt = self._core._actor_runtime
+        return rt.spec.options.resource_demand() if rt else {}
+
 
 def get_runtime_context() -> RuntimeContext:
     return RuntimeContext(_require_worker())

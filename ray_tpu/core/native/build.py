@@ -1,11 +1,15 @@
 """Builds the native C++ components into shared libraries, lazily and cached.
 
 The reference builds its native core with Bazel; here each component is a
-single translation unit compiled with g++ at first use (cached by source
-mtime), which keeps the repo hermetic with no install step.
+single translation unit compiled with g++ at first use, which keeps the repo
+hermetic with no install step. The built library is named after a hash of
+its source and flags, so a tree that was copied with a library built from
+other source (file times mean nothing after a copy) builds its own.
 """
 from __future__ import annotations
 
+import glob
+import hashlib
 import os
 import subprocess
 import threading
@@ -15,15 +19,25 @@ _lock = threading.Lock()
 
 
 def build_lib(name: str, extra_flags: list[str] | None = None) -> str:
-    """Compile ``<name>.cpp`` in this directory -> ``_<name>.so``; return path."""
+    """Compile ``<name>.cpp`` in this directory -> ``_<name>.<hash>.so``;
+    return its path."""
     src = os.path.join(_DIR, f"{name}.cpp")
-    out = os.path.join(_DIR, f"_{name}.so")
+    flags = ["-O2", "-std=c++17", "-shared", "-fPIC"]
+    tail = ["-lpthread", *(extra_flags or [])]
+    with open(src, "rb") as f:
+        digest = hashlib.sha256(f.read() + " ".join(flags + tail).encode()).hexdigest()[:16]
+    out = os.path.join(_DIR, f"_{name}.{digest}.so")
     with _lock:
-        if os.path.exists(out) and os.path.getmtime(out) >= os.path.getmtime(src):
+        if os.path.exists(out):
             return out
         tmp = out + f".tmp{os.getpid()}"
-        cmd = ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-o", tmp, src, "-lpthread"]
-        cmd += extra_flags or []
-        subprocess.run(cmd, check=True, capture_output=True, text=True)
+        subprocess.run(["g++", *flags, "-o", tmp, src, *tail],
+                       check=True, capture_output=True, text=True)
         os.replace(tmp, out)
+        for stale in glob.glob(os.path.join(_DIR, f"_{name}.*so")):
+            if stale != out:
+                try:
+                    os.remove(stale)
+                except OSError:
+                    pass  # another process removed it, or still has it mapped
     return out

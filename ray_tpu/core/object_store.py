@@ -20,21 +20,6 @@ from ray_tpu.core.native.build import build_lib
 _ID_SIZE = 20
 
 
-class _Pep688Probe:
-    def __buffer__(self, flags):
-        return memoryview(b"")
-
-
-try:  # PEP 688 (Python 3.12+): Python classes can export the buffer protocol
-    memoryview(_Pep688Probe()).release()
-    SUPPORTS_PEP688 = True
-except TypeError:
-    # Pre-3.12: memoryview() cannot see PinnedBuffer.__buffer__, so zero-copy
-    # pinned reads are impossible to do SAFELY (derived views would not hold
-    # the eviction pin). Readers degrade to a copy via PinnedBuffer.tobytes().
-    SUPPORTS_PEP688 = False
-
-
 class _Lib:
     _instance = None
     _lock = threading.Lock()
@@ -113,12 +98,6 @@ class PinnedBuffer:
 
     def __len__(self):
         return len(self._view)
-
-    def tobytes(self) -> bytes:
-        """Copy-out escape hatch for pre-PEP-688 interpreters (see
-        SUPPORTS_PEP688): the copy is safe without pin tracking because it
-        shares no pages with the arena."""
-        return bytes(self._view)
 
     def __del__(self):
         try:

@@ -35,9 +35,26 @@ def get_serialization_context() -> SerializationContext:
     return _context
 
 
+def _holds_device() -> bool:
+    """Whether a device array arriving here is restored onto a device. In a
+    worker, yes: the code that receives it is device code, scheduled where
+    the chip is. In a driver, only if it already initialised a backend:
+    restoring would initialise one, and that claims the chip — a driver that
+    only fetches a worker's result must not take the chip from the worker
+    that needs it, so it gets the host buffers back instead."""
+    from ray_tpu.accel.device import backend_initialized
+    from ray_tpu.core import api
+
+    worker = api._global_worker
+    return worker is None or worker.mode != "driver" or backend_initialized()
+
+
 def _restore_device_array(host):
     """Re-materialize a device array on this process's default device (H2D
-    put on a TPU worker; no copy on the CPU backend)."""
+    put on a TPU worker; no copy on the CPU backend); the host buffer itself
+    in a process that holds no device (_holds_device)."""
+    if not _holds_device():
+        return host
     import jax.numpy as jnp
 
     return jnp.asarray(host)
@@ -55,27 +72,34 @@ def _restore_sharded_array(hosts, indices, dev_to_host, shape, axis_names,
     shard onto the device at the same mesh position — one H2D per device,
     never a global host copy. Degrade: a receiver with too few devices
     assembles the global array on host from the shipped shard indices and
-    puts it on the default device (the send side still never gathered)."""
-    import jax
+    puts it on the default device (the send side still never gathered); a
+    receiver that holds no device (_holds_device) keeps it on the host."""
     import numpy as np
+
+    def on_host():
+        out = np.empty(tuple(shape), hosts[0].dtype)
+        for h, idx in zip(hosts, indices):
+            out[tuple(slice(a, b) for a, b in idx)] = h
+        return out
+
+    if not _holds_device():
+        return on_host()
+    import jax
     from jax.sharding import Mesh, NamedSharding
 
     n = 1
     for s in mesh_shape:
         n *= s
     devs = jax.devices()
-    if len(devs) >= n:
-        mesh = Mesh(np.array(devs[:n]).reshape(mesh_shape), axis_names)
-        sharding = NamedSharding(mesh, spec)
-        arrays = [
-            jax.device_put(hosts[k], d)
-            for k, d in zip(dev_to_host, mesh.devices.flat)
-        ]
-        return jax.make_array_from_single_device_arrays(tuple(shape), sharding, arrays)
-    out = np.empty(tuple(shape), hosts[0].dtype)
-    for h, idx in zip(hosts, indices):
-        out[tuple(slice(a, b) for a, b in idx)] = h
-    return jax.numpy.asarray(out)
+    if len(devs) < n:
+        return jax.numpy.asarray(on_host())
+    mesh = Mesh(np.array(devs[:n]).reshape(mesh_shape), axis_names)
+    sharding = NamedSharding(mesh, spec)
+    arrays = [
+        jax.device_put(hosts[k], d)
+        for k, d in zip(dev_to_host, mesh.devices.flat)
+    ]
+    return jax.make_array_from_single_device_arrays(tuple(shape), sharding, arrays)
 
 
 class _RefAwarePickler(cloudpickle.CloudPickler):
@@ -232,18 +256,7 @@ def serialize_args(args: tuple, kwargs: dict) -> tuple[bytes, list]:
 
 
 def deserialize(data: bytes | memoryview) -> Any:
-    try:
-        data = memoryview(data)
-    except TypeError:
-        # A PinnedBuffer on a pre-PEP-688 interpreter (Python < 3.12):
-        # memoryview() cannot see its __buffer__ export, so zero-copy
-        # deserialization is impossible to do safely (derived views would
-        # not hold the eviction pin). Degrade to a copy — correctness over
-        # zero-copy on old interpreters.
-        if hasattr(data, "tobytes"):
-            data = memoryview(data.tobytes())
-        else:
-            raise
+    data = memoryview(data)
     tag = bytes(data[:1])
     if tag == b"P":
         return pickle.loads(data[1:])

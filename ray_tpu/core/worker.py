@@ -2577,8 +2577,14 @@ class CoreWorker:
         cls = await self._load_callable(spec.cls_id)
         args, kwargs = serialization.deserialize(spec.init_args_blob)
         runtime = ActorRuntime(self, spec, cls)
-        await runtime.construct(args, kwargs)
+        # Set before construct: the constructor may read its own runtime
+        # context (assigned resources).
         self._actor_runtime = runtime
+        try:
+            await runtime.construct(args, kwargs)
+        except BaseException:
+            self._actor_runtime = None
+            raise
         return True
 
     async def handle_push_actor_task(self, conn, p):

@@ -39,7 +39,16 @@ class LLMServer:
     def __init__(self, model_config: dict, engine_config: Optional[dict] = None,
                  warmup_buckets: Optional[tuple] = None, params=None,
                  weights_channel: Optional[str] = None):
-        import jax
+        import ray_tpu as rt
+        from ray_tpu.accel import device as _device
+
+        self._compile_cache_dir = _device.enable_compile_cache()
+        chips = (
+            rt.get_runtime_context().get_assigned_resources().get("TPU", 0)
+            if rt.is_initialized() else 0
+        )
+        if chips:
+            _device.require_tpu(chips, "LLM replica")
 
         from ray_tpu.llm.engine import EngineConfig, LLMEngine
         from ray_tpu.models.transformer import TransformerConfig
@@ -56,15 +65,15 @@ class LLMServer:
             from ray_tpu.core.object_ref import ObjectRef
 
             if isinstance(params, ObjectRef):
-                import ray_tpu as rt
-
                 params = rt.get(params, timeout=300.0)
         self.engine = LLMEngine(cfg, params=params, engine_config=ec)
+        t0 = time.perf_counter()
         if warmup_buckets:
             # Compile prefill/decode programs before the replica reports
             # healthy (vLLM-style startup warmup): cold compiles belong to
             # startup, never to a request's TTFT.
             self.engine.warmup(buckets=tuple(warmup_buckets))
+        self._warmup_s = time.perf_counter() - t0
         self._cond = threading.Condition()
         self._done: dict[str, dict] = {}
         self._ttft: dict[str, float] = {}
@@ -304,6 +313,34 @@ class LLMServer:
             out["prefix_cache"] = self.engine.prefix_cache_stats
         return out
 
+    def device_report(self) -> dict:
+        """Where this replica runs, as its OWN process sees it — a driver
+        must not touch JAX to find out, since that would claim the chip:
+        platform, device kind and count, the compile cache in use, warm-up
+        (compile) seconds, which programs hold the Mosaic kernels (recorded
+        by the engine's warm-up), and what one device holds of the weights
+        and the KV pool (a tensor-parallel replica: one shard of each, and
+        its share of the memory). Reads only; takes no lock."""
+        import jax
+
+        from ray_tpu.accel import device as _device
+
+        eng = self.engine
+        per_device = {
+            "wq": list(eng.params["layers"]["wq"].sharding.shard_shape(
+                eng.params["layers"]["wq"].shape)),
+            "k_pages": list(eng.k_pages.sharding.shard_shape(eng.k_pages.shape)),
+            "bytes_in_use": [
+                (d.memory_stats() or {}).get("bytes_in_use") for d in jax.local_devices()],
+        }
+        return {
+            **_device.device_report(),
+            "compile_cache_dir": self._compile_cache_dir,
+            "warmup_s": round(self._warmup_s, 3),
+            "mosaic": dict(eng.mosaic),
+            "per_device": per_device,
+        }
+
     def __raytpu_exit__(self):
         self._stop = True
         if self._weights_sub is not None:
@@ -335,8 +372,9 @@ def build_llm_app(model_config: dict, engine_config: Optional[dict] = None,
     if ec.tensor_parallel > 1:
         # Tensor-parallel replica: gang-schedule it onto a host advertising
         # that many chips (reference: TP degree -> placement-group bundles,
-        # vllm_models.py:233-238). The worker's TPU_VISIBLE_CHIPS isolation
-        # (accel/tpu.py) then exposes exactly those chips to the engine mesh.
+        # vllm_models.py:233-238). The reservation is bookkeeping only: no
+        # per-worker chip assignment exists, so the replica's process claims
+        # every chip on its host and the engine meshes the first `tp` of them.
         aopts.setdefault("resources", {}).setdefault(
             "TPU", float(ec.tensor_parallel)
         )

@@ -400,6 +400,9 @@ class OpenAIServer:
     def stats(self) -> dict:
         return self._llm.stats()
 
+    def device_report(self) -> dict:
+        return self._llm.device_report()
+
     def __raytpu_exit__(self):
         self._llm.__raytpu_exit__()
 

@@ -186,6 +186,41 @@ def _rope(x, positions, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
 
 
+def _flash(q, k, v, cfg: TransformerConfig, segment_ids):
+    """The flash kernel on this device's shard. Under a multi-device mesh the
+    call is shard_map'd over the batch and head axes the active strategy
+    shards: GSPMD cannot partition a Mosaic kernel, and jax refuses to lower
+    a bare pallas_call there ("Mosaic kernels cannot be automatically
+    partitioned"). The sequence stays whole per shard (ring/ulysses are the
+    sequence-parallel impls)."""
+    from ray_tpu.ops.attention import DEFAULT_BLOCK_K, DEFAULT_BLOCK_Q, flash_attention
+    from ray_tpu.parallel.sharding import _ambient_mesh, _current_strategy
+
+    def local(q, k, v, seg):
+        return flash_attention(
+            q, k, v, causal=True, segment_ids=seg,
+            block_q=cfg.attention_block_q or DEFAULT_BLOCK_Q,
+            block_k=cfg.attention_block_k or DEFAULT_BLOCK_K,
+        )
+
+    mesh, strategy = _ambient_mesh(), _current_strategy()
+    if mesh is None or strategy is None or mesh.size == 1:
+        return local(q, k, v, segment_ids)
+    q_spec = strategy.spec(("batch", None, "heads", None))
+    kv_spec = strategy.spec(("batch", None, "kv_heads", None))
+    if segment_ids is None:
+        return jax.shard_map(
+            lambda q, k, v: local(q, k, v, None), mesh=mesh,
+            in_specs=(q_spec, kv_spec, kv_spec), out_specs=q_spec,
+            check_vma=False,
+        )(q, k, v)
+    return jax.shard_map(
+        local, mesh=mesh,
+        in_specs=(q_spec, kv_spec, kv_spec, strategy.spec(("batch", None))),
+        out_specs=q_spec, check_vma=False,
+    )(q, k, v, segment_ids)
+
+
 def _attention(q, k, v, cfg: TransformerConfig, positions=None, segment_ids=None):
     """Dispatch to the configured attention implementation.
 
@@ -195,15 +230,11 @@ def _attention(q, k, v, cfg: TransformerConfig, positions=None, segment_ids=None
     """
     impl = cfg.attention_impl
     if impl == "auto":
-        impl = "flash" if jax.default_backend() == "tpu" else "reference"
-    if impl == "flash":
-        from ray_tpu.ops.attention import DEFAULT_BLOCK_K, DEFAULT_BLOCK_Q, flash_attention
+        from ray_tpu.ops.attention import flash_supported
 
-        return flash_attention(
-            q, k, v, causal=True, segment_ids=segment_ids,
-            block_q=cfg.attention_block_q or DEFAULT_BLOCK_Q,
-            block_k=cfg.attention_block_k or DEFAULT_BLOCK_K,
-        )
+        impl = "flash" if flash_supported(q.shape[1]) else "reference"
+    if impl == "flash":
+        return _flash(q, k, v, cfg, segment_ids)
     if impl == "splash":
         from ray_tpu.ops.splash import splash_attention
 
@@ -364,26 +395,6 @@ def _ce_from_logits(logits, targets, mask=None):
     return jnp.mean(nll)
 
 
-@jax.custom_vjp
-def _diff_barrier(xs):
-    """optimization_barrier with an explicit identity gradient: this jax
-    version has no differentiation rule for the primitive, and the barrier
-    is a pure scheduling hint — cotangents pass through unchanged (what
-    newer jax's built-in rule does too)."""
-    return lax.optimization_barrier(xs)
-
-
-def _diff_barrier_fwd(xs):
-    return lax.optimization_barrier(xs), None
-
-
-def _diff_barrier_bwd(_, g):
-    return (g,)
-
-
-_diff_barrier.defvjp(_diff_barrier_fwd, _diff_barrier_bwd)
-
-
 def _ce_chunked(x, lm_head, targets, mask, chunk: int):
     """Fused-style CE: the [B, S, V] logits are never materialized — a
     rematted scan computes each sequence chunk's logits [B, c, V], reduces
@@ -416,7 +427,7 @@ def _ce_chunked(x, lm_head, targets, mask, chunk: int):
         sl = slice(i * chunk, (i + 1) * chunk)
         x_i = x[:, sl]
         if i:
-            x_i, tot = _diff_barrier((x_i, tot))
+            x_i, tot = lax.optimization_barrier((x_i, tot))
         s_i, c_i = body(x_i, targets[:, sl], mask[:, sl])
         tot += s_i
         cnt += c_i

@@ -672,19 +672,18 @@ def device_server(port: int = 9012):
 
 def device_memory_records(ts: Optional[float] = None) -> list[dict]:
     """``tpu.device.bytes_in_use`` gauge records from jax local_devices()
-    memory stats, reporter-record shaped. Gated hard: never IMPORTS jax
-    (only reads it if the process already did), and CPU backends report no
-    memory_stats (None) — so CPU-only workers pay a sys.modules lookup."""
-    jax = sys.modules.get("jax")
-    if jax is None:
-        return []
-    try:
-        devices = jax.local_devices()
-    except Exception:
+    memory stats, reporter-record shaped. Gated hard: reads only a backend
+    this process has ALREADY initialised — a metrics tick that initialised
+    one would claim the chip from the worker that needs it (a driver or
+    proxy that merely imported jax must stay off it). CPU backends report
+    no memory_stats (None)."""
+    from ray_tpu.accel.device import backend_initialized
+
+    if not backend_initialized():
         return []
     now = time.time() if ts is None else ts
     out = []
-    for d in devices:
+    for d in sys.modules["jax"].local_devices():
         try:
             ms = d.memory_stats()
         except Exception:

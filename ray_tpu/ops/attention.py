@@ -41,15 +41,8 @@ NEG_INF = -1e30
 
 
 # ---------------------------------------------------------------------------
-# Reference implementation (numerical oracle + CPU fallback)
+# Reference implementation (numerical oracle + non-TPU backends)
 # ---------------------------------------------------------------------------
-
-def _compiler_params(pltpu):
-    """The pallas TPU compiler-params class under either of its names:
-    jax renamed TPUCompilerParams -> CompilerParams across versions, and
-    these kernels must build on both."""
-    return getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 
 def mha_reference(q, k, v, causal=True, scale=None, segment_ids=None):
     """q: [B, S, H, D]; k,v: [B, S, KV, D] (KV divides H) -> [B, S, H, D].
@@ -72,6 +65,13 @@ def mha_reference(q, k, v, causal=True, scale=None, segment_ids=None):
         s = jnp.where(seg, s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+
+
+def flash_supported(seq_len: int) -> bool:
+    """Whether ``flash_attention`` can run compiled on this process's backend
+    at this sequence length — what an "auto" caller observes to choose
+    between the kernel and ``mha_reference``."""
+    return jax.default_backend() == "tpu" and seq_len % 128 == 0
 
 
 def _mask_scores(s, q_start, k_start, causal, seg_q, seg_k):
@@ -196,7 +196,7 @@ def _fwd_pallas(q, k, v, seg, *, causal, scale, block_q, block_k, group, H, inte
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
-        compiler_params=_compiler_params(pltpu)(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -385,7 +385,7 @@ def _bwd_pallas(res, g, *, causal, scale, block_q, block_k, group, H, KV, interp
             pltpu.VMEM((block_k, D), jnp.float32),
             pltpu.VMEM((block_k, D), jnp.float32),
         ],
-        compiler_params=_compiler_params(pltpu)(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -417,7 +417,7 @@ def _bwd_pallas(res, g, *, causal, scale, block_q, block_k, group, H, KV, interp
         out_specs=pl.BlockSpec((1, block_q, D), lambda b, qi, ki: (b, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, S, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        compiler_params=_compiler_params(pltpu)(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -467,19 +467,25 @@ def flash_attention(q, k, v, causal=True, scale=None, segment_ids=None,
     KV may be smaller than H (GQA): kv heads are shared across groups of
     H // KV query heads inside the kernel — no repeat/materialization.
     ``segment_ids`` [B, S] masks attention to same-segment pairs (packed
-    sequences). Uses the Pallas kernels on TPU (or anywhere with
-    interpret=True — the CPU test path); falls back to the jnp reference
-    otherwise. S must be a multiple of 128 for the TPU path (callers pad);
+    sequences). These are the Pallas kernels: they run on a TPU backend, or
+    anywhere with interpret=True (the CPU test path), and raise elsewhere —
+    a caller that may land on another backend chooses ``mha_reference``
+    itself from what it observes. S must be a multiple of 128 (callers pad);
     D should be a lane multiple (64/128/256).
     """
     B, S, H, D = q.shape
     KV = k.shape[2]
     if H % KV:
         raise ValueError(f"n_heads {H} not divisible by kv_heads {KV}")
+    if S % 128:
+        raise ValueError(f"flash_attention needs S % 128 == 0, got S={S}")
+    if not interpret and jax.default_backend() != "tpu":
+        raise RuntimeError(
+            f"flash_attention needs a TPU backend (or interpret=True); this "
+            f"process runs on {jax.default_backend()!r}"
+        )
     group = H // KV
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
-    if (jax.default_backend() != "tpu" and not interpret) or S % 128 != 0:
-        return mha_reference(q, k, v, causal=causal, scale=scale, segment_ids=segment_ids)
     # Blocks must divide S exactly: Pallas pads out-of-bounds block reads with
     # undefined data, and the non-causal path applies no mask that would
     # neutralize padded key columns. S is a multiple of 128 here, so halving

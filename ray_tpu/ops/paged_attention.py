@@ -31,13 +31,8 @@ NEG_INF = -1e30
 
 
 # ---------------------------------------------------------------------------
-# Reference implementation (numerical oracle + CPU path)
+# Reference implementation (numerical oracle + non-TPU backends)
 # ---------------------------------------------------------------------------
-
-# One compat shim for the whole ops package (attention.py owns it): the
-# pallas TPU compiler-params class was renamed across jax versions.
-from ray_tpu.ops.attention import _compiler_params  # noqa: E402
-
 
 def paged_attention_reference(q, k_pages, v_pages, lengths, page_indices, scale=None):
     """q: [B, H, D]; k_pages/v_pages: [KV, P_total, ps, D]; lengths: [B]
@@ -145,7 +140,7 @@ def _paged_pallas(q, k_pages, v_pages, lengths, page_indices, *, scale, interpre
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, Gp, D), q.dtype),
-        compiler_params=_compiler_params(pltpu)(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -159,24 +154,22 @@ def paged_attention(q, k_pages, v_pages, lengths, page_indices, scale=None,
     per sequence including the current one; page_indices: [B, pages_per_seq]
     (entries past a sequence's length must still be valid page ids — use 0).
 
-    Pallas kernel on TPU (or interpret=True); jnp reference elsewhere.
+    This is the Pallas kernel: it runs on a TPU backend, or anywhere with
+    interpret=True, and raises elsewhere — a caller that may land on another
+    backend chooses ``paged_attention_reference`` from what it observes.
 
     mesh: tensor-parallel serving (llm/engine.py) — the head axes (H of q, KV
     of the page pools) are sharded over ``mesh[head_axis]`` and the kernel is
     shard_map'd: each device attends its own head shard against its own KV
     pool shard (embarrassingly parallel — GQA groups never straddle shards
-    because callers validate KV % degree == 0). Without the explicit map a
-    Pallas call is an opaque custom-call GSPMD would have to gather around.
+    because callers validate KV % degree == 0). Without the explicit map jax
+    refuses to lower the call: GSPMD cannot partition a Mosaic kernel.
     """
     if mesh is not None and mesh.shape.get(head_axis, 1) > 1:
-        from functools import partial
-
         from jax.sharding import PartitionSpec as P
 
-        from ray_tpu.parallel._shard_map import shard_map
-
-        inner = partial(paged_attention, scale=scale, interpret=interpret)
-        return shard_map(
+        inner = functools.partial(paged_attention, scale=scale, interpret=interpret)
+        return jax.shard_map(
             inner,
             mesh=mesh,
             in_specs=(
@@ -187,6 +180,7 @@ def paged_attention(q, k_pages, v_pages, lengths, page_indices, scale=None,
                 P(None, None),
             ),
             out_specs=P(None, head_axis, None),
+            check_vma=False,
         )(q, k_pages, v_pages, lengths, page_indices)
     B, H, D = q.shape
     KV = k_pages.shape[0]
@@ -194,9 +188,14 @@ def paged_attention(q, k_pages, v_pages, lengths, page_indices, scale=None,
         raise ValueError(f"n_heads {H} not divisible by kv_heads {KV}")
     group = H // KV
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
-    if jax.default_backend() != "tpu" and not interpret:
-        return paged_attention_reference(q, k_pages, v_pages, lengths, page_indices, scale)
-    # Sublane-pad the group axis up to 8 (min f32 tile is (8, 128)).
+    if not interpret and jax.default_backend() != "tpu":
+        raise RuntimeError(
+            f"paged_attention needs a TPU backend (or interpret=True); this "
+            f"process runs on {jax.default_backend()!r}"
+        )
+    # Sublane-pad the group axis up to 8, the rows of the kernel's f32 score
+    # and accumulator tiles. q itself may be bf16 (tile 16 rows): Mosaic
+    # compiles the 8-row block as is (chip_smoke.py checks it on the chip).
     Gp = max(8, group)
     qg = q.reshape(B, KV, group, D)
     if Gp != group:

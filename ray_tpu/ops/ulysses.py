@@ -25,6 +25,7 @@ import functools
 import math
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -32,7 +33,7 @@ from jax import lax
 def _ulysses_body(q, k, v, seg, *, axis_name: str, causal: bool, scale: float):
     """Per-shard body. q: [B, S_loc, H, D]; k/v: [B, S_loc, KV, D];
     seg: [B, S_loc] or None."""
-    from ray_tpu.ops.attention import flash_attention
+    from ray_tpu.ops.attention import flash_attention, flash_supported, mha_reference
 
     # Scatter heads, gather sequence: [B, S/n, H, D] -> [B, S, H/n, D].
     a2a = functools.partial(
@@ -42,7 +43,8 @@ def _ulysses_body(q, k, v, seg, *, axis_name: str, causal: bool, scale: float):
     seg_g = (
         lax.all_gather(seg, axis_name, axis=1, tiled=True) if seg is not None else None
     )
-    o = flash_attention(qg, kg, vg, causal=causal, scale=scale, segment_ids=seg_g)
+    local = flash_attention if flash_supported(qg.shape[1]) else mha_reference
+    o = local(qg, kg, vg, causal=causal, scale=scale, segment_ids=seg_g)
     # Back: scatter sequence, gather heads: [B, S, H/n, D] -> [B, S/n, H, D].
     return lax.all_to_all(o, axis_name, split_axis=1, concat_axis=2, tiled=True)
 
@@ -68,7 +70,6 @@ def ulysses_attention(
     from jax.sharding import PartitionSpec as P
 
     from ray_tpu.ops.attention import mha_reference
-    from ray_tpu.parallel._shard_map import shard_map
     from ray_tpu.parallel.sharding import _ambient_mesh
 
     *_, H, D = q.shape
@@ -102,12 +103,14 @@ def ulysses_attention(
         _ulysses_body, axis_name=axis_name, causal=causal, scale=scale
     )
     if segment_ids is None:
-        return shard_map(
+        return jax.shard_map(
             lambda q, k, v: body(q, k, v, None),
             mesh=mesh,
             in_specs=(spec, spec, spec),
             out_specs=spec,
+            check_vma=False,
         )(q, k, v)
-    return shard_map(
-        body, mesh=mesh, in_specs=(spec, spec, spec, seg_spec), out_specs=spec
+    return jax.shard_map(
+        body, mesh=mesh, in_specs=(spec, spec, spec, seg_spec), out_specs=spec,
+        check_vma=False,
     )(q, k, v, segment_ids)

@@ -71,32 +71,17 @@ class MeshSpec:
     def build(self, devices: Optional[Sequence] = None) -> "jax.sharding.Mesh":
         """Materialize a jax Mesh over `devices` (default: all visible)."""
         import jax
+        from jax.experimental import mesh_utils
         from jax.sharding import Mesh
 
         if devices is None:
             devices = jax.devices()
         sizes = self.resolved_sizes(len(devices))
-        try:
-            # mesh_utils lays devices out so inner axes land on ICI neighbours.
-            from jax.experimental import mesh_utils
-
-            dev_array = mesh_utils.create_device_mesh(
-                tuple(sizes[a] for a in AXIS_ORDER), devices=list(devices)
-            )
-        except Exception as e:
-            # Naive enumeration order loses ICI adjacency on real pods —
-            # loudly degrade, never silently.
-            import logging
-            import numpy as np
-
-            logging.getLogger(__name__).warning(
-                "mesh_utils.create_device_mesh failed (%s); falling back to "
-                "enumeration-order layout. On multi-chip hardware this can "
-                "put inner mesh axes on non-adjacent chips.", e
-            )
-            dev_array = np.asarray(list(devices)).reshape(
-                tuple(sizes[a] for a in AXIS_ORDER)
-            )
+        # mesh_utils lays devices out so inner axes land on ICI neighbours; a
+        # shape it cannot lay out is an error, not an enumeration-order mesh.
+        dev_array = mesh_utils.create_device_mesh(
+            tuple(sizes[a] for a in AXIS_ORDER), devices=list(devices)
+        )
         return Mesh(dev_array, AXIS_ORDER)
 
     def replace_inferred(self, n_devices: int) -> "MeshSpec":

@@ -48,8 +48,6 @@ def pipeline_apply(
     """Run the staged computation; returns [n_micro, mb, ...] outputs."""
     from jax.sharding import PartitionSpec as P
 
-    from ray_tpu.parallel._shard_map import shard_map
-
     n_stages = mesh.shape[axis_name]
     if n_stages == 1:
         def apply_all(h):
@@ -73,11 +71,12 @@ def pipeline_apply(
         n_stages=n_stages,
         n_micro=n_micro,
     )
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(param_specs, x_spec),
         out_specs=x_spec,
+        check_vma=False,
     )(stage_params, x)
 
 

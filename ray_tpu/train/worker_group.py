@@ -58,20 +58,28 @@ class TrainWorker:
     def setup_distributed(self, coordinator_addr: str, num_processes: int,
                           process_id: int, use_tpu: bool) -> bool:
         """jax.distributed bootstrap (reference: _setup_jax_distributed_environment,
-        v2/jax/config.py:30-86). No-op when the gang is a single process or on
-        the fake topology."""
+        v2/jax/config.py:30-86), skipped when the gang is a single process or
+        on the fake topology. A worker that was scheduled onto TPU resources
+        (or told use_tpu) then claims its chips here and raises unless its
+        own process sees a TPU with that many chips — never trains from
+        another backend under a TPU's name. Same trigger as the LLM replica:
+        the resources the scheduler assigned."""
         os.environ["RAYTPU_COORDINATOR"] = coordinator_addr
-        if use_tpu:
-            os.environ.setdefault("JAX_PLATFORMS", "tpu")
-        if num_processes <= 1 or not use_tpu:
+        chips = rt.get_runtime_context().get_assigned_resources().get("TPU", 0)
+        if not (use_tpu or chips):
             return True
-        import jax
+        from ray_tpu.accel import device as _device
 
-        jax.distributed.initialize(
-            coordinator_address=coordinator_addr,
-            num_processes=num_processes,
-            process_id=process_id,
-        )
+        _device.enable_compile_cache()
+        if use_tpu and num_processes > 1:
+            import jax
+
+            jax.distributed.initialize(
+                coordinator_address=coordinator_addr,
+                num_processes=num_processes,
+                process_id=process_id,
+            )
+        _device.require_tpu(chips, f"train worker {self.world_rank}")
         return True
 
     # -- training lifecycle ------------------------------------------------

@@ -105,6 +105,10 @@ def test_tp_params_ref_served_through_deployment():
                            "prefill_buckets": (16, 32), "kv_layout": "dense",
                            "tensor_parallel": 2},
             params=ref,
+            # Zero chips: this replica shards over the virtual CPU devices.
+            # One scheduled onto TPU resources would refuse to run on them
+            # (test_tp_replica_on_cpu_host_raises).
+            ray_actor_options={"resources": {"TPU": 0.0}},
         )
         serve.run(app, name="tp-handoff", http=False)
         h = serve.get_deployment_handle("llm", "tp-handoff")
@@ -153,39 +157,22 @@ def test_tp_prefix_cache_hit_correct():
     assert warm == cold
 
 
-def test_tp_serve_replica_gang():
-    """A TP-2 deployment declares {"TPU": 2}; the replica lands on the node
-    advertising those chips and serves correctly."""
+def test_tp_replica_on_cpu_host_raises():
+    """A TP-2 replica declares {"TPU": 2} (build_llm_app). Scheduled onto
+    those resources on a host whose devices are CPUs, it refuses to come up
+    rather than serve from the CPU under a TPU's name."""
     import ray_tpu as rt
-    from ray_tpu import serve
-    from ray_tpu.llm import build_llm_app
+    from ray_tpu.llm.deployment import LLMServer
 
     rt.init(num_cpus=8, resources={"TPU": 2.0})
-    serve.start(proxy=False)
     try:
-        app = build_llm_app(
-            model_config=dict(
-                vocab_size=96, d_model=64, n_layers=2, n_heads=4, n_kv_heads=2,
-                d_ff=128, max_seq_len=128, attention_impl="reference",
-            ),
-            engine_config={"max_slots": 4, "max_seq": 128,
-                           "prefill_buckets": (16, 32), "tensor_parallel": 2},
+        replica = rt.remote(LLMServer).options(resources={"TPU": 2.0}).remote(
+            dict(vocab_size=96, d_model=64, n_layers=2, n_heads=4, n_kv_heads=2,
+                 d_ff=128, max_seq_len=128, attention_impl="reference"),
+            {"max_slots": 4, "max_seq": 128, "prefill_buckets": (16, 32),
+             "tensor_parallel": 2},
         )
-        handle = serve.run(app, name="llm_tp_app", http=False)
-        out = handle.remote({"tokens": PROMPT, "max_tokens": 8}).result(timeout=300)
-        assert len(out["tokens"]) == 8
-        # The gang reservation is real: the TPU capacity is now held, so a
-        # second TP-2 replica cannot also fit on this 2-chip node.
-        from ray_tpu.core import api
-
-        state = api._cluster_state()
-        tpu_avail = [
-            n.get("available", {}).get("TPU", 0.0)
-            for n in state["nodes"].values()
-            if n["state"] == "ALIVE"
-        ]
-        assert max(tpu_avail, default=0.0) == 0.0, tpu_avail
-        serve.delete("llm_tp_app")
+        with pytest.raises(Exception, match="scheduled onto 2 TPU chip.*platform 'cpu'"):
+            rt.get(replica.check_health.remote(), timeout=300)
     finally:
-        serve.shutdown()
         rt.shutdown()

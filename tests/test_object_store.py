@@ -25,7 +25,6 @@ needs_native = pytest.mark.skipif(
 # first store construction), so these names are importable either way.
 from ray_tpu.core.ids import ObjectID
 from ray_tpu.core.object_store import (
-    SUPPORTS_PEP688,
     MemoryStore,
     ObjectExistsError,
     ObjectStoreFullError,
@@ -156,11 +155,6 @@ def test_memory_store():
     assert not ms.contains(oid)
 
 
-@pytest.mark.skipif(
-    not SUPPORTS_PEP688,
-    reason="zero-copy pinned reads need PEP 688 (__buffer__), Python 3.12+; "
-    "pre-3.12 interpreters read shm objects through a safe copy instead",
-)
 @needs_native
 def test_pinned_buffer_zero_copy_get():
     """get() of a big ndarray views the arena zero-copy: the array is
@@ -192,11 +186,9 @@ def test_pinned_buffer_zero_copy_get():
 
 
 @needs_native
-def test_big_object_get_any_interpreter():
-    """Value correctness of a big shm-object get on EVERY interpreter: on
-    3.12+ the read is a zero-copy pinned view; pre-3.12 it degrades to a
-    safe copy (deserialize's PinnedBuffer fallback) — either way the bytes
-    must round-trip."""
+def test_big_object_get_roundtrip():
+    """Value correctness of a big shm-object get (a zero-copy pinned view):
+    the bytes must round-trip."""
     import numpy as np
 
     import ray_tpu as rt

@@ -228,8 +228,9 @@ def test_attention_reference_causal():
 
 @pytest.mark.slow  # heavy battery; tier-1 budget (see CHANGES PR-13)
 def test_graft_entry_contract():
+    import os
     import sys
-    sys.path.insert(0, "/root/repo")
+    sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     import __graft_entry__ as ge
 
     fn, args = ge.entry()

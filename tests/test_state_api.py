@@ -354,7 +354,9 @@ def test_memory_summary_owner_and_borrower():
                 if "error" not in w for b in w.get("borrowed", []) if b["oid"] == oid
             ]
             drv = [b for b in ms["driver"]["borrowed"] if b["oid"] == oid]
-            if owners and borrows and drv:
+            # A borrower lists the object before its registration has reached
+            # the owner: wait for the owner's count too, not only the tables.
+            if owners and borrows and drv and owners[0][1]["borrowers"] == 2:
                 return ms, owners, borrows, drv
             return None
 

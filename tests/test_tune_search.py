@@ -247,11 +247,11 @@ rt.shutdown()
 
 
 def test_sweep_resumes_after_controller_killed(tmp_path):
-    repo = "/root/repo"
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     storage = str(tmp_path / "sweep")
     marks = str(tmp_path / "marks")
     os.makedirs(marks)
-    env = {**os.environ, "JAX_PLATFORMS": "cpu", "RAYTPU_FORCE_JAX_PLATFORM": "cpu"}
+    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
     # Phase 1: kill the controller process mid-sweep (trial 0/1 done or
     # running, later trials not started).
     p = subprocess.Popen(
