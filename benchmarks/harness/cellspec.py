@@ -1,0 +1,111 @@
+"""Find a cell's files by the names BENCHMARK.json gives, and turn them into
+what the program's entry points take. Everything that belongs to one
+configuration, traffic mix, cell or metric is a file of its own; this module
+is the only place that knows where they are."""
+from __future__ import annotations
+
+import copy
+import importlib.util
+import json
+import os
+
+BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+ROOT = os.path.dirname(BENCH_DIR)
+
+
+def _load(path: str) -> dict:
+    with open(path) as f:
+        return json.load(f)
+
+
+def load_cell(name: str) -> dict:
+    manifest = _load(os.path.join(ROOT, "BENCHMARK.json"))
+    cell = next((w for w in manifest["workloads"] if w["name"] == name), None)
+    if cell is None:
+        raise SystemExit(f"benchmark: no workload {name!r} in BENCHMARK.json; it has "
+                         f"{[w['name'] for w in manifest['workloads']]}")
+    config = next(c for c in manifest["configs"] if c["name"] == cell["config"])
+    spec = {
+        "name": name, "chips": int(cell["chips"]), "config_name": cell["config"],
+        "traffic_name": cell["traffic"],
+        "config": _load(os.path.join(ROOT, config["file"])),
+        "traffic": _load(os.path.join(BENCH_DIR, "traffic", cell["traffic"] + ".json")),
+    }
+    # A cell's own file is optional: overrides of the configuration's engine
+    # or train group that belong to this pairing alone.
+    own = os.path.join(BENCH_DIR, "workloads", name + ".json")
+    if os.path.exists(own):
+        for group, over in (_load(own).get("overrides") or {}).items():
+            spec["config"].setdefault(group, {}).update(over)
+
+    def metrics(kind):
+        return [m for m in manifest[kind] if "workloads" not in m or name in m["workloads"]]
+
+    spec["end_to_end"], spec["per_layer"] = metrics("end_to_end"), metrics("per_layer")
+    return spec
+
+
+def load_metric(name: str):
+    """The reader of one metric: benchmarks/metrics/<name>.py, `read(ctx)`."""
+    path = os.path.join(BENCH_DIR, "metrics", name + ".py")
+    if not os.path.exists(path):
+        raise SystemExit(f"benchmark: metric {name!r} has no reader at {path}")
+    mod_spec = importlib.util.spec_from_file_location("bench_metric_" + name.replace(".", "_"), path)
+    mod = importlib.util.module_from_spec(mod_spec)
+    mod_spec.loader.exec_module(mod)
+    return mod.read
+
+
+def transformer_kwargs(config: dict) -> dict:
+    """The published config's keys -> ray_tpu.models.TransformerConfig's.
+    head_dim is derived there as d_model // n_heads, which both published
+    configs satisfy (128); rms_norm_eps has no counterpart (see `assumed`)."""
+    d, h = config["hidden_size"], config["num_attention_heads"]
+    if config.get("head_dim", d // h) != d // h:
+        raise SystemExit("benchmark: TransformerConfig derives head_dim = hidden_size / heads")
+    return dict(
+        vocab_size=config["vocab_size"], d_model=d, n_layers=config["num_hidden_layers"],
+        n_heads=h, n_kv_heads=config["num_key_value_heads"], d_ff=config["intermediate_size"],
+        max_seq_len=config["max_position_embeddings"], rope_theta=float(config["rope_theta"]),
+        attention_impl="auto",
+        # Further TransformerConfig fields the configuration sets (dtypes by name).
+        **(config.get("transformer") or {}),
+    )
+
+
+def shrink_for_rehearsal(spec: dict) -> dict:
+    """Toy sizes for a CPU run of the same control flow. Its output is
+    counts only; nothing it prints is a measurement."""
+    spec = copy.deepcopy(spec)
+    spec["config"].update(hidden_size=128, num_hidden_layers=2, num_attention_heads=4,
+                          num_key_value_heads=2, intermediate_size=256, vocab_size=512,
+                          max_position_embeddings=512)
+    eng = spec["config"].get("engine")
+    if eng:
+        eng.update(max_slots=4, max_seq=256, page_size=32, total_pages=48,
+                   prefill_buckets=[32, 64, 128, 256], tensor_parallel=min(eng.get("tensor_parallel", 1), 2))
+    trn = spec["config"].get("train")
+    if trn:
+        trn.update(batch_rows=2, attention_block_q=0, attention_block_k=0, ce_chunk=0)
+    t = spec["traffic"]
+
+    def small(dist, cap):
+        dist = dict(dist)
+        for k in ("median", "min", "max", "value"):
+            if k in dist:
+                dist[k] = max(2, min(int(dist[k]) // 8, cap))
+        return dist
+
+    if t["kind"] == "serve":
+        t["prompt_len"], t["output_len"] = small(t["prompt_len"], 96), small(t["output_len"], 24)
+        if t.get("turns"):
+            t["turns"]["add_len"] = small(t["turns"]["add_len"], 16)
+            t["turns"]["think_s"] = 0.1
+        if t.get("prefix", {}).get("shared_len"):
+            t["prefix"]["shared_len"] = 32
+        t.update(ramp_s=1.0, cooldown_s=0.5)
+        if t["loop"] == "closed":
+            t.update(multiset=16, cycles=64)
+    else:
+        t.update(seq_len=128, documents=64, doc_len=small(t["doc_len"], 128), warm_steps=1)
+    return spec

@@ -1,0 +1,140 @@
+"""The yardstick's own checks: `python3 benchmarks/run.py --selftest`, on the
+CPU, in seconds. Kept with the benchmark (this PR may touch nothing else)."""
+from __future__ import annotations
+
+import json
+import os
+import traceback
+
+from harness import flops, schedule, xplane
+from harness.cellspec import BENCH_DIR, load_cell, load_metric
+from harness.stats import percentile, spread
+
+
+def _load(*parts):
+    with open(os.path.join(BENCH_DIR, *parts)) as f:
+        return json.load(f)
+
+
+def check_schedule_same_work_every_seed():
+    traffic = _load("traffic", "chat.json")
+    seen, orders = set(), set()
+    for seed in list(range(10)) + [2 ** 31 + 12345]:
+        plan = schedule.serve_plan(traffic, seed, 45, 32)
+        for phase in ("ramp", "window", "cooldown"):
+            rs = [r for r in plan["requests"] if r["phase"] == phase]
+            seen.add((phase, len(rs), tuple(sorted(r["prompt_len"] for r in rs)),
+                      tuple(sorted(r["out_len"] for r in rs))))
+        win = [r for r in plan["requests"] if r["phase"] == "window"]
+        orders.add(tuple(r["prompt_len"] for r in win))
+        due = [r["due"] for r in win]
+        assert due == sorted(due) and plan["ramp_s"] < due[0] and due[-1] < plan["ramp_s"] + 45
+        assert len(win) == round(traffic["rate_rps"] * 45)
+    assert len(seen) == 3, "the multiset or the count of a phase differs between seeds"
+    assert len(orders) == 11, "two seeds gave the same order"
+    a = schedule.serve_plan(traffic, 7, 45, 32)
+    assert a == schedule.serve_plan(traffic, 7, 45, 32), "the same seed gave another plan"
+    assert schedule.prompt_tokens(7, 3, 50, 1000) == schedule.prompt_tokens(7, 3, 50, 1000)
+
+
+def check_closed_loop_and_train_work():
+    plan = schedule.serve_plan(_load("traffic", "backlog.json"), 1, 45, 32)
+    assert plan["concurrency"] == 32 and len(plan["requests"]) == 64 * 32
+    first, second = plan["requests"][:64], plan["requests"][64:128]
+    assert sorted(r["out_len"] for r in first) == sorted(r["out_len"] for r in second)
+    traffic = _load("traffic", "pretrain-packed-4k.json")
+    rows = schedule.train_rows(traffic)
+    assert all(sum(r) <= 4097 for r in rows) and sum(len(r) for r in rows) == 1024
+    a, b = schedule.train_arrays(traffic, 0, 32768), schedule.train_arrays(traffic, 1, 32768)
+    assert sorted(map(tuple, a["doc_lens"])) == sorted(map(tuple, b["doc_lens"]))
+    assert a["doc_lens"] != b["doc_lens"]
+    seg, pos, docs = a["segment_ids"][0], a["positions"][0], a["doc_lens"][0]
+    assert int((seg > 0).sum()) == sum(docs) and int(pos[docs[0] - 1]) == docs[0] - 1
+    assert int(pos[docs[0]]) == 0 or len(docs) == 1
+    assert int(schedule.trained_tokens_per_row(a["doc_lens"])[0]) == sum(d - 1 for d in docs)
+
+
+def check_percentile_and_spread():
+    assert percentile([1, 2, 3, 4, 5], 50) == 3
+    assert percentile(range(101), 90) == 90
+    assert abs(percentile([10, 20], 90) - 19.0) < 1e-12
+    assert percentile([7], 99) == 7
+    # statistics.quantiles(n=4) of 1..6: Q1 = 1.75, Q3 = 5.25 (exclusive method)
+    assert abs(spread([1, 2, 3, 4, 5, 6]) - 3.5 / 3.5) < 1e-12
+
+
+def check_flops_against_hand_counts():
+    m7 = _load("configs", "mistral-7b-v0.3-l2.json")
+    # by hand: a layer is q 4096x4096 + k,v 2x4096x1024 + o 4096x4096 + 3x4096x14336
+    layer = 16_777_216 + 8_388_608 + 16_777_216 + 176_160_768
+    pc = flops.param_counts(m7)
+    assert pc["per_layer_matmul"] == layer == 218_103_808
+    assert pc["matmul"] == 2 * layer + 4096 * 32768 == 570_425_344
+    assert pc["total"] == 704_663_552
+    assert flops.param_counts(dict(m7, num_hidden_layers=32))["total"] == 7_248_023_552
+    i2 = _load("configs", "internlm2-1.8b.json")
+    assert flops.param_counts(i2)["total"] == 1_889_110_016
+    # one document of 4 tokens: 10 causal pairs; 12 * L * H * hd * pairs
+    assert flops.causal_pairs([4]) == 10
+    assert flops.train_flops(m7, 4, [4]) == 6.0 * 570_425_344 * 4 + 12.0 * 2 * 32 * 128 * 10
+    need = flops.paged_decode_needs(i2, context_tokens=1000, rows=10)
+    assert need["bytes"] == 2 * 8 * 128 * 2 * 1000 + 2 * 10 * 16 * 128 * 2
+    assert need["flops"] == 4.0 * 16 * 128 * 1000
+    fl = flops.flash_train_needs(m7, [4])
+    assert fl["flops"] == 12.0 * 32 * 128 * 10
+    assert fl["bytes"] == 6 * (4 * 32 * 128 * 2) + 6 * (4 * 8 * 128 * 2)
+    t, which = flops.roofline_seconds(need, {"flops_bf16": 197e12, "hbm_bytes_per_s": 819e9})
+    assert which == "memory" and abs(t - need["bytes"] / 819e9) < 1e-18
+
+
+def check_trace_reduction_on_recorded_trace():
+    """selftest_data/trace_small.json: a two-device trace in the plain form,
+    written by hand around the numbers below (ns). Device 0: module
+    jit_step(1) 1000..9000; ops a 1000..3000, kernel 3000..4000 (named as a
+    Mosaic call), all-reduce 5000..7000, b 6500..8000. Window 0..10000."""
+    out = xplane.reduce(_load("selftest_data", "trace_small.json"))
+    assert out["devices"] == 2 and abs(out["window_s"] - 10e-6) < 1e-15
+    # device 0 busy: 1000..4000 and 5000..8000 = 6000; device 1: 2000..6000 = 4000
+    assert abs(out["busy_s"] - 5e-6) < 1e-15, out["busy_s"]
+    # exposed collective on device 0: 5000..6500 = 1500; device 1 has none
+    assert abs(out["collective_exposed_s"] - 0.75e-6) < 1e-15, out["collective_exposed_s"]
+    assert abs(out["module_s"]["jit_step"] - (8000 + 4000) / 2 * 1e-9) < 1e-15
+    assert out["kernel"]["jit_step"]["calls"] == 0.5
+    assert abs(out["kernel"]["jit_step"]["seconds"] - 0.5e-6) < 1e-15
+    gaps = dict(out["idle_gaps"])
+    # device 0 idle: 0..1000 (between steps), 4000..5000 (inside bench.engine.step
+    # 3500..5200), 8000..10000 (between steps)
+    assert abs(gaps["inside_engine.step"] - 1e-6) < 1e-15 and abs(gaps["between_steps"] - 3e-6) < 1e-15
+    assert out["device_ops"][0][0] == "jit_step/a"
+
+
+def check_manifest_and_files():
+    manifest = _load(os.pardir, "BENCHMARK.json")
+    e2e = {m["name"] for m in manifest["end_to_end"]}
+    for w in manifest["workloads"]:
+        spec = load_cell(w["name"])
+        assert {m["name"] for m in spec["end_to_end"]} >= {"setup_s"} and len(spec["end_to_end"]) >= 2
+        assert spec["per_layer"], w["name"]
+        for m in spec["end_to_end"] + spec["per_layer"]:
+            load_metric(m["name"])
+        for m in spec["per_layer"]:
+            assert m["moves"] in {x["name"] for x in spec["end_to_end"]}, (w["name"], m["name"])
+    assert all(m["moves"] in e2e for m in manifest["per_layer"])
+
+
+CHECKS = [check_schedule_same_work_every_seed, check_closed_loop_and_train_work,
+          check_percentile_and_spread, check_flops_against_hand_counts,
+          check_trace_reduction_on_recorded_trace, check_manifest_and_files]
+
+
+def main() -> int:
+    failed = 0
+    for check in CHECKS:
+        try:
+            check()
+            print(f"ok    {check.__name__}")
+        except Exception:
+            failed += 1
+            print(f"FAIL  {check.__name__}\n{traceback.format_exc()}")
+    print(f"selftest: {len(CHECKS) - failed} of {len(CHECKS)} passed")
+    return 1 if failed else 0

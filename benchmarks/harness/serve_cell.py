@@ -1,0 +1,144 @@
+"""A serve cell: serve.run(build_llm_app(...)), load over streaming HTTP from a
+process of its own, numbers taken at the client. This driver never touches
+JAX: the replica holds the chip."""
+from __future__ import annotations
+
+import dataclasses
+import json
+import os
+import subprocess
+import sys
+import time
+
+from harness import schedule
+from harness.cellspec import BENCH_DIR, transformer_kwargs
+
+APP, ROUTE = "bench", "/llm"
+TRACE_S = 6.0  # the traced part of a --trace 1 window
+PROBE = {"prompt_len": 96, "out_len": 12}
+
+
+def _warmup_buckets(traffic: dict, engine: dict) -> list:
+    """Only the prefill buckets this traffic's prompts can reach."""
+    longest = int(traffic["prompt_len"].get("max", traffic["prompt_len"].get("value", 0)))
+    longest += int((traffic.get("prefix") or {}).get("shared_len", 0))
+    shortest = int(traffic["prompt_len"].get("min", traffic["prompt_len"].get("value", 1)))
+    buckets = sorted(engine["prefill_buckets"])
+    top = next((b for b in buckets if b >= longest), buckets[-1])
+    bottom = next(b for b in buckets if b >= min(shortest, top))
+    return [b for b in buckets if bottom <= b <= top]
+
+
+def _post(port: int, tokens: list, max_tokens: int) -> tuple[int, list]:
+    import http.client
+
+    conn = http.client.HTTPConnection("127.0.0.1", port, timeout=600)
+    try:
+        conn.request("POST", ROUTE, json.dumps(
+            {"tokens": tokens, "max_tokens": max_tokens, "stream": True, "ignore_eos": True}))
+        resp = conn.getresponse()
+        body = resp.read().decode()
+    finally:
+        conn.close()
+    out = []
+    for frame in body.split("\n\n"):
+        if frame.startswith("data: ") and frame != "data: [DONE]":
+            out += json.loads(frame[6:]).get("new_tokens", [])
+    return resp.status, out
+
+
+def run(spec: dict, seed: int, seconds: float, trace: bool, rehearse: bool, t_start: float,
+        workdir: str, say) -> dict:
+    import ray_tpu as rt
+    from ray_tpu import serve
+    from ray_tpu.accel.device import backend_initialized
+    from ray_tpu.llm import build_llm_app
+
+    from harness.replica import BenchLLMServer
+
+    config, traffic, chips = spec["config"], spec["traffic"], spec["chips"]
+    engine = dict(config["engine"])
+    engine["seed"] = int(seed) % (2 ** 31 - 1)  # weights from the seed, on the device
+    serve_opts = dict(config.get("serve") or {})
+    model_kwargs = transformer_kwargs(config)
+    plan = schedule.serve_plan(traffic, seed, seconds, engine["max_slots"])
+    phase = "window" if plan["loop"] == "open" else "stream"
+    say(f"offered: {json.dumps(schedule.plan_totals(plan, phase))} in phase {phase!r}"
+        + (f" at {traffic['rate_rps']} requests/s" if plan["loop"] == "open"
+           else f" to {plan['concurrency']} clients"))
+
+    tpu = 0 if rehearse else chips
+    rt.init(num_cpus=8, resources={"TPU": tpu} if tpu else None)
+    try:
+        serve.start()
+        app = build_llm_app(
+            model_config=model_kwargs, engine_config=engine,
+            warmup_buckets=tuple(_warmup_buckets(traffic, engine)),
+            ray_actor_options={"resources": {"TPU": float(tpu)}},
+            **serve_opts,
+        )
+        # The application as build_llm_app made it, its class replaced by the
+        # subclass that adds the benchmark's read-only methods and counters.
+        app.deployment = dataclasses.replace(app.deployment, func_or_class=BenchLLMServer)
+        app.deployment.config.startup_timeout_s = 1100.0
+        t_run = time.time()
+        serve.run(app, name=APP, route_prefix=ROUTE, timeout_s=1100)
+        ready_s = time.time() - t_run
+        replica = serve.get_deployment_handle("llm", APP)
+        call = lambda m, *a, t=600: getattr(replica, m).remote(*a).result(timeout=t)  # noqa: E731
+        dev0 = call("bench_device")
+        port = serve.http_port()
+        # One probe alone through the proxy: proves the path before load, and
+        # its tokens are held to the reference after the window.
+        probe_prompt = schedule.prompt_tokens(seed, 10 ** 6, PROBE["prompt_len"] if not rehearse else 12,
+                                              config["vocab_size"])
+        status, probe_out = _post(port, probe_prompt, PROBE["out_len"])
+        if status != 200 or len(probe_out) != PROBE["out_len"]:
+            raise SystemExit(f"benchmark: probe request failed: status {status}, {len(probe_out)} tokens")
+        call("bench_counters", True)
+
+        start_at = time.monotonic() + 1.0
+        # Process start to the first measured request: the ramp that brings
+        # the server to a steady state is set-up, not window.
+        setup_s = time.time() + 1.0 + plan["ramp_s"] - t_start
+        job = {"plan": plan, "port": port, "route": ROUTE, "vocab": config["vocab_size"],
+               "start_at": start_at, "drain_s": float(traffic.get("drain_s", 60.0)),
+               "max_seq": engine["max_seq"]}
+        job_path = os.path.join(workdir, "loadgen_job.json")
+        with open(job_path, "w") as f:
+            json.dump(job, f)
+        gen = subprocess.Popen([sys.executable, os.path.join(BENCH_DIR, "harness", "loadgen.py"), job_path])
+        if trace:
+            t_trace = start_at + plan["ramp_s"] + max(0.0, (seconds - TRACE_S) * 0.4)
+            call("bench_trace_start", t_trace, min(TRACE_S, seconds), os.path.join(workdir, "trace"))
+        w0 = start_at + plan["ramp_s"]
+        # Counters at the window's two ends (the replica answers between steps).
+        time.sleep(max(0.0, w0 - time.monotonic()))
+        c0 = call("bench_counters")
+        time.sleep(max(0.0, w0 + seconds - time.monotonic()))
+        c1 = call("bench_counters")
+        rc = gen.wait(timeout=seconds + plan["ramp_s"] + job["drain_s"] + 120)
+        if rc != 0:
+            raise SystemExit(f"benchmark: the load generator exited with code {rc}")
+        with open(job_path + ".out") as f:
+            client = json.load(f)
+        device = call("bench_device")
+        stats = call("stats")
+        traced = call("bench_trace_result", t=300) if trace else None
+        check = call("bench_reference_check", probe_prompt, probe_out, config, t=900)
+        driver_touched_jax = backend_initialized()
+    finally:
+        serve.shutdown()
+        rt.shutdown()
+
+    window = {k: c1[k] - c0[k] for k in c0 if isinstance(c0[k], (int, float)) and k != "at"}
+    window["queue_wait_s"] = c1["queue_wait_s"][len(c0["queue_wait_s"]):]
+    window["seconds"] = c1["at"] - c0["at"]
+    say(f"replica in the window: {json.dumps({k: v for k, v in window.items() if k != 'queue_wait_s'})}")
+    say(f"replica: ready in {ready_s:.1f} s (engine init + warm-up {dev0['init_s']:.1f} s, of which "
+        f"warm-up {dev0['warmup_s']:.1f} s); buckets warmed {_warmup_buckets(traffic, engine)}; "
+        f"reference check {json.dumps(check)}")
+    return {"kind": "serve", "plan": plan, "client": client, "window": window, "device": device,
+            "traced": traced, "check": check, "setup_s": setup_s, "stats": stats,
+            "driver_touched_jax": driver_touched_jax, "seconds": seconds, "traffic": traffic,
+            "config": config, "engine": engine}

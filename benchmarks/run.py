@@ -1,0 +1,263 @@
+"""One cell of the benchmark, one run, one JSON line.
+
+    python3 benchmarks/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
+    python3 benchmarks/run.py --workload <cell> --rehearse   toy sizes on the CPU: counts, no metric
+    python3 benchmarks/run.py --selftest                     the yardstick's own checks, on the CPU
+
+The process you start is a supervisor that never imports jax or ray_tpu: it
+runs the cell in a child that leads a session of its own, and when the child
+is done it sweeps that session and waits until every thread of it is gone
+(a killed chip holder lets go of the chip seconds after its leader dies).
+The child drives the program's normal entry points and never touches JAX
+either; the chip belongs to the serve replica or the train worker.
+"""
+from __future__ import annotations
+
+import time
+
+T_START = time.time()
+
+import argparse  # noqa: E402
+import json  # noqa: E402
+import os  # noqa: E402
+import re  # noqa: E402
+import shutil  # noqa: E402
+import signal  # noqa: E402
+import subprocess  # noqa: E402
+import sys  # noqa: E402
+
+BENCH_DIR = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(BENCH_DIR)
+RESULT_TAG = "BENCH_RESULT "
+DEADLINE_S = 1150  # a first run compiles; the driver allows it 1200 s
+
+
+def say(msg: str) -> None:
+    print(msg, flush=True)
+
+
+# ---------------------------------------------------------------------------
+# The child: run the cell, reduce it to the metrics of the line
+# ---------------------------------------------------------------------------
+
+def _clean_name(name: str) -> str:
+    return re.sub(r"[^A-Za-z0-9_./-]+", "_", name).strip("_")
+
+
+def child(args) -> None:
+    sys.path.insert(0, BENCH_DIR)
+    from harness import cellspec
+    from harness.context import Context
+
+    spec = cellspec.load_cell(args.workload)
+    if args.rehearse:
+        spec = cellspec.shrink_for_rehearsal(spec)
+    else:
+        # Fail at once where there is no chip to hold: a replica that cannot
+        # come up would keep serve.run waiting for its whole start-up budget.
+        # Counting the kernel's device files claims nothing (the process that
+        # holds the chip checks again, through JAX, what it really sees).
+        from ray_tpu.accel.tpu import chip_device_files
+
+        if len(chip_device_files()) < spec["chips"]:
+            raise SystemExit(f"benchmark: the cell needs {spec['chips']} TPU chip(s); this host "
+                             f"exposes {len(chip_device_files())}. No result.")
+    for item in args.set:
+        key, value = item.split("=", 1)
+        spec["traffic"][key] = json.loads(value)
+        say(f"traffic override (a sweep, not the cell as committed): {key} = {value}")
+    workdir = os.path.join(BENCH_DIR, ".work", _clean_name(args.workload))
+    shutil.rmtree(workdir, ignore_errors=True)
+    os.makedirs(workdir)
+    if spec["traffic"]["kind"] == "serve":
+        from harness import serve_cell as cell
+    elif spec["traffic"]["kind"] == "train":
+        from harness import train_cell as cell
+    else:
+        raise SystemExit(f"benchmark: unknown traffic kind {spec['traffic']['kind']!r}")
+    result = cell.run(spec, args.seed, args.seconds, bool(args.trace), args.rehearse,
+                      args.t_start, workdir, say)
+    ctx = Context(result, spec["chips"])
+
+    if result["kind"] == "serve":
+        dev = result["device"]
+        attempted = len(ctx.measured)
+        failed = attempted - len(ctx.finished)
+        done = ctx.finished
+        say("done: " + json.dumps({
+            "requests": len(done), "prompt_tokens": sum(x["prompt_len"] for x in done),
+            "output_tokens": sum(x["n_out"] for x in done),
+            "decode_steps": result["window"]["decode_steps"],
+            "prefills": result["window"]["prefill_calls"],
+            "prefill_requests": result["window"]["prefill_requests"]}))
+        if failed:
+            why = {}
+            for x in ctx.measured:
+                if not ctx.ok(x):
+                    key = f"status {x.get('status')}, {x.get('error')}, {x['n_out']}/{x['out_len']} tokens"
+                    why[key] = why.get(key, 0) + 1
+            say(f"failed requests: {json.dumps(why)}")
+        compiles = result["window"]["compiles"]
+        problems = [] if result["check"]["ok"] else [f"reference check failed: {result['check']}"]
+        if result["client"].get("stream_exhausted"):
+            problems.append("the closed loop ran out of requests")
+        device = {"platform": dev["platform"], "kind": dev["kind"], "count": dev["count"],
+                  "memory_peak_bytes": dev["memory_peak_bytes"]}
+    else:
+        w = result["worker"]
+        attempted, failed = w["steps"], 0
+        compiles = w["window_compiles"]
+        losses = w["losses"]
+        problems = []
+        if not all(x == x and abs(x) != float("inf") for x in losses):
+            problems.append(f"loss not finite: {losses[:5]}")
+        elif not sum(losses[-5:]) / 5 < sum(losses[:5]) / 5:
+            problems.append(f"loss did not fall over the window: {losses[:5]} -> {losses[-5:]}")
+        from harness.train_cell import LOSS_TOL
+
+        if not abs(w["loss_program"] - w["loss_reference"]) <= LOSS_TOL:
+            problems.append(f"loss {w['loss_program']} vs reference {w['loss_reference']} "
+                            f"(tolerance {LOSS_TOL}, {w['reference_tokens']} tokens)")
+        device = {"platform": w["platform"], "kind": w["device_kind"], "count": w["device_count"],
+                  "memory_peak_bytes": w["memory_peak_bytes"]}
+    if compiles:
+        problems.append(f"{compiles} compilation(s) inside the window")
+    if result["driver_touched_jax"]:
+        problems.append("the benchmark's driver initialised a JAX backend")
+    for p in problems:
+        say(f"NOT CORRECT: {p}")
+
+    if args.rehearse:
+        say(f"rehearsal complete on {device['platform']!r}: control flow only, "
+            f"attempted {attempted}, failed {failed}, problems {len(problems)}; no result")
+        if problems or failed:
+            raise SystemExit(1)
+        return
+    if device["platform"] != "tpu" or device["count"] != spec["chips"]:
+        raise SystemExit(f"benchmark: the cell needs {spec['chips']} TPU chip(s); the process that "
+                         f"holds the device reports {device}")
+    metrics = {}
+    for m in spec["per_layer"] if args.trace else spec["end_to_end"]:
+        value = cellspec.load_metric(m["name"])(ctx)
+        if value is not None:
+            metrics[m["name"]] = {"value": value, "unit": m["unit"]}
+    line = {"correct": not problems, "attempted": attempted, "failed": failed,
+            "metrics": metrics, "device": device}
+    if args.trace:
+        if not ctx.traced:
+            raise SystemExit(f"benchmark: the traced run gave no trace: "
+                             f"{result.get('traced') or result['worker'].get('traced')}")
+        device["busy_s"], device["window_s"] = ctx.traced["busy_s"], ctx.traced["window_s"]
+        line["breakdown"] = {
+            k: [[_clean_name(n), s] for n, s in ctx.traced[k]] for k in ("device_ops", "idle_gaps")}
+        say("traced: " + json.dumps({k: ctx.traced[k] for k in
+                                     ("module_s", "module_runs", "kernel", "line_names",
+                                      "collective_exposed_s", "xplane_bytes", "op_samples") if k in ctx.traced}))
+    say(RESULT_TAG + json.dumps(line))
+
+
+# ---------------------------------------------------------------------------
+# The supervisor
+# ---------------------------------------------------------------------------
+
+def _session_pids(sid: int) -> list[int]:
+    """Every process of a session with a thread still alive (a killed
+    process's leader turns zombie while its other threads are still closing
+    the files they share, the chip's among them)."""
+    out = []
+    for name in os.listdir("/proc"):
+        if name.isdigit():
+            try:
+                if os.getsid(int(name)) != sid:
+                    continue
+                for tid in os.listdir(f"/proc/{name}/task"):
+                    with open(f"/proc/{name}/task/{tid}/stat") as f:
+                        if f.read().rsplit(")", 1)[1].split()[0] not in "ZX":
+                            out.append(int(name))
+                            break
+            except (OSError, IndexError):
+                pass
+    return sorted(out)
+
+
+def supervise(args, argv: list) -> int:
+    if not os.path.isdir(os.path.join(ROOT, "ray_tpu")) or not os.path.exists(
+            os.path.join(ROOT, "BENCHMARK.json")):
+        say("benchmark: this checkout holds no system to measure (no ray_tpu/ beside benchmarks/)")
+        return 2
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.pathsep.join(p for p in (BENCH_DIR, ROOT, env.get("PYTHONPATH")) if p)
+    env["PYTHONUNBUFFERED"] = "1"
+    if args.rehearse:
+        env["JAX_PLATFORMS"] = "cpu"
+    cmd = [sys.executable, os.path.abspath(__file__), "--child", "--t-start", repr(T_START)] + argv
+    proc = subprocess.Popen(cmd, cwd=ROOT, env=env, stdout=subprocess.PIPE, text=True,
+                            start_new_session=True)
+    result = None
+    old = signal.signal(signal.SIGALRM, lambda *_: os.killpg(proc.pid, signal.SIGKILL))
+    signal.alarm(DEADLINE_S)
+    try:
+        for line in proc.stdout:
+            if line.startswith(RESULT_TAG):
+                result = line[len(RESULT_TAG):].strip()
+            else:
+                sys.stdout.write(line)
+                sys.stdout.flush()
+        rc = proc.wait()
+    finally:
+        signal.alarm(0)
+        signal.signal(signal.SIGALRM, old)
+        stragglers = _session_pids(proc.pid)
+        try:
+            os.killpg(proc.pid, signal.SIGKILL)
+        except ProcessLookupError:
+            pass
+        t0 = time.monotonic()
+        while _session_pids(proc.pid) and time.monotonic() - t0 < 120:
+            time.sleep(0.1)
+    left = _session_pids(proc.pid)
+    say(f"processes the run left for the sweep: {len(stragglers)}; after it: {len(left)} "
+        f"(waited {time.monotonic() - t0:.1f} s)")
+    if rc != 0 or left or (result is None and not args.rehearse):
+        say(f"benchmark: no result (exit code {rc}, processes left {left})")
+        return rc or 1
+    if result is not None:
+        say(result)
+    return 0
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
+    ap.add_argument("--workload")
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--seconds", type=float, default=None)
+    ap.add_argument("--trace", type=int, default=0, choices=(0, 1))
+    ap.add_argument("--rehearse", action="store_true")
+    ap.add_argument("--selftest", action="store_true")
+    ap.add_argument("--set", action="append", default=[], metavar="KEY=VALUE",
+                    help="override a key of the traffic file for this run (a sweep, not a measurement)")
+    ap.add_argument("--child", action="store_true", help=argparse.SUPPRESS)
+    ap.add_argument("--t-start", type=float, default=T_START, help=argparse.SUPPRESS)
+    args = ap.parse_args()
+    if args.selftest:
+        sys.path.insert(0, BENCH_DIR)
+        from harness import selftest
+
+        return selftest.main()
+    if not args.workload:
+        ap.error("--workload is required")
+    if args.seconds is None:
+        if not args.rehearse:
+            ap.error("--seconds is required")
+        args.seconds = 4.0
+    if args.child:
+        child(args)
+        return 0
+    argv = sys.argv[1:]
+    if "--seconds" not in argv:
+        argv += ["--seconds", str(args.seconds)]
+    return supervise(args, argv)
+
+
+if __name__ == "__main__":
+    sys.exit(main())
