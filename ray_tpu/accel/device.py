@@ -11,20 +11,31 @@ from __future__ import annotations
 import math
 import os
 import sys
+import time
+
+from ray_tpu.util.tracing import Ring
 
 COMPILE_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
 _CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 # The path is part of the cache key, so it is fixed: never a temp name.
 DEFAULT_COMPILE_CACHE_DIR = os.path.join(_CHECKOUT, ".jax_compile_cache")
+# What jax.monitoring reports once for each program the backend compiles (a
+# program found in the persistent cache is not compiled and not reported).
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_compiles = Ring(512)  # (time.monotonic() at the end of a compile, its seconds)
+_compile_counter = None  # the metrics plane's jax.compiles, once listening
 
 
 def enable_compile_cache() -> str:
     """Turn on JAX's persistent compilation cache; returns the directory.
+    From here on this process also counts its compilations
+    (``compile_events``).
 
     Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and this
     sets nothing — the operator placed the cache. Otherwise the cache sits at
     one fixed directory inside the checkout. Worker processes inherit the
     variable from the daemon's environment, so a whole cluster shares it."""
+    _count_compiles()
     placed = os.environ.get(COMPILE_CACHE_ENV)
     if placed:
         return placed
@@ -32,6 +43,33 @@ def enable_compile_cache() -> str:
 
     jax.config.update("jax_compilation_cache_dir", DEFAULT_COMPILE_CACHE_DIR)
     return DEFAULT_COMPILE_CACHE_DIR
+
+
+def _count_compiles() -> None:
+    """Register, once, this process's listener for backend compilations."""
+    global _compile_counter
+    if _compile_counter is not None:
+        return
+    import jax.monitoring
+
+    from ray_tpu.util import metrics
+
+    _compile_counter = metrics.Counter(
+        "jax.compiles", "programs this process's JAX backend compiled")
+
+    def on_duration(name, seconds, **_kw):
+        if name == COMPILE_EVENT:
+            _compiles.push((time.monotonic(), seconds))
+            _compile_counter.inc()
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+
+
+def compile_events() -> dict:
+    """Compilations since ``enable_compile_cache``: their count, and the most
+    recent as (time.monotonic() stamp at the end of the compile, seconds).
+    A compile inside a serving window is a request that waited for it."""
+    return {"count": _compiles.total, "recent": _compiles.snapshot()}
 
 
 def backend_initialized() -> bool:

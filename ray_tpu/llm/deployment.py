@@ -61,22 +61,34 @@ class LLMServer:
         # arrive one OOB buffer per shard and reassemble onto this replica's
         # devices (core/serialization.py; reference: tensor_transport
         # keeping tensors off the generic path, gpu_object_manager.py:55-75).
+        t0 = time.perf_counter()
         if params is not None:
             from ray_tpu.core.object_ref import ObjectRef
 
             if isinstance(params, ObjectRef):
                 params = rt.get(params, timeout=300.0)
+        t1 = time.perf_counter()
         self.engine = LLMEngine(cfg, params=params, engine_config=ec)
-        t0 = time.perf_counter()
+        t2 = time.perf_counter()
         if warmup_buckets:
             # Compile prefill/decode programs before the replica reports
             # healthy (vLLM-style startup warmup): cold compiles belong to
             # startup, never to a request's TTFT.
             self.engine.warmup(buckets=tuple(warmup_buckets))
-        self._warmup_s = time.perf_counter() - t0
+        self._warmup_s = time.perf_counter() - t2
+        # Where start-up went (stats()["startup"]): fetching the params,
+        # building the engine (weights made or resharded, KV pool), and each
+        # warmed program with its seconds (a cold compile or a cache read).
+        self._startup = {
+            "fetch_params_s": t1 - t0, "engine_init_s": t2 - t1, "warmup_s": self._warmup_s,
+            "programs": self.engine.warmup_log,
+        }
         self._cond = threading.Condition()
         self._done: dict[str, dict] = {}
         self._ttft: dict[str, float] = {}
+        # Lifecycle records (engine.add_request) of the requests in flight:
+        # the loop stamps first_emitted into them, their streams first_yielded.
+        self._life: dict[str, dict] = {}
         # TTFT distribution (serve.ttft_s): the SLO engine's third metric —
         # an LLM objective on time-to-first-token reads this histogram the
         # same way latency objectives read serve.request.latency_s.
@@ -124,9 +136,13 @@ class LLMServer:
         return sub.current_version if sub is not None else None
 
     def _loop(self):
+        from ray_tpu.llm.engine import record_request_spans
+
         while not self._stop:
             with self._cond:
                 aborts, self._aborts = self._aborts, set()
+                for rid in aborts:
+                    self._life.pop(rid, None)
                 if not aborts and not self.engine.has_work():
                     self._cond.wait(timeout=0.05)
                     continue
@@ -138,11 +154,15 @@ class LLMServer:
                 events = self.engine.step()
             if not events:
                 continue
+            first, traced = [], []
             with self._cond:
                 for rid, ev in events.items():
                     if ev.get("ttft_s") is not None:
                         self._ttft[rid] = ev["ttft_s"]
                         self._ttft_hist.observe(ev["ttft_s"])
+                        life = self._life.get(rid)
+                        if life is not None and life["first_emitted"] is None:
+                            first.append(life)
                     stream = self._streams.get(rid)
                     if stream is not None:
                         stream.append(ev)
@@ -152,7 +172,17 @@ class LLMServer:
                             "ttft_s": self._ttft.pop(rid, ev.get("ttft_s")),
                             "finish_reason": ev.get("finish_reason"),
                         }
+                        life = self._life.pop(rid, None)
+                        if life is not None and life["trace"] is not None:
+                            traced.append(life)
+                # The events are where their consumers will find them: one
+                # stamp for every request whose first token is among them.
+                now = time.monotonic()
+                for life in first:
+                    life["first_emitted"] = now
                 self._cond.notify_all()
+            for life in traced:
+                record_request_spans(life)
 
     def _new_rid(self) -> str:
         self._counter += 1
@@ -185,7 +215,8 @@ class LLMServer:
         with _tracing.child_span("llm.generate", max_tokens=max_tokens):
             with self._cond:
                 rid = self._new_rid()
-                self.engine.add_request(rid, tokens, max_tokens, sampling=sampling)
+                self._life[rid] = self.engine.add_request(
+                    rid, tokens, max_tokens, sampling=sampling)
                 self._cond.notify_all()
                 deadline = time.time() + timeout_s
                 while rid not in self._done:
@@ -228,7 +259,8 @@ class LLMServer:
         with self._cond:
             rid = self._new_rid()
             self._streams[rid] = deque()
-            self.engine.add_request(rid, tokens, max_tokens, sampling=sampling)
+            life = self._life[rid] = self.engine.add_request(
+                rid, tokens, max_tokens, sampling=sampling)
             self._cond.notify_all()
         deadline = time.time() + timeout_s
         finished = False
@@ -246,6 +278,8 @@ class LLMServer:
                             raise TimeoutError(f"generate timed out after {timeout_s}s")
                         self._cond.wait(timeout=min(remaining, slice_s))
                     ev = self._streams[rid].popleft()
+                if life["first_yielded"] is None:
+                    life["first_yielded"] = time.monotonic()
                 out = {
                     "new_tokens": ev.get("new_tokens", []),
                     "ttft_s": ev.get("ttft_s"),
@@ -307,10 +341,25 @@ class LLMServer:
         return self._thread.is_alive()
 
     def stats(self) -> dict:
+        """The operator's pull surface (openai.py's stats route returns it).
+        "trace" is what the program recorded of itself, on time.monotonic():
+        the lifecycle records of the finished requests and the phase records
+        of the ended steps still in their rings (engine.TRACE_RING each; what
+        fell off is counted in "dropped"), cumulative seconds by step phase,
+        and this process's compilations. "startup" says where start-up went.
+        Reads only; takes no lock."""
+        from ray_tpu.accel import device as _device
+
         active = sum(1 for s in self.engine.slots if s is not None)
         out = {"active_slots": active, "waiting": len(self.engine.waiting)}
         if self.engine.ec.prefix_cache:
             out["prefix_cache"] = self.engine.prefix_cache_stats
+        compiles = _device.compile_events()
+        out["trace"] = {
+            **self.engine.trace_snapshot(),
+            "compiles": compiles["recent"], "compiles_total": compiles["count"],
+        }
+        out["startup"] = self._startup
         return out
 
     def device_report(self) -> dict:

@@ -59,6 +59,17 @@ import numpy as np
 from ray_tpu.llm.sampling import SamplingParams, sample_batch
 from ray_tpu.models.transformer import TransformerConfig, _dense_ffn, _rms_norm, _rope, init_params
 from ray_tpu.ops.paged_attention import paged_attention, paged_attention_reference
+from ray_tpu.util import tracing as _tracing
+
+# The seams of LLMEngine.step, in the order a step passes them (PERF.md
+# section 3 says what each covers and which metric reads it).
+STEP_PHASES = (
+    "admit", "prefix_lookup", "prefill_dispatch", "mirror_sync", "prefill_fetch",
+    "decode_dispatch", "decode_fetch", "emit", "retire_sync",
+)
+# Finished requests and ended steps kept for LLMServer.stats(): a benchmark
+# run whole (a 51 s window with ramp and drain is ~300 requests, ~250 steps).
+TRACE_RING = 2048
 
 
 @dataclasses.dataclass
@@ -156,6 +167,10 @@ class _Slot:
     # prefix cache at retire (miss or partial hit; an exact hit adds nothing).
     prompt_tokens: Optional[np.ndarray] = None
     prompt_len: int = 0
+    # The request's lifecycle record (add_request makes it; stamps on
+    # time.monotonic()). arrived_at / first_token_at above stay on
+    # perf_counter: the streamed ttft_s is their difference.
+    life: Optional[dict] = None
 
 
 def _attn_proj(h, lp, cfg, dt):
@@ -176,33 +191,37 @@ def _prefill_layer(x, lp, cfg: TransformerConfig, positions, seg, mesh=None):
     from ray_tpu.ops.attention import flash_attention, flash_supported, mha_reference
 
     dt = x.dtype
-    h = _rms_norm(x, lp["attn_norm"])
-    q, k, v = _attn_proj(h, lp, cfg, dt)
-    q = _rope(q, positions, cfg.rope_theta)
-    k = _rope(k, positions, cfg.rope_theta)
+    with jax.named_scope("qkv"):
+        h = _rms_norm(x, lp["attn_norm"])
+        q, k, v = _attn_proj(h, lp, cfg, dt)
+        q = _rope(q, positions, cfg.rope_theta)
+        k = _rope(k, positions, cfg.rope_theta)
     use_flash = flash_supported(x.shape[1])
     tp_sharded = mesh is not None and mesh.shape.get("tensor", 1) > 1
-    if use_flash and tp_sharded:
-        from jax.sharding import PartitionSpec as P
+    with jax.named_scope("flash_attn"):
+        if use_flash and tp_sharded:
+            from jax.sharding import PartitionSpec as P
 
-        def _flash_shard(q_, k_, v_, seg_):
-            return flash_attention(q_, k_, v_, causal=True, segment_ids=seg_)
+            def _flash_shard(q_, k_, v_, seg_):
+                return flash_attention(q_, k_, v_, causal=True, segment_ids=seg_)
 
-        hs = P(None, None, "tensor", None)
-        o = jax.shard_map(
-            _flash_shard,
-            mesh=mesh,
-            in_specs=(hs, hs, hs, P(None, None)),
-            out_specs=hs,
-            check_vma=False,
-        )(q, k, v, seg)
-    elif use_flash:
-        o = flash_attention(q, k, v, causal=True, segment_ids=seg)
-    else:
-        o = mha_reference(q, k, v, causal=True, segment_ids=seg)
-    x = x + jnp.einsum("bshk,hkd->bsd", o, lp["wo"].astype(dt))
-    h = _rms_norm(x, lp["ffn_norm"])
-    x = x + _dense_ffn(h, lp)
+            hs = P(None, None, "tensor", None)
+            o = jax.shard_map(
+                _flash_shard,
+                mesh=mesh,
+                in_specs=(hs, hs, hs, P(None, None)),
+                out_specs=hs,
+                check_vma=False,
+            )(q, k, v, seg)
+        elif use_flash:
+            o = flash_attention(q, k, v, causal=True, segment_ids=seg)
+        else:
+            o = mha_reference(q, k, v, causal=True, segment_ids=seg)
+    with jax.named_scope("attn_out"):
+        x = x + jnp.einsum("bshk,hkd->bsd", o, lp["wo"].astype(dt))
+    with jax.named_scope("ffn"):
+        h = _rms_norm(x, lp["ffn_norm"])
+        x = x + _dense_ffn(h, lp)
     return x, k, v
 
 
@@ -239,6 +258,36 @@ def _decode_layer_dense(x, lp, ck, cv, cfg: TransformerConfig, lengths):
 def _sample1(logits, temp, top_p, top_k, key, cap=None):
     """Single-row wrapper over the batched per-request sampler."""
     return sample_batch(logits[None], temp[None], top_p[None], top_k[None], key, cap=cap)[0]
+
+
+# A traced request's phases inside the replica, as child spans of the trace
+# context add_request captured: (span name, stamp it starts at, stamp it ends at).
+REQUEST_SPANS = (
+    ("llm.queue", "arrived", "admitted"),
+    ("llm.prefill", "admitted", "first_token"),
+    ("llm.first_emit", "first_token", "first_emitted"),
+    ("llm.decode", "first_emitted", "finished"),
+)
+
+
+def record_request_spans(life: dict) -> None:
+    """Lay a finished request's lifecycle onto its own trace (no-op for an
+    untraced one). Called by the thread that steps the engine, where the
+    request's contextvar is not set: the context rides the record. Stamps
+    are converted once to the tracing.now() clock. A phase with a stamp
+    missing (an abort before admission; no serving loop around the engine)
+    is left out; one that ends before it starts (a request that finished in
+    the step of its first token retires before that token is emitted)
+    records zero seconds."""
+    ctx = life["trace"]
+    if ctx is None:
+        return
+    to_span_clock = _tracing.now() - time.monotonic()
+    for name, start, end in REQUEST_SPANS:
+        t0, t1 = life[start], life[end]
+        if t0 is not None and t1 is not None:
+            _tracing.record_span(ctx, name, t0 + to_span_clock, t1 - t0,
+                                 req_id=life["req_id"], slot=life["slot"])
 
 
 class LLMEngine:
@@ -368,6 +417,11 @@ class LLMEngine:
         self.d_top_ps = jnp.asarray(self.samp_top_ps)
         self.d_top_ks = jnp.asarray(self.samp_top_ks)
         self.waiting: deque = deque()
+        self._phases = _tracing.PhaseSpans(
+            "llm.step", STEP_PHASES, TRACE_RING, annotation=jax.profiler.TraceAnnotation
+        )
+        self.request_ring = _tracing.Ring(TRACE_RING)
+        self.warmup_log: list[dict] = []  # one entry a warmed program, with its seconds
         self._key = jax.random.PRNGKey(self.ec.seed + 1)
         self._prefill_jit: dict[int, Any] = {}
         self.mosaic: dict[str, bool] = {}  # filled by warmup()
@@ -492,37 +546,41 @@ class LLMEngine:
         ps = self.ec.page_size
         P = tokens.shape[0]
         n_pg = P // ps
-        x = params["embed"].astype(cfg.dtype)[tokens][None]  # [1,P,D]
+        with jax.named_scope("embed"):
+            x = params["embed"].astype(cfg.dtype)[tokens][None]  # [1,P,D]
         pos = jnp.arange(P, dtype=jnp.int32)[None]
         seg = (pos >= length).astype(jnp.int32)  # pads = their own segment
 
         def scan_fn(h, xs):
             lp, ck_l, cv_l = xs
             h, k_new, v_new = _prefill_layer(h, lp, cfg, pos, seg, mesh=self.mesh)
-            # [1,P,KV,Hd] -> [KV,P,Hd]; scatter page chunks into the pool.
-            kt = k_new[0].transpose(1, 0, 2).astype(ck_l.dtype)
-            vt = v_new[0].transpose(1, 0, 2).astype(cv_l.dtype)
+            with jax.named_scope("kv_write"):
+                # [1,P,KV,Hd] -> [KV,P,Hd]; scatter page chunks into the pool.
+                kt = k_new[0].transpose(1, 0, 2).astype(ck_l.dtype)
+                vt = v_new[0].transpose(1, 0, 2).astype(cv_l.dtype)
 
-            def write(p, pools):
-                ck, cv = pools
-                start = page_idxs[p] * ps
-                ck = jax.lax.dynamic_update_slice(
-                    ck, jax.lax.dynamic_slice(kt, (0, p * ps, 0), (cfg.kv_heads, ps, cfg.head_dim)),
-                    (0, start, 0))
-                cv = jax.lax.dynamic_update_slice(
-                    cv, jax.lax.dynamic_slice(vt, (0, p * ps, 0), (cfg.kv_heads, ps, cfg.head_dim)),
-                    (0, start, 0))
-                return ck, cv
+                def write(p, pools):
+                    ck, cv = pools
+                    start = page_idxs[p] * ps
+                    ck = jax.lax.dynamic_update_slice(
+                        ck, jax.lax.dynamic_slice(kt, (0, p * ps, 0), (cfg.kv_heads, ps, cfg.head_dim)),
+                        (0, start, 0))
+                    cv = jax.lax.dynamic_update_slice(
+                        cv, jax.lax.dynamic_slice(vt, (0, p * ps, 0), (cfg.kv_heads, ps, cfg.head_dim)),
+                        (0, start, 0))
+                    return ck, cv
 
-            ck_l, cv_l = jax.lax.fori_loop(0, n_pg, write, (ck_l, cv_l))
+                ck_l, cv_l = jax.lax.fori_loop(0, n_pg, write, (ck_l, cv_l))
             return h, (ck_l, cv_l)
 
         x, (k_pages, v_pages) = jax.lax.scan(scan_fn, x, (params["layers"], k_pages, v_pages))
-        x = _rms_norm(x, params["final_norm"])
-        last = jax.lax.dynamic_index_in_dim(x[0], length - 1, axis=0, keepdims=False)
-        logits = last @ params["lm_head"].astype(cfg.dtype)
-        tok = _sample1(logits.astype(jnp.float32), temp, top_p, top_k, key,
-                       cap=self.ec.sample_topk_cap)
+        with jax.named_scope("lm_head"):
+            x = _rms_norm(x, params["final_norm"])
+            last = jax.lax.dynamic_index_in_dim(x[0], length - 1, axis=0, keepdims=False)
+            logits = last @ params["lm_head"].astype(cfg.dtype)
+        with jax.named_scope("sample"):
+            tok = _sample1(logits.astype(jnp.float32), temp, top_p, top_k, key,
+                           cap=self.ec.sample_topk_cap)
         return k_pages, v_pages, tok
 
     def _decode_impl(self, params, k_pages, v_pages, last_tokens, lengths, page_tables, n_steps, key, temps, top_ps, top_ks):
@@ -542,36 +600,44 @@ class LLMEngine:
 
         def one_step(carry, step_key):
             kp, vp, last, lens = carry
-            x = params["embed"].astype(cfg.dtype)[last][:, None, :]  # [B,1,D]
+            with jax.named_scope("embed"):
+                x = params["embed"].astype(cfg.dtype)[last][:, None, :]  # [B,1,D]
             # Linear write position per slot: its page for len, plus offset.
             lin = page_tables[rows, lens // ps] * ps + lens % ps  # [B]
 
             def scan_fn(h, xs):
                 lp, ck_l, cv_l = xs
                 dt = h.dtype
-                hh = _rms_norm(h, lp["attn_norm"])
-                q, k_new, v_new = _attn_proj(hh, lp, cfg, dt)
-                pos = lens[:, None]
-                q = _rope(q, pos, cfg.rope_theta)
-                k_new = _rope(k_new, pos, cfg.rope_theta)
-                # [B,1,KV,Hd] -> [KV,B,Hd]; scatter at lin per slot.
-                ck_l = ck_l.at[:, lin].set(k_new[:, 0].transpose(1, 0, 2).astype(ck_l.dtype))
-                cv_l = cv_l.at[:, lin].set(v_new[:, 0].transpose(1, 0, 2).astype(cv_l.dtype))
-                pool = (cfg.kv_heads, -1, ps, cfg.head_dim)
-                o = attend(
-                    q[:, 0], ck_l.reshape(pool), cv_l.reshape(pool),
-                    lens + 1, page_tables,
-                )  # [B, H, Hd]
-                h = h + jnp.einsum("bhk,hkd->bd", o, lp["wo"].astype(dt))[:, None, :]
-                hh = _rms_norm(h, lp["ffn_norm"])
-                h = h + _dense_ffn(hh, lp)
+                with jax.named_scope("qkv"):
+                    hh = _rms_norm(h, lp["attn_norm"])
+                    q, k_new, v_new = _attn_proj(hh, lp, cfg, dt)
+                    pos = lens[:, None]
+                    q = _rope(q, pos, cfg.rope_theta)
+                    k_new = _rope(k_new, pos, cfg.rope_theta)
+                with jax.named_scope("kv_write"):
+                    # [B,1,KV,Hd] -> [KV,B,Hd]; scatter at lin per slot.
+                    ck_l = ck_l.at[:, lin].set(k_new[:, 0].transpose(1, 0, 2).astype(ck_l.dtype))
+                    cv_l = cv_l.at[:, lin].set(v_new[:, 0].transpose(1, 0, 2).astype(cv_l.dtype))
+                with jax.named_scope("paged_attn"):
+                    pool = (cfg.kv_heads, -1, ps, cfg.head_dim)
+                    o = attend(
+                        q[:, 0], ck_l.reshape(pool), cv_l.reshape(pool),
+                        lens + 1, page_tables,
+                    )  # [B, H, Hd]
+                with jax.named_scope("attn_out"):
+                    h = h + jnp.einsum("bhk,hkd->bd", o, lp["wo"].astype(dt))[:, None, :]
+                with jax.named_scope("ffn"):
+                    hh = _rms_norm(h, lp["ffn_norm"])
+                    h = h + _dense_ffn(hh, lp)
                 return h, (ck_l, cv_l)
 
             x, (kp, vp) = jax.lax.scan(scan_fn, x, (params["layers"], kp, vp))
-            x = _rms_norm(x, params["final_norm"])
-            logits = jnp.einsum("bsd,dv->bv", x, params["lm_head"].astype(cfg.dtype))
-            toks = sample_batch(logits.astype(jnp.float32), temps, top_ps, top_ks,
-                                step_key, cap=self.ec.sample_topk_cap)
+            with jax.named_scope("lm_head"):
+                x = _rms_norm(x, params["final_norm"])
+                logits = jnp.einsum("bsd,dv->bv", x, params["lm_head"].astype(cfg.dtype))
+            with jax.named_scope("sample"):
+                toks = sample_batch(logits.astype(jnp.float32), temps, top_ps, top_ks,
+                                    step_key, cap=self.ec.sample_topk_cap)
             return (kp, vp, toks, lens + 1), toks
 
         keys = jax.random.split(key, n_steps)
@@ -785,8 +851,10 @@ class LLMEngine:
             # the persistent cache instead of compiling a second time.
             return on_tpu and "tpu_custom_call" in jitted.lower(*args).compile().as_text()
 
+        log = self.warmup_log
         for b in buckets:
             for k in k_values:
+                t0 = time.monotonic()
                 toks = jnp.zeros((k, b), jnp.int32)
                 lens = jnp.ones(k, jnp.int32)
                 if self.paged:
@@ -808,7 +876,10 @@ class LLMEngine:
                 self.d_lengths = self.d_lengths.at[idxs].set(lens)
                 self.d_last = self.d_last.at[idxs].set(td)
                 jax.device_get(td)
+                log.append({"program": "prefill", "bucket": b, "k": k,
+                            "seconds": time.monotonic() - t0})
         for n in self.block_sizes:
+            t0 = time.monotonic()
             head = (self.params, self.k_pages, self.v_pages, self.d_last, self.d_lengths)
             tail = (n, key, self.d_temps, self.d_top_ps, self.d_top_ks)
             args = head + ((self.d_page_tables,) if self.paged else ()) + tail
@@ -817,12 +888,16 @@ class LLMEngine:
             out = self._decode_jit(*args)
             self.k_pages, self.v_pages = out[0], out[1]
             jax.device_get(out[2])
+            log.append({"program": "decode", "block": n, "seconds": time.monotonic() - t0})
         if self.paged and self.ec.prefix_cache:
             # Compile the prefix-cache page copy (padded rows hit page 0).
+            t0 = time.monotonic()
             z = jnp.zeros(self.ppseq, jnp.int32)
             self.k_pages, self.v_pages = self._copy_pages_jit(
                 self.k_pages, self.v_pages, z, z
             )
+            jax.block_until_ready(self.k_pages)
+            log.append({"program": "copy_pages", "seconds": time.monotonic() - t0})
         # Reset device mirrors dirtied by the dummy executions.
         self.d_lengths = jnp.zeros(self.ec.max_slots, jnp.int32)
         self.d_last = jnp.zeros(self.ec.max_slots, jnp.int32)
@@ -832,7 +907,17 @@ class LLMEngine:
                     sampling: SamplingParams | None = None):
         """Queue a request. `sampling` carries the per-request decode params
         (temperature/top_p/top_k/max_tokens/stop_token_ids); without it the
-        engine-global defaults (EngineConfig.temperature, greedy top) apply."""
+        engine-global defaults (EngineConfig.temperature, greedy top) apply.
+
+        Returns the request's lifecycle record: one stamp an event, on
+        time.monotonic(), each written where the event happens. This engine
+        writes arrived, admitted (with slot, prefix_hit_len, bucket),
+        first_token (the token is on the host), finished (the slot retired;
+        with n_out, finish_reason) and then pushes the record to
+        request_ring; a serving loop around the engine writes first_emitted
+        and first_yielded into the same dict. `trace` is the caller's active
+        trace context or None: the one ContextVar.get an untraced request
+        pays (util/tracing's cost contract)."""
         if sampling is None:
             sampling = SamplingParams(
                 temperature=self.ec.temperature, max_tokens=max_tokens
@@ -844,9 +929,23 @@ class LLMEngine:
             raise ValueError(
                 f"request needs {need} pages > pool size {self.ec.total_pages - 1}"
             )
+        life = {
+            "req_id": req_id, "arrived": time.monotonic(), "admitted": None, "slot": None,
+            "prompt_len": len(tokens), "prefix_hit_len": None, "bucket": None,
+            "first_token": None, "first_emitted": None, "first_yielded": None,
+            "finished": None, "n_out": None, "finish_reason": None,
+            "trace": _tracing.current_trace(),
+        }
         self.waiting.append(
-            (req_id, np.asarray(tokens, np.int32), sampling, time.perf_counter())
+            (req_id, np.asarray(tokens, np.int32), sampling, time.perf_counter(), life)
         )
+        return life
+
+    def _close_life(self, life: dict, n_out: int, reason: str) -> None:
+        life["finished"] = time.monotonic()
+        life["n_out"] = n_out
+        life["finish_reason"] = reason
+        self.request_ring.push(life)
 
     def set_params(self, params) -> None:
         """In-place weight hot-swap (ckpt publication plane): reshard the
@@ -867,9 +966,13 @@ class LLMEngine:
         """Drop a request whose consumer went away: dequeue it, or free its
         slot so decode stops spending steps on it. Call from the stepping
         thread only (mutates scheduler state + device mirrors)."""
+        for w in self.waiting:
+            if w[0] == req_id:
+                self._close_life(w[4], 0, "abort")
         self.waiting = deque(w for w in self.waiting if w[0] != req_id)
         for i, s in enumerate(self.slots):
             if s is not None and s.req_id == req_id:
+                self._close_life(s.life, len(s.emitted), "abort")
                 self._retire(i)
                 self.d_lengths = jnp.asarray(self._masked_lengths())
                 self.d_page_tables = jnp.asarray(self._masked_page_tables())
@@ -978,12 +1081,40 @@ class LLMEngine:
             "cached_pages": len(self._page_refs),  # distinct pages held
         }
 
+    def trace_snapshot(self) -> dict:
+        """What the program recorded of itself, for LLMServer.stats(): the
+        finished requests' lifecycle records and the ended steps' phase
+        records still in their rings (stamps on time.monotonic()), the
+        cumulative seconds and entries of each phase, and what the rings
+        dropped. Reads only, takes no lock; any thread may call it."""
+        steps = self._phases
+        return {
+            "clock": "monotonic", "now": time.monotonic(),
+            "requests": self.request_ring.snapshot(), "requests_total": self.request_ring.total,
+            "steps": steps.ring.snapshot(), "steps_total": steps.ring.total,
+            "phase_s": dict(steps.phase_s), "phase_n": dict(steps.phase_n),
+            "dropped": {"requests": self.request_ring.dropped, "steps": steps.ring.dropped},
+        }
+
     def step(self) -> dict:
         """One engine iteration: admit waiting requests into free slots +
         free pages (prefill, grouped by length bucket, groups dispatched
         async then fetched in order), then one decode block for all slots.
         Returns {req_id: {"token": int, "new_tokens": [...], "finished":
-        bool, "ttft_s": float|None, "tokens": [..] when done}}."""
+        bool, "ttft_s": float|None, "tokens": [..] when done}}.
+
+        The step's seams are phase spans (STEP_PHASES; util/tracing.PhaseSpans):
+        each `ph.to(...)` below ends one phase and starts the next, and the
+        step's record goes to the ring that LLMServer.stats() returns."""
+        ph = self._phases
+        ph.begin("admit", waiting=len(self.waiting), n_admitted=0, n_prefill=0,
+                 block=0, active=0)
+        try:
+            return self._step(ph)
+        finally:
+            ph.end()
+
+    def _step(self, ph) -> dict:
         events: dict[str, dict] = {}
         retired = False
         ps = self.ec.page_size
@@ -997,7 +1128,7 @@ class LLMEngine:
         for i in range(self.ec.max_slots):
             if not self.waiting or self.slots[i] is not None:
                 continue
-            req_id, tokens, sp, arrived = self.waiting[0]
+            req_id, tokens, sp, arrived, life = self.waiting[0]
             P = len(tokens)
             need = self._pages_needed(P, sp.max_tokens)
             # Cache lookup BEFORE eviction: longest match first — the full
@@ -1006,11 +1137,13 @@ class LLMEngine:
             hit_dg = hit_entry = None
             hit_len = 0
             if use_cache:
+                ph.to("prefix_lookup")
                 for n, dg in reversed(self._prefix_digests(tokens)):
                     e = self._prefix_cache.get(dg)
                     if e is not None and e["prompt_len"] == n and (n == P or n % ps == 0):
                         hit_dg, hit_entry, hit_len = dg, e, n
                         break
+                ph.to("admit")
             if need > len(self.free_pages):
                 self._evict_prefix_cache(
                     need - len(self.free_pages),
@@ -1027,6 +1160,10 @@ class LLMEngine:
                 if need > len(self.free_pages):
                     break  # head-of-line blocks until pages free (FIFO fairness)
             self.waiting.popleft()
+            life["admitted"] = time.monotonic()
+            life["slot"] = i
+            life["prefix_hit_len"] = hit_len if hit_entry is not None else 0
+            ph.rec["n_admitted"] += 1
             pages = [self.free_pages.popleft() for _ in range(need)]
             exact = hit_entry is not None and hit_len == P
             self.slots[i] = _Slot(
@@ -1036,7 +1173,7 @@ class LLMEngine:
                 prompt_tokens=(
                     np.asarray(tokens, np.int32) if (use_cache and not exact) else None
                 ),
-                prompt_len=P,
+                prompt_len=P, life=life,
             )
             self.samp_temps[i] = sp.temperature
             self.samp_top_ps[i] = sp.top_p
@@ -1056,9 +1193,11 @@ class LLMEngine:
                 src[:n_pp] = hit_entry["pages"]
                 dst = np.zeros(self.ppseq, np.int32)
                 dst[:n_pp] = pages[:n_pp]
+                ph.to("prefill_dispatch")  # the copy stands where a prefill would
                 self.k_pages, self.v_pages = self._copy_pages_jit(
                     self.k_pages, self.v_pages, jnp.asarray(src), jnp.asarray(dst)
                 )
+                ph.to("admit")
                 if exact:
                     # Decode from position P-1: the block re-derives that
                     # position's KV and emits the first token — no prefill.
@@ -1093,9 +1232,10 @@ class LLMEngine:
                 if use_cache:
                     self.prefix_misses += 1
                 self.lengths[i] = P
-                bucket = next(b for b in self.buckets if b >= P)
+                bucket = life["bucket"] = next(b for b in self.buckets if b >= P)
                 admitted.append((i, req_id, tokens, bucket, sp.max_tokens, arrived))
         if cache_hits:
+            ph.to("mirror_sync")
             idx = jnp.asarray(np.array([h[0] for h in cache_hits], np.int32))
             self.d_lengths = self.d_lengths.at[idx].set(
                 jnp.asarray(np.array([self.lengths[h[0]] for h in cache_hits], np.int32))
@@ -1105,6 +1245,8 @@ class LLMEngine:
             )
         # 2. dispatch prefill groups back-to-back (async), fetch in order so
         # each group's TTFT is its own completion time.
+        if admitted or tail_admitted or self._prefilling:
+            ph.to("prefill_dispatch")
         by_bucket: dict[int, list] = {}
         for item in admitted:
             by_bucket.setdefault(item[3], []).append(item)
@@ -1134,8 +1276,11 @@ class LLMEngine:
                     jnp.asarray(self.samp_top_ps[idxs]),
                     jnp.asarray(self.samp_top_ks[idxs]),
                 )
+                ph.to("mirror_sync")
                 self.d_lengths = self.d_lengths.at[idx_arr].set(jnp.asarray(lens))
                 self.d_last = self.d_last.at[idx_arr].set(toks_dev)
+                ph.to("prefill_dispatch")
+                ph.rec["n_prefill"] += 1
                 dispatched.append((chunk, toks_dev))
         # Partial-prefix hits: per-request tail prefill over the cached
         # context pages (tail + ctx sizes snap to buckets; one compiled
@@ -1144,6 +1289,7 @@ class LLMEngine:
             P = len(tokens)
             tail = tokens[start:]
             tb = next(b for b in self.buckets if b >= len(tail))
+            self.slots[i].life["bucket"] = tb
             j = start // ps
             C = next(c for c in self.c_buckets if c >= j)
             padded = np.zeros(tb, np.int32)
@@ -1163,8 +1309,11 @@ class LLMEngine:
                 jnp.asarray(self.samp_top_ps[i:i + 1]),
                 jnp.asarray(self.samp_top_ks[i:i + 1]),
             )
+            ph.to("mirror_sync")
             self.d_lengths = self.d_lengths.at[i].set(P)
             self.d_last = self.d_last.at[i].set(toks_dev[0])
+            ph.to("prefill_dispatch")
+            ph.rec["n_prefill"] += 1
             dispatched.append(([(i, req_id, tokens, None, _mt, arrived)], toks_dev))
         # 2c. chunked prefill: advance every mid-prefill slot by ONE chunk —
         # the interleave contract is at most one chunk of prefill compute
@@ -1209,18 +1358,22 @@ class LLMEngine:
                 jnp.asarray(self.samp_top_ps[i:i + 1]),
                 jnp.asarray(self.samp_top_ks[i:i + 1]),
             )
+            ph.rec["n_prefill"] += 1
             if last_chunk:
                 del self._prefilling[i]
                 slot.prefill_pos = P
                 slot.n_generated = 1
+                ph.to("mirror_sync")
                 self.d_lengths = self.d_lengths.at[i].set(P)
                 self.d_last = self.d_last.at[i].set(toks_dev[0])
+                ph.to("prefill_dispatch")
                 dispatched.append(
                     ([(i, slot.req_id, tokens, None, slot.max_tokens,
                        slot.arrived_at)], toks_dev))
             else:
                 slot.prefill_pos = start + n_tok
         if admitted or cache_hits or tail_admitted or chunk_dispatched:
+            ph.to("mirror_sync")
             self.d_page_tables = jnp.asarray(self._masked_page_tables())
             self.d_temps = jnp.asarray(self.samp_temps)
             self.d_top_ps = jnp.asarray(self.samp_top_ps)
@@ -1228,12 +1381,16 @@ class LLMEngine:
         # Fetch per group, in dispatch order: group g's fetch returns while
         # g+1 still runs on device (async dispatch), so TTFT is per-group.
         for chunk, toks_dev in dispatched:
+            ph.to("prefill_fetch")
             group_toks = np.asarray(jax.device_get(toks_dev)).tolist()
             now = time.perf_counter()
+            now_mono = time.monotonic()
+            ph.to("emit")
             for (i, req_id, tokens, _b, _mt, arrived), tok in zip(chunk, group_toks):
                 slot = self.slots[i]
                 tok = int(tok)
                 slot.first_token_at = now
+                slot.life["first_token"] = now_mono
                 slot.emitted.append(tok)
                 events[req_id] = {
                     "token": tok,
@@ -1246,8 +1403,10 @@ class LLMEngine:
         # the block so the next admission wave starts sooner. Slots mid
         # chunked-prefill ride along masked (writes to dead page 0, tokens
         # discarded) but do not drive the block's budget arithmetic.
+        ph.to("decode_dispatch")
         active = [i for i, s in enumerate(self.slots)
                   if s is not None and i not in self._prefilling]
+        ph.rec["active"] = len(active)
         toks = None
         n = 0
         if active:
@@ -1302,10 +1461,14 @@ class LLMEngine:
                             ev["ttft_s"] = ev.get("ttft_s") or (
                                 (slot.first_token_at or slot.arrived_at) - slot.arrived_at
                             )
+                            self._close_life(slot.life, len(slot.emitted), "length")
                             self._retire(i)
                             retired = True
         if toks is not None:
+            ph.rec["block"] = n
+            ph.to("decode_fetch")
             block_toks = np.asarray(jax.device_get(toks))  # [n, B]
+            ph.to("emit")
             for step_i in range(n):
                 for i in active:
                     slot = self.slots[i]
@@ -1319,11 +1482,13 @@ class LLMEngine:
                         # Prefix-cache hits skip prefill; their first token
                         # comes out of the decode block.
                         slot.first_token_at = time.perf_counter()
+                        slot.life["first_token"] = time.monotonic()
                         ev["ttft_s"] = slot.first_token_at - slot.arrived_at
                     ev["token"] = tok
                     ev.setdefault("new_tokens", []).append(tok)
                     retired |= self._maybe_finish(i, events)
         if retired:
+            ph.to("retire_sync")
             # Re-sync device mirrors so retired slots stop advancing their
             # (now meaningless) lengths toward max_seq, and their writes land
             # in the dead page. Mid-prefill slots stay masked.
@@ -1358,6 +1523,7 @@ class LLMEngine:
             ev["finish_reason"] = "stop" if stopped else "length"
             ev["tokens"] = list(slot.emitted)
             ev["ttft_s"] = ev.get("ttft_s") or (slot.first_token_at - slot.arrived_at)
+            self._close_life(slot.life, len(slot.emitted), ev["finish_reason"])
             self._retire(i)
         return bool(done)
 

@@ -14,7 +14,11 @@ requests):
               queue on the caller side)
     wire      dispatch -> executor picks it up (rpc transit + the worker's
               inbox)
-    exec      user code on the replica (the serve.replica.<dep> span)
+    exec      user code on the replica (the serve.replica.<dep> span); an
+              LLM replica lays the request's phases inside its engine onto
+              the trace (llm.queue, llm.prefill, llm.first_emit, llm.decode:
+              llm/engine.py REQUEST_SPANS), and the hop then carries them
+              as ``parts``, with ``other`` for what of the hop they leave
     drain     reply/stream drain back through the proxy after exec ended
 
 plus ``unattributed`` = total - sum(hops): the residue the decomposition
@@ -29,6 +33,8 @@ from __future__ import annotations
 from typing import Optional
 
 HOPS = ("proxy", "admission", "dispatch", "wire", "exec", "drain")
+# Spans that split the exec hop, in the order a request passes them.
+EXEC_PARTS = ("llm.queue", "llm.prefill", "llm.first_emit", "llm.decode")
 
 
 def _first(events, **match) -> Optional[dict]:
@@ -98,6 +104,12 @@ def autopsy(events: list[dict]) -> dict:
             hop("wire", w_from, exec_start["ts"] - w_from)
     if replica is not None:
         hop("exec", replica["ts"], replica.get("dur", 0.0))
+        parts = [{"part": s["name"].split(".", 1)[1], "dur_s": max(0.0, s.get("dur", 0.0))}
+                 for name in EXEC_PARTS for s in spans if s.get("name") == name]
+        if parts:
+            named = sum(p["dur_s"] for p in parts)
+            parts.append({"part": "other", "dur_s": max(0.0, hops[-1]["dur_s"] - named)})
+            hops[-1]["parts"] = parts
         exec_end = replica["ts"] + replica.get("dur", 0.0)
         hop("drain", exec_end, t_end - exec_end)
     attributed = sum(h["dur_s"] for h in hops)
@@ -141,6 +153,9 @@ def aggregate(autopsies: list[dict]) -> dict:
             rec = agg["hops"].setdefault(h["hop"], {"total_s": 0.0, "max_s": 0.0})
             rec["total_s"] += h["dur_s"]
             rec["max_s"] = max(rec["max_s"], h["dur_s"])
+            for p in h.get("parts", ()):
+                by_part = rec.setdefault("parts", {})
+                by_part[p["part"]] = by_part.get(p["part"], 0.0) + p["dur_s"]
     for agg in by_dep.values():
         denom = agg["total_s"] or 1.0
         for rec in agg["hops"].values():

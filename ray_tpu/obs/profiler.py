@@ -650,24 +650,24 @@ def _require_device_jax(what: str):
 def device_capture(logdir: str):
     """Capture a JAX device trace (XPlane; TensorBoard/Perfetto) around a
     block of device work, as a bounded profiler session — the single entry
-    point `tracing.profile_tpu` routes through."""
+    point `tracing.profile_tpu` routes through. The host's Python tracer is
+    off (it slows the very loop a capture is there to look at) and the host
+    tracer on, so the capture holds the device's operations and the
+    program's own annotations (`llm.step` and its phases, on the thread
+    that steps the engine), not every Python call."""
     jax = _require_device_jax("device_capture")
     sid = _sampler.session_begin("device", note=logdir)
     try:
-        jax.profiler.start_trace(logdir)
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        options.host_tracer_level = 2
+        jax.profiler.start_trace(logdir, profiler_options=options)
         try:
             yield
         finally:
             jax.profiler.stop_trace()
     finally:
         _sampler.session_end(sid)
-
-
-def device_server(port: int = 9012):
-    """Start the JAX profiler server for remote capture (TensorBoard
-    'capture profile'); typed-and-loud without a device backend."""
-    jax = _require_device_jax("device_server")
-    return jax.profiler.start_server(port)
 
 
 def device_memory_records(ts: Optional[float] = None) -> list[dict]:

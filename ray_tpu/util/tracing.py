@@ -33,6 +33,7 @@ import contextvars
 import json
 import os
 import time
+from collections import deque
 from typing import Optional
 
 # The active trace context of this thread/task: (trace_id, span_id) or None.
@@ -192,19 +193,8 @@ def event(name: str, **attrs):
     chunk retries, queue admissions, anything worth a timeline tick without
     its own span. Free no-op when no trace is active."""
     ctx = _ctx.get()
-    if ctx is None:
-        return
-    ev = {
-        "name": name,
-        "trace_id": ctx[0],
-        "span_id": new_span_id(),
-        "parent_id": ctx[1],
-        "ts": now(),
-        "dur": 0.0,
-    }
-    if attrs:
-        ev["attrs"] = attrs
-    _record_event(ev)
+    if ctx is not None:
+        record_span(ctx, name, now(), 0.0, **attrs)
 
 
 def child_span(name: str, **attrs):
@@ -214,6 +204,121 @@ def child_span(name: str, **attrs):
     if _ctx.get() is None:
         return contextlib.nullcontext()
     return Span(name, attrs or None)
+
+
+def record_span(ctx: tuple, name: str, ts: float, dur: float, **attrs):
+    """Record a finished span on the trace ``ctx`` = (trace_id, parent span
+    id) from a thread in which that trace is not the active one: a loop
+    thread that serves many requests (the LLM engine's) keeps each request's
+    captured context and lays the request's phases onto it when they are
+    over. ``ts`` is on the ``now()`` clock."""
+    ev = {
+        "name": name,
+        "trace_id": ctx[0],
+        "span_id": new_span_id(),
+        "parent_id": ctx[1],
+        "ts": ts,
+        "dur": max(0.0, dur),
+    }
+    if attrs:
+        ev["attrs"] = attrs
+    _record_event(ev)
+
+
+class Ring:
+    """The last ``size`` records of something that happens in ONE thread,
+    readable from any other without a lock: the writer pays a deque append
+    and two counts. ``total`` counts every push and ``dropped`` every record
+    that fell off the far end."""
+
+    __slots__ = ("_records", "size", "total", "dropped")
+
+    def __init__(self, size: int):
+        self._records: deque = deque(maxlen=size)
+        self.size = size
+        self.total = 0
+        self.dropped = 0
+
+    def push(self, rec) -> None:
+        if len(self._records) == self.size:
+            self.dropped += 1
+        self._records.append(rec)
+        self.total += 1
+
+    def snapshot(self) -> list:
+        while True:
+            try:
+                return list(self._records)
+            except RuntimeError:  # the writer appended during the copy
+                continue
+
+
+class PhaseSpans:
+    """Spans at the seams of one iteration of a host loop (``LLMEngine.step``
+    is the user): ``begin`` opens the iteration in its first phase, ``to``
+    closes the running phase and opens the next, ``end`` closes the iteration.
+    Phases follow one another and never nest, so an iteration's phase seconds
+    add up to its own. Each phase interval
+
+    - adds its seconds to the iteration's record (``rec``: start stamp ``t``
+      on ``time.monotonic()``, ``dur``, ``phase_s`` by phase, and whatever
+      counts the caller writes into it), which ``end`` pushes to ``ring``,
+      and to the cumulative ``phase_s`` / ``phase_n``;
+    - is an annotation ``<name>.<phase>`` inside one ``<name>`` around the
+      iteration, so that in a device capture the phases lie on the trace's
+      own clock beside the device's operations.
+
+    ``annotation`` is ``jax.profiler.TraceAnnotation``, handed in by a caller
+    that has jax already: this module is imported by drivers and proxies
+    that must never import it. Without one the phases only time. One thread
+    drives an instance; ``ring`` and the cumulative dicts may be read from
+    any. No lock, nothing allocated for each phase but its annotation."""
+
+    def __init__(self, name: str, phases: tuple, ring_size: int, annotation=None):
+        self.name = name
+        self._ann = annotation or contextlib.nullcontext  # called with a name, like the class
+        self._full = {p: f"{name}.{p}" for p in phases}
+        self.ring = Ring(ring_size)
+        self.phase_s = dict.fromkeys(phases, 0.0)
+        self.phase_n = dict.fromkeys(phases, 0)
+        self.rec: Optional[dict] = None
+        self._phase = ""
+        self._t = 0.0
+        self._whole = self._open = None
+
+    def begin(self, phase: str, **fields) -> None:
+        t = time.monotonic()
+        self.rec = {"t": t, "dur": 0.0, "phase_s": {}, **fields}
+        self._whole = self._ann(self.name)
+        self._whole.__enter__()
+        self._enter(phase, t)
+
+    def _enter(self, phase: str, t: float) -> None:
+        self._phase, self._t = phase, t
+        self.phase_n[phase] += 1
+        self._open = self._ann(self._full[phase])
+        self._open.__enter__()
+
+    def _leave(self, t: float) -> None:
+        self._open.__exit__(None, None, None)
+        by_phase = self.rec["phase_s"]
+        by_phase[self._phase] = by_phase.get(self._phase, 0.0) + (t - self._t)
+
+    def to(self, phase: str) -> None:
+        t = time.monotonic()
+        self._leave(t)
+        self._enter(phase, t)
+
+    def end(self) -> None:
+        t = time.monotonic()
+        self._leave(t)
+        self._whole.__exit__(None, None, None)
+        rec, self.rec = self.rec, None
+        rec["dur"] = t - rec["t"]
+        total = self.phase_s
+        for phase, secs in rec["phase_s"].items():
+            total[phase] += secs
+        self.ring.push(rec)
 
 
 def get_task_events(limit: int = 20000) -> list[dict]:
@@ -391,13 +496,3 @@ def profile_tpu(logdir: str):
 
     with _profiler.device_capture(logdir):
         yield
-
-
-def profile_server(port: int = 9012):
-    """Start the JAX profiler server for on-demand remote capture
-    (TensorBoard 'capture profile' against this port). Same typed-and-loud
-    backend gate as profile_tpu (obs.profiler.DeviceProfilerUnavailable on
-    hosts with no TPU/GPU backend)."""
-    from ray_tpu.obs import profiler as _profiler
-
-    return _profiler.device_server(port)

@@ -231,3 +231,238 @@ def test_dense_and_paged_layouts_agree():
     assert a[0] == b[0], outs
     agree = next((i for i in range(10) if a[i] != b[i]), 10)
     assert agree >= 6, f"layouts diverged at step {agree}: {outs}"
+
+
+# ---------------------------------------------------------------------------
+# the program's own record: request lifecycle, step phases, rings (PR 24)
+# ---------------------------------------------------------------------------
+
+MODEL_KW = dict(
+    vocab_size=97, d_model=64, n_layers=2, n_heads=4, n_kv_heads=2, d_ff=128,
+    max_seq_len=128, dtype=jnp.float32, attention_impl="reference",
+)
+ENGINE_KW = dict(max_slots=4, max_seq=128, prefill_buckets=(16, 32), kv_layout="paged",
+                 page_size=16, prefix_cache=True)
+ENGINE_STAMPS = ("arrived", "admitted", "first_token", "finished")
+
+
+@pytest.fixture(scope="module")
+def llm_server():
+    """An LLMServer in this process (no cluster: it is a plain class), its
+    engine's step slowed by 20 ms so that a stream's consumer is awake again
+    before the next step ends."""
+    from ray_tpu.llm.deployment import LLMServer
+
+    srv = LLMServer(MODEL_KW, ENGINE_KW, warmup_buckets=(16,))
+    step = srv.engine.step
+
+    def slow_step():
+        events = step()
+        time.sleep(0.02)
+        return events
+
+    srv.engine.step = slow_step
+    yield srv
+    srv.__raytpu_exit__()
+
+
+def test_perf_counter_and_monotonic_read_one_clock():
+    """_Slot.arrived_at is a perf_counter reading and the lifecycle record is
+    on monotonic; the benchmark's wrapper subtracts one from the other's
+    twin. On this platform they are the same clock: asserted, not assumed."""
+    assert (time.get_clock_info("perf_counter").implementation
+            == time.get_clock_info("monotonic").implementation)
+    for _ in range(5):
+        a, b, c = time.monotonic(), time.perf_counter(), time.monotonic()
+        assert a <= b <= c
+
+
+def test_request_lifecycle_stamps_are_ordered(llm_server):
+    prompts = [[3, 1, 4, 1, 5], [2, 7, 1, 8, 2, 8], [9, 9, 8]]
+    outs = [None] * len(prompts)
+
+    def consume(i):
+        outs[i] = list(llm_server.generate_stream(prompts[i], max_tokens=24))
+
+    before = llm_server.stats()["trace"]["requests_total"]
+    threads = [threading.Thread(target=consume, args=(i,)) for i in range(len(prompts))]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(120)
+    trace = llm_server.stats()["trace"]
+    assert trace["clock"] == "monotonic" and trace["requests_total"] == before + 3
+    recs = trace["requests"][-3:]
+    assert sorted(r["prompt_len"] for r in recs) == [3, 5, 6]
+    order = ("arrived", "admitted", "first_token", "first_emitted", "first_yielded", "finished")
+    for r in recs:
+        stamps = [r[k] for k in order]
+        assert all(isinstance(s, float) for s in stamps), r
+        assert stamps == sorted(stamps), r
+        assert r["n_out"] == 24 and r["finish_reason"] == "length" and r["trace"] is None
+        assert r["slot"] in range(4) and r["bucket"] == 16 and r["prefix_hit_len"] == 0
+    assert all(o[-1]["finished"] and len(o[-1]["tokens"]) == 24 for o in outs)
+    assert not llm_server._life, "a finished request's record stayed with the loop"
+
+
+def test_one_step_request_keeps_each_threads_order(llm_server):
+    """A request that finishes in the step of its first token retires (the
+    engine's stamp, inside the step) before the loop has emitted that token:
+    the engine's stamps are ordered among themselves, the loop's follow the
+    token. A blocking generate() has no stream to yield from."""
+    llm_server.generate([5, 4, 3, 2], max_tokens=1)
+    r = llm_server.stats()["trace"]["requests"][-1]
+    engine_side = [r[k] for k in ENGINE_STAMPS]
+    assert engine_side == sorted(engine_side)
+    assert r["first_token"] <= r["first_emitted"] and r["first_yielded"] is None
+    assert r["n_out"] == 1
+
+
+def test_exact_prefix_hit_and_abort_are_recorded(llm_server):
+    prompt = list(range(1, 33))  # two whole pages: cached at retire
+    llm_server.generate(prompt, max_tokens=4)
+    llm_server.generate(prompt, max_tokens=4)
+    hit = llm_server.stats()["trace"]["requests"][-1]
+    assert hit["prefix_hit_len"] == 32 and hit["bucket"] is None
+    assert hit["arrived"] <= hit["admitted"] <= hit["first_token"] <= hit["finished"]
+    gen = llm_server.generate_stream([7, 7, 7], max_tokens=100)
+    next(gen)
+    gen.close()  # the consumer left: the loop aborts the request in the engine
+    deadline = time.time() + 30
+    while time.time() < deadline:
+        last = llm_server.stats()["trace"]["requests"][-1]
+        if last["finish_reason"] == "abort":
+            break
+        time.sleep(0.05)
+    assert last["finish_reason"] == "abort" and last["prompt_len"] == 3 and last["finished"]
+    assert not llm_server._life
+
+
+def test_stats_is_cheap_when_idle_and_says_where_startup_went():
+    from ray_tpu.llm.deployment import LLMServer
+
+    srv = LLMServer(MODEL_KW, ENGINE_KW, warmup_buckets=(16,))
+    try:
+        took = []
+        for _ in range(20):
+            t0 = time.perf_counter()
+            stats = srv.stats()
+            took.append(time.perf_counter() - t0)
+        assert min(took) < 1e-3, took
+        trace, startup = stats["trace"], stats["startup"]
+        assert trace["requests"] == [] and trace["steps"] == [] and trace["steps_total"] == 0
+        assert trace["dropped"] == {"requests": 0, "steps": 0}
+        # warm-up compiled here, in this process: the program's counter saw it
+        assert trace["compiles_total"] >= 1 and len(trace["compiles"]) >= 1
+        assert {(p["bucket"], p["k"]) for p in startup["programs"] if p["program"] == "prefill"} \
+            == {(16, k) for k in (8, 4, 2, 1)}
+        assert {p["block"] for p in startup["programs"] if p["program"] == "decode"} == {2, 8}
+        assert all(p["seconds"] > 0 for p in startup["programs"])
+        assert startup["warmup_s"] >= sum(p["seconds"] for p in startup["programs"]) * 0.99
+        assert startup["engine_init_s"] > 0 and startup["fetch_params_s"] >= 0
+    finally:
+        srv.__raytpu_exit__()
+
+
+def _drain(eng):
+    done = {}
+    while eng.has_work():
+        for rid, ev in eng.step().items():
+            if ev.get("finished"):
+                done[rid] = ev["tokens"]
+    return done
+
+
+def test_step_phases_sum_to_no_more_than_the_step():
+    from ray_tpu.llm import engine as engine_mod
+
+    eng = LLMEngine(CFG, engine_config=EngineConfig(**ENGINE_KW))
+    for i in range(6):
+        eng.add_request(f"r{i}", np.arange(3 + 5 * i, dtype=np.int32) % 97, 12)
+    _drain(eng)
+    snap = eng.trace_snapshot()
+    steps = snap["steps"]
+    assert len(steps) == snap["steps_total"] > 1 and snap["dropped"]["steps"] == 0
+    for s in steps:
+        assert set(s["phase_s"]) <= set(engine_mod.STEP_PHASES)
+        assert all(v >= 0 for v in s["phase_s"].values())
+        assert sum(s["phase_s"].values()) <= s["dur"] + 1e-9
+        assert sum(s["phase_s"].values()) >= 0.9 * s["dur"]  # phases follow one another: no hole
+    assert [a["t"] for a in steps] == sorted(a["t"] for a in steps)
+    assert sum(s["n_admitted"] for s in steps) == 6
+    assert sum(s["n_prefill"] for s in steps) >= 2  # 6 requests in 4 slots: at least two waves
+    assert steps[0]["waiting"] == 6 and steps[0]["active"] == 4 and steps[0]["block"] in (2, 8)
+    for phase in engine_mod.STEP_PHASES:
+        assert snap["phase_s"][phase] == pytest.approx(
+            sum(s["phase_s"].get(phase, 0.0) for s in steps))
+    assert snap["phase_n"]["decode_fetch"] == sum(1 for s in steps if s["block"])
+    assert snap["phase_n"]["prefix_lookup"] == 6
+
+
+def test_trace_rings_are_bounded_and_count_drops(monkeypatch):
+    from ray_tpu.llm import engine as engine_mod
+
+    monkeypatch.setattr(engine_mod, "TRACE_RING", 4)
+    eng = LLMEngine(CFG, engine_config=EngineConfig(max_slots=2, max_seq=128, prefill_buckets=(16,)))
+    for i in range(7):
+        eng.add_request(f"r{i}", np.array([1 + i, 2, 3], np.int32), 10)
+    _drain(eng)
+    snap = eng.trace_snapshot()
+    assert len(snap["requests"]) == 4 and snap["requests_total"] == 7
+    assert [r["req_id"] for r in snap["requests"]] == ["r3", "r4", "r5", "r6"]
+    assert len(snap["steps"]) == 4 and snap["steps_total"] > 4
+    assert snap["dropped"] == {"requests": 3, "steps": snap["steps_total"] - 4}
+
+
+def test_greedy_tokens_unchanged_with_and_without_a_trace_context(engine):
+    """The instrumentation changes no token: with a trace active around
+    add_request and without one, the engine's greedy output is the full
+    forward's (what the engine gave before it recorded anything)."""
+    from ray_tpu.util import tracing
+
+    prompt = np.array([5, 17, 42, 7, 23], np.int32)
+    want = _naive_greedy(engine.params, prompt, 12)
+    engine.add_request("plain", prompt, 12)
+    with tracing.span("test.llm"):
+        traced_life = engine.add_request("traced", prompt, 12)
+    assert traced_life["trace"] is not None and traced_life["trace"][0]
+    done = _drain(engine)
+    assert done["plain"] == want and done["traced"] == want
+    recs = {r["req_id"]: r for r in engine.trace_snapshot()["requests"]}
+    assert recs["plain"]["trace"] is None and recs["traced"] is traced_life
+
+
+def test_a_capture_shows_the_steps_phases_inside_llm_step(engine, tmp_path):
+    """The phases are TraceAnnotations: a profiler capture with the host
+    tracer on holds one `llm.step` per step and its phases inside it, one
+    after another, on the capture's own clock."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    from ray_tpu.llm.engine import STEP_PHASES
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        engine.generate(np.array([1, 2, 3, 4], np.int32), max_tokens=12)
+    finally:
+        jax.profiler.stop_trace()
+    [path] = glob.glob(str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb"))
+    lines = [[(e.name, e.start_ns, e.start_ns + e.duration_ns) for e in line.events
+              if e.name.startswith("llm.step")]
+             for plane in ProfileData.from_file(path).planes for line in plane.lines]
+    [events] = [evs for evs in lines if evs]  # one thread stepped the engine
+    whole = [e for e in events if e[0] == "llm.step"]
+    phases = sorted((e for e in events if e[0] != "llm.step"), key=lambda e: e[1])
+    assert len(whole) >= 2 and phases
+    assert {name for name, _s, _e in phases} <= {f"llm.step.{p}" for p in STEP_PHASES}
+    assert {"llm.step.admit", "llm.step.prefill_dispatch", "llm.step.prefill_fetch",
+            "llm.step.decode_dispatch", "llm.step.decode_fetch", "llm.step.emit"} \
+        <= {name for name, _s, _e in phases}
+    for name, start, end in phases:
+        assert any(s <= start and end <= e for _n, s, e in whole), name
+    for (_n, _s, end), (_m, start, _e) in zip(phases, phases[1:]):
+        assert end <= start  # never nested, never overlapping

@@ -486,6 +486,74 @@ def test_autopsy_aggregate_shares():
     assert p["unattributed_s"] == pytest.approx(0.15)
 
 
+def _llm_spans(t0=100.0):
+    """What an LLM replica lays inside its replica span (100.40 .. 100.90)."""
+    def llm(name, start, dur):
+        return {"ts": t0 + start, "kind": "span", "name": name, "dur": dur, "trace_id": "tr",
+                "span_id": name, "parent_id": "rep", "worker": "replica"}
+
+    return [llm("llm.queue", 0.41, 0.10), llm("llm.prefill", 0.51, 0.04),
+            llm("llm.first_emit", 0.55, 0.20), llm("llm.decode", 0.75, 0.13)]
+
+
+def test_autopsy_splits_exec_by_the_llm_spans():
+    plain = obs_autopsy.autopsy(_synthetic_trace())
+    assert all("parts" not in h for h in plain["hops"])
+    a = obs_autopsy.autopsy(_synthetic_trace() + _llm_spans())
+    hops = {h["hop"]: h for h in a["hops"]}
+    # The hop decomposition itself is what it was: the split is inside exec.
+    assert {k: v["dur_s"] for k, v in hops.items()} == \
+        {h["hop"]: h["dur_s"] for h in plain["hops"]}
+    assert a["attributed_s"] == pytest.approx(plain["attributed_s"])
+    parts = hops["exec"]["parts"]
+    assert [p["part"] for p in parts] == ["queue", "prefill", "first_emit", "decode", "other"]
+    assert [p["dur_s"] for p in parts] == pytest.approx([0.10, 0.04, 0.20, 0.13, 0.03])
+    assert sum(p["dur_s"] for p in parts) == pytest.approx(hops["exec"]["dur_s"])
+    assert all("parts" not in h for name, h in hops.items() if name != "exec")
+    # A partial trace (llm.decode not shipped yet) splits by what is there.
+    some = obs_autopsy.autopsy(_synthetic_trace() + _llm_spans()[:2])
+    parts = next(h for h in some["hops"] if h["hop"] == "exec")["parts"]
+    assert [p["part"] for p in parts] == ["queue", "prefill", "other"]
+    assert parts[-1]["dur_s"] == pytest.approx(0.36)
+    agg = obs_autopsy.aggregate([a, a, plain])["Pinger"]
+    assert agg["hops"]["exec"]["total_s"] == pytest.approx(1.5)
+    assert agg["hops"]["exec"]["parts"] == pytest.approx(
+        {"queue": 0.20, "prefill": 0.08, "first_emit": 0.40, "decode": 0.26, "other": 0.06})
+
+
+def test_compile_counter_counts_fresh_jits_only():
+    """accel/device counts this process's backend compilations from the moment
+    the compile cache is enabled: one more for a fresh jax.jit, none for a
+    call that finds its program compiled; the metrics plane's jax.compiles
+    counts with it."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.accel import device
+    from ray_tpu.util import metrics
+
+    device.enable_compile_cache()
+    device.enable_compile_cache()  # registers its listener once
+
+    def plane_count():
+        return sum(r["value"] for r in metrics.snapshot() if r["name"] == "jax.compiles")
+
+    x = jnp.arange(8.0)
+    y = x + 1.0  # an eager operation compiles too: before the baseline
+    fresh = jax.jit(lambda v: v * 3.0 + 1.0)
+    c0, m0 = device.compile_events()["count"], plane_count()
+    t0 = time.monotonic()
+    fresh(x).block_until_ready()
+    c1 = device.compile_events()
+    assert c1["count"] == c0 + 1 and plane_count() == m0 + 1
+    stamp, seconds = c1["recent"][-1]
+    assert t0 <= stamp <= time.monotonic() and 0 < seconds < 60
+    fresh(x).block_until_ready()
+    fresh(y).block_until_ready()  # same shape and type: the program is there
+    assert device.compile_events()["count"] == c0 + 1
+    assert len(c1["recent"]) <= 512
+
+
 # ---------------------------------------------------------------------------
 # loop-lag probe: injected stall -> spike event with thread dump
 # ---------------------------------------------------------------------------

@@ -209,3 +209,164 @@ def test_trace_overhead_guard_no_context_cost(serve_cluster):
     assert not [e for e in new
                 if "trace_id" in e
                 or e["kind"] in ("span", "task_submitted", "task_exec_start")]
+
+
+# ---------------------------------------------------------------------------
+# phase spans, rings, and a request's phases inside the LLM engine (PR 24)
+# ---------------------------------------------------------------------------
+
+def test_tracing_module_never_imports_jax():
+    """Drivers and proxies import util/tracing and must never import jax:
+    the phase primitive takes its annotation class from the caller."""
+    import subprocess
+    import sys
+
+    code = ("import sys; from ray_tpu.util import tracing; from ray_tpu.accel import device; "
+            "tracing.PhaseSpans('x', ('a',), 4); sys.exit(1 if 'jax' in sys.modules else 0)")
+    assert subprocess.run([sys.executable, "-c", code], timeout=120).returncode == 0
+
+
+def test_phase_spans_time_count_and_annotate():
+    seen = []
+
+    class Note:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            seen.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            seen.append(("exit", self.name))
+
+    ph = tracing.PhaseSpans("loop", ("a", "b", "c"), 3, annotation=Note)
+    for i in range(5):
+        ph.begin("a", iteration=i)
+        time.sleep(0.002)
+        ph.to("b")
+        ph.rec["n"] = 7
+        ph.to("a")
+        time.sleep(0.001)
+        ph.end()
+    assert ph.rec is None
+    # One annotation around the iteration, the phases inside it, one after
+    # another and never nested.
+    assert seen[:8] == [("enter", "loop"), ("enter", "loop.a"), ("exit", "loop.a"),
+                        ("enter", "loop.b"), ("exit", "loop.b"), ("enter", "loop.a"),
+                        ("exit", "loop.a"), ("exit", "loop")]
+    assert len(seen) == 5 * 8
+    assert ph.phase_n == {"a": 10, "b": 5, "c": 0}
+    recs = ph.ring.snapshot()
+    assert [r["iteration"] for r in recs] == [2, 3, 4] and ph.ring.dropped == 2
+    for r in recs:
+        assert set(r["phase_s"]) == {"a", "b"} and r["n"] == 7
+        assert r["phase_s"]["a"] >= 0.003 and sum(r["phase_s"].values()) <= r["dur"]
+        assert r["t"] <= time.monotonic()
+    assert ph.phase_s["a"] >= 5 * 0.003 and ph.phase_s["c"] == 0.0
+    with pytest.raises(KeyError):
+        ph.begin("never-declared")
+    # Without an annotation class the phases still time.
+    bare = tracing.PhaseSpans("bare", ("x",), 2)
+    bare.begin("x")
+    bare.end()
+    assert bare.ring.total == 1 and bare.phase_n == {"x": 1}
+
+
+def test_ring_is_read_without_a_lock_while_its_thread_writes():
+    import threading
+
+    ring = tracing.Ring(64)
+    stop = threading.Event()
+
+    def write():
+        i = 0
+        while not stop.is_set():
+            ring.push(i)
+            i += 1
+
+    t = threading.Thread(target=write)
+    t.start()
+    try:
+        deadline = time.time() + 1.0
+        while time.time() < deadline:
+            snap = ring.snapshot()
+            assert len(snap) <= 64 and snap == sorted(snap)
+    finally:
+        stop.set()
+        t.join(10)
+    assert ring.total > 64 and ring.dropped == ring.total - 64
+    assert ring.snapshot() == list(range(ring.total - 64, ring.total))
+
+
+def test_traced_llm_request_lays_its_phases_on_its_trace(serve_cluster):
+    """A request sent with a trace context (`x-trace: 1`) reaches the engine's
+    thread, where its contextvar is not set: the context rides the request's
+    lifecycle record, the loop lays llm.queue / llm.prefill / llm.first_emit /
+    llm.decode onto the trace as children of the replica's span, and
+    autopsy's exec hop splits by them."""
+    from ray_tpu.llm import build_llm_app
+    from ray_tpu.obs import autopsy as obs_autopsy
+
+    app = build_llm_app(
+        model_config=dict(vocab_size=97, d_model=64, n_layers=2, n_heads=4, n_kv_heads=2,
+                          d_ff=128, max_seq_len=128, attention_impl="reference"),
+        engine_config={"max_slots": 4, "max_seq": 128, "prefill_buckets": (16, 32)},
+    )
+    handle = serve.run(app, name="llm_traced", route_prefix="/llm_traced", timeout_s=300)
+    port = serve.http_port()
+    body = json.dumps({"tokens": [3, 1, 4, 1, 5], "max_tokens": 20, "stream": True}).encode()
+
+    def post(headers):
+        req = urllib.request.Request(f"http://127.0.0.1:{port}/llm_traced", data=body,
+                                     headers=headers, method="POST")
+        with urllib.request.urlopen(req, timeout=120) as resp:
+            assert resp.status == 200
+            frames = [json.loads(f[6:]) for f in resp.read().decode().split("\n\n")
+                      if f.startswith("data: ") and f != "data: [DONE]"]
+        return [t for f in frames for t in f["new_tokens"]]
+
+    plain = post({})
+    traced = post({"x-trace": "1"})
+    assert len(plain) == 20 and traced == plain  # a trace context changes no token
+
+    names = ("llm.queue", "llm.prefill", "llm.first_emit", "llm.decode")
+    from ray_tpu.core import api
+
+    core = api._require_worker()
+    deadline = time.time() + 90
+    events = []
+    while time.time() < deadline:
+        core._run(core._flush_task_events())
+        for tr in core._run(core.controller.call("list_traces", {"q": "serve.request"})):
+            evs = core._run(core.controller.call("get_trace", {"trace_id": tr["trace_id"]}))
+            if {e.get("name") for e in evs} >= set(names) | {"serve.replica.llm"}:
+                events = evs
+        if events:
+            break
+        time.sleep(0.5)
+    assert events, "the traced request's llm.* spans never reached the trace index"
+    by_name = {e["name"]: e for e in events if e.get("kind") == "span"}
+    replica = by_name["serve.replica.llm"]
+    for name in names:
+        span = by_name[name]
+        assert span["trace_id"] == replica["trace_id"] and span["parent_id"] == replica["span_id"]
+        assert span["dur"] >= 0 and span["attrs"]["slot"] in range(4)
+        # on the spans' clock, inside the replica's span (a few ms of slack for
+        # the one conversion from the engine's monotonic stamps)
+        assert replica["ts"] - 0.05 <= span["ts"] <= replica["ts"] + replica["dur"] + 0.05
+    starts = [by_name[n]["ts"] for n in names]
+    assert starts == sorted(starts)
+    # Only the traced request left spans: the untraced one paid a ContextVar.get.
+    assert sum(e.get("name") == "llm.decode" for e in events) == 1
+    stats = handle.stats.remote().result(timeout=30)
+    lives = [r for r in stats["trace"]["requests"] if r["prompt_len"] == 5]
+    assert [r["trace"] is not None for r in lives] == [False, True]
+    assert lives[1]["trace"][0] == replica["trace_id"]
+
+    a = obs_autopsy.autopsy(events)
+    exec_hop = next(h for h in a["hops"] if h["hop"] == "exec")
+    parts = {p["part"]: p["dur_s"] for p in exec_hop["parts"]}
+    assert list(parts) == ["queue", "prefill", "first_emit", "decode", "other"]
+    assert sum(parts.values()) == pytest.approx(exec_hop["dur_s"], abs=1e-6)
+    assert parts["decode"] > 0
+    serve.delete("llm_traced")
