@@ -215,14 +215,18 @@ def phase_kernels(sz: dict, rehearsal: bool) -> dict:
             check(f"flash {name}{label}", g, w)
 
     # paged decode attention: ragged lengths, a one-token and a full
-    # sequence, and a partial last page
+    # sequence, and a partial last page; the last of three layers' pools,
+    # told to the kernel as the engine's layer loop tells it (a traced
+    # index), and the token's own K/V written into the pools by the call
     pg = sz["paged"]
     B, H, KV, D, ps, ppseq = (pg[k] for k in ("B", "H", "KV", "D", "page", "pages_per_seq"))
-    n_pages = B * ppseq + 1
-    kq, kk, kv_ = jax.random.split(jax.random.PRNGKey(1), 3)
+    n_pages, n_layers = B * ppseq + 1, 3
+    kq, kk, kv_, kn, vn = jax.random.split(jax.random.PRNGKey(1), 5)
     q = jax.random.normal(kq, (B, H, D), jnp.bfloat16)
-    kp = jax.random.normal(kk, (KV, n_pages, ps, D), jnp.bfloat16)
-    vp = jax.random.normal(kv_, (KV, n_pages, ps, D), jnp.bfloat16)
+    kp = jax.random.normal(kk, (n_layers, KV, n_pages, ps, D), jnp.bfloat16)
+    vp = jax.random.normal(kv_, (n_layers, KV, n_pages, ps, D), jnp.bfloat16)
+    k_new = jax.random.normal(kn, (B, KV, D), jnp.bfloat16)
+    v_new = jax.random.normal(vn, (B, KV, D), jnp.bfloat16)
     rng = np.random.default_rng(0)
     lens = rng.integers(1, ppseq * ps + 1, B).astype(np.int32)
     lens[:3] = (1, ppseq * ps, (ppseq // 2) * ps + ps // 3)
@@ -231,13 +235,16 @@ def phase_kernels(sz: dict, rehearsal: bool) -> dict:
     for b in range(B):
         used = -(-int(lens[b]) // ps)
         table[b, :used] = free[b * ppseq: b * ppseq + used]
-    lens, table = jnp.asarray(lens), jnp.asarray(table)
+    lens, table, layer = jnp.asarray(lens), jnp.asarray(table), jnp.int32(n_layers - 1)
 
-    def paged(q, kp, vp, lens, table):
-        return paged_attention(q, kp, vp, lens, table, interpret=interpret)
+    def paged(q, k_new, v_new, kp, vp, lens, table, layer):
+        return paged_attention(q, k_new, v_new, kp, vp, lens, table, layer, interpret=interpret)
 
-    check("paged decode", timed_compile(paged, q, kp, vp, lens, table)(q, kp, vp, lens, table),
-          jax.jit(paged_attention_reference)(*f32(q, kp, vp), lens, table))
+    args = (q, k_new, v_new, kp, vp, lens, table, layer)
+    got = timed_compile(paged, *args)(*args)
+    want = jax.jit(paged_attention_reference)(*f32(q, k_new, v_new, kp, vp), lens, table, layer)
+    for name, g, w in zip(("paged decode", "paged decode: K pool", "paged decode: V pool"), got, want):
+        check(name, g, w)
 
     # One dispatch's round trip: a trivial program, dispatched and awaited.
     bump = jax.jit(lambda x: x + 1)

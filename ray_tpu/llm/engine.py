@@ -9,10 +9,12 @@ llm/_internal/serve/engines/vllm/vllm_engine.py:174):
   slots per iteration — XLA sees a handful of programs total, not a shape
   per batch composition.
 - Paged KV (vLLM's core idea, re-expressed for XLA): each sequence owns a
-  page list; prefill scatters K/V into its pages, decode scatters one token
-  at (page[len // ps], len % ps) and attends through the page table with the
-  Pallas paged-attention kernel (ops/paged_attention.py — scalar-prefetch
-  page-table walk, no materialized gather). Memory scales with reserved
+  page list; prefill scatters K/V into its pages; decode carries the pool
+  whole through its loops, and the Pallas paged-attention kernel
+  (ops/paged_attention.py — scalar-prefetch page-table walk at a layer
+  index, no materialized gather, no slice of the pool) writes one token's
+  row in place at (layer, page[len // ps], len % ps) and attends through
+  the page table. Memory scales with reserved
   pages, not slots × max_seq; admission is page-budgeted, so many more slots
   than a dense cache can be configured.
 - Continuous batching is the host loop: between device programs, finished
@@ -462,13 +464,20 @@ class LLMEngine:
 
             def _copy_pages_impl(kp, vp, src, dst):
                 # UNROLLED slice-all-then-update-all (n_pg is small and
-                # static). Formulations that loop (fori_loop carry) or
-                # gather/scatter the page axis made XLA copy the whole
-                # multi-hundred-MB pool per page (~450-570ms measured on
-                # v5e); unrolled, the program ran at ~24ms, then the floor
-                # of any program touching the donated pools. Whether donation
-                # is in place on the current stack is unverified in code —
-                # PERF.md "Bring-up" has the measurement.
+                # static). On an earlier stack, formulations that looped
+                # (fori_loop carry) or gathered/scattered the page axis made
+                # XLA copy the whole pool per page (~450-570ms on v5e);
+                # unrolled, the program ran at ~24ms. On the current stack
+                # (jax 0.9.0; PERF.md section 6, PR 25) a loop-carried,
+                # donated pool IS updated in place: the decode block carried
+                # both pools through two nested scans with 64
+                # dynamic_update_slice a layer (0.74 us each on the chip, no
+                # copy of a pool in the program, both pools aliased to its
+                # outputs) before its write moved into the kernel. What
+                # still copies is a pool passed through a scan as xs/ys, and
+                # a scatter (.at[].set) on a layer's slice. This form was
+                # not re-measured against a looped one; it is a handful of
+                # pages either way.
                 ks = [jax.lax.dynamic_slice(kp, (0, 0, src[i] * ps_, 0), n_pg_axes)
                       for i in range(n_pg)]
                 vs = [jax.lax.dynamic_slice(vp, (0, 0, src[i] * ps_, 0), n_pg_axes)
@@ -586,11 +595,25 @@ class LLMEngine:
     def _decode_impl(self, params, k_pages, v_pages, last_tokens, lengths, page_tables, n_steps, key, temps, top_ps, top_ks):
         """n_steps tokens for every slot in ONE device program (outer scan
         over steps, inner scan over layers): one host round trip per block.
-        Returns (k_pages, v_pages, toks [n_steps, B], last', lengths')."""
+        Returns (k_pages, v_pages, toks [n_steps, B], last', lengths').
+
+        How the pools are threaded: both scans CARRY the two pools whole (as
+        [L, KV, pages, ps, Hd], a free reshape); the layer scan's ``xs`` are
+        the layer's weights and its index. The one Mosaic call of a layer is
+        told the layer by an operand, reads the pages where they lie, and
+        writes each slot's new K/V row itself, into the pool its outputs
+        alias: no operation of this program but that call has a pool, or a
+        layer's slice of one, for operand or result. Passed as ``xs`` and
+        taken back as ``ys`` instead, the pools cost the serve cells 70% of
+        this program (PERF.md section 6, PR 25): XLA took a 100 MB slice out
+        and stacked it back every layer, relaid it twice around the slice's
+        scatter, and copied both 2.4 GB pools once a step for the ``ys``
+        (53-57 ms a step and 2.33 pools of temporaries, against 11-14 ms and
+        an eighth of a pool now)."""
         cfg = self.cfg
         ps = self.ec.page_size
-        B = page_tables.shape[0]
-        rows = jnp.arange(B)
+        flat = k_pages.shape  # [L, KV, total_pages * ps, Hd], as every other program has it
+        pool = (cfg.n_layers, cfg.kv_heads, -1, ps, cfg.head_dim)
         # The kernel where it can run; elsewhere the einsum reference, which
         # GSPMD partitions as-is under TP.
         attend = (
@@ -602,11 +625,10 @@ class LLMEngine:
             kp, vp, last, lens = carry
             with jax.named_scope("embed"):
                 x = params["embed"].astype(cfg.dtype)[last][:, None, :]  # [B,1,D]
-            # Linear write position per slot: its page for len, plus offset.
-            lin = page_tables[rows, lens // ps] * ps + lens % ps  # [B]
 
-            def scan_fn(h, xs):
-                lp, ck_l, cv_l = xs
+            def scan_fn(carry, xs):
+                h, kp, vp = carry
+                lp, layer = xs
                 dt = h.dtype
                 with jax.named_scope("qkv"):
                     hh = _rms_norm(h, lp["attn_norm"])
@@ -614,24 +636,22 @@ class LLMEngine:
                     pos = lens[:, None]
                     q = _rope(q, pos, cfg.rope_theta)
                     k_new = _rope(k_new, pos, cfg.rope_theta)
-                with jax.named_scope("kv_write"):
-                    # [B,1,KV,Hd] -> [KV,B,Hd]; scatter at lin per slot.
-                    ck_l = ck_l.at[:, lin].set(k_new[:, 0].transpose(1, 0, 2).astype(ck_l.dtype))
-                    cv_l = cv_l.at[:, lin].set(v_new[:, 0].transpose(1, 0, 2).astype(cv_l.dtype))
                 with jax.named_scope("paged_attn"):
-                    pool = (cfg.kv_heads, -1, ps, cfg.head_dim)
-                    o = attend(
-                        q[:, 0], ck_l.reshape(pool), cv_l.reshape(pool),
-                        lens + 1, page_tables,
-                    )  # [B, H, Hd]
+                    # writes k_new / v_new at position lens of each slot's
+                    # pages (page_tables[b, lens // ps], offset lens % ps)
+                    o, kp, vp = attend(
+                        q[:, 0], k_new[:, 0], v_new[:, 0], kp, vp,
+                        lens + 1, page_tables, layer,
+                    )  # o: [B, H, Hd]
                 with jax.named_scope("attn_out"):
                     h = h + jnp.einsum("bhk,hkd->bd", o, lp["wo"].astype(dt))[:, None, :]
                 with jax.named_scope("ffn"):
                     hh = _rms_norm(h, lp["ffn_norm"])
                     h = h + _dense_ffn(hh, lp)
-                return h, (ck_l, cv_l)
+                return (h, kp, vp), None
 
-            x, (kp, vp) = jax.lax.scan(scan_fn, x, (params["layers"], kp, vp))
+            layers = jnp.arange(cfg.n_layers, dtype=jnp.int32)
+            (x, kp, vp), _ = jax.lax.scan(scan_fn, (x, kp, vp), (params["layers"], layers))
             with jax.named_scope("lm_head"):
                 x = _rms_norm(x, params["final_norm"])
                 logits = jnp.einsum("bsd,dv->bv", x, params["lm_head"].astype(cfg.dtype))
@@ -642,9 +662,9 @@ class LLMEngine:
 
         keys = jax.random.split(key, n_steps)
         (k_pages, v_pages, last, lengths), toks = jax.lax.scan(
-            one_step, (k_pages, v_pages, last_tokens, lengths), keys
+            one_step, (k_pages.reshape(pool), v_pages.reshape(pool), last_tokens, lengths), keys
         )
-        return k_pages, v_pages, toks, last, lengths
+        return k_pages.reshape(flat), v_pages.reshape(flat), toks, last, lengths
 
     def _prefill_batch_impl(self, params, k_pages, v_pages, tokens, lengths, third, key, temps, top_ps, top_ks):
         """Prefill k requests of one length bucket in ONE device program
@@ -830,7 +850,10 @@ class LLMEngine:
         Also records, once, in ``self.mosaic`` whether the first prefill
         program and the full decode block, compiled, hold a Mosaic custom
         call: how a caller tells that the Pallas kernels run and not the jnp
-        references. Off the TPU they cannot, and nothing extra is compiled."""
+        references (off the TPU they cannot). Each decode entry of
+        ``warmup_log`` carries ``temp_bytes``, what the compiled program
+        holds on the device beside its arguments, on any backend: a decode
+        program that moved the KV pools would need room for them there."""
         if buckets is None:
             buckets = self.buckets
         else:
@@ -846,10 +869,13 @@ class LLMEngine:
         key = jax.random.PRNGKey(0)
         on_tpu = jax.default_backend() == "tpu"
 
-        def holds_mosaic(jitted, args) -> bool:
+        def compiled_ahead(jitted, args):
             # Ahead of the jitted call, so that call finds this compile in
             # the persistent cache instead of compiling a second time.
-            return on_tpu and "tpu_custom_call" in jitted.lower(*args).compile().as_text()
+            return jitted.lower(*args).compile()
+
+        def holds_mosaic(compiled) -> bool:
+            return "tpu_custom_call" in compiled.as_text()
 
         log = self.warmup_log
         for b in buckets:
@@ -867,7 +893,8 @@ class LLMEngine:
                     jnp.zeros(k, jnp.int32),
                 )
                 if "prefill" not in self.mosaic:
-                    self.mosaic["prefill"] = holds_mosaic(self._prefill(b, k), args)
+                    self.mosaic["prefill"] = on_tpu and holds_mosaic(
+                        compiled_ahead(self._prefill(b, k), args))
                 self.k_pages, self.v_pages, td = self._prefill(b, k)(*args)
                 # The admit path's per-group mirror updates are their own tiny
                 # jitted programs, one shape variant per k — compile them here
@@ -883,12 +910,17 @@ class LLMEngine:
             head = (self.params, self.k_pages, self.v_pages, self.d_last, self.d_lengths)
             tail = (n, key, self.d_temps, self.d_top_ps, self.d_top_ks)
             args = head + ((self.d_page_tables,) if self.paged else ()) + tail
+            # What the program holds beside its arguments: a decode program
+            # that moved the pools would need room for them here.
+            compiled = compiled_ahead(self._decode_jit, args)
+            temp_bytes = compiled.memory_analysis().temp_size_in_bytes
             if n == self.block_sizes[-1]:
-                self.mosaic["decode"] = holds_mosaic(self._decode_jit, args)
+                self.mosaic["decode"] = on_tpu and holds_mosaic(compiled)
             out = self._decode_jit(*args)
             self.k_pages, self.v_pages = out[0], out[1]
             jax.device_get(out[2])
-            log.append({"program": "decode", "block": n, "seconds": time.monotonic() - t0})
+            log.append({"program": "decode", "block": n, "temp_bytes": temp_bytes,
+                        "seconds": time.monotonic() - t0})
         if self.paged and self.ec.prefix_cache:
             # Compile the prefix-cache page copy (padded rows hit page 0).
             t0 = time.monotonic()

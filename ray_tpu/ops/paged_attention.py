@@ -1,16 +1,25 @@
 """Paged decode attention for TPU: single-token GQA queries against a
-block-paged KV cache.
+block-paged KV cache, with the token's own K/V written into the cache by the
+same call.
 
-The serving engine's KV cache is a pool of fixed-size pages ([KV, P_total,
-page_size, D]); each sequence owns a page list (its page table row). Decode
-attention must therefore gather a sequence's keys from non-contiguous pages.
-An XLA gather would materialize the whole per-sequence KV every step (HBM
-copy of the entire working set per token); the Pallas kernel instead walks
-the page table through scalar prefetch — the BlockSpec index map reads the
-NEXT page index while the current page is in flight, so pages stream through
-VMEM exactly once with no materialized gather.
+The serving engine's KV cache is a pool of fixed-size pages, every layer's
+in one array ([L, KV, P_total, page_size, D]); each sequence owns a page list
+(its page table row). Decode attention must therefore gather a sequence's
+keys from non-contiguous pages. An XLA gather would materialize the whole
+per-sequence KV every step (HBM copy of the entire working set per token);
+the Pallas kernel instead walks the page table through scalar prefetch — the
+BlockSpec index map reads the NEXT page index while the current page is in
+flight, so pages stream through VMEM exactly once with no materialized gather.
 
-Kernel shape: grid (B, KV, pages_per_seq), online-softmax accumulator in VMEM
+The pool never moves. A caller that slices its layer out first moves that
+layer's whole pool every call (100 MB a layer in the serve cells), and one
+that writes the token's K/V beside the kernel pays an operation a slot
+(PERF.md section 6, PR 25). So the layer is an operand that the index maps
+add to each page's address, and the pools are aliased to the call's outputs:
+the token's row is spliced into its page where the page lies in VMEM for
+attention anyway, and the few rows around it are stored back.
+
+Kernel shape: grid (B, pages_per_seq), online-softmax accumulator in VMEM
 scratch across the page axis (innermost, "arbitrary"), pages past a
 sequence's length predicated off entirely (their DMAs still target a valid
 page — dead table entries point at page 0 — but compute is skipped).
@@ -34,37 +43,62 @@ NEG_INF = -1e30
 # Reference implementation (numerical oracle + non-TPU backends)
 # ---------------------------------------------------------------------------
 
-def paged_attention_reference(q, k_pages, v_pages, lengths, page_indices, scale=None):
-    """q: [B, H, D]; k_pages/v_pages: [KV, P_total, ps, D]; lengths: [B]
-    (valid token count per sequence, INCLUDING the current position);
-    page_indices: [B, pages_per_seq] -> [B, H, D]."""
+def paged_attention_reference(q, k_new, v_new, k_pages, v_pages, lengths, page_indices,
+                              layer, scale=None):
+    """q: [B, H, D]; k_new/v_new: [B, KV, D], the current token's;
+    k_pages/v_pages: [L, KV, P_total, ps, D]; lengths: [B] (valid token count
+    per sequence, INCLUDING the current position); page_indices:
+    [B, pages_per_seq]; layer: scalar index into L
+    -> (o [B, H, D], k_pages, v_pages) with the token written at position
+    lengths - 1 of each sequence."""
     B, H, D = q.shape
-    KV, _, ps, _ = k_pages.shape
+    _, KV, _, ps, _ = k_pages.shape
     group = H // KV
     ppseq = page_indices.shape[1]
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
+    pos = lengths - 1
+    page = page_indices[jnp.arange(B), jnp.minimum(pos // ps, ppseq - 1)]
+    for b in range(B):  # a row a sequence, in place in a donated or loop-carried pool
+        at = (layer, 0, page[b], pos[b] % ps, 0)
+        k_pages = jax.lax.dynamic_update_slice(
+            k_pages, k_new[b].astype(k_pages.dtype)[None, :, None, None, :], at)
+        v_pages = jax.lax.dynamic_update_slice(
+            v_pages, v_new[b].astype(v_pages.dtype)[None, :, None, None, :], at)
+    k_layer = jax.lax.dynamic_index_in_dim(k_pages, layer, 0, keepdims=False)
+    v_layer = jax.lax.dynamic_index_in_dim(v_pages, layer, 0, keepdims=False)
     # [KV, B, ppseq, ps, D] -> [B, KV, S_virt, D]
-    k = k_pages[:, page_indices].transpose(1, 0, 2, 3, 4).reshape(B, KV, ppseq * ps, D)
-    v = v_pages[:, page_indices].transpose(1, 0, 2, 3, 4).reshape(B, KV, ppseq * ps, D)
+    k = k_layer[:, page_indices].transpose(1, 0, 2, 3, 4).reshape(B, KV, ppseq * ps, D)
+    v = v_layer[:, page_indices].transpose(1, 0, 2, 3, 4).reshape(B, KV, ppseq * ps, D)
     qg = q.reshape(B, KV, group, D)
     s = jnp.einsum("bkgd,bksd->bkgs", qg, k).astype(jnp.float32) * scale
     valid = (jnp.arange(ppseq * ps)[None, :] < lengths[:, None])[:, None, None, :]
     s = jnp.where(valid, s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
     o = jnp.einsum("bkgs,bksd->bkgd", p, v)
-    return o.reshape(B, H, D)
+    return o.reshape(B, H, D), k_pages, v_pages
 
 
 # ---------------------------------------------------------------------------
 # Pallas kernel
 # ---------------------------------------------------------------------------
 
-def _paged_kernel(lens_ref, pidx_ref, q_ref, k_ref, v_ref, o_ref,
-                  m_scr, l_scr, acc_scr, *, scale, ps, n_pages, kv):
+def _newest_page(length, ps, n_pages):
+    """Which of a sequence's pages holds its current position (the last of
+    `length`); a sequence run past its table stays inside its last page."""
+    return jnp.minimum((length - 1) // ps, n_pages - 1)
+
+
+def _paged_kernel(lens_ref, pidx_ref, layer_ref, q_ref, kn_ref, vn_ref, k_ref, v_ref,
+                  o_ref, ko_ref, vo_ref, m_scr, l_scr, acc_scr, *, scale, ps, n_pages, kv):
     """Grid (B, n_pages): ONE page DMA carries ALL kv heads (page ids are
     shared across heads in the pool layout), and the head loop unrolls
     statically inside the step — 4-8x fewer, larger DMAs than a per-head
-    grid, which is what the decode path's throughput is bound by."""
+    grid, which is what the decode path's throughput is bound by.
+
+    ``ko_ref`` / ``vo_ref`` are a window of rows of the sequence's newest
+    page in the pools the inputs alias: stored once a sequence, with the
+    current token's row (``kn_ref`` / ``vn_ref``, f32) spliced in.
+    ``layer_ref`` is read by the index maps alone."""
     from jax.experimental import pallas as pl
 
     b = pl.program_id(0)
@@ -78,6 +112,24 @@ def _paged_kernel(lens_ref, pidx_ref, q_ref, k_ref, v_ref, o_ref,
 
     length = lens_ref[b]
     start = j * ps
+
+    @pl.when(j == _newest_page(length, ps, n_pages))
+    def _write_the_token():
+        # The token's row goes into its window of `win` rows twice: into the
+        # page as it lies in VMEM, where the attention below reads it, and
+        # into the output block, which is all that is stored back. Spliced in
+        # f32 (bf16 -> f32 -> bf16 is exact): a 32-bit select needs no
+        # packed-row mask, and the row comes as a plain f32 sublane.
+        win = ko_ref.shape[2]
+        row = (length - 1) % ps
+        first = pl.multiple_of(row // win * win, win)
+        here = jax.lax.broadcasted_iota(jnp.int32, ko_ref.shape[2:], 0) == row - first
+        for page_ref, new_ref, out_ref in ((k_ref, kn_ref, ko_ref), (v_ref, vn_ref, vo_ref)):
+            for h in range(kv):
+                window = page_ref[h, 0, pl.ds(first, win), :].astype(jnp.float32)
+                window = jnp.where(here, new_ref[0, pl.ds(h, 1), :], window).astype(out_ref.dtype)
+                page_ref[h, 0, pl.ds(first, win), :] = window
+                out_ref[h, 0] = window
 
     @pl.when(start < length)
     def _compute():
@@ -109,24 +161,52 @@ def _paged_kernel(lens_ref, pidx_ref, q_ref, k_ref, v_ref, o_ref,
             o_ref[0, h] = (acc_scr[h] / l_safe[:, None]).astype(o_ref.dtype)
 
 
-def _paged_pallas(q, k_pages, v_pages, lengths, page_indices, *, scale, interpret):
-    """q: [B, KV, Gp, D] (Gp >= 8, sublane-padded); -> o [B, KV, Gp, D]."""
+def _paged_pallas(q, k_new, v_new, k_pages, v_pages, lengths, page_indices, layer,
+                  *, scale, interpret):
+    """q: [B, KV, Gp, D] (Gp >= 8, sublane-padded); k_new/v_new: f32
+    [B, KV, D]; k_pages/v_pages: [L, KV, P_total, ps, D]; layer: int32[1];
+    -> (o [B, KV, Gp, D], k_pages, v_pages), the pools aliased to the inputs."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, KV, Gp, D = q.shape
-    ps = k_pages.shape[2]
+    ps = k_pages.shape[3]
     n_pages = page_indices.shape[1]
 
+    def whole(b, j, lens, pidx, layer):
+        return (b, 0, 0, 0)
+
+    def token(b, j, lens, pidx, layer):
+        return (b, 0, 0)
+
+    def page(b, j, lens, pidx, layer):
+        return (layer[0], 0, pidx[b, j], 0, 0)
+
+    # The stored window: one packed tile of rows (16 of bf16, and a multiple
+    # of f32's 8), so a sequence's write-back is a sliver of its page.
+    win = min(ps, 16)
+
+    def token_window(b, j, lens, pidx, layer):
+        newest = _newest_page(lens[b], ps, n_pages)
+        return (layer[0], 0, pidx[b, newest], (lens[b] - 1) % ps // win, 0)
+
+    one_page = (None, KV, 1, ps, D)  # the layer axis squeezed
+    one_window = (None, KV, 1, win, D)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B, n_pages),
         in_specs=[
-            pl.BlockSpec((1, KV, Gp, D), lambda b, j, lens, pidx: (b, 0, 0, 0)),
-            pl.BlockSpec((KV, 1, ps, D), lambda b, j, lens, pidx: (0, pidx[b, j], 0, 0)),
-            pl.BlockSpec((KV, 1, ps, D), lambda b, j, lens, pidx: (0, pidx[b, j], 0, 0)),
+            pl.BlockSpec((1, KV, Gp, D), whole),
+            pl.BlockSpec((1, KV, D), token),
+            pl.BlockSpec((1, KV, D), token),
+            pl.BlockSpec(one_page, page),
+            pl.BlockSpec(one_page, page),
         ],
-        out_specs=pl.BlockSpec((1, KV, Gp, D), lambda b, j, lens, pidx: (b, 0, 0, 0)),
+        out_specs=[
+            pl.BlockSpec((1, KV, Gp, D), whole),
+            pl.BlockSpec(one_window, token_window),
+            pl.BlockSpec(one_window, token_window),
+        ],
         scratch_shapes=[
             pltpu.VMEM((KV, Gp, 128), jnp.float32),
             pltpu.VMEM((KV, Gp, 128), jnp.float32),
@@ -139,51 +219,63 @@ def _paged_pallas(q, k_pages, v_pages, lengths, page_indices, *, scale, interpre
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, KV, Gp, D), q.dtype),
+        out_shape=[
+            jax.ShapeDtypeStruct((B, KV, Gp, D), q.dtype),
+            jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype),
+            jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype),
+        ],
+        # operands count the three scalar-prefetch arrays: 6, 7 are the pools
+        input_output_aliases={6: 1, 7: 2},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(lengths, page_indices, q, k_pages, v_pages)
+    )(lengths, page_indices, layer, q, k_new, v_new, k_pages, v_pages)
 
 
-def paged_attention(q, k_pages, v_pages, lengths, page_indices, scale=None,
-                    interpret=False, mesh=None, head_axis="tensor"):
+def paged_attention(q, k_new, v_new, k_pages, v_pages, lengths, page_indices, layer,
+                    scale=None, interpret=False, mesh=None, head_axis="tensor"):
     """Paged decode attention. q: [B, H, D] (one query token per sequence);
-    k_pages/v_pages: [KV, P_total, page_size, D]; lengths: [B] valid tokens
-    per sequence including the current one; page_indices: [B, pages_per_seq]
-    (entries past a sequence's length must still be valid page ids — use 0).
+    k_new/v_new: [B, KV, D], that token's K and V; k_pages/v_pages:
+    [L, KV, P_total, page_size, D], every layer's pool; lengths: [B] valid
+    tokens per sequence including the current one; page_indices:
+    [B, pages_per_seq] (entries past a sequence's length must still be valid
+    page ids — use 0); layer: which of the L pools to attend (an int or a
+    traced int32 scalar: the engine's layer loop passes its counter, so one
+    compiled call serves every layer).
+
+    Returns (o [B, H, D], k_pages, v_pages): the token's K/V lies at position
+    lengths - 1 of each sequence's pages in the returned pools, which alias
+    the arguments — in place wherever the caller donates the pools or carries
+    them through a loop, and nothing but the written pages moves.
 
     This is the Pallas kernel: it runs on a TPU backend, or anywhere with
     interpret=True, and raises elsewhere — a caller that may land on another
     backend chooses ``paged_attention_reference`` from what it observes.
 
     mesh: tensor-parallel serving (llm/engine.py) — the head axes (H of q, KV
-    of the page pools) are sharded over ``mesh[head_axis]`` and the kernel is
-    shard_map'd: each device attends its own head shard against its own KV
-    pool shard (embarrassingly parallel — GQA groups never straddle shards
-    because callers validate KV % degree == 0). Without the explicit map jax
-    refuses to lower the call: GSPMD cannot partition a Mosaic kernel.
+    of the token's rows and of the page pools) are sharded over
+    ``mesh[head_axis]`` and the kernel is shard_map'd: each device attends its
+    own head shard against its own KV pool shard (embarrassingly parallel —
+    GQA groups never straddle shards because callers validate KV % degree ==
+    0). Without the explicit map jax refuses to lower the call: GSPMD cannot
+    partition a Mosaic kernel.
     """
     if mesh is not None and mesh.shape.get(head_axis, 1) > 1:
         from jax.sharding import PartitionSpec as P
 
         inner = functools.partial(paged_attention, scale=scale, interpret=interpret)
+        heads, pool = P(None, head_axis, None), P(None, head_axis, None, None, None)
         return jax.shard_map(
             inner,
             mesh=mesh,
-            in_specs=(
-                P(None, head_axis, None),
-                P(head_axis, None, None, None),
-                P(head_axis, None, None, None),
-                P(None),
-                P(None, None),
-            ),
-            out_specs=P(None, head_axis, None),
+            in_specs=(heads, heads, heads, pool, pool, P(None), P(None, None), P()),
+            out_specs=(heads, pool, pool),
             check_vma=False,
-        )(q, k_pages, v_pages, lengths, page_indices)
+        )(q, k_new, v_new, k_pages, v_pages, lengths, page_indices,
+          jnp.asarray(layer, jnp.int32))
     B, H, D = q.shape
-    KV = k_pages.shape[0]
+    KV = k_pages.shape[1]
     if H % KV:
         raise ValueError(f"n_heads {H} not divisible by kv_heads {KV}")
     group = H // KV
@@ -200,8 +292,13 @@ def paged_attention(q, k_pages, v_pages, lengths, page_indices, scale=None,
     qg = q.reshape(B, KV, group, D)
     if Gp != group:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, Gp - group), (0, 0)))
-    o = _paged_pallas(
-        qg, k_pages, v_pages, lengths.astype(jnp.int32),
-        page_indices.astype(jnp.int32), scale=scale, interpret=interpret,
+
+    def as_rows(new, pages):  # rounded as the pool stores it, handed over in f32
+        return new.astype(pages.dtype).astype(jnp.float32)
+
+    o, k_pages, v_pages = _paged_pallas(
+        qg, as_rows(k_new, k_pages), as_rows(v_new, v_pages), k_pages, v_pages,
+        lengths.astype(jnp.int32), page_indices.astype(jnp.int32),
+        jnp.asarray(layer, jnp.int32).reshape(1), scale=scale, interpret=interpret,
     )
-    return o[:, :, :group].reshape(B, H, D)
+    return o[:, :, :group].reshape(B, H, D), k_pages, v_pages

@@ -109,9 +109,10 @@ def test_explicit_kernels_raise_without_tpu():
     cfg = TransformerConfig(d_model=128, n_heads=2, attention_impl="flash")
     with pytest.raises(RuntimeError, match="needs a TPU backend"):
         _attention(q, q, q, cfg)
-    pages = jnp.zeros((2, 3, 32, 64))
+    pages = jnp.zeros((1, 2, 3, 32, 64))
     with pytest.raises(RuntimeError, match="needs a TPU backend"):
-        paged_attention(q[:, 0], pages, pages, jnp.ones(1, jnp.int32), jnp.zeros((1, 2), jnp.int32))
+        paged_attention(q[:, 0], q[:, 0], q[:, 0], pages, pages, jnp.ones(1, jnp.int32),
+                        jnp.zeros((1, 2), jnp.int32), 0)
 
 
 def test_flash_runs_on_its_batch_shard_under_a_mesh(monkeypatch):

@@ -211,6 +211,27 @@ def test_paged_decode_matches_across_pool_layouts():
     assert outs[0] == outs[1]
 
 
+def test_paged_decode_program_does_not_move_the_pool():
+    """The decode block carries both KV pools through its layer loop and
+    writes the new rows in place, so what it holds beside its arguments is a
+    sliver of one pool. Passing the pools through the layer scan as xs / ys
+    made the compiler keep copies of them: more than two pools of
+    temporaries, on this backend as on the chip (PERF.md section 6, PR 25)."""
+    cfg = TransformerConfig(
+        vocab_size=97, d_model=64, n_layers=4, n_heads=4, n_kv_heads=2, d_ff=128,
+        max_seq_len=128, dtype=jnp.float32, attention_impl="reference",
+    )
+    eng = LLMEngine(cfg, engine_config=EngineConfig(
+        max_slots=2, max_seq=64, kv_layout="paged", page_size=16, total_pages=256,
+        prefill_buckets=(16,), decode_block=4,
+    ))
+    eng.warmup(buckets=(16,), k_values=(1,))
+    decode = [p for p in eng.warmup_log if p["program"] == "decode"]
+    assert {p["block"] for p in decode} == {1, 4}
+    for p in decode:
+        assert 0 <= p["temp_bytes"] < eng.k_pages.nbytes // 2, (p, eng.k_pages.nbytes)
+
+
 def test_dense_and_paged_layouts_agree():
     """Same request through both KV layouts: greedy tokens agree (the layout
     is a memory/performance knob, not a numerics change). The two attention
@@ -356,7 +377,10 @@ def test_stats_is_cheap_when_idle_and_says_where_startup_went():
         assert trace["compiles_total"] >= 1 and len(trace["compiles"]) >= 1
         assert {(p["bucket"], p["k"]) for p in startup["programs"] if p["program"] == "prefill"} \
             == {(16, k) for k in (8, 4, 2, 1)}
-        assert {p["block"] for p in startup["programs"] if p["program"] == "decode"} == {2, 8}
+        decode = [p for p in startup["programs"] if p["program"] == "decode"]
+        assert {p["block"] for p in decode} == {2, 8}
+        # what each decode block holds on the device beside its arguments
+        assert all(isinstance(p["temp_bytes"], int) and p["temp_bytes"] >= 0 for p in decode), decode
         assert all(p["seconds"] > 0 for p in startup["programs"])
         assert startup["warmup_s"] >= sum(p["seconds"] for p in startup["programs"]) * 0.99
         assert startup["engine_init_s"] > 0 and startup["fetch_params_s"] >= 0

@@ -1,5 +1,7 @@
 """Paged decode attention: reference vs contiguous oracle, Pallas kernel
-(interpret mode) vs reference — GQA, ragged lengths, partial pages."""
+(interpret mode) vs reference — GQA, ragged lengths, partial pages, the
+layer index into a pool that holds every layer, and the current token's K/V
+written into that pool by the call."""
 import math
 
 import jax
@@ -13,18 +15,26 @@ from ray_tpu.ops.paged_attention import (
 )
 
 
-def _make_case(B, H, KV, D, ps, ppseq, lengths, seed=0):
-    """Random paged cache where sequence b owns pages [b*ppseq .. ) shuffled,
-    plus a contiguous copy for the oracle."""
+L = 3  # layers in the pool, each with contents of its own
+LAYERS = (0, L - 1)
+
+
+def _make_case(B, H, KV, D, ps, ppseq, lengths, layer, seed=0):
+    """Random paged cache of L layers where sequence b owns pages
+    [b*ppseq .. ) shuffled, plus a contiguous copy of `layer` for the
+    oracle. The call under test gets the pools as they are BEFORE the current
+    token (its row in `layer` NaN) with the row beside them, and must return
+    the attention over, and the pools with, the row in place."""
     rng = np.random.default_rng(seed)
     P_total = B * ppseq + 1  # page 0 reserved as the dead-entry target
     q = rng.normal(size=(B, H, D)).astype(np.float32)
-    k_pages = rng.normal(size=(KV, P_total, ps, D)).astype(np.float32)
-    v_pages = rng.normal(size=(KV, P_total, ps, D)).astype(np.float32)
+    k_pages = rng.normal(size=(L, KV, P_total, ps, D)).astype(np.float32)
+    v_pages = rng.normal(size=(L, KV, P_total, ps, D)).astype(np.float32)
     page_indices = np.zeros((B, ppseq), np.int32)
     for b in range(B):
         n_used = math.ceil(lengths[b] / ps)
-        perm = rng.permutation(np.arange(1, P_total))[:n_used]
+        # its own pages: the call writes into a sequence's newest page
+        perm = rng.permutation(np.arange(1 + b * ppseq, 1 + (b + 1) * ppseq))[:n_used]
         page_indices[b, :n_used] = perm
     # Contiguous K/V per sequence for the oracle.
     k_full = np.zeros((B, KV, ppseq * ps, D), np.float32)
@@ -32,11 +42,34 @@ def _make_case(B, H, KV, D, ps, ppseq, lengths, seed=0):
     for b in range(B):
         for j in range(ppseq):
             pg = page_indices[b, j]
-            k_full[b, :, j * ps:(j + 1) * ps] = k_pages[:, pg]
-            v_full[b, :, j * ps:(j + 1) * ps] = v_pages[:, pg]
-    return (jnp.asarray(q), jnp.asarray(k_pages), jnp.asarray(v_pages),
-            jnp.asarray(np.asarray(lengths, np.int32)), jnp.asarray(page_indices),
-            jnp.asarray(k_full), jnp.asarray(v_full))
+            k_full[b, :, j * ps:(j + 1) * ps] = k_pages[layer, :, pg]
+            v_full[b, :, j * ps:(j + 1) * ps] = v_pages[layer, :, pg]
+    k_before, v_before = k_pages.copy(), v_pages.copy()
+    k_new = np.zeros((B, KV, D), np.float32)
+    v_new = np.zeros((B, KV, D), np.float32)
+    for b in range(B):
+        pg, off = page_indices[b, (lengths[b] - 1) // ps], (lengths[b] - 1) % ps
+        k_new[b], v_new[b] = k_pages[layer, :, pg, off], v_pages[layer, :, pg, off]
+        k_before[layer, :, pg, off] = v_before[layer, :, pg, off] = np.nan
+    return dict(
+        call=(jnp.asarray(q), jnp.asarray(k_new), jnp.asarray(v_new), jnp.asarray(k_before),
+              jnp.asarray(v_before), jnp.asarray(np.asarray(lengths, np.int32)),
+              jnp.asarray(page_indices)),
+        pools=(k_pages, v_pages), oracle=(jnp.asarray(k_full), jnp.asarray(v_full)),
+    )
+
+
+def _assert_same(got, want, tol):
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=tol, atol=tol)
+
+
+def _kernel_against_reference(case, layer):
+    want = paged_attention_reference(*case["call"], layer)
+    _assert_same(want[1:], case["pools"], 0)  # the reference wrote the rows, and only them
+    got = paged_attention(*case["call"], layer, interpret=True)
+    _assert_same(got[:1], want[:1], 2e-3)
+    _assert_same(got[1:], case["pools"], 0)
 
 
 def _oracle(q, k_full, v_full, lengths):
@@ -52,45 +85,61 @@ def _oracle(q, k_full, v_full, lengths):
     return jnp.einsum("bkgs,bksd->bkgd", p, v_full).reshape(B, H, D)
 
 
+@pytest.mark.parametrize("layer", LAYERS)
 @pytest.mark.parametrize("H,KV", [(8, 8), (8, 2), (16, 4)])
-def test_reference_matches_oracle(H, KV):
+def test_reference_matches_oracle(H, KV, layer):
     lengths = [1, 17, 64, 33]
-    q, kp, vp, lens, pidx, kf, vf = _make_case(
-        B=4, H=H, KV=KV, D=64, ps=16, ppseq=4, lengths=lengths
-    )
-    got = paged_attention_reference(q, kp, vp, lens, pidx)
-    want = _oracle(q, kf, vf, lens)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5)
+    case = _make_case(B=4, H=H, KV=KV, D=64, ps=16, ppseq=4, lengths=lengths, layer=layer)
+    got, kp, vp = paged_attention_reference(*case["call"], layer)
+    want = _oracle(case["call"][0], *case["oracle"], case["call"][5])
+    _assert_same([got], [want], 2e-5)
+    _assert_same((kp, vp), case["pools"], 0)
 
 
+@pytest.mark.parametrize("layer", LAYERS)
 @pytest.mark.parametrize("H,KV", [(8, 8), (8, 2), (16, 4)])
-def test_kernel_matches_reference(H, KV):
+def test_kernel_matches_reference(H, KV, layer):
     lengths = [5, 16, 61, 128]
-    q, kp, vp, lens, pidx, _, _ = _make_case(
-        B=4, H=H, KV=KV, D=64, ps=32, ppseq=4, lengths=lengths, seed=1
-    )
-    want = paged_attention_reference(q, kp, vp, lens, pidx)
-    got = paged_attention(q, kp, vp, lens, pidx, interpret=True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-3, atol=2e-3)
+    _kernel_against_reference(_make_case(
+        B=4, H=H, KV=KV, D=64, ps=32, ppseq=4, lengths=lengths, layer=layer, seed=1
+    ), layer)
 
 
-def test_kernel_ragged_and_single_page():
+@pytest.mark.parametrize("layer", LAYERS)
+def test_kernel_ragged_and_single_page(layer):
     # Lengths straddling page boundaries, incl. a 1-token sequence; large
     # group (no sublane padding) and page_size 128 lane-width case.
-    q, kp, vp, lens, pidx, _, _ = _make_case(
-        B=3, H=16, KV=2, D=128, ps=128, ppseq=2, lengths=[1, 129, 256], seed=2
-    )
-    want = paged_attention_reference(q, kp, vp, lens, pidx)
-    got = paged_attention(q, kp, vp, lens, pidx, interpret=True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-3, atol=2e-3)
+    _kernel_against_reference(_make_case(
+        B=3, H=16, KV=2, D=128, ps=128, ppseq=2, lengths=[1, 129, 256], layer=layer, seed=2
+    ), layer)
 
 
-def test_dead_table_entries_are_ignored():
+@pytest.mark.parametrize("layer", LAYERS)
+def test_dead_table_entries_are_ignored(layer):
     """Entries past a sequence's length point at page 0 (shared, full of
     data) — they must not contribute."""
-    q, kp, vp, lens, pidx, _, _ = _make_case(
-        B=2, H=4, KV=4, D=64, ps=16, ppseq=8, lengths=[16, 40], seed=3
-    )
-    want = paged_attention_reference(q, kp, vp, lens, pidx)
-    got = paged_attention(q, kp, vp, lens, pidx, interpret=True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-3, atol=2e-3)
+    _kernel_against_reference(_make_case(
+        B=2, H=4, KV=4, D=64, ps=16, ppseq=8, lengths=[16, 40], layer=layer, seed=3
+    ), layer)
+
+
+def test_layer_index_reads_and_writes_that_layer_and_no_other():
+    """A traced layer index, as the engine's layer loop passes it: the call
+    attends that layer's pages against the contiguous oracle and writes the
+    token's row there; every other layer's pool can hold anything (here:
+    NaN) without showing, and comes back as it went in."""
+    layer = 1
+    case = _make_case(B=2, H=8, KV=2, D=64, ps=32, ppseq=2, lengths=[7, 50], layer=layer, seed=4)
+    q, k_new, v_new, kp, vp, lens, pidx = case["call"]
+    others = (jnp.arange(L) != layer)[:, None, None, None, None]
+    kp, vp = jnp.where(others, jnp.nan, kp), jnp.where(others, jnp.nan, vp)
+    want = _oracle(q, *case["oracle"], lens)
+    kernel = jax.jit(lambda l: paged_attention(q, k_new, v_new, kp, vp, lens, pidx, l, interpret=True))
+    reference = jax.jit(lambda l: paged_attention_reference(q, k_new, v_new, kp, vp, lens, pidx, l))
+    for fn in (kernel, reference):
+        o, kp_out, vp_out = fn(jnp.int32(layer))
+        _assert_same([o], [want], 2e-3)
+        for out, full in zip((kp_out, vp_out), case["pools"]):
+            np.testing.assert_array_equal(np.asarray(out[layer]), full[layer])
+            assert np.isnan(np.asarray(out)[np.arange(L) != layer]).all()
+        assert np.isnan(np.asarray(fn(jnp.int32(0))[0])).any()
