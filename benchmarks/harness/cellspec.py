@@ -5,6 +5,7 @@ is the only place that knows where they are."""
 from __future__ import annotations
 
 import copy
+import functools
 import importlib.util
 import json
 import os
@@ -56,30 +57,37 @@ def load_metric(name: str):
     return mod.read
 
 
+@functools.lru_cache(maxsize=None)
+def load_architecture(name: str):
+    """An architecture's module: benchmarks/architectures/<name>.py, with
+    `logits`, `packed_loss`, `transformer_kwargs`, `shrink`, `attention_dims`
+    and `param_counts` (README, "An architecture"). The self-test's fixture
+    reaches its own directory with a relative name."""
+    path = os.path.join(BENCH_DIR, "architectures", name + ".py")
+    if not os.path.exists(path):
+        raise SystemExit(f"benchmark: architecture {name!r} has no file at {path}")
+    mod_spec = importlib.util.spec_from_file_location(
+        "bench_architecture_" + "".join(c if c.isalnum() else "_" for c in name), path)
+    mod = importlib.util.module_from_spec(mod_spec)
+    mod_spec.loader.exec_module(mod)
+    return mod
+
+
+def architecture(config: dict):
+    """The module a configuration file names under "architecture"; a file
+    without the key describes a dense decoder."""
+    return load_architecture(config.get("architecture", "dense"))
+
+
 def transformer_kwargs(config: dict) -> dict:
-    """The published config's keys -> ray_tpu.models.TransformerConfig's.
-    head_dim is derived there as d_model // n_heads, which both published
-    configs satisfy (128); rms_norm_eps has no counterpart (see `assumed`)."""
-    d, h = config["hidden_size"], config["num_attention_heads"]
-    if config.get("head_dim", d // h) != d // h:
-        raise SystemExit("benchmark: TransformerConfig derives head_dim = hidden_size / heads")
-    return dict(
-        vocab_size=config["vocab_size"], d_model=d, n_layers=config["num_hidden_layers"],
-        n_heads=h, n_kv_heads=config["num_key_value_heads"], d_ff=config["intermediate_size"],
-        max_seq_len=config["max_position_embeddings"], rope_theta=float(config["rope_theta"]),
-        attention_impl="auto",
-        # Further TransformerConfig fields the configuration sets (dtypes by name).
-        **(config.get("transformer") or {}),
-    )
+    return architecture(config).transformer_kwargs(config)
 
 
 def shrink_for_rehearsal(spec: dict) -> dict:
     """Toy sizes for a CPU run of the same control flow. Its output is
     counts only; nothing it prints is a measurement."""
     spec = copy.deepcopy(spec)
-    spec["config"].update(hidden_size=128, num_hidden_layers=2, num_attention_heads=4,
-                          num_key_value_heads=2, intermediate_size=256, vocab_size=512,
-                          max_position_embeddings=512)
+    architecture(spec["config"]).shrink(spec["config"])
     eng = spec["config"].get("engine")
     if eng:
         eng.update(max_slots=4, max_seq=256, page_size=32, total_pages=48,

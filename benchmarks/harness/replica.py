@@ -128,8 +128,64 @@ class BenchLLMServer(LLMServer):
             "warmup_s": rep["warmup_s"], "init_s": self._b_init_s, "mosaic": rep["mosaic"],
             "compile_cache_dir": rep["compile_cache_dir"], "pid": os.getpid(),
             "buckets": list(self.engine.buckets), "block_sizes": list(self.engine.block_sizes),
+            "k_buckets": list(self.engine.k_buckets),
             "total_pages": self.engine.ec.total_pages, "max_slots": self.engine.ec.max_slots,
         }
+
+    def bench_warm_groups(self, rounds: list, timeout_s: float = 600.0) -> dict:
+        """Set-up for a sharded replica: admit prefill groups of every size the
+        engine forms (k_buckets), in both states its device mirrors can be in.
+        The engine's own warm-up compiles each size's mirror update against
+        the mirrors as it made them, on one device; under a mesh a decode block
+        returns them laid over it, an update's own result is laid out a third
+        way, and the first update of a size against a layout it has not met
+        compiles: inside the window, if the ramp happened not to bring the two
+        together (PERF.md section 7).
+
+        `rounds` is a list of (state, keeper, prompts). Each round ends when
+        every request of it has retired, which leaves the mirrors as the
+        host rewrote them ("retired"): the next round's prompts, all of one
+        bucket and queued in one go, are admitted in the next step and split
+        into groups by the engine. Under "decoded" a keeper request goes first and the
+        prompts follow once its first token is out: they meet the mirrors as
+        the keeper's decode block left them. These are requests like any
+        other, through the server's own queue. Returns how many compilations
+        each round caused."""
+        eng = self.engine
+        deadline = time.monotonic() + timeout_s
+
+        def wait_for(done):
+            while not done():
+                if time.monotonic() > deadline:
+                    raise TimeoutError("bench_warm_groups: the engine did not finish the warm-up requests")
+                self._cond.wait(timeout=0.05)
+
+        def add(tokens, max_tokens):
+            rid = self._new_rid()
+            self._life[rid] = eng.add_request(rid, tokens, max_tokens)
+            return rid
+
+        compiles, live = [], []
+
+        def drain():
+            wait_for(lambda: all(r in self._done for r in live))
+            for r in live:
+                del self._done[r]
+            live.clear()
+
+        with self._cond:
+            for state, keeper, prompts in rounds:
+                before = self._b["compiles"]
+                if state == "decoded":
+                    live.append(add(keeper, 3 * eng.ec.decode_block))
+                    life = self._life[live[0]]
+                    self._cond.notify_all()
+                    wait_for(lambda: life["first_emitted"] is not None)
+                live += [add(p, 2) for p in prompts]
+                self._cond.notify_all()
+                drain()
+                compiles.append(self._b["compiles"] - before)
+        return {"rounds": len(rounds), "requests": sum(len(p) for _s, _k, p in rounds), "compiles": compiles}
 
     def bench_trace_start(self, start_at: float, duration_s: float, logdir: str) -> bool:
         """Trace the device from start_at (CLOCK_MONOTONIC) for duration_s, in
@@ -157,44 +213,41 @@ class BenchLLMServer(LLMServer):
         return True
 
     def bench_trace_result(self) -> dict:
-        """The reduced trace (parsed here, after the window, where the file is)."""
-        from harness import xplane
-
+        """Where the finished trace lies, and the counters at its two ends. The
+        cell's driver reduces it (harness/serve_cell.py): parsing a trace of
+        four devices holds this process's interpreter for longer than the
+        serve controller waits for a heartbeat, and it kills the replica."""
         box = self._b_trace
         if box is None or not box["done"].wait(timeout=120):
             return {"error": "no finished trace"}
         self._b_annotate = False
-        summary = xplane.reduce_logdir(box["logdir"])
-        summary["counters_before"], summary["counters_after"] = box["counters_before"], box["counters_after"]
-        return summary
+        return {"logdir": box["logdir"], "counters_before": box["counters_before"],
+                "counters_after": box["counters_after"]}
 
     def bench_reference_check(self, prompt: list, served: list, model: dict) -> dict:
         """Were the tokens the served path returned for `prompt` (greedy) the
-        plain float32 reference's choices, up to bf16's rounding? Teacher-
-        forced: the reference's logits at every generated position; bf16's
-        error there is taken from the program's own forward in bf16. A served
-        token may trail the reference's best logit by twice that error."""
+        plain float32 reference's choices, up to bf16's rounding, and is the
+        program's own forward as close to the reference as bf16 allows?
+        Teacher-forced: the reference's logits at every generated position,
+        the program's own forward in bf16 there, and the reference from
+        coarse weights as the yardstick (harness/refcheck.py `judge`)."""
         import dataclasses
 
         import jax
         import jax.numpy as jnp
-        import numpy as np
 
-        from harness import reference
+        from harness import refcheck
+        from harness.cellspec import architecture
         from ray_tpu.models.transformer import forward
 
         eng = self.engine
+        reference = architecture(model)
         P, n = len(prompt), len(served)
         toks = jnp.asarray([list(prompt) + list(served)], jnp.int32)
         with jax.default_matmul_precision("highest"):
-            ref = jax.jit(lambda p, t: reference.logits(p, t, model)[0, P - 1: P - 1 + n])(eng.params, toks)
-        ref = np.asarray(ref, np.float32)
+            plain = jax.jit(lambda p, t: reference.logits(p, t, model)[0, P - 1: P - 1 + n])
+            ref = plain(eng.params, toks)
+            coarse = plain(jax.jit(refcheck.coarse_weights)(eng.params), toks)
         cfg = dataclasses.replace(eng.cfg, attention_impl="reference")
         own = jax.jit(lambda p, t: forward(p, t, cfg)[0][0, P - 1: P - 1 + n])(eng.params, toks)
-        noise = float(np.abs(np.asarray(own, np.float32) - ref).max())
-        chosen = ref[np.arange(n), np.asarray(served)]
-        trail = float((ref.max(-1) - chosen).max())
-        scale = float(np.abs(ref).max())
-        return {"bf16_logit_error": noise, "worst_trail": trail, "logit_scale": scale,
-                "tokens": n, "ok": bool(np.isfinite(ref).all() and trail <= 2 * noise
-                                        and noise <= 0.05 * max(scale, 1.0))}
+        return refcheck.judge(ref, own, coarse, served)

@@ -7,7 +7,7 @@ import os
 import traceback
 
 from harness import flops, schedule, xplane
-from harness.cellspec import BENCH_DIR, load_cell, load_metric
+from harness.cellspec import BENCH_DIR, architecture, load_cell, load_metric
 from harness.stats import percentile, spread
 
 
@@ -87,11 +87,55 @@ def check_flops_against_hand_counts():
     assert which == "memory" and abs(t - need["bytes"] / 819e9) < 1e-18
 
 
+def _param_counts_before_the_seam(model: dict) -> dict:
+    """harness/flops.py's _dims and param_counts as they stood before an
+    architecture became a file (PR 23 to PR 25), kept here word for word so
+    that the dense file's counts are held to them key by key."""
+    d, L = model["hidden_size"], model["num_hidden_layers"]
+    H, KV = model["num_attention_heads"], model["num_key_value_heads"]
+    hd = model.get("head_dim") or d // H
+    F, V = model["intermediate_size"], model["vocab_size"]
+    attn = d * H * hd + 2 * d * KV * hd + H * hd * d
+    ffn = 3 * d * F
+    norms = 2 * d
+    head = 0 if model.get("tie_word_embeddings") else d * V
+    return {"embedding": V * d, "lm_head": head, "per_layer_matmul": attn + ffn,
+            "matmul": L * (attn + ffn) + d * V,  # the head multiplies even when tied
+            "total": V * d + head + L * (attn + ffn + norms) + d}
+
+
+def check_architecture_seam():
+    for name in ("mistral-7b-v0.3-l2.json", "internlm2-1.8b.json", "mistral-7b-v0.3.json"):
+        model = _load("configs", name)
+        assert "architecture" not in model and architecture(model).__name__ == "bench_architecture_dense"
+        now = flops.param_counts(model)
+        assert now == architecture(model).param_counts(model)
+        for key, value in _param_counts_before_the_seam(model).items():
+            assert now[key] == value, (name, key)
+        assert now["resident_matmul"] == now["matmul"]
+    # A second architecture, in which resident and per-token parameters
+    # differ: OLMoE-1B-7B's published row, counted by hand.
+    moe = _load("selftest_data", "routed_experts_olmoe.json")
+    assert architecture(moe).__name__ != architecture(model).__name__
+    pc = flops.param_counts(moe)
+    attn, expert, router = 4 * 2048 * 2048, 3 * 2048 * 1024, 2048 * 64
+    assert (attn, 64 * expert, router) == (16_777_216, 402_653_184, 131_072)
+    assert pc["per_layer_matmul"] == attn + 8 * expert + router == 67_239_936
+    assert pc["total"] == 6_919_161_856
+    assert pc["matmul"] == 16 * 67_239_936 + 2048 * 50304 == 1_178_861_568
+    assert pc["resident_matmul"] == 16 * (attn + 64 * expert + router) + 2048 * 50304 == 6_816_006_144
+    # 6 operations a multiplied parameter a token; attention over 16 heads of 128
+    assert flops.train_flops(moe, 4, [4]) == 6.0 * 1_178_861_568 * 4 + 12.0 * 16 * 16 * 128 * 10
+    assert flops.decode_weight_bytes(moe, 2) == 2.0 * 6_816_006_144
+    assert flops.paged_decode_needs(moe, 1000, 10)["flops"] == 4.0 * 16 * 128 * 1000
+
+
 def check_trace_reduction_on_recorded_trace():
     """selftest_data/trace_small.json: a two-device trace in the plain form,
     written by hand around the numbers below (ns). Device 0: module
     jit_step(1) 1000..9000; ops a 1000..3000, kernel 3000..4000 (named as a
-    Mosaic call), all-reduce 5000..7000, b 6500..8000. Window 0..10000."""
+    Mosaic call), all-reduce 5000..7000, b 6500..8000 (named by its whole
+    HLO text, metadata last). Window 0..10000."""
     out = xplane.reduce(_load("selftest_data", "trace_small.json"))
     assert out["devices"] == 2 and abs(out["window_s"] - 10e-6) < 1e-15
     # device 0 busy: 1000..4000 and 5000..8000 = 6000; device 1: 2000..6000 = 4000
@@ -103,9 +147,13 @@ def check_trace_reduction_on_recorded_trace():
     assert abs(out["kernel"]["jit_step"]["seconds"] - 0.5e-6) < 1e-15
     gaps = dict(out["idle_gaps"])
     # device 0 idle: 0..1000 (between steps), 4000..5000 (inside bench.engine.step
-    # 3500..5200), 8000..10000 (between steps)
-    assert abs(gaps["inside_engine.step"] - 1e-6) < 1e-15 and abs(gaps["between_steps"] - 3e-6) < 1e-15
+    # 3500..5200), 8000..10000 (inside bench.engine.step 7900..9500, llm.step
+    # 7920..9480 and, innermost, the program's phase llm.step.decode_fetch 7950..9400)
+    assert abs(gaps["inside_engine.step"] - 1e-6) < 1e-15 and abs(gaps["between_steps"] - 1e-6) < 1e-15
+    assert abs(gaps["llm.step.decode_fetch"] - 2e-6) < 1e-15 and len(gaps) == 3
     assert out["device_ops"][0][0] == "jit_step/a"
+    samples = {key: rest for key, _seconds, *rest in out["op_samples"]}
+    assert samples["jit_step/b"][1] == "jit(step)/ffn/mul" and samples["jit_step/a"] == ["a", None]
 
 
 def check_manifest_and_files():
@@ -123,7 +171,7 @@ def check_manifest_and_files():
 
 
 CHECKS = [check_schedule_same_work_every_seed, check_closed_loop_and_train_work,
-          check_percentile_and_spread, check_flops_against_hand_counts,
+          check_percentile_and_spread, check_flops_against_hand_counts, check_architecture_seam,
           check_trace_reduction_on_recorded_trace, check_manifest_and_files]
 
 

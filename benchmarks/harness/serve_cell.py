@@ -29,6 +29,24 @@ def _warmup_buckets(traffic: dict, engine: dict) -> list:
     return [b for b in buckets if bottom <= b <= top]
 
 
+def warm_group_rounds(engine: dict, bucket: int, seed: int, vocab: int, k_buckets: list) -> list:
+    """The rounds of replica.bench_warm_groups for this engine: each group
+    size alone, every size in one step (8 + 4 + 2 + 1: the later groups meet
+    what the first one's update left), and the largest twice; each in both
+    states of the mirrors. Prompts of half the cell's first warmed bucket,
+    their tokens from the seed."""
+    room = engine["max_slots"] - 1  # a keeper holds one slot
+    sizes = sorted((k for k in k_buckets if k <= room), reverse=True)
+    counts = sizes + [n for n in (sum(sizes), 2 * sizes[0]) if n <= room and n not in sizes]
+    rounds, idx = [], 2 * 10 ** 6
+    for state in ("retired", "decoded"):
+        for n in counts:
+            tokens = [schedule.prompt_tokens(seed, idx + j, bucket // 2, vocab) for j in range(n + 1)]
+            rounds.append((state, tokens[0], tokens[1:]))
+            idx += n + 1
+    return rounds
+
+
 def _post(port: int, tokens: list, max_tokens: int) -> tuple[int, list]:
     import http.client
 
@@ -95,6 +113,12 @@ def run(spec: dict, seed: int, seconds: float, trace: bool, rehearse: bool, t_st
         status, probe_out = _post(port, probe_prompt, PROBE["out_len"])
         if status != 200 or len(probe_out) != PROBE["out_len"]:
             raise SystemExit(f"benchmark: probe request failed: status {status}, {len(probe_out)} tokens")
+        warm_groups = None
+        if int(engine.get("tensor_parallel", 1)) > 1:
+            # Under a mesh; on one device there is nothing to warm and the
+            # replica is left as it was.
+            warm_groups = call("bench_warm_groups", warm_group_rounds(
+                engine, _warmup_buckets(traffic, engine)[0], seed, config["vocab_size"], dev0["k_buckets"]))
         call("bench_counters", True)
 
         start_at = time.monotonic() + 1.0
@@ -125,6 +149,10 @@ def run(spec: dict, seed: int, seconds: float, trace: bool, rehearse: bool, t_st
         device = call("bench_device")
         stats = call("stats")
         traced = call("bench_trace_result", t=300) if trace else None
+        if traced and "logdir" in traced:
+            from harness import xplane
+
+            traced.update(xplane.reduce_logdir(traced.pop("logdir")))  # parses a file: no backend, no chip
         check = call("bench_reference_check", probe_prompt, probe_out, config, t=900)
         driver_touched_jax = backend_initialized()
     finally:
@@ -137,7 +165,7 @@ def run(spec: dict, seed: int, seconds: float, trace: bool, rehearse: bool, t_st
     say(f"replica in the window: {json.dumps({k: v for k, v in window.items() if k != 'queue_wait_s'})}")
     say(f"replica: ready in {ready_s:.1f} s (engine init + warm-up {dev0['init_s']:.1f} s, of which "
         f"warm-up {dev0['warmup_s']:.1f} s); buckets warmed {_warmup_buckets(traffic, engine)}; "
-        f"reference check {json.dumps(check)}")
+        f"groups warmed before the ramp {json.dumps(warm_groups)}; reference check {json.dumps(check)}")
     return {"kind": "serve", "plan": plan, "client": client, "window": window, "device": device,
             "traced": traced, "check": check, "setup_s": setup_s, "stats": stats,
             "driver_touched_jax": driver_touched_jax, "seconds": seconds, "traffic": traffic,

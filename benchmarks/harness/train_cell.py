@@ -26,7 +26,8 @@ def train_fn(cfg: dict) -> None:
     import numpy as np
     from jax.profiler import TraceAnnotation
 
-    from harness import reference, xplane
+    from harness import xplane
+    from harness.cellspec import architecture
     from ray_tpu import train
     from ray_tpu.accel.device import device_report, enable_compile_cache
     from ray_tpu.data import prefetch_to_device
@@ -42,6 +43,7 @@ def train_fn(cfg: dict) -> None:
     compiles = [0]
     jax.monitoring.register_event_duration_secs_listener(
         lambda name, _s, **_k: compiles.__setitem__(0, compiles[0] + (name == COMPILE_EVENT)))
+    reference = architecture(cfg["config"])
     tcfg = TransformerConfig(**cfg["model"], **cfg["train_config"])
     rows, seconds, trace = cfg["batch_rows"], cfg["seconds"], cfg["trace"]
     cols = ("tokens", "segment_ids", "positions", "mask")

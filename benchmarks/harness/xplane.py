@@ -10,9 +10,10 @@ be checked on a small recorded one (selftest_data/, `run.py --selftest`):
 What it reads of a TPU trace: planes "/device:TPU:<n>" with a line
 "XLA Ops" (one event per executed HLO operation) and a line "XLA Modules"
 (one event per executed program, named "jit_<fn>(<fingerprint>)"); host
-planes whose lines are threads, where the benchmark's own
-jax.profiler.TraceAnnotations appear under the names it gave them
-("bench.window" brackets the traced window).
+planes whose lines are threads, where jax.profiler.TraceAnnotations appear
+under the names they were given: the benchmark's own ("bench.window" brackets
+the traced window) and the program's step phases ("llm.step.<phase>",
+ray_tpu.util.tracing.PhaseSpans).
 """
 from __future__ import annotations
 
@@ -23,6 +24,8 @@ import re
 
 OPS_LINE, MODULES_LINE = "XLA Ops", "XLA Modules"
 WINDOW = "bench.window"
+NOTE_FAMILIES = ("bench.", "llm.step.")  # annotations an idle gap can be laid to
+OP_NAME = re.compile(r'op_name="([^"]*)"')
 COLLECTIVE = re.compile(r"all-reduce|all-gather|reduce-scatter|collective-permute|all-to-all", re.I)
 KERNEL = re.compile(r"mosaic|tpu_custom_call", re.I)
 
@@ -134,7 +137,7 @@ def reduce(trace: dict) -> dict:
     # Host annotations of the benchmark, on the trace's clock.
     notes = sorted(
         (s, s + d, name) for p in hosts for ln in p["lines"] for name, s, d in ln["events"]
-        if name.startswith("bench."))
+        if name.startswith(NOTE_FAMILIES))
     windows = [(s, e) for s, e, name in notes if name == WINDOW]
     all_ops = [(s, s + d) for p in devices for ln in p["lines"] if ln["name"] == OPS_LINE
                for _n, s, d in ln["events"]]
@@ -146,6 +149,16 @@ def reduce(trace: dict) -> dict:
 
     busy, exposed, per_op, per_module, module_runs, kernel, samples = [], [], {}, {}, {}, {}, {}
     first_gaps = None
+    kinds: dict[str, tuple] = {}
+
+    def kind(text):
+        """(is a collective, is a kernel). Looked up once for each distinct
+        instruction text: a window holds a million events of a few hundred."""
+        k = kinds.get(text)
+        if k is None:
+            k = kinds[text] = (bool(COLLECTIVE.search(text)), bool(KERNEL.search(text)))
+        return k
+
     for p in devices:
         lines = {ln["name"]: ln["events"] for ln in p["lines"]}
         ops = [(n, s, s + d) for n, s, d in lines.get(OPS_LINE, []) if s + d > lo and s < hi]
@@ -158,16 +171,20 @@ def reduce(trace: dict) -> dict:
 
         b = _clip(_union([[s, e] for _n, s, e in ops]), lo, hi)
         busy.append(_length(b) / 1e9)
-        coll = _clip(_union([[s, e] for n, s, e in ops if COLLECTIVE.search(n)]), lo, hi)
-        comp = _clip(_union([[s, e] for n, s, e in ops if not COLLECTIVE.search(n)]), lo, hi)
+        coll = _clip(_union([[s, e] for n, s, e in ops if kind(n)[0]]), lo, hi)
+        comp = _clip(_union([[s, e] for n, s, e in ops if not kind(n)[0]]), lo, hi)
         exposed.append(_length(_subtract(coll, comp)) / 1e9)
         for n, s, e, self_ns in _self_times(ops):
             m = module_of(s)
             dur = max(0.0, self_ns) / 1e9
             key = f"{m}/{_op_name(n)}"
             per_op[key] = per_op.get(key, 0.0) + dur
-            samples.setdefault(key, n[:240])
-            if KERNEL.search(n):
+            if key not in samples:
+                # the text's head, and the named scope from its metadata (which
+                # lies past any cut worth printing)
+                scope = OP_NAME.search(n)
+                samples[key] = (n[:240], scope.group(1) if scope else None)
+            if kind(n)[1]:
                 k = kernel.setdefault(m, {"seconds": 0.0, "calls": 0})
                 k["seconds"] += dur
                 k["calls"] += 1
@@ -179,7 +196,8 @@ def reduce(trace: dict) -> dict:
             first_gaps = _subtract([[lo, hi]], b)
 
     # Idle gaps of the first device, each laid to the innermost annotation of
-    # the benchmark that covers its start, else to "between_steps".
+    # either family that covers its start (a bench.<x> as "inside_<x>", a
+    # phase of the program's step under its own name), else to "between_steps".
     spans = [(s, e, n) for s, e, n in notes if n != WINDOW]
     gaps: dict[str, float] = {}
     for s, e in first_gaps:
@@ -198,5 +216,5 @@ def reduce(trace: dict) -> dict:
         "kernel": {m: {"seconds": k["seconds"] / n_dev, "calls": k["calls"] / n_dev}
                    for m, k in kernel.items()},
         "line_names": sorted({ln["name"] for p in devices for ln in p["lines"]}),
-        "op_samples": [[k, v, samples[k]] for k, v in top(per_op)[:40]],
+        "op_samples": [[k, v, *samples[k]] for k, v in top(per_op)[:40]],
     }

@@ -6,10 +6,13 @@
 
 The process you start is a supervisor that never imports jax or ray_tpu: it
 runs the cell in a child that leads a session of its own, and when the child
-is done it sweeps that session and waits until every thread of it is gone
-(a killed chip holder lets go of the chip seconds after its leader dies).
-The child drives the program's normal entry points and never touches JAX
-either; the chip belongs to the serve replica or the train worker.
+is done, or the supervisor is told to end, it kills that session and reaps
+every process of it (the supervisor is their subreaper: a process can be
+reaped only when its last thread has gone, and a killed chip holder lets go
+of the chip seconds after its leader dies). Should the supervisor itself be
+killed, the child sees its lifeline close and kills the session. The child
+drives the program's normal entry points and never touches JAX either; the
+chip belongs to the serve replica or the train worker.
 """
 from __future__ import annotations
 
@@ -18,13 +21,16 @@ import time
 T_START = time.time()
 
 import argparse  # noqa: E402
+import ctypes  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import re  # noqa: E402
+import select  # noqa: E402
 import shutil  # noqa: E402
 import signal  # noqa: E402
 import subprocess  # noqa: E402
 import sys  # noqa: E402
+import threading  # noqa: E402
 
 BENCH_DIR = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH_DIR)
@@ -160,24 +166,148 @@ def child(args) -> None:
 # The supervisor
 # ---------------------------------------------------------------------------
 
+SWEEP_S = 120  # how long the sweep waits for a killed session to be gone
+END_SIGNALS = (signal.SIGTERM, signal.SIGINT, signal.SIGHUP, signal.SIGALRM)
+
+
+class _ToldToEnd(Exception):
+    pass
+
+
 def _session_pids(sid: int) -> list[int]:
     """Every process of a session with a thread still alive (a killed
     process's leader turns zombie while its other threads are still closing
-    the files they share, the chip's among them)."""
+    the files they share, the chip's among them). A thread that ends between
+    the listing and the look is passed over alone: the process's other
+    threads are still looked at."""
     out = []
     for name in os.listdir("/proc"):
-        if name.isdigit():
+        if not name.isdigit():
+            continue
+        try:
+            if os.getsid(int(name)) != sid:
+                continue
+            tids = os.listdir(f"/proc/{name}/task")
+        except OSError:
+            continue
+        for tid in tids:
             try:
-                if os.getsid(int(name)) != sid:
-                    continue
-                for tid in os.listdir(f"/proc/{name}/task"):
-                    with open(f"/proc/{name}/task/{tid}/stat") as f:
-                        if f.read().rsplit(")", 1)[1].split()[0] not in "ZX":
-                            out.append(int(name))
-                            break
+                with open(f"/proc/{name}/task/{tid}/stat") as f:
+                    state = f.read().rsplit(")", 1)[1].split()[0]
             except (OSError, IndexError):
-                pass
+                continue
+            if state not in "ZX":
+                out.append(int(name))
+                break
     return sorted(out)
+
+
+def _kill_session(sid: int, but: int = 0) -> None:
+    for pid in _session_pids(sid):
+        if pid != but:
+            try:
+                os.kill(pid, signal.SIGKILL)
+            except ProcessLookupError:
+                pass
+
+
+def _lifeline(fd: int) -> None:
+    """In the child: the read end of a pipe whose write end only the
+    supervisor holds. It closes when the supervisor is gone, however it went;
+    then this session, which nobody is left to sweep, ends itself."""
+    def watch():
+        try:
+            while os.read(fd, 1):
+                pass
+        except OSError:
+            pass
+        _kill_session(os.getsid(0), but=os.getpid())
+        os.kill(os.getpid(), signal.SIGKILL)
+
+    threading.Thread(target=watch, name="lifeline", daemon=True).start()
+
+
+def _adopt_orphans() -> bool:
+    """Make this process the subreaper of its descendants (Linux's
+    PR_SET_CHILD_SUBREAPER): a worker whose parent has died becomes this
+    process's child, so that the sweep can reap it, which the kernel allows
+    only when its last thread has gone, and leaves no zombie behind."""
+    try:
+        return ctypes.CDLL(None, use_errno=True).prctl(36, 1, 0, 0, 0) == 0
+    except (OSError, AttributeError):
+        return False
+
+
+def _reap_all(deadline: float) -> bool:
+    """Reap every child this process has, adopted ones too, until none is
+    left (True) or the deadline has passed."""
+    while True:
+        try:
+            pid, _ = os.waitpid(-1, os.WNOHANG)
+        except ChildProcessError:
+            return True
+        if pid == 0:
+            if time.monotonic() > deadline:
+                return False
+            time.sleep(0.05)
+
+
+def run_swept(cmd: list, env: dict, deadline_s: float, on_line) -> dict:
+    """Run `cmd` as the leader of a session of its own, hand its output to
+    `on_line` line by line, and leave nothing of that session behind, on
+    every way out: the command's own end, its deadline, or a signal that
+    tells this process to end (`signal` in the result, and no line is handed
+    on after it)."""
+    adopted = _adopt_orphans()
+    life_r, life_w = os.pipe()
+    proc = subprocess.Popen(cmd + ["--lifeline", str(life_r)], cwd=ROOT, env=env, stdout=subprocess.PIPE,
+                            start_new_session=True, pass_fds=(life_r,))
+    os.close(life_r)
+    told, old, rc, sweeping = [], {}, None, False
+
+    def end(signum, _frame):
+        told.append(signum)
+        if len(told) == 1 and not sweeping:  # once, and never into the sweep
+            raise _ToldToEnd()
+
+    try:
+        for s in END_SIGNALS:
+            old[s] = signal.signal(s, end)
+        signal.alarm(int(deadline_s))
+        fd, buf = proc.stdout.fileno(), b""
+        while True:
+            if select.select([fd], [], [], 0.5)[0]:
+                chunk = os.read(fd, 1 << 16)
+                if not chunk:
+                    break
+                *lines, buf = (buf + chunk).split(b"\n")
+                for line in lines:
+                    on_line(line.decode(errors="replace") + "\n")
+            elif proc.poll() is not None:
+                break  # the leader has ended; whoever still holds its pipe is for the sweep
+        if buf:
+            on_line(buf.decode(errors="replace") + "\n")
+        rc = proc.wait()
+    except _ToldToEnd:
+        pass
+    finally:
+        sweeping = True
+        signal.alarm(0)
+        t0 = time.monotonic()
+        stragglers = _session_pids(proc.pid)
+        _kill_session(proc.pid)
+        if rc is None:
+            rc = proc.wait()
+        reaped = _reap_all(t0 + SWEEP_S) if adopted else False
+        while _session_pids(proc.pid) and time.monotonic() - t0 < SWEEP_S:
+            time.sleep(0.1)
+        left = _session_pids(proc.pid)
+        waited = time.monotonic() - t0
+        os.close(life_w)
+        for s, h in old.items():
+            signal.signal(s, h)
+    return {"rc": rc, "signal": told[0] if told and rc != 0 else None, "stragglers": stragglers, "left": left,
+            "waited_s": waited, "reaped_all": reaped}
 
 
 def supervise(args, argv: list) -> int:
@@ -191,38 +321,28 @@ def supervise(args, argv: list) -> int:
     if args.rehearse:
         env["JAX_PLATFORMS"] = "cpu"
     cmd = [sys.executable, os.path.abspath(__file__), "--child", "--t-start", repr(T_START)] + argv
-    proc = subprocess.Popen(cmd, cwd=ROOT, env=env, stdout=subprocess.PIPE, text=True,
-                            start_new_session=True)
-    result = None
-    old = signal.signal(signal.SIGALRM, lambda *_: os.killpg(proc.pid, signal.SIGKILL))
-    signal.alarm(DEADLINE_S)
-    try:
-        for line in proc.stdout:
-            if line.startswith(RESULT_TAG):
-                result = line[len(RESULT_TAG):].strip()
-            else:
-                sys.stdout.write(line)
-                sys.stdout.flush()
-        rc = proc.wait()
-    finally:
-        signal.alarm(0)
-        signal.signal(signal.SIGALRM, old)
-        stragglers = _session_pids(proc.pid)
-        try:
-            os.killpg(proc.pid, signal.SIGKILL)
-        except ProcessLookupError:
-            pass
-        t0 = time.monotonic()
-        while _session_pids(proc.pid) and time.monotonic() - t0 < 120:
-            time.sleep(0.1)
-    left = _session_pids(proc.pid)
-    say(f"processes the run left for the sweep: {len(stragglers)}; after it: {len(left)} "
-        f"(waited {time.monotonic() - t0:.1f} s)")
-    if rc != 0 or left or (result is None and not args.rehearse):
+    result = []
+
+    def on_line(line: str) -> None:
+        if line.startswith(RESULT_TAG):
+            result.append(line[len(RESULT_TAG):].strip())
+        else:
+            sys.stdout.write(line)
+            sys.stdout.flush()
+
+    end = run_swept(cmd, env, DEADLINE_S, on_line)
+    rc, left = end["rc"], end["left"]
+    say(f"processes the run left for the sweep: {len(end['stragglers'])}; after it: {len(left)} "
+        f"(waited {end['waited_s']:.1f} s; every one reaped: {end['reaped_all']}); "
+        f"the run took {time.time() - T_START:.1f} s")
+    if end["signal"] is not None:
+        say(f"benchmark: no result (ended by signal {end['signal']}, processes left {left})")
+        return 128 + end["signal"]
+    if rc != 0 or left or (not result and not args.rehearse):
         say(f"benchmark: no result (exit code {rc}, processes left {left})")
         return rc or 1
-    if result is not None:
-        say(result)
+    if result:
+        say(result[-1])
     return 0
 
 
@@ -238,6 +358,7 @@ def main() -> int:
                     help="override a key of the traffic file for this run (a sweep, not a measurement)")
     ap.add_argument("--child", action="store_true", help=argparse.SUPPRESS)
     ap.add_argument("--t-start", type=float, default=T_START, help=argparse.SUPPRESS)
+    ap.add_argument("--lifeline", type=int, default=None, help=argparse.SUPPRESS)
     args = ap.parse_args()
     if args.selftest:
         sys.path.insert(0, BENCH_DIR)
@@ -251,6 +372,8 @@ def main() -> int:
             ap.error("--seconds is required")
         args.seconds = 4.0
     if args.child:
+        if args.lifeline is not None:
+            _lifeline(args.lifeline)
         child(args)
         return 0
     argv = sys.argv[1:]
