@@ -60,9 +60,9 @@ def load_metric(name: str):
 @functools.lru_cache(maxsize=None)
 def load_architecture(name: str):
     """An architecture's module: benchmarks/architectures/<name>.py, with
-    `logits`, `packed_loss`, `transformer_kwargs`, `shrink`, `attention_dims`
-    and `param_counts` (README, "An architecture"). The self-test's fixture
-    reaches its own directory with a relative name."""
+    `logits`, `packed_loss`, `transformer_kwargs`, `shrink`, `attention_dims`,
+    `param_counts` and optionally `routing` (README, "An architecture"). The
+    self-test's fixture reaches its own directory with a relative name."""
     path = os.path.join(BENCH_DIR, "architectures", name + ".py")
     if not os.path.exists(path):
         raise SystemExit(f"benchmark: architecture {name!r} has no file at {path}")
@@ -81,6 +81,15 @@ def architecture(config: dict):
 
 def transformer_kwargs(config: dict) -> dict:
     return architecture(config).transformer_kwargs(config)
+
+
+def routing(config: dict):
+    """How many top-k choices among experts a token meets on its way through
+    the model, as the architecture's file declares it; None for a file that
+    declares no discrete choice (the serve check then holds every position as
+    tightly as the quietest one: harness/refcheck.py `judge`)."""
+    declared = getattr(architecture(config), "routing", None)
+    return declared(config) if declared else None
 
 
 def shrink_for_rehearsal(spec: dict) -> dict:

@@ -237,7 +237,7 @@ class BenchLLMServer(LLMServer):
         import jax.numpy as jnp
 
         from harness import refcheck
-        from harness.cellspec import architecture
+        from harness.cellspec import architecture, routing
         from ray_tpu.models.transformer import forward
 
         eng = self.engine
@@ -250,4 +250,4 @@ class BenchLLMServer(LLMServer):
             coarse = plain(jax.jit(refcheck.coarse_weights)(eng.params), toks)
         cfg = dataclasses.replace(eng.cfg, attention_impl="reference")
         own = jax.jit(lambda p, t: forward(p, t, cfg)[0][0, P - 1: P - 1 + n])(eng.params, toks)
-        return refcheck.judge(ref, own, coarse, served)
+        return refcheck.judge(ref, own, coarse, served, routing(model))

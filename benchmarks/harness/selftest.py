@@ -6,7 +6,7 @@ import json
 import os
 import traceback
 
-from harness import flops, schedule, xplane
+from harness import flops, refcheck, schedule, xplane
 from harness.cellspec import BENCH_DIR, architecture, load_cell, load_metric
 from harness.stats import percentile, spread
 
@@ -156,6 +156,33 @@ def check_trace_reduction_on_recorded_trace():
     assert samples["jit_step/b"][1] == "jit(step)/ffn/mul" and samples["jit_step/a"] == ["a", None]
 
 
+def check_serve_check_two_tests():
+    """judge by hand: 12 positions x 4 logits, the reference all zeros with
+    logit 0 raised to 1 (the served token), the coarse reference 0.1 off at
+    every position, so that every limit is a round number."""
+    import numpy as np
+
+    ref = np.zeros((12, 4), np.float32)
+    ref[:, 0] = 1.0
+    served, coarse = [0] * 12, ref + 0.1
+    quiet, loud = refcheck.LIMITS["routed"]
+    assert refcheck.LIMITS["dense"] == (0.4, 0.4) and quiet < 1 < loud
+    one_odd = ref + 0.01
+    one_odd[5] = ref[5] + 0.1  # one position with ten times the others' error, as large as the coarse one's
+    routed, dense = refcheck.judge(ref, one_odd, coarse, served, routing=16), refcheck.judge(ref, one_odd, coarse, served)
+    assert routed["ok"] and routed["refused_by"] == [] and routed["position_limit"] == loud
+    assert not dense["ok"] and dense["refused_by"] == ["every_position"] and dense["position_limit"] == 0.4
+    for verdict in (routed, dense):
+        assert abs(verdict["quietest_share_of_coarse"] - 0.1) < 1e-6 and abs(verdict["noise_share_of_coarse"] - 1) < 1e-6
+    for routing in (None, 16):  # every position at 1.2 x the coarse error: refused both ways
+        all_off = refcheck.judge(ref, ref + 0.12, coarse, served, routing)
+        assert not all_off["ok"] and "quietest_position" in all_off["refused_by"], all_off
+        assert abs(all_off["quietest_share_of_coarse"] - 1.2) < 1e-6
+    # one position beyond what a flip may do: refused with the declaration too
+    one_odd[5] = ref[5] + 0.1 * (loud + 0.1)
+    assert refcheck.judge(ref, one_odd, coarse, served, routing=16)["refused_by"] == ["every_position"]
+
+
 def check_manifest_and_files():
     manifest = _load(os.pardir, "BENCHMARK.json")
     e2e = {m["name"] for m in manifest["end_to_end"]}
@@ -172,7 +199,7 @@ def check_manifest_and_files():
 
 CHECKS = [check_schedule_same_work_every_seed, check_closed_loop_and_train_work,
           check_percentile_and_spread, check_flops_against_hand_counts, check_architecture_seam,
-          check_trace_reduction_on_recorded_trace, check_manifest_and_files]
+          check_trace_reduction_on_recorded_trace, check_serve_check_two_tests, check_manifest_and_files]
 
 
 def main() -> int:

@@ -1,16 +1,25 @@
-"""The reference check's two readings, for any configuration file: what the
+"""The reference check's readings, for any configuration file: what the
 program's own forward reads against the plain float32 reference (sound), and
-what the control reads, put in the program's place: the reference computed
-from weights kept in 3 mantissa bits, which is what fp8 e4m3 holds, the step
-below bfloat16 that would tempt a later PR. Both as harness/refcheck.judge
-reads them: the error's share of the coarse reference's (weights in 4 bits).
-The rounding is of the parameter tree, so it needs nothing of an
-architecture's file but its reference.
+what two controls read, put in the program's place. `control`: the reference
+computed from weights kept in 3 mantissa bits, which is what fp8 e4m3 holds,
+the step below bfloat16 that would tempt a later PR; it moves every position,
+and the quietest-position test has to refuse it. `wrong_position`: the sound
+forward with one position's logits taken from the next position, a position
+computed from the wrong context; it leaves the other positions alone, and
+the every-position test has to refuse it. All as
+harness/refcheck.judge reads them: shares of the coarse reference's error
+(weights in 4 bits). The rounding is of the parameter tree, so it needs
+nothing of an architecture's file but its reference.
 
-On the chip, at a configuration's own size (harness/refcheck.py's limit was
+On the chip, at a configuration's own size (harness/refcheck.py's limits were
 set from these readings; PERF.md section 2):
 
     python3 benchmarks/tests/control.py --config internlm2-1.8b --seeds 12
+    python3 benchmarks/tests/control.py --config ../selftest_data/routed_experts_olmoe --layers 8
+
+(the second is the routed fixture at OLMoE's widths: --config is a name
+relative to benchmarks/configs). A routed model's PR reads both controls so,
+on its own configuration, before it adds its cell.
 
 builds no engine and serves nothing: weights from each seed, made on the
 device(s) in one jitted call with the engine's sharding, one sequence of the
@@ -32,6 +41,7 @@ if os.path.dirname(BENCH_DIR) not in sys.path:
 
 PROMPT, SERVED = 96, 12  # the probe's lengths (harness/serve_cell.py PROBE)
 CONTROL_MANTISSA_BITS = 3
+FORWARDS = ("sound", "control", "wrong_position")
 
 
 def make_forwards(model: dict):
@@ -72,23 +82,36 @@ def make_forwards(model: dict):
 
 def readings(model: dict, seeds) -> list:
     """For each seed the check's verdict on the program's forward and on the
-    control, both judged as the replica judges (harness/refcheck.judge), with
-    the reference's own greedy tokens as the served ones."""
+    two controls, judged as the replica judges (harness/refcheck.judge, with
+    the architecture's routing declaration), the reference's own greedy
+    tokens as the served ones."""
     import jax
     import numpy as np
 
-    from harness import refcheck, schedule
+    from harness import cellspec, refcheck, schedule
 
     init, sound, control, reference, coarse = make_forwards(model)
+    routing = cellspec.routing(model)
+
+    def rms(a):
+        return float(np.sqrt(np.mean(np.square(a, dtype=np.float64))))
+
     out = []
     for seed in seeds:
         params = init(jax.random.PRNGKey(int(seed) % (2 ** 31 - 1)))
         toks = np.asarray([schedule.prompt_tokens(seed, 10 ** 6, PROMPT + SERVED, model["vocab_size"])], np.int32)
         ref = np.asarray(reference(params, toks), np.float32)
-        served, yard = ref.argmax(-1), coarse(params, toks)
-        out.append({"seed": int(seed),
-                    "sound": refcheck.judge(ref, sound(params, toks), yard, served),
-                    "control": refcheck.judge(ref, control(params, toks), yard, served)})
+        served, yard = ref.argmax(-1), np.asarray(coarse(params, toks), np.float32)
+        own = np.asarray(sound(params, toks), np.float32)
+        moved = own.copy()
+        at = int(seed) % SERVED
+        moved[at] = own[(at + 1) % SERVED]
+        row = {"seed": int(seed)}
+        for name, logits in zip(FORWARDS, (own, control(params, toks), moved)):
+            row[name] = refcheck.judge(ref, logits, yard, served, routing)
+            # no test of judge's: read beside them (PERF.md section 7)
+            row[name]["rms_share_of_coarse"] = rms(np.asarray(logits, np.float32) - ref) / rms(yard - ref)
+        out.append(row)
     return out
 
 
@@ -100,8 +123,6 @@ def main() -> int:
     ap.add_argument("--tensor-parallel", type=int, help="another degree than the file's")
     args = ap.parse_args()
     import jax
-
-    from harness import refcheck
 
     with open(os.path.join(BENCH_DIR, "configs", args.config + ".json")) as f:
         model = json.load(f)
@@ -115,16 +136,23 @@ def main() -> int:
     rows = readings(model, [1000003 * i + 17 for i in range(1, args.seeds + 1)])
     for r in rows:
         print(json.dumps(r), flush=True)
-    sound = [r["sound"]["noise_share_of_coarse"] for r in rows]
-    control = [r["control"]["noise_share_of_coarse"] for r in rows]
+    def span(which, key):
+        values = [r[which][key] for r in rows]
+        return [min(values), max(values)]
+
     print(json.dumps({
         "config": args.config, "layers": model["num_hidden_layers"], "tensor_parallel": tp,
-        "device": jax.devices()[0].device_kind, "seeds": len(rows),
-        "sound_share_of_coarse_min_max": [min(sound), max(sound)],
-        "control_share_of_coarse_min_max": [min(control), max(control)],
-        "noise_limit": refcheck.NOISE_LIMIT,
+        "device": jax.devices()[0].device_kind, "seeds": len(rows), "routing": rows[0]["sound"]["routing"],
+        # [least, most] over the seeds, of each forward by each test's statistic
+        "quietest_share_of_coarse": {w: span(w, "quietest_share_of_coarse") for w in FORWARDS},
+        "noise_share_of_coarse": {w: span(w, "noise_share_of_coarse") for w in FORWARDS},
+        "rms_share_of_coarse": {w: span(w, "rms_share_of_coarse") for w in FORWARDS},
+        "quietest_limit": rows[0]["sound"]["quietest_limit"], "position_limit": rows[0]["sound"]["position_limit"],
         "sound_all_ok": all(r["sound"]["ok"] for r in rows),
-        "control_all_refused": not any(r["control"]["ok"] for r in rows)}))
+        "control_all_refused_at_the_quietest_position": all(
+            "quietest_position" in r["control"]["refused_by"] for r in rows),
+        "wrong_position_all_refused_at_every_position_alone": all(
+            r["wrong_position"]["refused_by"] == ["every_position"] for r in rows)}))
     return 0
 
 

@@ -65,6 +65,8 @@ def test_a_configuration_without_the_key_is_dense(name):
         assert callable(getattr(arch, fn)), fn
     assert cellspec.transformer_kwargs(model) == arch.transformer_kwargs(model)
     assert flops.param_counts(model) == arch.param_counts(model)
+    # the optional declaration: dense.py has none and resolves as before PR 28
+    assert not hasattr(arch, "routing") and cellspec.routing(model) is None
 
 
 def test_a_configuration_names_another_architecture_by_file():
@@ -73,6 +75,7 @@ def test_a_configuration_names_another_architecture_by_file():
     assert arch.__file__ == os.path.join(BENCH_DIR, "architectures", "..", "selftest_data", "routed_experts.py")
     counts = flops.param_counts(moe)
     assert counts["total"] == 6_919_161_856 and counts["resident_matmul"] > 5 * counts["matmul"]
+    assert cellspec.routing(moe) == moe["num_hidden_layers"] == 16  # one top-k choice a layer
     with pytest.raises(SystemExit, match="no file"):
         cellspec.architecture({"architecture": "no-such-architecture"})
 
