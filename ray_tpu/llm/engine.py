@@ -182,6 +182,12 @@ def _attn_proj(h, lp, cfg, dt):
     return q, k, v
 
 
+def _kv_rows(kv, dtype):
+    """One request's K or V of a layer, [1, P, KV, Hd], as the paged pool
+    stores it: [KV, P, Hd] in the pool's dtype."""
+    return kv[0].transpose(1, 0, 2).astype(dtype)
+
+
 def _prefill_layer(x, lp, cfg: TransformerConfig, positions, seg, mesh=None):
     """Standard causal layer over the (padded) prompt; returns new K/V for
     the cache. seg masks pad columns (pad tokens are their own segment).
@@ -385,6 +391,19 @@ class LLMEngine:
             P_total = self.ec.total_pages
             self.ppseq = S // ps  # page-table width (max pages per sequence)
             # Linear page pool: position (page, offset) lives at page*ps + offset.
+            # The rule for every program that takes the two pools (each
+            # donates them): a pool that is CARRIED whole (argument -> loop
+            # carry -> result) and updated with dynamic_update_slice, or
+            # aliased to a Mosaic call's outputs, is updated in place; a
+            # pool, or a layer's slice of one, passed through a scan as xs
+            # and taken back as ys is copied, sliced out and stacked back
+            # every layer (70% of the decode program before PR 25, 65% of
+            # the prefill program before PR 29, two pools and more of
+            # temporaries each: PERF.md section 6), and so is a pool a scan
+            # only reads, if its consumer prefers another layout. So decode
+            # carries the pools through both its scans with the layer index
+            # in xs, and the layer scans of prefill see a prompt's K/V and
+            # never a pool (_write_pages, _copy_pages_impl).
             pool_shape = (L, cfg.kv_heads, P_total * ps, cfg.head_dim)
             kv_spec = _P(None, "tensor", None, None)
             self.k_pages = _pool_zeros(pool_shape, kv_spec)
@@ -457,39 +476,9 @@ class LLMEngine:
         # on the pages the chunks are filling.
         self._prefilling: dict[int, np.ndarray] = {}
         if self.paged:
-            ps_ = self.ec.page_size
-            n_pg_axes = (cfg.n_layers, cfg.kv_heads, ps_, cfg.head_dim)
-
-            n_pg = self.ppseq
-
-            def _copy_pages_impl(kp, vp, src, dst):
-                # UNROLLED slice-all-then-update-all (n_pg is small and
-                # static). On an earlier stack, formulations that looped
-                # (fori_loop carry) or gathered/scattered the page axis made
-                # XLA copy the whole pool per page (~450-570ms on v5e);
-                # unrolled, the program ran at ~24ms. On the current stack
-                # (jax 0.9.0; PERF.md section 6, PR 25) a loop-carried,
-                # donated pool IS updated in place: the decode block carried
-                # both pools through two nested scans with 64
-                # dynamic_update_slice a layer (0.74 us each on the chip, no
-                # copy of a pool in the program, both pools aliased to its
-                # outputs) before its write moved into the kernel. What
-                # still copies is a pool passed through a scan as xs/ys, and
-                # a scatter (.at[].set) on a layer's slice. This form was
-                # not re-measured against a looped one; it is a handful of
-                # pages either way.
-                ks = [jax.lax.dynamic_slice(kp, (0, 0, src[i] * ps_, 0), n_pg_axes)
-                      for i in range(n_pg)]
-                vs = [jax.lax.dynamic_slice(vp, (0, 0, src[i] * ps_, 0), n_pg_axes)
-                      for i in range(n_pg)]
-                for i in range(n_pg):
-                    kp = jax.lax.dynamic_update_slice(kp, ks[i], (0, 0, dst[i] * ps_, 0))
-                    vp = jax.lax.dynamic_update_slice(vp, vs[i], (0, 0, dst[i] * ps_, 0))
-                return kp, vp
-
             # Padded rows copy page 0 onto itself (the dead sink) — static
             # [ppseq] shape, one compiled program for any hit size.
-            self._copy_pages_jit = jax.jit(_copy_pages_impl, donate_argnums=(0, 1))
+            self._copy_pages_jit = jax.jit(self._copy_pages_impl, donate_argnums=(0, 1))
             # Context-page buckets for the tail-prefill program (partial
             # prefix hits): powers of two up to the page-table width, so the
             # compiled-program count stays |buckets| x log(ppseq).
@@ -547,42 +536,63 @@ class LLMEngine:
         return m
 
     # -- jitted programs ---------------------------------------------------
+    def _read_pages(self, pool, page_idxs):
+        """Pages ``page_idxs`` [n] of every layer, side by side:
+        [L, KV, n * ps, Hd] (unrolled: n is small and static)."""
+        cfg, ps = self.cfg, self.ec.page_size
+        page = (cfg.n_layers, cfg.kv_heads, ps, cfg.head_dim)
+        return jnp.concatenate(
+            [jax.lax.dynamic_slice(pool, (0, 0, page_idxs[i] * ps, 0), page)
+             for i in range(page_idxs.shape[0])], axis=2)
+
+    def _write_pages(self, k_pages, v_pages, ks, vs, page_idxs):
+        """A prompt's fresh K/V, ``ks`` / ``vs`` [L, KV, n * ps, Hd] in the
+        pool's dtype, into pages ``page_idxs`` [n] of the carried pools: one
+        in-place ``dynamic_update_slice`` of [L, KV, ps, Hd] a page and pool
+        (the rule: where the pools are made, ``__init__``). Trailing page
+        ids 0 send a bucket's padding to the dead sink."""
+        ps = self.ec.page_size
+        with jax.named_scope("kv_write"):
+            for p in range(page_idxs.shape[0]):
+                at = (0, 0, page_idxs[p] * ps, 0)
+                rows = slice(p * ps, (p + 1) * ps)
+                k_pages = jax.lax.dynamic_update_slice(k_pages, ks[:, :, rows], at)
+                v_pages = jax.lax.dynamic_update_slice(v_pages, vs[:, :, rows], at)
+        return k_pages, v_pages
+
+    def _copy_pages_impl(self, k_pages, v_pages, src, dst):
+        """A prefix-cache hit's pages ``src`` copied onto ``dst`` ([ppseq]
+        each). Every source page is read before the first is written: a
+        hit's source and target pages never overlap, but padded rows all
+        name page 0. On an earlier stack a looped or gathered form made XLA
+        copy the whole pool a page (~450-570 ms on v5e against ~24 ms
+        unrolled); on the current one (jax 0.9.0) a carried, donated pool is
+        updated in place (the rule: where the pools are made), and compiled
+        for a v5e this program holds under a megabyte beside the pools,
+        kv_heads-sharded or on one chip (PERF.md section 6, PR 29)."""
+        return self._write_pages(
+            k_pages, v_pages, self._read_pages(k_pages, src), self._read_pages(v_pages, src), dst)
+
     def _prefill_impl(self, params, k_pages, v_pages, tokens, length, page_idxs, key, temp, top_p, top_k):
         """tokens: [P] (padded to the bucket); page_idxs: [P // ps] page ids
-        (trailing entries may be 0 = dead sink). Writes K/V pages, returns
-        the first generated token + updated pools."""
+        (trailing entries may be 0 = dead sink). Returns the pools with the
+        prompt's K/V pages written and the first generated token. Attention
+        runs on the layer's fresh K/V, so the layer scan never sees a pool:
+        it hands out every layer's K/V as ``ys`` and the pages are written
+        once, after it."""
         cfg = self.cfg
-        ps = self.ec.page_size
         P = tokens.shape[0]
-        n_pg = P // ps
         with jax.named_scope("embed"):
             x = params["embed"].astype(cfg.dtype)[tokens][None]  # [1,P,D]
         pos = jnp.arange(P, dtype=jnp.int32)[None]
         seg = (pos >= length).astype(jnp.int32)  # pads = their own segment
 
-        def scan_fn(h, xs):
-            lp, ck_l, cv_l = xs
+        def scan_fn(h, lp):
             h, k_new, v_new = _prefill_layer(h, lp, cfg, pos, seg, mesh=self.mesh)
-            with jax.named_scope("kv_write"):
-                # [1,P,KV,Hd] -> [KV,P,Hd]; scatter page chunks into the pool.
-                kt = k_new[0].transpose(1, 0, 2).astype(ck_l.dtype)
-                vt = v_new[0].transpose(1, 0, 2).astype(cv_l.dtype)
+            return h, (_kv_rows(k_new, k_pages.dtype), _kv_rows(v_new, v_pages.dtype))
 
-                def write(p, pools):
-                    ck, cv = pools
-                    start = page_idxs[p] * ps
-                    ck = jax.lax.dynamic_update_slice(
-                        ck, jax.lax.dynamic_slice(kt, (0, p * ps, 0), (cfg.kv_heads, ps, cfg.head_dim)),
-                        (0, start, 0))
-                    cv = jax.lax.dynamic_update_slice(
-                        cv, jax.lax.dynamic_slice(vt, (0, p * ps, 0), (cfg.kv_heads, ps, cfg.head_dim)),
-                        (0, start, 0))
-                    return ck, cv
-
-                ck_l, cv_l = jax.lax.fori_loop(0, n_pg, write, (ck_l, cv_l))
-            return h, (ck_l, cv_l)
-
-        x, (k_pages, v_pages) = jax.lax.scan(scan_fn, x, (params["layers"], k_pages, v_pages))
+        x, (ks, vs) = jax.lax.scan(scan_fn, x, params["layers"])  # ks: [L,KV,P,Hd]
+        k_pages, v_pages = self._write_pages(k_pages, v_pages, ks, vs, page_idxs)
         with jax.named_scope("lm_head"):
             x = _rms_norm(x, params["final_norm"])
             last = jax.lax.dynamic_index_in_dim(x[0], length - 1, axis=0, keepdims=False)
@@ -597,19 +607,15 @@ class LLMEngine:
         over steps, inner scan over layers): one host round trip per block.
         Returns (k_pages, v_pages, toks [n_steps, B], last', lengths').
 
-        How the pools are threaded: both scans CARRY the two pools whole (as
-        [L, KV, pages, ps, Hd], a free reshape); the layer scan's ``xs`` are
-        the layer's weights and its index. The one Mosaic call of a layer is
-        told the layer by an operand, reads the pages where they lie, and
-        writes each slot's new K/V row itself, into the pool its outputs
-        alias: no operation of this program but that call has a pool, or a
-        layer's slice of one, for operand or result. Passed as ``xs`` and
-        taken back as ``ys`` instead, the pools cost the serve cells 70% of
-        this program (PERF.md section 6, PR 25): XLA took a 100 MB slice out
-        and stacked it back every layer, relaid it twice around the slice's
-        scatter, and copied both 2.4 GB pools once a step for the ``ys``
-        (53-57 ms a step and 2.33 pools of temporaries, against 11-14 ms and
-        an eighth of a pool now)."""
+        How the pools are threaded (the rule: where the pools are made):
+        both scans CARRY the two pools whole (as [L, KV, pages, ps, Hd], a
+        free reshape); the layer scan's ``xs`` are the layer's weights and
+        its index. The one Mosaic call of a layer is told the layer by an
+        operand, reads the pages where they lie, and writes each slot's new
+        K/V row itself, into the pool its outputs alias: no operation of
+        this program but that call has a pool, or a layer's slice of one,
+        for operand or result (11-14 ms a step and an eighth of a pool of
+        temporaries; PERF.md section 6, PR 25)."""
         cfg = self.cfg
         ps = self.ec.page_size
         flat = k_pages.shape  # [L, KV, total_pages * ps, Hd], as every other program has it
@@ -668,10 +674,14 @@ class LLMEngine:
 
     def _prefill_batch_impl(self, params, k_pages, v_pages, tokens, lengths, third, key, temps, top_ps, top_ks):
         """Prefill k requests of one length bucket in ONE device program
-        (scan over requests around the single-request body): one dispatch per
-        admitted group instead of one per request — where per-call latency
-        dominates prefill compute this is the main TTFT lever under load
-        (unverified on a directly attached chip — PERF.md "Bring-up").
+        (scan over requests around the single-request body): one dispatch
+        and one set of host-built arrays per admitted group instead of one
+        per request. On a directly attached chip an awaited dispatch costs
+        0.55-0.61 ms (PERF.md section 6, bring-up), little beside a prompt's
+        prefill, so the group saves host work (``prefill_dispatch``), not
+        device time: the k requests run one after another and each reads
+        the weights. The request scan carries the two donated pools; each
+        request writes its pages into them in place (_write_pages).
         tokens: [k, P]; `third` is the per-request
         placement input: page rows [k, P // ps] (paged) or slot ids [k]
         (dense); the layout-specific impl is picked once here."""
@@ -759,7 +769,6 @@ class LLMEngine:
         ps = self.ec.page_size
         Tb = tokens.shape[0]
         C = ctx_pages.shape[0]
-        n_tail_pg = Tb // ps
         KV, Hd = cfg.kv_heads, cfg.head_dim
         group = cfg.n_heads // KV
         x = params["embed"].astype(cfg.dtype)[tokens][None]  # [1,Tb,D]
@@ -776,33 +785,14 @@ class LLMEngine:
         mask = jnp.concatenate([ctx_mask, tail_mask], axis=1)
 
         def scan_fn(h, xs):
-            lp, ck_l, cv_l = xs
+            lp, ctx_k, ctx_v = xs
             dt = h.dtype
             hh = _rms_norm(h, lp["attn_norm"])
             q, k_new, v_new = _attn_proj(hh, lp, cfg, dt)
             q = _rope(q, pos, cfg.rope_theta)
             k_new = _rope(k_new, pos, cfg.rope_theta)
-            kt = k_new[0].transpose(1, 0, 2).astype(ck_l.dtype)  # [KV,Tb,Hd]
-            vt = v_new[0].transpose(1, 0, 2).astype(cv_l.dtype)
-
-            def write(p, pools):
-                ck, cv = pools
-                s0 = tail_pages[p] * ps
-                ck = jax.lax.dynamic_update_slice(
-                    ck, jax.lax.dynamic_slice(kt, (0, p * ps, 0), (KV, ps, Hd)), (0, s0, 0))
-                cv = jax.lax.dynamic_update_slice(
-                    cv, jax.lax.dynamic_slice(vt, (0, p * ps, 0), (KV, ps, Hd)), (0, s0, 0))
-                return ck, cv
-
-            ck_l, cv_l = jax.lax.fori_loop(0, n_tail_pg, write, (ck_l, cv_l))
-            # Gather the cached context from the pool (unrolled — C is
-            # small and static; see _copy_pages_impl for why not a loop).
-            ctx_k = jnp.concatenate(
-                [jax.lax.dynamic_slice(ck_l, (0, ctx_pages[c] * ps, 0), (KV, ps, Hd))
-                 for c in range(C)], axis=1)
-            ctx_v = jnp.concatenate(
-                [jax.lax.dynamic_slice(cv_l, (0, ctx_pages[c] * ps, 0), (KV, ps, Hd))
-                 for c in range(C)], axis=1)
+            kt = _kv_rows(k_new, k_pages.dtype)  # [KV,Tb,Hd]
+            vt = _kv_rows(v_new, v_pages.dtype)
             kall = jnp.concatenate([ctx_k, kt], axis=1)  # [KV, C*ps+Tb, Hd]
             vall = jnp.concatenate([ctx_v, vt], axis=1)
             qg = q[0].reshape(Tb, KV, group, Hd)
@@ -814,9 +804,15 @@ class LLMEngine:
             h = h + jnp.einsum("bshk,hkd->bsd", o, lp["wo"].astype(dt))
             hh = _rms_norm(h, lp["ffn_norm"])
             h = h + _dense_ffn(hh, lp)
-            return h, (ck_l, cv_l)
+            return h, (kt, vt)
 
-        x, (k_pages, v_pages) = jax.lax.scan(scan_fn, x, (params["layers"], k_pages, v_pages))
+        # The cached context of every layer, gathered once from the whole
+        # pools, so that the layer scan sees a prompt's K/V and never a pool.
+        ctx = (self._read_pages(k_pages, ctx_pages), self._read_pages(v_pages, ctx_pages))
+        x, (ks, vs) = jax.lax.scan(scan_fn, x, (params["layers"], *ctx))
+        # The gather above reads positions < start and this lands on the
+        # tail's pages, so the write can follow the scan.
+        k_pages, v_pages = self._write_pages(k_pages, v_pages, ks, vs, tail_pages)
         x = _rms_norm(x, params["final_norm"])
         last = jax.lax.dynamic_index_in_dim(x[0], length - 1 - start, axis=0, keepdims=False)
         logits = last @ params["lm_head"].astype(cfg.dtype)
@@ -850,10 +846,11 @@ class LLMEngine:
         Also records, once, in ``self.mosaic`` whether the first prefill
         program and the full decode block, compiled, hold a Mosaic custom
         call: how a caller tells that the Pallas kernels run and not the jnp
-        references (off the TPU they cannot). Each decode entry of
-        ``warmup_log`` carries ``temp_bytes``, what the compiled program
-        holds on the device beside its arguments, on any backend: a decode
-        program that moved the KV pools would need room for them there."""
+        references (off the TPU they cannot). The first prefill entry and
+        each decode entry of ``warmup_log`` carry ``temp_bytes``, what the
+        compiled program holds on the device beside its arguments, on any
+        backend: a program that moved the KV pools would need room for them
+        there."""
         if buckets is None:
             buckets = self.buckets
         else:
@@ -892,9 +889,13 @@ class LLMEngine:
                     jnp.zeros(k, jnp.float32), jnp.ones(k, jnp.float32),
                     jnp.zeros(k, jnp.int32),
                 )
+                entry = {"program": "prefill", "bucket": b, "k": k}
                 if "prefill" not in self.mosaic:
-                    self.mosaic["prefill"] = on_tpu and holds_mosaic(
-                        compiled_ahead(self._prefill(b, k), args))
+                    # The first program only: compiling all of them ahead
+                    # would lower each a second time inside set-up.
+                    compiled = compiled_ahead(self._prefill(b, k), args)
+                    self.mosaic["prefill"] = on_tpu and holds_mosaic(compiled)
+                    entry["temp_bytes"] = compiled.memory_analysis().temp_size_in_bytes
                 self.k_pages, self.v_pages, td = self._prefill(b, k)(*args)
                 # The admit path's per-group mirror updates are their own tiny
                 # jitted programs, one shape variant per k — compile them here
@@ -903,15 +904,12 @@ class LLMEngine:
                 self.d_lengths = self.d_lengths.at[idxs].set(lens)
                 self.d_last = self.d_last.at[idxs].set(td)
                 jax.device_get(td)
-                log.append({"program": "prefill", "bucket": b, "k": k,
-                            "seconds": time.monotonic() - t0})
+                log.append({**entry, "seconds": time.monotonic() - t0})
         for n in self.block_sizes:
             t0 = time.monotonic()
             head = (self.params, self.k_pages, self.v_pages, self.d_last, self.d_lengths)
             tail = (n, key, self.d_temps, self.d_top_ps, self.d_top_ks)
             args = head + ((self.d_page_tables,) if self.paged else ()) + tail
-            # What the program holds beside its arguments: a decode program
-            # that moved the pools would need room for them here.
             compiled = compiled_ahead(self._decode_jit, args)
             temp_bytes = compiled.memory_analysis().temp_size_in_bytes
             if n == self.block_sizes[-1]:

@@ -211,25 +211,102 @@ def test_paged_decode_matches_across_pool_layouts():
     assert outs[0] == outs[1]
 
 
+POOL_CFG = TransformerConfig(
+    vocab_size=97, d_model=64, n_layers=4, n_heads=4, n_kv_heads=2, d_ff=128,
+    max_seq_len=128, dtype=jnp.float32, attention_impl="reference",
+)
+
+
+def _big_pool_engine():
+    """A tiny model beside a pool that dwarfs it (2 MiB an array), so that a
+    program's temporaries say whether it holds a copy of a pool."""
+    return LLMEngine(POOL_CFG, engine_config=EngineConfig(
+        max_slots=2, max_seq=64, kv_layout="paged", page_size=16, total_pages=256,
+        prefill_buckets=(32,), decode_block=4,
+    ))
+
+
 def test_paged_decode_program_does_not_move_the_pool():
     """The decode block carries both KV pools through its layer loop and
     writes the new rows in place, so what it holds beside its arguments is a
     sliver of one pool. Passing the pools through the layer scan as xs / ys
     made the compiler keep copies of them: more than two pools of
     temporaries, on this backend as on the chip (PERF.md section 6, PR 25)."""
-    cfg = TransformerConfig(
-        vocab_size=97, d_model=64, n_layers=4, n_heads=4, n_kv_heads=2, d_ff=128,
-        max_seq_len=128, dtype=jnp.float32, attention_impl="reference",
-    )
-    eng = LLMEngine(cfg, engine_config=EngineConfig(
-        max_slots=2, max_seq=64, kv_layout="paged", page_size=16, total_pages=256,
-        prefill_buckets=(16,), decode_block=4,
-    ))
-    eng.warmup(buckets=(16,), k_values=(1,))
+    eng = _big_pool_engine()
+    eng.warmup(buckets=(32,), k_values=(1,))
     decode = [p for p in eng.warmup_log if p["program"] == "decode"]
     assert {p["block"] for p in decode} == {1, 4}
     for p in decode:
         assert 0 <= p["temp_bytes"] < eng.k_pages.nbytes // 2, (p, eng.k_pages.nbytes)
+
+
+@pytest.mark.parametrize("program", ["prefill-k1", "prefill-k4", "tail"])
+def test_paged_prefill_programs_do_not_move_the_pool(program):
+    """No prefill program has a pool, or a layer's slice of one, as xs / ys
+    of a scan: the layer scan hands out the prompt's K/V and each page is
+    written once, in place, into the donated pools. With the pools passed
+    through the layer scan (the form before PR 29) these three programs held
+    5,425,136 / 5,426,960 / 5,416,840 bytes of temporaries on this backend
+    beside pools of 2,097,152 bytes each: 2.6 pools, as on the chip (2.25 at
+    bucket 1024; PERF.md section 6, PR 29); now they hold the prompt's own
+    K/V."""
+    eng = _big_pool_engine()
+    i32 = lambda *shape: jnp.zeros(shape, jnp.int32)
+    key = jax.random.PRNGKey(0)
+    if program == "tail":
+        jitted = eng._tail_prefill(32, 2)
+        args = (eng.params, eng.k_pages, eng.v_pages, i32(32), jnp.int32(32), jnp.int32(40),
+                i32(2), i32(2), key, jnp.zeros(1), jnp.ones(1), i32(1))
+    else:
+        k = int(program[-1])
+        jitted = eng._prefill(32, k)
+        args = (eng.params, eng.k_pages, eng.v_pages, i32(k, 32), jnp.ones(k, jnp.int32),
+                i32(k, 2), key, jnp.zeros(k), jnp.ones(k), i32(k))
+    temp_bytes = jitted.lower(*args).compile().memory_analysis().temp_size_in_bytes
+    assert 0 <= temp_bytes < eng.k_pages.nbytes // 2, (temp_bytes, eng.k_pages.nbytes)
+
+
+def test_first_prefill_entry_of_warmup_log_carries_temp_bytes():
+    """warmup() compiles its first prefill program ahead anyway (for
+    ``mosaic``): that entry says what the program holds beside its
+    arguments; the others are not compiled a second time for it."""
+    eng = _big_pool_engine()
+    eng.warmup(k_values=(2, 1))
+    prefill = [p for p in eng.warmup_log if p["program"] == "prefill"]
+    assert [(p["bucket"], p["k"]) for p in prefill] == [(32, 2), (32, 1), (64, 2), (64, 1)]
+    assert isinstance(prefill[0]["temp_bytes"], int)
+    assert 0 <= prefill[0]["temp_bytes"] < eng.k_pages.nbytes // 2, prefill[0]
+    assert all("temp_bytes" not in p for p in prefill[1:]), prefill
+
+
+@pytest.mark.parametrize("prompt_len", [9, 20, 40])
+def test_paged_prefill_into_scattered_pages_matches_contiguous(prompt_len):
+    """One prefill program writes a prompt's pages wherever the free list
+    puts them: a prompt that fills 1, 2 and all 3 pages of its bucket (the
+    bucket's other page ids are 0, the dead sink), prefilled into scattered
+    pages and then decoded, gives the tokens of the same request in a fresh,
+    contiguous pool."""
+    prompt = [(7 * i + 3) % 97 for i in range(prompt_len)]
+    outs, tables = [], []
+    for scattered in (False, True):
+        eng = LLMEngine(CFG, engine_config=EngineConfig(
+            max_slots=2, max_seq=128, kv_layout="paged", page_size=16, total_pages=24,
+            prefill_buckets=(48,), decode_block=4,
+        ))
+        if scattered:
+            eng.free_pages = type(eng.free_pages)([19, 4, 11, 2, 23, 7, 13, 5, 17, 1])
+        eng.add_request("r", prompt, 10)
+        eng.step()
+        tables.append(list(eng.slots[0].pages))
+        toks = []
+        while eng.has_work():
+            for ev in eng.step().values():
+                toks = ev.get("tokens", toks)
+        outs.append(toks)
+    n = len(tables[0])
+    assert n >= -(-prompt_len // 16) and tables[0] == list(range(1, n + 1)), tables
+    assert tables[1] == [19, 4, 11, 2][:n], tables
+    assert len(outs[0]) == 10 and outs[0] == outs[1], outs
 
 
 def test_dense_and_paged_layouts_agree():
