@@ -17,7 +17,7 @@ belongs to one process at a time. This parent never touches JAX:
 - serve:   rt.init / serve.start / serve.run(build_llm_app(...)), streaming
            HTTP requests through the proxy; the replica holds the chip.
 
-The model is the flagship preset (bench.py) at full width and depth, weights
+The model is the flagship preset (MODEL below) at full width and depth, weights
 random from a seed. Step times printed here are information, not results.
 The last line of standard output is one JSON object naming the device.
 """
@@ -45,7 +45,7 @@ CHIP = dict(
     train=dict(remat=True, remat_policy="dots", attention_block_q=1024,
                attention_block_k=1024),
     batch_per_chip=16, seq=2048, steps=5,
-    engine=dict(max_slots=32, max_seq=2048, kv_layout="paged", page_size=128,
+    engine=dict(max_slots=32, max_seq=2048, page_size=128,
                 prefix_cache=True, prefill_buckets=(128, 256, 512, 1024)),
     warmup_buckets=(512,), prompt_len=512, shared_prefix=384, new_tokens=64,
 )
@@ -58,7 +58,7 @@ REHEARSAL = dict(
     train=dict(remat=True, remat_policy="dots", attention_block_q=128,
                attention_block_k=128),
     batch_per_chip=2, seq=256, steps=5,
-    engine=dict(max_slots=4, max_seq=256, kv_layout="paged", page_size=32,
+    engine=dict(max_slots=4, max_seq=256, page_size=32,
                 prefix_cache=True, prefill_buckets=(32, 64, 128)),
     warmup_buckets=(128,), prompt_len=96, shared_prefix=64, new_tokens=8,
 )
@@ -119,6 +119,24 @@ def _chip_files_held(pid: int) -> list[str]:
     except OSError:
         pass
     return sorted(held)
+
+
+def _chips_free() -> bool:
+    """Whether every chip of this host can be opened. A vfio group opens for
+    one holder at a time, so EBUSY says that some process, of whatever
+    session, has yet to let go (a phase's cluster starts its workers in
+    sessions of their own, which _session_pids(phase) does not list)."""
+    import errno
+
+    from ray_tpu.accel.tpu import chip_device_files
+
+    for path in chip_device_files():
+        try:
+            os.close(os.open(path, os.O_RDWR))
+        except OSError as e:
+            if e.errno == errno.EBUSY:
+                return False
+    return True
 
 
 def audit_processes(roles: dict[int, str]) -> dict[int, list[str]]:
@@ -265,7 +283,7 @@ def phase_kernels(sz: dict, rehearsal: bool) -> dict:
 # ---------------------------------------------------------------------------
 
 def _train_fn(config: dict) -> None:
-    """Runs in the train worker: bench.py's train step, a few steps."""
+    """Runs in the train worker: make_train_step's step, a few steps."""
     import jax
     import numpy as np
 
@@ -719,7 +737,7 @@ def _run_phase(name: str, extra: list, deadline: float) -> dict:
         # (longer with four chips than with one); the next phase, and
         # whoever runs after this script, needs it gone.
         t0 = time.monotonic()
-        while _session_pids(proc.pid) and time.monotonic() - t0 < 120:
+        while (_session_pids(proc.pid) or not _chips_free()) and time.monotonic() - t0 < 120:
             time.sleep(0.1)
         waited = time.monotonic() - t0
     left = _session_pids(proc.pid)

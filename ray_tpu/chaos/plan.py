@@ -8,8 +8,7 @@ makes every fault a pure function of ``(seed, rule, hit-counter)`` instead
 of wall time:
 
 * every fault site in the tree calls ONE gate, :func:`maybe_inject`, whose
-  disabled path is a single module-attribute load + ``None`` check (bench
-  A/B in ``bench_core.py`` ``detail.chaos_overhead``);
+  disabled path is a single module-attribute load + ``None`` check;
 * an installed :class:`FaultSchedule` compiles a declarative spec
   (site pattern x ctx filter x nth/every/probability x kind) into per-rule
   hit counters; the fire/no-fire decision for hit *n* of rule *r* is

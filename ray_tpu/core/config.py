@@ -77,8 +77,8 @@ class Config:
     raw_mac_granularity: str = "window"
     # Vectored raw-lane sends (one sendmsg syscall per frame + direct
     # socket writes that bypass the transport's buffer copy). Off = the
-    # pre-wire-speed sequential-write path; exists so bench_core can A/B
-    # the legacy wire shape in-process.
+    # pre-wire-speed sequential-write path, kept so the legacy wire shape
+    # can be A/B'd in-process (ROADMAP D5b).
     raw_vectored_send: bool = True
     # Degraded-network shaping for the raw data lane, cluster-propagated:
     # JSON {"rate_mb_s": X, "delay_ms": Y} token-bucket pacing applied at
@@ -132,7 +132,7 @@ class Config:
     # --- state introspection (task lifecycle FSM -> controller index) ---
     # Emit per-attempt task lifecycle events (worker.py _task_event). Off,
     # the state API sees no tasks (tracing still works); the flag exists so
-    # the pipeline's cost can be A/B'd (bench_core detail.state_overhead).
+    # the pipeline's cost can be A/B'd.
     task_events_enabled: bool = True
     # Debounce window for the early lifecycle-event flush: a transition
     # reaches the controller within this bound instead of the metrics tick.

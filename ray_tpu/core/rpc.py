@@ -307,7 +307,7 @@ FRAME_TAG_LEN = _TAG_LEN
 
 # Process-wide envelope-size histograms ({messages-per-envelope: envelopes}),
 # send and receive sides, across every Connection in this process. Cheap
-# enough to keep always-on; bench_core.py reports them in row `detail`.
+# enough to keep always-on; metrics_series() ships them to /metrics.
 _SEND_BATCH_HIST: collections.Counter = collections.Counter()
 _RECV_BATCH_HIST: collections.Counter = collections.Counter()
 # Bytes-on-wire (payload + header), both directions. Plain ints: one += per
@@ -342,8 +342,7 @@ def metrics_series() -> list[dict]:
     """This process's RPC transport counters as snapshot()-shaped metric
     records (see ray_tpu.util.metrics): envelope batch-size histograms per
     side + bytes-on-wire counters. Shipped by the CoreWorker reporter so the
-    coalescing behavior of the live cluster is visible on /metrics, not just
-    in bench_core histograms."""
+    coalescing behavior of the live cluster is visible on /metrics."""
     import time as _time
 
     now = _time.time()

@@ -78,8 +78,7 @@ _task_latency_actor = _task_latency.bind({"kind": "actor"})
 
 # Owner-side streamed-batch histogram ({items-per-generator_items-frame:
 # frames}) across every stream this process consumes — the streaming lane's
-# analogue of rpc.batch_stats (bench_core reports it in the
-# streaming_generator_items row's detail; _runtime_series promotes it to the
+# analogue of rpc.batch_stats (_runtime_series promotes it to the
 # stream.batch.items metric on /metrics).
 _STREAM_BATCH_HIST: collections.Counter = collections.Counter()
 _STREAM_BATCH_BUCKETS = [1, 2, 4, 8, 16, 32, 64]
@@ -1017,6 +1016,12 @@ class CoreWorker:
         try:
             return fut.result(timeout)
         except concurrent.futures.TimeoutError:
+            if fut.done():
+                # Not this wait running out: the coroutine itself raised a
+                # TimeoutError (builtin and concurrent.futures' are one class
+                # since 3.11), e.g. the pickled qos.DeadlineExceeded of a
+                # call dropped at the executor's gate. It stays typed.
+                raise
             fut.cancel()
             raise GetTimeoutError(f"timed out after {timeout}s")
 

@@ -478,7 +478,7 @@ def build_openai_app(model_config: dict, engine_config: Optional[dict] = None,
     """OpenAI-compatible serving app; serve.run(...) it with a route_prefix
     and POST /v1/chat/completions to the proxy port. prefix_routing=True
     installs the prefix-affinity router policy in the proxy (pair with
-    engine_config={"kv_layout": "paged", "prefix_cache": True} so the sticky
+    engine_config={"prefix_cache": True} so the sticky
     replica actually reuses the pages)."""
     from ray_tpu import serve
     from ray_tpu.llm.engine import EngineConfig

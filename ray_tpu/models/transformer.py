@@ -51,7 +51,7 @@ class TransformerConfig:
     # keep cheap-to-store dot results — measured +1pt MFU on v5e at the
     # flagship size (PROFILES.md round 4).
     remat_policy: str = "full"
-    attention_impl: str = "auto"  # auto | flash | splash | reference | ring
+    attention_impl: str = "auto"  # auto | flash | reference | ring | ulysses
     # Flash-kernel tile sizes (0 = ops/attention.py defaults). v5e at
     # S=2048/hd=64 measures fastest at 1024x1024 (PROFILES.md round 4).
     attention_block_q: int = 0
@@ -235,10 +235,6 @@ def _attention(q, k, v, cfg: TransformerConfig, positions=None, segment_ids=None
         impl = "flash" if flash_supported(q.shape[1]) else "reference"
     if impl == "flash":
         return _flash(q, k, v, cfg, segment_ids)
-    if impl == "splash":
-        from ray_tpu.ops.splash import splash_attention
-
-        return splash_attention(q, k, v, causal=True, segment_ids=segment_ids)
     if impl == "ring":
         from ray_tpu.ops.ring_attention import ring_attention
 
@@ -312,30 +308,48 @@ def _load_balance_loss(weights, top_idx, n_experts):
     return n_experts * jnp.sum(me * ce)
 
 
-def _layer(x, lp, cfg: TransformerConfig, positions, segment_ids=None):
-    """One decoder block. x: [B, S, D] in cfg.dtype."""
+def decoder_block(x, lp, cfg: TransformerConfig, positions, attend):
+    """The one decoder block that training, prefill and decode all run: what
+    the model is (norms, projections, rope, the FFN's kind) lives here, what
+    a program does with K/V is its ``attend``.
+
+    x: [B, S, D] in cfg.dtype; lp: one layer's parameters; positions: [B, S].
+    attend(q [B,S,H,Hd], k [B,S,KV,Hd], v [B,S,KV,Hd]) -> (o [B,S,H,Hd], kept):
+    q and k arrive roped, grouped K/V as they are (native GQA); ``kept`` is
+    whatever the attention side wants handed out of the layer (a prompt's
+    K/V rows, the carried KV pools, None). Returns (x, moe_aux, kept)."""
     dt = x.dtype
-    h = _rms_norm(x, lp["attn_norm"])
-    q = jnp.einsum("bsd,dhk->bshk", h, lp["wq"].astype(dt))
-    k = jnp.einsum("bsd,dhk->bshk", h, lp["wk"].astype(dt))
-    v = jnp.einsum("bsd,dhk->bshk", h, lp["wv"].astype(dt))
-    q = wlc(q, ("batch", "seq", "heads", "head_dim"))
-    k = wlc(k, ("batch", "seq", "kv_heads", "head_dim"))
-    q = _rope(q, positions, cfg.rope_theta)
-    k = _rope(k, positions, cfg.rope_theta)
-    # Grouped K/V go to the kernel as-is (native GQA); see _attention.
-    o = _attention(q, k, v, cfg, positions, segment_ids)
-    o = wlc(o, ("batch", "seq", "heads", "head_dim"))
-    attn_out = jnp.einsum("bshk,hkd->bsd", o, lp["wo"].astype(dt))
-    x = x + attn_out
-    h = _rms_norm(x, lp["ffn_norm"])
-    if cfg.n_experts:
-        ffn_out, aux = _moe_ffn(h, lp, cfg)
-    else:
-        ffn_out, aux = _dense_ffn(h, lp), jnp.zeros((), jnp.float32)
-    x = x + ffn_out
+    with jax.named_scope("qkv"):
+        h = _rms_norm(x, lp["attn_norm"])
+        q = jnp.einsum("bsd,dhk->bshk", h, lp["wq"].astype(dt))
+        k = jnp.einsum("bsd,dhk->bshk", h, lp["wk"].astype(dt))
+        v = jnp.einsum("bsd,dhk->bshk", h, lp["wv"].astype(dt))
+        q = wlc(q, ("batch", "seq", "heads", "head_dim"))
+        k = wlc(k, ("batch", "seq", "kv_heads", "head_dim"))
+        q = _rope(q, positions, cfg.rope_theta)
+        k = _rope(k, positions, cfg.rope_theta)
+    o, kept = attend(q, k, v)
+    with jax.named_scope("attn_out"):
+        o = wlc(o, ("batch", "seq", "heads", "head_dim"))
+        x = x + jnp.einsum("bshk,hkd->bsd", o, lp["wo"].astype(dt))
+    with jax.named_scope("ffn"):
+        h = _rms_norm(x, lp["ffn_norm"])
+        if cfg.n_experts:
+            ffn_out, aux = _moe_ffn(h, lp, cfg)
+        else:
+            ffn_out, aux = _dense_ffn(h, lp), jnp.zeros((), jnp.float32)
+        x = x + ffn_out
     x = wlc(x, ("batch", "seq", "embed"))
-    return x, aux
+    return x, aux, kept
+
+
+def _layer(x, lp, cfg: TransformerConfig, positions, segment_ids=None):
+    """The block as training runs it: attention over the layer's own K/V by
+    the configured implementation, nothing kept. x: [B, S, D] in cfg.dtype."""
+    def attend(q, k, v):
+        return _attention(q, k, v, cfg, positions, segment_ids), None
+
+    return decoder_block(x, lp, cfg, positions, attend)[:2]
 
 
 def forward_hidden(params: dict, tokens: jax.Array, cfg: TransformerConfig,

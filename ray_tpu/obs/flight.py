@@ -28,7 +28,7 @@ tests/test_obs_plane.py, so every trigger path stays enumerable and tested:
 Cost contract: the recorder only *absorbs* events other subsystems already
 produce (worker._event, chaos._record, qos.raise_expired, rpc conn
 lifecycle) — one deque append under a lock per event, no new per-request
-work on the quiet path (bench_core ``detail.obs_overhead`` holds this).
+work on the quiet path.
 """
 from __future__ import annotations
 

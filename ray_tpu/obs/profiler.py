@@ -37,8 +37,8 @@ driver aggregation reuses one shape end to end; ``to_collapsed`` /
 
 Cost contract: disarmed, nothing runs and ``tracing.activate`` pays one
 module-global read on traced paths only. Armed but idle, the entire cost
-is the sampler thread's own tick (bench_core ``detail.profiler_overhead``
-holds this within noise).
+is the sampler thread's own tick (tests/test_profiler.py
+``test_armed_idle_overhead_interleaved`` holds the mechanism, with CI slack).
 """
 from __future__ import annotations
 

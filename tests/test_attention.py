@@ -1,6 +1,6 @@
 """Flash-attention kernel exactness vs the jnp oracle, run in Pallas
 interpret mode on CPU (the kernels themselves, not the fallback; real-TPU
-execution is covered by bench.py). Covers MHA, native GQA (grouped KV heads,
+execution is covered by chip_smoke.py and the benchmark's train cell). Covers MHA, native GQA (grouped KV heads,
 no repeat), segment masking (packed sequences), and backward gradients."""
 import math
 

@@ -119,7 +119,7 @@ def test_llm_batch_generate(pool_ray):
             vocab_size=512, d_model=64, n_layers=2, n_heads=4, n_kv_heads=2,
             d_ff=128, max_seq_len=128, attention_impl="reference",
         ),
-        engine_config={"max_slots": 4, "max_seq": 128, "prefill_buckets": (16, 32)},
+        engine_config={"max_slots": 4, "max_seq": 128, "page_size": 16, "prefill_buckets": (16, 32)},
         sampling={"max_tokens": 8},
         concurrency=1,
     )

@@ -33,7 +33,8 @@ def _naive_greedy(params, prompt, n):
 
 @pytest.fixture(scope="module")
 def engine():
-    return LLMEngine(CFG, engine_config=EngineConfig(max_slots=4, max_seq=128, prefill_buckets=(16, 32, 64)))
+    return LLMEngine(CFG, engine_config=EngineConfig(
+        max_slots=4, max_seq=128, page_size=16, prefill_buckets=(16, 32, 64)))
 
 
 @pytest.mark.slow  # heavy battery; tier-1 budget (see CHANGES PR-13)
@@ -87,7 +88,7 @@ def test_slot_reuse_after_finish(engine):
 def test_eos_stops_generation():
     eng = LLMEngine(
         CFG,
-        engine_config=EngineConfig(max_slots=2, max_seq=128, prefill_buckets=(16,), eos_id=0),
+        engine_config=EngineConfig(max_slots=2, max_seq=128, page_size=16, prefill_buckets=(16,), eos_id=0),
     )
     out = eng.generate(np.array([5, 6, 7], np.int32), max_tokens=40)
     if 0 in out["tokens"]:
@@ -108,7 +109,7 @@ def test_llm_serve_deployment():
                 vocab_size=97, d_model=64, n_layers=2, n_heads=4, n_kv_heads=2,
                 d_ff=128, max_seq_len=128, attention_impl="reference",
             ),
-            engine_config={"max_slots": 4, "max_seq": 128, "prefill_buckets": (16, 32)},
+            engine_config={"max_slots": 4, "max_seq": 128, "page_size": 16, "prefill_buckets": (16, 32)},
         )
         handle = serve.run(app, name="llm_app", http=False)
         # Concurrent requests batch at iteration level on one replica.
@@ -136,7 +137,7 @@ def test_paged_pool_memory_independent_of_slots():
     """The point of paging: slot count is a scheduling knob, not a memory
     multiplier. 32 slots over a 16-page pool uses 16 pages of HBM, not
     32 x max_seq."""
-    ec = EngineConfig(max_slots=32, max_seq=128, kv_layout="paged", page_size=16, total_pages=17,
+    ec = EngineConfig(max_slots=32, max_seq=128, page_size=16, total_pages=17,
                       prefill_buckets=(16,), decode_block=2)
     eng = LLMEngine(CFG, engine_config=ec)
     assert eng.k_pages.shape[2] == 17 * 16  # pool tokens, NOT 32*128
@@ -147,7 +148,7 @@ def test_paged_pool_memory_independent_of_slots():
 def test_paged_admission_waits_for_pages_then_proceeds():
     """Pool smaller than the aggregate demand: admission queues on the page
     budget (not slot count) and every request still completes."""
-    ec = EngineConfig(max_slots=8, max_seq=128, kv_layout="paged", page_size=16, total_pages=9,
+    ec = EngineConfig(max_slots=8, max_seq=128, page_size=16, total_pages=9,
                       prefill_buckets=(16,), decode_block=2)
     eng = LLMEngine(CFG, engine_config=ec)
     # Each request needs ceil((3 + 8 + 2)/16) = 1 page prompt... force more:
@@ -169,7 +170,7 @@ def test_paged_admission_waits_for_pages_then_proceeds():
 
 
 def test_paged_pages_recycled_after_finish():
-    ec = EngineConfig(max_slots=2, max_seq=128, kv_layout="paged", page_size=16, total_pages=9,
+    ec = EngineConfig(max_slots=2, max_seq=128, page_size=16, total_pages=9,
                       prefill_buckets=(16,), decode_block=2)
     eng = LLMEngine(CFG, engine_config=ec)
     free0 = len(eng.free_pages)
@@ -179,7 +180,7 @@ def test_paged_pages_recycled_after_finish():
 
 
 def test_paged_abort_frees_pages():
-    ec = EngineConfig(max_slots=2, max_seq=128, kv_layout="paged", page_size=16, total_pages=9,
+    ec = EngineConfig(max_slots=2, max_seq=128, page_size=16, total_pages=9,
                       prefill_buckets=(16,), decode_block=2)
     eng = LLMEngine(CFG, engine_config=ec)
     free0 = len(eng.free_pages)
@@ -200,7 +201,7 @@ def test_paged_decode_matches_across_pool_layouts():
     prompt = [9, 8, 7, 6, 5, 4, 3, 2, 1]
     outs = []
     for total_pages in (0, 12):
-        ec = EngineConfig(max_slots=3, max_seq=128, kv_layout="paged", page_size=16,
+        ec = EngineConfig(max_slots=3, max_seq=128, page_size=16,
                           prefill_buckets=(16,), total_pages=total_pages,
                           decode_block=4)
         eng = LLMEngine(CFG, engine_config=ec)
@@ -221,7 +222,7 @@ def _big_pool_engine():
     """A tiny model beside a pool that dwarfs it (2 MiB an array), so that a
     program's temporaries say whether it holds a copy of a pool."""
     return LLMEngine(POOL_CFG, engine_config=EngineConfig(
-        max_slots=2, max_seq=64, kv_layout="paged", page_size=16, total_pages=256,
+        max_slots=2, max_seq=64, page_size=16, total_pages=256,
         prefill_buckets=(32,), decode_block=4,
     ))
 
@@ -290,7 +291,7 @@ def test_paged_prefill_into_scattered_pages_matches_contiguous(prompt_len):
     outs, tables = [], []
     for scattered in (False, True):
         eng = LLMEngine(CFG, engine_config=EngineConfig(
-            max_slots=2, max_seq=128, kv_layout="paged", page_size=16, total_pages=24,
+            max_slots=2, max_seq=128, page_size=16, total_pages=24,
             prefill_buckets=(48,), decode_block=4,
         ))
         if scattered:
@@ -309,26 +310,27 @@ def test_paged_prefill_into_scattered_pages_matches_contiguous(prompt_len):
     assert len(outs[0]) == 10 and outs[0] == outs[1], outs
 
 
-def test_dense_and_paged_layouts_agree():
-    """Same request through both KV layouts: greedy tokens agree (the layout
-    is a memory/performance knob, not a numerics change). The two attention
-    algorithms accumulate in different orders, so a near-tie between top-2
-    logits could legitimately flip ONE argmax and cascade — require exact
-    agreement up to such a first divergence, with a long matching prefix."""
-    prompt = [7, 3, 11, 2]
-    outs = {}
-    for layout in ("dense", "paged"):
-        eng = LLMEngine(CFG, engine_config=EngineConfig(
-            max_slots=2, max_seq=128, kv_layout=layout,
-            **({"page_size": 16} if layout == "paged" else {}),
-            prefill_buckets=(16,), decode_block=4,
-        ))
-        outs[layout] = eng.generate(prompt, max_tokens=10)["tokens"]
-    a, b = outs["dense"], outs["paged"]
-    # First token comes from the (identical) prefill math: must match exactly.
-    assert a[0] == b[0], outs
-    agree = next((i for i in range(10) if a[i] != b[i]), 10)
-    assert agree >= 6, f"layouts diverged at step {agree}: {outs}"
+@pytest.mark.parametrize("prompt_len", [9, 20, 40])
+def test_paged_greedy_matches_full_forward(prompt_len):
+    """The engine against the model itself: a prompt that fills 1, 2 and 3
+    pages is prefilled into its pages and decoded through the page table,
+    and its greedy tokens are those of forward() run on the whole sequence
+    again for every token (no cache at all)."""
+    prompt = np.array([(5 * i + 2) % 97 for i in range(prompt_len)], np.int32)
+    eng = LLMEngine(CFG, engine_config=EngineConfig(
+        max_slots=2, max_seq=128, page_size=16, prefill_buckets=(16, 48), decode_block=4,
+    ))
+    assert eng.generate(prompt, max_tokens=10)["tokens"] == _naive_greedy(eng.params, prompt, 10)
+
+
+def test_kv_layout_other_than_paged_is_refused():
+    """kv_layout is a retired key (files under benchmarks/ still spell
+    "paged"): the dense layout went in PR 30 and asking for it must not
+    silently serve from another."""
+    with pytest.raises(ValueError, match="removed in PR 30"):
+        EngineConfig(max_slots=2, max_seq=128, kv_layout="dense")
+    assert LLMEngine(CFG, engine_config=EngineConfig(
+        max_slots=2, max_seq=128, page_size=16, kv_layout="paged")).paged is True
 
 
 # ---------------------------------------------------------------------------
@@ -339,7 +341,7 @@ MODEL_KW = dict(
     vocab_size=97, d_model=64, n_layers=2, n_heads=4, n_kv_heads=2, d_ff=128,
     max_seq_len=128, dtype=jnp.float32, attention_impl="reference",
 )
-ENGINE_KW = dict(max_slots=4, max_seq=128, prefill_buckets=(16, 32), kv_layout="paged",
+ENGINE_KW = dict(max_slots=4, max_seq=128, prefill_buckets=(16, 32),
                  page_size=16, prefix_cache=True)
 ENGINE_STAMPS = ("arrived", "admitted", "first_token", "finished")
 

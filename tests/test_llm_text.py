@@ -80,7 +80,7 @@ def test_tokenizer_deep_merge_chain_decodes():
 @pytest.fixture(scope="module")
 def engine():
     return LLMEngine(CFG, engine_config=EngineConfig(
-        max_slots=4, max_seq=128, prefill_buckets=(16, 32)))
+        max_slots=4, max_seq=128, page_size=16, prefill_buckets=(16, 32)))
 
 
 def _drain(engine):
@@ -201,7 +201,7 @@ TINY_MODEL = dict(
     vocab_size=512, d_model=32, n_layers=1, n_heads=2, n_kv_heads=2,
     d_ff=64, max_seq_len=64, attention_impl="reference",
 )
-TINY_ENGINE = {"max_slots": 2, "max_seq": 64, "prefill_buckets": (16,)}
+TINY_ENGINE = {"max_slots": 2, "max_seq": 64, "page_size": 16, "prefill_buckets": (16,)}
 
 CHATML = (
     "{% for message in messages %}"
@@ -290,7 +290,7 @@ def test_openai_ingress_end_to_end():
                 vocab_size=512, d_model=64, n_layers=2, n_heads=4, n_kv_heads=2,
                 d_ff=128, max_seq_len=128, attention_impl="reference",
             ),
-            engine_config={"max_slots": 4, "max_seq": 128, "prefill_buckets": (16, 32)},
+            engine_config={"max_slots": 4, "max_seq": 128, "page_size": 16, "prefill_buckets": (16, 32)},
             model_name="tiny-test-model",
         )
         serve.run(app, name="oai", route_prefix="/")

@@ -1,7 +1,8 @@
 """Tensor-parallel LLM serving: the engine sharded over a `tensor` mesh axis
 (params Megatron-split, KV pools split by kv_heads) must produce byte-identical
-greedy output to the single-device engine, for both KV layouts, and a serve
-replica must gang-schedule onto a host advertising the TP degree's chips.
+greedy output to the single-device engine, whole-prompt and chunked prefill
+alike, and a serve replica must gang-schedule onto a host advertising the TP
+degree's chips.
 
 Reference analogue: TP degree -> placement-group bundle mapping
 (llm/_internal/serve/engines/vllm/vllm_models.py:233-238; vLLM executes the
@@ -22,35 +23,33 @@ CFG = TransformerConfig(
 PROMPT = [5, 17, 42, 7, 23, 11, 2]
 
 
-def _engine(tp: int, layout: str, **ec_kw) -> LLMEngine:
-    kw = dict(max_slots=4, max_seq=128, prefill_buckets=(16, 32),
-              kv_layout=layout, tensor_parallel=tp)
-    if layout == "paged":
-        kw["page_size"] = 32
-    kw.update(ec_kw)
-    return LLMEngine(CFG, engine_config=EngineConfig(**kw))
+ENGINE_KW = dict(max_slots=4, max_seq=128, prefill_buckets=(16, 32), page_size=32)
 
 
-@pytest.mark.parametrize("layout", ["dense", "paged"])
-def test_tp_greedy_matches_single_device(layout):
+def _engine(tp: int, **ec_kw) -> LLMEngine:
+    return LLMEngine(CFG, engine_config=EngineConfig(**ENGINE_KW, tensor_parallel=tp, **ec_kw))
+
+
+@pytest.mark.parametrize("chunked_prefill", [0, 32])
+def test_tp_greedy_matches_single_device(chunked_prefill):
     """mesh=tensor(2) must not change greedy output vs one device — the
-    round-5 acceptance bar for sharded serving."""
-    ref = _engine(1, layout).generate(PROMPT, max_tokens=10)["tokens"]
-    tp = _engine(2, layout).generate(PROMPT, max_tokens=10)["tokens"]
-    assert tp == ref, f"{layout}: TP output diverged: {tp} vs {ref}"
+    round-5 acceptance bar for sharded serving. A 70-token prompt is one
+    prefill of bucket 128, or with chunked_prefill three runs of the tail
+    program (32 + 32 + 6 tokens over 0, 1 and 2 context pages)."""
+    prompt = [(11 * i + 5) % 96 for i in range(70)]
+    ref = _engine(1, chunked_prefill=chunked_prefill).generate(prompt, max_tokens=10)["tokens"]
+    tp = _engine(2, chunked_prefill=chunked_prefill).generate(prompt, max_tokens=10)["tokens"]
+    assert tp == ref, f"chunked_prefill={chunked_prefill}: TP output diverged: {tp} vs {ref}"
 
 
 def test_tp_actually_shards_params_and_kv():
-    eng = _engine(2, "paged")
+    eng = _engine(2)
     wq = eng.params["layers"]["wq"]  # [L, D, H, Hd]: heads sharded
     assert wq.addressable_shards[0].data.shape[2] == CFG.n_heads // 2
     mlp = eng.params["layers"]["w_gate"]  # [L, D, F]: ffn hidden sharded
     assert mlp.addressable_shards[0].data.shape[2] == CFG.d_ff // 2
     # Paged KV pool [L, KV, pages*ps, Hd]: kv_heads sharded.
     assert eng.k_pages.addressable_shards[0].data.shape[1] == CFG.kv_heads // 2
-    dense = _engine(2, "dense")
-    # Dense cache [L, B, S, KV, Hd]: kv_heads sharded.
-    assert dense.k_pages.addressable_shards[0].data.shape[3] == CFG.kv_heads // 2
 
 
 def test_tp_weight_handoff_through_object_store():
@@ -60,7 +59,7 @@ def test_tp_weight_handoff_through_object_store():
     from the fetched tree serves byte-identical greedy output."""
     import ray_tpu as rt
 
-    src = _engine(2, "dense")
+    src = _engine(2)
     ref_out = src.generate(PROMPT, max_tokens=10)["tokens"]
     wq = src.params["layers"]["wq"]
     assert len(wq.sharding.device_set) == 2  # really sharded going in
@@ -76,8 +75,7 @@ def test_tp_weight_handoff_through_object_store():
     assert len(fq.sharding.device_set) == 2
     assert fq.addressable_shards[0].data.shape == wq.addressable_shards[0].data.shape
     served = LLMEngine(CFG, params=fetched, engine_config=EngineConfig(
-        max_slots=4, max_seq=128, prefill_buckets=(16, 32),
-        kv_layout="dense", tensor_parallel=2))
+        **ENGINE_KW, tensor_parallel=2))
     assert served.generate(PROMPT, max_tokens=10)["tokens"] == ref_out
 
 
@@ -89,7 +87,7 @@ def test_tp_params_ref_served_through_deployment():
     from ray_tpu import serve
     from ray_tpu.llm import build_llm_app
 
-    src = _engine(2, "dense")
+    src = _engine(2)
     ref_out = src.generate(PROMPT, max_tokens=8)["tokens"]
 
     rt.init(num_cpus=8, resources={"TPU": 2.0})
@@ -101,9 +99,7 @@ def test_tp_params_ref_served_through_deployment():
                 vocab_size=96, d_model=64, n_layers=2, n_heads=4, n_kv_heads=2,
                 d_ff=128, max_seq_len=128, attention_impl="reference",
             ),
-            engine_config={"max_slots": 4, "max_seq": 128,
-                           "prefill_buckets": (16, 32), "kv_layout": "dense",
-                           "tensor_parallel": 2},
+            engine_config={**ENGINE_KW, "tensor_parallel": 2},
             params=ref,
             # Zero chips: this replica shards over the virtual CPU devices.
             # One scheduled onto TPU resources would refuse to run on them
@@ -122,7 +118,7 @@ def test_tp_params_ref_served_through_deployment():
 
 def test_tp_rejects_indivisible_model():
     with pytest.raises(ValueError, match="not divisible"):
-        _engine(4, "dense")  # kv_heads=2 % 4 != 0
+        _engine(4)  # kv_heads=2 % 4 != 0
 
 
 def test_tp_mixed_batch_and_sampling():
@@ -130,7 +126,7 @@ def test_tp_mixed_batch_and_sampling():
     per-request sampling params behave like the single-device engine."""
     from ray_tpu.llm.sampling import SamplingParams
 
-    eng = _engine(2, "paged")
+    eng = _engine(2)
     eng.add_request("greedy", PROMPT, 8,
                     sampling=SamplingParams(temperature=0.0, max_tokens=8))
     eng.add_request("hot", list(reversed(PROMPT)), 8,
@@ -140,7 +136,7 @@ def test_tp_mixed_batch_and_sampling():
         for rid, ev in eng.step().items():
             if ev.get("finished"):
                 done[rid] = ev["tokens"]
-    ref = _engine(1, "paged").generate(
+    ref = _engine(1).generate(
         PROMPT, 8, sampling=SamplingParams(temperature=0.0, max_tokens=8)
     )["tokens"]
     assert done["greedy"] == ref
@@ -150,7 +146,7 @@ def test_tp_mixed_batch_and_sampling():
 def test_tp_prefix_cache_hit_correct():
     """Prefix-cache page copy works on a kv_heads-sharded pool (the copy
     slices the token axis; the sharded axis rides along)."""
-    eng = _engine(2, "paged", prefix_cache=True, temperature=0.0)
+    eng = _engine(2, prefix_cache=True, temperature=0.0)
     cold = eng.generate(PROMPT, max_tokens=8)["tokens"]
     warm = eng.generate(PROMPT, max_tokens=8)["tokens"]
     assert eng.prefix_cache_stats["hits"] == 1

@@ -17,7 +17,7 @@ CFG = TransformerConfig(
 
 def _engine(**kw):
     defaults = dict(max_slots=4, max_seq=1024, prefill_buckets=(64, 512),
-                    kv_layout="paged", page_size=64, prefix_cache=True)
+                    page_size=64, prefix_cache=True)
     defaults.update(kw)
     return LLMEngine(CFG, engine_config=EngineConfig(**defaults))
 
@@ -249,12 +249,6 @@ def test_partial_hit_ttft_beats_cold():
     assert eng.prefix_cache_stats["partial_hits"] >= 4
     cold, warm = min(colds), min(warms)
     assert warm < cold, f"partial-hit ttft {warm:.4f}s not below cold {cold:.4f}s"
-
-
-def test_dense_layout_rejects_prefix_cache():
-    with pytest.raises(ValueError):
-        LLMEngine(CFG, engine_config=EngineConfig(
-            max_slots=2, max_seq=1024, kv_layout="dense", prefix_cache=True))
 
 
 # ---------------------------------------------------------------------------

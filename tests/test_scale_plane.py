@@ -293,20 +293,17 @@ def _mk_engine(chunked: int, seed: int = 7, slots: int = 4):
 
     return LLMEngine(_tiny_cfg(), engine_config=EngineConfig(
         max_slots=slots, max_seq=256, prefill_buckets=(32, 64, 128, 256),
-        kv_layout="paged", page_size=32, decode_block=4, seed=seed,
+        page_size=32, decode_block=4, seed=seed,
         chunked_prefill=chunked,
     ))
 
 
-def test_chunked_prefill_requires_paged_and_page_multiple():
+def test_chunked_prefill_requires_page_multiple():
     from ray_tpu.llm import EngineConfig, LLMEngine
 
-    with pytest.raises(ValueError, match="paged"):
-        LLMEngine(_tiny_cfg(), engine_config=EngineConfig(
-            max_slots=2, chunked_prefill=64))
     with pytest.raises(ValueError, match="multiple"):
         LLMEngine(_tiny_cfg(), engine_config=EngineConfig(
-            max_slots=2, kv_layout="paged", page_size=32, chunked_prefill=48))
+            max_slots=2, page_size=32, chunked_prefill=48))
 
 
 def test_chunked_prefill_interleaves_with_decode_and_matches_unchunked():
@@ -380,7 +377,7 @@ def test_chunked_prefill_with_prefix_cache_partial_hit():
     def mk(chunked):
         return LLMEngine(_tiny_cfg(), engine_config=EngineConfig(
             max_slots=4, max_seq=256, prefill_buckets=(32, 64, 128, 256),
-            kv_layout="paged", page_size=32, decode_block=4, seed=3,
+            page_size=32, decode_block=4, seed=3,
             chunked_prefill=chunked, prefix_cache=True,
         ))
 

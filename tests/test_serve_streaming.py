@@ -191,7 +191,7 @@ def test_llm_sse_streaming_end_to_end(serve_cluster):
             vocab_size=97, d_model=64, n_layers=2, n_heads=4, n_kv_heads=2,
             d_ff=128, max_seq_len=128, attention_impl="reference",
         ),
-        engine_config={"max_slots": 4, "max_seq": 128, "prefill_buckets": (16, 32),
+        engine_config={"max_slots": 4, "max_seq": 128, "page_size": 16, "prefill_buckets": (16, 32),
                        "decode_block": 4},
     )
     serve.run(app, name="llm_sse", route_prefix="/llm")
@@ -241,7 +241,7 @@ def test_llm_abandoned_stream_frees_engine_slot(serve_cluster):
             vocab_size=97, d_model=64, n_layers=2, n_heads=4, n_kv_heads=2,
             d_ff=128, max_seq_len=512, attention_impl="reference",
         ),
-        engine_config={"max_slots": 2, "max_seq": 512, "prefill_buckets": (16,),
+        engine_config={"max_slots": 2, "max_seq": 512, "page_size": 16, "prefill_buckets": (16,),
                        "decode_block": 2},
     )
     handle = serve.run(app, name="llm_abort", http=False)

@@ -310,7 +310,7 @@ def test_traced_llm_request_lays_its_phases_on_its_trace(serve_cluster):
     app = build_llm_app(
         model_config=dict(vocab_size=97, d_model=64, n_layers=2, n_heads=4, n_kv_heads=2,
                           d_ff=128, max_seq_len=128, attention_impl="reference"),
-        engine_config={"max_slots": 4, "max_seq": 128, "prefill_buckets": (16, 32)},
+        engine_config={"max_slots": 4, "max_seq": 128, "page_size": 16, "prefill_buckets": (16, 32)},
     )
     handle = serve.run(app, name="llm_traced", route_prefix="/llm_traced", timeout_s=300)
     port = serve.http_port()

@@ -11,7 +11,7 @@ from ray_tpu.tune.search import grid_search
 @pytest.mark.slow  # heavy battery; tier-1 budget (see CHANGES PR-13)
 def test_pbt_improves_population(tmp_path):
     """PBT on fake v4-16 TPU slices: bad lr trials clone good ones and the
-    whole population converges (BASELINE.md Tune target)."""
+    whole population converges."""
     from ray_tpu.accel.tpu import TPU_POD_TYPE_LABEL, TPU_SLICE_NAME_LABEL, TPU_WORKER_ID_LABEL
     from ray_tpu.core.api import Cluster
     from ray_tpu.train import Checkpoint, RunConfig
