@@ -19,6 +19,25 @@ import time
 from ray_tpu.llm.deployment import LLMServer
 
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+TRACE_POLL_S = 0.05  # how often the traced part looks at the counters
+
+
+def traced_part(limit_s: float, limit_units: int, units, clock=time.monotonic, sleep=time.sleep) -> dict:
+    """Keep the trace open for limit_s seconds, or until `units()` (a count
+    of the device work dispatched so far, read from the replica's counters)
+    has grown by limit_units, whichever comes first: a trace's size, and the
+    time stop_trace takes to write it, follow what it records and not the
+    seconds it was open, so a faster program fills it sooner."""
+    t0, u0, ended_by = clock(), units(), "time"
+    while True:
+        left = limit_s - (clock() - t0)
+        if left <= 0:
+            break
+        if units() - u0 >= limit_units:
+            ended_by = "volume"
+            break
+        sleep(min(TRACE_POLL_S, left))
+    return {"traced_part_s": clock() - t0, "trace_units": units() - u0, "ended_by": ended_by}
 
 
 class BenchLLMServer(LLMServer):
@@ -187,8 +206,17 @@ class BenchLLMServer(LLMServer):
                 compiles.append(self._b["compiles"] - before)
         return {"rounds": len(rounds), "requests": sum(len(p) for _s, _k, p in rounds), "compiles": compiles}
 
-    def bench_trace_start(self, start_at: float, duration_s: float, logdir: str) -> bool:
-        """Trace the device from start_at (CLOCK_MONOTONIC) for duration_s, in
+    def bench_trace_units(self) -> int:
+        """What a trace's size follows: the layer passes dispatched so far, one
+        for each decode step or prefilled request, layer and device (every
+        pass is the decoder block's operations once more on each device, and
+        the trace holds an event for every operation executed)."""
+        b, eng = self._b, self.engine
+        return (b["decode_steps"] + b["prefill_requests"]) * eng.cfg.n_layers * eng.ec.tensor_parallel
+
+    def bench_trace_start(self, start_at: float, limit_s: float, limit_units: int, logdir: str) -> bool:
+        """Trace the device from start_at (CLOCK_MONOTONIC) until limit_s
+        seconds or limit_units of device work have passed (`traced_part`), in
         a thread of this process; returns at once."""
         self._b_annotate = True
         box = {"logdir": logdir, "done": threading.Event()}
@@ -200,29 +228,39 @@ class BenchLLMServer(LLMServer):
 
             from harness import xplane
 
-            time.sleep(max(0.0, start_at - time.monotonic()))
-            box["counters_before"] = self.bench_counters()
-            xplane.start(jax, logdir)
-            with TraceAnnotation("bench.window"):
-                time.sleep(duration_s)
-            jax.profiler.stop_trace()
-            box["counters_after"] = self.bench_counters()
+            try:
+                time.sleep(max(0.0, start_at - time.monotonic()))
+                box["counters_before"] = self.bench_counters()
+                xplane.start(jax, logdir)
+                with TraceAnnotation("bench.window"):
+                    box.update(traced_part(limit_s, limit_units, self.bench_trace_units))
+                box["counters_after"] = self.bench_counters()
+                t = time.monotonic()
+                jax.profiler.stop_trace()
+                box["stop_trace_s"] = time.monotonic() - t
+            except Exception as e:  # the thread's end has to be seen: the driver waits for it
+                box["error"] = f"the trace thread failed: {e!r}"
             box["done"].set()
 
         threading.Thread(target=run, name="bench-trace", daemon=True).start()
         return True
 
-    def bench_trace_result(self) -> dict:
-        """Where the finished trace lies, and the counters at its two ends. The
-        cell's driver reduces it (harness/serve_cell.py): parsing a trace of
-        four devices holds this process's interpreter for longer than the
+    def bench_trace_result(self, wait_s: float) -> dict:
+        """Where the finished trace lies, the counters at the traced part's
+        two ends, how that part ended and what stop_trace cost; or, after
+        wait_s seconds without stop_trace having returned, `pending`: the
+        driver asks again for as long as the run's deadline leaves
+        (serve_cell.await_trace), so that no call holds a thread of this
+        replica for long. The cell's driver reduces the trace: parsing a trace
+        of four devices holds this process's interpreter for longer than the
         serve controller waits for a heartbeat, and it kills the replica."""
         box = self._b_trace
-        if box is None or not box["done"].wait(timeout=120):
-            return {"error": "no finished trace"}
+        if box is None:
+            return {"error": "no trace was started"}
+        if not box["done"].wait(timeout=wait_s):
+            return {"pending": True}
         self._b_annotate = False
-        return {"logdir": box["logdir"], "counters_before": box["counters_before"],
-                "counters_after": box["counters_after"]}
+        return {k: v for k, v in box.items() if k != "done"}
 
     def bench_reference_check(self, prompt: list, served: list, model: dict) -> dict:
         """Were the tokens the served path returned for `prompt` (greedy) the

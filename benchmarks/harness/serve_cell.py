@@ -14,7 +14,21 @@ from harness import schedule
 from harness.cellspec import BENCH_DIR, transformer_kwargs
 
 APP, ROUTE = "bench", "/llm"
-TRACE_S = 6.0  # the traced part of a --trace 1 window
+# The traced part of a --trace 1 window ends at the first of two limits
+# (replica.traced_part). TRACE_S, the time: what every cell has been traced
+# for since PR 23. TRACE_UNITS, the volume, in layer passes
+# (replica.bench_trace_units): 4-5% over the 32,256-32,640 that
+# mistral-7b.backlog-tp4, the largest, records in 6 s on PR 30's program (a
+# trace of 65-66 MB, which stop_trace takes 135 s to write: 4 ms a pass on
+# four chips, 3.4-3.9 on one); the one-chip cells record 9,300-12,400. A
+# faster program reaches it sooner and is traced for less than TRACE_S.
+TRACE_S = 6.0
+TRACE_UNITS = 34_000
+TRACE_ASK_S = 10.0  # one call of bench_trace_result waits this long for stop_trace
+# What a run still does once it has its trace, before run.py's deadline. On
+# four chips: reducing the trace 12-15 s, the reference check 7-8 s (32 s
+# where it compiles), the shutdown 8-17 s (chip runs, PR 32); over twice their sum.
+AFTER_TRACE_S = 150.0
 PROBE = {"prompt_len": 96, "out_len": 12}
 
 
@@ -47,6 +61,32 @@ def warm_group_rounds(engine: dict, bucket: int, seed: int, vocab: int, k_bucket
     return rounds
 
 
+def trace_bytes(logdir: str) -> int:
+    return sum(os.path.getsize(os.path.join(d, f)) for d, _dirs, files in os.walk(logdir) for f in files)
+
+
+def await_trace(ask, give_up_at: float, logdir: str, clock=time.monotonic) -> dict:
+    """The finished trace's record, for which the replica's `stop_trace` is
+    waited for until `give_up_at` (on `clock`): in calls of `ask(seconds)`
+    that each wait at most TRACE_ASK_S in the replica. How long `stop_trace`
+    takes follows the trace's size (about 4 ms a layer pass, 2 s a megabyte), so
+    the wait follows what the run has left, not a constant. A trace that is
+    not there by then is an `error` that says how long it was waited for and
+    how many bytes of it are on disk."""
+    t0 = clock()
+    while True:
+        out = ask(max(0.0, min(TRACE_ASK_S, give_up_at - clock())))
+        if not out.get("pending"):
+            break
+        if clock() >= give_up_at:
+            out = {"error": "stop_trace has not returned"}
+            break
+    out["trace_wait_s"] = clock() - t0
+    if "error" in out:
+        out["error"] += f" (waited {out['trace_wait_s']:.1f} s for it; {trace_bytes(logdir)} bytes of trace on disk)"
+    return out
+
+
 def _post(port: int, tokens: list, max_tokens: int) -> tuple[int, list]:
     import http.client
 
@@ -66,7 +106,8 @@ def _post(port: int, tokens: list, max_tokens: int) -> tuple[int, list]:
 
 
 def run(spec: dict, seed: int, seconds: float, trace: bool, rehearse: bool, t_start: float,
-        workdir: str, say) -> dict:
+        workdir: str, say, deadline: float) -> dict:
+    """`deadline`: when run.py ends the run whatever its state, on time.time()."""
     import ray_tpu as rt
     from ray_tpu import serve
     from ray_tpu.accel.device import backend_initialized
@@ -132,9 +173,10 @@ def run(spec: dict, seed: int, seconds: float, trace: bool, rehearse: bool, t_st
         with open(job_path, "w") as f:
             json.dump(job, f)
         gen = subprocess.Popen([sys.executable, os.path.join(BENCH_DIR, "harness", "loadgen.py"), job_path])
+        trace_dir, trace_s = os.path.join(workdir, "trace"), min(TRACE_S, seconds)
         if trace:
             t_trace = start_at + plan["ramp_s"] + max(0.0, (seconds - TRACE_S) * 0.4)
-            call("bench_trace_start", t_trace, min(TRACE_S, seconds), os.path.join(workdir, "trace"))
+            call("bench_trace_start", t_trace, trace_s, TRACE_UNITS, trace_dir)
         w0 = start_at + plan["ramp_s"]
         # Counters at the window's two ends (the replica answers between steps).
         time.sleep(max(0.0, w0 - time.monotonic()))
@@ -148,12 +190,24 @@ def run(spec: dict, seed: int, seconds: float, trace: bool, rehearse: bool, t_st
             client = json.load(f)
         device = call("bench_device")
         stats = call("stats")
-        traced = call("bench_trace_result", t=300) if trace else None
-        if traced and "logdir" in traced:
+        traced = None
+        if trace:
             from harness import xplane
 
+            give_up_at = time.monotonic() + (deadline - AFTER_TRACE_S - time.time())
+            traced = await_trace(lambda s: call("bench_trace_result", s, t=s + 60), give_up_at, trace_dir)
+            if "error" in traced:
+                raise SystemExit(f"benchmark: the traced run gave no trace: {traced['error']}")
+            say(f"trace: open for {traced['traced_part_s']:.2f} s, ended by {traced['ended_by']} at "
+                f"{traced['trace_units']} layer passes (limits {trace_s} s, {TRACE_UNITS}); "
+                f"stop_trace took {traced['stop_trace_s']:.1f} s, of which the run waited "
+                f"{traced['trace_wait_s']:.1f} s after the load's end")
+            t_reduce = time.monotonic()
             traced.update(xplane.reduce_logdir(traced.pop("logdir")))  # parses a file: no backend, no chip
+            traced["reduce_s"] = time.monotonic() - t_reduce
+        t_check = time.monotonic()
         check = call("bench_reference_check", probe_prompt, probe_out, config, t=900)
+        check_s = time.monotonic() - t_check
         driver_touched_jax = backend_initialized()
     finally:
         serve.shutdown()
@@ -165,7 +219,8 @@ def run(spec: dict, seed: int, seconds: float, trace: bool, rehearse: bool, t_st
     say(f"replica in the window: {json.dumps({k: v for k, v in window.items() if k != 'queue_wait_s'})}")
     say(f"replica: ready in {ready_s:.1f} s (engine init + warm-up {dev0['init_s']:.1f} s, of which "
         f"warm-up {dev0['warmup_s']:.1f} s); buckets warmed {_warmup_buckets(traffic, engine)}; "
-        f"groups warmed before the ramp {json.dumps(warm_groups)}; reference check {json.dumps(check)}")
+        f"groups warmed before the ramp {json.dumps(warm_groups)}; reference check in {check_s:.1f} s "
+        f"{json.dumps(check)}")
     return {"kind": "serve", "plan": plan, "client": client, "window": window, "device": device,
             "traced": traced, "check": check, "setup_s": setup_s, "stats": stats,
             "driver_touched_jax": driver_touched_jax, "seconds": seconds, "traffic": traffic,

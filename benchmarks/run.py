@@ -75,14 +75,19 @@ def child(args) -> None:
     workdir = os.path.join(BENCH_DIR, ".work", _clean_name(args.workload))
     shutil.rmtree(workdir, ignore_errors=True)
     os.makedirs(workdir)
+    more = {}
     if spec["traffic"]["kind"] == "serve":
         from harness import serve_cell as cell
+
+        # A serve cell's trace is written by a thread of the replica while the
+        # driver waits; how long it may wait follows from when this run ends.
+        more["deadline"] = args.t_start + DEADLINE_S
     elif spec["traffic"]["kind"] == "train":
         from harness import train_cell as cell
     else:
         raise SystemExit(f"benchmark: unknown traffic kind {spec['traffic']['kind']!r}")
     result = cell.run(spec, args.seed, args.seconds, bool(args.trace), args.rehearse,
-                      args.t_start, workdir, say)
+                      args.t_start, workdir, say, **more)
     ctx = Context(result, spec["chips"])
 
     if result["kind"] == "serve":
@@ -158,7 +163,9 @@ def child(args) -> None:
             k: [[_clean_name(n), s] for n, s in ctx.traced[k]] for k in ("device_ops", "idle_gaps")}
         say("traced: " + json.dumps({k: ctx.traced[k] for k in
                                      ("module_s", "module_runs", "kernel", "line_names",
-                                      "collective_exposed_s", "xplane_bytes", "op_samples") if k in ctx.traced}))
+                                      "collective_exposed_s", "xplane_bytes", "stop_trace_s", "traced_part_s",
+                                      "ended_by", "trace_units", "trace_wait_s", "reduce_s", "op_samples")
+                                     if k in ctx.traced}))
     say(RESULT_TAG + json.dumps(line))
 
 
