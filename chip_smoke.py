@@ -42,6 +42,8 @@ CHIP = dict(
     model=MODEL,
     flash=dict(B=2, S=2048, H=16, KV=4, D=64, tile=1024),
     paged=dict(B=32, H=16, KV=4, D=64, page=128, pages_per_seq=16),
+    # a chip's share of the four-chip serve cell: 2 KV heads, 32 pages a slot
+    paged_sparse=dict(B=32, H=8, KV=2, D=128, page=128, pages_per_seq=32),
     train=dict(remat=True, remat_policy="dots", attention_block_q=1024,
                attention_block_k=1024),
     batch_per_chip=16, seq=2048, steps=5,
@@ -55,6 +57,7 @@ REHEARSAL = dict(
     model=TOY_MODEL,
     flash=dict(B=1, S=256, H=4, KV=2, D=64, tile=128),
     paged=dict(B=4, H=4, KV=2, D=64, page=32, pages_per_seq=4),
+    paged_sparse=dict(B=6, H=4, KV=2, D=64, page=16, pages_per_seq=8),
     train=dict(remat=True, remat_policy="dots", attention_block_q=128,
                attention_block_k=128),
     batch_per_chip=2, seq=256, steps=5,
@@ -232,37 +235,54 @@ def phase_kernels(sz: dict, rehearsal: bool) -> dict:
         for name, g, w in zip(("dq", "dk", "dv"), got, want):
             check(f"flash {name}{label}", g, w)
 
-    # paged decode attention: ragged lengths, a one-token and a full
-    # sequence, and a partial last page; the last of three layers' pools,
-    # told to the kernel as the engine's layer loop tells it (a traced
-    # index), and the token's own K/V written into the pools by the call
+    # paged decode attention: the last of three layers' pools, told to the
+    # kernel as the engine's layer loop tells it (a traced index), and the
+    # token's own K/V written into the pools by the call. Dead table entries,
+    # and the whole row of a slot in `empty`, name page 0.
+    def paged_check(label, pg, lens, empty=()):
+        B, H, KV, D, ps, ppseq = (pg[k] for k in ("B", "H", "KV", "D", "page", "pages_per_seq"))
+        used = [0 if b in empty else -(-int(lens[b]) // ps) for b in range(B)]
+        n_pages, n_layers = sum(used) + 1, 3
+        kq, kk, kv_, kn, vn = jax.random.split(jax.random.PRNGKey(1), 5)
+        q = jax.random.normal(kq, (B, H, D), jnp.bfloat16)
+        kp = jax.random.normal(kk, (n_layers, KV, n_pages, ps, D), jnp.bfloat16)
+        vp = jax.random.normal(kv_, (n_layers, KV, n_pages, ps, D), jnp.bfloat16)
+        k_new = jax.random.normal(kn, (B, KV, D), jnp.bfloat16)
+        v_new = jax.random.normal(vn, (B, KV, D), jnp.bfloat16)
+        table = np.zeros((B, ppseq), np.int32)
+        free = iter(np.random.default_rng(0).permutation(np.arange(1, n_pages)))
+        for b in range(B):
+            table[b, :used[b]] = [next(free) for _ in range(used[b])]
+        lens, table, layer = jnp.asarray(lens, jnp.int32), jnp.asarray(table), jnp.int32(n_layers - 1)
+
+        def paged(q, k_new, v_new, kp, vp, lens, table, layer):
+            return paged_attention(q, k_new, v_new, kp, vp, lens, table, layer, interpret=interpret)
+
+        args = (q, k_new, v_new, kp, vp, lens, table, layer)
+        got = timed_compile(paged, *args)(*args)
+        want = jax.jit(paged_attention_reference)(*f32(q, k_new, v_new, kp, vp), lens, table, layer)
+        # empty slots share one row of page 0: the reference attends the row
+        # as the last of them left it, the kernel each its own, and nobody
+        # reads either; the pools end as the last one wrote them in both
+        live = np.array([b for b in range(B) if b not in empty])
+        check(f"paged decode{label}", got[0][live], want[0][live])
+        for name, g, w in zip(("K", "V"), got[1:], want[1:]):
+            check(f"paged decode{label}: {name} pool", g, w)
+
+    # ragged lengths, a one-token and a full sequence, and a partial last page
     pg = sz["paged"]
-    B, H, KV, D, ps, ppseq = (pg[k] for k in ("B", "H", "KV", "D", "page", "pages_per_seq"))
-    n_pages, n_layers = B * ppseq + 1, 3
-    kq, kk, kv_, kn, vn = jax.random.split(jax.random.PRNGKey(1), 5)
-    q = jax.random.normal(kq, (B, H, D), jnp.bfloat16)
-    kp = jax.random.normal(kk, (n_layers, KV, n_pages, ps, D), jnp.bfloat16)
-    vp = jax.random.normal(kv_, (n_layers, KV, n_pages, ps, D), jnp.bfloat16)
-    k_new = jax.random.normal(kn, (B, KV, D), jnp.bfloat16)
-    v_new = jax.random.normal(vn, (B, KV, D), jnp.bfloat16)
-    rng = np.random.default_rng(0)
-    lens = rng.integers(1, ppseq * ps + 1, B).astype(np.int32)
-    lens[:3] = (1, ppseq * ps, (ppseq // 2) * ps + ps // 3)
-    table = np.zeros((B, ppseq), np.int32)  # dead entries -> page 0
-    free = rng.permutation(np.arange(1, n_pages))
-    for b in range(B):
-        used = -(-int(lens[b]) // ps)
-        table[b, :used] = free[b * ppseq: b * ppseq + used]
-    lens, table, layer = jnp.asarray(lens), jnp.asarray(table), jnp.int32(n_layers - 1)
-
-    def paged(q, k_new, v_new, kp, vp, lens, table, layer):
-        return paged_attention(q, k_new, v_new, kp, vp, lens, table, layer, interpret=interpret)
-
-    args = (q, k_new, v_new, kp, vp, lens, table, layer)
-    got = timed_compile(paged, *args)(*args)
-    want = jax.jit(paged_attention_reference)(*f32(q, k_new, v_new, kp, vp), lens, table, layer)
-    for name, g, w in zip(("paged decode", "paged decode: K pool", "paged decode: V pool"), got, want):
-        check(name, g, w)
+    full = pg["pages_per_seq"] * pg["page"]
+    lens = np.random.default_rng(0).integers(1, full + 1, pg["B"])
+    lens[:3] = (1, full, full // 2 + pg["page"] // 3)
+    paged_check("", pg, lens)
+    # the walk's edge: most slots empty (length 1 on the dead page), beside
+    # a full table, exactly one page, and a page's first and last row
+    pg = sz["paged_sparse"]
+    ps, lens = pg["page"], np.ones(pg["B"], np.int64)
+    live = {1: pg["pages_per_seq"] * ps, 2: ps, 4: ps + 1, pg["B"] - 1: 3 * ps}
+    for b, n in live.items():
+        lens[b] = n
+    paged_check(", sparse", pg, lens, empty=set(range(pg["B"])) - set(live))
 
     # One dispatch's round trip: a trivial program, dispatched and awaited.
     bump = jax.jit(lambda x: x + 1)

@@ -11,11 +11,14 @@ llm/_internal/serve/engines/vllm/vllm_engine.py:174):
 - Paged KV (vLLM's core idea, re-expressed for XLA): each sequence owns a
   page list; prefill scatters K/V into its pages; decode carries the pool
   whole through its loops, and the Pallas paged-attention kernel
-  (ops/paged_attention.py — scalar-prefetch page-table walk at a layer
-  index, no materialized gather, no slice of the pool) writes one token's
-  row in place at (layer, page[len // ps], len % ps) and attends through
-  the page table. Memory scales with reserved pages, not slots × max_seq,
-  and admission is page-budgeted.
+  (ops/paged_attention.py — no materialized gather, no slice of the pool)
+  writes one token's row in place at (layer, page[len // ps], len % ps) and
+  attends through the page table. Its grid is the batch's live pages, a
+  list the decode step builds once from the lengths and hands to every
+  layer (``live_pages``), so a call costs the tokens in the cache; before
+  PR 33 it was the whole table, slots × pages a slot, live or dead. Memory
+  scales with reserved pages, not slots × max_seq, and admission is
+  page-budgeted.
 - Continuous batching is the host loop: between device programs, finished
   slots retire (their pages return to the free list) and queued requests
   prefill into free slots. Prefill groups are dispatched back-to-back
@@ -61,7 +64,7 @@ from jax.sharding import NamedSharding, PartitionSpec as _P
 
 from ray_tpu.llm.sampling import SamplingParams, sample_batch
 from ray_tpu.models.transformer import TransformerConfig, _rms_norm, decoder_block, init_params, param_logical_axes
-from ray_tpu.ops.paged_attention import paged_attention, paged_attention_reference
+from ray_tpu.ops.paged_attention import live_pages, paged_attention, paged_attention_reference
 from ray_tpu.util import tracing as _tracing
 
 # The seams of LLMEngine.step, in the order a step passes them (PERF.md
@@ -514,15 +517,18 @@ class LLMEngine:
         ps = self.ec.page_size
         flat = k_pages.shape  # [L, KV, total_pages * ps, Hd], as every other program has it
         pool = (cfg.n_layers, cfg.kv_heads, -1, ps, cfg.head_dim)
-        # The kernel where it can run; elsewhere the einsum reference, which
-        # GSPMD partitions as-is under TP.
-        paged_attend = (
-            functools.partial(paged_attention, mesh=self.mesh)
-            if jax.default_backend() == "tpu" else paged_attention_reference
-        )
+        on_tpu = jax.default_backend() == "tpu"
 
         def one_step(carry, step_key):
             kp, vp, last, lens = carry
+            # The kernel where it can run, on the step's walk of live pages
+            # (built here, once for all layers: lengths change between steps
+            # and not between layers); elsewhere the einsum reference, which
+            # GSPMD partitions as-is under TP.
+            seen = lens + 1  # the kernel's lengths count the step's own token
+            paged_attend = functools.partial(
+                paged_attention, mesh=self.mesh, walk=live_pages(seen, page_tables, ps),
+            ) if on_tpu else paged_attention_reference
             with jax.named_scope("embed"):
                 x = params["embed"].astype(cfg.dtype)[last][:, None, :]  # [B,1,D]
 
@@ -536,7 +542,7 @@ class LLMEngine:
                         # pages (page_tables[b, lens // ps], offset lens % ps)
                         o, kp2, vp2 = paged_attend(
                             q[:, 0], k_new[:, 0], v_new[:, 0], kp, vp,
-                            lens + 1, page_tables, layer,
+                            seen, page_tables, layer,
                         )  # o: [B, H, Hd]
                     return o[:, None], (kp2, vp2)
 
@@ -551,7 +557,11 @@ class LLMEngine:
             with jax.named_scope("sample"):
                 toks = sample_batch(logits.astype(jnp.float32), temps, top_ps, top_ks,
                                     step_key, cap=self.ec.sample_topk_cap)
-            return (kp, vp, toks, lens + 1), toks
+            # A slot with no pages (empty, or masked while it prefills) stays
+            # at its length: the kernel's cost follows the lengths, and such a
+            # slot costs it one step on dead page 0 however long the mirrors
+            # go without a resync.
+            return (kp, vp, toks, jnp.where(page_tables[:, 0] > 0, lens + 1, lens)), toks
 
         keys = jax.random.split(key, n_steps)
         (k_pages, v_pages, last, lengths), toks = jax.lax.scan(
@@ -990,7 +1000,7 @@ class LLMEngine:
         step's record goes to the ring that LLMServer.stats() returns."""
         ph = self._phases
         ph.begin("admit", waiting=len(self.waiting), n_admitted=0, n_prefill=0,
-                 block=0, active=0)
+                 block=0, active=0, live_pages=0)
         try:
             return self._step(ph)
         finally:
@@ -1296,6 +1306,7 @@ class LLMEngine:
                             retired = True
         if toks is not None:
             ph.rec["block"] = n
+            ph.rec["live_pages"] = self._live_pages(active, n)
             ph.to("decode_fetch")
             block_toks = np.asarray(jax.device_get(toks))  # [n, B]
             ph.to("emit")
@@ -1330,6 +1341,19 @@ class LLMEngine:
                     last[i] = s.emitted[-1]
             self.d_last = jnp.asarray(last)
         return events
+
+    def _live_pages(self, active: list[int], n: int) -> int:
+        """The page steps the paged kernel walks in a decode block of ``n``
+        steps, a layer: every slot's ceil(length / page_size) at each step,
+        the step's own token counted; a slot that is not ``active`` (empty,
+        or masked while it prefills) costs the one step on dead page 0 it is
+        held at. Over n x max_slots x (max_seq / page_size) it is the share
+        of the page table the kernel walks. From the host's mirror of the
+        lengths as they stood when the block was dispatched."""
+        ps = self.ec.page_size
+        held = self.ec.max_slots - len(active)
+        seen = self.lengths[active][None, :] + np.arange(1, n + 1)[:, None]
+        return int(np.minimum(-(-seen // ps), self.ppseq).sum()) + held * n
 
     def _maybe_finish(self, i: int, events: dict) -> bool:
         slot = self.slots[i]

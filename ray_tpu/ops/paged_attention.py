@@ -19,10 +19,18 @@ add to each page's address, and the pools are aliased to the call's outputs:
 the token's row is spliced into its page where the page lies in VMEM for
 attention anyway, and the few rows around it are stored back.
 
-Kernel shape: grid (B, pages_per_seq), online-softmax accumulator in VMEM
-scratch across the page axis (innermost, "arbitrary"), pages past a
-sequence's length predicated off entirely (their DMAs still target a valid
-page — dead table entries point at page 0 — but compute is skipped).
+Kernel shape: a grid of one axis whose steps are the batch's live pages,
+sequence after sequence and each one's pages in ascending order
+(``live_pages``: every step's sequence, page and addresses and their count,
+through scalar prefetch; the count is the grid's length, a runtime value). A
+call costs what the tokens in the cache cost, ceil(length / page_size) steps
+a sequence, and a page beyond a sequence's length costs nothing: no step, no
+DMA, no predicate. Before PR 33 the grid was the whole table, (B,
+pages_per_seq) = 32 x 16 or 32 x 32 in the serve cells, and a step past a
+sequence's length skipped its arithmetic and still cost 0.4 us: 0.21 ms a
+call with 41 live steps of 512, 44-46% of the device's busy time in every
+serve cell (PERF.md section 6, PR 33). The online-softmax accumulator lives
+in VMEM scratch across a sequence's steps.
 
 The reference framework delegates paged KV to vLLM
 (llm/_internal/serve/engines/vllm/vllm_engine.py:174); this is the TPU-native
@@ -82,38 +90,95 @@ def paged_attention_reference(q, k_new, v_new, k_pages, v_pages, lengths, page_i
 # Pallas kernel
 # ---------------------------------------------------------------------------
 
-def _newest_page(length, ps, n_pages):
-    """Which of a sequence's pages holds its current position (the last of
-    `length`); a sequence run past its table stays inside its last page."""
-    return jnp.minimum((length - 1) // ps, n_pages - 1)
+def _page_range(length, ps, n_pages):
+    """(first, last): the pages of its table a sequence of `length` tokens
+    (>= 1) attends, both ends included: last - first + 1 page steps, which is
+    ceil(length / ps) while the sequence is inside its table. ``last`` holds
+    the current position, the last of `length` (a sequence run past its table
+    stays inside its last page); ``first`` is 0, and an attention window
+    (ROADMAP M2) is a later ``first`` here and a mask on its columns. Never
+    empty, whatever `length`: a sequence with no step would leave its row of
+    the output unwritten."""
+    first = jnp.zeros_like(length)
+    return first, jnp.clip((length - 1) // ps, first, n_pages - 1)
 
 
-def _paged_kernel(lens_ref, pidx_ref, layer_ref, q_ref, kn_ref, vn_ref, k_ref, v_ref,
-                  o_ref, ko_ref, vo_ref, m_scr, l_scr, acc_scr, *, scale, ps, n_pages, kv):
-    """Grid (B, n_pages): ONE page DMA carries ALL kv heads (page ids are
-    shared across heads in the pool layout), and the head loop unrolls
-    statically inside the step — 4-8x fewer, larger DMAs than a per-head
-    grid, which is what the decode path's throughput is bound by.
+WINDOW_ROWS = 16  # of the token's page, stored back: one packed tile of bf16
+
+
+def live_pages(lengths, page_indices, page_size):
+    """The kernel's walk, from lengths [B] (the current token counted) and
+    the page table [B, n_pages]: six int32 arrays, the first five of
+    B * n_pages entries. Entry t < count is the t-th page step:
+
+    - ``slots[t]``, ``pages[t]``: page ``pages[t]`` of sequence ``slots[t]``'s
+      table, sequences in order and each one's pages ascending over its
+      ``_page_range``;
+    - ``where[t]``: that page in the pool;
+    - ``win_page[t]``, ``win_row[t]``: where the sequence's current token
+      goes, as the pool page and the block of WINDOW_ROWS rows in it;
+    - ``count`` [1]: the number of steps.
+
+    Everything an index map needs is an entry here, so a grid step's address
+    arithmetic is a handful of scalar loads (worth 5-14% of a call beside
+    maps that derive it from lengths and table; PERF.md section 6, PR 33).
+    Plain jnp, and the same for every layer of a decode step: a caller with
+    several calls on the same lengths and table builds it once and hands it
+    to each."""
+    B, n_pages = page_indices.shape
+    lengths, table = lengths.astype(jnp.int32), page_indices.astype(jnp.int32)
+    first, last = _page_range(lengths, page_size, n_pages)
+    j = jnp.arange(n_pages, dtype=jnp.int32)[None, :]
+    live = (first[:, None] <= j) & (j <= last[:, None])  # [B, n_pages]
+    win_page = jnp.sum(jnp.where(j == last[:, None], table, 0), axis=1)  # table[b, last[b]]
+    win_row = (lengths - 1) % page_size // min(page_size, WINDOW_ROWS)
+
+    def of_its_sequence(x):  # [B] -> an entry a table entry
+        return jnp.broadcast_to(x[:, None], table.shape).reshape(-1)
+
+    # The table's live entries first, in the table's order (a stable sort on
+    # one bit, the lists riding along: no gather, which costs a TPU program
+    # megabytes of temporaries for arrays this small). Past count come the
+    # dead entries, which nothing visits and which are valid all the same.
+    _, entry, where, win_page, win_row = jax.lax.sort(
+        (jnp.where(live, 0, 1).reshape(-1), jnp.arange(B * n_pages, dtype=jnp.int32),
+         table.reshape(-1), of_its_sequence(win_page), of_its_sequence(win_row)),
+        num_keys=1, is_stable=True,
+    )
+    count = jnp.sum(live, dtype=jnp.int32).reshape(1)
+    return entry // n_pages, entry % n_pages, where, win_page, win_row, count
+
+
+def _paged_kernel(lens_ref, layer_ref, slots_ref, pages_ref, where_ref, win_page_ref,
+                  win_row_ref, q_ref, kn_ref, vn_ref, k_ref, v_ref, o_ref, ko_ref, vo_ref,
+                  m_scr, l_scr, acc_scr, *, scale, ps, n_pages, kv):
+    """Grid (count,), the live pages of the batch (``live_pages``): step t is
+    page ``pages_ref[t]`` of sequence ``slots_ref[t]``. ONE page DMA carries
+    ALL kv heads (page ids are shared across heads in the pool layout), and
+    the head loop unrolls statically inside the step — 4-8x fewer, larger
+    DMAs than a per-head grid.
 
     ``ko_ref`` / ``vo_ref`` are a window of rows of the sequence's newest
     page in the pools the inputs alias: stored once a sequence, with the
     current token's row (``kn_ref`` / ``vn_ref``, f32) spliced in.
-    ``layer_ref`` is read by the index maps alone."""
+    ``layer_ref``, ``where_ref`` and the window's two lists are read by the
+    index maps alone."""
     from jax.experimental import pallas as pl
 
-    b = pl.program_id(0)
-    j = pl.program_id(1)
+    t = pl.program_id(0)
+    b = slots_ref[t]
+    j = pages_ref[t]
+    length = lens_ref[b]
+    start = j * ps
+    _, last = _page_range(length, ps, n_pages)
 
-    @pl.when(j == 0)
+    @pl.when((t == 0) | (slots_ref[jnp.maximum(t - 1, 0)] != b))
     def _init():
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    length = lens_ref[b]
-    start = j * ps
-
-    @pl.when(j == _newest_page(length, ps, n_pages))
+    @pl.when(j == last)
     def _write_the_token():
         # The token's row goes into its window of `win` rows twice: into the
         # page as it lies in VMEM, where the attention below reads it, and
@@ -122,38 +187,36 @@ def _paged_kernel(lens_ref, pidx_ref, layer_ref, q_ref, kn_ref, vn_ref, k_ref, v
         # packed-row mask, and the row comes as a plain f32 sublane.
         win = ko_ref.shape[2]
         row = (length - 1) % ps
-        first = pl.multiple_of(row // win * win, win)
-        here = jax.lax.broadcasted_iota(jnp.int32, ko_ref.shape[2:], 0) == row - first
+        top = pl.multiple_of(row // win * win, win)
+        here = jax.lax.broadcasted_iota(jnp.int32, ko_ref.shape[2:], 0) == row - top
         for page_ref, new_ref, out_ref in ((k_ref, kn_ref, ko_ref), (v_ref, vn_ref, vo_ref)):
             for h in range(kv):
-                window = page_ref[h, 0, pl.ds(first, win), :].astype(jnp.float32)
+                window = page_ref[h, 0, pl.ds(top, win), :].astype(jnp.float32)
                 window = jnp.where(here, new_ref[0, pl.ds(h, 1), :], window).astype(out_ref.dtype)
-                page_ref[h, 0, pl.ds(first, win), :] = window
+                page_ref[h, 0, pl.ds(top, win), :] = window
                 out_ref[h, 0] = window
 
-    @pl.when(start < length)
-    def _compute():
-        for h in range(kv):  # static unroll: kv is small (2-8)
-            q = q_ref[0, h]  # [Gp, D]
-            k = k_ref[h, 0]  # [ps, D]
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-            ) * scale  # [Gp, ps]
-            cols = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(cols < length, s, NEG_INF)
-            m_prev = m_scr[h, :, 0]
-            m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1))
-            alpha = jnp.exp(m_prev - m_cur)
-            p = jnp.exp(s - m_cur[:, None])
-            l_cur = l_scr[h, :, 0] * alpha + jnp.sum(p, axis=1)
-            acc_scr[h] = acc_scr[h] * alpha[:, None] + jax.lax.dot_general(
-                p.astype(v_ref.dtype), v_ref[h, 0], (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            m_scr[h] = jnp.broadcast_to(m_cur[:, None], m_scr.shape[1:])
-            l_scr[h] = jnp.broadcast_to(l_cur[:, None], l_scr.shape[1:])
+    for h in range(kv):  # static unroll: kv is small (2-8)
+        q = q_ref[0, h]  # [Gp, D]
+        k = k_ref[h, 0]  # [ps, D]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        ) * scale  # [Gp, ps]
+        cols = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(cols < length, s, NEG_INF)
+        m_prev = m_scr[h, :, 0]
+        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1))
+        alpha = jnp.exp(m_prev - m_cur)
+        p = jnp.exp(s - m_cur[:, None])
+        l_cur = l_scr[h, :, 0] * alpha + jnp.sum(p, axis=1)
+        acc_scr[h] = acc_scr[h] * alpha[:, None] + jax.lax.dot_general(
+            p.astype(v_ref.dtype), v_ref[h, 0], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        m_scr[h] = jnp.broadcast_to(m_cur[:, None], m_scr.shape[1:])
+        l_scr[h] = jnp.broadcast_to(l_cur[:, None], l_scr.shape[1:])
 
-    @pl.when(j == n_pages - 1)
+    @pl.when(j == last)
     def _finalize():
         for h in range(kv):
             l = l_scr[h, :, 0]
@@ -161,40 +224,39 @@ def _paged_kernel(lens_ref, pidx_ref, layer_ref, q_ref, kn_ref, vn_ref, k_ref, v
             o_ref[0, h] = (acc_scr[h] / l_safe[:, None]).astype(o_ref.dtype)
 
 
-def _paged_pallas(q, k_new, v_new, k_pages, v_pages, lengths, page_indices, layer,
+def _paged_pallas(q, k_new, v_new, k_pages, v_pages, lengths, n_pages, layer, walk,
                   *, scale, interpret):
     """q: [B, KV, Gp, D] (Gp >= 8, sublane-padded); k_new/v_new: f32
-    [B, KV, D]; k_pages/v_pages: [L, KV, P_total, ps, D]; layer: int32[1];
+    [B, KV, D]; k_pages/v_pages: [L, KV, P_total, ps, D]; n_pages: the
+    table's width; layer: int32[1]; walk: ``live_pages`` of lengths and table
     -> (o [B, KV, Gp, D], k_pages, v_pages), the pools aliased to the inputs."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, KV, Gp, D = q.shape
     ps = k_pages.shape[3]
-    n_pages = page_indices.shape[1]
+    slots, pages, where, win_page, win_row, count = walk
 
-    def whole(b, j, lens, pidx, layer):
-        return (b, 0, 0, 0)
+    def whole(t, lens, layer, slots, pages, where, win_page, win_row):
+        return (slots[t], 0, 0, 0)
 
-    def token(b, j, lens, pidx, layer):
-        return (b, 0, 0)
+    def token(t, lens, layer, slots, pages, where, win_page, win_row):
+        return (slots[t], 0, 0)
 
-    def page(b, j, lens, pidx, layer):
-        return (layer[0], 0, pidx[b, j], 0, 0)
+    def page(t, lens, layer, slots, pages, where, win_page, win_row):
+        return (layer[0], 0, where[t], 0, 0)
+
+    def token_window(t, lens, layer, slots, pages, where, win_page, win_row):
+        return (layer[0], 0, win_page[t], win_row[t], 0)
 
     # The stored window: one packed tile of rows (16 of bf16, and a multiple
     # of f32's 8), so a sequence's write-back is a sliver of its page.
-    win = min(ps, 16)
-
-    def token_window(b, j, lens, pidx, layer):
-        newest = _newest_page(lens[b], ps, n_pages)
-        return (layer[0], 0, pidx[b, newest], (lens[b] - 1) % ps // win, 0)
-
+    win = min(ps, WINDOW_ROWS)
     one_page = (None, KV, 1, ps, D)  # the layer axis squeezed
     one_window = (None, KV, 1, win, D)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(B, n_pages),
+        num_scalar_prefetch=7,
+        grid=(count[0],),  # a runtime value: one compiled call serves every batch
         in_specs=[
             pl.BlockSpec((1, KV, Gp, D), whole),
             pl.BlockSpec((1, KV, D), token),
@@ -224,25 +286,28 @@ def _paged_pallas(q, k_new, v_new, k_pages, v_pages, lengths, page_indices, laye
             jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype),
             jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype),
         ],
-        # operands count the three scalar-prefetch arrays: 6, 7 are the pools
-        input_output_aliases={6: 1, 7: 2},
+        # operands count the seven scalar-prefetch arrays: 10, 11 are the pools
+        input_output_aliases={10: 1, 11: 2},
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
+            # in order: a sequence's pages accumulate into one scratch
+            dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
-    )(lengths, page_indices, layer, q, k_new, v_new, k_pages, v_pages)
+    )(lengths, layer, slots, pages, where, win_page, win_row, q, k_new, v_new, k_pages, v_pages)
 
 
 def paged_attention(q, k_new, v_new, k_pages, v_pages, lengths, page_indices, layer,
-                    scale=None, interpret=False, mesh=None, head_axis="tensor"):
+                    scale=None, interpret=False, mesh=None, head_axis="tensor", walk=None):
     """Paged decode attention. q: [B, H, D] (one query token per sequence);
     k_new/v_new: [B, KV, D], that token's K and V; k_pages/v_pages:
     [L, KV, P_total, page_size, D], every layer's pool; lengths: [B] valid
-    tokens per sequence including the current one; page_indices:
+    tokens per sequence including the current one (so >= 1); page_indices:
     [B, pages_per_seq] (entries past a sequence's length must still be valid
     page ids — use 0); layer: which of the L pools to attend (an int or a
     traced int32 scalar: the engine's layer loop passes its counter, so one
-    compiled call serves every layer).
+    compiled call serves every layer); walk: ``live_pages`` of these
+    lengths and this table, for a caller that makes several calls on them (a
+    decode step's layers) and builds it once; built here without it.
 
     Returns (o [B, H, D], k_pages, v_pages): the token's K/V lies at position
     lengths - 1 of each sequence's pages in the returned pools, which alias
@@ -261,19 +326,24 @@ def paged_attention(q, k_new, v_new, k_pages, v_pages, lengths, page_indices, la
     0). Without the explicit map jax refuses to lower the call: GSPMD cannot
     partition a Mosaic kernel.
     """
+    if walk is None:
+        walk = live_pages(lengths, page_indices, k_pages.shape[3])
     if mesh is not None and mesh.shape.get(head_axis, 1) > 1:
         from jax.sharding import PartitionSpec as P
 
-        inner = functools.partial(paged_attention, scale=scale, interpret=interpret)
+        def inner(*args):  # every device walks the same pages, of its own heads
+            return paged_attention(*args[:8], scale=scale, interpret=interpret, walk=args[8:])
+
         heads, pool = P(None, head_axis, None), P(None, head_axis, None, None, None)
         return jax.shard_map(
             inner,
             mesh=mesh,
-            in_specs=(heads, heads, heads, pool, pool, P(None), P(None, None), P()),
+            in_specs=(heads, heads, heads, pool, pool, P(None), P(None, None), P(),
+                      *(P(None),) * len(walk)),
             out_specs=(heads, pool, pool),
             check_vma=False,
         )(q, k_new, v_new, k_pages, v_pages, lengths, page_indices,
-          jnp.asarray(layer, jnp.int32))
+          jnp.asarray(layer, jnp.int32), *walk)
     B, H, D = q.shape
     KV = k_pages.shape[1]
     if H % KV:
@@ -298,7 +368,7 @@ def paged_attention(q, k_new, v_new, k_pages, v_pages, lengths, page_indices, la
 
     o, k_pages, v_pages = _paged_pallas(
         qg, as_rows(k_new, k_pages), as_rows(v_new, v_pages), k_pages, v_pages,
-        lengths.astype(jnp.int32), page_indices.astype(jnp.int32),
-        jnp.asarray(layer, jnp.int32).reshape(1), scale=scale, interpret=interpret,
+        lengths.astype(jnp.int32), page_indices.shape[1],
+        jnp.asarray(layer, jnp.int32).reshape(1), walk, scale=scale, interpret=interpret,
     )
     return o[:, :, :group].reshape(B, H, D), k_pages, v_pages

@@ -1,0 +1,70 @@
+"""The paged decode call compiled by the TPU's own compiler, for a v5e that is
+described and not attached (no chip, no chip time): what interpret mode cannot
+show. Mosaic has to lower a grid whose length is a runtime value, index maps
+that read scalar-prefetched lists, and pools aliased to the outputs, at the
+shapes a chip holds in the serve cells and at a head size under a lane tile.
+A compile that passes is not a chip run and says nothing about results or
+speed; tests/test_paged_attention.py holds the results, chip_smoke.py the chip.
+
+The topology is described inside a fixture and never at import: one process
+at a time may hold the TPU's library, and every xdist worker imports this file.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+
+from ray_tpu.ops import paged_attention as pa
+
+SHAPES = {
+    # a chip's share: B, H, KV, D, page_size, pages a sequence, layers, pool pages
+    "internlm2-1.8b": (32, 16, 8, 128, 128, 16, 24, 384),
+    "mistral-7b-v0.3, a chip of four": (32, 8, 2, 128, 128, 32, 32, 1088),
+    "head size 64 (chip_smoke's model)": (32, 16, 4, 64, 128, 16, 12, 96),
+}
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here, or another process holds it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def no_compile_cache():
+    """A compile for a described device is written to the persistent cache
+    and cannot be read back without the device: keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("shape", sorted(SHAPES))
+def test_the_paged_call_lowers_for_a_v5e(shape, one_chip, no_compile_cache, monkeypatch):
+    B, H, KV, D, ps, n_pages, L, P_total = SHAPES[shape]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the call asks before it lowers
+
+    def arr(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    pool = arr((L, KV, P_total, ps, D), jnp.bfloat16)
+    args = (arr((B, H, D), jnp.bfloat16), arr((B, KV, D), jnp.bfloat16), arr((B, KV, D), jnp.bfloat16),
+            pool, pool, arr((B,), jnp.int32), arr((B, n_pages), jnp.int32), arr((), jnp.int32))
+    compiled = jax.jit(pa.paged_attention, donate_argnums=(3, 4)).lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1  # one Mosaic call, the walk beside it plain XLA
+    if D % 128 == 0:
+        # nothing but the call's operands: no copy of a pool (a pool is
+        # 0.3-2.4 GB here). A head size under a lane tile is padded by the
+        # call's operand layout, before PR 33 as after it.
+        assert compiled.memory_analysis().temp_size_in_bytes < 4 << 20

@@ -502,6 +502,48 @@ def test_step_phases_sum_to_no_more_than_the_step():
     assert snap["phase_n"]["prefix_lookup"] == 6
 
 
+def test_step_record_counts_the_page_steps_the_decode_block_walks():
+    """``live_pages`` of a step's record against the lengths and tables the
+    decode program was handed: each of the block's steps, every slot's
+    ceil(length / page_size) with the step's own token counted, an empty slot
+    the one step it is held at; a step with no block records 0."""
+    ec = EngineConfig(**ENGINE_KW)
+    ps, table = ec.page_size, ec.max_seq // ec.page_size
+    eng = LLMEngine(CFG, engine_config=ec)
+    handed = []
+    decode = eng._decode_jit
+
+    def spy(*args):
+        handed.append((np.array(args[4]), np.array(args[5]), args[6]))  # copies: the mirrors' buffers are reused
+        out = decode(*args)
+        # a slot without pages is held at its length, the others advance
+        np.testing.assert_array_equal(
+            np.asarray(out[4]), handed[-1][0] + args[6] * (handed[-1][1][:, 0] > 0))
+        return out
+
+    eng._decode_jit = spy
+    # a page's first rows, exactly one full page, one row short of two pages
+    for rid, n_prompt in (("a", 3), ("b", ps), ("c", 2 * ps - 1)):
+        eng.add_request(rid, np.arange(n_prompt, dtype=np.int32) % 97, 40)
+    _drain(eng)
+    eng.step()  # nothing to do: no block
+    steps = eng.trace_snapshot()["steps"]
+    blocks = [s for s in steps if s["block"]]
+    assert len(blocks) == len(handed) > 3
+    assert sorted(handed[0][0]) == [0, 3, ps, 2 * ps - 1]  # the fourth slot is empty
+    for rec, (lens, tables, n) in zip(blocks, handed):
+        live = tables[:, 0] > 0
+        seen = np.where(live, lens + np.arange(1, n + 1)[:, None], 1)
+        assert rec["block"] == n and rec["active"] == live.sum()
+        assert rec["live_pages"] == np.minimum(-(-seen // ps), table).sum()
+        assert n * ec.max_slots <= rec["live_pages"] <= n * ec.max_slots * table
+    n = handed[0][2]
+    assert blocks[0]["live_pages"] == sum(
+        1 + -(-(3 + s) // ps) + -(-(ps + s) // ps) + -(-(2 * ps - 1 + s) // ps) for s in range(1, n + 1))
+    assert [s["live_pages"] for s in steps if not s["block"]] == [0] * (len(steps) - len(blocks))
+    assert steps[-1]["block"] == 0 and steps[-1]["live_pages"] == 0
+
+
 def test_trace_rings_are_bounded_and_count_drops(monkeypatch):
     from ray_tpu.llm import engine as engine_mod
 

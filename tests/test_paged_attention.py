@@ -10,6 +10,7 @@ import numpy as np
 import pytest
 
 from ray_tpu.ops.paged_attention import (
+    live_pages,
     paged_attention,
     paged_attention_reference,
 )
@@ -19,20 +20,23 @@ L = 3  # layers in the pool, each with contents of its own
 LAYERS = (0, L - 1)
 
 
-def _make_case(B, H, KV, D, ps, ppseq, lengths, layer, seed=0):
+def _make_case(B, H, KV, D, ps, ppseq, lengths, layer, seed=0, empty=()):
     """Random paged cache of L layers where sequence b owns pages
     [b*ppseq .. ) shuffled, plus a contiguous copy of `layer` for the
     oracle. The call under test gets the pools as they are BEFORE the current
     token (its row in `layer` NaN) with the row beside them, and must return
-    the attention over, and the pools with, the row in place."""
+    the attention over, and the pools with, the row in place. A sequence in
+    `empty` is what the engine makes of a free slot: length 1 and a table of
+    zeros, so all of them write the same row of dead page 0."""
     rng = np.random.default_rng(seed)
     P_total = B * ppseq + 1  # page 0 reserved as the dead-entry target
     q = rng.normal(size=(B, H, D)).astype(np.float32)
     k_pages = rng.normal(size=(L, KV, P_total, ps, D)).astype(np.float32)
     v_pages = rng.normal(size=(L, KV, P_total, ps, D)).astype(np.float32)
     page_indices = np.zeros((B, ppseq), np.int32)
+    assert all(lengths[b] == 1 for b in empty)
     for b in range(B):
-        n_used = math.ceil(lengths[b] / ps)
+        n_used = 0 if b in empty else math.ceil(lengths[b] / ps)
         # its own pages: the call writes into a sequence's newest page
         perm = rng.permutation(np.arange(1 + b * ppseq, 1 + (b + 1) * ppseq))[:n_used]
         page_indices[b, :n_used] = perm
@@ -143,3 +147,76 @@ def test_layer_index_reads_and_writes_that_layer_and_no_other():
             np.testing.assert_array_equal(np.asarray(out[layer]), full[layer])
             assert np.isnan(np.asarray(out)[np.arange(L) != layer]).all()
         assert np.isnan(np.asarray(fn(jnp.int32(0))[0])).any()
+
+
+# The walk over live pages (PR 33): what a grid over the whole table could not
+# get wrong, because it visited every entry of every row anyway.
+B_WALK = 4
+WALKS = {
+    # every slot empty but one
+    "one_live": dict(lengths=[1, 1, 37, 1], empty=(0, 1, 3)),
+    "one_live_last": dict(lengths=[1, 1, 1, 64], empty=(0, 1, 2)),
+    # exactly one full page, and a full table, beside slots of length 1
+    "full_page_full_table": dict(lengths=[1, 16, 64, 1], empty=(0,)),
+    # a length at a page's first row and at its last
+    "page_edges": dict(lengths=[17, 32, 49, 48]),
+    # the batch's live pages number B (one each) and B x n_pages (all of them)
+    "one_page_each": dict(lengths=[1, 1, 1, 1], empty=(0, 1, 2, 3)),
+    "whole_table": dict(lengths=[64, 64, 64, 64]),
+}
+
+
+@pytest.mark.parametrize("layer", LAYERS)
+@pytest.mark.parametrize("walk", sorted(WALKS))
+def test_walk_of_live_pages(walk, layer):
+    _kernel_against_reference(_make_case(
+        B=B_WALK, H=8, KV=2, D=64, ps=16, ppseq=4, layer=layer, seed=5, **WALKS[walk]
+    ), layer)
+
+
+@pytest.mark.parametrize("layer", LAYERS)
+def test_a_batch_of_one_page(layer):
+    _kernel_against_reference(_make_case(
+        B=1, H=4, KV=2, D=64, ps=16, ppseq=4, lengths=[9], layer=layer, seed=6
+    ), layer)
+
+
+@pytest.mark.parametrize("layer", LAYERS)
+@pytest.mark.parametrize("ppseq,H,KV", [(16, 16, 8), (32, 8, 2)])
+def test_the_serve_cells_tables(ppseq, H, KV, layer):
+    """Table widths and heads a chip of the two serve configurations: most
+    slots empty, one near the end of its table, one on its second page."""
+    ps = 8
+    lengths = [1, ppseq * ps - 3, 1, ps + 1, 1, 1]
+    _kernel_against_reference(_make_case(
+        B=6, H=H, KV=KV, D=64, ps=ps, ppseq=ppseq, lengths=lengths, layer=layer, seed=7,
+        empty=(0, 2, 4, 5),
+    ), layer)
+
+
+@pytest.mark.parametrize("ps,n_pages", [(16, 4), (128, 16), (128, 32)])
+def test_page_steps_are_the_tokens_in_the_cache(ps, n_pages):
+    """The walk the kernel's grid follows: ceil(length / page_size) steps a
+    sequence, in sequence order and each one's pages ascending, one that ran
+    past its table its whole table, none without a step (its row of the
+    output would stay unwritten); with every step, where its page lies and
+    where the sequence's token goes."""
+    lengths = np.array([1, 2, ps - 1, ps, ps + 1, 2 * ps, 3 * ps - 1, n_pages * ps - 1,
+                        n_pages * ps, n_pages * ps + 5, 0], np.int32)
+    B = len(lengths)
+    table = np.random.default_rng(0).permutation(B * n_pages).reshape(B, n_pages).astype(np.int32)
+    walk = live_pages(jnp.asarray(lengths), jnp.asarray(table), ps)
+    slots, pages, where, win_page, win_row, count = (np.asarray(x) for x in walk)
+    steps = [min(max(math.ceil(n / ps), 1), n_pages) for n in lengths]
+    inside = (lengths >= 1) & (lengths <= n_pages * ps)
+    assert count.tolist() == [sum(steps)]
+    assert sum(s for s, ok in zip(steps, inside) if ok) == sum(math.ceil(n / ps) for n in lengths[inside])
+    want = [(b, j) for b in range(B) for j in range(steps[b])]
+    n = count[0]
+    assert list(zip(slots[:n], pages[:n])) == want
+    assert where[:n].tolist() == [table[b, j] for b, j in want]
+    assert win_page[:n].tolist() == [table[b, steps[b] - 1] for b, _ in want]
+    assert win_row[:n].tolist() == [(lengths[b] - 1) % ps // min(ps, 16) for b, _ in want]
+    # past the count nothing is visited, and every entry is still inside the table
+    for x, hi in ((slots, B), (pages, n_pages), (where, B * n_pages), (win_page, B * n_pages)):
+        assert x.shape == (B * n_pages,) and (0 <= x).all() and (x < hi).all()
