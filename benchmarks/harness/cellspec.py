@@ -61,8 +61,9 @@ def load_metric(name: str):
 def load_architecture(name: str):
     """An architecture's module: benchmarks/architectures/<name>.py, with
     `logits`, `packed_loss`, `transformer_kwargs`, `shrink`, `attention_dims`,
-    `param_counts` and optionally `routing` (README, "An architecture"). The
-    self-test's fixture reaches its own directory with a relative name."""
+    `param_counts` and optionally `routing` and `decode_kernels` (README, "An
+    architecture"). The self-test's fixture reaches its own directory with a
+    relative name."""
     path = os.path.join(BENCH_DIR, "architectures", name + ".py")
     if not os.path.exists(path):
         raise SystemExit(f"benchmark: architecture {name!r} has no file at {path}")
@@ -89,6 +90,16 @@ def routing(config: dict):
     declares no discrete choice (the serve check then holds every position as
     tightly as the quietest one: harness/refcheck.py `judge`)."""
     declared = getattr(architecture(config), "routing", None)
+    return declared(config) if declared else None
+
+
+def decode_kernels(config: dict):
+    """The Mosaic calls of one decode step, as the architecture's file
+    declares them: {a fragment of the kernel's name in the trace: its calls
+    a step}, the first entry the one decode steps are counted from
+    (harness/context.py `traced_decode_steps`); None for a file that declares
+    none, whose decode program calls one kernel once a layer."""
+    declared = getattr(architecture(config), "decode_kernels", None)
     return declared(config) if declared else None
 
 

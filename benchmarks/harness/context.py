@@ -7,7 +7,7 @@ from __future__ import annotations
 import functools
 
 from harness import flops
-from harness.cellspec import load_metric
+from harness.cellspec import decode_kernels, load_metric
 from harness.peaks import peaks_for
 from harness.stats import percentile
 
@@ -94,15 +94,28 @@ class Context:
                 return name, secs, self.traced["module_runs"][name]
         return None
 
-    def kernel_of(self, fragment: str):
-        """{'seconds', 'calls'} of the Mosaic kernels inside that program."""
+    def kernel_of(self, fragment: str, name: str | None = None):
+        """{'seconds', 'calls'} of the Mosaic kernels inside that program: all
+        of them, or with `name` those whose instruction's name holds it (None
+        where the program has no such kernel)."""
         m = self.module(fragment)
-        return self.traced["kernel"].get(m[0]) if m else None
+        if not m:
+            return None
+        if name is None:
+            return self.traced["kernel"].get(m[0])
+        named = [k for n, k in (self.traced.get("kernels") or {}).get(m[0], {}).items() if name in n]
+        return {key: sum(k[key] for k in named) for key in ("seconds", "calls")} if named else None
 
     def traced_decode_steps(self):
-        """Decode steps inside the traced window: every step calls the paged
-        kernel once a layer, so the trace itself says how many ran."""
-        k = self.kernel_of("_decode_impl")
-        if not k:
-            return None
-        return k["calls"] / self.config["num_hidden_layers"]
+        """Decode steps inside the traced window, which the trace itself says:
+        every step calls its kernels a known number of times. An architecture
+        file that defines `decode_kernels` says which kernel how often (the
+        first one it names is counted); without it the decode program has one
+        kernel, the paged one, called once a layer."""
+        declared = decode_kernels(self.config)
+        if declared:
+            name, calls_a_step = next(iter(declared.items()))
+            k = self.kernel_of("_decode_impl", name)
+        else:
+            k, calls_a_step = self.kernel_of("_decode_impl"), self.config["num_hidden_layers"]
+        return k["calls"] / calls_a_step if k else None

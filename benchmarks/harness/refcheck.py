@@ -26,6 +26,44 @@ def coarse_weights(params, mantissa_bits: int = COARSE_MANTISSA_BITS):
     return jax.tree.map(lambda a: jax.lax.reduce_precision(a, 8, mantissa_bits), params)
 
 
+class _ReadCoarsely:
+    """A weight of `read_coarsely`'s tree: indexing it gives another of its
+    kind (a layer's slice of a stacked weight), and only `astype`, or a
+    jax.numpy function that takes it for an array, rounds what is left."""
+
+    def __init__(self, array, mantissa_bits: int):
+        self._array, self._bits = array, mantissa_bits
+        self.shape, self.dtype, self.ndim = array.shape, array.dtype, array.ndim
+
+    def __getitem__(self, index):
+        return _ReadCoarsely(self._array[index], self._bits)
+
+    def __jax_array__(self):
+        import jax
+
+        return jax.lax.reduce_precision(self._array, 8, self._bits)
+
+    def astype(self, dtype):
+        return self.__jax_array__().astype(dtype)
+
+
+def read_coarsely(params, mantissa_bits: int = COARSE_MANTISSA_BITS):
+    """`coarse_weights`, value for value, for a reference that is traced with
+    the engine's own weights as its argument: rounding is elementwise, so a
+    slice of the rounded weight is the rounded slice, and here the slice is
+    taken first. A reference that reads a stacked weight layer by layer
+    (`v[i]`) then rounds a layer's slice where it reads it, and the compiled
+    program holds no rounded copy of the stack. With `coarse_weights` under
+    the same jit the TPU compiler keeps two stacked FFN weights' rounded
+    copies at once (4,938,210,816 bytes of temporaries at 21 layers of
+    mistral-7b-v0.3's widths, 9.7 GB of weights, against 895,030,784 for the
+    reference itself; compiled for a described v5e, PR 35), and outside it
+    the whole second tree lies on the device."""
+    import jax
+
+    return jax.tree.map(lambda a: _ReadCoarsely(a, mantissa_bits), params)
+
+
 def judge(ref, own, coarse, served, routing=None) -> dict:
     """ref: [n, V] logits of the plain float32 reference at the n generated
     positions; own: the forward under test there (the program's, in the

@@ -12,6 +12,7 @@ the host was doing. Only this process can trace the chip it holds.
 """
 from __future__ import annotations
 
+import inspect
 import os
 import threading
 import time
@@ -40,6 +41,14 @@ def traced_part(limit_s: float, limit_units: int, units, clock=time.monotonic, s
     return {"traced_part_s": clock() - t0, "trace_units": units() - u0, "ended_by": ended_by}
 
 
+def block_length_reader(decode_impl):
+    """(args, kwargs) of a call of the decode program -> `n_steps`, its static
+    block length, found by its name in the program's own signature (looked at
+    once): a program whose cache is not two pools has it at another position."""
+    at = list(inspect.signature(decode_impl).parameters).index("n_steps")
+    return lambda args, kwargs: kwargs["n_steps"] if "n_steps" in kwargs else args[at]
+
+
 class BenchLLMServer(LLMServer):
     def __init__(self, *args, **kwargs):
         t0 = time.monotonic()
@@ -58,6 +67,7 @@ class BenchLLMServer(LLMServer):
         jax.monitoring.register_event_duration_secs_listener(on_event)
         eng = self.engine
         step, decode, prefill_of = eng.step, eng._decode_jit, eng._prefill
+        n_steps_of = block_length_reader(eng._decode_impl)
         from jax.profiler import TraceAnnotation
 
         def wrapped_step():
@@ -84,7 +94,7 @@ class BenchLLMServer(LLMServer):
 
         def wrapped_decode(*a, **kw):
             b = self._b
-            n = a[6] if eng.paged else a[5]  # n_steps, the static argument
+            n = n_steps_of(a, kw)
             lens = eng.lengths
             active = [i for i, s in enumerate(eng.slots) if s is not None and i not in eng._prefilling]
             ctx = int(sum(int(lens[i]) for i in active))
@@ -263,29 +273,54 @@ class BenchLLMServer(LLMServer):
         return {k: v for k, v in box.items() if k != "done"}
 
     def bench_reference_check(self, prompt: list, served: list, model: dict) -> dict:
-        """Were the tokens the served path returned for `prompt` (greedy) the
-        plain float32 reference's choices, up to bf16's rounding, and is the
-        program's own forward as close to the reference as bf16 allows?
-        Teacher-forced: the reference's logits at every generated position,
-        the program's own forward in bf16 there, and the reference from
-        coarse weights as the yardstick (harness/refcheck.py `judge`)."""
-        import dataclasses
+        """`reference_check` on the engine's own weights: {"verdict":
+        refcheck.judge's dict, "temp_bytes": each of the three programs'
+        temporaries as compiled}."""
+        verdict, temp_bytes = reference_check(self.engine.params, self.engine.cfg, model, prompt, served)
+        return {"verdict": verdict, "temp_bytes": temp_bytes}
 
-        import jax
-        import jax.numpy as jnp
 
-        from harness import refcheck
-        from harness.cellspec import architecture, routing
-        from ray_tpu.models.transformer import forward
+def reference_check(params, cfg, model: dict, prompt: list, served: list) -> tuple:
+    """Were the tokens the served path returned for `prompt` (greedy) the
+    plain float32 reference's choices, up to bf16's rounding, and is the
+    program's own forward as close to the reference as bf16 allows?
+    Teacher-forced: the reference's logits at every generated position,
+    the program's own forward in bf16 there, and the reference from
+    coarse weights as the yardstick (harness/refcheck.py `judge`).
 
-        eng = self.engine
-        reference = architecture(model)
-        P, n = len(prompt), len(served)
-        toks = jnp.asarray([list(prompt) + list(served)], jnp.int32)
-        with jax.default_matmul_precision("highest"):
-            plain = jax.jit(lambda p, t: reference.logits(p, t, model)[0, P - 1: P - 1 + n])
-            ref = plain(eng.params, toks)
-            coarse = plain(jax.jit(refcheck.coarse_weights)(eng.params), toks)
-        cfg = dataclasses.replace(eng.cfg, attention_impl="reference")
-        own = jax.jit(lambda p, t: forward(p, t, cfg)[0][0, P - 1: P - 1 + n])(eng.params, toks)
-        return refcheck.judge(ref, own, coarse, served, routing(model))
+    The weights lie on the device once. The yardstick is one jitted function
+    of the engine's own parameters that runs the reference on
+    `refcheck.read_coarsely(params)`: a layer's slice of a stacked weight is
+    rounded where the reference reads it, and no coarse copy of the tree is
+    ever an argument, a result or (as the TPU compiler made of rounding the
+    whole tree inside the same jit) a temporary. Each of the three programs
+    is compiled ahead and its temporaries are returned beside the verdict:
+    (judge's dict, {"reference", "coarse", "own"} -> temp_size_in_bytes, or
+    None where the backend does not say)."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+
+    from harness import refcheck
+    from harness.cellspec import architecture, routing
+    from ray_tpu.models.transformer import forward
+
+    reference = architecture(model)
+    P, n = len(prompt), len(served)
+    toks = jnp.asarray([list(prompt) + list(served)], jnp.int32)
+    probe = slice(P - 1, P - 1 + n)
+    own_cfg = dataclasses.replace(cfg, attention_impl="reference")
+    logits, temp_bytes = {}, {}
+
+    def run(name, fn):
+        compiled = jax.jit(fn).lower(params, toks).compile()
+        temp_bytes[name] = getattr(compiled.memory_analysis(), "temp_size_in_bytes", None)
+        logits[name] = compiled(params, toks)
+
+    with jax.default_matmul_precision("highest"):
+        run("reference", lambda p, t: reference.logits(p, t, model)[0, probe])
+        run("coarse", lambda p, t: reference.logits(refcheck.read_coarsely(p), t, model)[0, probe])
+    run("own", lambda p, t: forward(p, t, own_cfg)[0][0, probe])
+    verdict = refcheck.judge(logits["reference"], logits["own"], logits["coarse"], served, routing(model))
+    return verdict, temp_bytes

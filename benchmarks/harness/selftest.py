@@ -156,6 +156,34 @@ def check_trace_reduction_on_recorded_trace():
     assert samples["jit_step/b"][1] == "jit(step)/ffn/mul" and samples["jit_step/a"] == ["a", None]
 
 
+def check_two_kernels_of_one_program():
+    """selftest_data/trace_two_kernels.json (its "what" has the numbers): a
+    decode block of 2 steps x 4 layers, the attention kernel in every layer
+    and a grouped matmul in every second one; a prefill run with flash."""
+    from harness.cellspec import decode_kernels
+    from harness.context import Context
+
+    out = xplane.reduce(_load("selftest_data", "trace_two_kernels.json"))
+    decode = out["kernels"]["jit__decode_impl"]
+    assert set(decode) == {"paged_attn.6", "gmm.9"} and set(out["kernels"]["jit__prefill_batch_impl"]) == {"flash_attn.7"}
+    assert (decode["paged_attn.6"]["calls"], decode["gmm.9"]["calls"]) == (8, 4)
+    assert abs(decode["paged_attn.6"]["seconds"] - 24e-6) < 1e-15 and abs(decode["gmm.9"]["seconds"] - 16e-6) < 1e-15
+    total = out["kernel"]["jit__decode_impl"]  # what the parent read: every Mosaic call of the program
+    assert total["calls"] == 12 and abs(total["seconds"] - 40e-6) < 1e-15
+
+    def ctx(config):
+        return Context({"kind": "serve", "seconds": 51.0, "config": config, "traffic": {}, "traced": out}, 1)
+
+    declaring = {"architecture": "../selftest_data/two_kernel_decoder", "num_hidden_layers": 4}
+    assert decode_kernels(declaring) == {"paged_attn": 4, "gmm": 2} and decode_kernels({"num_hidden_layers": 4}) is None
+    assert ctx(declaring).traced_decode_steps() == 2.0   # from the declared kernel's 8 calls, 4 a step
+    assert ctx({"num_hidden_layers": 4}).traced_decode_steps() == 3.0  # 12 calls over 4 layers: one kernel a layer assumed
+    assert ctx(declaring).kernel_of("_decode_impl", "gmm")["calls"] == 4
+    assert ctx(declaring).kernel_of("_decode_impl") == total and ctx(declaring).kernel_of("_decode_impl", "flash") is None
+    small = xplane.reduce(_load("selftest_data", "trace_small.json"))
+    assert small["kernels"] == {"jit_step": {"closed_call.1 (Mosaic kernel)": small["kernel"]["jit_step"]}}
+
+
 def check_serve_check_two_tests():
     """judge by hand: 12 positions x 4 logits, the reference all zeros with
     logit 0 raised to 1 (the served token), the coarse reference 0.1 off at
@@ -199,7 +227,8 @@ def check_manifest_and_files():
 
 CHECKS = [check_schedule_same_work_every_seed, check_closed_loop_and_train_work,
           check_percentile_and_spread, check_flops_against_hand_counts, check_architecture_seam,
-          check_trace_reduction_on_recorded_trace, check_serve_check_two_tests, check_manifest_and_files]
+          check_trace_reduction_on_recorded_trace, check_two_kernels_of_one_program,
+          check_serve_check_two_tests, check_manifest_and_files]
 
 
 def main() -> int:

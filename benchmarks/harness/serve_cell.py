@@ -206,8 +206,11 @@ def run(spec: dict, seed: int, seconds: float, trace: bool, rehearse: bool, t_st
             traced.update(xplane.reduce_logdir(traced.pop("logdir")))  # parses a file: no backend, no chip
             traced["reduce_s"] = time.monotonic() - t_reduce
         t_check = time.monotonic()
-        check = call("bench_reference_check", probe_prompt, probe_out, config, t=900)
+        checked = call("bench_reference_check", probe_prompt, probe_out, config, t=900)
         check_s = time.monotonic() - t_check
+        check = checked.pop("verdict")
+        # `device` above was read before the check, as the run's peak has to be
+        peak_after_check = call("bench_device")["memory_peak_bytes"]
         driver_touched_jax = backend_initialized()
     finally:
         serve.shutdown()
@@ -219,8 +222,10 @@ def run(spec: dict, seed: int, seconds: float, trace: bool, rehearse: bool, t_st
     say(f"replica in the window: {json.dumps({k: v for k, v in window.items() if k != 'queue_wait_s'})}")
     say(f"replica: ready in {ready_s:.1f} s (engine init + warm-up {dev0['init_s']:.1f} s, of which "
         f"warm-up {dev0['warmup_s']:.1f} s); buckets warmed {_warmup_buckets(traffic, engine)}; "
-        f"groups warmed before the ramp {json.dumps(warm_groups)}; reference check in {check_s:.1f} s "
-        f"{json.dumps(check)}")
+        f"groups warmed before the ramp {json.dumps(warm_groups)}; reference check in {check_s:.1f} s, "
+        f"the device's peak after it {peak_after_check} bytes ({device['memory_peak_bytes']} before it, "
+        f"{max(b or 0 for b in device['bytes_in_use'])} in use then), its programs' temporaries "
+        f"{json.dumps(checked['temp_bytes'])} {json.dumps(check)}")
     return {"kind": "serve", "plan": plan, "client": client, "window": window, "device": device,
             "traced": traced, "check": check, "setup_s": setup_s, "stats": stats,
             "driver_touched_jax": driver_touched_jax, "seconds": seconds, "traffic": traffic,

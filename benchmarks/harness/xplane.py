@@ -1,5 +1,6 @@
 """From a profiler trace to numbers: device busy time, idle gaps laid to what
-the host was doing, time by operation, by program and by kernel.
+the host was doing, time by operation, by program and by kernel (a program's
+Mosaic calls summed, and each under its own name).
 
 The reduction works on a plain form of the trace (`to_plain`), so that it can
 be checked on a small recorded one (selftest_data/, `run.py --selftest`):
@@ -147,7 +148,7 @@ def reduce(trace: dict) -> dict:
     lo, hi = windows[0] if windows else (min(s for s, _ in all_ops), max(e for _, e in all_ops))
     window_s = (hi - lo) / 1e9
 
-    busy, exposed, per_op, per_module, module_runs, kernel, samples = [], [], {}, {}, {}, {}, {}
+    busy, exposed, per_op, per_module, module_runs, kernel, kernels, samples = [], [], {}, {}, {}, {}, {}, {}
     first_gaps = None
     kinds: dict[str, tuple] = {}
 
@@ -185,9 +186,11 @@ def reduce(trace: dict) -> dict:
                 scope = OP_NAME.search(n)
                 samples[key] = (n[:240], scope.group(1) if scope else None)
             if kind(n)[1]:
-                k = kernel.setdefault(m, {"seconds": 0.0, "calls": 0})
-                k["seconds"] += dur
-                k["calls"] += 1
+                # once for the program, once under the call's own name
+                for k in (kernel.setdefault(m, {"seconds": 0.0, "calls": 0}),
+                          kernels.setdefault(m, {}).setdefault(_op_name(n), {"seconds": 0.0, "calls": 0})):
+                    k["seconds"] += dur
+                    k["calls"] += 1
         for s, e, m in mods:
             if e > lo and s < hi:
                 per_module[m] = per_module.get(m, 0.0) + (min(e, hi) - max(s, lo)) / 1e9
@@ -206,6 +209,7 @@ def reduce(trace: dict) -> dict:
         gaps[name] = gaps.get(name, 0.0) + (e - s) / 1e9
     n_dev = len(devices)
     top = lambda d: sorted(([k, v / n_dev] for k, v in d.items()), key=lambda kv: -kv[1])  # noqa: E731
+    a_device = lambda k: {"seconds": k["seconds"] / n_dev, "calls": k["calls"] / n_dev}  # noqa: E731
     return {
         "window_s": window_s, "busy_s": sum(busy) / n_dev, "devices": n_dev,
         "collective_exposed_s": sum(exposed) / n_dev,
@@ -213,8 +217,12 @@ def reduce(trace: dict) -> dict:
         "idle_gaps": sorted(([k, v] for k, v in gaps.items()), key=lambda kv: -kv[1])[:10],
         "module_s": {k: v / n_dev for k, v in per_module.items()},
         "module_runs": {k: v / n_dev for k, v in module_runs.items()},
-        "kernel": {m: {"seconds": k["seconds"] / n_dev, "calls": k["calls"] / n_dev}
-                   for m, k in kernel.items()},
+        # every Mosaic call of a program summed, and each by the name its
+        # instruction carries, which is all of a trace event that names it: a
+        # pallas_call's `name=` where it has one, else the scope around the call
+        # (`paged_attn.6`, `flash_attn.7`; `shard_map.141` under a mesh)
+        "kernel": {m: a_device(k) for m, k in kernel.items()},
+        "kernels": {m: {name: a_device(k) for name, k in by_name.items()} for m, by_name in kernels.items()},
         "line_names": sorted({ln["name"] for p in devices for ln in p["lines"]}),
         "op_samples": [[k, v, *samples[k]] for k, v in top(per_op)[:40]],
     }

@@ -109,7 +109,9 @@ def child(args) -> None:
                     why[key] = why.get(key, 0) + 1
             say(f"failed requests: {json.dumps(why)}")
         compiles = result["window"]["compiles"]
-        problems = [] if result["check"]["ok"] else [f"reference check failed: {result['check']}"]
+        check = result["check"]
+        problems = [] if check["ok"] else [f"reference check failed: {check}"]
+        compared = serve_compared(check)
         if result["client"].get("stream_exhausted"):
             problems.append("the closed loop ran out of requests")
         device = {"platform": dev["platform"], "kind": dev["kind"], "count": dev["count"],
@@ -129,8 +131,10 @@ def child(args) -> None:
         if not abs(w["loss_program"] - w["loss_reference"]) <= LOSS_TOL:
             problems.append(f"loss {w['loss_program']} vs reference {w['loss_reference']} "
                             f"(tolerance {LOSS_TOL}, {w['reference_tokens']} tokens)")
+        compared = train_compared(w, LOSS_TOL)
         device = {"platform": w["platform"], "kind": w["device_kind"], "count": w["device_count"],
                   "memory_peak_bytes": w["memory_peak_bytes"]}
+    compared["window_compiles"] = [compiles, 0]
     if compiles:
         problems.append(f"{compiles} compilation(s) inside the window")
     if result["driver_touched_jax"]:
@@ -162,11 +166,39 @@ def child(args) -> None:
         line["breakdown"] = {
             k: [[_clean_name(n), s] for n, s in ctx.traced[k]] for k in ("device_ops", "idle_gaps")}
         say("traced: " + json.dumps({k: ctx.traced[k] for k in
-                                     ("module_s", "module_runs", "kernel", "line_names",
+                                     ("module_s", "module_runs", "kernel", "kernels", "line_names",
                                       "collective_exposed_s", "xplane_bytes", "stop_trace_s", "traced_part_s",
                                       "ended_by", "trace_units", "trace_wait_s", "reduce_s", "op_samples")
                                      if k in ctx.traced}))
+    # last in the line: each number `correct` was decided by, beside its limit
+    line["compared"] = {name: {"value": value if value == value and abs(value) != float("inf") else None,
+                               "limit": limit} for name, (value, limit) in compared.items()}
     say(RESULT_TAG + json.dumps(line))
+
+
+def serve_compared(check: dict) -> dict:
+    """harness/refcheck.py `judge`'s three comparisons as name -> [number, its
+    limit]: each has to be at most its limit (the fourth test is that the
+    reference's logits are finite)."""
+    return {"worst_trail": [check["worst_trail"], 2 * check["bf16_logit_error"]],
+            "quietest_share_of_coarse": [check["quietest_share_of_coarse"], check["quietest_limit"]],
+            "noise_share_of_coarse": [check["noise_share_of_coarse"], check["position_limit"]]}
+
+
+def train_compared(worker: dict, loss_tol: float) -> dict:
+    """The train cells' comparisons: the program's loss against the plain
+    reference's on one sequence, at most the tolerance apart; the mean of the
+    window's last five losses less that of its first five, below 0."""
+    losses = worker["losses"]
+    return {"loss_gap_to_reference": [abs(worker["loss_program"] - worker["loss_reference"]), loss_tol],
+            "loss_change_over_window": [sum(losses[-5:]) / 5 - sum(losses[:5]) / 5, 0.0]}
+
+
+def compared_lines(result_line: str) -> list:
+    """What the supervisor prints as the last lines of standard error: the
+    result line's `compared`, a number and its limit a line."""
+    return [f"compared: {name} {c['value']} (limit {c['limit']})"
+            for name, c in json.loads(result_line).get("compared", {}).items()]
 
 
 # ---------------------------------------------------------------------------
@@ -349,6 +381,8 @@ def supervise(args, argv: list) -> int:
         say(f"benchmark: no result (exit code {rc}, processes left {left})")
         return rc or 1
     if result:
+        # the numbers compared, as the last lines of standard error too
+        print("\n".join(compared_lines(result[-1])), file=sys.stderr, flush=True)
         say(result[-1])
     return 0
 

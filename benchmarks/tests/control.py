@@ -75,8 +75,10 @@ def make_forwards(model: dict):
             return arch.logits(p, t, model)[0, n]
 
     reference = jax.jit(plain)
-    control = jax.jit(lambda p, t: plain(refcheck.coarse_weights(p, CONTROL_MANTISSA_BITS), t))
-    coarse = jax.jit(lambda p, t: plain(refcheck.coarse_weights(p), t))
+    # rounded where the reference reads a layer's slice, as the replica's check
+    # computes its yardstick: no rounded copy of a stacked weight is held
+    control = jax.jit(lambda p, t: plain(refcheck.read_coarsely(p, CONTROL_MANTISSA_BITS), t))
+    coarse = jax.jit(lambda p, t: plain(refcheck.read_coarsely(p), t))
     return init, sound, control, reference, coarse
 
 
