@@ -1,0 +1,7 @@
+"""The latent paged kernel's device time in the decode program, over the
+device's busy time in the traced window."""
+
+
+def read(ctx):
+    k = ctx.kernel_of("_decode_impl", "latent_attn")
+    return 100.0 * k["seconds"] / ctx.traced["busy_s"] if k and ctx.traced["busy_s"] else None

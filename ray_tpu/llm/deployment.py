@@ -375,10 +375,11 @@ class LLMServer:
         from ray_tpu.accel import device as _device
 
         eng = self.engine
+        wq = eng.params["layers"]["wq_b" if eng.cfg.latent else "wq"]  # heads on axis 2 either way
         per_device = {
-            "wq": list(eng.params["layers"]["wq"].sharding.shard_shape(
-                eng.params["layers"]["wq"].shape)),
-            "k_pages": list(eng.k_pages.sharding.shard_shape(eng.k_pages.shape)),
+            "wq": list(wq.sharding.shard_shape(wq.shape)),
+            # the first pool: a head's K rows, or a latent layer's one pool
+            "k_pages": list(eng.cache[0].sharding.shard_shape(eng.cache[0].shape)),
             "bytes_in_use": [
                 (d.memory_stats() or {}).get("bytes_in_use") for d in jax.local_devices()],
         }

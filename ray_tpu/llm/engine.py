@@ -31,6 +31,19 @@ llm/_internal/serve/engines/vllm/vllm_engine.py:174):
   attached chip, against 11-18 ms a decode step: PERF.md section 6).
 - GQA cache: K/V stored at kv-head count (the HBM saving is what makes long
   contexts fit); the paged kernel reads grouped heads directly.
+- Latent cache (a model with ``attention_kind="latent"``): one pool of one
+  row a token a layer, ``[c | k_rope]`` (the normed low-rank latent and the
+  roped key all heads share), in place of two pools of head rows. ``__init__``
+  derives the pool's row from the model in one place (``self.cache``, the
+  tuple of pools) and the four programs take their cache through it; page
+  tables, lengths, admission and the prefix cache's digests are the same. A
+  prompt expands its own rows to keys and values for the flash kernel; decode
+  absorbs the two up-projections into the query and the output and attends
+  the rows as they lie (ops/latent_attention.py). A model's stacks of layers
+  (leading dense layers, then the rest) are scanned one after the other, and
+  a layer that serves a chip's share of routed experts hands its counts out
+  of the decode program beside the tokens (``expert_pairs``,
+  ``expert_tiles`` of a step's record).
 - Tensor-parallel serving (EngineConfig.tensor_parallel > 1): params shard
   Megatron-style and the KV pools shard by kv_heads over a `tensor` mesh
   axis (parallel/), so a model bigger than one chip's HBM serves from a
@@ -63,7 +76,11 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as _P
 
 from ray_tpu.llm.sampling import SamplingParams, sample_batch
-from ray_tpu.models.transformer import TransformerConfig, _rms_norm, decoder_block, init_params, param_logical_axes
+from ray_tpu.models.transformer import (
+    TransformerConfig, _rms_norm, decoder_block, init_params, lane_padded, latent_absorb, latent_expand,
+    latent_scale, latent_values, layer_stacks, pad_last, param_logical_axes, scan_stack,
+)
+from ray_tpu.ops.latent_attention import latent_attention_reference, latent_paged_attention, latent_row_width
 from ray_tpu.ops.paged_attention import live_pages, paged_attention, paged_attention_reference
 from ray_tpu.util import tracing as _tracing
 
@@ -185,9 +202,30 @@ def _kv_rows(kv, dtype):
     return kv[0].transpose(1, 0, 2).astype(dtype)
 
 
-def _prompt_attention(q, k, v, seg, mesh):
+def _latent_rows(c, k_rope, width, dtype):
+    """What a latent layer caches of tokens: c [..., R] and k_rope [..., rope]
+    side by side, zero-padded to the pool's row width, in its dtype."""
+    return pad_last(jnp.concatenate([c, k_rope], axis=-1), width).astype(dtype)
+
+
+def _row_major(rows):
+    """``rows`` held to the layout the pool has, last axis minor. A prompt's
+    latent rows are put together from a 64 wide roped key, which the TPU
+    compiler lays token-minor, and a loop that carries the pool then takes the
+    rows' layout for the pool: a transposed copy of the whole pool into the
+    request scan and one out of it (2.9 GB each, as compiled for a v5e)."""
+    if jax.default_backend() != "tpu":
+        return rows
+    from jax.experimental.layout import Layout, with_layout_constraint
+
+    return with_layout_constraint(rows, Layout(major_to_minor=tuple(range(rows.ndim))))
+
+
+def _prompt_attention(q, k, v, seg, mesh, scale=None):
     """Causal attention of a (padded) prompt over its own fresh K/V. seg
-    masks pad columns (pad tokens are their own segment).
+    masks pad columns (pad tokens are their own segment). scale: a latent
+    layer's (its keys are wider than its values, so the flash kernel gets
+    both zero-padded to one lane multiple); None is 1 / sqrt(head width).
 
     mesh: tensor-parallel serving — heads are sharded over mesh["tensor"],
     so the Pallas flash kernel runs per-shard under shard_map (GSPMD cannot
@@ -196,11 +234,14 @@ def _prompt_attention(q, k, v, seg, mesh):
     from ray_tpu.ops.attention import flash_attention, flash_supported, mha_reference
 
     def flash(q_, k_, v_, seg_):
-        return flash_attention(q_, k_, v_, causal=True, segment_ids=seg_)
+        if scale is None:
+            return flash_attention(q_, k_, v_, causal=True, segment_ids=seg_)
+        return flash_attention(*lane_padded(q_, k_, v_), causal=True, segment_ids=seg_,
+                               scale=scale)[..., :v_.shape[-1]]
 
     with jax.named_scope("flash_attn"):
         if not flash_supported(q.shape[1]):
-            return mha_reference(q, k, v, causal=True, segment_ids=seg)
+            return mha_reference(q, k, v, causal=True, segment_ids=seg, scale=scale)
         if mesh is not None and mesh.shape.get("tensor", 1) > 1:
             hs = _P(None, None, "tensor", None)
             flash = jax.shard_map(
@@ -244,10 +285,17 @@ class LLMEngine:
     """Host-side continuous batching over the jitted prefill/decode programs."""
 
     def __init__(self, cfg: TransformerConfig, params=None, engine_config: EngineConfig | None = None):
-        if cfg.n_experts:
-            raise ValueError("MoE serving not supported yet (dense decode path only)")
+        if cfg.n_experts and not cfg.experts_held:
+            raise ValueError(
+                "MoE serving needs a model that says which experts this chip holds "
+                "(TransformerConfig.experts_held / first_expert); the training form, every "
+                "expert computed for every token, is not served")
         self.cfg = cfg
         self.ec = engine_config or EngineConfig()
+        if (cfg.latent or cfg.experts_held) and self.ec.tensor_parallel > 1:
+            raise ValueError(
+                "tensor_parallel > 1 is not written for a latent cache or held experts: their "
+                "kernels run on one chip (ROADMAP M1, M3)")
         if self.ec.max_seq <= 0:
             self.ec = dataclasses.replace(self.ec, max_seq=cfg.max_seq_len)
         S = self.ec.max_seq
@@ -330,10 +378,19 @@ class LLMEngine:
         # the pools through both its scans with the layer index in xs, and the
         # layer scans of prefill see a prompt's K/V and never a pool
         # (_write_pages, _copy_pages_impl).
-        pool_shape = (L, cfg.kv_heads, P_total * ps, cfg.head_dim)
-        kv_spec = _P(None, "tensor", None, None)
-        self.k_pages = _pool_zeros(pool_shape, kv_spec)
-        self.v_pages = _pool_zeros(pool_shape, kv_spec)
+        # What a layer caches of a token, the one place that says it: a head's
+        # K and V rows in two pools [L, KV, tokens, Hd], or for a latent layer
+        # one pool [L, tokens, W] of [c | k_rope] rows (W a lane multiple).
+        # Every program takes ``self.cache``, the tuple of pools, whole, and
+        # slices tokens along ``self._tok_axis``.
+        if cfg.latent:
+            self._row_width = latent_row_width(cfg.kv_lora_rank, cfg.qk_rope_head_dim)
+            pools = [((L, P_total * ps, self._row_width), _P(None, None, None))]
+            self._tok_axis = 1
+        else:
+            pools = [((L, cfg.kv_heads, P_total * ps, cfg.head_dim), _P(None, "tensor", None, None))] * 2
+            self._tok_axis = 2
+        self.cache = tuple(_pool_zeros(shape, spec) for shape, spec in pools)
         self.free_pages: deque = deque(range(1, P_total))  # page 0 = dead sink
         self.page_tables = np.zeros((B, self.ppseq), np.int32)
         self.d_page_tables = jnp.zeros((B, self.ppseq), jnp.int32)
@@ -386,7 +443,7 @@ class LLMEngine:
         self._prefilling: dict[int, np.ndarray] = {}
         # Padded rows copy page 0 onto itself (the dead sink) — static [ppseq]
         # shape, one compiled program for any hit size.
-        self._copy_pages_jit = jax.jit(self._copy_pages_impl, donate_argnums=(0, 1))
+        self._copy_pages_jit = jax.jit(self._copy_pages_impl, donate_argnums=(0,))
         # Context-page buckets for the tail-prefill program (partial prefix
         # hits): powers of two up to the page-table width, so the
         # compiled-program count stays |buckets| x log(ppseq).
@@ -397,7 +454,7 @@ class LLMEngine:
         cs.append(self.ppseq)
         self.c_buckets = tuple(sorted(set(cs)))
         self._tail_jit: dict[tuple, Any] = {}
-        self._decode_jit = jax.jit(self._decode_impl, donate_argnums=(1, 2), static_argnums=(6,))
+        self._decode_jit = jax.jit(self._decode_impl, donate_argnums=(1,), static_argnames=("n_steps",))
         # Buckets: page-size multiples only (a prefill writes whole pages).
         self.buckets = tuple(sorted(
             {min(ps * math.ceil(b / ps), S) for b in self.ec.prefill_buckets if b <= S} | {S}
@@ -429,31 +486,55 @@ class LLMEngine:
         return m
 
     # -- jitted programs ---------------------------------------------------
-    def _read_pages(self, pool, page_idxs):
-        """Pages ``page_idxs`` [n] of every layer, side by side:
-        [L, KV, n * ps, Hd] (unrolled: n is small and static)."""
-        cfg, ps = self.cfg, self.ec.page_size
-        page = (cfg.n_layers, cfg.kv_heads, ps, cfg.head_dim)
-        return jnp.concatenate(
-            [jax.lax.dynamic_slice(pool, (0, 0, page_idxs[i] * ps, 0), page)
-             for i in range(page_idxs.shape[0])], axis=2)
+    def _stacks(self, params) -> list:
+        """The model's stacks of identical layers in order, each with the
+        index of its first layer in the pools: [(stack params, first, n)]."""
+        out, first = [], 0
+        for stack in layer_stacks(params):
+            n = jax.tree.leaves(stack)[0].shape[0]
+            out.append((stack, first, n))
+            first += n
+        return out
 
-    def _write_pages(self, k_pages, v_pages, ks, vs, page_idxs):
-        """A prompt's fresh K/V, ``ks`` / ``vs`` [L, KV, n * ps, Hd] in the
-        pool's dtype, into pages ``page_idxs`` [n] of the carried pools: one
-        in-place ``dynamic_update_slice`` of [L, KV, ps, Hd] a page and pool
-        (the rule: where the pools are made, ``__init__``). Trailing page
-        ids 0 send a bucket's padding to the dead sink."""
-        ps = self.ec.page_size
+    def _prompt_layers(self, params, x, scan_fn, ctx=()):
+        """A prompt's hidden state through every stack of layers, by
+        ``scan_fn(h, lp, *a layer's slice of each of ctx) -> (h, rows)``;
+        returns (x, every layer's rows, one array a pool: [L, ...])."""
+        kept = []
+        for stack, first, n in self._stacks(params):
+            x, rows = scan_stack(scan_fn, x, stack, self.cfg, *(c[first:first + n] for c in ctx))
+            kept.append(rows)
+        return x, (kept[0] if len(kept) == 1 else [jnp.concatenate(r, axis=0) for r in zip(*kept)])
+
+    def _read_pages(self, pool, page_idxs):
+        """Pages ``page_idxs`` [n] of every layer, side by side along the
+        pool's token axis: [L, KV, n * ps, Hd] or [L, n * ps, W] (unrolled: n
+        is small and static)."""
+        ps, ax = self.ec.page_size, self._tok_axis
+        page = pool.shape[:ax] + (ps,) + pool.shape[ax + 1:]
+        zeros = (0,) * pool.ndim
+        return jnp.concatenate(
+            [jax.lax.dynamic_slice(pool, zeros[:ax] + (page_idxs[i] * ps,) + zeros[ax + 1:], page)
+             for i in range(page_idxs.shape[0])], axis=ax)
+
+    def _write_pages(self, cache, rows, page_idxs):
+        """A prompt's fresh rows, one array a pool (``rows[i]`` as the pool
+        but n * ps tokens long, in its dtype), into pages ``page_idxs`` [n] of
+        the carried pools: one in-place ``dynamic_update_slice`` of a page's
+        rows a page and pool (the rule: where the pools are made,
+        ``__init__``). Trailing page ids 0 send a bucket's padding to the
+        dead sink."""
+        ps, ax = self.ec.page_size, self._tok_axis
+        cache = list(cache)
         with jax.named_scope("kv_write"):
             for p in range(page_idxs.shape[0]):
-                at = (0, 0, page_idxs[p] * ps, 0)
-                rows = slice(p * ps, (p + 1) * ps)
-                k_pages = jax.lax.dynamic_update_slice(k_pages, ks[:, :, rows], at)
-                v_pages = jax.lax.dynamic_update_slice(v_pages, vs[:, :, rows], at)
-        return k_pages, v_pages
+                for i, new in enumerate(rows):
+                    at = (0,) * ax + (page_idxs[p] * ps,) + (0,) * (new.ndim - ax - 1)
+                    cache[i] = jax.lax.dynamic_update_slice(
+                        cache[i], jax.lax.slice_in_dim(new, p * ps, (p + 1) * ps, axis=ax), at)
+        return tuple(cache)
 
-    def _copy_pages_impl(self, k_pages, v_pages, src, dst):
+    def _copy_pages_impl(self, cache, src, dst):
         """A prefix-cache hit's pages ``src`` copied onto ``dst`` ([ppseq]
         each). Every source page is read before the first is written: a
         hit's source and target pages never overlap, but padded rows all
@@ -463,15 +544,32 @@ class LLMEngine:
         updated in place (the rule: where the pools are made), and compiled
         for a v5e this program holds under a megabyte beside the pools,
         kv_heads-sharded or on one chip (PERF.md section 6, PR 29)."""
-        return self._write_pages(
-            k_pages, v_pages, self._read_pages(k_pages, src), self._read_pages(v_pages, src), dst)
+        return self._write_pages(cache, [self._read_pages(pool, src) for pool in cache], dst)
 
-    def _prefill_impl(self, params, k_pages, v_pages, tokens, length, page_idxs, key, temp, top_p, top_k):
+    def _prompt_attend(self, lp, seg, dtypes):
+        """The ``attend`` of a prompt over its own fresh rows, and what it
+        keeps of them for the pools: the K and V rows of a head, or a latent
+        layer's [c | k_rope] rows (expanded to keys and values here, for the
+        prompt alone)."""
+        cfg = self.cfg
+        if not cfg.latent:
+            def attend(q, k, v):
+                o = _prompt_attention(q, k, v, seg, self.mesh)
+                return o, (_kv_rows(k, dtypes[0]), _kv_rows(v, dtypes[1]))
+            return attend
+
+        def attend(q, c, k_rope):
+            k, v = latent_expand(lp, c, k_rope, c.dtype)
+            o = _prompt_attention(jnp.concatenate(q, axis=-1), k, v, seg, self.mesh, latent_scale(cfg))
+            return o, (_row_major(_latent_rows(c[0], k_rope[0], self._row_width, dtypes[0])),)
+        return attend
+
+    def _prefill_impl(self, params, cache, tokens, length, page_idxs, key, temp, top_p, top_k):
         """tokens: [P] (padded to the bucket); page_idxs: [P // ps] page ids
         (trailing entries may be 0 = dead sink). Returns the pools with the
-        prompt's K/V pages written and the first generated token. Attention
+        prompt's pages written and the first generated token. Attention
         runs on the layer's fresh K/V, so the layer scan never sees a pool:
-        it hands out every layer's K/V as ``ys`` and the pages are written
+        it hands out every layer's rows as ``ys`` and the pages are written
         once, after it."""
         cfg = self.cfg
         P = tokens.shape[0]
@@ -479,80 +577,109 @@ class LLMEngine:
             x = params["embed"].astype(cfg.dtype)[tokens][None]  # [1,P,D]
         pos = jnp.arange(P, dtype=jnp.int32)[None]
         seg = (pos >= length).astype(jnp.int32)  # pads = their own segment
-
-        def attend(q, k, v):
-            o = _prompt_attention(q, k, v, seg, self.mesh)
-            return o, (_kv_rows(k, k_pages.dtype), _kv_rows(v, v_pages.dtype))
+        dtypes = [pool.dtype for pool in cache]
 
         def scan_fn(h, lp):
-            h, _aux, kv = decoder_block(h, lp, cfg, pos, attend)
-            return h, kv
+            h, _aux, rows = decoder_block(h, lp, cfg, pos, self._prompt_attend(lp, seg, dtypes))
+            return h, rows
 
-        x, (ks, vs) = jax.lax.scan(scan_fn, x, params["layers"])  # ks: [L,KV,P,Hd]
-        k_pages, v_pages = self._write_pages(k_pages, v_pages, ks, vs, page_idxs)
+        x, rows = self._prompt_layers(params, x, scan_fn)  # rows[i]: [L,KV,P,Hd] or [L,P,W]
+        cache = self._write_pages(cache, rows, page_idxs)
         with jax.named_scope("lm_head"):
-            x = _rms_norm(x, params["final_norm"])
+            x = _rms_norm(x, params["final_norm"], cfg.norm_eps)
             last = jax.lax.dynamic_index_in_dim(x[0], length - 1, axis=0, keepdims=False)
             logits = last @ params["lm_head"].astype(cfg.dtype)
         with jax.named_scope("sample"):
             tok = sample_batch(logits.astype(jnp.float32)[None], temp[None], top_p[None],
                                top_k[None], key, cap=self.ec.sample_topk_cap)[0]
-        return k_pages, v_pages, tok
+        return cache, tok
 
-    def _decode_impl(self, params, k_pages, v_pages, last_tokens, lengths, page_tables, n_steps, key, temps, top_ps, top_ks):
+    def _decode_attend(self, lp, pools, seen, page_tables, layer, walk):
+        """The ``attend`` of one decode step in one layer: the step's rows
+        written at position seen - 1 of each slot's pages and the queries
+        attended over the pages, by the kernel where it can run (on the
+        step's walk of live pages) and by the einsum reference elsewhere,
+        which GSPMD partitions as-is under TP. A latent layer absorbs its
+        key and value projections into the query and the output here."""
+        cfg = self.cfg
+        on_tpu = walk is not None  # decided once a program, where the walk is built
+        if not cfg.latent:
+            paged_attend = functools.partial(
+                paged_attention, mesh=self.mesh, walk=walk) if on_tpu else paged_attention_reference
+
+            def attend(q, k_new, v_new):
+                with jax.named_scope("paged_attn"):
+                    # writes k_new / v_new at position lens of each slot's
+                    # pages (page_tables[b, lens // ps], offset lens % ps)
+                    o, kp2, vp2 = paged_attend(
+                        q[:, 0], k_new[:, 0], v_new[:, 0], *pools, seen, page_tables, layer,
+                    )  # o: [B, H, Hd]
+                return o[:, None], (kp2, vp2)
+            return attend
+
+        latent_attend = functools.partial(
+            latent_paged_attention, walk=walk) if on_tpu else latent_attention_reference
+
+        def attend(q, c, k_rope):
+            q_nope, q_rope = q
+            dt = c.dtype
+            with jax.named_scope("latent_attn"):
+                qt = latent_absorb(lp, q_nope[:, 0], dt)
+                q_row = _latent_rows(qt, q_rope[:, 0], self._row_width, dt)  # [B, H, W]
+                row = _latent_rows(c[:, 0], k_rope[:, 0], self._row_width, dt)  # [B, W]
+                ctx, pool = latent_attend(
+                    q_row, row, pools[0], seen, page_tables, layer,
+                    v_width=cfg.kv_lora_rank, scale=latent_scale(cfg))  # ctx: [B, H, R]
+                o = latent_values(lp, ctx, dt)
+            return o[:, None], (pool,)
+        return attend
+
+    def _decode_impl(self, params, cache, last_tokens, lengths, page_tables, key, n_steps, temps, top_ps, top_ks):
         """n_steps tokens for every slot in ONE device program (outer scan
         over steps, inner scan over layers): one host round trip per block.
-        Returns (k_pages, v_pages, toks [n_steps, B], last', lengths').
+        Returns (cache, toks [n_steps, B], last', lengths', counts): counts
+        is None for a model without held experts, else int32 [2], the routed
+        (token, expert) pairs that landed on held experts and the live tiles
+        of the grouped matmul (a tile reads its expert's matrices), both
+        summed over the block's steps and the routed layers.
 
         How the pools are threaded (the rule: where the pools are made):
-        both scans CARRY the two pools whole (as [L, KV, pages, ps, Hd], a
-        free reshape); the layer scan's ``xs`` are the layer's weights and
+        both scans CARRY the pools whole (the token axis split into pages and
+        rows, a free reshape); the layer scan's ``xs`` are the layer's weights and
         its index. The one Mosaic call of a layer is told the layer by an
         operand, reads the pages where they lie, and writes each slot's new
-        K/V row itself, into the pool its outputs alias: no operation of
+        row itself, into the pool its outputs alias: no operation of
         this program but that call has a pool, or a layer's slice of one,
         for operand or result (11-14 ms a step and an eighth of a pool of
         temporaries; PERF.md section 6, PR 25)."""
         cfg = self.cfg
-        ps = self.ec.page_size
-        flat = k_pages.shape  # [L, KV, total_pages * ps, Hd], as every other program has it
-        pool = (cfg.n_layers, cfg.kv_heads, -1, ps, cfg.head_dim)
-        on_tpu = jax.default_backend() == "tpu"
+        ps, ax = self.ec.page_size, self._tok_axis
+        flat = [pool.shape for pool in cache]  # as every other program has them
+        paged = [shape[:ax] + (-1, ps) + shape[ax + 1:] for shape in flat]
 
         def one_step(carry, step_key):
-            kp, vp, last, lens = carry
-            # The kernel where it can run, on the step's walk of live pages
-            # (built here, once for all layers: lengths change between steps
-            # and not between layers); elsewhere the einsum reference, which
-            # GSPMD partitions as-is under TP.
+            pools, last, lens = carry
+            # The step's walk of live pages, built here, once for all layers:
+            # lengths change between steps and not between layers.
             seen = lens + 1  # the kernel's lengths count the step's own token
-            paged_attend = functools.partial(
-                paged_attention, mesh=self.mesh, walk=live_pages(seen, page_tables, ps),
-            ) if on_tpu else paged_attention_reference
+            walk = live_pages(seen, page_tables, ps) if jax.default_backend() == "tpu" else None
             with jax.named_scope("embed"):
                 x = params["embed"].astype(cfg.dtype)[last][:, None, :]  # [B,1,D]
 
-            def scan_fn(carry, xs):
-                h, kp, vp = carry
-                lp, layer = xs
+            def scan_fn(carry, lp, layer):
+                h, pools = carry
+                h, aux, pools = decoder_block(
+                    h, lp, cfg, lens[:, None], self._decode_attend(lp, pools, seen, page_tables, layer, walk))
+                return (h, pools), (aux if cfg.experts_held and "router" in lp else None)
 
-                def attend(q, k_new, v_new):
-                    with jax.named_scope("paged_attn"):
-                        # writes k_new / v_new at position lens of each slot's
-                        # pages (page_tables[b, lens // ps], offset lens % ps)
-                        o, kp2, vp2 = paged_attend(
-                            q[:, 0], k_new[:, 0], v_new[:, 0], kp, vp,
-                            seen, page_tables, layer,
-                        )  # o: [B, H, Hd]
-                    return o[:, None], (kp2, vp2)
-
-                h, _aux, (kp, vp) = decoder_block(h, lp, cfg, lens[:, None], attend)
-                return (h, kp, vp), None
-
-            layers = jnp.arange(cfg.n_layers, dtype=jnp.int32)
-            (x, kp, vp), _ = jax.lax.scan(scan_fn, (x, kp, vp), (params["layers"], layers))
+            counts = None
+            for stack, first, n in self._stacks(params):
+                layers = jnp.arange(first, first + n, dtype=jnp.int32)
+                (x, pools), aux = scan_stack(scan_fn, (x, pools), stack, cfg, layers)
+                if aux is not None:
+                    counts = jnp.sum(aux, axis=0)
             with jax.named_scope("lm_head"):
-                x = _rms_norm(x, params["final_norm"])
+                x = _rms_norm(x, params["final_norm"], cfg.norm_eps)
                 logits = jnp.einsum("bsd,dv->bv", x, params["lm_head"].astype(cfg.dtype))
             with jax.named_scope("sample"):
                 toks = sample_batch(logits.astype(jnp.float32), temps, top_ps, top_ks,
@@ -561,15 +688,16 @@ class LLMEngine:
             # at its length: the kernel's cost follows the lengths, and such a
             # slot costs it one step on dead page 0 however long the mirrors
             # go without a resync.
-            return (kp, vp, toks, jnp.where(page_tables[:, 0] > 0, lens + 1, lens)), toks
+            return (pools, toks, jnp.where(page_tables[:, 0] > 0, lens + 1, lens)), (toks, counts)
 
         keys = jax.random.split(key, n_steps)
-        (k_pages, v_pages, last, lengths), toks = jax.lax.scan(
-            one_step, (k_pages.reshape(pool), v_pages.reshape(pool), last_tokens, lengths), keys
-        )
-        return k_pages.reshape(flat), v_pages.reshape(flat), toks, last, lengths
+        pools = tuple(pool.reshape(shape) for pool, shape in zip(cache, paged))
+        (pools, last, lengths), (toks, counts) = jax.lax.scan(one_step, (pools, last_tokens, lengths), keys)
+        if counts is not None:
+            counts = jnp.sum(counts, axis=0)
+        return tuple(pool.reshape(shape) for pool, shape in zip(pools, flat)), toks, last, lengths, counts
 
-    def _prefill_batch_impl(self, params, k_pages, v_pages, tokens, lengths, page_rows, key, temps, top_ps, top_ks):
+    def _prefill_batch_impl(self, params, cache, tokens, lengths, page_rows, key, temps, top_ps, top_ks):
         """Prefill k requests of one length bucket in ONE device program
         (scan over requests around the single-request body): one dispatch
         and one set of host-built arrays per admitted group instead of one
@@ -577,34 +705,32 @@ class LLMEngine:
         0.55-0.61 ms (PERF.md section 6, bring-up), little beside a prompt's
         prefill, so the group saves host work (``prefill_dispatch``), not
         device time: the k requests run one after another and each reads
-        the weights. The request scan carries the two donated pools; each
+        the weights. The request scan carries the donated pools; each
         request writes its pages into them in place (_write_pages).
         tokens: [k, P]; page_rows: [k, P // ps], each request's pages."""
         keys = jax.random.split(key, tokens.shape[0])
 
-        def scan_req(carry, xs):
-            kp, vp = carry
+        def scan_req(cache, xs):
             toks_i, len_i, pages_i, key_i, t_i, p_i, k_i = xs
-            kp, vp, tok = self._prefill_impl(params, kp, vp, toks_i, len_i, pages_i, key_i, t_i, p_i, k_i)
-            return (kp, vp), tok
+            return self._prefill_impl(params, cache, toks_i, len_i, pages_i, key_i, t_i, p_i, k_i)
 
-        (k_pages, v_pages), toks = jax.lax.scan(
-            scan_req, (k_pages, v_pages), (tokens, lengths, page_rows, keys, temps, top_ps, top_ks)
+        return jax.lax.scan(  # (cache, toks [k])
+            scan_req, cache, (tokens, lengths, page_rows, keys, temps, top_ps, top_ks)
         )
-        return k_pages, v_pages, toks  # toks: [k]
 
-    def _tail_prefill_impl(self, params, k_pages, v_pages, tokens, start, length,
+    def _tail_prefill_impl(self, params, cache, tokens, start, length,
                            ctx_pages, tail_pages, key, temp, top_p, top_k):
         """Chunked prefill over a cached prefix (partial-prefix KV reuse):
         the prompt's first `start` tokens (page-aligned) already sit in this
         request's pages, copied from the prefix cache; only the tail is
-        embedded and projected here. Tail K/V scatter into the request's
+        embedded and projected here. The tail's rows scatter into the request's
         remaining pages; queries attend to the cached context pages
         (gathered from the pool) plus causally to the tail itself, so the
         sampled first token is that of a cold full prefill (in f32; in bf16
         this is einsum attention where the cold prefill runs the flash
         kernel — not compared on the chip) while prefill compute scales
-        with the tail length.
+        with the tail length. A latent layer expands context and tail rows
+        alike to keys and values (the plain path; no absorbed form here).
 
         tokens: [Tb] padded tail; start/length: scalars (start page-aligned);
         ctx_pages: [C] context page ids (trailing 0 = dead, masked by
@@ -613,8 +739,6 @@ class LLMEngine:
         ps = self.ec.page_size
         Tb = tokens.shape[0]
         C = ctx_pages.shape[0]
-        KV, Hd = cfg.kv_heads, cfg.head_dim
-        group = cfg.n_heads // KV
         x = params["embed"].astype(cfg.dtype)[tokens][None]  # [1,Tb,D]
         tpos = jnp.arange(Tb, dtype=jnp.int32)
         pos = (start + tpos)[None]  # [1,Tb] absolute positions
@@ -627,13 +751,15 @@ class LLMEngine:
         )
         tail_mask = (tpos[None, :] <= tpos[:, None]) & ((start + tpos)[None, :] < length)
         mask = jnp.concatenate([ctx_mask, tail_mask], axis=1)
+        dtypes = [pool.dtype for pool in cache]
 
-        def scan_fn(h, xs):
-            lp, ctx_k, ctx_v = xs
+        def heads_attend(ctx_k, ctx_v):
+            KV, Hd = cfg.kv_heads, cfg.head_dim
+            group = cfg.n_heads // KV
 
             def attend(q, k_new, v_new):
-                kt = _kv_rows(k_new, k_pages.dtype)  # [KV,Tb,Hd]
-                vt = _kv_rows(v_new, v_pages.dtype)
+                kt = _kv_rows(k_new, dtypes[0])  # [KV,Tb,Hd]
+                vt = _kv_rows(v_new, dtypes[1])
                 kall = jnp.concatenate([ctx_k, kt], axis=1)  # [KV, C*ps+Tb, Hd]
                 vall = jnp.concatenate([ctx_v, vt], axis=1)
                 qg = q[0].reshape(Tb, KV, group, Hd)
@@ -643,29 +769,44 @@ class LLMEngine:
                 pr = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
                 o = jnp.einsum("tkgs,ksh->tkgh", pr, vall).reshape(1, Tb, cfg.n_heads, Hd)
                 return o, (kt, vt)
+            return attend
 
-            h, _aux, kv = decoder_block(h, lp, cfg, pos, attend)
-            return h, kv
+        def latent_attend(lp, ctx_rows):
+            R, rope = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+
+            def attend(q, c, k_rope):
+                rows = _latent_rows(c[0], k_rope[0], self._row_width, dtypes[0])  # [Tb, W]
+                every = jnp.concatenate([ctx_rows, rows], axis=0)[None]  # [1, C*ps+Tb, W]
+                k, v = latent_expand(lp, every[..., :R], every[..., R:R + rope], c.dtype)
+                scores = jnp.einsum("bthk,bshk->bhts", jnp.concatenate(q, axis=-1), k).astype(jnp.float32)
+                scores = jnp.where(mask[None, None], scores * latent_scale(cfg), -1e30)
+                pr = jax.nn.softmax(scores, axis=-1).astype(c.dtype)
+                return jnp.einsum("bhts,bshk->bthk", pr, v), (rows,)
+            return attend
+
+        def scan_fn(h, lp, *ctx):
+            attend = latent_attend(lp, *ctx) if cfg.latent else heads_attend(*ctx)
+            h, _aux, rows = decoder_block(h, lp, cfg, pos, attend)
+            return h, rows
 
         # The cached context of every layer, gathered once from the whole
-        # pools, so that the layer scan sees a prompt's K/V and never a pool.
-        ctx = (self._read_pages(k_pages, ctx_pages), self._read_pages(v_pages, ctx_pages))
-        x, (ks, vs) = jax.lax.scan(scan_fn, x, (params["layers"], *ctx))
+        # pools, so that the layer scan sees a prompt's rows and never a pool.
+        x, rows = self._prompt_layers(params, x, scan_fn, [self._read_pages(pool, ctx_pages) for pool in cache])
         # The gather above reads positions < start and this lands on the
         # tail's pages, so the write can follow the scan.
-        k_pages, v_pages = self._write_pages(k_pages, v_pages, ks, vs, tail_pages)
-        x = _rms_norm(x, params["final_norm"])
+        cache = self._write_pages(cache, rows, tail_pages)
+        x = _rms_norm(x, params["final_norm"], cfg.norm_eps)
         last = jax.lax.dynamic_index_in_dim(x[0], length - 1 - start, axis=0, keepdims=False)
         logits = last @ params["lm_head"].astype(cfg.dtype)
         toks = sample_batch(logits.astype(jnp.float32)[None], temp, top_p, top_k, key,
                             cap=self.ec.sample_topk_cap)
-        return k_pages, v_pages, toks  # toks: [1]
+        return cache, toks  # toks: [1]
 
     def _tail_prefill(self, tail_bucket: int, n_ctx: int):
         fn = self._tail_jit.get((tail_bucket, n_ctx))
         if fn is None:
             fn = self._tail_jit[(tail_bucket, n_ctx)] = jax.jit(
-                self._tail_prefill_impl, donate_argnums=(1, 2)
+                self._tail_prefill_impl, donate_argnums=(1,)
             )
         return fn
 
@@ -689,8 +830,8 @@ class LLMEngine:
         m = min(n_tpg, self.ppseq - j)
         tpg[:m] = self.page_tables[i, j:j + m]  # zeros past need -> dead sink
         self._key, sub = jax.random.split(self._key)
-        self.k_pages, self.v_pages, toks_dev = self._tail_prefill(tb, C)(
-            self.params, self.k_pages, self.v_pages,
+        self.cache, toks_dev = self._tail_prefill(tb, C)(
+            self.params, self.cache,
             jnp.asarray(padded), jnp.int32(start), jnp.int32(length),
             jnp.asarray(ctx), jnp.asarray(tpg), sub,
             jnp.asarray(self.samp_temps[i:i + 1]),
@@ -703,7 +844,7 @@ class LLMEngine:
         fn = self._prefill_jit.get((bucket, k))
         if fn is None:
             fn = self._prefill_jit[(bucket, k)] = jax.jit(
-                self._prefill_batch_impl, donate_argnums=(1, 2)
+                self._prefill_batch_impl, donate_argnums=(1,)
             )
         return fn
 
@@ -753,7 +894,7 @@ class LLMEngine:
                 lens = jnp.ones(k, jnp.int32)
                 page_rows = jnp.zeros((k, b // ps), jnp.int32)  # writes -> dead page
                 args = (
-                    self.params, self.k_pages, self.v_pages, toks, lens, page_rows, key,
+                    self.params, self.cache, toks, lens, page_rows, key,
                     jnp.zeros(k, jnp.float32), jnp.ones(k, jnp.float32),
                     jnp.zeros(k, jnp.int32),
                 )
@@ -764,7 +905,7 @@ class LLMEngine:
                     compiled = compiled_ahead(self._prefill(b, k), args)
                     self.mosaic["prefill"] = on_tpu and holds_mosaic(compiled)
                     entry["temp_bytes"] = compiled.memory_analysis().temp_size_in_bytes
-                self.k_pages, self.v_pages, td = self._prefill(b, k)(*args)
+                self.cache, td = self._prefill(b, k)(*args)
                 # The admit path's per-group mirror updates are their own tiny
                 # jitted programs, one shape variant per k — compile them here
                 # too or they land in the first loaded step's TTFT.
@@ -775,25 +916,23 @@ class LLMEngine:
                 log.append({**entry, "seconds": time.monotonic() - t0})
         for n in self.block_sizes:
             t0 = time.monotonic()
-            args = (self.params, self.k_pages, self.v_pages, self.d_last, self.d_lengths,
-                    self.d_page_tables, n, key, self.d_temps, self.d_top_ps, self.d_top_ks)
+            args = (self.params, self.cache, self.d_last, self.d_lengths,
+                    self.d_page_tables, key, n, self.d_temps, self.d_top_ps, self.d_top_ks)
             compiled = compiled_ahead(self._decode_jit, args)
             temp_bytes = compiled.memory_analysis().temp_size_in_bytes
             if n == self.block_sizes[-1]:
                 self.mosaic["decode"] = on_tpu and holds_mosaic(compiled)
             out = self._decode_jit(*args)
-            self.k_pages, self.v_pages = out[0], out[1]
-            jax.device_get(out[2])
+            self.cache = out[0]
+            jax.device_get(out[1])
             log.append({"program": "decode", "block": n, "temp_bytes": temp_bytes,
                         "seconds": time.monotonic() - t0})
         if self.ec.prefix_cache:
             # Compile the prefix-cache page copy (padded rows hit page 0).
             t0 = time.monotonic()
             z = jnp.zeros(self.ppseq, jnp.int32)
-            self.k_pages, self.v_pages = self._copy_pages_jit(
-                self.k_pages, self.v_pages, z, z
-            )
-            jax.block_until_ready(self.k_pages)
+            self.cache = self._copy_pages_jit(self.cache, z, z)
+            jax.block_until_ready(self.cache)
             log.append({"program": "copy_pages", "seconds": time.monotonic() - t0})
         # Reset device mirrors dirtied by the dummy executions.
         self.d_lengths = jnp.zeros(self.ec.max_slots, jnp.int32)
@@ -1000,7 +1139,7 @@ class LLMEngine:
         step's record goes to the ring that LLMServer.stats() returns."""
         ph = self._phases
         ph.begin("admit", waiting=len(self.waiting), n_admitted=0, n_prefill=0,
-                 block=0, active=0, live_pages=0)
+                 block=0, active=0, live_pages=0, expert_pairs=0, expert_tiles=0)
         try:
             return self._step(ph)
         finally:
@@ -1086,9 +1225,7 @@ class LLMEngine:
                 dst = np.zeros(self.ppseq, np.int32)
                 dst[:n_pp] = pages[:n_pp]
                 ph.to("prefill_dispatch")  # the copy stands where a prefill would
-                self.k_pages, self.v_pages = self._copy_pages_jit(
-                    self.k_pages, self.v_pages, jnp.asarray(src), jnp.asarray(dst)
-                )
+                self.cache = self._copy_pages_jit(self.cache, jnp.asarray(src), jnp.asarray(dst))
                 ph.to("admit")
                 if exact:
                     # Decode from position P-1: the block re-derives that
@@ -1158,8 +1295,8 @@ class LLMEngine:
                     pgs[j] = self.page_tables[i, :n_pg]  # trailing zeros -> dead sink
                 idx_arr = jnp.asarray(np.asarray(idxs, np.int32))
                 self._key, sub = jax.random.split(self._key)
-                self.k_pages, self.v_pages, toks_dev = self._prefill(bucket, k)(
-                    self.params, self.k_pages, self.v_pages,
+                self.cache, toks_dev = self._prefill(bucket, k)(
+                    self.params, self.cache,
                     jnp.asarray(padded), jnp.asarray(lens), jnp.asarray(pgs), sub,
                     jnp.asarray(self.samp_temps[idxs]),
                     jnp.asarray(self.samp_top_ps[idxs]),
@@ -1254,7 +1391,7 @@ class LLMEngine:
         active = [i for i, s in enumerate(self.slots)
                   if s is not None and i not in self._prefilling]
         ph.rec["active"] = len(active)
-        toks = None
+        toks = counts = None
         n = 0
         if active:
             remaining = [self.slots[i].max_tokens - self.slots[i].n_generated for i in active]
@@ -1280,9 +1417,9 @@ class LLMEngine:
                 if fits:
                     n = fits[-1]
                     self._key, sub = jax.random.split(self._key)
-                    (self.k_pages, self.v_pages, toks, self.d_last, self.d_lengths) = self._decode_jit(
-                        self.params, self.k_pages, self.v_pages, self.d_last,
-                        self.d_lengths, self.d_page_tables, n, sub,
+                    self.cache, toks, self.d_last, self.d_lengths, counts = self._decode_jit(
+                        self.params, self.cache, self.d_last,
+                        self.d_lengths, self.d_page_tables, sub, n,
                         self.d_temps, self.d_top_ps, self.d_top_ks,
                     )
                     for i in active:
@@ -1308,7 +1445,11 @@ class LLMEngine:
             ph.rec["block"] = n
             ph.rec["live_pages"] = self._live_pages(active, n)
             ph.to("decode_fetch")
-            block_toks = np.asarray(jax.device_get(toks))  # [n, B]
+            if counts is None:
+                block_toks = np.asarray(jax.device_get(toks))  # [n, B]
+            else:  # a model with held experts: its counts ride the same fetch
+                block_toks, (pairs, tiles) = jax.device_get((toks, counts))
+                ph.rec["expert_pairs"], ph.rec["expert_tiles"] = int(pairs), int(tiles)
             ph.to("emit")
             for step_i in range(n):
                 for i in active:

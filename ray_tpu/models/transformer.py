@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Any, Optional
 
 import jax
@@ -60,6 +61,37 @@ class TransformerConfig:
     # so the full [B, S, V] logits never materialize (0 = off). Requires
     # chunk | (S-1 of the train batch); big win at large vocab (PROFILES.md).
     ce_chunk: int = 0
+    norm_eps: float = 1e-6
+    # Attention kind. "gqa": a head's K and V projected from the hidden state
+    # and cached. "latent": low-rank query and key/value projections with
+    # their inner norms; a head's query and key are qk_nope_head_dim wide plus
+    # a roped part of qk_rope_head_dim whose key all heads share, its value
+    # v_head_dim wide; what a token caches is the kv_lora_rank latent and that
+    # roped key, one row for all heads.
+    attention_kind: str = "gqa"
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # A norm after each sublayer as well as before it:
+    # h = x + N2(Attn(N1(x))), y = h + N4(FFN(N3(h))).
+    sandwich_norm: bool = False
+    # Leading layers whose FFN is dense (width d_ff) before the routed ones:
+    # params["dense_layers"], scanned before params["layers"].
+    n_dense_layers: int = 0
+    # A routed layer as one chip of an expert-parallel deployment serves it:
+    # the router is n_experts wide and every token takes its expert_top_k
+    # best, the chip holds experts first_expert .. first_expert +
+    # experts_held - 1 (each a SwiGLU of width expert_d_ff) and computes the
+    # part of the result those give, beside n_shared_experts every token
+    # passes. experts_held = 0: the training form (_moe_ffn), every expert here.
+    experts_held: int = 0
+    first_expert: int = 0
+    expert_d_ff: int = 0
+    n_shared_experts: int = 0
+    routed_scaling: float = 1.0
+    router_score: str = "softmax"  # softmax | sigmoid, over the router's logits in float32
 
     @property
     def kv_heads(self) -> int:
@@ -69,9 +101,19 @@ class TransformerConfig:
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
 
+    @property
+    def latent(self) -> bool:
+        return self.attention_kind == "latent"
+
     def __post_init__(self):
         assert self.d_model % self.n_heads == 0
         assert self.n_heads % self.kv_heads == 0
+        assert self.attention_kind in ("gqa", "latent"), self.attention_kind
+        assert 0 <= self.n_dense_layers <= self.n_layers
+        assert self.router_score in ("softmax", "sigmoid"), self.router_score
+        if self.experts_held:
+            assert self.first_expert + self.experts_held <= self.n_experts
+            assert self.expert_d_ff > 0
 
 
 # ---------------------------------------------------------------------------
@@ -88,31 +130,60 @@ def _dense_init(key, shape, dtype, in_axis=0):
     return jax.random.normal(key, shape, dtype) * scale
 
 
-def init_params(key: jax.Array, cfg: TransformerConfig) -> dict:
-    """Stacked-layer parameter pytree (leading 'layers' dim on layer params)."""
+def _init_stack(key: jax.Array, cfg: TransformerConfig, L: int, routed: bool) -> tuple:
+    """One stack of L identical layers (leading 'layers' dim on every leaf);
+    `routed`: the FFN is experts behind a router, else dense of width d_ff.
+    Returns (the stack, the iterator over the keys it left)."""
     pd = cfg.param_dtype
     k = iter(jax.random.split(key, 16))
-    L, D, F = cfg.n_layers, cfg.d_model, cfg.d_ff
-    H, KV, Hd = cfg.n_heads, cfg.kv_heads, cfg.head_dim
-
-    layer = {
-        "attn_norm": jnp.ones((L, D), pd),
-        "wq": _dense_init(next(k), (L, D, H, Hd), pd, in_axis=1),
-        "wk": _dense_init(next(k), (L, D, KV, Hd), pd, in_axis=1),
-        "wv": _dense_init(next(k), (L, D, KV, Hd), pd, in_axis=1),
-        "wo": _dense_init(next(k), (L, H, Hd, D), pd, in_axis=(1, 2)),
-        "ffn_norm": jnp.ones((L, D), pd),
-    }
-    if cfg.n_experts:
-        E, EF = cfg.n_experts, F
+    D, F, H = cfg.d_model, cfg.d_ff, cfg.n_heads
+    if cfg.latent:
+        R, Rq = cfg.kv_lora_rank, cfg.q_lora_rank
+        nope, rope, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+        layer = {
+            "attn_norm": jnp.ones((L, D), pd),
+            "wq_a": _dense_init(next(k), (L, D, Rq), pd, in_axis=1),
+            "q_norm": jnp.ones((L, Rq), pd),
+            "wq_b": _dense_init(next(k), (L, Rq, H, nope + rope), pd, in_axis=1),
+            "wkv_a": _dense_init(next(k), (L, D, R + rope), pd, in_axis=1),
+            "kv_norm": jnp.ones((L, R), pd),
+            "wk_b": _dense_init(next(k), (L, R, H, nope), pd, in_axis=1),
+            "wv_b": _dense_init(next(k), (L, R, H, vd), pd, in_axis=1),
+            "wo": _dense_init(next(k), (L, H, vd, D), pd, in_axis=(1, 2)),
+            "ffn_norm": jnp.ones((L, D), pd),
+        }
+    else:
+        KV, Hd = cfg.kv_heads, cfg.head_dim
+        layer = {
+            "attn_norm": jnp.ones((L, D), pd),
+            "wq": _dense_init(next(k), (L, D, H, Hd), pd, in_axis=1),
+            "wk": _dense_init(next(k), (L, D, KV, Hd), pd, in_axis=1),
+            "wv": _dense_init(next(k), (L, D, KV, Hd), pd, in_axis=1),
+            "wo": _dense_init(next(k), (L, H, Hd, D), pd, in_axis=(1, 2)),
+            "ffn_norm": jnp.ones((L, D), pd),
+        }
+    if cfg.sandwich_norm:
+        layer.update({"post_attn_norm": jnp.ones((L, D), pd), "post_ffn_norm": jnp.ones((L, D), pd)})
+    if routed:
+        E = cfg.experts_held or cfg.n_experts
+        EF = cfg.expert_d_ff or F
         layer.update(
             {
-                "router": _dense_init(next(k), (L, D, E), pd, in_axis=1),
+                "router": _dense_init(next(k), (L, D, cfg.n_experts), pd, in_axis=1),
                 "w_gate": _dense_init(next(k), (L, E, D, EF), pd, in_axis=2),
                 "w_up": _dense_init(next(k), (L, E, D, EF), pd, in_axis=2),
                 "w_down": _dense_init(next(k), (L, E, EF, D), pd, in_axis=2),
             }
         )
+        if cfg.n_shared_experts:
+            SF = cfg.n_shared_experts * EF
+            layer.update(
+                {
+                    "ws_gate": _dense_init(next(k), (L, D, SF), pd, in_axis=1),
+                    "ws_up": _dense_init(next(k), (L, D, SF), pd, in_axis=1),
+                    "ws_down": _dense_init(next(k), (L, SF, D), pd, in_axis=1),
+                }
+            )
     else:
         layer.update(
             {
@@ -121,25 +192,81 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> dict:
                 "w_down": _dense_init(next(k), (L, F, D), pd, in_axis=1),
             }
         )
-    return {
+    return layer, k
+
+
+def init_params(key: jax.Array, cfg: TransformerConfig) -> dict:
+    """Stacked-layer parameter pytree: ``layers`` (leading 'layers' dim on
+    every leaf) and, for a model with leading dense layers, ``dense_layers``
+    before it; a model with none has no such key (an empty first stack)."""
+    pd = cfg.param_dtype
+    D = cfg.d_model
+    layer, k = _init_stack(key, cfg, cfg.n_layers - cfg.n_dense_layers, routed=bool(cfg.n_experts))
+    params = {
         "embed": _dense_init(next(k), (cfg.vocab_size, D), pd) * (D ** 0.5),
         "layers": layer,
         "final_norm": jnp.ones((D,), pd),
         "lm_head": _dense_init(next(k), (D, cfg.vocab_size), pd, in_axis=0),
     }
+    if cfg.n_dense_layers:
+        params["dense_layers"], _ = _init_stack(
+            jax.random.fold_in(key, 1), cfg, cfg.n_dense_layers, routed=False)
+    return params
 
 
-def param_logical_axes(cfg: TransformerConfig) -> dict:
-    """Same-structure pytree of logical-axis tuples (see LOGICAL_AXES)."""
-    layer = {
-        "attn_norm": ("layers", "embed"),
-        "wq": ("layers", "embed", "heads", "head_dim"),
-        "wk": ("layers", "embed", "kv_heads", "head_dim"),
-        "wv": ("layers", "embed", "kv_heads", "head_dim"),
-        "wo": ("layers", "heads", "head_dim", "embed"),
-        "ffn_norm": ("layers", "embed"),
-    }
-    if cfg.n_experts:
+def layer_stacks(params: dict) -> list:
+    """The model's stacks of identical layers, in the order a token passes
+    them: the leading dense layers where it has any, then ``layers``."""
+    return [params[name] for name in ("dense_layers", "layers") if name in params]
+
+
+HELD_EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
+
+
+def scan_stack(body, carry, stack: dict, cfg: TransformerConfig, *xs):
+    """``lax.scan`` of ``body(carry, lp, *xs_i)`` over a stack of layers, lp
+    one layer's parameters. The matrices of held experts stay out of the
+    scanned operands: lp holds them whole, [L, E, ...], with the layer's
+    index in the stack under "expert_layer", and the grouped matmul is told
+    the layer by an operand. A layer's slice of them handed to a Mosaic call
+    is a copy of it (1.5 GB a layer at the published widths of the serve
+    cell's model, as compiled for a v5e), as a layer's slice of a KV pool was
+    (llm/engine.py, the rule where the pools are made)."""
+    if not (cfg.experts_held and "router" in stack):
+        return lax.scan(lambda c, s: body(c, *s), carry, (stack, *xs))
+    whole = {k: stack[k] for k in HELD_EXPERT_WEIGHTS}
+    scanned = {k: v for k, v in stack.items() if k not in whole}
+    index = jnp.arange(stack["router"].shape[0], dtype=jnp.int32)
+    return lax.scan(lambda c, s: body(c, {**s[0], **whole, "expert_layer": s[1]}, *s[2:]),
+                    carry, (scanned, index, *xs))
+
+
+def _stack_logical_axes(cfg: TransformerConfig, routed: bool) -> dict:
+    if cfg.latent:
+        layer = {
+            "attn_norm": ("layers", "embed"),
+            "wq_a": ("layers", "embed", None),
+            "q_norm": ("layers", None),
+            "wq_b": ("layers", None, "heads", "head_dim"),
+            "wkv_a": ("layers", "embed", None),
+            "kv_norm": ("layers", None),
+            "wk_b": ("layers", None, "heads", "head_dim"),
+            "wv_b": ("layers", None, "heads", "head_dim"),
+            "wo": ("layers", "heads", "head_dim", "embed"),
+            "ffn_norm": ("layers", "embed"),
+        }
+    else:
+        layer = {
+            "attn_norm": ("layers", "embed"),
+            "wq": ("layers", "embed", "heads", "head_dim"),
+            "wk": ("layers", "embed", "kv_heads", "head_dim"),
+            "wv": ("layers", "embed", "kv_heads", "head_dim"),
+            "wo": ("layers", "heads", "head_dim", "embed"),
+            "ffn_norm": ("layers", "embed"),
+        }
+    if cfg.sandwich_norm:
+        layer.update({"post_attn_norm": ("layers", "embed"), "post_ffn_norm": ("layers", "embed")})
+    if routed:
         layer.update(
             {
                 "router": ("layers", "embed", None),
@@ -148,6 +275,14 @@ def param_logical_axes(cfg: TransformerConfig) -> dict:
                 "w_down": ("layers", "experts", "expert_mlp", "embed"),
             }
         )
+        if cfg.n_shared_experts:
+            layer.update(
+                {
+                    "ws_gate": ("layers", "embed", "mlp"),
+                    "ws_up": ("layers", "embed", "mlp"),
+                    "ws_down": ("layers", "mlp", "embed"),
+                }
+            )
     else:
         layer.update(
             {
@@ -156,12 +291,20 @@ def param_logical_axes(cfg: TransformerConfig) -> dict:
                 "w_down": ("layers", "mlp", "embed"),
             }
         )
-    return {
+    return layer
+
+
+def param_logical_axes(cfg: TransformerConfig) -> dict:
+    """Same-structure pytree of logical-axis tuples (see LOGICAL_AXES)."""
+    axes = {
         "embed": ("vocab", "embed"),
-        "layers": layer,
+        "layers": _stack_logical_axes(cfg, routed=bool(cfg.n_experts)),
         "final_norm": ("embed",),
         "lm_head": ("embed", "vocab"),
     }
+    if cfg.n_dense_layers:
+        axes["dense_layers"] = _stack_logical_axes(cfg, routed=False)
+    return axes
 
 
 # ---------------------------------------------------------------------------
@@ -186,7 +329,7 @@ def _rope(x, positions, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
 
 
-def _flash(q, k, v, cfg: TransformerConfig, segment_ids):
+def _flash(q, k, v, cfg: TransformerConfig, segment_ids, scale=None):
     """The flash kernel on this device's shard. Under a multi-device mesh the
     call is shard_map'd over the batch and head axes the active strategy
     shards: GSPMD cannot partition a Mosaic kernel, and jax refuses to lower
@@ -198,7 +341,7 @@ def _flash(q, k, v, cfg: TransformerConfig, segment_ids):
 
     def local(q, k, v, seg):
         return flash_attention(
-            q, k, v, causal=True, segment_ids=seg,
+            q, k, v, causal=True, segment_ids=seg, scale=scale,
             block_q=cfg.attention_block_q or DEFAULT_BLOCK_Q,
             block_k=cfg.attention_block_k or DEFAULT_BLOCK_K,
         )
@@ -221,7 +364,7 @@ def _flash(q, k, v, cfg: TransformerConfig, segment_ids):
     )(q, k, v, segment_ids)
 
 
-def _attention(q, k, v, cfg: TransformerConfig, positions=None, segment_ids=None):
+def _attention(q, k, v, cfg: TransformerConfig, positions=None, segment_ids=None, scale=None):
     """Dispatch to the configured attention implementation.
 
     q: [B,S,H,D]; k,v: [B,S,KV,D] — flash and reference handle grouped KV
@@ -234,7 +377,12 @@ def _attention(q, k, v, cfg: TransformerConfig, positions=None, segment_ids=None
 
         impl = "flash" if flash_supported(q.shape[1]) else "reference"
     if impl == "flash":
-        return _flash(q, k, v, cfg, segment_ids)
+        if scale is None:
+            return _flash(q, k, v, cfg, segment_ids)
+        # A latent layer: keys wider than values; the kernel takes one width.
+        return _flash(*lane_padded(q, k, v), cfg, segment_ids, scale)[..., :v.shape[-1]]
+    if scale is not None and impl != "reference":
+        raise NotImplementedError(f"attention_impl={impl!r} is not written for latent attention")
     if impl == "ring":
         from ray_tpu.ops.ring_attention import ring_attention
 
@@ -256,7 +404,7 @@ def _attention(q, k, v, cfg: TransformerConfig, positions=None, segment_ids=None
         )
     from ray_tpu.ops.attention import mha_reference
 
-    return mha_reference(q, k, v, causal=True, segment_ids=segment_ids)
+    return mha_reference(q, k, v, causal=True, segment_ids=segment_ids, scale=scale)
 
 
 def _dense_ffn(x, p):
@@ -308,36 +456,166 @@ def _load_balance_loss(weights, top_idx, n_experts):
     return n_experts * jnp.sum(me * ce)
 
 
+def latent_scale(cfg: TransformerConfig) -> float:
+    """Softmax scale of a latent layer: over the whole query/key width."""
+    return 1.0 / math.sqrt(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
+
+
+def latent_expand(lp, c, k_rope, dt):
+    """A latent layer's keys and values from what it caches. c: [B, S, R];
+    k_rope: [B, S, rope] -> k [B, S, H, nope + rope], v [B, S, H, v]: the
+    path over a prompt (decode absorbs the two projections instead)."""
+    k_nope = jnp.einsum("bsr,rhk->bshk", c, lp["wk_b"].astype(dt))
+    v = jnp.einsum("bsr,rhk->bshk", c, lp["wv_b"].astype(dt))
+    k_rope = jnp.broadcast_to(k_rope[:, :, None, :], (*k_nope.shape[:3], k_rope.shape[-1]))
+    return jnp.concatenate([k_nope, k_rope], axis=-1), v
+
+
+def latent_absorb(lp, q_nope, dt):
+    """Decode's query with the key up-projection absorbed: q_nope [B, H, nope]
+    -> [B, H, R], to be scored against the cached latents as they lie."""
+    return jnp.einsum("bhk,rhk->bhr", q_nope, lp["wk_b"].astype(dt))
+
+
+def latent_values(lp, ctx, dt):
+    """The value up-projection applied after attention: ctx [B, H, R], a
+    head's weighted sum of cached latents -> its output [B, H, v]."""
+    return jnp.einsum("bhr,rhk->bhk", ctx, lp["wv_b"].astype(dt))
+
+
+def pad_last(a, width: int):
+    """a with its last axis zero-padded up to `width`."""
+    return jnp.pad(a, ((0, 0),) * (a.ndim - 1) + ((0, width - a.shape[-1]),))
+
+
+def lane_padded(q, k, v):
+    """q, k (one width) and v (another) zero-padded to one lane multiple,
+    for a flash kernel that takes one head width: zero columns change neither
+    a score nor, once the output is cut back to v's width, a value."""
+    wide = -(-max(q.shape[-1], v.shape[-1]) // 128) * 128
+    return pad_last(q, wide), pad_last(k, wide), pad_last(v, wide)
+
+
+def _latent_qkv(h, lp, cfg: TransformerConfig, positions):
+    """-> (q_nope [B,S,H,nope], q_rope [B,S,H,rope]) roped, c [B,S,R] normed,
+    k_rope [B,S,rope] roped."""
+    dt, eps, R = h.dtype, cfg.norm_eps, cfg.kv_lora_rank
+    with jax.named_scope("mla_q"):
+        cq = _rms_norm(jnp.einsum("bsd,dr->bsr", h, lp["wq_a"].astype(dt)), lp["q_norm"], eps)
+        q = jnp.einsum("bsr,rhk->bshk", cq, lp["wq_b"].astype(dt))
+        q = wlc(q, ("batch", "seq", "heads", "head_dim"))
+        q_nope, q_rope = q[..., :cfg.qk_nope_head_dim], q[..., cfg.qk_nope_head_dim:]
+        q_rope = _rope(q_rope, positions, cfg.rope_theta)
+    with jax.named_scope("mla_kv"):
+        ckr = jnp.einsum("bsd,dr->bsr", h, lp["wkv_a"].astype(dt))
+        c = _rms_norm(ckr[..., :R], lp["kv_norm"], eps)
+        k_rope = _rope(ckr[:, :, None, R:], positions, cfg.rope_theta)[:, :, 0]
+    return (q_nope, q_rope), c, k_rope
+
+
+def _expert_tile(tokens: int, cfg: TransformerConfig) -> int:
+    """Rows of a grouped-matmul tile: twice the pairs an expert expects, as a
+    power of two in 16 .. 256, so that most experts fill one tile and an
+    expert's weights are read once."""
+    expect = 2 * tokens * cfg.expert_top_k // cfg.n_experts
+    return min(256, max(16, 1 << max(expect - 1, 0).bit_length()))
+
+
+def _held_experts_ffn(x, p, cfg: TransformerConfig):
+    """A routed FFN as the chip that holds experts first_expert ..
+    first_expert + experts_held - 1 serves it. Every token is scored over all
+    n_experts (router logits in float32) and takes its expert_top_k best,
+    weights normalised over all of them and scaled; the pairs that landed on
+    experts held here are sorted by expert and multiplied by a grouped matmul
+    (ops/grouped_matmul.py), none dropped whatever the imbalance; what the
+    absent experts would have added is left out. Beside it the shared expert,
+    which every token passes.
+    Returns (out [B,S,D], int32 [2]: the pairs on held experts, and the live
+    tiles of the grouped matmul, each of which reads its expert's matrices)."""
+    from ray_tpu.ops.grouped_matmul import expert_matmul, group_rows
+
+    B, S, D = x.shape
+    dt = x.dtype
+    xt = x.reshape(B * S, D)
+    with jax.named_scope("experts/route"):
+        logits = jnp.einsum("td,de->te", xt.astype(jnp.float32), p["router"].astype(jnp.float32),
+                            precision=lax.Precision.HIGHEST)
+        score = jax.nn.sigmoid(logits) if cfg.router_score == "sigmoid" else jax.nn.softmax(logits, axis=-1)
+        top_s, top_e = lax.top_k(score, cfg.expert_top_k)  # [T, K]
+        top_w = top_s / jnp.sum(top_s, axis=-1, keepdims=True) * cfg.routed_scaling
+        tm = _expert_tile(B * S, cfg)
+        plan = group_rows(top_e, cfg.first_expert, cfg.experts_held, tm)
+    with jax.named_scope("experts/gmm"):
+        # One layer's matrices [E, ...] (layer 0 of a stack of one: a free
+        # reshape), or the whole stack with this layer's index (scan_stack).
+        stacked = p["w_gate"].ndim == 4
+        gmm = functools.partial(
+            expert_matmul(),
+            layer=p["expert_layer"] if stacked else 0,
+            tile_expert=plan.tile_expert, n_tiles=plan.n_tiles, tm=tm)
+        w_gate, w_up, w_down = (p[k] if stacked else p[k][None] for k in HELD_EXPERT_WEIGHTS)
+        xs = xt[plan.token_of_row]  # [M, D]
+        h = jax.nn.silu(gmm(xs, w_gate)) * gmm(xs, w_up)
+        y = gmm(h, w_down)  # [M, D]; rows past the live tiles hold nothing
+        pair = y[plan.row_of_pair].astype(jnp.float32) * top_w[..., None]  # [T, K, D]
+        routed = jnp.sum(jnp.where(plan.held[..., None], pair, 0.0), axis=1)
+        routed = routed.astype(dt).reshape(B, S, D)
+    with jax.named_scope("experts/shared"):
+        if cfg.n_shared_experts:
+            shared = {"w_gate": p["ws_gate"], "w_up": p["ws_up"], "w_down": p["ws_down"]}
+            routed = routed + _dense_ffn(x, shared)
+    return routed, jnp.stack([jnp.sum(plan.sizes), plan.n_tiles[0]]).astype(jnp.int32)
+
+
 def decoder_block(x, lp, cfg: TransformerConfig, positions, attend):
     """The one decoder block that training, prefill and decode all run: what
-    the model is (norms, projections, rope, the FFN's kind) lives here, what
-    a program does with K/V is its ``attend``.
+    the model is (norms, projections, rope, the attention's and the FFN's
+    kind) lives here, what a program does with what a layer caches is its
+    ``attend``.
 
-    x: [B, S, D] in cfg.dtype; lp: one layer's parameters; positions: [B, S].
-    attend(q [B,S,H,Hd], k [B,S,KV,Hd], v [B,S,KV,Hd]) -> (o [B,S,H,Hd], kept):
-    q and k arrive roped, grouped K/V as they are (native GQA); ``kept`` is
+    x: [B, S, D] in cfg.dtype; lp: one layer's parameters (its FFN is routed
+    if they hold a router, dense otherwise); positions: [B, S].
+    attend(q, k, v) -> (o [B,S,H,v width], kept). "gqa": q [B,S,H,Hd],
+    k and v [B,S,KV,Hd], q and k roped, grouped K/V as they are (native GQA).
+    "latent": q = (q_nope [B,S,H,nope], q_rope [B,S,H,rope]), k = the normed
+    latent c [B,S,R], v = the roped shared key k_rope [B,S,rope], which is
+    what such a layer caches; the attention side expands them over a prompt
+    (``latent_expand``) or absorbs the projections in decode. ``kept`` is
     whatever the attention side wants handed out of the layer (a prompt's
-    K/V rows, the carried KV pools, None). Returns (x, moe_aux, kept)."""
+    rows, the carried pools, None). Returns (x, aux, kept): aux is the MoE
+    balance term of a training layer, a zero for a dense one, and the
+    [pairs, live tiles] counts of a layer that serves held experts."""
+    eps = cfg.norm_eps
     dt = x.dtype
-    with jax.named_scope("qkv"):
-        h = _rms_norm(x, lp["attn_norm"])
-        q = jnp.einsum("bsd,dhk->bshk", h, lp["wq"].astype(dt))
-        k = jnp.einsum("bsd,dhk->bshk", h, lp["wk"].astype(dt))
-        v = jnp.einsum("bsd,dhk->bshk", h, lp["wv"].astype(dt))
-        q = wlc(q, ("batch", "seq", "heads", "head_dim"))
-        k = wlc(k, ("batch", "seq", "kv_heads", "head_dim"))
-        q = _rope(q, positions, cfg.rope_theta)
-        k = _rope(k, positions, cfg.rope_theta)
+    if cfg.latent:
+        q, k, v = _latent_qkv(_rms_norm(x, lp["attn_norm"], eps), lp, cfg, positions)
+    else:
+        with jax.named_scope("qkv"):
+            h = _rms_norm(x, lp["attn_norm"], eps)
+            q = jnp.einsum("bsd,dhk->bshk", h, lp["wq"].astype(dt))
+            k = jnp.einsum("bsd,dhk->bshk", h, lp["wk"].astype(dt))
+            v = jnp.einsum("bsd,dhk->bshk", h, lp["wv"].astype(dt))
+            q = wlc(q, ("batch", "seq", "heads", "head_dim"))
+            k = wlc(k, ("batch", "seq", "kv_heads", "head_dim"))
+            q = _rope(q, positions, cfg.rope_theta)
+            k = _rope(k, positions, cfg.rope_theta)
     o, kept = attend(q, k, v)
     with jax.named_scope("attn_out"):
         o = wlc(o, ("batch", "seq", "heads", "head_dim"))
-        x = x + jnp.einsum("bshk,hkd->bsd", o, lp["wo"].astype(dt))
+        a = jnp.einsum("bshk,hkd->bsd", o, lp["wo"].astype(dt))
+        if cfg.sandwich_norm:
+            a = _rms_norm(a, lp["post_attn_norm"], eps)
+        x = x + a
     with jax.named_scope("ffn"):
-        h = _rms_norm(x, lp["ffn_norm"])
-        if cfg.n_experts:
-            ffn_out, aux = _moe_ffn(h, lp, cfg)
-        else:
+        h = _rms_norm(x, lp["ffn_norm"], eps)
+        if "router" not in lp:
             ffn_out, aux = _dense_ffn(h, lp), jnp.zeros((), jnp.float32)
+        elif cfg.experts_held:
+            ffn_out, aux = _held_experts_ffn(h, lp, cfg)
+        else:
+            ffn_out, aux = _moe_ffn(h, lp, cfg)
+        if cfg.sandwich_norm:
+            ffn_out = _rms_norm(ffn_out, lp["post_ffn_norm"], eps)
         x = x + ffn_out
     x = wlc(x, ("batch", "seq", "embed"))
     return x, aux, kept
@@ -347,9 +625,15 @@ def _layer(x, lp, cfg: TransformerConfig, positions, segment_ids=None):
     """The block as training runs it: attention over the layer's own K/V by
     the configured implementation, nothing kept. x: [B, S, D] in cfg.dtype."""
     def attend(q, k, v):
-        return _attention(q, k, v, cfg, positions, segment_ids), None
+        if not cfg.latent:
+            return _attention(q, k, v, cfg, positions, segment_ids), None
+        k, v = latent_expand(lp, k, v, x.dtype)
+        q = jnp.concatenate(q, axis=-1)
+        return _attention(q, k, v, cfg, positions, segment_ids, scale=latent_scale(cfg)), None
 
-    return decoder_block(x, lp, cfg, positions, attend)[:2]
+    x, aux, _ = decoder_block(x, lp, cfg, positions, attend)
+    # A layer that serves held experts hands out counts, not a loss term.
+    return x, (jnp.zeros((), jnp.float32) if cfg.experts_held else aux)
 
 
 def forward_hidden(params: dict, tokens: jax.Array, cfg: TransformerConfig,
@@ -375,12 +659,11 @@ def forward_hidden(params: dict, tokens: jax.Array, cfg: TransformerConfig,
                 f"unknown remat_policy {cfg.remat_policy!r} (full|dots)"
             )
 
-    def scan_fn(carry, lp):
-        y, aux = body(carry, lp)
-        return y, aux
-
-    x, auxes = lax.scan(scan_fn, x, params["layers"])
-    return _rms_norm(x, params["final_norm"]), jnp.sum(auxes)
+    aux = None
+    for stack in layer_stacks(params):
+        x, auxes = scan_stack(body, x, stack, cfg)
+        aux = jnp.sum(auxes) if aux is None else aux + jnp.sum(auxes)
+    return _rms_norm(x, params["final_norm"], cfg.norm_eps), aux
 
 
 def forward(params: dict, tokens: jax.Array, cfg: TransformerConfig,
@@ -560,6 +843,8 @@ def make_pipeline_train_step(cfg: TransformerConfig, mesh, n_micro: int, optimiz
             "the pipeline schedule; use a dense stack with pp (or make_train_step "
             "with ep over a separate mesh axis)"
         )
+    if cfg.n_dense_layers:
+        raise ValueError("make_pipeline_train_step stages one stack of identical layers (n_dense_layers = 0)")
     optimizer = optimizer or optax.adamw(3e-4, weight_decay=0.01)
     base_init, _base_step, state_logical_axes = make_train_step(cfg, optimizer)
 
@@ -594,7 +879,7 @@ def make_pipeline_train_step(cfg: TransformerConfig, mesh, n_micro: int, optimiz
             stage_fn, params["layers"], xm, mesh=mesh, axis_name=axis_name, x_spec=x_spec
         )
         h = h.reshape(B, S, -1)
-        h = _rms_norm(h, params["final_norm"])
+        h = _rms_norm(h, params["final_norm"], cfg.norm_eps)
         logits = jnp.einsum("bsd,dv->bsv", h, params["lm_head"].astype(cfg.dtype))
         mask = batch.get("mask")
         return _ce_from_logits(logits, targets, None if mask is None else mask[:, 1:])

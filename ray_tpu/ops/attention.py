@@ -200,6 +200,7 @@ def _fwd_pallas(q, k, v, seg, *, causal, scale, block_q, block_k, group, H, inte
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_attn_fwd",
     )(*inputs)
 
 
@@ -389,6 +390,7 @@ def _bwd_pallas(res, g, *, causal, scale, block_q, block_k, group, H, KV, interp
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_attn_dkv",
     )(*dkv_inputs)
     dk, dv = dkv
 
@@ -421,6 +423,7 @@ def _bwd_pallas(res, g, *, causal, scale, block_q, block_k, group, H, KV, interp
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_attn_dq",
     )(*dq_inputs)
     return dq, dk, dv
 

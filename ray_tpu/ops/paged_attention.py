@@ -293,6 +293,7 @@ def _paged_pallas(q, k_new, v_new, k_pages, v_pages, lengths, n_pages, layer, wa
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
+        name="paged_attn",
     )(lengths, layer, slots, pages, where, win_page, win_row, q, k_new, v_new, k_pages, v_pages)
 
 

@@ -142,7 +142,10 @@ def test_every_new_metric_is_in_the_manifest_with_its_cells(bench):
     chat, back = "internlm2-1.8b.chat", "internlm2-1.8b.backlog"
     for name in KNOWN:
         entry = by_name[name]
-        assert entry["workloads"] == ([back] if name.endswith(".backlog") else [chat]), name
+        if name.endswith(".backlog"):
+            assert back in entry["workloads"], name  # later cells may join the list
+        else:
+            assert entry["workloads"] == [chat], name
         assert entry["source"] == ("program_counter" if "compiles" in name else "program_span")
         # a per-layer metric moves an end-to-end metric that its cells report
         assert set(entry["workloads"]) <= set(end_to_end[entry["moves"]]["workloads"]), name

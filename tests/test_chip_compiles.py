@@ -68,3 +68,47 @@ def test_the_paged_call_lowers_for_a_v5e(shape, one_chip, no_compile_cache, monk
         # 0.3-2.4 GB here). A head size under a lane tile is padded by the
         # call's operand layout, before PR 33 as after it.
         assert compiled.memory_analysis().temp_size_in_bytes < 4 << 20
+
+
+def test_the_latent_paged_call_lowers_for_a_v5e(one_chip, no_compile_cache, monkeypatch):
+    """ops/latent_attention.py at the serve cell's shapes (128 slots, 128
+    heads, rows of 640 = 512 + 64 padded to lane tiles, 5 layers, 3,584 pages):
+    one Mosaic call, the pool aliased and not copied."""
+    from ray_tpu.ops import latent_attention as la
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    B, H, W, R, ps, n_pages, L, P_total = 128, 128, 640, 512, 128, 32, 5, 3584
+
+    def arr(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def call(q, row, pool, lengths, table, layer):
+        return la.latent_paged_attention(q, row, pool, lengths, table, layer, v_width=R, scale=192 ** -0.5)
+
+    args = (arr((B, H, W), jnp.bfloat16), arr((B, W), jnp.bfloat16), arr((L, P_total, ps, W), jnp.bfloat16),
+            arr((B,), jnp.int32), arr((B, n_pages), jnp.int32), arr((), jnp.int32))
+    compiled = jax.jit(call, donate_argnums=(2,)).lower(*args).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 << 20  # the pool is 2.9 GB
+
+
+@pytest.mark.parametrize("tokens,tm,K,N", [(128, 16, 7680, 2048), (128, 16, 2048, 7680), (2048, 128, 7680, 2048)])
+def test_the_grouped_matmul_lowers_for_a_v5e(tokens, tm, K, N, one_chip, no_compile_cache, monkeypatch):
+    """ops/grouped_matmul.py at the serve cell's shapes: a decode step's and a
+    2048-token prompt's pairs over 16 held experts of 4 layers, the whole
+    stack an operand (a layer's slice of it would be a 0.5 GB copy a call)."""
+    from ray_tpu.ops import grouped_matmul as gm
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    M = gm.plan_rows(tokens * 8, 16, tm)
+
+    def arr(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def call(x, w, layer, tile_expert, n_tiles):
+        return gm.expert_gmm(x, w, layer, tile_expert, n_tiles, tm=tm)
+
+    compiled = jax.jit(call).lower(arr((M, K), jnp.bfloat16), arr((4, 16, K, N), jnp.bfloat16), arr((), jnp.int32),
+                                   arr((M // tm,), jnp.int32), arr((1,), jnp.int32)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 << 20  # the stack is 2.0 GB

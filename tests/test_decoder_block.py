@@ -15,7 +15,10 @@ from ray_tpu.models.transformer import (
 from ray_tpu.ops.attention import mha_reference
 
 RAY_TPU = pathlib.Path(__file__).resolve().parent.parent / "ray_tpu"
-LAYER_WEIGHTS = {"attn_norm", "wq", "wk", "wv", "wo", "ffn_norm", "router", "w_gate", "w_up", "w_down"}
+LAYER_WEIGHTS = {"attn_norm", "wq", "wk", "wv", "wo", "ffn_norm", "router", "w_gate", "w_up", "w_down",
+                 # a latent layer's, the sandwich's and the shared expert's (PR 36)
+                 "wq_a", "q_norm", "wq_b", "wkv_a", "kv_norm", "wk_b", "wv_b", "post_attn_norm", "post_ffn_norm",
+                 "ws_gate", "ws_up", "ws_down"}
 
 
 @pytest.mark.parametrize("n_experts", [0, 4])

@@ -140,7 +140,7 @@ def test_paged_pool_memory_independent_of_slots():
     ec = EngineConfig(max_slots=32, max_seq=128, page_size=16, total_pages=17,
                       prefill_buckets=(16,), decode_block=2)
     eng = LLMEngine(CFG, engine_config=ec)
-    assert eng.k_pages.shape[2] == 17 * 16  # pool tokens, NOT 32*128
+    assert eng.cache[0].shape[2] == 17 * 16  # pool tokens, NOT 32*128
     out = eng.generate([1, 2, 3], max_tokens=4)
     assert len(out["tokens"]) == 4
 
@@ -238,7 +238,7 @@ def test_paged_decode_program_does_not_move_the_pool():
     decode = [p for p in eng.warmup_log if p["program"] == "decode"]
     assert {p["block"] for p in decode} == {1, 4}
     for p in decode:
-        assert 0 <= p["temp_bytes"] < eng.k_pages.nbytes // 2, (p, eng.k_pages.nbytes)
+        assert 0 <= p["temp_bytes"] < eng.cache[0].nbytes // 2, (p, eng.cache[0].nbytes)
 
 
 @pytest.mark.parametrize("program", ["prefill-k1", "prefill-k4", "tail"])
@@ -256,15 +256,15 @@ def test_paged_prefill_programs_do_not_move_the_pool(program):
     key = jax.random.PRNGKey(0)
     if program == "tail":
         jitted = eng._tail_prefill(32, 2)
-        args = (eng.params, eng.k_pages, eng.v_pages, i32(32), jnp.int32(32), jnp.int32(40),
+        args = (eng.params, eng.cache, i32(32), jnp.int32(32), jnp.int32(40),
                 i32(2), i32(2), key, jnp.zeros(1), jnp.ones(1), i32(1))
     else:
         k = int(program[-1])
         jitted = eng._prefill(32, k)
-        args = (eng.params, eng.k_pages, eng.v_pages, i32(k, 32), jnp.ones(k, jnp.int32),
+        args = (eng.params, eng.cache, i32(k, 32), jnp.ones(k, jnp.int32),
                 i32(k, 2), key, jnp.zeros(k), jnp.ones(k), i32(k))
     temp_bytes = jitted.lower(*args).compile().memory_analysis().temp_size_in_bytes
-    assert 0 <= temp_bytes < eng.k_pages.nbytes // 2, (temp_bytes, eng.k_pages.nbytes)
+    assert 0 <= temp_bytes < eng.cache[0].nbytes // 2, (temp_bytes, eng.cache[0].nbytes)
 
 
 def test_first_prefill_entry_of_warmup_log_carries_temp_bytes():
@@ -276,7 +276,7 @@ def test_first_prefill_entry_of_warmup_log_carries_temp_bytes():
     prefill = [p for p in eng.warmup_log if p["program"] == "prefill"]
     assert [(p["bucket"], p["k"]) for p in prefill] == [(32, 2), (32, 1), (64, 2), (64, 1)]
     assert isinstance(prefill[0]["temp_bytes"], int)
-    assert 0 <= prefill[0]["temp_bytes"] < eng.k_pages.nbytes // 2, prefill[0]
+    assert 0 <= prefill[0]["temp_bytes"] < eng.cache[0].nbytes // 2, prefill[0]
     assert all("temp_bytes" not in p for p in prefill[1:]), prefill
 
 
@@ -514,11 +514,12 @@ def test_step_record_counts_the_page_steps_the_decode_block_walks():
     decode = eng._decode_jit
 
     def spy(*args):
-        handed.append((np.array(args[4]), np.array(args[5]), args[6]))  # copies: the mirrors' buffers are reused
+        # (params, cache, last, lengths, page_tables, key, n_steps, ...); copies: the mirrors' buffers are reused
+        handed.append((np.array(args[3]), np.array(args[4]), args[6]))
         out = decode(*args)
         # a slot without pages is held at its length, the others advance
         np.testing.assert_array_equal(
-            np.asarray(out[4]), handed[-1][0] + args[6] * (handed[-1][1][:, 0] > 0))
+            np.asarray(out[3]), handed[-1][0] + args[6] * (handed[-1][1][:, 0] > 0))
         return out
 
     eng._decode_jit = spy

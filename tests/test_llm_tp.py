@@ -49,7 +49,7 @@ def test_tp_actually_shards_params_and_kv():
     mlp = eng.params["layers"]["w_gate"]  # [L, D, F]: ffn hidden sharded
     assert mlp.addressable_shards[0].data.shape[2] == CFG.d_ff // 2
     # Paged KV pool [L, KV, pages*ps, Hd]: kv_heads sharded.
-    assert eng.k_pages.addressable_shards[0].data.shape[1] == CFG.kv_heads // 2
+    assert eng.cache[0].addressable_shards[0].data.shape[1] == CFG.kv_heads // 2
 
 
 def test_tp_weight_handoff_through_object_store():
