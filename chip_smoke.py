@@ -638,7 +638,7 @@ def phase_repeat(sz: dict, rehearsal: bool, tensor_parallel: int = 1) -> dict:
         rows = jnp.asarray(np.concatenate(
             [np.arange(pg * ps, (pg + 1) * ps) for pg in pages[:n_pg]])[:P])
         return np.stack([np.asarray(pool[:, :, rows, :].astype(jnp.float32))
-                         for pool in (eng.k_pages, eng.v_pages)])
+                         for pool in eng.cache])
 
     cold = generate(eng, "cold", prompt, new)
     entry = eng._prefix_cache[eng._prefix_digests(prompt)[-1][1]]

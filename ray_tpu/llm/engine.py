@@ -691,6 +691,10 @@ class LLMEngine:
             return (pools, toks, jnp.where(page_tables[:, 0] > 0, lens + 1, lens)), (toks, counts)
 
         keys = jax.random.split(key, n_steps)
+        # A slot with no pages is a greedy row whatever its last request asked
+        # for (its token is thrown away): the sampler draws only where a live
+        # slot samples, which is what the step record's ``sampled`` counts.
+        temps = jnp.where(page_tables[:, 0] > 0, temps, 0.0)
         pools = tuple(pool.reshape(shape) for pool, shape in zip(cache, paged))
         (pools, last, lengths), (toks, counts) = jax.lax.scan(one_step, (pools, last_tokens, lengths), keys)
         if counts is not None:
@@ -1139,7 +1143,7 @@ class LLMEngine:
         step's record goes to the ring that LLMServer.stats() returns."""
         ph = self._phases
         ph.begin("admit", waiting=len(self.waiting), n_admitted=0, n_prefill=0,
-                 block=0, active=0, live_pages=0, expert_pairs=0, expert_tiles=0)
+                 block=0, active=0, sampled=0, live_pages=0, expert_pairs=0, expert_tiles=0)
         try:
             return self._step(ph)
         finally:
@@ -1391,6 +1395,7 @@ class LLMEngine:
         active = [i for i, s in enumerate(self.slots)
                   if s is not None and i not in self._prefilling]
         ph.rec["active"] = len(active)
+        ph.rec["sampled"] = int(np.count_nonzero(self.samp_temps[active] > 0))
         toks = counts = None
         n = 0
         if active:

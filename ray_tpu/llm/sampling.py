@@ -64,23 +64,48 @@ def sample_batch(logits, temps, top_ps, top_ks, key, cap: int | None = None):
     sample among the top-`cap` candidates (default TOPK_CAP=128; see its
     caveat) after top-k and nucleus masking; plain-temperature rows sample
     the full distribution.
+
+    What is computed when: the argmax always; the truncated candidate (the
+    top-`cap` of [B, V], its softmax and draw) only if some row of the batch
+    is a truncated row, and the full one (Gumbel noise over [B, V]) only if
+    some row is a plain-temperature row. Each sits under a `lax.cond` on the
+    params the program already holds, so an all-greedy batch pays for the
+    argmax alone, and a row's token is the same expression of the same key
+    whatever the other rows ask for.
+
+    Must not be called under `vmap`: there a `cond` becomes a `select` that
+    computes both sides, for every row of every step. The engine reaches it
+    only under `lax.scan` (tests/test_llm_sampling.py holds that).
     """
-    V = logits.shape[-1]
+    B, V = logits.shape
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    scaled = logits / jnp.maximum(temps, 1e-6)[:, None]
     cap = min(TOPK_CAP if cap is None else cap, V)
-    top_vals, top_idx = jax.lax.top_k(scaled, cap)  # [B, cap], descending
-    ks = jnp.where(top_ks <= 0, cap, jnp.minimum(top_ks, cap))
-    pos = jnp.arange(cap)[None, :]
-    masked = jnp.where(pos < ks[:, None], top_vals, -jnp.inf)
-    probs = jax.nn.softmax(masked, axis=-1)
-    cum = jnp.cumsum(probs, axis=-1)
-    keep = (cum - probs) < top_ps[:, None]  # prefix mass before the token
-    masked = jnp.where(keep, masked, -jnp.inf)  # first candidate always kept
     k1, k2 = jax.random.split(key)
-    choice = jax.random.categorical(k1, masked, axis=-1)
-    truncated = jnp.take_along_axis(top_idx, choice[:, None], axis=-1)[:, 0]
-    full = jax.random.categorical(k2, scaled, axis=-1)
+    sampled = temps > 0.0
     plain = (top_ps >= 1.0) & (top_ks <= 0)
+
+    def scaled():  # inside the branches: an operand of a `cond` is computed before it
+        return logits / jnp.maximum(temps, 1e-6)[:, None]
+
+    def draw_truncated():
+        top_vals, top_idx = jax.lax.top_k(scaled(), cap)  # [B, cap], descending
+        ks = jnp.where(top_ks <= 0, cap, jnp.minimum(top_ks, cap))
+        pos = jnp.arange(cap)[None, :]
+        masked = jnp.where(pos < ks[:, None], top_vals, -jnp.inf)
+        probs = jax.nn.softmax(masked, axis=-1)
+        cum = jnp.cumsum(probs, axis=-1)
+        keep = (cum - probs) < top_ps[:, None]  # prefix mass before the token
+        masked = jnp.where(keep, masked, -jnp.inf)  # first candidate always kept
+        choice = jax.random.categorical(k1, masked, axis=-1)
+        return jnp.take_along_axis(top_idx, choice[:, None], axis=-1)[:, 0].astype(jnp.int32)
+
+    def draw_full():
+        return jax.random.categorical(k2, scaled(), axis=-1).astype(jnp.int32)
+
+    def nobody():
+        return jnp.zeros(B, jnp.int32)
+
+    truncated = jax.lax.cond(jnp.any(sampled & ~plain), draw_truncated, nobody)
+    full = jax.lax.cond(jnp.any(sampled & plain), draw_full, nobody)
     out = jnp.where(plain, full, truncated)
     return jnp.where(temps <= 0.0, greedy, out).astype(jnp.int32)

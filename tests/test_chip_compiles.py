@@ -112,3 +112,28 @@ def test_the_grouped_matmul_lowers_for_a_v5e(tokens, tm, K, N, one_chip, no_comp
                                    arr((M // tm,), jnp.int32), arr((1,), jnp.int32)).compile()
     assert compiled.as_text().count("tpu_custom_call") == 1
     assert compiled.memory_analysis().temp_size_in_bytes < 4 << 20  # the stack is 2.0 GB
+
+
+@pytest.mark.parametrize("rows,vocab", [(32, 92544), (128, 19200)])
+def test_the_samplers_conditionals_compile_for_a_v5e(rows, vocab, one_chip, no_compile_cache):
+    """llm/sampling.py ``sample_batch`` under a scan of decode steps at the
+    serve cells' batch and vocabulary: the TPU compiler keeps both
+    conditionals (it makes neither a select), and the top-k and the random
+    bits lie in their branches, so an all-greedy step runs neither."""
+    from ray_tpu.llm.sampling import sample_batch
+
+    def arr(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def steps(logits, temps, top_ps, top_ks, key):
+        def one_step(last, step_key):
+            with jax.named_scope("sample"):
+                toks = sample_batch(logits + last[:, None].astype(jnp.float32), temps, top_ps, top_ks, step_key)
+            return toks, toks
+        return jax.lax.scan(one_step, jnp.zeros(rows, jnp.int32), jax.random.split(key, 8))
+
+    text = jax.jit(steps).lower(arr((rows, vocab), jnp.float32), arr((rows,), jnp.float32), arr((rows,), jnp.float32),
+                                arr((rows,), jnp.int32), arr((2,), jnp.uint32)).compile().as_text()
+    assert text.count(" conditional(") == 2
+    costly = [line for line in text.splitlines() if "sample/" in line and ("/top_k" in line or "_gumbel" in line)]
+    assert costly and all("sample/cond/" in line for line in costly)
