@@ -82,6 +82,8 @@ class LLMServer:
         self._startup = {
             "fetch_params_s": t1 - t0, "engine_init_s": t2 - t1, "warmup_s": self._warmup_s,
             "programs": self.engine.warmup_log,
+            # the KV pools' bytes, by layer kind (one kind for a model whose layers are alike)
+            "pool_bytes": self.engine.pool_bytes,
         }
         self._cond = threading.Condition()
         self._done: dict[str, dict] = {}
@@ -375,7 +377,9 @@ class LLMServer:
         from ray_tpu.accel import device as _device
 
         eng = self.engine
-        wq = eng.params["layers"]["wq_b" if eng.cfg.latent else "wq"]  # heads on axis 2 either way
+        # the last layers' stack (of a model with a layer pattern: its first kind's)
+        stack = eng.params["layers"] if "layers" in eng.params else next(iter(eng.params["kind_layers"].values()))
+        wq = stack["wq_b" if eng.cfg.latent else "wq"]  # heads on axis 2 either way
         per_device = {
             "wq": list(wq.sharding.shard_shape(wq.shape)),
             # the first pool: a head's K rows, or a latent layer's one pool

@@ -44,6 +44,17 @@ llm/_internal/serve/engines/vllm/vllm_engine.py:174):
   a layer that serves a chip's share of routed experts hands its counts out
   of the decode program beside the tokens (``expert_pairs``,
   ``expert_tiles`` of a step's record).
+- The cache by layer kind (a model with a ``layer_pattern``): each LayerKind
+  has two pools of its own. A kind without a window is paged from
+  ``total_pages`` through the page tables, as above. A kind with a window
+  keeps, a slot, a ring of ``ring_pages(window, page_size)`` pages and nothing
+  behind it (ops/paged_attention.py says how a page finds its place in the
+  ring): its pools are max_slots x ring pages whatever the contexts, prefill
+  writes a prompt's last window of rows into the slot's ring
+  (``_write_ring``), and decode's ``window_attn`` call walks the window's
+  pages. Admission budgets pages of the layers that keep every token. A
+  prefix hit or a chunk of a prompt cannot restore a ring from pages, so both
+  are refused for such a model.
 - Tensor-parallel serving (EngineConfig.tensor_parallel > 1): params shard
   Megatron-style and the KV pools shard by kv_heads over a `tensor` mesh
   axis (parallel/), so a model bigger than one chip's HBM serves from a
@@ -78,10 +89,12 @@ from jax.sharding import NamedSharding, PartitionSpec as _P
 from ray_tpu.llm.sampling import SamplingParams, sample_batch
 from ray_tpu.models.transformer import (
     TransformerConfig, _rms_norm, decoder_block, init_params, lane_padded, latent_absorb, latent_expand,
-    latent_scale, latent_values, layer_stacks, pad_last, param_logical_axes, scan_stack,
+    latent_scale, latent_values, pad_last, param_logical_axes, run_layers,
 )
 from ray_tpu.ops.latent_attention import latent_attention_reference, latent_paged_attention, latent_row_width
-from ray_tpu.ops.paged_attention import live_pages, paged_attention, paged_attention_reference
+from ray_tpu.ops.paged_attention import (
+    live_pages, paged_attention, paged_attention_reference, ring_pages, window_attention_reference,
+)
 from ray_tpu.util import tracing as _tracing
 
 # The seams of LLMEngine.step, in the order a step passes them (PERF.md
@@ -221,11 +234,12 @@ def _row_major(rows):
     return with_layout_constraint(rows, Layout(major_to_minor=tuple(range(rows.ndim))))
 
 
-def _prompt_attention(q, k, v, seg, mesh, scale=None):
+def _prompt_attention(q, k, v, seg, mesh, scale=None, window=0):
     """Causal attention of a (padded) prompt over its own fresh K/V. seg
     masks pad columns (pad tokens are their own segment). scale: a latent
     layer's (its keys are wider than its values, so the flash kernel gets
     both zero-padded to one lane multiple); None is 1 / sqrt(head width).
+    window: a sliding layer's (the flash kernel visits the band's blocks only).
 
     mesh: tensor-parallel serving — heads are sharded over mesh["tensor"],
     so the Pallas flash kernel runs per-shard under shard_map (GSPMD cannot
@@ -235,13 +249,13 @@ def _prompt_attention(q, k, v, seg, mesh, scale=None):
 
     def flash(q_, k_, v_, seg_):
         if scale is None:
-            return flash_attention(q_, k_, v_, causal=True, segment_ids=seg_)
+            return flash_attention(q_, k_, v_, causal=True, segment_ids=seg_, window=window)
         return flash_attention(*lane_padded(q_, k_, v_), causal=True, segment_ids=seg_,
                                scale=scale)[..., :v_.shape[-1]]
 
     with jax.named_scope("flash_attn"):
         if not flash_supported(q.shape[1]):
-            return mha_reference(q, k, v, causal=True, segment_ids=seg, scale=scale)
+            return mha_reference(q, k, v, causal=True, segment_ids=seg, scale=scale, window=window)
         if mesh is not None and mesh.shape.get("tensor", 1) > 1:
             hs = _P(None, None, "tensor", None)
             flash = jax.shard_map(
@@ -296,6 +310,22 @@ class LLMEngine:
             raise ValueError(
                 "tensor_parallel > 1 is not written for a latent cache or held experts: their "
                 "kernels run on one chip (ROADMAP M1, M3)")
+        self._window = max((kind.window for kind in cfg.kinds), default=0)  # 0: every layer keeps every token
+        if any(kind.window not in (0, self._window) for kind in cfg.kinds):
+            raise ValueError(f"window layers of one window are written; the pattern has {cfg.kinds}")
+        if self._window and self.ec.tensor_parallel > 1:
+            raise ValueError(
+                "tensor_parallel > 1 is not written for window layers: a slot's ring of pages is "
+                "addressed by the slot, not through the page table the sharded kernel walks (ROADMAP M2)")
+        if self._window and self.ec.prefix_cache:
+            raise ValueError(
+                "prefix_cache is not written for window layers: a hit copies pages, and a window layer "
+                "keeps a slot's last window of rows in a ring, which cached pages cannot restore; a "
+                "partial hit's tail prefill would attend a context those layers no longer hold (ROADMAP M2)")
+        if self._window and self.ec.chunked_prefill:
+            raise ValueError(
+                "chunked_prefill is not written for window layers: a chunk attends the earlier chunks' "
+                "pages, and a window layer keeps no pages behind its ring (ROADMAP M2)")
         if self.ec.max_seq <= 0:
             self.ec = dataclasses.replace(self.ec, max_seq=cfg.max_seq_len)
         S = self.ec.max_seq
@@ -383,14 +413,27 @@ class LLMEngine:
         # one pool [L, tokens, W] of [c | k_rope] rows (W a lane multiple).
         # Every program takes ``self.cache``, the tuple of pools, whole, and
         # slices tokens along ``self._tok_axis``.
+        # By layer kind (``self._kind_pools``: a kind's pools in the tuple): a
+        # kind without a window holds P_total pages for the page tables to
+        # share out; one with a window holds a ring of pages a slot and
+        # nothing behind it, B x ring pages whatever the contexts.
         if cfg.latent:
             self._row_width = latent_row_width(cfg.kv_lora_rank, cfg.qk_rope_head_dim)
             pools = [((L, P_total * ps, self._row_width), _P(None, None, None))]
             self._tok_axis = 1
+            self._kind_pools = {cfg.kinds[0].name: slice(0, 1)}
         else:
-            pools = [((L, cfg.kv_heads, P_total * ps, cfg.head_dim), _P(None, "tensor", None, None))] * 2
+            pools, self._kind_pools = [], {}
+            for kind in cfg.kinds:
+                tokens = (B * ring_pages(kind.window, ps) if kind.window else P_total) * ps
+                self._kind_pools[kind.name] = slice(len(pools), len(pools) + 2)
+                pools += [((cfg.layers_of(kind), cfg.kv_heads, tokens, cfg.head_dim),
+                           _P(None, "tensor", None, None))] * 2
             self._tok_axis = 2
         self.cache = tuple(_pool_zeros(shape, spec) for shape, spec in pools)
+        # stats()["startup"]: what each kind's pools take
+        self._ring_pages = ring_pages(self._window, ps) if self._window else 0
+        self.pool_bytes = {name: sum(pool.nbytes for pool in self.cache[sl]) for name, sl in self._kind_pools.items()}
         self.free_pages: deque = deque(range(1, P_total))  # page 0 = dead sink
         self.page_tables = np.zeros((B, self.ppseq), np.int32)
         self.d_page_tables = jnp.zeros((B, self.ppseq), jnp.int32)
@@ -486,25 +529,16 @@ class LLMEngine:
         return m
 
     # -- jitted programs ---------------------------------------------------
-    def _stacks(self, params) -> list:
-        """The model's stacks of identical layers in order, each with the
-        index of its first layer in the pools: [(stack params, first, n)]."""
-        out, first = [], 0
-        for stack in layer_stacks(params):
-            n = jax.tree.leaves(stack)[0].shape[0]
-            out.append((stack, first, n))
-            first += n
-        return out
-
-    def _prompt_layers(self, params, x, scan_fn, ctx=()):
-        """A prompt's hidden state through every stack of layers, by
-        ``scan_fn(h, lp, *a layer's slice of each of ctx) -> (h, rows)``;
-        returns (x, every layer's rows, one array a pool: [L, ...])."""
-        kept = []
-        for stack, first, n in self._stacks(params):
-            x, rows = scan_stack(scan_fn, x, stack, self.cfg, *(c[first:first + n] for c in ctx))
-            kept.append(rows)
-        return x, (kept[0] if len(kept) == 1 else [jnp.concatenate(r, axis=0) for r in zip(*kept)])
+    def _prompt_layers(self, params, x, scan_fn, ctx=None):
+        """A prompt's hidden state through every layer, by ``scan_fn(h, lp,
+        kind, *a layer's slice of each of ctx) -> (h, rows)``; ctx: every
+        layer's cached context, one array a pool. Returns (x, every layer's
+        rows, one array a pool, in the pools' order: [a kind's layers, ...])."""
+        xs = None
+        if ctx is not None:
+            xs = {name: tuple(ctx[sl]) for name, sl in self._kind_pools.items()}
+        x, rows = run_layers(lambda h, lp, kind, _index, *c: scan_fn(h, lp, kind, *c), x, params, self.cfg, xs)
+        return x, [r for name in self._kind_pools for r in rows[name]]
 
     def _read_pages(self, pool, page_idxs):
         """Pages ``page_idxs`` [n] of every layer, side by side along the
@@ -534,6 +568,43 @@ class LLMEngine:
                         cache[i], jax.lax.slice_in_dim(new, p * ps, (p + 1) * ps, axis=ax), at)
         return tuple(cache)
 
+    def _write_ring(self, pools, rows, ring, length):
+        """A prompt's last window of rows into a slot's ring, for one window
+        kind: ``pools`` its two pools, ``rows`` the prompt's fresh rows of its
+        layers (as the pools but the bucket's tokens long), ``ring`` the
+        slot's first ring page, ``length`` the prompt's. The pages that hold
+        positions length - window .. length - 1 are at most the ring's, and
+        page j goes to ring page j % ring; a prompt of fewer pages writes its
+        first page again where it has no further one. What the bucket padded
+        behind ``length`` lands in the last page's later rows, which a
+        query's length masks until decode has overwritten them, as in a full
+        layer's page."""
+        ps, n_ring = self.ec.page_size, self._ring_pages
+        pools = list(pools)
+        last = (length - 1) // ps
+        with jax.named_scope("kv_write"):
+            for r in range(n_ring):
+                j = jnp.maximum(last - r, 0)
+                for i, new in enumerate(rows):
+                    pools[i] = jax.lax.dynamic_update_slice(
+                        pools[i], jax.lax.dynamic_slice_in_dim(new, j * ps, ps, axis=2),
+                        (0, 0, (ring + j % n_ring) * ps, 0))
+        return pools
+
+    def _write_prompt(self, cache, rows, page_idxs, ring, length):
+        """A prompt's fresh rows (one array a pool) into the carried pools, by
+        layer kind: pages of its page table for a kind that keeps every token
+        (``_write_pages``), its last window into the slot's ring for a kind
+        with a window (``_write_ring``)."""
+        if not self._window:
+            return self._write_pages(cache, rows, page_idxs)
+        cache = list(cache)
+        for kind in self.cfg.kinds:
+            sl = self._kind_pools[kind.name]
+            cache[sl] = (self._write_ring(cache[sl], rows[sl], ring, length) if kind.window
+                         else self._write_pages(cache[sl], rows[sl], page_idxs))
+        return tuple(cache)
+
     def _copy_pages_impl(self, cache, src, dst):
         """A prefix-cache hit's pages ``src`` copied onto ``dst`` ([ppseq]
         each). Every source page is read before the first is written: a
@@ -546,15 +617,16 @@ class LLMEngine:
         kv_heads-sharded or on one chip (PERF.md section 6, PR 29)."""
         return self._write_pages(cache, [self._read_pages(pool, src) for pool in cache], dst)
 
-    def _prompt_attend(self, lp, seg, dtypes):
+    def _prompt_attend(self, lp, seg, dtypes, kind):
         """The ``attend`` of a prompt over its own fresh rows, and what it
-        keeps of them for the pools: the K and V rows of a head, or a latent
-        layer's [c | k_rope] rows (expanded to keys and values here, for the
-        prompt alone)."""
+        keeps of them for the pools: the K and V rows of a head (attended
+        inside the kind's window where it has one), or a latent layer's
+        [c | k_rope] rows (expanded to keys and values here, for the prompt
+        alone)."""
         cfg = self.cfg
         if not cfg.latent:
             def attend(q, k, v):
-                o = _prompt_attention(q, k, v, seg, self.mesh)
+                o = _prompt_attention(q, k, v, seg, self.mesh, window=kind.window)
                 return o, (_kv_rows(k, dtypes[0]), _kv_rows(v, dtypes[1]))
             return attend
 
@@ -564,9 +636,10 @@ class LLMEngine:
             return o, (_row_major(_latent_rows(c[0], k_rope[0], self._row_width, dtypes[0])),)
         return attend
 
-    def _prefill_impl(self, params, cache, tokens, length, page_idxs, key, temp, top_p, top_k):
+    def _prefill_impl(self, params, cache, tokens, length, page_idxs, key, temp, top_p, top_k, ring=None):
         """tokens: [P] (padded to the bucket); page_idxs: [P // ps] page ids
-        (trailing entries may be 0 = dead sink). Returns the pools with the
+        (trailing entries may be 0 = dead sink); ring: the slot's first ring
+        page, for a model with window layers. Returns the pools with the
         prompt's pages written and the first generated token. Attention
         runs on the layer's fresh K/V, so the layer scan never sees a pool:
         it hands out every layer's rows as ``ys`` and the pages are written
@@ -579,12 +652,12 @@ class LLMEngine:
         seg = (pos >= length).astype(jnp.int32)  # pads = their own segment
         dtypes = [pool.dtype for pool in cache]
 
-        def scan_fn(h, lp):
-            h, _aux, rows = decoder_block(h, lp, cfg, pos, self._prompt_attend(lp, seg, dtypes))
+        def scan_fn(h, lp, kind):
+            h, _aux, rows = decoder_block(h, lp, cfg, pos, self._prompt_attend(lp, seg, dtypes, kind), kind)
             return h, rows
 
         x, rows = self._prompt_layers(params, x, scan_fn)  # rows[i]: [L,KV,P,Hd] or [L,P,W]
-        cache = self._write_pages(cache, rows, page_idxs)
+        cache = self._write_prompt(cache, rows, page_idxs, ring, length)
         with jax.named_scope("lm_head"):
             x = _rms_norm(x, params["final_norm"], cfg.norm_eps)
             last = jax.lax.dynamic_index_in_dim(x[0], length - 1, axis=0, keepdims=False)
@@ -594,29 +667,40 @@ class LLMEngine:
                                top_k[None], key, cap=self.ec.sample_topk_cap)[0]
         return cache, tok
 
-    def _decode_attend(self, lp, pools, seen, page_tables, layer, walk):
-        """The ``attend`` of one decode step in one layer: the step's rows
-        written at position seen - 1 of each slot's pages and the queries
-        attended over the pages, by the kernel where it can run (on the
-        step's walk of live pages) and by the einsum reference elsewhere,
-        which GSPMD partitions as-is under TP. A latent layer absorbs its
-        key and value projections into the query and the output here."""
+    def _decode_attend(self, lp, kind, pools, seen, page_tables, layer, walks):
+        """The ``attend`` of one decode step in one layer of ``kind``, the
+        ``layer``-th of its kind: the step's rows written at position
+        seen - 1 of each slot's pages (of its ring, in a layer with a window)
+        and the queries attended over them, by the kernel where it can run
+        (on the step's walk of the kind's live pages) and by the einsum
+        reference elsewhere, which GSPMD partitions as-is under TP. A latent
+        layer absorbs its key and value projections into the query and the
+        output here. Hands on every pool, the kind's own replaced."""
         cfg = self.cfg
-        on_tpu = walk is not None  # decided once a program, where the walk is built
+        on_tpu = walks is not None  # decided once a program, where the walks are built
+        sl = self._kind_pools[kind.name]
         if not cfg.latent:
-            paged_attend = functools.partial(
-                paged_attention, mesh=self.mesh, walk=walk) if on_tpu else paged_attention_reference
+            if not on_tpu:
+                paged_attend = paged_attention_reference if not kind.window else (
+                    lambda q, k, v, kp, vp, seen, _table, layer:
+                    window_attention_reference(q, k, v, kp, vp, seen, layer, kind.window))
+            elif kind.window:
+                paged_attend = functools.partial(paged_attention, walk=walks[kind.window], window=kind.window)
+            else:
+                paged_attend = functools.partial(paged_attention, mesh=self.mesh, walk=walks[0])
 
             def attend(q, k_new, v_new):
-                with jax.named_scope("paged_attn"):
+                with jax.named_scope("window_attn" if kind.window else "paged_attn"):
                     # writes k_new / v_new at position lens of each slot's
-                    # pages (page_tables[b, lens // ps], offset lens % ps)
+                    # pages (page_tables[b, lens // ps], offset lens % ps; of
+                    # its ring, in a layer with a window)
                     o, kp2, vp2 = paged_attend(
-                        q[:, 0], k_new[:, 0], v_new[:, 0], *pools, seen, page_tables, layer,
+                        q[:, 0], k_new[:, 0], v_new[:, 0], *pools[sl], seen, page_tables, layer,
                     )  # o: [B, H, Hd]
-                return o[:, None], (kp2, vp2)
+                return o[:, None], pools[:sl.start] + (kp2, vp2) + pools[sl.stop:]
             return attend
 
+        walk = walks[0] if on_tpu else None
         latent_attend = functools.partial(
             latent_paged_attention, walk=walk) if on_tpu else latent_attention_reference
 
@@ -662,22 +746,24 @@ class LLMEngine:
             # The step's walk of live pages, built here, once for all layers:
             # lengths change between steps and not between layers.
             seen = lens + 1  # the kernel's lengths count the step's own token
-            walk = live_pages(seen, page_tables, ps) if jax.default_backend() == "tpu" else None
+            # two walks at most: the layers that keep every token (0) and the window layers
+            walks = ({w: live_pages(seen, page_tables, ps, w) for w in sorted({0, self._window})}
+                     if jax.default_backend() == "tpu" else None)
             with jax.named_scope("embed"):
                 x = params["embed"].astype(cfg.dtype)[last][:, None, :]  # [B,1,D]
 
-            def scan_fn(carry, lp, layer):
+            def scan_fn(carry, lp, kind, layer):
                 h, pools = carry
                 h, aux, pools = decoder_block(
-                    h, lp, cfg, lens[:, None], self._decode_attend(lp, pools, seen, page_tables, layer, walk))
+                    h, lp, cfg, lens[:, None],
+                    self._decode_attend(lp, kind, pools, seen, page_tables, layer, walks), kind)
                 return (h, pools), (aux if cfg.experts_held and "router" in lp else None)
 
+            (x, pools), auxes = run_layers(scan_fn, (x, pools), params, cfg)
             counts = None
-            for stack, first, n in self._stacks(params):
-                layers = jnp.arange(first, first + n, dtype=jnp.int32)
-                (x, pools), aux = scan_stack(scan_fn, (x, pools), stack, cfg, layers)
+            for aux in auxes.values():  # of a kind's routed layers; None where it has none
                 if aux is not None:
-                    counts = jnp.sum(aux, axis=0)
+                    counts = jnp.sum(aux, axis=0) if counts is None else counts + jnp.sum(aux, axis=0)
             with jax.named_scope("lm_head"):
                 x = _rms_norm(x, params["final_norm"], cfg.norm_eps)
                 logits = jnp.einsum("bsd,dv->bv", x, params["lm_head"].astype(cfg.dtype))
@@ -701,7 +787,8 @@ class LLMEngine:
             counts = jnp.sum(counts, axis=0)
         return tuple(pool.reshape(shape) for pool, shape in zip(pools, flat)), toks, last, lengths, counts
 
-    def _prefill_batch_impl(self, params, cache, tokens, lengths, page_rows, key, temps, top_ps, top_ks):
+    def _prefill_batch_impl(self, params, cache, tokens, lengths, page_rows, key, temps, top_ps, top_ks,
+                            rings=None):
         """Prefill k requests of one length bucket in ONE device program
         (scan over requests around the single-request body): one dispatch
         and one set of host-built arrays per admitted group instead of one
@@ -711,16 +798,17 @@ class LLMEngine:
         device time: the k requests run one after another and each reads
         the weights. The request scan carries the donated pools; each
         request writes its pages into them in place (_write_pages).
-        tokens: [k, P]; page_rows: [k, P // ps], each request's pages."""
+        tokens: [k, P]; page_rows: [k, P // ps], each request's pages; rings:
+        [k], each request's slot's first ring page (a model with window
+        layers; None without)."""
         keys = jax.random.split(key, tokens.shape[0])
 
         def scan_req(cache, xs):
-            toks_i, len_i, pages_i, key_i, t_i, p_i, k_i = xs
-            return self._prefill_impl(params, cache, toks_i, len_i, pages_i, key_i, t_i, p_i, k_i)
+            return self._prefill_impl(params, cache, *xs)
 
+        xs = (tokens, lengths, page_rows, keys, temps, top_ps, top_ks)
         return jax.lax.scan(  # (cache, toks [k])
-            scan_req, cache, (tokens, lengths, page_rows, keys, temps, top_ps, top_ks)
-        )
+            scan_req, cache, xs if rings is None else (*xs, rings))
 
     def _tail_prefill_impl(self, params, cache, tokens, start, length,
                            ctx_pages, tail_pages, key, temp, top_p, top_k):
@@ -757,9 +845,9 @@ class LLMEngine:
         mask = jnp.concatenate([ctx_mask, tail_mask], axis=1)
         dtypes = [pool.dtype for pool in cache]
 
-        def heads_attend(ctx_k, ctx_v):
+        def heads_attend(kind, ctx_k, ctx_v):
             KV, Hd = cfg.kv_heads, cfg.head_dim
-            group = cfg.n_heads // KV
+            group = kind.n_heads // KV
 
             def attend(q, k_new, v_new):
                 kt = _kv_rows(k_new, dtypes[0])  # [KV,Tb,Hd]
@@ -771,7 +859,7 @@ class LLMEngine:
                 scores = scores / math.sqrt(Hd)
                 scores = jnp.where(mask[:, None, None, :], scores, -1e30)
                 pr = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-                o = jnp.einsum("tkgs,ksh->tkgh", pr, vall).reshape(1, Tb, cfg.n_heads, Hd)
+                o = jnp.einsum("tkgs,ksh->tkgh", pr, vall).reshape(1, Tb, kind.n_heads, Hd)
                 return o, (kt, vt)
             return attend
 
@@ -788,9 +876,9 @@ class LLMEngine:
                 return jnp.einsum("bhts,bshk->bthk", pr, v), (rows,)
             return attend
 
-        def scan_fn(h, lp, *ctx):
-            attend = latent_attend(lp, *ctx) if cfg.latent else heads_attend(*ctx)
-            h, _aux, rows = decoder_block(h, lp, cfg, pos, attend)
+        def scan_fn(h, lp, kind, *ctx):
+            attend = latent_attend(lp, *ctx) if cfg.latent else heads_attend(kind, *ctx)
+            h, _aux, rows = decoder_block(h, lp, cfg, pos, attend, kind)
             return h, rows
 
         # The cached context of every layer, gathered once from the whole
@@ -902,6 +990,9 @@ class LLMEngine:
                     jnp.zeros(k, jnp.float32), jnp.ones(k, jnp.float32),
                     jnp.zeros(k, jnp.int32),
                 )
+                if self._window:
+                    # slot 0's ring takes the dummy rows: a length masks whatever a ring held before
+                    args += (jnp.zeros(k, jnp.int32),)
                 entry = {"program": "prefill", "bucket": b, "k": k}
                 if "prefill" not in self.mosaic:
                     # The first program only: compiling all of them ahead
@@ -1143,7 +1234,8 @@ class LLMEngine:
         step's record goes to the ring that LLMServer.stats() returns."""
         ph = self._phases
         ph.begin("admit", waiting=len(self.waiting), n_admitted=0, n_prefill=0,
-                 block=0, active=0, sampled=0, live_pages=0, expert_pairs=0, expert_tiles=0)
+                 block=0, active=0, sampled=0, live_pages=0, expert_pairs=0, expert_tiles=0,
+                 **({"window_pages": 0, "window_tokens": 0} if self._window else {}))
         try:
             return self._step(ph)
         finally:
@@ -1299,12 +1391,16 @@ class LLMEngine:
                     pgs[j] = self.page_tables[i, :n_pg]  # trailing zeros -> dead sink
                 idx_arr = jnp.asarray(np.asarray(idxs, np.int32))
                 self._key, sub = jax.random.split(self._key)
+                rings = ()
+                if self._window:  # each slot's first ring page, the same in every window kind's pools
+                    rings = (jnp.asarray(np.asarray(idxs, np.int32) * self._ring_pages),)
                 self.cache, toks_dev = self._prefill(bucket, k)(
                     self.params, self.cache,
                     jnp.asarray(padded), jnp.asarray(lens), jnp.asarray(pgs), sub,
                     jnp.asarray(self.samp_temps[idxs]),
                     jnp.asarray(self.samp_top_ps[idxs]),
                     jnp.asarray(self.samp_top_ks[idxs]),
+                    *rings,
                 )
                 ph.to("mirror_sync")
                 self.d_lengths = self.d_lengths.at[idx_arr].set(jnp.asarray(lens))
@@ -1449,6 +1545,8 @@ class LLMEngine:
         if toks is not None:
             ph.rec["block"] = n
             ph.rec["live_pages"] = self._live_pages(active, n)
+            if self._window:
+                ph.rec["window_pages"], ph.rec["window_tokens"] = self._window_walk(active, n)
             ph.to("decode_fetch")
             if counts is None:
                 block_toks = np.asarray(jax.device_get(toks))  # [n, B]
@@ -1490,7 +1588,7 @@ class LLMEngine:
 
     def _live_pages(self, active: list[int], n: int) -> int:
         """The page steps the paged kernel walks in a decode block of ``n``
-        steps, a layer: every slot's ceil(length / page_size) at each step,
+        steps, a layer that keeps every token: every slot's ceil(length / page_size) at each step,
         the step's own token counted; a slot that is not ``active`` (empty,
         or masked while it prefills) costs the one step on dead page 0 it is
         held at. Over n x max_slots x (max_seq / page_size) it is the share
@@ -1500,6 +1598,20 @@ class LLMEngine:
         held = self.ec.max_slots - len(active)
         seen = self.lengths[active][None, :] + np.arange(1, n + 1)[:, None]
         return int(np.minimum(-(-seen // ps), self.ppseq).sum()) + held * n
+
+    def _window_walk(self, active: list[int], n: int) -> tuple:
+        """(page steps, positions attended) of ONE window layer in a decode
+        block of ``n`` steps, as ``_live_pages`` counts a full layer's: a
+        slot walks the pages from the one that holds position seen - window
+        to the current one and attends min(seen, window) positions, seen its
+        length with the step's own token; a slot that is not ``active`` is
+        held at one step on its ring's first page and attends its one token
+        (not counted as a position: nothing reads it)."""
+        ps, w = self.ec.page_size, self._window
+        seen = self.lengths[active][None, :] + np.arange(1, n + 1)[:, None]
+        pages = (seen - 1) // ps - np.maximum(seen - w, 0) // ps + 1
+        held = self.ec.max_slots - len(active)
+        return int(pages.sum()) + held * n, int(np.minimum(seen, w).sum())
 
     def _maybe_finish(self, i: int, events: dict) -> bool:
         slot = self.slots[i]

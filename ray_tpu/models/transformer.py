@@ -32,6 +32,31 @@ from ray_tpu.parallel.sharding import with_logical_constraint as wlc
 
 
 @dataclasses.dataclass(frozen=True)
+class LayerKind:
+    """One kind of attention layer in a model whose layers are not all alike:
+    its query heads, its window and its rope. Two layers of one kind have
+    weights of one shape and cache what the same rule keeps."""
+    name: str  # the key of this kind's stack in params["kind_layers"], of its pools in the engine
+    n_heads: int
+    # Position i sees j with i - window < j <= i (its own among them); 0: every j <= i.
+    window: int = 0
+    rope_theta: float = 10_000.0
+    rope_share: float = 1.0  # the leading share of a head's columns that is roped; the rest pass
+    # YaRN (factor 0: plain rope): frequencies below the original length's
+    # reach divided by `yarn_factor`, blended between the two betas' columns,
+    # and cos / sin multiplied by `attention_factor`.
+    yarn_factor: float = 0.0
+    yarn_original_len: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+    @property
+    def plain_rope(self) -> bool:
+        return self.rope_share == 1.0 and not self.yarn_factor and self.attention_factor == 1.0
+
+
+@dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     vocab_size: int = 32_000
     d_model: int = 512
@@ -92,22 +117,70 @@ class TransformerConfig:
     n_shared_experts: int = 0
     routed_scaling: float = 1.0
     router_score: str = "softmax"  # softmax | sigmoid, over the router's logits in float32
+    # A head's width; 0 -> d_model // n_heads.
+    head_dim: int = 0
+    # Layers of more than one kind ("gqa" only): the LayerKinds of one period,
+    # layer l being of kind layer_pattern[l % len]. The leading dense layers
+    # are of one kind and lie in params["dense_layers"]; the layers after them
+    # are whole periods, each kind's in one stack of params["kind_layers"],
+    # and run as one scan over periods (run_layers). Empty: every layer is of
+    # the one kind n_heads and rope_theta describe, params["layers"].
+    layer_pattern: tuple = ()
+    # "per_head": each head's attention output times sigmoid(h Wg_h), h the
+    # layer's normed input, before the output projection.
+    attn_gate: str = ""
 
     @property
     def kv_heads(self) -> int:
         return self.n_kv_heads or self.n_heads
 
     @property
-    def head_dim(self) -> int:
-        return self.d_model // self.n_heads
-
-    @property
     def latent(self) -> bool:
         return self.attention_kind == "latent"
 
+    @property
+    def kinds(self) -> tuple:
+        """The model's distinct layer kinds, in the order layers first meet them."""
+        if not self.layer_pattern:
+            return (LayerKind("layers", self.n_heads, rope_theta=self.rope_theta),)
+        return tuple(dict.fromkeys(self.layer_pattern))
+
+    def kind_of(self, layer: int) -> LayerKind:
+        return self.layer_pattern[layer % len(self.layer_pattern)] if self.layer_pattern else self.kinds[0]
+
+    def layers_of(self, kind: LayerKind) -> int:
+        return sum(self.kind_of(l) == kind for l in range(self.n_layers))
+
+    @property
+    def period(self) -> tuple:
+        """The kinds of one period in the order the layers after the leading
+        dense ones pass them."""
+        p = len(self.layer_pattern)
+        return tuple(self.kind_of(self.n_dense_layers + j) for j in range(p))
+
+    @property
+    def n_periods(self) -> int:
+        return (self.n_layers - self.n_dense_layers) // len(self.layer_pattern)
+
     def __post_init__(self):
-        assert self.d_model % self.n_heads == 0
+        if not self.head_dim:
+            assert self.d_model % self.n_heads == 0
+            object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
         assert self.n_heads % self.kv_heads == 0
+        assert self.attn_gate in ("", "per_head") and not (self.attn_gate and self.latent), "a per-head gate, on gqa layers"
+        if self.layer_pattern:
+            object.__setattr__(self, "layer_pattern", tuple(self.layer_pattern))
+            p = len(self.layer_pattern)
+            assert not self.latent, "a layer pattern is written for gqa layers"
+            assert len({k.name for k in self.kinds}) == len(self.kinds), "two kinds under one name"
+            assert all(k.n_heads % self.kv_heads == 0 for k in self.kinds)
+            assert len({self.kind_of(l) for l in range(self.n_dense_layers)}) <= 1, (
+                "the leading dense layers are one stack: of one kind")
+            if (self.n_layers - self.n_dense_layers) % p:
+                raise ValueError(
+                    f"a trailing partial period is not written: {self.n_layers} layers, "
+                    f"{self.n_dense_layers} of them leading dense ones, leave "
+                    f"{self.n_layers - self.n_dense_layers} for periods of {p} (run_layers scans whole periods)")
         assert self.attention_kind in ("gqa", "latent"), self.attention_kind
         assert 0 <= self.n_dense_layers <= self.n_layers
         assert self.router_score in ("softmax", "sigmoid"), self.router_score
@@ -130,13 +203,14 @@ def _dense_init(key, shape, dtype, in_axis=0):
     return jax.random.normal(key, shape, dtype) * scale
 
 
-def _init_stack(key: jax.Array, cfg: TransformerConfig, L: int, routed: bool) -> tuple:
-    """One stack of L identical layers (leading 'layers' dim on every leaf);
-    `routed`: the FFN is experts behind a router, else dense of width d_ff.
+def _init_stack(key: jax.Array, cfg: TransformerConfig, L: int, routed: bool, kind: LayerKind | None = None) -> tuple:
+    """One stack of L identical layers of `kind` (None: the model's one kind;
+    leading 'layers' dim on every leaf); `routed`: the FFN is experts behind
+    a router, else dense of width d_ff.
     Returns (the stack, the iterator over the keys it left)."""
     pd = cfg.param_dtype
     k = iter(jax.random.split(key, 16))
-    D, F, H = cfg.d_model, cfg.d_ff, cfg.n_heads
+    D, F, H = cfg.d_model, cfg.d_ff, (kind or cfg.kinds[0]).n_heads
     if cfg.latent:
         R, Rq = cfg.kv_lora_rank, cfg.q_lora_rank
         nope, rope, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
@@ -162,6 +236,8 @@ def _init_stack(key: jax.Array, cfg: TransformerConfig, L: int, routed: bool) ->
             "wo": _dense_init(next(k), (L, H, Hd, D), pd, in_axis=(1, 2)),
             "ffn_norm": jnp.ones((L, D), pd),
         }
+        if cfg.attn_gate:
+            layer["wg"] = _dense_init(next(k), (L, D, H), pd, in_axis=1)
     if cfg.sandwich_norm:
         layer.update({"post_attn_norm": jnp.ones((L, D), pd), "post_ffn_norm": jnp.ones((L, D), pd)})
     if routed:
@@ -198,26 +274,32 @@ def _init_stack(key: jax.Array, cfg: TransformerConfig, L: int, routed: bool) ->
 def init_params(key: jax.Array, cfg: TransformerConfig) -> dict:
     """Stacked-layer parameter pytree: ``layers`` (leading 'layers' dim on
     every leaf) and, for a model with leading dense layers, ``dense_layers``
-    before it; a model with none has no such key (an empty first stack)."""
+    before it; a model with none has no such key (an empty first stack). A
+    model with a layer pattern has, in place of ``layers``, ``kind_layers``:
+    {a kind's name: the stack of that kind's layers after the dense ones, in
+    the order a token passes them}."""
     pd = cfg.param_dtype
     D = cfg.d_model
-    layer, k = _init_stack(key, cfg, cfg.n_layers - cfg.n_dense_layers, routed=bool(cfg.n_experts))
+    routed = bool(cfg.n_experts)
+    if cfg.layer_pattern:
+        k = iter(jax.random.split(jax.random.fold_in(key, 2), 2))
+        stacks = {"kind_layers": {
+            kind.name: _init_stack(jax.random.fold_in(key, 3 + i), cfg,
+                                   cfg.n_periods * cfg.period.count(kind), routed, kind)[0]
+            for i, kind in enumerate(dict.fromkeys(cfg.period))}}
+    else:
+        layer, k = _init_stack(key, cfg, cfg.n_layers - cfg.n_dense_layers, routed)
+        stacks = {"layers": layer}
     params = {
         "embed": _dense_init(next(k), (cfg.vocab_size, D), pd) * (D ** 0.5),
-        "layers": layer,
+        **stacks,
         "final_norm": jnp.ones((D,), pd),
         "lm_head": _dense_init(next(k), (D, cfg.vocab_size), pd, in_axis=0),
     }
     if cfg.n_dense_layers:
         params["dense_layers"], _ = _init_stack(
-            jax.random.fold_in(key, 1), cfg, cfg.n_dense_layers, routed=False)
+            jax.random.fold_in(key, 1), cfg, cfg.n_dense_layers, routed=False, kind=cfg.kind_of(0))
     return params
-
-
-def layer_stacks(params: dict) -> list:
-    """The model's stacks of identical layers, in the order a token passes
-    them: the leading dense layers where it has any, then ``layers``."""
-    return [params[name] for name in ("dense_layers", "layers") if name in params]
 
 
 HELD_EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
@@ -241,7 +323,94 @@ def scan_stack(body, carry, stack: dict, cfg: TransformerConfig, *xs):
                     carry, (scanned, index, *xs))
 
 
+def _scan_periods(body, carry, stacks: dict, cfg: TransformerConfig, first: dict, xs: dict | None = None):
+    """``lax.scan`` over the periods of a model with a layer pattern: the
+    scan's body runs one period, ``body(carry, lp, kind, index) -> (carry,
+    y)`` once for each of its layers in order. The kinds' stacks are whole
+    operands of the loop and a layer's parameters are its index's slice of
+    each (what a scan makes of its xs; a period's slice of a stack, taken
+    first and then indexed by the layer, is a copy of it: 170 MB for three
+    sliding layers' wq at the serve cell's widths, as compiled for a v5e);
+    the held experts' matrices are handed on whole with the layer's index in
+    its kind's stack, as in ``scan_stack``. `index` counts the layer among
+    ALL of its kind's (`first[kind.name]` leading ones came before these
+    stacks), and is where a layer's slice of `xs` (one tuple of arrays a
+    kind, every layer of the kind along the leading axis) is taken from,
+    handed to body behind the index. Returns (carry, {a kind's name: its
+    layers' y, stacked in order})."""
+    period = cfg.period
+    a_period = {kind.name: period.count(kind) for kind in period}
+    held = cfg.experts_held and all("router" in stack for stack in stacks.values())
+
+    def layer_of(tree, at):
+        return jax.tree.map(lambda a: lax.dynamic_index_in_dim(a, at, 0, keepdims=False), tree)
+
+    def one_period(carry, t):
+        seen = dict.fromkeys(a_period, 0)
+        ys = {name: [] for name in a_period}
+        for kind in period:
+            j, c, stack = seen[kind.name], a_period[kind.name], stacks[kind.name]
+            seen[kind.name] += 1
+            at = t * c + j  # in its kind's stack
+            if held:
+                lp = layer_of({k: v for k, v in stack.items() if k not in HELD_EXPERT_WEIGHTS}, at)
+                lp.update({k: stack[k] for k in HELD_EXPERT_WEIGHTS}, expert_layer=at)
+            else:
+                lp = layer_of(stack, at)
+            index = first[kind.name] + at
+            carry, y = body(carry, lp, kind, index, *(() if xs is None else layer_of(tuple(xs[kind.name]), index)))
+            ys[kind.name].append(y)
+        return carry, {name: jax.tree.map(lambda *a: jnp.stack(a), *y) for name, y in ys.items()}
+
+    carry, ys = lax.scan(one_period, carry, jnp.arange(cfg.n_periods, dtype=jnp.int32))
+    # [periods, a kind's layers a period, ...] -> [the kind's layers, ...]
+    return carry, {name: jax.tree.map(lambda a: a.reshape(-1, *a.shape[2:]), y) for name, y in ys.items()}
+
+
+def run_layers(body, carry, params: dict, cfg: TransformerConfig, xs: dict | None = None):
+    """Every layer of the model in the order a token passes them:
+    ``body(carry, lp, kind, index, *xs_i) -> (carry, y)``, lp one layer's
+    parameters (scan_stack's), kind its LayerKind, index its place among the
+    layers of its kind (an int32 scalar: what a cache kept by kind is indexed
+    with), xs_i the layer's slice of each array of ``xs[kind.name]`` (arrays
+    with every layer of the kind along the leading axis; scanned operands of a
+    stack's scan).
+    The leading dense layers where the model has any, then ``layers`` as one
+    scan, or for a model with a layer pattern its periods (_scan_periods).
+    Returns (carry, {a kind's name: y of its layers [that kind's layers,
+    ...], in order})."""
+    first = {kind.name: 0 for kind in cfg.kinds}
+    ys: dict = {}
+
+    def stack_of(name, kind):
+        nonlocal carry
+        n = jax.tree.leaves(params[name])[0].shape[0]
+        at = first[kind.name]
+        index = jnp.arange(at, at + n, dtype=jnp.int32)
+        extra = () if xs is None else tuple(a[at:at + n] for a in xs[kind.name])
+        carry, y = scan_stack(lambda c, lp, i, *x: body(c, lp, kind, i, *x), carry, params[name], cfg, index, *extra)
+        first[kind.name] += n
+        ys.setdefault(kind.name, []).append(y)
+
+    if "dense_layers" in params:
+        stack_of("dense_layers", cfg.kind_of(0))
+    if cfg.layer_pattern:
+        carry, y = _scan_periods(body, carry, params["kind_layers"], cfg, first, xs)
+        for name, v in y.items():
+            ys.setdefault(name, []).append(v)
+    else:
+        stack_of("layers", cfg.kinds[0])
+    def joined(v):
+        v = [y for y in v if y is not None]  # a stack whose layers hand nothing out
+        if len(v) < 2:
+            return v[0] if v else None
+        return jax.tree.map(lambda *a: jnp.concatenate(a, axis=0), *v)
+
+    return carry, {name: joined(v) for name, v in ys.items()}
+
+
 def _stack_logical_axes(cfg: TransformerConfig, routed: bool) -> dict:
+    """A stack's logical axes (the same for every kind: kinds differ in sizes)."""
     if cfg.latent:
         layer = {
             "attn_norm": ("layers", "embed"),
@@ -264,6 +433,8 @@ def _stack_logical_axes(cfg: TransformerConfig, routed: bool) -> dict:
             "wo": ("layers", "heads", "head_dim", "embed"),
             "ffn_norm": ("layers", "embed"),
         }
+        if cfg.attn_gate:
+            layer["wg"] = ("layers", "embed", "heads")
     if cfg.sandwich_norm:
         layer.update({"post_attn_norm": ("layers", "embed"), "post_ffn_norm": ("layers", "embed")})
     if routed:
@@ -296,9 +467,11 @@ def _stack_logical_axes(cfg: TransformerConfig, routed: bool) -> dict:
 
 def param_logical_axes(cfg: TransformerConfig) -> dict:
     """Same-structure pytree of logical-axis tuples (see LOGICAL_AXES)."""
+    layers = _stack_logical_axes(cfg, routed=bool(cfg.n_experts))
     axes = {
         "embed": ("vocab", "embed"),
-        "layers": _stack_logical_axes(cfg, routed=bool(cfg.n_experts)),
+        **({"kind_layers": {kind.name: layers for kind in dict.fromkeys(cfg.period)}}
+           if cfg.layer_pattern else {"layers": layers}),
         "final_norm": ("embed",),
         "lm_head": ("embed", "vocab"),
     }
@@ -329,7 +502,46 @@ def _rope(x, positions, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
 
 
-def _flash(q, k, v, cfg: TransformerConfig, segment_ids, scale=None):
+def rope_inv_freq(kind: LayerKind, width: int):
+    """The `width // 2` rotation frequencies of a kind's roped columns
+    (numpy, float64: constants of the program). Plain: theta^(-2i/width).
+    YaRN: with c(r) = width ln(L / (2 pi r)) / (2 ln theta) the column at
+    which a frequency turns r times over the original length L, columns
+    below low = floor(c(beta_fast)) keep their frequency, those above high =
+    ceil(c(beta_slow)) have it divided by the factor, a linear blend between."""
+    import numpy as np
+
+    half = width // 2
+    e = kind.rope_theta ** (-np.arange(half, dtype=np.float64) * 2.0 / width)
+    if not kind.yarn_factor:
+        return e
+
+    def column(turns):
+        return width * math.log(kind.yarn_original_len / (2 * math.pi * turns)) / (2 * math.log(kind.rope_theta))
+
+    low = max(math.floor(column(kind.yarn_beta_fast)), 0)
+    high = min(math.ceil(column(kind.yarn_beta_slow)), width - 1)
+    keep = 1.0 - np.clip((np.arange(half, dtype=np.float64) - low) / max(high - low, 0.001), 0.0, 1.0)
+    return e / kind.yarn_factor * (1.0 - keep) + e * keep
+
+
+def _rope_kind(x, positions, kind: LayerKind):
+    """x [B, S, H, Hd] roped as its layer's kind says: the leading
+    rope_share of a head's columns rotated (rotate-half inside them) by the
+    kind's frequencies, cos and sin times its attention_factor; the rest pass."""
+    if kind.plain_rope:
+        return _rope(x, positions, kind.rope_theta)
+    width = int(x.shape[-1] * kind.rope_share)
+    half = width // 2
+    freqs = jnp.asarray(rope_inv_freq(kind, width), jnp.float32)
+    angles = positions[..., None].astype(jnp.float32) * freqs  # [B, S, half]
+    cos = (jnp.cos(angles) * kind.attention_factor)[:, :, None, :].astype(x.dtype)
+    sin = (jnp.sin(angles) * kind.attention_factor)[:, :, None, :].astype(x.dtype)
+    x1, x2 = x[..., :half], x[..., half:width]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin, x[..., width:]], axis=-1)
+
+
+def _flash(q, k, v, cfg: TransformerConfig, segment_ids, scale=None, window=0):
     """The flash kernel on this device's shard. Under a multi-device mesh the
     call is shard_map'd over the batch and head axes the active strategy
     shards: GSPMD cannot partition a Mosaic kernel, and jax refuses to lower
@@ -341,7 +553,7 @@ def _flash(q, k, v, cfg: TransformerConfig, segment_ids, scale=None):
 
     def local(q, k, v, seg):
         return flash_attention(
-            q, k, v, causal=True, segment_ids=seg, scale=scale,
+            q, k, v, causal=True, segment_ids=seg, scale=scale, window=window,
             block_q=cfg.attention_block_q or DEFAULT_BLOCK_Q,
             block_k=cfg.attention_block_k or DEFAULT_BLOCK_K,
         )
@@ -364,8 +576,9 @@ def _flash(q, k, v, cfg: TransformerConfig, segment_ids, scale=None):
     )(q, k, v, segment_ids)
 
 
-def _attention(q, k, v, cfg: TransformerConfig, positions=None, segment_ids=None, scale=None):
-    """Dispatch to the configured attention implementation.
+def _attention(q, k, v, cfg: TransformerConfig, positions=None, segment_ids=None, scale=None, window=0):
+    """Dispatch to the configured attention implementation. window: a
+    sliding layer's (0: none); flash and the reference take it.
 
     q: [B,S,H,D]; k,v: [B,S,KV,D] — flash and reference handle grouped KV
     natively (no repeat: the KV HBM-footprint saving is the point of GQA);
@@ -378,11 +591,13 @@ def _attention(q, k, v, cfg: TransformerConfig, positions=None, segment_ids=None
         impl = "flash" if flash_supported(q.shape[1]) else "reference"
     if impl == "flash":
         if scale is None:
-            return _flash(q, k, v, cfg, segment_ids)
+            return _flash(q, k, v, cfg, segment_ids, window=window)
         # A latent layer: keys wider than values; the kernel takes one width.
         return _flash(*lane_padded(q, k, v), cfg, segment_ids, scale)[..., :v.shape[-1]]
     if scale is not None and impl != "reference":
         raise NotImplementedError(f"attention_impl={impl!r} is not written for latent attention")
+    if window and impl != "reference":
+        raise NotImplementedError(f"attention_impl={impl!r} is not written for an attention window")
     if impl == "ring":
         from ray_tpu.ops.ring_attention import ring_attention
 
@@ -404,7 +619,7 @@ def _attention(q, k, v, cfg: TransformerConfig, positions=None, segment_ids=None
         )
     from ray_tpu.ops.attention import mha_reference
 
-    return mha_reference(q, k, v, causal=True, segment_ids=segment_ids, scale=scale)
+    return mha_reference(q, k, v, causal=True, segment_ids=segment_ids, scale=scale, window=window)
 
 
 def _dense_ffn(x, p):
@@ -521,7 +736,31 @@ def _expert_tile(tokens: int, cfg: TransformerConfig) -> int:
     return min(256, max(16, 1 << max(expect - 1, 0).bit_length()))
 
 
+PAIRS_A_PASS = 32_768  # (token, expert) pairs one pass of _held_experts_ffn lays out
+
+
 def _held_experts_ffn(x, p, cfg: TransformerConfig):
+    """``_held_experts_pass`` over x [B, S, D], a prompt of many tokens in
+    passes of at most PAIRS_A_PASS pairs: the rows laid out for the grouped
+    matmul hold every pair a pass could send here (all of them), so one pass
+    over 8,192 tokens of 10 choices would take 90,112 rows, 0.55 GB a copy of
+    them and 1.0 GB for the weighted pairs in float32 (3.9 GB of temporaries
+    in that prefill program, as compiled for a v5e). The tokens are padded
+    with zero rows to whole passes, whose results are dropped; the counts are
+    summed over the passes (padding's pairs among them: counts are read of
+    decode steps, which are one pass)."""
+    B, S, D = x.shape
+    T = B * S
+    size = 1 << ((PAIRS_A_PASS // cfg.expert_top_k).bit_length() - 1)  # tokens a pass, a power of two
+    if T <= size:
+        return _held_experts_pass(x, p, cfg)
+    n = -(-T // size)
+    xt = jnp.pad(x.reshape(T, D), ((0, n * size - T), (0, 0))).reshape(n, 1, size, D)
+    out, counts = lax.map(lambda chunk: _held_experts_pass(chunk, p, cfg), xt)
+    return out.reshape(n * size, D)[:T].reshape(B, S, D), jnp.sum(counts, axis=0)
+
+
+def _held_experts_pass(x, p, cfg: TransformerConfig):
     """A routed FFN as the chip that holds experts first_expert ..
     first_expert + experts_held - 1 serves it. Every token is scored over all
     n_experts (router logits in float32) and takes its expert_top_k best,
@@ -567,14 +806,16 @@ def _held_experts_ffn(x, p, cfg: TransformerConfig):
     return routed, jnp.stack([jnp.sum(plan.sizes), plan.n_tiles[0]]).astype(jnp.int32)
 
 
-def decoder_block(x, lp, cfg: TransformerConfig, positions, attend):
+def decoder_block(x, lp, cfg: TransformerConfig, positions, attend, kind: LayerKind | None = None):
     """The one decoder block that training, prefill and decode all run: what
     the model is (norms, projections, rope, the attention's and the FFN's
     kind) lives here, what a program does with what a layer caches is its
     ``attend``.
 
     x: [B, S, D] in cfg.dtype; lp: one layer's parameters (its FFN is routed
-    if they hold a router, dense otherwise); positions: [B, S].
+    if they hold a router, dense otherwise); positions: [B, S]; kind: the
+    layer's LayerKind (None: the model's one kind), which says how its "gqa"
+    heads are roped; its window is the ``attend``'s to keep.
     attend(q, k, v) -> (o [B,S,H,v width], kept). "gqa": q [B,S,H,Hd],
     k and v [B,S,KV,Hd], q and k roped, grouped K/V as they are (native GQA).
     "latent": q = (q_nope [B,S,H,nope], q_rope [B,S,H,rope]), k = the normed
@@ -587,6 +828,7 @@ def decoder_block(x, lp, cfg: TransformerConfig, positions, attend):
     [pairs, live tiles] counts of a layer that serves held experts."""
     eps = cfg.norm_eps
     dt = x.dtype
+    kind = kind or cfg.kinds[0]
     if cfg.latent:
         q, k, v = _latent_qkv(_rms_norm(x, lp["attn_norm"], eps), lp, cfg, positions)
     else:
@@ -597,9 +839,13 @@ def decoder_block(x, lp, cfg: TransformerConfig, positions, attend):
             v = jnp.einsum("bsd,dhk->bshk", h, lp["wv"].astype(dt))
             q = wlc(q, ("batch", "seq", "heads", "head_dim"))
             k = wlc(k, ("batch", "seq", "kv_heads", "head_dim"))
-            q = _rope(q, positions, cfg.rope_theta)
-            k = _rope(k, positions, cfg.rope_theta)
+            q = _rope_kind(q, positions, kind)
+            k = _rope_kind(k, positions, kind)
     o, kept = attend(q, k, v)
+    if cfg.attn_gate:
+        with jax.named_scope("attn_gate"):
+            gate = jax.nn.sigmoid(jnp.einsum("bsd,dh->bsh", h, lp["wg"].astype(dt)).astype(jnp.float32))
+            o = o * gate[..., None].astype(dt)
     with jax.named_scope("attn_out"):
         o = wlc(o, ("batch", "seq", "heads", "head_dim"))
         a = jnp.einsum("bshk,hkd->bsd", o, lp["wo"].astype(dt))
@@ -621,17 +867,18 @@ def decoder_block(x, lp, cfg: TransformerConfig, positions, attend):
     return x, aux, kept
 
 
-def _layer(x, lp, cfg: TransformerConfig, positions, segment_ids=None):
+def _layer(x, lp, cfg: TransformerConfig, positions, segment_ids=None, kind: LayerKind | None = None):
     """The block as training runs it: attention over the layer's own K/V by
-    the configured implementation, nothing kept. x: [B, S, D] in cfg.dtype."""
+    the configured implementation (inside the kind's window where it has
+    one), nothing kept. x: [B, S, D] in cfg.dtype."""
     def attend(q, k, v):
         if not cfg.latent:
-            return _attention(q, k, v, cfg, positions, segment_ids), None
+            return _attention(q, k, v, cfg, positions, segment_ids, window=kind.window if kind else 0), None
         k, v = latent_expand(lp, k, v, x.dtype)
         q = jnp.concatenate(q, axis=-1)
         return _attention(q, k, v, cfg, positions, segment_ids, scale=latent_scale(cfg)), None
 
-    x, aux, _ = decoder_block(x, lp, cfg, positions, attend)
+    x, aux, _ = decoder_block(x, lp, cfg, positions, attend, kind)
     # A layer that serves held experts hands out counts, not a loss term.
     return x, (jnp.zeros((), jnp.float32) if cfg.experts_held else aux)
 
@@ -646,23 +893,18 @@ def forward_hidden(params: dict, tokens: jax.Array, cfg: TransformerConfig,
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
 
-    body = functools.partial(_layer, cfg=cfg, positions=positions, segment_ids=segment_ids)
-    if cfg.remat:
+    def body_of(kind):
+        body = functools.partial(_layer, cfg=cfg, positions=positions, segment_ids=segment_ids, kind=kind)
+        if not cfg.remat:
+            return body
         if cfg.remat_policy == "dots":
-            body = jax.checkpoint(
-                body, policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-            )
-        elif cfg.remat_policy == "full":
-            body = jax.checkpoint(body)
-        else:
-            raise ValueError(
-                f"unknown remat_policy {cfg.remat_policy!r} (full|dots)"
-            )
+            return jax.checkpoint(body, policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
+        if cfg.remat_policy == "full":
+            return jax.checkpoint(body)
+        raise ValueError(f"unknown remat_policy {cfg.remat_policy!r} (full|dots)")
 
-    aux = None
-    for stack in layer_stacks(params):
-        x, auxes = scan_stack(body, x, stack, cfg)
-        aux = jnp.sum(auxes) if aux is None else aux + jnp.sum(auxes)
+    x, auxes = run_layers(lambda h, lp, kind, _index: body_of(kind)(h, lp), x, params, cfg)
+    aux = functools.reduce(lambda a, b: a + b, [jnp.sum(a) for a in auxes.values()])
     return _rms_norm(x, params["final_norm"], cfg.norm_eps), aux
 
 
@@ -843,8 +1085,9 @@ def make_pipeline_train_step(cfg: TransformerConfig, mesh, n_micro: int, optimiz
             "the pipeline schedule; use a dense stack with pp (or make_train_step "
             "with ep over a separate mesh axis)"
         )
-    if cfg.n_dense_layers:
-        raise ValueError("make_pipeline_train_step stages one stack of identical layers (n_dense_layers = 0)")
+    if cfg.n_dense_layers or cfg.layer_pattern:
+        raise ValueError("make_pipeline_train_step stages one stack of identical layers "
+                         "(n_dense_layers = 0, no layer_pattern: layers of two kinds are two stacks)")
     optimizer = optimizer or optax.adamw(3e-4, weight_decay=0.01)
     base_init, _base_step, state_logical_axes = make_train_step(cfg, optimizer)
 

@@ -44,10 +44,11 @@ NEG_INF = -1e30
 # Reference implementation (numerical oracle + non-TPU backends)
 # ---------------------------------------------------------------------------
 
-def mha_reference(q, k, v, causal=True, scale=None, segment_ids=None):
+def mha_reference(q, k, v, causal=True, scale=None, segment_ids=None, window=0):
     """q: [B, S, H, D]; k,v: [B, S, KV, D] (KV divides H) -> [B, S, H, D].
     Softmax in f32. segment_ids: optional [B, S] int; attention is masked to
-    same-segment pairs (packed sequences)."""
+    same-segment pairs (packed sequences). window (causal only): row i sees
+    columns i - window < j <= i; 0 is no window."""
     *_, H, D = q.shape
     KV = k.shape[2]
     if KV != H:
@@ -59,6 +60,8 @@ def mha_reference(q, k, v, causal=True, scale=None, segment_ids=None):
     S_q, S_k = s.shape[-2], s.shape[-1]
     if causal:
         mask = jnp.tril(jnp.ones((S_q, S_k), bool), k=S_k - S_q)
+        if window:
+            mask = mask & ~jnp.tril(jnp.ones((S_q, S_k), bool), k=S_k - S_q - window)
         s = jnp.where(mask, s, NEG_INF)
     if segment_ids is not None:
         seg = (segment_ids[:, None, :, None] == segment_ids[:, None, None, :])
@@ -74,22 +77,59 @@ def flash_supported(seq_len: int) -> bool:
     return jax.default_backend() == "tpu" and seq_len % 128 == 0
 
 
-def _mask_scores(s, q_start, k_start, causal, seg_q, seg_k):
-    """Apply causal + segment masks to a [bq, bk] score block."""
+def _mask_scores(s, q_start, k_start, causal, seg_q, seg_k, window=0):
+    """Apply causal (inside `window` columns where there is one) + segment
+    masks to a [bq, bk] score block."""
     if causal:
         rows = q_start + lax.broadcasted_iota(jnp.int32, s.shape, 0)
         cols = k_start + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(rows >= cols, s, NEG_INF)
+        seen = rows >= cols
+        if window:
+            seen = seen & (cols > rows - window)
+        s = jnp.where(seen, s, NEG_INF)
     if seg_q is not None:
         s = jnp.where(seg_q[:, None] == seg_k[None, :], s, NEG_INF)
     return s
+
+
+def _in_band(q_start, k_start, block_q, block_k, window):
+    """Whether a [block_q, block_k] block of a causal score matrix holds a
+    pair that is seen: not wholly above the diagonal nor, with a window,
+    wholly below the band."""
+    seen = k_start <= q_start + block_q - 1
+    if window:
+        seen = seen & (k_start + block_k - 1 > q_start - window)
+    return seen
+
+
+def band_blocks(S: int, block_q: int, block_k: int, window: int) -> int:
+    """K blocks a q block of a windowed causal forward visits: the grid's
+    innermost length. A q block's rows see columns q_start - window + 1 ..
+    q_start + block_q - 1; the most k blocks those lie in, over the q blocks
+    (2 at blocks of 512 and a window of 512, whatever S). Without a window,
+    every k block."""
+    if not window:
+        return -(-S // block_k)
+    return max((q_start + block_q - 1) // block_k - max(q_start - window + 1, 0) // block_k + 1
+               for q_start in range(0, S, block_q))
+
+
+def _first_band_block(qi, block_q, block_k, window):
+    """The first k block a q block's band touches."""
+    return jnp.maximum(qi * block_q - window + 1, 0) // block_k
 
 
 # ---------------------------------------------------------------------------
 # Pallas forward
 # ---------------------------------------------------------------------------
 
-def _fwd_kernel(*refs, scale, block_q, block_k, n_k, causal, has_seg):
+def _fwd_kernel(*refs, scale, block_q, block_k, n_k, causal, has_seg, window=0):
+    """Grid (BH, q blocks, k steps). Without a window the k steps are the k
+    blocks, those above the diagonal skipped by predicate. With one they are
+    the `band_blocks` blocks from the band's first on: a block wholly outside
+    the band is no grid step (a step past the diagonal, which the last q
+    blocks of a short band have none of, is held at the diagonal's block by
+    the index maps and skipped here)."""
     from jax.experimental import pallas as pl
 
     if has_seg:
@@ -108,7 +148,7 @@ def _fwd_kernel(*refs, scale, block_q, block_k, n_k, causal, has_seg):
         acc_scr[:, :] = jnp.zeros_like(acc_scr)
 
     q_start = qi * block_q
-    k_start = ki * block_k
+    k_start = (ki + _first_band_block(qi, block_q, block_k, window) if window else ki) * block_k
 
     def _compute():
         q = q_ref[0, :, :]
@@ -118,7 +158,7 @@ def _fwd_kernel(*refs, scale, block_q, block_k, n_k, causal, has_seg):
         ) * scale  # [bq, bk]
         seg_q = sq_ref[0, 0, :] if has_seg else None
         seg_k = sk_ref[0, 0, :] if has_seg else None
-        s = _mask_scores(s, q_start, k_start, causal, seg_q, seg_k)
+        s = _mask_scores(s, q_start, k_start, causal, seg_q, seg_k, window)
         m_prev = m_scr[:, 0]
         m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1))
         alpha = jnp.exp(m_prev - m_cur)
@@ -132,8 +172,8 @@ def _fwd_kernel(*refs, scale, block_q, block_k, n_k, causal, has_seg):
         l_scr[:, :] = jnp.broadcast_to(l_cur[:, None], l_scr.shape)
 
     if causal:
-        # Skip blocks strictly above the diagonal.
-        @pl.when(k_start <= q_start + block_q - 1)
+        # Skip blocks strictly above the diagonal (a window's grid has none below its band).
+        @pl.when(_in_band(q_start, k_start, block_q, block_k, 0))
         def _():
             _compute()
     else:
@@ -150,7 +190,7 @@ def _fwd_kernel(*refs, scale, block_q, block_k, n_k, causal, has_seg):
         lse_ref[0, :, :] = jnp.broadcast_to(lse[None, :], lse_ref.shape[1:])
 
 
-def _fwd_pallas(q, k, v, seg, *, causal, scale, block_q, block_k, group, H, interpret):
+def _fwd_pallas(q, k, v, seg, *, causal, scale, block_q, block_k, group, H, interpret, window=0):
     """q: [BH, S, D]; k,v: [BKV, S, D]; seg: [B, 8, S] i32 or None
     -> (o [BH, S, D], lse [BH, S] f32)."""
     from jax.experimental import pallas as pl
@@ -160,23 +200,32 @@ def _fwd_pallas(q, k, v, seg, *, causal, scale, block_q, block_k, group, H, inte
     block_q = min(block_q, S)
     block_k = min(block_k, S)
     n_q = pl.cdiv(S, block_q)
-    n_k = pl.cdiv(S, block_k)
+    n_k = band_blocks(S, block_q, block_k, window)  # the grid's k steps
     has_seg = seg is not None
 
     kernel = functools.partial(
         _fwd_kernel, scale=scale, block_q=block_q, block_k=block_k, n_k=n_k,
-        causal=causal, has_seg=has_seg,
+        causal=causal, has_seg=has_seg, window=window,
     )
+
+    def k_block(qi, ki):
+        """The k block of a grid step: the step itself, or with a window the
+        band's first block plus the step, held at the diagonal's block."""
+        if not window:
+            return ki
+        return jnp.minimum(_first_band_block(qi, block_q, block_k, window) + ki,
+                           (qi * block_q + block_q - 1) // block_k)
+
     in_specs = [
         pl.BlockSpec((1, block_q, D), lambda b, qi, ki: (b, qi, 0)),
-        pl.BlockSpec((1, block_k, D), lambda b, qi, ki: (b // group, ki, 0)),
-        pl.BlockSpec((1, block_k, D), lambda b, qi, ki: (b // group, ki, 0)),
+        pl.BlockSpec((1, block_k, D), lambda b, qi, ki: (b // group, k_block(qi, ki), 0)),
+        pl.BlockSpec((1, block_k, D), lambda b, qi, ki: (b // group, k_block(qi, ki), 0)),
     ]
     inputs = [q, k, v]
     if has_seg:
         in_specs += [
             pl.BlockSpec((1, 8, block_q), lambda b, qi, ki: (b // H, 0, qi)),
-            pl.BlockSpec((1, 8, block_k), lambda b, qi, ki: (b // H, 0, ki)),
+            pl.BlockSpec((1, 8, block_k), lambda b, qi, ki: (b // H, 0, k_block(qi, ki))),
         ]
         inputs += [seg, seg]
     return pl.pallas_call(
@@ -208,7 +257,7 @@ def _fwd_pallas(q, k, v, seg, *, causal, scale, block_q, block_k, group, H, inte
 # Pallas backward (dk/dv kernel + dq kernel)
 # ---------------------------------------------------------------------------
 
-def _bwd_dkv_kernel(*refs, scale, block_q, block_k, n_q, group, causal, has_seg):
+def _bwd_dkv_kernel(*refs, scale, block_q, block_k, n_q, group, causal, has_seg, window=0):
     """Grid: (B*KV, n_k, group*n_q) — the inner axis walks every (q-head of
     the group) × (q-block), accumulating this kv head's dk/dv in scratch."""
     from jax.experimental import pallas as pl
@@ -245,7 +294,7 @@ def _bwd_dkv_kernel(*refs, scale, block_q, block_k, n_q, group, causal, has_seg)
         ) * scale  # [bq, bk]
         seg_q = sq_ref[0, 0, :] if has_seg else None
         seg_k = sk_ref[0, 0, :] if has_seg else None
-        s = _mask_scores(s, q_start, k_start, causal, seg_q, seg_k)
+        s = _mask_scores(s, q_start, k_start, causal, seg_q, seg_k, window)
         p = jnp.exp(s - lse[:, None])  # [bq, bk] f32
         # dv += p^T @ do
         dv_scr[:, :] += jax.lax.dot_general(
@@ -263,7 +312,7 @@ def _bwd_dkv_kernel(*refs, scale, block_q, block_k, n_q, group, causal, has_seg)
         )
 
     if causal:
-        @pl.when(q_start + block_q - 1 >= k_start)
+        @pl.when(_in_band(q_start, k_start, block_q, block_k, window))
         def _():
             _compute()
     else:
@@ -275,7 +324,7 @@ def _bwd_dkv_kernel(*refs, scale, block_q, block_k, n_q, group, causal, has_seg)
         dv_ref[0, :, :] = dv_scr[:, :].astype(dv_ref.dtype)
 
 
-def _bwd_dq_kernel(*refs, scale, block_q, block_k, n_k, causal, has_seg):
+def _bwd_dq_kernel(*refs, scale, block_q, block_k, n_k, causal, has_seg, window=0):
     from jax.experimental import pallas as pl
 
     if has_seg:
@@ -307,7 +356,7 @@ def _bwd_dq_kernel(*refs, scale, block_q, block_k, n_k, causal, has_seg):
         ) * scale
         seg_q = sq_ref[0, 0, :] if has_seg else None
         seg_k = sk_ref[0, 0, :] if has_seg else None
-        s = _mask_scores(s, q_start, k_start, causal, seg_q, seg_k)
+        s = _mask_scores(s, q_start, k_start, causal, seg_q, seg_k, window)
         p = jnp.exp(s - lse[:, None])
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
@@ -319,7 +368,7 @@ def _bwd_dq_kernel(*refs, scale, block_q, block_k, n_k, causal, has_seg):
         )
 
     if causal:
-        @pl.when(k_start <= q_start + block_q - 1)
+        @pl.when(_in_band(q_start, k_start, block_q, block_k, window))
         def _():
             _compute()
     else:
@@ -330,7 +379,7 @@ def _bwd_dq_kernel(*refs, scale, block_q, block_k, n_k, causal, has_seg):
         dq_ref[0, :, :] = dq_scr[:, :].astype(dq_ref.dtype)
 
 
-def _bwd_pallas(res, g, *, causal, scale, block_q, block_k, group, H, KV, interpret):
+def _bwd_pallas(res, g, *, causal, scale, block_q, block_k, group, H, KV, interpret, window=0):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -370,7 +419,7 @@ def _bwd_pallas(res, g, *, causal, scale, block_q, block_k, group, H, KV, interp
     dkv = pl.pallas_call(
         functools.partial(
             _bwd_dkv_kernel, scale=scale, block_q=block_q, block_k=block_k,
-            n_q=n_q, group=group, causal=causal, has_seg=has_seg,
+            n_q=n_q, group=group, causal=causal, has_seg=has_seg, window=window,
         ),
         grid=(BKV, n_k, group * n_q),
         in_specs=dkv_in_specs,
@@ -412,7 +461,7 @@ def _bwd_pallas(res, g, *, causal, scale, block_q, block_k, group, H, KV, interp
     dq = pl.pallas_call(
         functools.partial(
             _bwd_dq_kernel, scale=scale, block_q=block_q, block_k=block_k,
-            n_k=n_k, causal=causal, has_seg=has_seg,
+            n_k=n_k, causal=causal, has_seg=has_seg, window=window,
         ),
         grid=(BH, n_q, n_k),
         in_specs=dq_in_specs,
@@ -432,27 +481,27 @@ def _bwd_pallas(res, g, *, causal, scale, block_q, block_k, group, H, KV, interp
 # Public API with custom VJP
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10, 11))
-def _flash_folded(q, k, v, seg, causal, scale, block_q, block_k, group, H, KV, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10, 11, 12))
+def _flash_folded(q, k, v, seg, causal, scale, block_q, block_k, group, H, KV, interpret, window):
     o, _ = _fwd_pallas(
         q, k, v, seg, causal=causal, scale=scale, block_q=block_q,
-        block_k=block_k, group=group, H=H, interpret=interpret,
+        block_k=block_k, group=group, H=H, interpret=interpret, window=window,
     )
     return o
 
 
-def _flash_fwd(q, k, v, seg, causal, scale, block_q, block_k, group, H, KV, interpret):
+def _flash_fwd(q, k, v, seg, causal, scale, block_q, block_k, group, H, KV, interpret, window):
     o, lse = _fwd_pallas(
         q, k, v, seg, causal=causal, scale=scale, block_q=block_q,
-        block_k=block_k, group=group, H=H, interpret=interpret,
+        block_k=block_k, group=group, H=H, interpret=interpret, window=window,
     )
     return o, (q, k, v, o, lse, seg)
 
 
-def _flash_bwd(causal, scale, block_q, block_k, group, H, KV, interpret, res, g):
+def _flash_bwd(causal, scale, block_q, block_k, group, H, KV, interpret, window, res, g):
     dq, dk, dv = _bwd_pallas(
         res, g, causal=causal, scale=scale, block_q=block_q, block_k=block_k,
-        group=group, H=H, KV=KV, interpret=interpret,
+        group=group, H=H, KV=KV, interpret=interpret, window=window,
     )
     seg = res[5]
     dseg = None if seg is None else np.zeros(seg.shape, jax.dtypes.float0)
@@ -464,8 +513,13 @@ _flash_folded.defvjp(_flash_fwd, _flash_bwd)
 
 def flash_attention(q, k, v, causal=True, scale=None, segment_ids=None,
                     block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
-                    interpret=False):
+                    interpret=False, window=0):
     """Flash attention. q: [B, S, H, D]; k,v: [B, S, KV, D] -> [B, S, H, D].
+
+    ``window`` (causal only; 0 is none): row i sees columns i - window < j <=
+    i. The forward visits the k blocks of a q block's band and no other
+    (``band_blocks`` grid steps a q block, not S / block_k); the backward
+    kernels walk every block and skip those outside the band by predicate.
 
     KV may be smaller than H (GQA): kv heads are shared across groups of
     H // KV query heads inside the kernel — no repeat/materialization.
@@ -482,6 +536,8 @@ def flash_attention(q, k, v, causal=True, scale=None, segment_ids=None,
         raise ValueError(f"n_heads {H} not divisible by kv_heads {KV}")
     if S % 128:
         raise ValueError(f"flash_attention needs S % 128 == 0, got S={S}")
+    if window and not causal:
+        raise ValueError("flash_attention: a window is written for causal attention")
     if not interpret and jax.default_backend() != "tpu":
         raise RuntimeError(
             f"flash_attention needs a TPU backend (or interpret=True); this "
@@ -505,6 +561,6 @@ def flash_attention(q, k, v, causal=True, scale=None, segment_ids=None,
         )  # sublane-tiled like lse
     o = _flash_folded(
         fold(q), fold(k), fold(v), seg, causal, scale, block_q, block_k,
-        group, H, KV, interpret,
+        group, H, KV, interpret, int(window),
     )
     return o.reshape(B, H, S, D).transpose(0, 2, 1, 3)

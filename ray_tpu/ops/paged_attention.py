@@ -32,6 +32,17 @@ call with 41 live steps of 512, 44-46% of the device's busy time in every
 serve cell (PERF.md section 6, PR 33). The online-softmax accumulator lives
 in VMEM scratch across a sequence's steps.
 
+A layer with an attention window (``window`` > 0: position i sees j with
+i - window < j <= i) keeps no page table. Its pool holds, a sequence, a ring
+of ``ring`` pages (``ring_pages(window, page_size)``: ceil(window / page_size)
++ 1, the window's pages and the one being written), sequence b's at pool pages
+b * ring .. b * ring + ring - 1, and page j of a sequence lies at ring page
+j % ring: the token written at position p replaces the one at p - ring *
+page_size, which no later query sees. The walk (``live_pages``) starts at the
+page that holds position length - window, so a call costs the window's pages
+whatever the context, and the kernel masks that first page's older columns.
+Such a call is named ``window_attn`` in the trace, the others ``paged_attn``.
+
 The reference framework delegates paged KV to vLLM
 (llm/_internal/serve/engines/vllm/vllm_engine.py:174); this is the TPU-native
 equivalent for our own engine.
@@ -90,26 +101,70 @@ def paged_attention_reference(q, k_new, v_new, k_pages, v_pages, lengths, page_i
 # Pallas kernel
 # ---------------------------------------------------------------------------
 
-def _page_range(length, ps, n_pages):
+def _page_range(length, ps, n_pages, window=0):
     """(first, last): the pages of its table a sequence of `length` tokens
     (>= 1) attends, both ends included: last - first + 1 page steps, which is
     ceil(length / ps) while the sequence is inside its table. ``last`` holds
     the current position, the last of `length` (a sequence run past its table
-    stays inside its last page); ``first`` is 0, and an attention window
-    (ROADMAP M2) is a later ``first`` here and a mask on its columns. Never
-    empty, whatever `length`: a sequence with no step would leave its row of
-    the output unwritten."""
+    stays inside its last page); ``first`` is 0, or with an attention window
+    the page of position length - window, the oldest the current one sees
+    (the kernel masks that page's older columns). Never empty, whatever
+    `length`: a sequence with no step would leave its row of the output
+    unwritten."""
     first = jnp.zeros_like(length)
-    return first, jnp.clip((length - 1) // ps, first, n_pages - 1)
+    last = jnp.clip((length - 1) // ps, first, n_pages - 1)
+    if window:
+        first = jnp.minimum(jnp.maximum(length - window, 0) // ps, last)
+    return first, last
+
+
+def ring_pages(window: int, page_size: int) -> int:
+    """Pages of a window layer's ring, a sequence: those a window can touch
+    (ceil(window / page_size) + 1 when it straddles page boundaries), the page
+    being written among them. A decode step writes one row and then reads, so
+    a block of steps needs no page beyond these."""
+    return -(-window // page_size) + 1
+
+
+def window_attention_reference(q, k_new, v_new, k_pages, v_pages, lengths, layer, window, scale=None):
+    """``paged_attention_reference`` for a layer with a window, over rings:
+    k_pages/v_pages [L, KV, B * ring, ps, D], sequence b's ring at pages
+    b * ring ..; the token written at ring row (lengths - 1) % (ring * ps),
+    then every ring row attended whose position, the newest one congruent to
+    it at most lengths - 1, lies inside the window. -> (o, k_pages, v_pages)."""
+    B, H, D = q.shape
+    _, KV, n_ring, ps, _ = k_pages.shape
+    rows = n_ring // B * ps  # a sequence's ring, in rows
+    group = H // KV
+    scale = scale if scale is not None else 1.0 / math.sqrt(D)
+    pos = lengths - 1
+    for b in range(B):
+        at = (layer, 0, b * (rows // ps) + pos[b] % rows // ps, pos[b] % ps, 0)
+        k_pages = jax.lax.dynamic_update_slice(
+            k_pages, k_new[b].astype(k_pages.dtype)[None, :, None, None, :], at)
+        v_pages = jax.lax.dynamic_update_slice(
+            v_pages, v_new[b].astype(v_pages.dtype)[None, :, None, None, :], at)
+    k = jax.lax.dynamic_index_in_dim(k_pages, layer, 0, keepdims=False).reshape(KV, B, rows, D)
+    v = jax.lax.dynamic_index_in_dim(v_pages, layer, 0, keepdims=False).reshape(KV, B, rows, D)
+    r = jnp.arange(rows)[None, :]
+    held = pos[:, None] - (pos[:, None] - r) % rows  # [B, rows]: the position a ring row holds
+    valid = (held >= 0) & (held > pos[:, None] - window)
+    s = jnp.einsum("bkgd,kbsd->bkgs", q.reshape(B, KV, group, D), k).astype(jnp.float32) * scale
+    s = jnp.where(valid[:, None, None, :], s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+    o = jnp.einsum("bkgs,kbsd->bkgd", p, v)
+    return o.reshape(B, H, D), k_pages, v_pages
 
 
 WINDOW_ROWS = 16  # of the token's page, stored back: one packed tile of bf16
 
 
-def live_pages(lengths, page_indices, page_size):
+def live_pages(lengths, page_indices, page_size, window=0):
     """The kernel's walk, from lengths [B] (the current token counted) and
     the page table [B, n_pages]: six int32 arrays, the first five of
-    B * n_pages entries. Entry t < count is the t-th page step:
+    B * n_pages entries. With a window the table's width alone is read (how
+    many pages a sequence may reach) and a page's place in the pool is its
+    place in its sequence's ring. Entry t < count is the t-th page step:
 
     - ``slots[t]``, ``pages[t]``: page ``pages[t]`` of sequence ``slots[t]``'s
       table, sequences in order and each one's pages ascending over its
@@ -127,8 +182,11 @@ def live_pages(lengths, page_indices, page_size):
     to each."""
     B, n_pages = page_indices.shape
     lengths, table = lengths.astype(jnp.int32), page_indices.astype(jnp.int32)
-    first, last = _page_range(lengths, page_size, n_pages)
+    first, last = _page_range(lengths, page_size, n_pages, window)
     j = jnp.arange(n_pages, dtype=jnp.int32)[None, :]
+    if window:
+        ring = ring_pages(window, page_size)
+        table = jnp.arange(B, dtype=jnp.int32)[:, None] * ring + j % ring
     live = (first[:, None] <= j) & (j <= last[:, None])  # [B, n_pages]
     win_page = jnp.sum(jnp.where(j == last[:, None], table, 0), axis=1)  # table[b, last[b]]
     win_row = (lengths - 1) % page_size // min(page_size, WINDOW_ROWS)
@@ -151,7 +209,7 @@ def live_pages(lengths, page_indices, page_size):
 
 def _paged_kernel(lens_ref, layer_ref, slots_ref, pages_ref, where_ref, win_page_ref,
                   win_row_ref, q_ref, kn_ref, vn_ref, k_ref, v_ref, o_ref, ko_ref, vo_ref,
-                  m_scr, l_scr, acc_scr, *, scale, ps, n_pages, kv):
+                  m_scr, l_scr, acc_scr, *, scale, ps, n_pages, kv, window=0):
     """Grid (count,), the live pages of the batch (``live_pages``): step t is
     page ``pages_ref[t]`` of sequence ``slots_ref[t]``. ONE page DMA carries
     ALL kv heads (page ids are shared across heads in the pool layout), and
@@ -203,7 +261,10 @@ def _paged_kernel(lens_ref, layer_ref, slots_ref, pages_ref, where_ref, win_page
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale  # [Gp, ps]
         cols = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(cols < length, s, NEG_INF)
+        seen = cols < length
+        if window:  # the first live page's older columns, and a ring page's rows of an earlier turn
+            seen = seen & (cols >= length - window)
+        s = jnp.where(seen, s, NEG_INF)
         m_prev = m_scr[h, :, 0]
         m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1))
         alpha = jnp.exp(m_prev - m_cur)
@@ -225,7 +286,7 @@ def _paged_kernel(lens_ref, layer_ref, slots_ref, pages_ref, where_ref, win_page
 
 
 def _paged_pallas(q, k_new, v_new, k_pages, v_pages, lengths, n_pages, layer, walk,
-                  *, scale, interpret):
+                  *, scale, interpret, window=0):
     """q: [B, KV, Gp, D] (Gp >= 8, sublane-padded); k_new/v_new: f32
     [B, KV, D]; k_pages/v_pages: [L, KV, P_total, ps, D]; n_pages: the
     table's width; layer: int32[1]; walk: ``live_pages`` of lengths and table
@@ -276,7 +337,7 @@ def _paged_pallas(q, k_new, v_new, k_pages, v_pages, lengths, n_pages, layer, wa
         ],
     )
     kernel = functools.partial(
-        _paged_kernel, scale=scale, ps=ps, n_pages=n_pages, kv=KV
+        _paged_kernel, scale=scale, ps=ps, n_pages=n_pages, kv=KV, window=window
     )
     return pl.pallas_call(
         kernel,
@@ -293,12 +354,13 @@ def _paged_pallas(q, k_new, v_new, k_pages, v_pages, lengths, n_pages, layer, wa
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
-        name="paged_attn",
+        # the trace tells a window layer's call from a full one's by this name
+        name="window_attn" if window else "paged_attn",
     )(lengths, layer, slots, pages, where, win_page, win_row, q, k_new, v_new, k_pages, v_pages)
 
 
 def paged_attention(q, k_new, v_new, k_pages, v_pages, lengths, page_indices, layer,
-                    scale=None, interpret=False, mesh=None, head_axis="tensor", walk=None):
+                    scale=None, interpret=False, mesh=None, head_axis="tensor", walk=None, window=0):
     """Paged decode attention. q: [B, H, D] (one query token per sequence);
     k_new/v_new: [B, KV, D], that token's K and V; k_pages/v_pages:
     [L, KV, P_total, page_size, D], every layer's pool; lengths: [B] valid
@@ -308,7 +370,10 @@ def paged_attention(q, k_new, v_new, k_pages, v_pages, lengths, page_indices, la
     traced int32 scalar: the engine's layer loop passes its counter, so one
     compiled call serves every layer); walk: ``live_pages`` of these
     lengths and this table, for a caller that makes several calls on them (a
-    decode step's layers) and builds it once; built here without it.
+    decode step's layers) and builds it once; built here without it; window:
+    the layer's attention window (0: none), whose pools hold rings
+    ([L, KV, B * ring_pages(window, page_size), page_size, D]: the module's
+    docstring) and of whose page_indices the width alone is read.
 
     Returns (o [B, H, D], k_pages, v_pages): the token's K/V lies at position
     lengths - 1 of each sequence's pages in the returned pools, which alias
@@ -328,9 +393,12 @@ def paged_attention(q, k_new, v_new, k_pages, v_pages, lengths, page_indices, la
     partition a Mosaic kernel.
     """
     if walk is None:
-        walk = live_pages(lengths, page_indices, k_pages.shape[3])
+        walk = live_pages(lengths, page_indices, k_pages.shape[3], window)
     if mesh is not None and mesh.shape.get(head_axis, 1) > 1:
         from jax.sharding import PartitionSpec as P
+
+        if window:
+            raise NotImplementedError("paged_attention: a window layer's rings are not sharded over a mesh")
 
         def inner(*args):  # every device walks the same pages, of its own heads
             return paged_attention(*args[:8], scale=scale, interpret=interpret, walk=args[8:])
@@ -356,10 +424,11 @@ def paged_attention(q, k_new, v_new, k_pages, v_pages, lengths, page_indices, la
             f"paged_attention needs a TPU backend (or interpret=True); this "
             f"process runs on {jax.default_backend()!r}"
         )
-    # Sublane-pad the group axis up to 8, the rows of the kernel's f32 score
-    # and accumulator tiles. q itself may be bf16 (tile 16 rows): Mosaic
-    # compiles the 8-row block as is (chip_smoke.py checks it on the chip).
-    Gp = max(8, group)
+    # Sublane-pad the group axis up to a multiple of 8, the rows of the
+    # kernel's f32 score and accumulator tiles (a group of 9 takes two). q
+    # itself may be bf16 (tile 16 rows): Mosaic compiles the 8-row block as
+    # is (chip_smoke.py checks it on the chip).
+    Gp = -(-group // 8) * 8
     qg = q.reshape(B, KV, group, D)
     if Gp != group:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, Gp - group), (0, 0)))
@@ -370,6 +439,6 @@ def paged_attention(q, k_new, v_new, k_pages, v_pages, lengths, page_indices, la
     o, k_pages, v_pages = _paged_pallas(
         qg, as_rows(k_new, k_pages), as_rows(v_new, v_pages), k_pages, v_pages,
         lengths.astype(jnp.int32), page_indices.shape[1],
-        jnp.asarray(layer, jnp.int32).reshape(1), walk, scale=scale, interpret=interpret,
+        jnp.asarray(layer, jnp.int32).reshape(1), walk, scale=scale, interpret=interpret, window=window,
     )
     return o[:, :, :group].reshape(B, H, D), k_pages, v_pages

@@ -20,6 +20,7 @@ SHAPES = {
     "internlm2-1.8b": (32, 16, 8, 128, 128, 16, 24, 384),
     "mistral-7b-v0.3, a chip of four": (32, 8, 2, 128, 128, 32, 32, 1088),
     "head size 64 (chip_smoke's model)": (32, 16, 4, 64, 128, 16, 12, 96),
+    "laguna-s-2.1-ep8's full layers, a group of 6": (64, 48, 8, 128, 128, 73, 3, 3072),
 }
 
 
@@ -68,6 +69,68 @@ def test_the_paged_call_lowers_for_a_v5e(shape, one_chip, no_compile_cache, monk
         # 0.3-2.4 GB here). A head size under a lane tile is padded by the
         # call's operand layout, before PR 33 as after it.
         assert compiled.memory_analysis().temp_size_in_bytes < 4 << 20
+
+
+def test_the_window_call_lowers_for_a_v5e(one_chip, no_compile_cache, monkeypatch):
+    """The paged call with a window at the serve cell's shapes (64 slots, 72
+    query heads over 8 KV heads: a group of 9, two sublane tiles; a window of
+    512 over pages of 128: rings of 5 pages, 6 layers): one Mosaic call named
+    for the trace, the ring pools aliased and not copied."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    B, H, KV, D, ps, n_pages, L, W = 64, 72, 8, 128, 128, 73, 6, 512
+
+    def arr(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def call(q, k_new, v_new, k_pages, v_pages, lengths, table, layer):
+        return pa.paged_attention(q, k_new, v_new, k_pages, v_pages, lengths, table, layer, window=W)
+
+    pool = arr((L, KV, B * pa.ring_pages(W, ps), ps, D), jnp.bfloat16)
+    args = (arr((B, H, D), jnp.bfloat16), arr((B, KV, D), jnp.bfloat16), arr((B, KV, D), jnp.bfloat16),
+            pool, pool, arr((B,), jnp.int32), arr((B, n_pages), jnp.int32), arr((), jnp.int32))
+    compiled = jax.jit(call, donate_argnums=(3, 4)).lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1 and "window_attn" in text and "paged_attn" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 << 20  # a pool is 0.5 GB
+
+
+def test_the_decode_program_of_a_model_with_window_layers_compiles_for_a_v5e(one_chip, no_compile_cache, monkeypatch):
+    """llm/engine.py ``_decode_impl`` of a model with a layer pattern, at
+    heads of 128 in groups of 6 and 9 and otherwise small widths: the period
+    scan around the two paged calls' kinds and the grouped matmul. 1 + 4
+    layers are one paged call in the dense stack, one and three window calls
+    in the period, and three grouped matmuls in each of its four layers; no
+    pool is copied (the two kinds' four pools are 27 MB, the program's
+    temporaries a fraction), and a layer's parameters are its slice of its
+    kind's stack, not a period's slice of it."""
+    from ray_tpu.llm.engine import EngineConfig, LLMEngine
+    from ray_tpu.models.transformer import LayerKind, TransformerConfig, init_params
+
+    full = LayerKind("full_attention", 12, rope_theta=5e5, rope_share=0.5, yarn_factor=128.0, yarn_original_len=8192,
+                     attention_factor=1.4852030263919618)
+    sliding = LayerKind("sliding_attention", 18, window=512)
+    cfg = TransformerConfig(
+        vocab_size=1024, d_model=256, n_layers=5, n_heads=12, n_kv_heads=2, head_dim=128, d_ff=512, max_seq_len=2048,
+        param_dtype=jnp.bfloat16, layer_pattern=(full, sliding, sliding, sliding), attn_gate="per_head",
+        n_dense_layers=1, n_experts=32, expert_top_k=4, experts_held=8, expert_d_ff=256, n_shared_experts=1,
+        routed_scaling=2.5, router_score="sigmoid")
+
+    def on_chip(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    params = jax.tree.map(on_chip, jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    eng = LLMEngine(cfg, params=params, engine_config=EngineConfig(
+        max_slots=8, max_seq=2048, page_size=128, total_pages=40, prefill_buckets=(512,), decode_block=8))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the program asks which attend to trace
+    B = eng.ec.max_slots
+    ints, floats = on_chip(jnp.zeros(B, jnp.int32)), on_chip(jnp.zeros(B, jnp.float32))
+    compiled = eng._decode_jit.lower(
+        params, tuple(on_chip(pool) for pool in eng.cache), ints, ints, on_chip(eng.d_page_tables),
+        on_chip(jax.random.PRNGKey(0)), 8, floats, floats, ints).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2 + 3 + 3 * 4
+    assert sum(pool.nbytes for pool in eng.cache) == 2 * (2 * 2 * 40 * 128 * 128 * 2) + 2 * (3 * 2 * 8 * 5 * 128 * 128 * 2)
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 << 20
 
 
 def test_the_latent_paged_call_lowers_for_a_v5e(one_chip, no_compile_cache, monkeypatch):
