@@ -19,11 +19,20 @@ llm/_internal/serve/engines/vllm/vllm_engine.py:174):
   PR 33 it was the whole table, slots × pages a slot, live or dead. Memory
   scales with reserved pages, not slots × max_seq, and admission is
   page-budgeted.
-- Continuous batching is the host loop: between device programs, finished
-  slots retire (their pages return to the free list) and queued requests
-  prefill into free slots. Prefill groups are dispatched back-to-back
-  asynchronously and fetched in order, so a request's TTFT is its own
-  group's completion, not the whole admission wave's.
+- Continuous batching is the host loop: finished slots retire (their pages
+  return to the free list) and queued requests prefill into free slots.
+  Prefill groups are dispatched back-to-back asynchronously and fetched in
+  order, so a request's TTFT is its own group's completion, not the whole
+  admission wave's.
+- The host is one decode block behind the device and never more: a step
+  enqueues its block BEFORE it fetches and walks the tokens of the block the
+  step before enqueued (``_Block``, ``_absorb``), so while block s runs the
+  host absorbs block s-1, hands its events to the serving loop and does the
+  next step's admission, prefill dispatch and mirror updates. A block's
+  outputs (last tokens, lengths, the pools) are the next block's inputs on
+  the device; the host's ``lengths`` advance at dispatch and so are the
+  device's. A request that ends by a token is found out a block late: its
+  row rides that block and the walk throws the row away.
 - Admission-aware decode: under queue pressure the decode block shrinks
   (fewer fused steps per host round trip) so waiting requests reach a
   prefill slot sooner; with an empty queue full blocks amortize the
@@ -69,7 +78,9 @@ completes inside that window), the standard serving definition.
 Page-0 convention: page 0 is never allocated; dead page-table entries point
 at it (the paged kernel masks them by length) and it absorbs writes from
 retired/overshooting slots (their lengths are zeroed, so nothing ever reads
-what they wrote).
+what they wrote): a row that has ended may be written up to two blocks past
+its budget, one more than ``_pages_needed`` reserves, and what passes its
+last page goes through the zero tail of its table row.
 """
 from __future__ import annotations
 
@@ -118,12 +129,16 @@ class EngineConfig:
     seed: int = 0
     # Decode steps fused into one device program per host round trip. 8 was
     # chosen on an earlier remote-chip stack where the call's latency
-    # dominated. Measured on a directly attached v5e (PERF.md sections 5-6):
-    # an awaited dispatch costs 0.55-0.61 ms (PR 21) and a decode step
-    # 11-18 ms (PRs 25-29), so the block saves the host's per-step work, not
-    # the call. Cost: the first token waits for its step's block (92.5 of a
-    # median TTFT of 173 ms in `chat`; ROADMAP S0), admissions happen between
-    # blocks, and a slot finishing mid-block discards its tail tokens.
+    # dominated. On a directly attached v5e an awaited dispatch costs
+    # 0.55-0.61 ms (PR 21) and a decode step 6-21 ms (PERF.md section 5).
+    # Since PR 39 the block is what the host hides under: a step's 20-40 ms
+    # of admission, prefill dispatch, mirror updates and token walk run while
+    # the block before it decodes, so a block has to last about as long as
+    # they do (8 steps: 51-167 ms; the short block of 2 under queue pressure
+    # hides only part). Cost: admissions happen between blocks, a request
+    # that ends by a token keeps its row one block longer on the device, and
+    # a slot finishing mid-block discards its tail tokens. The first token no
+    # longer waits for its own step's block (ROADMAP S0).
     decode_block: int = 8
     # Retired key: the block-paged pool is the one KV layout (the dense
     # per-slot cache went in PR 30). Configurations under benchmarks/ still
@@ -207,6 +222,17 @@ class _Slot:
     # time.monotonic()). arrived_at / first_token_at above stay on
     # perf_counter: the streamed ttft_s is their difference.
     life: Optional[dict] = None
+
+
+@dataclasses.dataclass
+class _Block:
+    """A decode block the device has been handed and the host has not fetched:
+    what ``LLMEngine._absorb`` needs to walk it a step later."""
+    toks: Any  # [n, max_slots], on the device
+    counts: Any  # a model with held experts: int32 [2] (pairs, tiles) on the device; else None
+    n: int
+    rec: dict  # the dispatching step's record (the ring holds this dict: counts land in it)
+    rows: list  # (slot index, the _Slot that held it at dispatch) of every active row
 
 
 def _kv_rows(kv, dtype):
@@ -436,12 +462,16 @@ class LLMEngine:
         self.pool_bytes = {name: sum(pool.nbytes for pool in self.cache[sl]) for name, sl in self._kind_pools.items()}
         self.free_pages: deque = deque(range(1, P_total))  # page 0 = dead sink
         self.page_tables = np.zeros((B, self.ppseq), np.int32)
-        self.d_page_tables = jnp.zeros((B, self.ppseq), jnp.int32)
+        # A mirror is replicated over the mesh whoever wrote it last (a decode
+        # block, a per-row update, the host: _mirror), so a program that takes
+        # one meets one layout and is compiled once.
+        self._replicated = NamedSharding(self.mesh, _P()) if self.mesh is not None else None
+        self.d_page_tables = self._mirror(self.page_tables)
         self.lengths = np.zeros(B, np.int32)  # host copy drives scheduling
         # Device-resident mirrors: decode blocks read/advance these without
         # any host->device transfer per step.
-        self.d_lengths = jnp.zeros(B, jnp.int32)
-        self.d_last = jnp.zeros(B, jnp.int32)
+        self.d_lengths = self._mirror(self.lengths)
+        self.d_last = self._mirror(np.zeros(B, np.int32))
         self.slots: list[Optional[_Slot]] = [None] * B
         # Per-slot sampling params (vLLM-style per-request SamplingParams,
         # llm/sampling.py): host copies set at admission, device mirrors ride
@@ -450,9 +480,9 @@ class LLMEngine:
         self.samp_temps = np.full(B, self.ec.temperature, np.float32)
         self.samp_top_ps = np.ones(B, np.float32)
         self.samp_top_ks = np.zeros(B, np.int32)
-        self.d_temps = jnp.asarray(self.samp_temps)
-        self.d_top_ps = jnp.asarray(self.samp_top_ps)
-        self.d_top_ks = jnp.asarray(self.samp_top_ks)
+        self.d_temps = self._mirror(self.samp_temps)
+        self.d_top_ps = self._mirror(self.samp_top_ps)
+        self.d_top_ks = self._mirror(self.samp_top_ks)
         self.waiting: deque = deque()
         self._phases = _tracing.PhaseSpans(
             "llm.step", STEP_PHASES, TRACE_RING, annotation=jax.profiler.TraceAnnotation
@@ -498,6 +528,13 @@ class LLMEngine:
         self.c_buckets = tuple(sorted(set(cs)))
         self._tail_jit: dict[tuple, Any] = {}
         self._decode_jit = jax.jit(self._decode_impl, donate_argnums=(1,), static_argnames=("n_steps",))
+        # The one-block look-ahead (step()): the block the device was handed
+        # last, unfetched; events absorbed outside a step (abort, set_params),
+        # which the next step returns; rows retired since the mirrors' last sync.
+        self._inflight: Optional[_Block] = None
+        self._carry: dict[str, dict] = {}
+        self._gone: list[int] = []
+        self._drop_rows_jit = jax.jit(self._drop_rows_impl)
         # Buckets: page-size multiples only (a prefill writes whole pages).
         self.buckets = tuple(sorted(
             {min(ps * math.ceil(b / ps), S) for b in self.ec.prefill_buckets if b <= S} | {S}
@@ -516,6 +553,12 @@ class LLMEngine:
         # request's own reservation.
         total = min(prompt_len + max_tokens + self.ec.decode_block, self.ec.max_seq)
         return math.ceil(total / self.ec.page_size)
+
+    def _mirror(self, host: np.ndarray):
+        """A host copy uploaded whole as its device mirror."""
+        if self._replicated is None:
+            return jnp.asarray(host)
+        return jax.device_put(host, self._replicated)
 
     # -- device-mirror masking (chunked prefill) ---------------------------
     def _masked(self, host: np.ndarray) -> np.ndarray:
@@ -1022,6 +1065,15 @@ class LLMEngine:
             jax.device_get(out[1])
             log.append({"program": "decode", "block": n, "temp_bytes": temp_bytes,
                         "seconds": time.monotonic() - t0})
+        # A retire's per-row write of the mirrors, against the lengths as a
+        # decode block leaves them and as the host wrote them (one layout,
+        # _mirror, so one compile), and the step's key split.
+        t0 = time.monotonic()
+        nobody = jnp.zeros(self.ec.max_slots, bool)
+        for lens in (out[3], self.d_lengths):
+            jax.block_until_ready(self._drop_rows_jit(lens, self.d_page_tables, nobody))
+        _key, _sub = jax.random.split(self._key)  # split and unpacked, as a dispatch does
+        log.append({"program": "drop_rows", "seconds": time.monotonic() - t0})
         if self.ec.prefix_cache:
             # Compile the prefix-cache page copy (padded rows hit page 0).
             t0 = time.monotonic()
@@ -1030,8 +1082,8 @@ class LLMEngine:
             jax.block_until_ready(self.cache)
             log.append({"program": "copy_pages", "seconds": time.monotonic() - t0})
         # Reset device mirrors dirtied by the dummy executions.
-        self.d_lengths = jnp.zeros(self.ec.max_slots, jnp.int32)
-        self.d_last = jnp.zeros(self.ec.max_slots, jnp.int32)
+        self.d_lengths = self._mirror(self.lengths)
+        self.d_last = self._mirror(np.zeros(self.ec.max_slots, np.int32))
 
     # -- request lifecycle -------------------------------------------------
     def add_request(self, req_id: str, tokens, max_tokens: int = 64,
@@ -1083,9 +1135,12 @@ class LLMEngine:
         new tree onto this engine's layout and flip the pointer. The caller
         must exclude step() for the duration (LLMServer holds its swap
         lock), so an in-flight batch finishes entirely on the old weights
-        and the next step reads entirely the new — never a mix. KV cache is
-        kept: a fine-tuned refresh of the same model keeps generating
-        coherently; swapping an unrelated model needs a redeploy."""
+        and the next step reads entirely the new — never a mix: a block the
+        device still holds is absorbed here (its events ride the next
+        step's). KV cache is kept: a fine-tuned refresh of the same model
+        keeps generating coherently; swapping an unrelated model needs a
+        redeploy."""
+        self._absorb_outside_step()
         self.params = (
             jax.device_put(params, self._param_shardings)
             if self._param_shardings else jax.device_put(params)
@@ -1093,22 +1148,30 @@ class LLMEngine:
 
     def abort(self, req_id: str) -> None:
         """Drop a request whose consumer went away: dequeue it, or free its
-        slot so decode stops spending steps on it. Call from the stepping
-        thread only (mutates scheduler state + device mirrors)."""
+        slot so decode stops spending steps on it (a block in flight is
+        absorbed first: the request's count is of tokens the host has seen).
+        Call from the stepping thread only (mutates scheduler state + device
+        mirrors)."""
         for w in self.waiting:
             if w[0] == req_id:
                 self._close_life(w[4], 0, "abort")
         self.waiting = deque(w for w in self.waiting if w[0] != req_id)
-        for i, s in enumerate(self.slots):
+        if not any(s is not None and s.req_id == req_id for s in self.slots):
+            return
+        self._absorb_outside_step()
+        for i, s in enumerate(self.slots):  # looked up again: the absorbed block may have ended it
             if s is not None and s.req_id == req_id:
                 self._close_life(s.life, len(s.emitted), "abort")
                 self._retire(i)
-                self.d_lengths = jnp.asarray(self._masked(self.lengths))
-                self.d_page_tables = jnp.asarray(self._masked(self.page_tables))
                 break
+        self._sync_retired()
+        self._carry.pop(req_id, None)  # nobody reads what the absorbed block held of it
 
     def has_work(self) -> bool:
-        return bool(self.waiting) or any(s is not None for s in self.slots)
+        """True while a request waits or holds a slot, and while a block's
+        tokens or absorbed events have not been returned by a step."""
+        return (bool(self.waiting) or any(s is not None for s in self.slots)
+                or self._inflight is not None or bool(self._carry))
 
     def _prefix_digests(self, tokens) -> list:
         """(covered_len, digest) pairs for every page-aligned prefix of the
@@ -1177,6 +1240,7 @@ class LLMEngine:
         self.slots[i] = None
         self.lengths[i] = 0
         self.page_tables[i, :] = 0
+        self._gone.append(i)
 
     def _evict_prefix_cache(self, need_pages: int, protect: frozenset = frozenset()) -> None:
         """LRU-evict cache entries until need_pages pages are back in the
@@ -1225,7 +1289,13 @@ class LLMEngine:
     def step(self) -> dict:
         """One engine iteration: admit waiting requests into free slots +
         free pages (prefill, grouped by length bucket, groups dispatched
-        async then fetched in order), then one decode block for all slots.
+        async), enqueue one decode block for all slots, fetch the prefill
+        groups in order (first tokens), then fetch and walk the decode block
+        the step BEFORE enqueued: the device runs this step's block while
+        the host does that, returns, and prepares the next step. So a decode
+        token is returned by the step after the one that dispatched it, and
+        the step that returns a request's last event may leave a block on
+        the device (``has_work`` stays true until a step has walked it).
         Returns {req_id: {"token": int, "new_tokens": [...], "finished":
         bool, "ttft_s": float|None, "tokens": [..] when done}}.
 
@@ -1234,7 +1304,8 @@ class LLMEngine:
         step's record goes to the ring that LLMServer.stats() returns."""
         ph = self._phases
         ph.begin("admit", waiting=len(self.waiting), n_admitted=0, n_prefill=0,
-                 block=0, active=0, sampled=0, live_pages=0, expert_pairs=0, expert_tiles=0,
+                 block=0, ahead=0, dropped_rows=0, active=0, sampled=0, live_pages=0,
+                 expert_pairs=0, expert_tiles=0,
                  **({"window_pages": 0, "window_tokens": 0} if self._window else {}))
         try:
             return self._step(ph)
@@ -1242,8 +1313,7 @@ class LLMEngine:
             ph.end()
 
     def _step(self, ph) -> dict:
-        events: dict[str, dict] = {}
-        retired = False
+        events, self._carry = self._carry, {}  # what abort / set_params absorbed since the last step
         ps = self.ec.page_size
         # 1. admit: page-budgeted assignment of waiting requests to free slots.
         admitted: list[tuple[int, str, np.ndarray, int, int, float]] = []
@@ -1458,10 +1528,13 @@ class LLMEngine:
                 slot.prefill_pos = start + n_tok
         if admitted or cache_hits or tail_admitted or chunk_dispatched:
             ph.to("mirror_sync")
-            self.d_page_tables = jnp.asarray(self._masked(self.page_tables))
-            self.d_temps = jnp.asarray(self.samp_temps)
-            self.d_top_ps = jnp.asarray(self.samp_top_ps)
-            self.d_top_ks = jnp.asarray(self.samp_top_ks)
+            self.d_page_tables = self._mirror(self._masked(self.page_tables))
+            self.d_temps = self._mirror(self.samp_temps)
+            self.d_top_ps = self._mirror(self.samp_top_ps)
+            self.d_top_ks = self._mirror(self.samp_top_ks)
+        # 3. decode: this step's block goes to the device BEFORE anything is
+        # fetched, so the device holds it while the host walks the block before.
+        block = self._dispatch_decode(ph, events)
         # Fetch per group, in dispatch order: group g's fetch returns while
         # g+1 still runs on device (async dispatch), so TTFT is per-group.
         for chunk, toks_dev in dispatched:
@@ -1482,109 +1555,170 @@ class LLMEngine:
                     "finished": False,
                     "ttft_s": now - arrived,
                 }
-                retired |= self._maybe_finish(i, events)
-        # 3. decode: one fused block over all slots. Queue pressure shrinks
-        # the block so the next admission wave starts sooner. Slots mid
-        # chunked-prefill ride along masked (writes to dead page 0, tokens
-        # discarded) but do not drive the block's budget arithmetic.
-        ph.to("decode_dispatch")
+                self._maybe_finish(i, events)
+        # 4. the block before: fetched and walked while the device runs this
+        # step's (the context-cap path of _dispatch_decode may have absorbed it).
+        if self._inflight is not None:
+            ph.rec["dropped_rows"] = self._absorb(events, ph.to)
+        self._inflight = block
+        if block is None:
+            self._retire_at_cap(events)
+        if self._gone:
+            ph.to("retire_sync")
+            self._sync_retired()
+        return events
+
+    def _fit(self) -> tuple[list[int], int, bool]:
+        """The rows a decode block would advance now, the compiled size to
+        run (0: none), and whether none runs only because the longest row's
+        headroom is under the smallest compiled block. Queue pressure shrinks
+        the block so the next admission wave starts sooner. Slots mid
+        chunked-prefill ride along masked (writes to dead page 0, tokens
+        discarded) but do not drive the block's budget arithmetic."""
         active = [i for i, s in enumerate(self.slots)
                   if s is not None and i not in self._prefilling]
-        ph.rec["active"] = len(active)
-        ph.rec["sampled"] = int(np.count_nonzero(self.samp_temps[active] > 0))
-        toks = counts = None
-        n = 0
-        if active:
-            remaining = [self.slots[i].max_tokens - self.slots[i].n_generated for i in active]
-            positive = [r for r in remaining if r > 0]
-            cap = self.ec.max_seq - 1 - int(max(self.lengths[i] for i in active))
-            if positive and cap > 0:
-                # Short block under queue pressure (admissions land sooner)
-                # OR while any slot still owes its FIRST token (prefix-cache
-                # hits skip prefill; their TTFT is the first decode block —
-                # a full block would pay block_size steps of latency for it).
-                awaiting_first = bool(self._prefilling) or any(
-                    self.slots[i] is not None and not self.slots[i].emitted
-                    for i in active
-                )
-                block = (
-                    self.block_sizes[0] if (self.waiting or awaiting_first)
-                    else self.block_sizes[-1]
-                )
-                # Snap DOWN to a compiled size: an oversized block advances
-                # lengths past max_seq-1 and the clamped device writes would
-                # scribble over the longest slot's earlier KV.
-                fits = [b for b in self.block_sizes if b <= min(block, cap)]
-                if fits:
-                    n = fits[-1]
-                    self._key, sub = jax.random.split(self._key)
-                    self.cache, toks, self.d_last, self.d_lengths, counts = self._decode_jit(
-                        self.params, self.cache, self.d_last,
-                        self.d_lengths, self.d_page_tables, sub, n,
-                        self.d_temps, self.d_top_ps, self.d_top_ks,
-                    )
-                    for i in active:
-                        self.slots[i].n_generated += n
-                else:
-                    # No compiled block fits the headroom left by the longest
-                    # slot(s): retire them (they are within block_sizes[0]
-                    # tokens of max_seq) so the next step has room to decode.
-                    for i in active:
-                        if int(self.lengths[i]) + self.block_sizes[0] >= self.ec.max_seq:
-                            slot = self.slots[i]
-                            ev = events.setdefault(slot.req_id, {"ttft_s": None})
-                            ev["finished"] = True
-                            ev["finish_reason"] = "length"  # context-cap retirement
-                            ev["tokens"] = list(slot.emitted)
-                            ev["ttft_s"] = ev.get("ttft_s") or (
-                                (slot.first_token_at or slot.arrived_at) - slot.arrived_at
-                            )
-                            self._close_life(slot.life, len(slot.emitted), "length")
-                            self._retire(i)
-                            retired = True
-        if toks is not None:
-            ph.rec["block"] = n
-            ph.rec["live_pages"] = self._live_pages(active, n)
-            if self._window:
-                ph.rec["window_pages"], ph.rec["window_tokens"] = self._window_walk(active, n)
-            ph.to("decode_fetch")
-            if counts is None:
-                block_toks = np.asarray(jax.device_get(toks))  # [n, B]
-            else:  # a model with held experts: its counts ride the same fetch
-                block_toks, (pairs, tiles) = jax.device_get((toks, counts))
-                ph.rec["expert_pairs"], ph.rec["expert_tiles"] = int(pairs), int(tiles)
-            ph.to("emit")
-            for step_i in range(n):
-                for i in active:
-                    slot = self.slots[i]
-                    if slot is None or len(slot.emitted) >= slot.n_generated:
-                        continue  # finished, or this block overshot its budget
-                    tok = int(block_toks[step_i, i])
-                    self.lengths[i] += 1
-                    slot.emitted.append(tok)
-                    ev = events.setdefault(slot.req_id, {"finished": False, "ttft_s": None})
-                    if slot.first_token_at is None:
-                        # Prefix-cache hits skip prefill; their first token
-                        # comes out of the decode block.
-                        slot.first_token_at = time.perf_counter()
-                        slot.life["first_token"] = time.monotonic()
-                        ev["ttft_s"] = slot.first_token_at - slot.arrived_at
-                    ev["token"] = tok
-                    ev.setdefault("new_tokens", []).append(tok)
-                    retired |= self._maybe_finish(i, events)
-        if retired:
+        if not active:
+            return active, 0, False
+        remaining = [self.slots[i].max_tokens - self.slots[i].n_generated for i in active]
+        cap = self.ec.max_seq - 1 - int(self.lengths[active].max())
+        if not any(r > 0 for r in remaining) or cap <= 0:
+            return active, 0, False  # every budget is on the device already, or a row at the cap waits to be walked
+        # Short block under queue pressure (admissions land sooner)
+        # OR while any slot still owes its FIRST token to a decode block
+        # (prefix-cache hits skip prefill; their TTFT is the first decode
+        # block — a full block would pay block_size steps of latency for it).
+        awaiting_first = bool(self._prefilling) or any(
+            self.slots[i].n_generated == 0 for i in active)
+        want = self.block_sizes[0] if (self.waiting or awaiting_first) else self.block_sizes[-1]
+        # Snap DOWN to a compiled size: an oversized block advances
+        # lengths past max_seq-1 and the clamped device writes would
+        # scribble over the longest slot's earlier KV.
+        fits = [b for b in self.block_sizes if b <= min(want, cap)]
+        return active, (fits[-1] if fits else 0), not fits
+
+    def _dispatch_decode(self, ph, events: dict) -> Optional[_Block]:
+        """Enqueue one fused decode block over all slots and return its
+        record, the tokens still on the device (None: nothing to decode, or
+        no room: ``_retire_at_cap``). ``lengths`` and every slot's
+        ``n_generated`` advance by the block here, at dispatch: they are the
+        device's, whatever the host has walked."""
+        ph.to("decode_dispatch")
+        active, n, short = self._fit()
+        if short and self._inflight is not None:
+            # The headroom is short by tokens the host has not seen: a row that
+            # only waits to be walked must end by its own tokens. Absorb, take
+            # what ended off the mirrors (no block goes out over a retired
+            # row's pages), look again.
+            ph.rec["dropped_rows"] = self._absorb(events, ph.to)
             ph.to("retire_sync")
-            # Re-sync device mirrors so retired slots stop advancing their
-            # (now meaningless) lengths toward max_seq, and their writes land
-            # in the dead page. Mid-prefill slots stay masked.
-            self.d_lengths = jnp.asarray(self._masked(self.lengths))
-            self.d_page_tables = jnp.asarray(self._masked(self.page_tables))
-            last = np.zeros(self.ec.max_slots, np.int32)
-            for i, s in enumerate(self.slots):
-                if s is not None and s.emitted:
-                    last[i] = s.emitted[-1]
-            self.d_last = jnp.asarray(last)
-        return events
+            self._sync_retired()
+            ph.to("decode_dispatch")
+            active, n, short = self._fit()
+        if not n:
+            return None
+        rec = ph.rec
+        rec["block"] = n
+        rec["ahead"] = int(self._inflight is not None)
+        rec["active"] = len(active)
+        rec["sampled"] = int(np.count_nonzero(self.samp_temps[active] > 0))
+        self._key, sub = jax.random.split(self._key)
+        self.cache, toks, self.d_last, self.d_lengths, counts = self._decode_jit(
+            self.params, self.cache, self.d_last,
+            self.d_lengths, self.d_page_tables, sub, n,
+            self.d_temps, self.d_top_ps, self.d_top_ks,
+        )
+        rec["live_pages"] = self._live_pages(active, n)
+        if self._window:
+            rec["window_pages"], rec["window_tokens"] = self._window_walk(active, n)
+        self.lengths[active] += n
+        for i in active:
+            self.slots[i].n_generated += n
+        return _Block(toks, counts, n, rec, [(i, self.slots[i]) for i in active])
+
+    def _retire_at_cap(self, events: dict) -> None:
+        """No compiled block fits the headroom left by the longest slot(s):
+        retire them (they are within block_sizes[0] tokens of max_seq) so the
+        next step has room to decode. Runs where a step dispatched no block,
+        after its fetches, so the host has seen every token there is: a
+        request admitted in this step has its first token."""
+        active, _n, short = self._fit()
+        if not short:
+            return
+        for i in active:
+            if int(self.lengths[i]) + self.block_sizes[0] >= self.ec.max_seq:
+                slot = self.slots[i]
+                ev = events.setdefault(slot.req_id, {"ttft_s": None})
+                ev["finished"] = True
+                ev["finish_reason"] = "length"  # context-cap retirement
+                ev["tokens"] = list(slot.emitted)
+                ev["ttft_s"] = ev.get("ttft_s") or (
+                    (slot.first_token_at or slot.arrived_at) - slot.arrived_at
+                )
+                self._close_life(slot.life, len(slot.emitted), "length")
+                self._retire(i)
+
+    def _absorb(self, events: dict, to=lambda _phase: None) -> int:
+        """Fetch the block in flight and walk its tokens into ``events``;
+        returns the rows it dropped. A row's tokens go to the request that
+        held the row when the block was dispatched, or nowhere: a request
+        that ended in the block before (EOS, a stop id, its budget, an abort)
+        was found out after this block was enqueued with its row still live,
+        and its slot may hold another request by now. ``to`` is the step's
+        ``PhaseSpans.to`` (outside a step nothing is timed)."""
+        blk, self._inflight = self._inflight, None
+        to("decode_fetch")
+        if blk.counts is None:
+            block_toks = np.asarray(jax.device_get(blk.toks))  # [n, B]
+        else:  # a model with held experts: its counts ride the same fetch
+            block_toks, (pairs, tiles) = jax.device_get((blk.toks, blk.counts))
+            blk.rec["expert_pairs"], blk.rec["expert_tiles"] = int(pairs), int(tiles)
+        to("emit")
+        rows = [(i, slot) for i, slot in blk.rows if self.slots[i] is slot]
+        for step_i in range(blk.n):
+            for i, slot in rows:
+                if self.slots[i] is not slot:
+                    continue  # finished inside this block: its tail is thrown away
+                tok = int(block_toks[step_i, i])
+                slot.emitted.append(tok)
+                ev = events.setdefault(slot.req_id, {"finished": False, "ttft_s": None})
+                if slot.first_token_at is None:
+                    # Prefix-cache hits skip prefill; their first token
+                    # comes out of the decode block.
+                    slot.first_token_at = time.perf_counter()
+                    slot.life["first_token"] = time.monotonic()
+                    ev["ttft_s"] = slot.first_token_at - slot.arrived_at
+                ev["token"] = tok
+                ev.setdefault("new_tokens", []).append(tok)
+                self._maybe_finish(i, events)
+        return len(blk.rows) - len(rows)
+
+    def _absorb_outside_step(self) -> None:
+        """For a caller between steps that needs the host to have seen every
+        token (abort, set_params, generate's return): the block in flight
+        walked into events the next step hands out."""
+        if self._inflight is not None:
+            self._absorb(self._carry)
+        self._sync_retired()
+
+    @staticmethod
+    def _drop_rows_impl(lengths, page_tables, gone):
+        """The two mirrors a retire touches, zeroed in the rows ``gone``
+        [max_slots] marks and in no other: the device may be a block ahead of
+        the host, so the live rows' lengths are the device's to keep. d_last
+        needs none: a slot without pages is a dead, greedy row."""
+        return jnp.where(gone, 0, lengths), jnp.where(gone[:, None], 0, page_tables)
+
+    def _sync_retired(self) -> None:
+        """Retired slots stop advancing their (now meaningless) lengths
+        toward max_seq and write into the dead page: their rows of d_lengths
+        and d_page_tables go to zero, behind whatever block is in flight."""
+        if not self._gone:
+            return
+        gone = np.zeros(self.ec.max_slots, bool)
+        gone[self._gone] = True
+        self._gone.clear()
+        self.d_lengths, self.d_page_tables = self._drop_rows_jit(
+            self.d_lengths, self.d_page_tables, jnp.asarray(gone))
 
     def _live_pages(self, active: list[int], n: int) -> int:
         """The page steps the paged kernel walks in a decode block of ``n``
@@ -1624,9 +1758,11 @@ class LLMEngine:
             (not slot.ignore_eos and self.ec.eos_id >= 0 and slot.emitted[-1] == self.ec.eos_id)
             or slot.emitted[-1] in slot.stop_ids
         )
+        # The context the host has walked (``lengths`` is the device's, up to
+        # two blocks further): the prompt and every token out but this one.
         capped = (
             len(slot.emitted) >= slot.max_tokens
-            or int(self.lengths[i]) + 1 >= self.ec.max_seq
+            or slot.prompt_len + len(slot.emitted) >= self.ec.max_seq
         )
         done = stopped or capped
         if done:
@@ -1653,4 +1789,5 @@ class LLMEngine:
                 if ev and ev.get("ttft_s") is not None:
                     ttft = ev["ttft_s"]
                 if ev and ev.get("finished"):
+                    self._absorb_outside_step()  # it ended a block ago on the device: leave none behind
                     return {"tokens": ev["tokens"], "ttft_s": ttft}

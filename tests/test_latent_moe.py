@@ -165,6 +165,43 @@ def test_decode_counts_pairs_on_held_experts_in_the_step_record():
     assert any(s["expert_pairs"] for s in steps)
 
 
+def test_a_block_ahead_the_counts_ride_the_fetch_a_step_later_into_the_dispatching_steps_record():
+    """The engine fetches a block's tokens, and with them the routed pairs and
+    live tiles, one step after it dispatched the block: the counts land in the
+    record of the step that dispatched it (the ring holds that dict), block
+    for block what the program returned, and three requests through two
+    slots return what each gives alone."""
+    params = _params()
+    prompts = [_tokens(n, seed=n) for n in (9, 20, 5)]
+    solo = LLMEngine(CFG, params=params, engine_config=EngineConfig(**ENGINE_KW))
+    want = [solo.generate(p, max_tokens=11)["tokens"] for p in prompts]
+    eng = LLMEngine(CFG, params=params, engine_config=EngineConfig(**ENGINE_KW))
+    decode, returned = eng._decode_jit, []
+
+    def spy(*args):
+        out = decode(*args)
+        returned.append((args[6], out[4]))  # (n_steps, counts, still on the device)
+        return out
+
+    eng._decode_jit = spy
+    for i, p in enumerate(prompts):
+        eng.add_request(f"r{i}", p, 11)
+    done, seen_late = {}, 0
+    while eng.has_work():
+        for rid, ev in eng.step().items():
+            if ev.get("finished"):
+                done[rid] = ev["tokens"]
+        last = eng.trace_snapshot()["steps"][-1]
+        # the step that has just ended dispatched a block: its counts are still on the device
+        seen_late += bool(last["block"] and eng._inflight is not None and last["expert_pairs"] == 0)
+    assert [done[f"r{i}"] for i in range(3)] == want
+    blocks = [s for s in eng.trace_snapshot()["steps"] if s["block"]]
+    assert len(blocks) == len(returned) > 3 and seen_late > 0
+    assert [(s["block"], s["expert_pairs"], s["expert_tiles"]) for s in blocks] == [
+        (n, int(counts[0]), int(counts[1])) for n, counts in returned]
+    assert all(s["expert_pairs"] > 0 for s in blocks) and any(s["ahead"] for s in blocks)
+
+
 # ---------------------------------------------------------------------------
 # the kernels, in interpret mode, against their jax.numpy references
 # ---------------------------------------------------------------------------

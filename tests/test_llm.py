@@ -612,3 +612,298 @@ def test_a_capture_shows_the_steps_phases_inside_llm_step(engine, tmp_path):
         assert any(s <= start and end <= e for _n, s, e in whole), name
     for (_n, _s, end), (_m, start, _e) in zip(phases, phases[1:]):
         assert end <= start  # never nested, never overlapping
+
+
+# ---------------------------------------------------------------------------
+# The one-block look-ahead: a step enqueues its decode block before it fetches
+# the one before, so the host is a block behind the device and never more.
+# ---------------------------------------------------------------------------
+
+AHEAD_KW = dict(max_slots=3, max_seq=128, page_size=16, prefill_buckets=(16, 32), decode_block=8)
+AHEAD_PROMPTS = [(np.arange(3 + 2 * i, dtype=np.int32) * (i + 3) + i) % 97 for i in range(7)]
+
+
+def _until_eos(tokens, eos):
+    return tokens[: tokens.index(eos) + 1] if eos in tokens else tokens
+
+
+def _staggered(eng, prompts, max_tokens, every=2):
+    """Requests added one every ``every`` steps and run to their ends:
+    id -> (tokens, finish_reason, the streamed new_tokens joined)."""
+    done, streamed = {}, {}
+    pending = list(enumerate(prompts))
+    steps = 0
+    while pending or eng.has_work():
+        if pending and steps % every == 0:
+            i, p = pending.pop(0)
+            eng.add_request(f"r{i}", p, max_tokens)
+        for rid, ev in eng.step().items():
+            streamed.setdefault(rid, []).extend(ev.get("new_tokens", []))
+            if ev.get("finished"):
+                done[rid] = (ev["tokens"], ev["finish_reason"])
+        steps += 1
+    return {rid: (toks, why, streamed[rid]) for rid, (toks, why) in done.items()}
+
+
+@pytest.fixture(scope="module")
+def ahead_solos():
+    """Each prompt's 20 greedy tokens, run alone (the first by the plain full
+    forward too)."""
+    eng = LLMEngine(CFG, engine_config=EngineConfig(**AHEAD_KW))
+    solos = [eng.generate(p, max_tokens=20)["tokens"] for p in AHEAD_PROMPTS]
+    assert solos[0] == _naive_greedy(eng.params, AHEAD_PROMPTS[0], 20)
+    return solos
+
+
+def _mid_block_eos(solos):
+    """A token some request emits inside a block of 8 and not at its end."""
+    for toks in solos:
+        for at, tok in enumerate(toks[1:], start=1):  # token `at` comes out of decode step at - 1
+            if (at - 1) % 8 != 7 and at >= 3:
+                return tok
+    raise AssertionError("no candidate")
+
+
+@pytest.mark.parametrize("with_eos", [False, True], ids=["budget", "eos_mid_block"])
+def test_a_block_ahead_staggered_requests_and_reused_slots_match_their_solo_runs(ahead_solos, with_eos):
+    """7 requests through 3 slots, one added every other step, blocks of 8:
+    every request's tokens, finish reason and count are what it gives run
+    alone, whether it ends by its budget or by an EOS inside a
+    block; what was streamed is what was returned."""
+    eos = _mid_block_eos(ahead_solos) if with_eos else -1
+    eng = LLMEngine(CFG, engine_config=EngineConfig(**AHEAD_KW, eos_id=eos))
+    got = _staggered(eng, AHEAD_PROMPTS, 20)
+    assert len(got) == len(AHEAD_PROMPTS)
+    n_stopped = 0
+    for i, solo in enumerate(ahead_solos):
+        want = _until_eos(solo, eos)
+        toks, why, streamed = got[f"r{i}"]
+        assert toks == want and streamed == want, i
+        assert why == ("stop" if want[-1] == eos else "length"), i
+        n_stopped += why == "stop"
+    assert n_stopped >= (1 if with_eos else 0)
+    steps = eng.trace_snapshot()["steps"]
+    blocks = [s for s in steps if s["block"]]
+    assert sum(s["ahead"] for s in blocks) >= len(blocks) // 2  # the mechanism engages
+    assert {s["ahead"] for s in steps} <= {0, 1} and all(not s["ahead"] for s in steps if not s["block"])
+    lives = {r["req_id"]: r for r in eng.trace_snapshot()["requests"]}
+    assert all(lives[f"r{i}"]["n_out"] == len(got[f"r{i}"][0]) for i in range(len(AHEAD_PROMPTS)))
+    assert not eng.has_work() and eng._inflight is None and len(eng.free_pages) == eng.ec.total_pages - 1
+
+
+def test_a_block_ahead_a_finished_rows_tokens_never_reach_the_slots_next_request(ahead_solos):
+    """One slot, two requests queued: the first ends by EOS in block s - 1, which
+    the host finds out after block s went to the device with the row still
+    live; the second takes the slot in the next step, before block s is
+    walked. That step's record counts the dropped row, and the second
+    request's tokens are its own."""
+    first, second = ahead_solos[2], ahead_solos[3]
+    eos = next(t for t in first[2:] if t not in second)
+    eng = LLMEngine(CFG, engine_config=EngineConfig(**{**AHEAD_KW, "max_slots": 1}, eos_id=eos))
+    eng.add_request("first", AHEAD_PROMPTS[2], 20)
+    eng.add_request("second", AHEAD_PROMPTS[3], 20)
+    done = _drain(eng)
+    assert done["first"] == _until_eos(first, eos) and done["first"][-1] == eos
+    assert done["second"] == second
+    steps = eng.trace_snapshot()["steps"]
+    readmit = next(k for k, s in enumerate(steps) if s["n_admitted"] and k > 0)
+    # the step that admitted `second` walked the block `first`'s row rode along in
+    assert steps[readmit]["dropped_rows"] == 1 and steps[readmit - 1]["block"] and steps[readmit - 1]["ahead"]
+    assert sum(s["dropped_rows"] for s in steps) >= 1
+
+
+def test_a_block_ahead_the_hosts_lengths_are_the_ones_the_decode_program_is_handed(ahead_solos):
+    """At every call of the decode program ``lengths[active]`` equals the
+    ``d_lengths`` rows it is handed (what ``cap``, ``live_pages`` and the
+    benchmark's wrapper read there), a slot the host has retired is dead on
+    the device too, and the record's ``live_pages`` is of those lengths."""
+    eos = _mid_block_eos(ahead_solos)
+    ec = EngineConfig(**AHEAD_KW, eos_id=eos)
+    eng = LLMEngine(CFG, engine_config=ec)
+    ps, table = ec.page_size, ec.max_seq // ec.page_size
+    decode, calls = eng._decode_jit, []
+
+    def spy(*args):
+        active = [i for i, s in enumerate(eng.slots) if s is not None and i not in eng._prefilling]
+        d_lengths, d_tables, n = np.array(args[3]), np.array(args[4]), args[6]
+        np.testing.assert_array_equal(eng.lengths[active], d_lengths[active])
+        np.testing.assert_array_equal(eng.page_tables, d_tables)
+        assert sorted(active) == sorted(np.flatnonzero(d_tables[:, 0] > 0))
+        seen = np.where(d_tables[:, 0] > 0, d_lengths + np.arange(1, n + 1)[:, None], 1)
+        calls.append((n, int(np.minimum(-(-seen // ps), table).sum())))
+        return decode(*args)
+
+    eng._decode_jit = spy
+    got = _staggered(eng, AHEAD_PROMPTS, 20)
+    assert [got[f"r{i}"][0] for i in range(7)] == [_until_eos(s, eos) for s in ahead_solos]
+    blocks = [s for s in eng.trace_snapshot()["steps"] if s["block"]]
+    assert [(s["block"], s["live_pages"]) for s in blocks] == calls and len(calls) > 5
+
+
+def test_a_block_ahead_abort_and_set_params_absorb_the_block_in_flight(ahead_solos):
+    """``abort`` of a running request and ``set_params`` find a block the
+    device still holds: they walk it first (its tokens are not lost: the next
+    step hands them out), and ``has_work`` stays true until the last block is
+    walked and its events returned."""
+    eng = LLMEngine(CFG, engine_config=EngineConfig(**AHEAD_KW))
+    streamed, done = {}, {}
+
+    def step():
+        events = eng.step()
+        for rid, ev in events.items():
+            streamed.setdefault(rid, []).extend(ev.get("new_tokens", []))
+            if ev.get("finished"):
+                done[rid] = ev["tokens"]
+        return events
+
+    eng.add_request("keep", AHEAD_PROMPTS[1], 20)
+    eng.add_request("gone", AHEAD_PROMPTS[4], 20)
+    step()
+    step()
+    assert eng._inflight is not None
+    free = len(eng.free_pages)
+    eng.abort("gone")
+    assert eng._inflight is None and "keep" in eng._carry and "gone" not in eng._carry
+    assert len(eng.free_pages) > free and sum(s is not None for s in eng.slots) == 1
+    gone = next(r for r in eng.trace_snapshot()["requests"] if r["req_id"] == "gone")
+    assert gone["finish_reason"] == "abort" and gone["n_out"] == 1 + 8 + 8  # both blocks it rode were walked
+    np.testing.assert_array_equal(np.asarray(eng.d_page_tables), eng.page_tables)
+    step()
+    assert eng._inflight is not None
+    eng.set_params(eng.params)  # same weights: the tokens must not change
+    assert eng._inflight is None and eng.has_work()
+    while eng.has_work():
+        step()
+    assert done == {"keep": ahead_solos[1]} and streamed["keep"] == ahead_solos[1]
+    # the end of work: a request that ended by its budget leaves nothing on the device...
+    assert eng._inflight is None and not eng._carry
+    # ...one that ends by EOS does: the step that returns its last event still holds a block
+    eos = _mid_block_eos(ahead_solos)
+    eng = LLMEngine(CFG, engine_config=EngineConfig(**AHEAD_KW, eos_id=eos))
+    i = next(k for k, s in enumerate(ahead_solos) if eos in s[1:])
+    eng.add_request("x", AHEAD_PROMPTS[i], 20)
+    events = {}
+    while not events.get("x", {}).get("finished"):
+        events = eng.step()
+    assert eng._inflight is not None and eng.has_work() and all(s is None for s in eng.slots)
+    assert eng.step() == {} and not eng.has_work()
+    assert eng.trace_snapshot()["steps"][-1]["dropped_rows"] == 1
+    assert eng.generate(AHEAD_PROMPTS[i], max_tokens=20)["tokens"] == _until_eos(ahead_solos[i], eos)
+    assert eng._inflight is None  # generate() leaves no block behind
+
+
+def test_a_block_ahead_a_write_past_a_rows_reservation_lands_in_the_dead_page(ahead_solos):
+    """A finished row rides up to two blocks past its budget, one more than
+    ``_pages_needed`` reserves: what it writes past its last page goes through
+    the zero tail of its table row to page 0 and nowhere else. A prompt of 5
+    with 3 tokens reserves one page of 16 (5 + 3 + 8); beside a long request
+    its row is written up to position 20."""
+    ec = EngineConfig(**{**AHEAD_KW, "total_pages": 12})
+    eng = LLMEngine(CFG, engine_config=ec)
+    ps = ec.page_size
+    short = AHEAD_PROMPTS[1]
+    assert len(short) == 5 and eng._pages_needed(5, 3) == 1
+    eng.add_request("long", AHEAD_PROMPTS[5], 20)
+    eng.add_request("short", short, 3)
+    owned, past = set(), []
+    decode = eng._decode_jit
+
+    def spy(*args):
+        for i, s in enumerate(eng.slots):
+            if s is not None:
+                owned.update(s.pages)
+                past.append(int(eng.lengths[i]) + args[6] - len(s.pages) * ps)  # the block's last write + 1
+        return decode(*args)
+
+    eng._decode_jit = spy
+    done = _drain(eng)
+    assert max(past) == 5 + 16 - ps  # the device wrote the short row's positions 16..20 past its one page
+    assert done == {"long": ahead_solos[5], "short": ahead_solos[1][:3]}
+    pool = np.asarray(eng.cache[0])  # [L, KV, pages * ps, Hd]
+    touched = {int(p) for p in np.flatnonzero(np.abs(pool).sum(axis=(0, 1, 3)).reshape(-1, ps).sum(axis=1))}
+    assert 0 in touched and touched <= owned | {0}
+
+
+def _events_of(eng, rids):
+    """Run to the end of work: every event each request of ``rids`` was sent."""
+    seen = {rid: [] for rid in rids}
+    while eng.has_work():
+        for rid, ev in eng.step().items():
+            seen[rid].append(ev)
+    return seen
+
+
+@pytest.fixture(scope="module")
+def ahead_engine():
+    """One engine for the context-cap cases: each leaves it empty."""
+    return LLMEngine(CFG, engine_config=EngineConfig(**AHEAD_KW))
+
+
+@pytest.mark.parametrize("beside", [False, True], ids=["alone", "beside_a_running_row"])
+@pytest.mark.parametrize("short_of_max_seq", [2, 1])
+def test_a_block_ahead_a_prompt_that_fills_the_context_ends_with_its_first_token(
+        ahead_engine, ahead_solos, short_of_max_seq, beside):
+    """A prompt of max_seq - 2 or max_seq - 1 tokens leaves no room for the
+    smallest compiled block. The step that admits it enqueues (or finds it
+    cannot enqueue) its decode block before the prefill's token is fetched:
+    the request must still end as it did when the fetch came first, in one
+    event with its first token and ``length``, and a row running beside it
+    keeps its own tokens."""
+    eng = ahead_engine
+    prompt = (np.arange(eng.ec.max_seq - short_of_max_seq, dtype=np.int32) * 7 + 3) % 97
+    t0 = _naive_greedy(eng.params, prompt, 1)
+    early = []
+    if beside:
+        eng.add_request("run", AHEAD_PROMPTS[1], 20)
+        early = [eng.step()["run"], eng.step()["run"]]
+        assert eng._inflight is not None
+    eng.add_request("full", prompt, 20)
+    seen = _events_of(eng, ["full", "run"])
+    seen["run"] = early + seen["run"]
+    assert len(seen["full"]) == 1
+    ev = seen["full"][0]
+    assert (ev["new_tokens"], ev["tokens"], ev["finished"], ev["finish_reason"]) == (t0, t0, True, "length")
+    assert ev["ttft_s"] is not None and ev["ttft_s"] > 0
+    if beside:
+        assert [t for e in seen["run"] for t in e.get("new_tokens", [])] == ahead_solos[1]
+        assert seen["run"][-1]["finish_reason"] == "length"
+    life = [r for r in eng.trace_snapshot()["requests"] if r["req_id"] == "full"][-1]
+    assert (life["n_out"], life["finish_reason"]) == (1, "length")
+    assert eng._inflight is None and len(eng.free_pages) == eng.ec.total_pages - 1
+    np.testing.assert_array_equal(np.asarray(eng.d_page_tables), 0)
+
+
+def test_a_block_ahead_no_block_goes_out_over_a_row_the_context_cap_path_retired(ahead_engine, ahead_solos):
+    """Two rows. The long one's budget ends exactly where its lengths leave
+    less than the smallest block of headroom, so the step finds no fit with a
+    block in flight, absorbs it, and the walk retires that row; the block it
+    then enqueues for the other row must meet mirrors without the retired
+    row, not its lengths two short of max_seq over pages the host has freed."""
+    eng = ahead_engine
+    long_prompt = (np.arange(100, dtype=np.int32) * 5 + 1) % 97
+    decode, calls = eng._decode_jit, []
+
+    def spy(*args):
+        active = [i for i, s in enumerate(eng.slots) if s is not None and i not in eng._prefilling]
+        d_lengths, d_tables = np.array(args[3]), np.array(args[4])
+        np.testing.assert_array_equal(eng.lengths[active], d_lengths[active])
+        np.testing.assert_array_equal(eng.page_tables, d_tables)
+        assert int(d_lengths.max()) + args[6] <= eng.ec.max_seq - 1
+        calls.append(len(active))
+        return decode(*args)
+
+    before = eng.trace_snapshot()["steps_total"]
+    eng._decode_jit = spy
+    try:
+        eng.add_request("long", long_prompt, 27)  # 100 + 8 + 8 + 8 + 2 = 126: cap 1 after 1 + 26 tokens
+        eng.add_request("other", AHEAD_PROMPTS[1], 60)
+        done = _drain(eng)
+    finally:
+        eng._decode_jit = decode
+    steps = eng.trace_snapshot()["steps"][before - eng.trace_snapshot()["steps_total"]:]
+    assert len(done["long"]) == 27 and done["long"] == eng.generate(long_prompt, max_tokens=27)["tokens"]
+    assert done["other"][:20] == ahead_solos[1]
+    assert done["other"] == eng.generate(AHEAD_PROMPTS[1], max_tokens=60)["tokens"]
+    # the step that absorbed before it dispatched: a block, not ahead, behind a step that was
+    forced = [k for k, s in enumerate(steps) if s["block"] and not s["ahead"] and k and steps[k - 1]["ahead"]]
+    assert forced and 1 in calls and 2 in calls

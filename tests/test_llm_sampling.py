@@ -181,11 +181,13 @@ def test_step_records_count_the_slots_that_sample(engine):
     eng = engine
     prompt_g = np.array([3, 1, 4, 1, 5, 9, 2, 6], np.int32)
     prompt_s = np.array([2, 7, 1, 8, 2, 8], np.int32)
-    greedy = SamplingParams(max_tokens=20)
+    # 28: the engine is a block ahead, so the sampled request's row rides one
+    # block past its end; the greedy one has to outlive that block too
+    greedy = SamplingParams(max_tokens=28)
     warm = SamplingParams(temperature=0.7, top_p=0.95, max_tokens=12)
 
     alone, blocks = _run(eng, {"g": (prompt_g, greedy)})
-    assert len(alone["g"]) == 20 and len(blocks) >= 2
+    assert len(alone["g"]) == 28 and len(blocks) >= 2
     assert [s["sampled"] for s in blocks] == [0] * len(blocks)
     assert [s["active"] for s in blocks] == [1] * len(blocks)
 
@@ -200,7 +202,22 @@ def test_step_records_count_the_slots_that_sample(engine):
     again, blocks = _run(eng, {"g": (prompt_g, greedy)})
     assert again == alone and [s["sampled"] for s in blocks] == [0] * len(blocks)
 
-    mixed, blocks = _run(eng, {"g": (prompt_g, greedy), "s": (prompt_s, warm)})
+    # `sampled` is the host's count at dispatch, and the device's too: a row the
+    # program samples is one with pages and a temperature in the mirrors it is
+    # handed. A finished sampled row keeps both for the one block that was
+    # enqueued before the host walked its last token, and is counted there.
+    decode, on_device = eng._decode_jit, []
+
+    def spy(*args):
+        on_device.append(int(np.count_nonzero((np.asarray(args[4])[:, 0] > 0) & (np.asarray(args[7]) > 0))))
+        return decode(*args)
+
+    eng._decode_jit = spy
+    try:
+        mixed, blocks = _run(eng, {"g": (prompt_g, greedy), "s": (prompt_s, warm)})
+    finally:
+        eng._decode_jit = decode
+    assert [s["sampled"] for s in blocks] == on_device
     assert mixed["g"] == alone["g"] and len(mixed["s"]) == 12
     both = [s for s in blocks if s["active"] == 2]
     assert both and [s["sampled"] for s in both] == [1] * len(both)

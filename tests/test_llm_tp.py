@@ -172,3 +172,40 @@ def test_tp_replica_on_cpu_host_raises():
             rt.get(replica.check_health.remote(), timeout=300)
     finally:
         rt.shutdown()
+
+
+def test_tp_a_block_ahead_staggered_requests_and_reused_slots_match_their_solo_runs():
+    """The look-ahead engine under a mesh: a step enqueues its decode block
+    before it fetches the one before, the mirrors stay replicated whoever
+    wrote them last, and 6 requests through 2 of its slots (blocks of 8, an EOS
+    inside a block) return what each gives alone on one device."""
+    from ray_tpu.accel import device
+
+    prompts = [[(7 * i + 3 * j + 1) % 96 for j in range(4 + 3 * i)] for i in range(6)]
+    one = _engine(1)
+    solos = [one.generate(p, max_tokens=18)["tokens"] for p in prompts]
+    eos = solos[1][4]  # inside the first block of 8
+    want = [s[: s.index(eos) + 1] if eos in s else s for s in solos]
+    eng = LLMEngine(CFG, engine_config=EngineConfig(
+        **{**ENGINE_KW, "max_slots": 2}, tensor_parallel=2, eos_id=eos))
+    device._count_compiles()
+    before = device.compile_events()["count"]
+    eng.warmup()
+    compiled = device.compile_events()["count"]
+    assert compiled > before
+    done = {}
+    pending = list(enumerate(prompts))
+    while pending or eng.has_work():
+        if pending:
+            i, p = pending.pop(0)
+            eng.add_request(f"r{i}", p, 18)
+        for rid, ev in eng.step().items():
+            if ev.get("finished"):
+                done[rid] = ev["tokens"]
+    assert [done[f"r{i}"] for i in range(6)] == want
+    steps = eng.trace_snapshot()["steps"]
+    assert sum(s["ahead"] for s in steps) > 0 and sum(s["dropped_rows"] for s in steps) > 0
+    # a retire's per-row write and every program that met its result were warmed
+    assert device.compile_events()["count"] == compiled
+    for mirror in (eng.d_lengths, eng.d_last, eng.d_page_tables, eng.d_temps):
+        assert mirror.sharding.is_equivalent_to(eng._replicated, mirror.ndim)

@@ -217,6 +217,33 @@ def test_the_step_record_counts_one_window_layers_walk_and_positions():
     assert eng.pool_bytes["sliding_attention"] == 2 * 6 * 2 * (2 * 3 * PS) * 16 * 4
 
 
+def test_a_block_ahead_a_slots_ring_reused_by_its_next_request_gives_that_requests_own_tokens():
+    """One slot, three requests queued, the engine a block ahead: a request
+    that has ended rides one more block and writes its slot's ring while the
+    next request's prefill waits behind that block on the device; the prefill
+    then writes the ring's window anew. A prompt past the window after one
+    inside the first page, and the reverse: each returns what it gives alone."""
+    params = _params()
+    prompts = [_tokens(70, seed=4), _tokens(7, seed=5), _tokens(50, seed=6)]
+    solo = LLMEngine(CFG, params=params, engine_config=EngineConfig(**ENGINE_KW))
+    want = [solo.generate(p, max_tokens=10)["tokens"] for p in prompts]
+    eos = want[0][5]  # the first request ends inside its second block of 4
+    want = [w[: w.index(eos) + 1] if eos in w else w for w in want]
+    eng = LLMEngine(CFG, params=params, engine_config=EngineConfig(**{**ENGINE_KW, "max_slots": 1}, eos_id=eos))
+    for i, p in enumerate(prompts):
+        eng.add_request(f"r{i}", p, 10)
+    done = {}
+    while eng.has_work():
+        for rid, ev in eng.step().items():
+            if ev.get("finished"):
+                done[rid] = ev["tokens"]
+    assert [done[f"r{i}"] for i in range(3)] == want and want[0][-1] == eos
+    steps = eng.trace_snapshot()["steps"]
+    assert sum(s["dropped_rows"] for s in steps) >= 1 and any(s["ahead"] for s in steps)
+    blocks = [s for s in steps if s["block"]]
+    assert all(s["window_pages"] >= s["block"] and s["window_tokens"] > 0 for s in blocks)
+
+
 # ---------------------------------------------------------------------------
 # the kernels, in interpret mode, against jax.numpy
 # ---------------------------------------------------------------------------
