@@ -53,6 +53,9 @@ class LLMServer:
         from ray_tpu.llm.engine import EngineConfig, LLMEngine
         from ray_tpu.models.transformer import TransformerConfig
 
+        # The process has its imports and its backend (require_tpu starts it):
+        # from here on the constructor is timed (stats()["startup"]).
+        init_began = time.monotonic()
         cfg = TransformerConfig(**model_config)
         ec = EngineConfig(**(engine_config or {}))
         # train->serve weight handoff: `params` may be an ObjectRef to a
@@ -78,8 +81,13 @@ class LLMServer:
         self._warmup_s = time.perf_counter() - t2
         # Where start-up went (stats()["startup"]): fetching the params,
         # building the engine (weights made or resharded, KV pool), and each
-        # warmed program with its seconds (a cold compile or a cache read).
+        # warmed program with its seconds (a cold compile or a cache read);
+        # init_began / init_ended put the three on time.monotonic(), the clock
+        # of the lifecycle stamps and of a client on this machine: a client's
+        # set-up is what went before the first (the process, its imports, the
+        # backend), the three durations, and what came after the second.
         self._startup = {
+            "init_began": init_began, "init_ended": None,
             "fetch_params_s": t1 - t0, "engine_init_s": t2 - t1, "warmup_s": self._warmup_s,
             "programs": self.engine.warmup_log,
             # the KV pools' bytes, by layer kind (one kind for a model whose layers are alike)
@@ -122,6 +130,7 @@ class LLMServer:
             self._weights_sub = WeightSubscriber(weights_channel, self._swap_weights)
         self._thread = threading.Thread(target=self._loop, name="llm-engine", daemon=True)
         self._thread.start()
+        self._startup["init_ended"] = time.monotonic()
 
     def _swap_weights(self, tree, summary):
         with self._swap_lock:
@@ -161,9 +170,10 @@ class LLMServer:
                 for rid, ev in events.items():
                     if ev.get("ttft_s") is not None:
                         self._ttft[rid] = ev["ttft_s"]
-                        self._ttft_hist.observe(ev["ttft_s"])
                         life = self._life.get(rid)
                         if life is not None and life["first_emitted"] is None:
+                            # once a request: its closing event, steps later, carries ttft_s again
+                            self._ttft_hist.observe(ev["ttft_s"])
                             first.append(life)
                     stream = self._streams.get(rid)
                     if stream is not None:
@@ -348,7 +358,11 @@ class LLMServer:
         the lifecycle records of the finished requests and the phase records
         of the ended steps still in their rings (engine.TRACE_RING each; what
         fell off is counted in "dropped"), cumulative seconds by step phase,
-        and this process's compilations. "startup" says where start-up went.
+        and this process's compilations. "startup" says where start-up went:
+        three durations between the stamps init_began (the process has its
+        imports and its backend) and init_ended (the constructor's last
+        statement) on the same clock, so a client that timed its own set-up
+        finds what went before the first and what came after the second.
         Reads only; takes no lock."""
         from ray_tpu.accel import device as _device
 

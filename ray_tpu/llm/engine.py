@@ -296,6 +296,7 @@ def _prompt_attention(q, k, v, seg, mesh, scale=None, window=0):
 REQUEST_SPANS = (
     ("llm.queue", "arrived", "admitted"),
     ("llm.prefill", "admitted", "first_token"),
+    ("llm.prefill.enqueue", "admitted", "prefill_enqueued"),  # the host's part of llm.prefill
     ("llm.first_emit", "first_token", "first_emitted"),
     ("llm.decode", "first_emitted", "finished"),
 )
@@ -1095,8 +1096,11 @@ class LLMEngine:
         Returns the request's lifecycle record: one stamp an event, on
         time.monotonic(), each written where the event happens. This engine
         writes arrived, admitted (with slot, prefix_hit_len, bucket),
-        first_token (the token is on the host), finished (the slot retired;
-        with n_out, finish_reason) and then pushes the record to
+        prefill_enqueued (the call that enqueues the request's prefill
+        program has returned: of a chunked prompt its last chunk's; None for
+        an exact prefix hit, which has none), first_token (the token is on
+        the host), finished (the slot retired; with n_out, finish_reason)
+        and then pushes the record to
         request_ring; a serving loop around the engine writes first_emitted
         and first_yielded into the same dict. `trace` is the caller's active
         trace context or None: the one ContextVar.get an untraced request
@@ -1115,7 +1119,7 @@ class LLMEngine:
         life = {
             "req_id": req_id, "arrived": time.monotonic(), "admitted": None, "slot": None,
             "prompt_len": len(tokens), "prefix_hit_len": None, "bucket": None,
-            "first_token": None, "first_emitted": None, "first_yielded": None,
+            "prefill_enqueued": None, "first_token": None, "first_emitted": None, "first_yielded": None,
             "finished": None, "n_out": None, "finish_reason": None,
             "trace": _tracing.current_trace(),
         }
@@ -1275,14 +1279,16 @@ class LLMEngine:
         """What the program recorded of itself, for LLMServer.stats(): the
         finished requests' lifecycle records and the ended steps' phase
         records still in their rings (stamps on time.monotonic()), the
-        cumulative seconds and entries of each phase, and what the rings
-        dropped. Reads only, takes no lock; any thread may call it."""
+        cumulative seconds and entries of each phase, the pages a step's
+        pages_reserved is a share of, and what the rings dropped.
+        Reads only, takes no lock; any thread may call it."""
         steps = self._phases
         return {
             "clock": "monotonic", "now": time.monotonic(),
             "requests": self.request_ring.snapshot(), "requests_total": self.request_ring.total,
             "steps": steps.ring.snapshot(), "steps_total": steps.ring.total,
             "phase_s": dict(steps.phase_s), "phase_n": dict(steps.phase_n),
+            "pages_total": self.ec.total_pages - 1,  # page 0 is the dead sink
             "dropped": {"requests": self.request_ring.dropped, "steps": steps.ring.dropped},
         }
 
@@ -1304,7 +1310,7 @@ class LLMEngine:
         step's record goes to the ring that LLMServer.stats() returns."""
         ph = self._phases
         ph.begin("admit", waiting=len(self.waiting), n_admitted=0, n_prefill=0,
-                 block=0, ahead=0, dropped_rows=0, active=0, sampled=0, live_pages=0,
+                 pages_reserved=0, block=0, ahead=0, dropped_rows=0, active=0, sampled=0, live_pages=0,
                  expert_pairs=0, expert_tiles=0,
                  **({"window_pages": 0, "window_tokens": 0} if self._window else {}))
         try:
@@ -1429,6 +1435,8 @@ class LLMEngine:
                 self.lengths[i] = P
                 bucket = life["bucket"] = next(b for b in self.buckets if b >= P)
                 admitted.append((i, req_id, tokens, bucket, sp.max_tokens, arrived))
+        # the pages slots hold once the step has admitted: what is neither free nor the prefix cache's
+        ph.rec["pages_reserved"] = self.ec.total_pages - 1 - len(self.free_pages) - len(self._page_refs)
         if cache_hits:
             ph.to("mirror_sync")
             idx = jnp.asarray(np.array([h[0] for h in cache_hits], np.int32))
@@ -1472,6 +1480,9 @@ class LLMEngine:
                     jnp.asarray(self.samp_top_ks[idxs]),
                     *rings,
                 )
+                enqueued = time.monotonic()
+                for i in idxs:
+                    self.slots[i].life["prefill_enqueued"] = enqueued
                 ph.to("mirror_sync")
                 self.d_lengths = self.d_lengths.at[idx_arr].set(jnp.asarray(lens))
                 self.d_last = self.d_last.at[idx_arr].set(toks_dev)
@@ -1482,7 +1493,9 @@ class LLMEngine:
         # context pages.
         for (i, req_id, tokens, start, _mt, arrived) in tail_admitted:
             P = len(tokens)
-            toks_dev, self.slots[i].life["bucket"] = self._dispatch_tail(i, tokens[start:], start, P)
+            life = self.slots[i].life
+            toks_dev, life["bucket"] = self._dispatch_tail(i, tokens[start:], start, P)
+            life["prefill_enqueued"] = time.monotonic()
             ph.to("mirror_sync")
             self.d_lengths = self.d_lengths.at[i].set(P)
             self.d_last = self.d_last.at[i].set(toks_dev[0])
@@ -1514,6 +1527,7 @@ class LLMEngine:
             toks_dev, _tb = self._dispatch_tail(i, tokens[start:start + n_tok], start, length)
             ph.rec["n_prefill"] += 1
             if last_chunk:
+                slot.life["prefill_enqueued"] = time.monotonic()
                 del self._prefilling[i]
                 slot.prefill_pos = P
                 slot.n_generated = 1

@@ -18,7 +18,10 @@ requests):
               LLM replica lays the request's phases inside its engine onto
               the trace (llm.queue, llm.prefill, llm.first_emit, llm.decode:
               llm/engine.py REQUEST_SPANS), and the hop then carries them
-              as ``parts``, with ``other`` for what of the hop they leave
+              as ``parts``, with ``other`` for what of the hop they leave;
+              the ``prefill`` part says as ``enqueue_s`` how much of it the
+              host took to enqueue the prefill program (llm.prefill.enqueue:
+              the rest is the device's queue, the program and the fetch)
     drain     reply/stream drain back through the proxy after exec ended
 
 plus ``unattributed`` = total - sum(hops): the residue the decomposition
@@ -35,6 +38,8 @@ from typing import Optional
 HOPS = ("proxy", "admission", "dispatch", "wire", "exec", "drain")
 # Spans that split the exec hop, in the order a request passes them.
 EXEC_PARTS = ("llm.queue", "llm.prefill", "llm.first_emit", "llm.decode")
+# The host's share of llm.prefill, from the same start: shown on that part, not beside it.
+PREFILL_ENQUEUE = "llm.prefill.enqueue"
 
 
 def _first(events, **match) -> Optional[dict]:
@@ -107,6 +112,11 @@ def autopsy(events: list[dict]) -> dict:
         parts = [{"part": s["name"].split(".", 1)[1], "dur_s": max(0.0, s.get("dur", 0.0))}
                  for name in EXEC_PARTS for s in spans if s.get("name") == name]
         if parts:
+            enqueue = _first(spans, name=PREFILL_ENQUEUE)
+            if enqueue is not None:
+                for p in parts:
+                    if p["part"] == "prefill":
+                        p["enqueue_s"] = max(0.0, enqueue.get("dur", 0.0))
             named = sum(p["dur_s"] for p in parts)
             parts.append({"part": "other", "dur_s": max(0.0, hops[-1]["dur_s"] - named)})
             hops[-1]["parts"] = parts

@@ -264,6 +264,15 @@ class PhaseSpans:
       on ``time.monotonic()``, ``dur``, ``phase_s`` by phase, and whatever
       counts the caller writes into it), which ``end`` pushes to ``ring``,
       and to the cumulative ``phase_s`` / ``phase_n``;
+    - adds the CPU seconds of the thread that drives the instance to the
+      record's ``phase_cpu_s``, key for key beside its ``phase_s`` (the
+      record only: the cumulative dicts hold wall seconds and entries):
+      ``time.thread_time()`` is read at every seam beside
+      ``time.monotonic()`` and does not advance while the thread is blocked
+      (a dispatch that waits for room in the device's queue, a fetch, the
+      GIL, off the core), so ``phase_s - phase_cpu_s`` is what a phase
+      waited and ``phase_cpu_s`` what it worked. ``cpu_t`` is that clock at
+      ``begin``: two records' difference also holds the loop between them;
     - is an annotation ``<name>.<phase>`` inside one ``<name>`` around the
       iteration, so that in a device capture the phases lie on the trace's
       own clock beside the device's operations.
@@ -283,35 +292,36 @@ class PhaseSpans:
         self.phase_n = dict.fromkeys(phases, 0)
         self.rec: Optional[dict] = None
         self._phase = ""
-        self._t = 0.0
+        self._t = self._cpu = 0.0
         self._whole = self._open = None
 
     def begin(self, phase: str, **fields) -> None:
-        t = time.monotonic()
-        self.rec = {"t": t, "dur": 0.0, "phase_s": {}, **fields}
+        t, cpu = time.monotonic(), time.thread_time()
+        self.rec = {"t": t, "dur": 0.0, "phase_s": {}, "cpu_t": cpu, "phase_cpu_s": {}, **fields}
         self._whole = self._ann(self.name)
         self._whole.__enter__()
-        self._enter(phase, t)
+        self._enter(phase, t, cpu)
 
-    def _enter(self, phase: str, t: float) -> None:
-        self._phase, self._t = phase, t
+    def _enter(self, phase: str, t: float, cpu: float) -> None:
+        self._phase, self._t, self._cpu = phase, t, cpu
         self.phase_n[phase] += 1
         self._open = self._ann(self._full[phase])
         self._open.__enter__()
 
-    def _leave(self, t: float) -> None:
+    def _leave(self, t: float, cpu: float) -> None:
         self._open.__exit__(None, None, None)
-        by_phase = self.rec["phase_s"]
-        by_phase[self._phase] = by_phase.get(self._phase, 0.0) + (t - self._t)
+        phase, rec = self._phase, self.rec
+        rec["phase_s"][phase] = rec["phase_s"].get(phase, 0.0) + (t - self._t)
+        rec["phase_cpu_s"][phase] = rec["phase_cpu_s"].get(phase, 0.0) + (cpu - self._cpu)
 
     def to(self, phase: str) -> None:
-        t = time.monotonic()
-        self._leave(t)
-        self._enter(phase, t)
+        t, cpu = time.monotonic(), time.thread_time()
+        self._leave(t, cpu)
+        self._enter(phase, t, cpu)
 
     def end(self) -> None:
-        t = time.monotonic()
-        self._leave(t)
+        t, cpu = time.monotonic(), time.thread_time()
+        self._leave(t, cpu)
         self._whole.__exit__(None, None, None)
         rec, self.rec = self.rec, None
         rec["dur"] = t - rec["t"]

@@ -27,6 +27,26 @@ os.environ.setdefault("RAYTPU_PROFILE_HZ", "0")
 import pytest
 
 
+@pytest.fixture(scope="session")
+def cpu_tick():
+    """The smallest step time.thread_time() takes on this host, in seconds:
+    under a microsecond where the kernel accounts a thread's CPU time at the
+    context switch, a scheduler tick (10 ms on the chip's host) where it
+    accounts by the tick; clock_getres says 1 ns of both. A tick clock charges
+    a whole tick to whoever runs when it fires, so an interval's CPU seconds
+    may read up to a tick over its wall seconds: tests of the two clocks side
+    by side allow for it."""
+    import time
+
+    steps, last, until = [], time.thread_time(), time.monotonic() + 0.05
+    while time.monotonic() < until or not steps:
+        now = time.thread_time()
+        if now > last:
+            steps.append(now - last)
+        last = now
+    return min(steps)
+
+
 @pytest.fixture(scope="module")
 def shared_ray():
     import ray_tpu as rt

@@ -149,3 +149,158 @@ def test_every_new_metric_is_in_the_manifest_with_its_cells(bench):
         assert entry["source"] == ("program_counter" if "compiles" in name else "program_span")
         # a per-layer metric moves an end-to-end metric that its cells report
         assert set(entry["workloads"]) <= set(end_to_end[entry["moves"]]["workloads"]), name
+
+
+# -- the readers of what PR 41 added to the record ---------------------------
+# The stepping thread's CPU clock beside each phase's wall seconds, a
+# request's `prefill_enqueued`, set-up's two stamps, a step's `pages_reserved`.
+# A table of their own: a plain name here may have more cells than `chat`, and
+# `cells` are the ones a metric had when it was added: later cells may join.
+CHAT = "internlm2-1.8b.chat"
+BACKLOGS = ["internlm2-1.8b.backlog", "mistral-7b.backlog-tp4", "openpangu-718b-ep16.backlog-long-out",
+            "laguna-s-2.1-ep8.backlog-long-ctx"]
+HOST_AND_WAITS = {  # name: (answer, cells, layer, moves, source, unit, better)
+    # the thread's CPU clock reads 20.000 / 20.013 / 20.030 s as the window's three steps begin
+    "engine_host_cpu_ms_per_step": (15.0, [CHAT], "scheduler", "tpot_p90_ms", "program_span", "ms", "lower"),
+    "engine_host_cpu_ms_per_step.backlog":
+        (15.0, BACKLOGS, "scheduler", "serve_out_tokens_per_s", "program_span", "ms", "lower"),
+    # wall less CPU outside the fetches: 2 + 2, 6 + 2 and 6 ms in the three steps
+    "engine_dispatch_blocked_ms_per_step": (6.0, [CHAT], "scheduler", "tpot_p90_ms", "program_span", "ms", "lower"),
+    "engine_dispatch_blocked_ms_per_step.backlog":
+        (6.0, BACKLOGS, "scheduler", "serve_out_tokens_per_s", "program_span", "ms", "lower"),
+    # enqueued 1 / 2 / 3 ms after admission, of prefills of 40 / 50 / 60 ms; the exact hit has no stamp
+    "engine_prefill_host_p50_ms": (2.0, [CHAT], "scheduler", "ttft_p90_ms", "program_span", "ms", "lower"),
+    "engine_prefill_device_p50_ms": (48.0, [CHAT], "scheduler", "ttft_p90_ms", "program_span", "ms", "lower"),
+    # setup_s 77.25 ends at the window's start; the constructor began 70 s before that
+    "setup_before_replica_s": (7.25, [CHAT] + BACKLOGS, "serve path", "setup_s", "program_span", "s", "lower"),
+    "setup_weights_s": (11.75, [CHAT] + BACKLOGS, "serve path", "setup_s", "program_span", "s", "lower"),
+    "setup_warmup_s": (33.5, [CHAT] + BACKLOGS, "serve path", "setup_s", "program_span", "s", "lower"),
+    # the constructor returned 24 s before the window's start
+    "setup_after_replica_s": (24.0, [CHAT] + BACKLOGS, "serve path", "setup_s", "program_span", "s", "lower"),
+    # 96, 144 and 120 of 400 pages
+    "kv_pages_reserved_share": (30.0, [CHAT], "scheduler", "ttft_p90_ms", "program_counter", "%", "lower"),
+    "kv_pages_reserved_share.backlog":
+        (30.0, BACKLOGS, "scheduler", "serve_out_tokens_per_s", "program_counter", "%", "higher"),
+}
+RING_READERS = sorted(n for n in HOST_AND_WAITS if not n.startswith("setup_"))
+
+
+def _cpu_step(t, cpu_t, pages_reserved, **wall_and_cpu_ms):
+    """A step record with both clocks: each phase as (wall ms, CPU ms)."""
+    rec = _step(t, **{k: wall for k, (wall, _cpu) in wall_and_cpu_ms.items()})
+    rec.update(cpu_t=cpu_t, pages_reserved=pages_reserved,
+               phase_cpu_s={k: cpu / 1e3 for k, (_wall, cpu) in wall_and_cpu_ms.items()})
+    return rec
+
+
+def _record_with_both_clocks():
+    record = _record()
+    trace = record["stats"]["trace"]
+    for r, enqueue_ms in zip(trace["requests"], (500, 1, 2, 3, 500)):
+        r["prefill_enqueued"] = r["admitted"] + enqueue_ms / 1e3
+    hit = _request(W0 + 25, 5, 9, 400, 1)  # an exact prefix hit: nothing was enqueued for it
+    hit["prefill_enqueued"] = None
+    trace["requests"].insert(4, hit)
+    trace["steps"] = [
+        _cpu_step(W0 - 5, 10.0, 100, admit=(90, 50), decode_fetch=(400, 1)),
+        _cpu_step(W0 + 2, 20.0, 96, admit=(2, 2), prefill_dispatch=(6, 4), mirror_sync=(3, 1), emit=(1, 1),
+                  prefill_fetch=(30, 0.5), decode_fetch=(400, 0.2)),
+        _cpu_step(W0 + 3, 20.013, 144, admit=(1, 1), decode_dispatch=(9, 3), emit=(4, 4), retire_sync=(4, 2),
+                  decode_fetch=(410, 0.1)),
+        _cpu_step(W0 + 6, 20.030, 120, decode_dispatch=(10, 4), decode_fetch=(100, 0.1)),
+        _cpu_step(W1 + 1, 30.0, 399, admit=(50, 1)),
+    ]
+    trace.update(steps_total=5, requests_total=6, pages_total=400)
+    record["setup_s"] = 77.25
+    record["stats"]["startup"] = {"init_began": W0 - 70.0, "init_ended": W0 - 24.0, "fetch_params_s": 0.25,
+                                  "engine_init_s": 11.5, "warmup_s": 33.5, "programs": []}
+    return record
+
+
+@pytest.mark.parametrize("name", sorted(HOST_AND_WAITS))
+def test_host_and_wait_reader_gives_the_hand_count(bench, name):
+    cellspec, context = bench
+    value = cellspec.load_metric(name)(context.Context(_record_with_both_clocks(), 1))
+    assert value == pytest.approx(HOST_AND_WAITS[name][0], abs=1e-6)
+
+
+@pytest.mark.parametrize("name", sorted(HOST_AND_WAITS))
+def test_host_and_wait_reader_is_silent_on_the_parents_record(bench, name):
+    """The parent's records have one clock, no `prefill_enqueued`, no
+    `pages_reserved` or `pages_total` and three durations of start-up without a
+    stamp: the reader returns None and does not raise, with or without stats."""
+    cellspec, context = bench
+    parents = _record()
+    parents["setup_s"] = 77.25
+    parents["stats"]["startup"] = {"fetch_params_s": 0.25, "engine_init_s": 11.5, "warmup_s": 33.5}
+    bare = _record()
+    bare["stats"] = None
+    half = _record_with_both_clocks()  # a stamp of set-up's two is no account of set-up
+    half["stats"]["trace"] = _record()["stats"]["trace"]
+    del half["stats"]["startup"]["init_ended"]
+    for record in (parents, _record(), bare, half):
+        assert cellspec.load_metric(name)(context.Context(record, 1)) is None
+    # one record of the window without the field is enough to blank a step reader
+    mixed = _record_with_both_clocks()
+    for key in ("cpu_t", "phase_cpu_s", "pages_reserved"):
+        del mixed["stats"]["trace"]["steps"][2][key]
+    if name in RING_READERS and "prefill" not in name:
+        assert cellspec.load_metric(name)(context.Context(mixed, 1)) is None
+
+
+@pytest.mark.parametrize("name", RING_READERS)
+def test_host_and_wait_reader_is_silent_after_a_drop_inside_the_window(bench, name):
+    cellspec, context = bench
+    ring = "requests" if "prefill" in name else "steps"
+    record = _record_with_both_clocks()
+    trace = record["stats"]["trace"]
+    trace[ring] = [r for r in trace[ring] if r.get("finished", r.get("t")) > W0 + 4]
+    trace["dropped"][ring] = 1
+    assert cellspec.load_metric(name)(context.Context(record, 1)) is None
+    older = _record_with_both_clocks()  # the oldest record still held is older than the window
+    older["stats"]["trace"]["dropped"][ring] = 1
+    assert cellspec.load_metric(name)(context.Context(older, 1)) == pytest.approx(HOST_AND_WAITS[name][0], abs=1e-6)
+
+
+def test_the_hosts_wall_time_is_its_cpu_time_plus_what_it_waited(bench):
+    """engine_host_ms_per_step = the non-fetch phases' CPU + engine_dispatch_blocked_ms_per_step,
+    by construction: (12 + 18 + 10) / 3 wall = (8 + 10 + 4) / 3 CPU + 6 blocked."""
+    cellspec, context = bench
+    ctx = context.Context(_record_with_both_clocks(), 1)
+    wall = cellspec.load_metric("engine_host_ms_per_step")(ctx)
+    blocked = cellspec.load_metric("engine_dispatch_blocked_ms_per_step")(ctx)
+    assert wall == pytest.approx(40 / 3) and wall - blocked == pytest.approx(22 / 3)
+
+
+def test_the_four_parts_of_set_up_add_up_to_it(bench):
+    """Before the constructor's stamp, its weights and warm-up, after it: setup_s
+    but for what lies between the stamps and outside the three durations (46 s
+    between them here, 45.25 in the durations; milliseconds in the program)."""
+    cellspec, context = bench
+    ctx = context.Context(_record_with_both_clocks(), 1)
+    parts = [cellspec.load_metric(f"setup_{part}_s")(ctx)
+             for part in ("before_replica", "weights", "warmup", "after_replica")]
+    assert sum(parts) == pytest.approx(77.25 - 0.75)
+
+
+def test_one_step_in_the_window_gives_no_cpu_cycle(bench):
+    cellspec, context = bench
+    record = _record_with_both_clocks()
+    record["stats"]["trace"]["steps"] = record["stats"]["trace"]["steps"][:2]
+    assert cellspec.load_metric("engine_host_cpu_ms_per_step")(context.Context(record, 1)) is None
+    assert cellspec.load_metric("engine_dispatch_blocked_ms_per_step")(context.Context(record, 1)) == pytest.approx(4.0)
+
+
+@pytest.mark.parametrize("name", sorted(HOST_AND_WAITS))
+def test_host_and_wait_metric_is_in_the_manifest_as_the_table_says(bench, name):
+    import json
+
+    with open(os.path.join(os.path.dirname(BENCH_DIR), "BENCHMARK.json")) as f:
+        manifest = json.load(f)
+    entry = next(m for m in manifest["per_layer"] if m["name"] == name)
+    _answer, cells, layer, moves, source, unit, better = HOST_AND_WAITS[name]
+    listed = entry.pop("workloads")
+    assert entry == {"name": name, "unit": unit, "better": better, "source": source, "layer": layer, "moves": moves}
+    assert set(cells) <= set(listed) and len(set(listed)) == len(listed)  # later cells may join the list
+    moved = next(m for m in manifest["end_to_end"] if m["name"] == moves)
+    assert set(listed) <= set(moved.get("workloads", listed)), "a cell that does not report what the metric moves"

@@ -438,6 +438,32 @@ def test_exact_prefix_hit_and_abort_are_recorded(llm_server):
     assert not llm_server._life
 
 
+def test_ttft_is_observed_once_for_a_request_of_many_steps(llm_server, monkeypatch):
+    """The engine writes ttft_s into a request's closing event too: the loop
+    observes serve.ttft_s where it first stamps `first_emitted`, once."""
+    observed = []
+    monkeypatch.setattr(llm_server, "_ttft_hist", type("Hist", (), {"observe": staticmethod(observed.append)}))
+    before = llm_server.stats()["trace"]["steps_total"]
+    out = llm_server.generate([8, 6, 7, 5, 3, 0, 9], max_tokens=20)  # a first token and three blocks
+    assert llm_server.stats()["trace"]["steps_total"] - before >= 3 and len(out["tokens"]) == 20
+    assert observed == [out["ttft_s"]]
+    llm_server.generate([5, 4, 3, 2, 1], max_tokens=1)  # first and last token in one event
+    assert len(observed) == 2
+
+
+def test_startup_stamps_bracket_what_startup_took(llm_server):
+    """init_began / init_ended: on time.monotonic(), the lifecycle stamps'
+    clock, around the three durations and next to nothing else (the imports and
+    the backend lie before the first), so a client's set-up is what went
+    before, the three, and what came after."""
+    startup = llm_server.stats()["startup"]
+    took = startup["fetch_params_s"] + startup["engine_init_s"] + startup["warmup_s"]
+    assert startup["init_began"] + took <= startup["init_ended"] <= time.monotonic()
+    assert startup["init_ended"] - startup["init_began"] - took < 1.0
+    first = llm_server.stats()["trace"]["requests"]
+    assert all(r["arrived"] >= startup["init_ended"] for r in first)
+
+
 def test_stats_is_cheap_when_idle_and_says_where_startup_went():
     from ray_tpu.llm.deployment import LLMServer
 
@@ -500,6 +526,81 @@ def test_step_phases_sum_to_no_more_than_the_step():
             sum(s["phase_s"].get(phase, 0.0) for s in steps))
     assert snap["phase_n"]["decode_fetch"] == sum(1 for s in steps if s["block"])
     assert snap["phase_n"]["prefix_lookup"] == 6
+
+
+def test_step_records_carry_both_clocks_and_the_pages_slots_hold(cpu_tick):
+    """Every step record has the stepping thread's CPU seconds beside its wall
+    seconds, phase for phase, and the thread's CPU clock at its start; and
+    `pages_reserved`, the pages slots hold once the step has admitted: with
+    the free pages and the prefix cache's as they stand then (read where the
+    decode dispatch begins, before any fetch retires a slot) it is the whole
+    pool but the dead page. Ten requests on four slots, two prompts repeated.
+    The CPU clock may step by a tick (`cpu_tick`: 10 ms on the chip's host) and
+    then reads up to a tick over the wall clock: held to what both kinds keep."""
+    eng = LLMEngine(CFG, engine_config=EngineConfig(**ENGINE_KW))
+    seen = []
+    dispatch = eng._dispatch_decode
+
+    def spy(ph, events):
+        held = sum(len(s.pages) for s in eng.slots if s is not None)
+        seen.append((len(eng.free_pages), len(eng._page_refs), held))
+        return dispatch(ph, events)
+
+    eng._dispatch_decode = spy
+    prompts = [np.arange(3 + 7 * (i % 5), dtype=np.int32) % 97 for i in range(10)]
+    for i, prompt in enumerate(prompts):
+        eng.add_request(f"r{i}", prompt, 6 + 3 * i)
+    _drain(eng)
+    eng.step()  # nothing to do: no slot holds a page
+    snap = eng.trace_snapshot()
+    steps = snap["steps"]
+    assert snap["pages_total"] == eng.ec.total_pages - 1 and len(steps) == len(seen) > 5
+    for rec, (free, cached, held) in zip(steps, seen):
+        assert list(rec["phase_cpu_s"]) == list(rec["phase_s"])
+        assert all(cpu >= 0 for cpu in rec["phase_cpu_s"].values()), rec
+        assert sum(rec["phase_cpu_s"].values()) <= rec["dur"] + 1e-3 + cpu_tick, rec
+        assert rec["pages_reserved"] == held
+        assert rec["pages_reserved"] + free + cached == snap["pages_total"]
+    assert max(c for _f, c, _h in seen) > 0 and eng.prefix_cache_stats["hits"] >= 1  # the cache did hold pages
+    assert max(s["pages_reserved"] for s in steps) > steps[-1]["pages_reserved"] == 0
+    cpu_t = [s["cpu_t"] for s in steps]
+    assert cpu_t == sorted(cpu_t) and cpu_t[-1] > cpu_t[0]
+    for a, b in zip(steps, steps[1:]):  # a step's CPU lies between its start and the next one's
+        assert b["cpu_t"] - a["cpu_t"] >= sum(a["phase_cpu_s"].values()) - 1e-6
+    # from the first step's start to the last one's end the thread had no more CPU than time
+    ran = steps[-1]["cpu_t"] + sum(steps[-1]["phase_cpu_s"].values()) - cpu_t[0]
+    assert 0 < ran <= steps[-1]["t"] + steps[-1]["dur"] - steps[0]["t"] + 1e-3 + cpu_tick
+
+
+def _life_of(eng, rid):
+    return next(r for r in eng.trace_snapshot()["requests"] if r["req_id"] == rid)
+
+
+@pytest.mark.parametrize("how", ["cold", "tail", "last_chunk", "exact_hit"])
+def test_prefill_enqueued_lies_between_admission_and_the_first_token(how):
+    """`prefill_enqueued`: the call that enqueues the request's prefill program
+    has returned. A whole prompt's group, a partial prefix hit's tail program
+    and a chunked prompt's last chunk each stamp it; an exact hit has no
+    prefill and no stamp."""
+    chunked = dict(chunked_prefill=16) if how == "last_chunk" else {}
+    eng = LLMEngine(CFG, engine_config=EngineConfig(**ENGINE_KW, **chunked))
+    base = (np.arange(40, dtype=np.int32) * 7 + 1) % 97
+    if how in ("tail", "exact_hit"):  # a first request leaves its prompt's pages in the prefix cache
+        eng.add_request("first", base, 4)
+        _drain(eng)
+    prompt = np.concatenate([base[:32], np.array([5, 6, 7], np.int32)]) if how == "tail" else base
+    eng.add_request("r", prompt, 4)
+    _drain(eng)
+    life = _life_of(eng, "r")
+    assert life["prefix_hit_len"] == {"cold": 0, "tail": 32, "last_chunk": 0, "exact_hit": 40}[how]
+    if how == "exact_hit":
+        assert life["prefill_enqueued"] is None and life["admitted"] <= life["first_token"]
+        return
+    assert life["admitted"] <= life["prefill_enqueued"] <= life["first_token"] <= life["finished"]
+    if how == "last_chunk":
+        # three chunks of 16, one a step: the stamp is the last one's, two steps after admission
+        steps = [s["t"] for s in eng.trace_snapshot()["steps"] if s["t"] >= life["arrived"]]
+        assert steps[0] <= life["admitted"] <= steps[1] <= steps[2] <= life["prefill_enqueued"]
 
 
 def test_step_record_counts_the_page_steps_the_decode_block_walks():

@@ -521,6 +521,22 @@ def test_autopsy_splits_exec_by_the_llm_spans():
         {"queue": 0.20, "prefill": 0.08, "first_emit": 0.40, "decode": 0.26, "other": 0.06})
 
 
+def test_autopsy_shows_the_hosts_share_of_the_prefill_on_its_part():
+    """llm.prefill.enqueue (admitted -> the prefill program enqueued) starts
+    where llm.prefill starts and lies inside it: the `prefill` part carries it
+    as `enqueue_s`, and the parts still add up to the hop."""
+    enqueue = dict(_llm_spans()[1], name="llm.prefill.enqueue", span_id="llm.prefill.enqueue", dur=0.003)
+    a = obs_autopsy.autopsy(_synthetic_trace() + _llm_spans() + [enqueue])
+    exec_hop = next(h for h in a["hops"] if h["hop"] == "exec")
+    parts = {p["part"]: p for p in exec_hop["parts"]}
+    assert list(parts) == ["queue", "prefill", "first_emit", "decode", "other"]
+    assert parts["prefill"] == {"part": "prefill", "dur_s": pytest.approx(0.04), "enqueue_s": pytest.approx(0.003)}
+    assert all("enqueue_s" not in p for name, p in parts.items() if name != "prefill")
+    assert sum(p["dur_s"] for p in parts.values()) == pytest.approx(exec_hop["dur_s"])
+    plain = obs_autopsy.autopsy(_synthetic_trace() + _llm_spans())
+    assert all("enqueue_s" not in p for h in plain["hops"] for p in h.get("parts", ()))
+
+
 def test_compile_counter_counts_fresh_jits_only():
     """accel/device counts this process's backend compilations from the moment
     the compile cache is enabled: one more for a fresh jax.jit, none for a

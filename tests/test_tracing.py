@@ -5,6 +5,7 @@ proxy -> replica -> nested actor; every resulting span must share one
 trace_id with correct parent/child links, and export_timeline must emit
 connected flow events (ph s/f) for the hops."""
 import json
+import threading
 import time
 import urllib.request
 
@@ -272,6 +273,85 @@ def test_phase_spans_time_count_and_annotate():
     assert bare.ring.total == 1 and bare.phase_n == {"x": 1}
 
 
+def _sleep_then_spin(ph, sleep_s, spin_s):
+    ph.begin("sleeps")
+    time.sleep(sleep_s)
+    ph.to("spins")
+    until = time.thread_time() + spin_s
+    while time.thread_time() < until:
+        pass
+    ph.to("sleeps")  # a phase entered twice in one iteration adds up on both clocks
+    time.sleep(sleep_s)
+    ph.end()
+
+
+def test_phase_spans_tell_a_phase_that_waits_from_one_that_works(cpu_tick):
+    """Beside each phase's wall seconds the CPU seconds of the thread that
+    drives the spans: a sleeping phase (as a dispatch that waits for room in
+    the device's queue, or a fetch) has nearly none, a spinning one nearly all.
+    `cpu_tick` is the CPU clock's step here: next to nothing on this sandbox,
+    10 ms on a host that accounts by the tick, where a phase is made long
+    enough to tell and may read a tick over its wall time each time it is entered."""
+    ph = tracing.PhaseSpans("loop", ("sleeps", "spins", "unused"), 32)
+    sleep_s, spin_s = max(0.03, 5 * cpu_tick), max(0.002, 3 * cpu_tick)
+    before = time.thread_time()
+
+    def spun_on_its_core(r):
+        wall, cpu = r["phase_s"]["spins"], r["phase_cpu_s"]["spins"]
+        return abs(wall - cpu) <= 0.2 * wall + cpu_tick
+
+    # a spinning thread is taken off its core on a busy machine (the other test
+    # workers): at least three iterations, and more until one kept its core
+    for n in range(30):
+        _sleep_then_spin(ph, sleep_s, spin_s)
+        if n >= 2 and any(map(spun_on_its_core, ph.ring.snapshot())):
+            break
+    recs = ph.ring.snapshot()
+    assert 3 <= len(recs) == ph.ring.total
+    for r in recs:
+        wall, cpu = r["phase_s"], r["phase_cpu_s"]
+        assert list(cpu) == list(wall) == ["sleeps", "spins"]
+        assert wall["sleeps"] >= 2 * sleep_s and cpu["sleeps"] <= wall["sleeps"] / 10 + 2 * cpu_tick
+        assert cpu["spins"] >= spin_s
+        assert all(0 <= cpu[p] <= wall[p] + 1e-3 + 2 * cpu_tick for p in wall)
+        assert sum(cpu.values()) <= r["dur"] + 1e-3 + cpu_tick
+    assert any(map(spun_on_its_core, recs))
+    # over the iterations a tick charged to a sleep by chance does not add up
+    assert sum(r["phase_cpu_s"]["sleeps"] for r in recs) <= sum(r["phase_s"]["sleeps"] for r in recs) / 10 + 2 * cpu_tick
+    # cpu_t: the thread's CPU clock as the iteration began, so two records'
+    # difference holds an iteration's CPU and what the loop did in between
+    assert before <= recs[0]["cpu_t"] and [r["cpu_t"] for r in recs] == sorted(r["cpu_t"] for r in recs)
+    for a, b in zip(recs, recs[1:]):
+        assert b["cpu_t"] - a["cpu_t"] >= sum(a["phase_cpu_s"].values()) - 1e-6
+    # the cumulative dicts hold wall seconds and entries, of every declared phase
+    assert set(ph.phase_s) == set(ph.phase_n) == {"sleeps", "spins", "unused"}
+    for phase in ph.phase_s:
+        assert ph.phase_s[phase] == pytest.approx(sum(r["phase_s"].get(phase, 0.0) for r in recs))
+
+
+def test_phase_spans_count_only_their_own_threads_cpu(cpu_tick):
+    """Another thread that burns CPU meanwhile adds nothing to a sleeping
+    phase: the clock is the driving thread's, not the process's (which would
+    read the sleeps' whole wall time)."""
+    stop = threading.Event()
+
+    def burn():
+        while not stop.is_set():
+            pass
+
+    other = threading.Thread(target=burn, daemon=True)
+    other.start()
+    try:
+        ph = tracing.PhaseSpans("loop", ("sleeps", "spins"), 2)
+        _sleep_then_spin(ph, max(0.05, 5 * cpu_tick), max(0.01, cpu_tick))
+    finally:
+        stop.set()
+        other.join(10)
+    rec = ph.ring.snapshot()[0]
+    assert rec["phase_s"]["sleeps"] >= 0.1
+    assert rec["phase_cpu_s"]["sleeps"] <= rec["phase_s"]["sleeps"] / 5 + 2 * cpu_tick
+
+
 def test_ring_is_read_without_a_lock_while_its_thread_writes():
     import threading
 
@@ -356,6 +436,10 @@ def test_traced_llm_request_lays_its_phases_on_its_trace(serve_cluster):
         assert replica["ts"] - 0.05 <= span["ts"] <= replica["ts"] + replica["dur"] + 0.05
     starts = [by_name[n]["ts"] for n in names]
     assert starts == sorted(starts)
+    # the host's share of the prefill, from the same start, inside it
+    enqueue = by_name["llm.prefill.enqueue"]
+    assert enqueue["parent_id"] == replica["span_id"] and enqueue["ts"] == pytest.approx(by_name["llm.prefill"]["ts"])
+    assert 0 <= enqueue["dur"] <= by_name["llm.prefill"]["dur"]
     # Only the traced request left spans: the untraced one paid a ContextVar.get.
     assert sum(e.get("name") == "llm.decode" for e in events) == 1
     stats = handle.stats.remote().result(timeout=30)
@@ -369,4 +453,5 @@ def test_traced_llm_request_lays_its_phases_on_its_trace(serve_cluster):
     assert list(parts) == ["queue", "prefill", "first_emit", "decode", "other"]
     assert sum(parts.values()) == pytest.approx(exec_hop["dur_s"], abs=1e-6)
     assert parts["decode"] > 0
+    assert exec_hop["parts"][1]["enqueue_s"] == pytest.approx(enqueue["dur"])
     serve.delete("llm_traced")
