@@ -13,12 +13,14 @@ llm/_internal/serve/engines/vllm/vllm_engine.py:174):
   whole through its loops, and the Pallas paged-attention kernel
   (ops/paged_attention.py — no materialized gather, no slice of the pool)
   writes one token's row in place at (layer, page[len // ps], len % ps) and
-  attends through the page table. Its grid is the batch's live pages, a
-  list the decode step builds once from the lengths and hands to every
-  layer (``live_pages``), so a call costs the tokens in the cache; before
-  PR 33 it was the whole table, slots × pages a slot, live or dead. Memory
-  scales with reserved pages, not slots × max_seq, and admission is
-  page-budgeted.
+  attends through the page table. A step of its grid is up to 8 consecutive
+  live pages of one sequence (a page group), a list the decode step builds
+  once from the lengths and hands to every layer (``page_groups``), so a
+  call costs the tokens in the cache and a slot without a request has no
+  step; from PR 33 to PR 43 a step was one page and an empty slot took one,
+  before PR 33 the grid was the whole table, slots × pages a slot, live or
+  dead. Memory scales with reserved pages, not slots × max_seq, and
+  admission is page-budgeted.
 - Continuous batching is the host loop: finished slots retire (their pages
   return to the free list) and queued requests prefill into free slots.
   Prefill groups are dispatched back-to-back asynchronously and fetched in
@@ -76,11 +78,12 @@ TTFT is measured from request arrival to its first sampled token (prefill
 completes inside that window), the standard serving definition.
 
 Page-0 convention: page 0 is never allocated; dead page-table entries point
-at it (the paged kernel masks them by length) and it absorbs writes from
-retired/overshooting slots (their lengths are zeroed, so nothing ever reads
-what they wrote): a row that has ended may be written up to two blocks past
-its budget, one more than ``_pages_needed`` reserves, and what passes its
-last page goes through the zero tail of its table row.
+at it (the paged kernel masks them by length) and it absorbs the writes of
+a row that overshoots: one that has ended may be written up to two blocks
+past its budget, one more than ``_pages_needed`` reserves, and what passes
+its last page goes through the zero tail of its table row (nobody reads
+what lands there). A retired or empty slot's rows of the mirrors are zero:
+the kernel walks no page and writes no row for it.
 """
 from __future__ import annotations
 
@@ -104,7 +107,8 @@ from ray_tpu.models.transformer import (
 )
 from ray_tpu.ops.latent_attention import latent_attention_reference, latent_paged_attention, latent_row_width
 from ray_tpu.ops.paged_attention import (
-    live_pages, paged_attention, paged_attention_reference, ring_pages, window_attention_reference,
+    group_pages, live_pages, page_groups, paged_attention, paged_attention_reference, ring_pages,
+    window_attention_reference,
 )
 from ray_tpu.util import tracing as _tracing
 
@@ -460,6 +464,12 @@ class LLMEngine:
         self.cache = tuple(_pool_zeros(shape, spec) for shape, spec in pools)
         # stats()["startup"]: what each kind's pools take
         self._ring_pages = ring_pages(self._window, ps) if self._window else 0
+        # The pages of a sequence the paged kernel takes a grid step, by the
+        # layer's window (0: none), from a page's K and V as a device holds
+        # them; the latent kernel takes one, and a step for an empty slot too.
+        self._group = {0: 1} if cfg.latent else {
+            w: group_pages(cfg.kv_heads // max(tp, 1), ps, cfg.head_dim, self.cache[0].dtype.itemsize, self.ppseq, w)
+            for w in {0, self._window}}
         self.pool_bytes = {name: sum(pool.nbytes for pool in self.cache[sl]) for name, sl in self._kind_pools.items()}
         self.free_pages: deque = deque(range(1, P_total))  # page 0 = dead sink
         self.page_tables = np.zeros((B, self.ppseq), np.int32)
@@ -511,7 +521,7 @@ class LLMEngine:
             )
         # Slots mid chunked-prefill: slot index -> full prompt tokens. Their
         # DEVICE length/page-table rows stay zeroed until the final chunk
-        # lands (the decode block's writes for them go to dead page 0), so
+        # lands (the decode block walks and writes nothing for them), so
         # decode interleaves with an in-progress prefill without scribbling
         # on the pages the chunks are filling.
         self._prefilling: dict[int, np.ndarray] = {}
@@ -564,8 +574,8 @@ class LLMEngine:
     # -- device-mirror masking (chunked prefill) ---------------------------
     def _masked(self, host: np.ndarray) -> np.ndarray:
         """A host mirror (lengths, page tables) with mid-prefill slots' rows
-        zeroed: the decode block must treat them as empty (writes land in
-        dead page 0) until their final chunk installs the real length."""
+        zeroed: the decode block must treat them as empty (no page walked,
+        no row written) until their final chunk installs the real length."""
         if not self._prefilling:
             return host
         m = host.copy()
@@ -790,9 +800,19 @@ class LLMEngine:
             # The step's walk of live pages, built here, once for all layers:
             # lengths change between steps and not between layers.
             seen = lens + 1  # the kernel's lengths count the step's own token
-            # two walks at most: the layers that keep every token (0) and the window layers
-            walks = ({w: live_pages(seen, page_tables, ps, w) for w in sorted({0, self._window})}
-                     if jax.default_backend() == "tpu" else None)
+            on_tpu = jax.default_backend() == "tpu"
+            if cfg.latent:
+                # the latent kernel's walk, a page a step: a slot with no pages
+                # is held at one step on dead page 0
+                walks = {0: live_pages(seen, page_tables, ps)} if on_tpu else None
+            else:
+                # 0 for a slot with no pages (empty, or masked while it
+                # prefills): no grid step, no row written, zeros attended.
+                # Two walks at most: the layers that keep every token (0) and
+                # the window layers.
+                seen = jnp.where(page_tables[:, 0] > 0, seen, 0)
+                walks = ({w: page_groups(seen, page_tables, ps, w, n) for w, n in sorted(self._group.items())}
+                         if on_tpu else None)
             with jax.named_scope("embed"):
                 x = params["embed"].astype(cfg.dtype)[last][:, None, :]  # [B,1,D]
 
@@ -814,10 +834,8 @@ class LLMEngine:
             with jax.named_scope("sample"):
                 toks = sample_batch(logits.astype(jnp.float32), temps, top_ps, top_ks,
                                     step_key, cap=self.ec.sample_topk_cap)
-            # A slot with no pages (empty, or masked while it prefills) stays
-            # at its length: the kernel's cost follows the lengths, and such a
-            # slot costs it one step on dead page 0 however long the mirrors
-            # go without a resync.
+            # A slot with no pages stays at its length (the kernel walks
+            # nothing for it, whatever the mirrors hold until a resync).
             return (pools, toks, jnp.where(page_tables[:, 0] > 0, lens + 1, lens)), (toks, counts)
 
         keys = jax.random.split(key, n_steps)
@@ -1310,7 +1328,7 @@ class LLMEngine:
         step's record goes to the ring that LLMServer.stats() returns."""
         ph = self._phases
         ph.begin("admit", waiting=len(self.waiting), n_admitted=0, n_prefill=0,
-                 pages_reserved=0, block=0, ahead=0, dropped_rows=0, active=0, sampled=0, live_pages=0,
+                 pages_reserved=0, block=0, ahead=0, dropped_rows=0, active=0, sampled=0, live_pages=0, grid_steps=0,
                  expert_pairs=0, expert_tiles=0,
                  **({"window_pages": 0, "window_tokens": 0} if self._window else {}))
         try:
@@ -1510,8 +1528,8 @@ class LLMEngine:
         # prompts stalls decode N chunks per step — still bounded and
         # spread, vs N whole prompts back to back). The final chunk samples
         # the request's first token and installs the slot's device mirrors
-        # (until then its device rows stay zeroed: decode writes for it hit
-        # dead page 0).
+        # (until then its device rows stay zeroed: decode writes nothing for
+        # it).
         chunk_dispatched = bool(self._prefilling)
         for i in sorted(self._prefilling):
             slot = self.slots[i]
@@ -1587,7 +1605,7 @@ class LLMEngine:
         run (0: none), and whether none runs only because the longest row's
         headroom is under the smallest compiled block. Queue pressure shrinks
         the block so the next admission wave starts sooner. Slots mid
-        chunked-prefill ride along masked (writes to dead page 0, tokens
+        chunked-prefill ride along masked (nothing written, tokens
         discarded) but do not drive the block's budget arithmetic."""
         active = [i for i, s in enumerate(self.slots)
                   if s is not None and i not in self._prefilling]
@@ -1641,7 +1659,7 @@ class LLMEngine:
             self.d_lengths, self.d_page_tables, sub, n,
             self.d_temps, self.d_top_ps, self.d_top_ks,
         )
-        rec["live_pages"] = self._live_pages(active, n)
+        rec["live_pages"], rec["grid_steps"] = self._live_pages(active, n)
         if self._window:
             rec["window_pages"], rec["window_tokens"] = self._window_walk(active, n)
         self.lengths[active] += n
@@ -1724,7 +1742,7 @@ class LLMEngine:
 
     def _sync_retired(self) -> None:
         """Retired slots stop advancing their (now meaningless) lengths
-        toward max_seq and write into the dead page: their rows of d_lengths
+        toward max_seq and are walked no more: their rows of d_lengths
         and d_page_tables go to zero, behind whatever block is in flight."""
         if not self._gone:
             return
@@ -1734,32 +1752,33 @@ class LLMEngine:
         self.d_lengths, self.d_page_tables = self._drop_rows_jit(
             self.d_lengths, self.d_page_tables, jnp.asarray(gone))
 
-    def _live_pages(self, active: list[int], n: int) -> int:
-        """The page steps the paged kernel walks in a decode block of ``n``
-        steps, a layer that keeps every token: every slot's ceil(length / page_size) at each step,
-        the step's own token counted; a slot that is not ``active`` (empty,
-        or masked while it prefills) costs the one step on dead page 0 it is
-        held at. Over n x max_slots x (max_seq / page_size) it is the share
-        of the page table the kernel walks. From the host's mirror of the
-        lengths as they stood when the block was dispatched."""
-        ps = self.ec.page_size
-        held = self.ec.max_slots - len(active)
+    def _live_pages(self, active: list[int], n: int) -> tuple:
+        """(pages, grid steps) the paged kernel walks in a decode block of
+        ``n`` steps, a layer that keeps every token: a slot's
+        ceil(length / page_size) pages at each step, the step's own token
+        counted, in ceil(pages / group) steps of up to ``group`` pages each
+        (``ops/paged_attention.group_pages``); a slot that is not ``active``
+        (empty, or masked while it prefills) is walked by nobody, but by the
+        latent kernel, which takes a page a step and holds such a slot at one
+        step on dead page 0. Pages over n x max_slots x (max_seq / page_size)
+        is the share of the page table the kernel walks, pages over steps how
+        full a grid step is. From the host's mirror of the lengths as they
+        stood when the block was dispatched."""
         seen = self.lengths[active][None, :] + np.arange(1, n + 1)[:, None]
-        return int(np.minimum(-(-seen // ps), self.ppseq).sum()) + held * n
+        pages = np.minimum(-(-seen // self.ec.page_size), self.ppseq)
+        held = (self.ec.max_slots - len(active)) * n if self.cfg.latent else 0
+        return int(pages.sum()) + held, int((-(-pages // self._group[0])).sum()) + held
 
     def _window_walk(self, active: list[int], n: int) -> tuple:
-        """(page steps, positions attended) of ONE window layer in a decode
-        block of ``n`` steps, as ``_live_pages`` counts a full layer's: a
-        slot walks the pages from the one that holds position seen - window
-        to the current one and attends min(seen, window) positions, seen its
-        length with the step's own token; a slot that is not ``active`` is
-        held at one step on its ring's first page and attends its one token
-        (not counted as a position: nothing reads it)."""
+        """(pages, positions attended) of ONE window layer in a decode block
+        of ``n`` steps, as ``_live_pages`` counts a full layer's: a slot
+        walks the pages from the one that holds position seen - window to
+        the current one and attends min(seen, window) positions, seen its
+        length with the step's own token."""
         ps, w = self.ec.page_size, self._window
         seen = self.lengths[active][None, :] + np.arange(1, n + 1)[:, None]
         pages = (seen - 1) // ps - np.maximum(seen - w, 0) // ps + 1
-        held = self.ec.max_slots - len(active)
-        return int(pages.sum()) + held * n, int(np.minimum(seen, w).sum())
+        return int(pages.sum()), int(np.minimum(seen, w).sum())
 
     def _maybe_finish(self, i: int, events: dict) -> bool:
         slot = self.slots[i]

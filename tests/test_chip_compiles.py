@@ -1,8 +1,10 @@
 """The paged decode call compiled by the TPU's own compiler, for a v5e that is
 described and not attached (no chip, no chip time): what interpret mode cannot
-show. Mosaic has to lower a grid whose length is a runtime value, index maps
-that read scalar-prefetched lists, and pools aliased to the outputs, at the
-shapes a chip holds in the serve cells and at a head size under a lane tile.
+show. Mosaic has to lower a grid whose length is a runtime value, copies of
+scattered pages out of pools that stay in HBM and are aliased to the outputs,
+a buffer of several pages a grid step and one of a single page (the same
+kernel), at the shapes a chip holds in the serve cells and at a head size
+under a lane tile.
 A compile that passes is not a chip run and says nothing about results or
 speed; tests/test_paged_attention.py holds the results, chip_smoke.py the chip.
 
@@ -50,8 +52,18 @@ def no_compile_cache():
     compilation_cache.reset_cache()
 
 
+def _call_with_group(group, window=0):
+    """The paged call on the walk it builds itself (group None: the page
+    group it reads off its shapes) or on a walk of `group` pages a step."""
+    def call(q, k_new, v_new, k_pages, v_pages, lengths, table, layer):
+        walk = None if group is None else pa.page_groups(lengths, table, k_pages.shape[3], window, group)
+        return pa.paged_attention(q, k_new, v_new, k_pages, v_pages, lengths, table, layer, walk=walk, window=window)
+    return call
+
+
+@pytest.mark.parametrize("group", [None, 1], ids=["its_own_group", "a_page_a_step"])
 @pytest.mark.parametrize("shape", sorted(SHAPES))
-def test_the_paged_call_lowers_for_a_v5e(shape, one_chip, no_compile_cache, monkeypatch):
+def test_the_paged_call_lowers_for_a_v5e(shape, group, one_chip, no_compile_cache, monkeypatch):
     B, H, KV, D, ps, n_pages, L, P_total = SHAPES[shape]
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the call asks before it lowers
 
@@ -61,17 +73,18 @@ def test_the_paged_call_lowers_for_a_v5e(shape, one_chip, no_compile_cache, monk
     pool = arr((L, KV, P_total, ps, D), jnp.bfloat16)
     args = (arr((B, H, D), jnp.bfloat16), arr((B, KV, D), jnp.bfloat16), arr((B, KV, D), jnp.bfloat16),
             pool, pool, arr((B,), jnp.int32), arr((B, n_pages), jnp.int32), arr((), jnp.int32))
-    compiled = jax.jit(pa.paged_attention, donate_argnums=(3, 4)).lower(*args).compile()
+    compiled = jax.jit(_call_with_group(group), donate_argnums=(3, 4)).lower(*args).compile()
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == 1  # one Mosaic call, the walk beside it plain XLA
     if D % 128 == 0:
         # nothing but the call's operands: no copy of a pool (a pool is
-        # 0.3-2.4 GB here). A head size under a lane tile is padded by the
-        # call's operand layout, before PR 33 as after it.
+        # 0.3-2.4 GB here). A head size under a lane tile is padded up to
+        # one by the call (by its operand layout before PR 42), pools and all.
         assert compiled.memory_analysis().temp_size_in_bytes < 4 << 20
 
 
-def test_the_window_call_lowers_for_a_v5e(one_chip, no_compile_cache, monkeypatch):
+@pytest.mark.parametrize("group", [None, 1], ids=["its_own_group", "a_page_a_step"])
+def test_the_window_call_lowers_for_a_v5e(group, one_chip, no_compile_cache, monkeypatch):
     """The paged call with a window at the serve cell's shapes (64 slots, 72
     query heads over 8 KV heads: a group of 9, two sublane tiles; a window of
     512 over pages of 128: rings of 5 pages, 6 layers): one Mosaic call named
@@ -82,13 +95,10 @@ def test_the_window_call_lowers_for_a_v5e(one_chip, no_compile_cache, monkeypatc
     def arr(dims, dtype):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
-    def call(q, k_new, v_new, k_pages, v_pages, lengths, table, layer):
-        return pa.paged_attention(q, k_new, v_new, k_pages, v_pages, lengths, table, layer, window=W)
-
     pool = arr((L, KV, B * pa.ring_pages(W, ps), ps, D), jnp.bfloat16)
     args = (arr((B, H, D), jnp.bfloat16), arr((B, KV, D), jnp.bfloat16), arr((B, KV, D), jnp.bfloat16),
             pool, pool, arr((B,), jnp.int32), arr((B, n_pages), jnp.int32), arr((), jnp.int32))
-    compiled = jax.jit(call, donate_argnums=(3, 4)).lower(*args).compile()
+    compiled = jax.jit(_call_with_group(group, W), donate_argnums=(3, 4)).lower(*args).compile()
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == 1 and "window_attn" in text and "paged_attn" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 4 << 20  # a pool is 0.5 GB

@@ -603,11 +603,12 @@ def test_prefill_enqueued_lies_between_admission_and_the_first_token(how):
         assert steps[0] <= life["admitted"] <= steps[1] <= steps[2] <= life["prefill_enqueued"]
 
 
-def test_step_record_counts_the_page_steps_the_decode_block_walks():
-    """``live_pages`` of a step's record against the lengths and tables the
-    decode program was handed: each of the block's steps, every slot's
-    ceil(length / page_size) with the step's own token counted, an empty slot
-    the one step it is held at; a step with no block records 0."""
+def test_step_record_counts_the_grid_steps_the_decode_block_walks():
+    """``live_pages`` and ``grid_steps`` of a step's record against the
+    lengths and tables the decode program was handed: each of the block's
+    steps, every slot's ceil(length / page_size) pages with the step's own
+    token counted, in groups of the kernel's page group, an empty slot
+    nothing; a step with no block records 0."""
     ec = EngineConfig(**ENGINE_KW)
     ps, table = ec.page_size, ec.max_seq // ec.page_size
     eng = LLMEngine(CFG, engine_config=ec)
@@ -633,16 +634,21 @@ def test_step_record_counts_the_page_steps_the_decode_block_walks():
     blocks = [s for s in steps if s["block"]]
     assert len(blocks) == len(handed) > 3
     assert sorted(handed[0][0]) == [0, 3, ps, 2 * ps - 1]  # the fourth slot is empty
+    group = eng._group[0]
+    assert group > 1  # so that a step holds more than a page
     for rec, (lens, tables, n) in zip(blocks, handed):
         live = tables[:, 0] > 0
-        seen = np.where(live, lens + np.arange(1, n + 1)[:, None], 1)
+        seen = np.where(live, lens + np.arange(1, n + 1)[:, None], 0)
+        pages = np.minimum(-(-seen // ps), table)
         assert rec["block"] == n and rec["active"] == live.sum()
-        assert rec["live_pages"] == np.minimum(-(-seen // ps), table).sum()
-        assert n * ec.max_slots <= rec["live_pages"] <= n * ec.max_slots * table
+        assert rec["live_pages"] == pages.sum() and rec["grid_steps"] == (-(-pages // group)).sum()
+        assert n * live.sum() <= rec["grid_steps"] <= rec["live_pages"] <= n * live.sum() * table
     n = handed[0][2]
     assert blocks[0]["live_pages"] == sum(
-        1 + -(-(3 + s) // ps) + -(-(ps + s) // ps) + -(-(2 * ps - 1 + s) // ps) for s in range(1, n + 1))
-    assert [s["live_pages"] for s in steps if not s["block"]] == [0] * (len(steps) - len(blocks))
+        -(-(3 + s) // ps) + -(-(ps + s) // ps) + -(-(2 * ps - 1 + s) // ps) for s in range(1, n + 1))
+    assert blocks[0]["grid_steps"] == 3 * n < blocks[0]["live_pages"]  # a sequence of two pages takes one step
+    for key in ("live_pages", "grid_steps"):
+        assert [s[key] for s in steps if not s["block"]] == [0] * (len(steps) - len(blocks))
     assert steps[-1]["block"] == 0 and steps[-1]["live_pages"] == 0
 
 
@@ -792,6 +798,40 @@ def test_a_block_ahead_staggered_requests_and_reused_slots_match_their_solo_runs
     assert not eng.has_work() and eng._inflight is None and len(eng.free_pages) == eng.ec.total_pages - 1
 
 
+def test_the_kernels_page_groups_with_empty_and_retiring_slots_emit_the_reference_paths_tokens(ahead_solos, monkeypatch):
+    """The decode program traced as on a TPU (the step's walks of page
+    groups, the Pallas kernel, here interpreted) under the same staggered
+    traffic: 7 requests through 3 slots, so blocks go out with slots empty
+    (lengths of 0: no grid step, no row written, zeros attended) and with
+    rows that an EOS retires inside a block. Request for request the tokens
+    of the reference path; a block's record holds no more grid steps than
+    pages, and pages of its active slots alone."""
+    import functools
+
+    from ray_tpu.llm import engine as engine_mod
+    from ray_tpu.ops.paged_attention import paged_attention
+
+    eos = _mid_block_eos(ahead_solos)
+    eng = LLMEngine(CFG, engine_config=EngineConfig(**AHEAD_KW, eos_id=eos))
+    assert eng._group[0] > 1
+
+    def as_on_a_tpu(*args):
+        with monkeypatch.context() as m:  # while the program is traced, and no longer
+            m.setattr(jax, "default_backend", lambda: "tpu")
+            m.setattr(engine_mod, "paged_attention", functools.partial(paged_attention, interpret=True))
+            return eng._decode_impl(*args)
+
+    eng._decode_jit = jax.jit(as_on_a_tpu, donate_argnums=(1,), static_argnums=(6,))
+    got = _staggered(eng, AHEAD_PROMPTS, 20)
+    assert [got[f"r{i}"][0] for i in range(7)] == [_until_eos(s, eos) for s in ahead_solos]
+    blocks = [s for s in eng.trace_snapshot()["steps"] if s["block"]]
+    assert any(s["active"] < eng.ec.max_slots for s in blocks) and sum(s["dropped_rows"] for s in blocks) >= 1
+    table = eng.ec.max_seq // eng.ec.page_size
+    for s in blocks:
+        assert s["block"] * s["active"] <= s["grid_steps"] <= s["live_pages"] <= s["block"] * s["active"] * table
+    assert any(s["grid_steps"] < s["live_pages"] for s in blocks)  # a step that held more than a page
+
+
 def test_a_block_ahead_a_finished_rows_tokens_never_reach_the_slots_next_request(ahead_solos):
     """One slot, two requests queued: the first ends by EOS in block s - 1, which
     the host finds out after block s went to the device with the row still
@@ -830,7 +870,7 @@ def test_a_block_ahead_the_hosts_lengths_are_the_ones_the_decode_program_is_hand
         np.testing.assert_array_equal(eng.lengths[active], d_lengths[active])
         np.testing.assert_array_equal(eng.page_tables, d_tables)
         assert sorted(active) == sorted(np.flatnonzero(d_tables[:, 0] > 0))
-        seen = np.where(d_tables[:, 0] > 0, d_lengths + np.arange(1, n + 1)[:, None], 1)
+        seen = np.where(d_tables[:, 0] > 0, d_lengths + np.arange(1, n + 1)[:, None], 0)
         calls.append((n, int(np.minimum(-(-seen // ps), table).sum())))
         return decode(*args)
 

@@ -200,15 +200,15 @@ def test_two_slots_of_unequal_length_in_one_batch_each_match_their_own_forward(l
 def test_the_step_record_counts_one_window_layers_walk_and_positions():
     """A prompt of 70 decoded in blocks of 4 steps: at lengths 71..74 (the step's
     own token counted) a window of 32 spans pages 2..4 (positions 39..73:
-    3 pages) and attends 32 positions; a full layer walks 5 pages. The idle
-    slot is held at one page step."""
+    3 pages) and attends 32 positions; a full layer walks 5 pages, in two
+    grid steps of up to 4. The idle slot is walked by nobody."""
     eng = LLMEngine(CFG, params=_params(), engine_config=EngineConfig(**ENGINE_KW))
     eng.generate(_tokens(70, seed=3), max_tokens=9)
     steps = [s for s in eng.trace_snapshot()["steps"] if s["block"]]
     first = steps[0]  # the first block of 4 steps, lengths 71..74, all inside page 4
     assert first["block"] == 4
-    assert first["live_pages"] == 4 * (5 + 1)
-    assert first["window_pages"] == 4 * (3 + 1) and first["window_tokens"] == 4 * 32
+    assert first["live_pages"] == 4 * 5 and first["grid_steps"] == 4 * -(-5 // eng._group[0])
+    assert first["window_pages"] == 4 * 3 and first["window_tokens"] == 4 * 32
     dense = LLMEngine(TransformerConfig(vocab_size=64, d_model=32, n_layers=1, n_heads=4, d_ff=32),
                       engine_config=EngineConfig(max_slots=2, max_seq=64, page_size=16, prefill_buckets=(32,)))
     dense.generate([1, 2, 3], max_tokens=2)
