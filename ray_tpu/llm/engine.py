@@ -66,6 +66,20 @@ llm/_internal/serve/engines/vllm/vllm_engine.py:174):
   pages. Admission budgets pages of the layers that keep every token. A
   prefix hit or a chunk of a prompt cannot restore a ring from pages, so both
   are refused for such a model.
+- The third rule, a state kept by slot (a kind with ``mixer="delta"``,
+  ops/linear_attention.py): such a layer keeps no rows of tokens. Its two
+  pools are addressed by the slot and do not grow with the context: a state
+  ``[the kind's layers, max_slots, heads, head_dim, head_dim]`` in float32
+  and the last conv_size - 1 inputs of its short convolutions. Prefill leaves
+  both as they stand at the prompt's own length, not at its bucket's end
+  (positions behind the length leave the state alone, and the tail is cut at
+  the length); decode carries both through its loops like the other pools,
+  the state updated in place by the ``kda_step`` call its output aliases, one
+  grid step a live slot and none for an empty one, whose state stays bit for
+  bit. Admission budgets pages for the layers that keep every token, as for
+  window layers. A page copy cannot restore a state and a chunk of a prompt
+  would have to start from one, so prefix hits, chunked prefill and a mesh are
+  refused for such a model (ROADMAP M4).
 - Tensor-parallel serving (EngineConfig.tensor_parallel > 1): params shard
   Megatron-style and the KV pools shard by kv_heads over a `tensor` mesh
   axis (parallel/), so a model bigger than one chip's HBM serves from a
@@ -106,6 +120,7 @@ from ray_tpu.models.transformer import (
     latent_scale, latent_values, pad_last, param_logical_axes, run_layers,
 )
 from ray_tpu.ops.latent_attention import latent_attention_reference, latent_paged_attention, latent_row_width
+from ray_tpu.ops.linear_attention import delta_rule
 from ray_tpu.ops.paged_attention import (
     group_pages, live_pages, page_groups, paged_attention, paged_attention_reference, ring_pages,
     window_attention_reference,
@@ -178,7 +193,10 @@ class EngineConfig:
     # step, interleaved with the decode blocks — a 512-token prefill can no
     # longer head-of-line-stall decoding slots for its whole length; decode
     # stall per step is bounded by one chunk's compute. Must be a multiple
-    # of page_size. 0 = off (whole-prompt prefill).
+    # of page_size. 0 = off (whole-prompt prefill). Refused for a model with
+    # window layers (a chunk attends pages a ring no longer holds) and for one
+    # with delta layers (a chunk would have to start from the state the one
+    # before left: ROADMAP M2, M4).
     chunked_prefill: int = 0
     # Prefix KV cache (reference: vLLM automatic prefix caching +
     # PrefixCacheAffinityRouter, prefix_aware_router.py:39). A retired
@@ -199,6 +217,8 @@ class EngineConfig:
     #   pages, then a chunked TAIL prefill embeds only the new tokens,
     #   attending to the cached pages gathered from the pool — prefill
     #   compute scales with the tail, not the prompt.
+    # Refused for a model with window layers or delta layers: a hit copies
+    # pages, which restore neither a ring nor a state (ROADMAP M2, M4).
     prefix_cache: bool = False
 
     def __post_init__(self):
@@ -233,7 +253,7 @@ class _Block:
     """A decode block the device has been handed and the host has not fetched:
     what ``LLMEngine._absorb`` needs to walk it a step later."""
     toks: Any  # [n, max_slots], on the device
-    counts: Any  # a model with held experts: int32 [2] (pairs, tiles) on the device; else None
+    counts: Any  # held experts' (pairs, tiles) and delta layers' rewritten states, int32 on the device; None without either
     n: int
     rec: dict  # the dispatching step's record (the ring holds this dict: counts land in it)
     rows: list  # (slot index, the _Slot that held it at dispatch) of every active row
@@ -357,6 +377,20 @@ class LLMEngine:
             raise ValueError(
                 "chunked_prefill is not written for window layers: a chunk attends the earlier chunks' "
                 "pages, and a window layer keeps no pages behind its ring (ROADMAP M2)")
+        self._recurrent = tuple(kind for kind in cfg.kinds if kind.recurrent)  # kinds that keep a state a slot
+        for option, on, why in (
+                ("prefix_cache", self.ec.prefix_cache,
+                 "a hit copies pages, and a page copy cannot restore the state a delta layer keeps of a prefix"),
+                ("chunked_prefill", self.ec.chunked_prefill,
+                 "a chunk would have to start from the state and the convolution tail the chunk before left, "
+                 "and the prefill programs start from an empty one"),
+                ("tensor_parallel > 1", self.ec.tensor_parallel > 1,
+                 "the state pool is addressed by the slot and its kernels run on one chip")):
+            if self._recurrent and on:
+                raise ValueError(f"{option} is not written for delta layers: {why} (ROADMAP M4)")
+        if self._recurrent and self._window:
+            raise ValueError("window layers beside delta layers are not written: a prefill is told its slot's "
+                             "ring or its slot (ROADMAP M4)")
         if self.ec.max_seq <= 0:
             self.ec = dataclasses.replace(self.ec, max_seq=cfg.max_seq_len)
         S = self.ec.max_seq
@@ -414,13 +448,13 @@ class LLMEngine:
         L = cfg.n_layers
         B = self.ec.max_slots
 
-        def _pool_zeros(shape, pool_spec):
+        def _pool_zeros(shape, pool_spec, dtype=cfg.dtype):
             if self.mesh is None:
-                return jnp.zeros(shape, cfg.dtype)
+                return jnp.zeros(shape, dtype)
             # Allocate directly sharded: a replicated-then-device_put pool
             # would materialize the full multi-GB buffer on one chip first.
             return jax.jit(
-                lambda: jnp.zeros(shape, cfg.dtype),
+                lambda: jnp.zeros(shape, dtype),
                 out_shardings=NamedSharding(self.mesh, pool_spec),
             )()
 
@@ -447,7 +481,9 @@ class LLMEngine:
         # By layer kind (``self._kind_pools``: a kind's pools in the tuple): a
         # kind without a window holds P_total pages for the page tables to
         # share out; one with a window holds a ring of pages a slot and
-        # nothing behind it, B x ring pages whatever the contexts.
+        # nothing behind it, B x ring pages whatever the contexts; a delta kind
+        # holds no tokens: a state a slot in float32 [its layers, B, H, Hd, Hd]
+        # and its convolutions' last inputs [its layers, B, T - 1, 3, H, Hd].
         if cfg.latent:
             self._row_width = latent_row_width(cfg.kv_lora_rank, cfg.qk_rope_head_dim)
             pools = [((L, P_total * ps, self._row_width), _P(None, None, None))]
@@ -456,12 +492,19 @@ class LLMEngine:
         else:
             pools, self._kind_pools = [], {}
             for kind in cfg.kinds:
-                tokens = (B * ring_pages(kind.window, ps) if kind.window else P_total) * ps
                 self._kind_pools[kind.name] = slice(len(pools), len(pools) + 2)
+                if kind.recurrent:
+                    per_slot = (cfg.layers_of(kind), B, kind.n_heads, cfg.head_dim)
+                    pools += [((*per_slot, cfg.head_dim), _P(), jnp.float32),
+                              ((*per_slot[:2], kind.conv_size - 1, 3, *per_slot[2:]), _P())]
+                    continue
+                tokens = (B * ring_pages(kind.window, ps) if kind.window else P_total) * ps
                 pools += [((cfg.layers_of(kind), cfg.kv_heads, tokens, cfg.head_dim),
                            _P(None, "tensor", None, None))] * 2
             self._tok_axis = 2
-        self.cache = tuple(_pool_zeros(shape, spec) for shape, spec in pools)
+        self.cache = tuple(_pool_zeros(*pool) for pool in pools)
+        # the pools addressed by the slot, which hold no pages of tokens
+        self._slot_pools = {i for kind in self._recurrent for i in range(len(pools))[self._kind_pools[kind.name]]}
         # stats()["startup"]: what each kind's pools take
         self._ring_pages = ring_pages(self._window, ps) if self._window else 0
         # The pages of a sequence the paged kernel takes a grid step, by the
@@ -645,18 +688,28 @@ class LLMEngine:
                         (0, 0, (ring + j % n_ring) * ps, 0))
         return pools
 
-    def _write_prompt(self, cache, rows, page_idxs, ring, length):
+    def _write_prompt(self, cache, rows, page_idxs, place, length):
         """A prompt's fresh rows (one array a pool) into the carried pools, by
         layer kind: pages of its page table for a kind that keeps every token
         (``_write_pages``), its last window into the slot's ring for a kind
-        with a window (``_write_ring``)."""
-        if not self._window:
+        with a window (``_write_ring``; ``place`` the slot's first ring
+        page), and for a delta kind the state and the convolution tail the
+        prompt left, every layer's, into its slot (``place``) of the two
+        pools: one in-place ``dynamic_update_slice`` a pool."""
+        if not self._window and not self._recurrent:
             return self._write_pages(cache, rows, page_idxs)
         cache = list(cache)
         for kind in self.cfg.kinds:
             sl = self._kind_pools[kind.name]
-            cache[sl] = (self._write_ring(cache[sl], rows[sl], ring, length) if kind.window
-                         else self._write_pages(cache[sl], rows[sl], page_idxs))
+            if kind.recurrent:
+                with jax.named_scope("state_write"):
+                    cache[sl] = [jax.lax.dynamic_update_slice(pool, new[:, None].astype(pool.dtype),
+                                                              (0, place) + (0,) * (pool.ndim - 2))
+                                 for pool, new in zip(cache[sl], rows[sl])]
+            elif kind.window:
+                cache[sl] = self._write_ring(cache[sl], rows[sl], place, length)
+            else:
+                cache[sl] = self._write_pages(cache[sl], rows[sl], page_idxs)
         return tuple(cache)
 
     def _copy_pages_impl(self, cache, src, dst):
@@ -671,17 +724,31 @@ class LLMEngine:
         kv_heads-sharded or on one chip (PERF.md section 6, PR 29)."""
         return self._write_pages(cache, [self._read_pages(pool, src) for pool in cache], dst)
 
-    def _prompt_attend(self, lp, seg, dtypes, kind):
+    def _prompt_attend(self, lp, seg, dtypes, kind, length):
         """The ``attend`` of a prompt over its own fresh rows, and what it
         keeps of them for the pools: the K and V rows of a head (attended
         inside the kind's window where it has one), or a latent layer's
         [c | k_rope] rows (expanded to keys and values here, for the prompt
-        alone)."""
+        alone), or for a delta layer the state and the convolution's last
+        inputs as they stand at the prompt's ``length``: the bucket's padding
+        behind it leaves the state alone (beta 0, no decay)."""
         cfg = self.cfg
+        if kind.recurrent:
+            def rule(q, k, v, g, beta, window):
+                real = (seg == 0)[..., None]  # [1, P, 1]
+                with jax.named_scope("kda_chunk"):
+                    o, state = delta_rule()[0](q, k, v, jnp.where(real[..., None], g, 0.0),
+                                               jnp.where(real, beta, 0.0), out_dtype=v.dtype)
+                # positions length - (T - 1) .. length - 1: the window leads with the T - 1 before position 0
+                tail = jax.lax.dynamic_slice_in_dim(window[0], length, kind.conv_size - 1, axis=0)
+                return o, (state[0], tail)
+            return None, rule
         if not cfg.latent:
+            k_dtype, v_dtype = dtypes[self._kind_pools[kind.name]]
+
             def attend(q, k, v):
                 o = _prompt_attention(q, k, v, seg, self.mesh, window=kind.window)
-                return o, (_kv_rows(k, dtypes[0]), _kv_rows(v, dtypes[1]))
+                return o, (_kv_rows(k, k_dtype), _kv_rows(v, v_dtype))
             return attend
 
         def attend(q, c, k_rope):
@@ -690,10 +757,11 @@ class LLMEngine:
             return o, (_row_major(_latent_rows(c[0], k_rope[0], self._row_width, dtypes[0])),)
         return attend
 
-    def _prefill_impl(self, params, cache, tokens, length, page_idxs, key, temp, top_p, top_k, ring=None):
+    def _prefill_impl(self, params, cache, tokens, length, page_idxs, key, temp, top_p, top_k, place=None):
         """tokens: [P] (padded to the bucket); page_idxs: [P // ps] page ids
-        (trailing entries may be 0 = dead sink); ring: the slot's first ring
-        page, for a model with window layers. Returns the pools with the
+        (trailing entries may be 0 = dead sink); place: the slot's first ring
+        page, for a model with window layers, or the slot, for one with delta
+        layers. Returns the pools with the
         prompt's pages written and the first generated token. Attention
         runs on the layer's fresh K/V, so the layer scan never sees a pool:
         it hands out every layer's rows as ``ys`` and the pages are written
@@ -707,11 +775,11 @@ class LLMEngine:
         dtypes = [pool.dtype for pool in cache]
 
         def scan_fn(h, lp, kind):
-            h, _aux, rows = decoder_block(h, lp, cfg, pos, self._prompt_attend(lp, seg, dtypes, kind), kind)
+            h, _aux, rows = decoder_block(h, lp, cfg, pos, self._prompt_attend(lp, seg, dtypes, kind, length), kind)
             return h, rows
 
         x, rows = self._prompt_layers(params, x, scan_fn)  # rows[i]: [L,KV,P,Hd] or [L,P,W]
-        cache = self._write_prompt(cache, rows, page_idxs, ring, length)
+        cache = self._write_prompt(cache, rows, page_idxs, place, length)
         with jax.named_scope("lm_head"):
             x = _rms_norm(x, params["final_norm"], cfg.norm_eps)
             last = jax.lax.dynamic_index_in_dim(x[0], length - 1, axis=0, keepdims=False)
@@ -733,6 +801,19 @@ class LLMEngine:
         cfg = self.cfg
         on_tpu = walks is not None  # decided once a program, where the walks are built
         sl = self._kind_pools[kind.name]
+        if kind.recurrent:
+            state, tails = pools[sl]
+            live = seen > 0
+            tail = jax.lax.dynamic_index_in_dim(tails, layer, 0, keepdims=False)  # [B, T - 1, 3, H, Hd]
+
+            def rule(q, k, v, g, beta, window):
+                # a slot without a request keeps its tail and its state as they were
+                kept = jnp.where(live[:, None, None, None, None], window[:, 1:].astype(tails.dtype), tail)
+                with jax.named_scope("kda_step"):
+                    o, new_state = delta_rule()[1](q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], state, layer, live)
+                new_tails = jax.lax.dynamic_update_slice(tails, kept[None], (layer,) + (0,) * (tails.ndim - 1))
+                return o[:, None], pools[:sl.start] + (new_state, new_tails) + pools[sl.stop:]
+            return tail, rule
         if not cfg.latent:
             if not on_tpu:
                 paged_attend = paged_attention_reference if not kind.window else (
@@ -776,10 +857,12 @@ class LLMEngine:
         """n_steps tokens for every slot in ONE device program (outer scan
         over steps, inner scan over layers): one host round trip per block.
         Returns (cache, toks [n_steps, B], last', lengths', counts): counts
-        is None for a model without held experts, else int32 [2], the routed
-        (token, expert) pairs that landed on held experts and the live tiles
-        of the grouped matmul (a tile reads its expert's matrices), both
-        summed over the block's steps and the routed layers.
+        is None for a model without held experts or delta layers. Held
+        experts give two int32, the routed (token, expert) pairs that landed
+        on held experts and the live tiles of the grouped matmul (a tile
+        reads its expert's matrices), both summed over the block's steps and
+        the routed layers; delta layers one more behind them, the slots whose
+        state a step rewrote (in each such layer), summed over the steps.
 
         How the pools are threaded (the rule: where the pools are made):
         both scans CARRY the pools whole (the token axis split into pages and
@@ -793,7 +876,8 @@ class LLMEngine:
         cfg = self.cfg
         ps, ax = self.ec.page_size, self._tok_axis
         flat = [pool.shape for pool in cache]  # as every other program has them
-        paged = [shape[:ax] + (-1, ps) + shape[ax + 1:] for shape in flat]
+        paged = [shape if i in self._slot_pools else shape[:ax] + (-1, ps) + shape[ax + 1:]
+                 for i, shape in enumerate(flat)]
 
         def one_step(carry, step_key):
             pools, last, lens = carry
@@ -828,6 +912,10 @@ class LLMEngine:
             for aux in auxes.values():  # of a kind's routed layers; None where it has none
                 if aux is not None:
                     counts = jnp.sum(aux, axis=0) if counts is None else counts + jnp.sum(aux, axis=0)
+            if self._recurrent:
+                # the slots whose state this step rewrote in every delta layer: the kda_step calls' grid
+                rows = jnp.sum(seen > 0, dtype=jnp.int32).reshape(1)
+                counts = rows if counts is None else jnp.concatenate([counts, rows])
             with jax.named_scope("lm_head"):
                 x = _rms_norm(x, params["final_norm"], cfg.norm_eps)
                 logits = jnp.einsum("bsd,dv->bv", x, params["lm_head"].astype(cfg.dtype))
@@ -850,7 +938,7 @@ class LLMEngine:
         return tuple(pool.reshape(shape) for pool, shape in zip(pools, flat)), toks, last, lengths, counts
 
     def _prefill_batch_impl(self, params, cache, tokens, lengths, page_rows, key, temps, top_ps, top_ks,
-                            rings=None):
+                            places=None):
         """Prefill k requests of one length bucket in ONE device program
         (scan over requests around the single-request body): one dispatch
         and one set of host-built arrays per admitted group instead of one
@@ -860,9 +948,9 @@ class LLMEngine:
         device time: the k requests run one after another and each reads
         the weights. The request scan carries the donated pools; each
         request writes its pages into them in place (_write_pages).
-        tokens: [k, P]; page_rows: [k, P // ps], each request's pages; rings:
+        tokens: [k, P]; page_rows: [k, P // ps], each request's pages; places:
         [k], each request's slot's first ring page (a model with window
-        layers; None without)."""
+        layers) or its slot (one with delta layers); None without either."""
         keys = jax.random.split(key, tokens.shape[0])
 
         def scan_req(cache, xs):
@@ -870,7 +958,7 @@ class LLMEngine:
 
         xs = (tokens, lengths, page_rows, keys, temps, top_ps, top_ks)
         return jax.lax.scan(  # (cache, toks [k])
-            scan_req, cache, xs if rings is None else (*xs, rings))
+            scan_req, cache, xs if places is None else (*xs, places))
 
     def _tail_prefill_impl(self, params, cache, tokens, start, length,
                            ctx_pages, tail_pages, key, temp, top_p, top_k):
@@ -1052,8 +1140,9 @@ class LLMEngine:
                     jnp.zeros(k, jnp.float32), jnp.ones(k, jnp.float32),
                     jnp.zeros(k, jnp.int32),
                 )
-                if self._window:
-                    # slot 0's ring takes the dummy rows: a length masks whatever a ring held before
+                if self._window or self._recurrent:
+                    # slot 0's ring takes the dummy rows: a length masks whatever a ring held before;
+                    # slot 0's state, which the prefill of the slot's next request replaces
                     args += (jnp.zeros(k, jnp.int32),)
                 entry = {"program": "prefill", "bucket": b, "k": k}
                 if "prefill" not in self.mosaic:
@@ -1330,7 +1419,8 @@ class LLMEngine:
         ph.begin("admit", waiting=len(self.waiting), n_admitted=0, n_prefill=0,
                  pages_reserved=0, block=0, ahead=0, dropped_rows=0, active=0, sampled=0, live_pages=0, grid_steps=0,
                  expert_pairs=0, expert_tiles=0,
-                 **({"window_pages": 0, "window_tokens": 0} if self._window else {}))
+                 **({"window_pages": 0, "window_tokens": 0} if self._window else {}),
+                 **({"state_rows": 0, "states_written": 0} if self._recurrent else {}))
         try:
             return self._step(ph)
         finally:
@@ -1487,16 +1577,19 @@ class LLMEngine:
                     pgs[j] = self.page_tables[i, :n_pg]  # trailing zeros -> dead sink
                 idx_arr = jnp.asarray(np.asarray(idxs, np.int32))
                 self._key, sub = jax.random.split(self._key)
-                rings = ()
+                places = ()
                 if self._window:  # each slot's first ring page, the same in every window kind's pools
-                    rings = (jnp.asarray(np.asarray(idxs, np.int32) * self._ring_pages),)
+                    places = (jnp.asarray(np.asarray(idxs, np.int32) * self._ring_pages),)
+                elif self._recurrent:  # each request's slot, where a delta kind's pools keep its state
+                    places = (idx_arr,)
+                    ph.rec["states_written"] += k
                 self.cache, toks_dev = self._prefill(bucket, k)(
                     self.params, self.cache,
                     jnp.asarray(padded), jnp.asarray(lens), jnp.asarray(pgs), sub,
                     jnp.asarray(self.samp_temps[idxs]),
                     jnp.asarray(self.samp_top_ps[idxs]),
                     jnp.asarray(self.samp_top_ks[idxs]),
-                    *rings,
+                    *places,
                 )
                 enqueued = time.monotonic()
                 for i in idxs:
@@ -1701,9 +1794,12 @@ class LLMEngine:
         to("decode_fetch")
         if blk.counts is None:
             block_toks = np.asarray(jax.device_get(blk.toks))  # [n, B]
-        else:  # a model with held experts: its counts ride the same fetch
-            block_toks, (pairs, tiles) = jax.device_get((blk.toks, blk.counts))
-            blk.rec["expert_pairs"], blk.rec["expert_tiles"] = int(pairs), int(tiles)
+        else:  # held experts' counts, then delta layers': they ride the same fetch
+            block_toks, counts = jax.device_get((blk.toks, blk.counts))
+            if self.cfg.experts_held:
+                blk.rec["expert_pairs"], blk.rec["expert_tiles"] = int(counts[0]), int(counts[1])
+            if self._recurrent:
+                blk.rec["state_rows"] = int(counts[-1])
         to("emit")
         rows = [(i, slot) for i, slot in blk.rows if self.slots[i] is slot]
         for step_i in range(blk.n):

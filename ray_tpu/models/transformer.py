@@ -33,15 +33,28 @@ from ray_tpu.parallel.sharding import with_logical_constraint as wlc
 
 @dataclasses.dataclass(frozen=True)
 class LayerKind:
-    """One kind of attention layer in a model whose layers are not all alike:
-    its query heads, its window and its rope. Two layers of one kind have
-    weights of one shape and cache what the same rule keeps."""
+    """One kind of layer in a model whose layers are not all alike: what mixes
+    a layer's tokens (softmax attention over cached rows, or a delta rule over
+    a state a head), its heads, its window and its rope. Two layers of one
+    kind have weights of one shape and cache what the same rule keeps."""
     name: str  # the key of this kind's stack in params["kind_layers"], of its pools in the engine
     n_heads: int
+    # "attention": softmax attention, query heads grouped over the model's KV
+    # heads. "delta": the gated delta rule with a decay a channel
+    # (ops/linear_attention.py): n_heads heads of the model's head_dim for
+    # queries, keys and values alike, each behind a causal depthwise
+    # convolution of conv_size taps and SiLU; a state [head_dim, head_dim] a
+    # head in float32 and no token rows; the decay and the output gate
+    # projected through low_rank columns; beta_scale 2 lets a step's
+    # eigenvalue reach -1. No window, no rope, none of the model's attn_gate.
+    mixer: str = "attention"
+    conv_size: int = 0
+    low_rank: int = 0
+    beta_scale: float = 1.0
     # Position i sees j with i - window < j <= i (its own among them); 0: every j <= i.
     window: int = 0
     rope_theta: float = 10_000.0
-    rope_share: float = 1.0  # the leading share of a head's columns that is roped; the rest pass
+    rope_share: float = 1.0  # the leading share of a head's columns that is roped; the rest pass (0: no rope)
     # YaRN (factor 0: plain rope): frequencies below the original length's
     # reach divided by `yarn_factor`, blended between the two betas' columns,
     # and cos / sin multiplied by `attention_factor`.
@@ -54,6 +67,11 @@ class LayerKind:
     @property
     def plain_rope(self) -> bool:
         return self.rope_share == 1.0 and not self.yarn_factor and self.attention_factor == 1.0
+
+    @property
+    def recurrent(self) -> bool:
+        """Keeps a state a slot that does not grow with the context, and no rows of tokens."""
+        return self.mixer == "delta"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -126,8 +144,9 @@ class TransformerConfig:
     # and run as one scan over periods (run_layers). Empty: every layer is of
     # the one kind n_heads and rope_theta describe, params["layers"].
     layer_pattern: tuple = ()
-    # "per_head": each head's attention output times sigmoid(h Wg_h), h the
-    # layer's normed input, before the output projection.
+    # An attention layer's output times sigmoid(h Wg), h the layer's normed
+    # input, before the output projection: "per_head" one scalar a head (Wg
+    # [D, H]), "elementwise" one a column (Wg [D, H, head_dim]).
     attn_gate: str = ""
 
     @property
@@ -167,13 +186,18 @@ class TransformerConfig:
             assert self.d_model % self.n_heads == 0
             object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
         assert self.n_heads % self.kv_heads == 0
-        assert self.attn_gate in ("", "per_head") and not (self.attn_gate and self.latent), "a per-head gate, on gqa layers"
+        assert self.attn_gate in ("", "per_head", "elementwise") and not (self.attn_gate and self.latent), (
+            "an output gate, on gqa layers")
         if self.layer_pattern:
             object.__setattr__(self, "layer_pattern", tuple(self.layer_pattern))
             p = len(self.layer_pattern)
             assert not self.latent, "a layer pattern is written for gqa layers"
             assert len({k.name for k in self.kinds}) == len(self.kinds), "two kinds under one name"
-            assert all(k.n_heads % self.kv_heads == 0 for k in self.kinds)
+            assert all(k.n_heads % self.kv_heads == 0 for k in self.kinds if not k.recurrent)
+            for k in self.kinds:
+                assert k.mixer in ("attention", "delta"), k.mixer
+                assert not k.recurrent or (k.conv_size > 1 and k.low_rank > 0 and not k.window), (
+                    f"a delta layer has a short convolution, low-rank sizes and no window: {k}")
             assert len({self.kind_of(l) for l in range(self.n_dense_layers)}) <= 1, (
                 "the leading dense layers are one stack: of one kind")
             if (self.n_layers - self.n_dense_layers) % p:
@@ -203,15 +227,41 @@ def _dense_init(key, shape, dtype, in_axis=0):
     return jax.random.normal(key, shape, dtype) * scale
 
 
+def _inverse_softplus(y):
+    return y + jnp.log(-jnp.expm1(-y))
+
+
 def _init_stack(key: jax.Array, cfg: TransformerConfig, L: int, routed: bool, kind: LayerKind | None = None) -> tuple:
     """One stack of L identical layers of `kind` (None: the model's one kind;
     leading 'layers' dim on every leaf); `routed`: the FFN is experts behind
     a router, else dense of width d_ff.
     Returns (the stack, the iterator over the keys it left)."""
     pd = cfg.param_dtype
-    k = iter(jax.random.split(key, 16))
-    D, F, H = cfg.d_model, cfg.d_ff, (kind or cfg.kinds[0]).n_heads
-    if cfg.latent:
+    kind = kind or cfg.kinds[0]
+    k = iter(jax.random.split(key, 24 if kind.recurrent else 16))
+    D, F, H = cfg.d_model, cfg.d_ff, kind.n_heads
+    if kind.recurrent:
+        Hd, R, T = cfg.head_dim, kind.low_rank, kind.conv_size
+        layer = {
+            "attn_norm": jnp.ones((L, D), pd),
+            "wq": _dense_init(next(k), (L, D, H, Hd), pd, in_axis=1),
+            "wk": _dense_init(next(k), (L, D, H, Hd), pd, in_axis=1),
+            "wv": _dense_init(next(k), (L, D, H, Hd), pd, in_axis=1),
+            "conv": _dense_init(next(k), (L, T, 3, H, Hd), pd, in_axis=1),  # q's, k's and v's taps, the oldest first
+            "wf_a": _dense_init(next(k), (L, D, R), pd, in_axis=1),
+            "wf_b": _dense_init(next(k), (L, R, H, Hd), pd, in_axis=1),
+            # a step's size before the projection moves it: softplus^-1 of 0.001 .. 0.1, log-uniform
+            "dt_bias": _inverse_softplus(jnp.exp(jax.random.uniform(
+                next(k), (L, H, Hd), jnp.float32, math.log(0.001), math.log(0.1)))).astype(pd),
+            "a_log": jnp.log(jax.random.uniform(next(k), (L, H), jnp.float32, 1.0, 16.0)).astype(pd),
+            "wb": _dense_init(next(k), (L, D, H), pd, in_axis=1),
+            "wg_a": _dense_init(next(k), (L, D, R), pd, in_axis=1),
+            "wg_b": _dense_init(next(k), (L, R, H, Hd), pd, in_axis=1),
+            "o_norm": jnp.ones((L, Hd), pd),
+            "wo": _dense_init(next(k), (L, H, Hd, D), pd, in_axis=(1, 2)),
+            "ffn_norm": jnp.ones((L, D), pd),
+        }
+    elif cfg.latent:
         R, Rq = cfg.kv_lora_rank, cfg.q_lora_rank
         nope, rope, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
         layer = {
@@ -237,7 +287,7 @@ def _init_stack(key: jax.Array, cfg: TransformerConfig, L: int, routed: bool, ki
             "ffn_norm": jnp.ones((L, D), pd),
         }
         if cfg.attn_gate:
-            layer["wg"] = _dense_init(next(k), (L, D, H), pd, in_axis=1)
+            layer["wg"] = _dense_init(next(k), (L, D, H) + ((Hd,) if cfg.attn_gate == "elementwise" else ()), pd, in_axis=1)
     if cfg.sandwich_norm:
         layer.update({"post_attn_norm": jnp.ones((L, D), pd), "post_ffn_norm": jnp.ones((L, D), pd)})
     if routed:
@@ -303,6 +353,7 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> dict:
 
 
 HELD_EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
+L2_EPS = 1e-6  # under the root of a delta layer's query and key norms
 
 
 def scan_stack(body, carry, stack: dict, cfg: TransformerConfig, *xs):
@@ -409,9 +460,20 @@ def run_layers(body, carry, params: dict, cfg: TransformerConfig, xs: dict | Non
     return carry, {name: joined(v) for name, v in ys.items()}
 
 
-def _stack_logical_axes(cfg: TransformerConfig, routed: bool) -> dict:
-    """A stack's logical axes (the same for every kind: kinds differ in sizes)."""
-    if cfg.latent:
+def _stack_logical_axes(cfg: TransformerConfig, routed: bool, kind: LayerKind | None = None) -> dict:
+    """A stack's logical axes (attention kinds differ in sizes only)."""
+    if kind is not None and kind.recurrent:
+        heads = ("layers", "embed", "heads", "head_dim")
+        low = ("layers", None, "heads", "head_dim")
+        layer = {
+            "attn_norm": ("layers", "embed"), "wq": heads, "wk": heads, "wv": heads,
+            "conv": ("layers", None, None, "heads", "head_dim"),
+            "wf_a": ("layers", "embed", None), "wf_b": low, "dt_bias": ("layers", "heads", "head_dim"),
+            "a_log": ("layers", "heads"), "wb": ("layers", "embed", "heads"),
+            "wg_a": ("layers", "embed", None), "wg_b": low, "o_norm": ("layers", "head_dim"),
+            "wo": ("layers", "heads", "head_dim", "embed"), "ffn_norm": ("layers", "embed"),
+        }
+    elif cfg.latent:
         layer = {
             "attn_norm": ("layers", "embed"),
             "wq_a": ("layers", "embed", None),
@@ -434,7 +496,7 @@ def _stack_logical_axes(cfg: TransformerConfig, routed: bool) -> dict:
             "ffn_norm": ("layers", "embed"),
         }
         if cfg.attn_gate:
-            layer["wg"] = ("layers", "embed", "heads")
+            layer["wg"] = ("layers", "embed", "heads") + (("head_dim",) if cfg.attn_gate == "elementwise" else ())
     if cfg.sandwich_norm:
         layer.update({"post_attn_norm": ("layers", "embed"), "post_ffn_norm": ("layers", "embed")})
     if routed:
@@ -467,16 +529,16 @@ def _stack_logical_axes(cfg: TransformerConfig, routed: bool) -> dict:
 
 def param_logical_axes(cfg: TransformerConfig) -> dict:
     """Same-structure pytree of logical-axis tuples (see LOGICAL_AXES)."""
-    layers = _stack_logical_axes(cfg, routed=bool(cfg.n_experts))
+    routed = bool(cfg.n_experts)
     axes = {
         "embed": ("vocab", "embed"),
-        **({"kind_layers": {kind.name: layers for kind in dict.fromkeys(cfg.period)}}
-           if cfg.layer_pattern else {"layers": layers}),
+        **({"kind_layers": {kind.name: _stack_logical_axes(cfg, routed, kind) for kind in dict.fromkeys(cfg.period)}}
+           if cfg.layer_pattern else {"layers": _stack_logical_axes(cfg, routed)}),
         "final_norm": ("embed",),
         "lm_head": ("embed", "vocab"),
     }
     if cfg.n_dense_layers:
-        axes["dense_layers"] = _stack_logical_axes(cfg, routed=False)
+        axes["dense_layers"] = _stack_logical_axes(cfg, routed=False, kind=cfg.kind_of(0))
     return axes
 
 
@@ -531,6 +593,8 @@ def _rope_kind(x, positions, kind: LayerKind):
     kind's frequencies, cos and sin times its attention_factor; the rest pass."""
     if kind.plain_rope:
         return _rope(x, positions, kind.rope_theta)
+    if not kind.rope_share:  # a kind without positions
+        return x
     width = int(x.shape[-1] * kind.rope_share)
     half = width // 2
     freqs = jnp.asarray(rope_inv_freq(kind, width), jnp.float32)
@@ -806,6 +870,50 @@ def _held_experts_pass(x, p, cfg: TransformerConfig):
     return routed, jnp.stack([jnp.sum(plan.sizes), plan.n_tiles[0]]).astype(jnp.int32)
 
 
+def _delta_mixer(h, lp, cfg: TransformerConfig, kind: LayerKind, attend):
+    """A delta layer's mixer on its normed input h [B, S, D]: projections to
+    q~, k~, v~ [B, S, H, Hd], the short convolution and SiLU on each, q and k
+    normed to length 1 a head (q times Hd^-1/2 besides), the decay a channel
+    g = -exp(a_log) softplus(low-rank f(h) + dt_bias) and the step size beta =
+    beta_scale sigmoid(h wb) in float32, the rule, then a head's output
+    RMS-normed and times sigmoid(low-rank g(h)). Returns (o [B, S, H, Hd]
+    before the output projection, kept).
+
+    ``attend`` is the program's side, a pair (tail, rule). tail: the T - 1
+    inputs of the convolution before position 0, [B, T - 1, 3, H, Hd] (None:
+    zeros, a sequence's start). rule(q, k, v, g, beta, window) -> (o
+    [B, S, H, Hd], kept): the delta rule over these positions on whatever
+    state the program keeps (ops/linear_attention.py); window
+    [B, T - 1 + S, 3, H, Hd] is the tail and the convolution's inputs behind
+    it, of which a program keeps its next tail."""
+    dt, T = h.dtype, kind.conv_size
+    S, Hd = h.shape[1], cfg.head_dim
+    tail, rule = attend
+    with jax.named_scope("qkv"):
+        u = jnp.stack([jnp.einsum("bsd,dhk->bshk", h, lp[w].astype(dt)) for w in ("wq", "wk", "wv")], axis=2)
+        u = wlc(u, ("batch", "seq", None, "heads", "head_dim"))
+    with jax.named_scope("short_conv"):
+        if tail is None:
+            tail = jnp.zeros((u.shape[0], T - 1, *u.shape[2:]), dt)
+        window = jnp.concatenate([tail.astype(dt), u], axis=1)
+        taps = lp["conv"].astype(jnp.float32)
+        y = sum(window[:, j:j + S].astype(jnp.float32) * taps[j] for j in range(T))
+        q, k, v = (y[:, :, i] for i in range(3))
+        q, k, v = jax.nn.silu(q), jax.nn.silu(k), jax.nn.silu(v).astype(dt)
+        q = q * lax.rsqrt(jnp.sum(q * q, axis=-1, keepdims=True) + L2_EPS) * Hd ** -0.5
+        k = k * lax.rsqrt(jnp.sum(k * k, axis=-1, keepdims=True) + L2_EPS)
+    with jax.named_scope("decay"):
+        f = jnp.einsum("bsr,rhk->bshk", jnp.einsum("bsd,dr->bsr", h, lp["wf_a"].astype(dt)), lp["wf_b"].astype(dt))
+        g = -jnp.exp(lp["a_log"].astype(jnp.float32))[:, None] * jax.nn.softplus(
+            f.astype(jnp.float32) + lp["dt_bias"].astype(jnp.float32))
+        beta = kind.beta_scale * jax.nn.sigmoid(jnp.einsum("bsd,dh->bsh", h, lp["wb"].astype(dt)).astype(jnp.float32))
+    o, kept = rule(q, k, v, g, beta, window)
+    with jax.named_scope("out_gate"):
+        gate = jnp.einsum("bsr,rhk->bshk", jnp.einsum("bsd,dr->bsr", h, lp["wg_a"].astype(dt)), lp["wg_b"].astype(dt))
+        o = _rms_norm(o.astype(dt), lp["o_norm"], cfg.norm_eps) * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(dt)
+    return o, kept
+
+
 def decoder_block(x, lp, cfg: TransformerConfig, positions, attend, kind: LayerKind | None = None):
     """The one decoder block that training, prefill and decode all run: what
     the model is (norms, projections, rope, the attention's and the FFN's
@@ -823,13 +931,16 @@ def decoder_block(x, lp, cfg: TransformerConfig, positions, attend, kind: LayerK
     what such a layer caches; the attention side expands them over a prompt
     (``latent_expand``) or absorbs the projections in decode. ``kept`` is
     whatever the attention side wants handed out of the layer (a prompt's
-    rows, the carried pools, None). Returns (x, aux, kept): aux is the MoE
+    rows, the carried pools, None). A "delta" kind's ``attend`` is a pair
+    (``_delta_mixer`` says of what). Returns (x, aux, kept): aux is the MoE
     balance term of a training layer, a zero for a dense one, and the
     [pairs, live tiles] counts of a layer that serves held experts."""
     eps = cfg.norm_eps
     dt = x.dtype
     kind = kind or cfg.kinds[0]
-    if cfg.latent:
+    if kind.recurrent:
+        o, kept = _delta_mixer(_rms_norm(x, lp["attn_norm"], eps), lp, cfg, kind, attend)
+    elif cfg.latent:
         q, k, v = _latent_qkv(_rms_norm(x, lp["attn_norm"], eps), lp, cfg, positions)
     else:
         with jax.named_scope("qkv"):
@@ -841,11 +952,16 @@ def decoder_block(x, lp, cfg: TransformerConfig, positions, attend, kind: LayerK
             k = wlc(k, ("batch", "seq", "kv_heads", "head_dim"))
             q = _rope_kind(q, positions, kind)
             k = _rope_kind(k, positions, kind)
-    o, kept = attend(q, k, v)
-    if cfg.attn_gate:
+    if not kind.recurrent:
+        o, kept = attend(q, k, v)
+    if cfg.attn_gate and not kind.recurrent:
         with jax.named_scope("attn_gate"):
-            gate = jax.nn.sigmoid(jnp.einsum("bsd,dh->bsh", h, lp["wg"].astype(dt)).astype(jnp.float32))
-            o = o * gate[..., None].astype(dt)
+            if cfg.attn_gate == "elementwise":
+                gate = jax.nn.sigmoid(jnp.einsum("bsd,dhk->bshk", h, lp["wg"].astype(dt)).astype(jnp.float32))
+                o = o * gate.astype(dt)
+            else:
+                gate = jax.nn.sigmoid(jnp.einsum("bsd,dh->bsh", h, lp["wg"].astype(dt)).astype(jnp.float32))
+                o = o * gate[..., None].astype(dt)
     with jax.named_scope("attn_out"):
         o = wlc(o, ("batch", "seq", "heads", "head_dim"))
         a = jnp.einsum("bshk,hkd->bsd", o, lp["wo"].astype(dt))
@@ -867,6 +983,17 @@ def decoder_block(x, lp, cfg: TransformerConfig, positions, attend, kind: LayerK
     return x, aux, kept
 
 
+def _whole_sequence_rule(q, k, v, g, beta, _window, cfg: TransformerConfig):
+    """A delta layer's rule over whole sequences from an empty state, nothing
+    kept: the chunked kernel where the configured implementation is a kernel's
+    and the backend a TPU's (forward only: the kernel has no backward pass),
+    its ``jax.numpy`` form for "reference" and everywhere else."""
+    from ray_tpu.ops.linear_attention import kda_chunk, kda_chunk_reference
+
+    kernel = cfg.attention_impl in ("auto", "flash") and jax.default_backend() == "tpu"
+    return (kda_chunk if kernel else kda_chunk_reference)(q, k, v, g, beta, out_dtype=v.dtype)[0], None
+
+
 def _layer(x, lp, cfg: TransformerConfig, positions, segment_ids=None, kind: LayerKind | None = None):
     """The block as training runs it: attention over the layer's own K/V by
     the configured implementation (inside the kind's window where it has
@@ -878,6 +1005,12 @@ def _layer(x, lp, cfg: TransformerConfig, positions, segment_ids=None, kind: Lay
         q = jnp.concatenate(q, axis=-1)
         return _attention(q, k, v, cfg, positions, segment_ids, scale=latent_scale(cfg)), None
 
+    if kind is not None and kind.recurrent:
+        if segment_ids is not None:
+            raise NotImplementedError(
+                "packed sequences are not written for a delta layer: its state and its convolution would have to "
+                "start again at each document's first position (ROADMAP M4)")
+        attend = (None, functools.partial(_whole_sequence_rule, cfg=cfg))
     x, aux, _ = decoder_block(x, lp, cfg, positions, attend, kind)
     # A layer that serves held experts hands out counts, not a loss term.
     return x, (jnp.zeros((), jnp.float32) if cfg.experts_held else aux)

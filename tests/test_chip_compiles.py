@@ -210,3 +210,68 @@ def test_the_samplers_conditionals_compile_for_a_v5e(rows, vocab, one_chip, no_c
     assert text.count(" conditional(") == 2
     costly = [line for line in text.splitlines() if "sample/" in line and ("/top_k" in line or "_gumbel" in line)]
     assert costly and all("sample/cond/" in line for line in costly)
+
+
+def test_the_delta_rules_two_calls_lower_for_a_v5e(one_chip, no_compile_cache, monkeypatch):
+    """ops/linear_attention.py at the serve cell's shapes. ``kda_step``: 128
+    slots of 64 heads of 128 x 128 float32, 3 layers' states in one pool of
+    1.6 GB that is aliased and not copied, the grid a runtime value.
+    ``kda_chunk``: one prompt of 2,048 positions of 64 heads, float32 matrix
+    products at the highest precision, a [128, 64] operand contracted over
+    its rows."""
+    from ray_tpu.ops import linear_attention as la
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    B, H, K, L, S = 128, 64, 128, 3, 2048
+
+    def arr(dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    row = arr((B, H, K))
+    compiled = jax.jit(la.kda_step, donate_argnums=(5,)).lower(
+        row, row, row, row, arr((B, H)), arr((L, B, H, K, K)), arr((), jnp.int32), arr((B,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1 and "kda_step" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 40 << 20  # the rows' operands, a tile a head: 34 MB
+
+    seq = arr((1, S, H, K))
+    compiled = jax.jit(lambda q, k, v, g, beta: la.kda_chunk(q, k, v, g, beta, out_dtype=jnp.bfloat16)).lower(
+        seq, seq, arr((1, S, H, K), jnp.bfloat16), seq, arr((1, S, H))).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1 and "kda_chunk" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 5 * S * H * K * 4 + (64 << 20)  # its five operands by head
+
+
+def test_the_decode_program_of_a_model_with_delta_layers_compiles_for_a_v5e(one_chip, no_compile_cache, monkeypatch):
+    """llm/engine.py ``_decode_impl`` of a model with one softmax layer and
+    three delta layers a period, at heads of 128 and otherwise small widths:
+    the period scan around one paged call, three ``kda_step`` calls and three
+    grouped matmuls a layer; the state pool (4 MB a slot) and the page pools
+    are carried and aliased, and no copy of either is among the temporaries."""
+    from ray_tpu.llm.engine import EngineConfig, LLMEngine
+    from ray_tpu.models.transformer import LayerKind, TransformerConfig, init_params
+
+    gqa = LayerKind("gqa", 8, rope_share=0.0)
+    kda = LayerKind("kda", 8, mixer="delta", conv_size=4, low_rank=128, beta_scale=2.0)
+    cfg = TransformerConfig(
+        vocab_size=1024, d_model=256, n_layers=4, n_heads=8, n_kv_heads=2, head_dim=128, d_ff=512, max_seq_len=2048,
+        param_dtype=jnp.bfloat16, layer_pattern=(gqa, kda, kda, kda), attn_gate="elementwise", norm_eps=1e-5,
+        n_experts=32, expert_top_k=4, experts_held=8, expert_d_ff=256, n_shared_experts=1, router_score="sigmoid")
+
+    def on_chip(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    params = jax.tree.map(on_chip, jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    eng = LLMEngine(cfg, params=params, engine_config=EngineConfig(
+        max_slots=16, max_seq=2048, page_size=128, total_pages=40, prefill_buckets=(512,), decode_block=8))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the program asks which attend to trace
+    B = eng.ec.max_slots
+    ints, floats = on_chip(jnp.zeros(B, jnp.int32)), on_chip(jnp.zeros(B, jnp.float32))
+    compiled = eng._decode_jit.lower(
+        params, tuple(on_chip(pool) for pool in eng.cache), ints, ints, on_chip(eng.d_page_tables),
+        on_chip(jax.random.PRNGKey(0)), 8, floats, floats, ints).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1 + 3 + 3 * 4
+    state, tails = eng.cache[2:]
+    assert state.shape == (3, 16, 8, 128, 128) and state.dtype == jnp.float32 and state.nbytes == 25_165_824
+    assert compiled.memory_analysis().temp_size_in_bytes < state.nbytes // 3  # not one layer's states

@@ -1,0 +1,137 @@
+"""The gated delta rule with a decay a channel (ops/linear_attention.py) at toy
+widths on the CPU: the chunk's arithmetic against the rule a position at a
+time and the one-token step, the two Pallas kernels in interpret mode, what a
+position with beta 0 and no decay leaves alone, an empty slot's state, and how
+far a state or decays kept in bfloat16 would be off."""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.models import reference_linear_moe as ref
+from ray_tpu.ops import linear_attention as la
+
+TOL = 1e-4
+
+
+def _inputs(B, S, H, K, V, seed=0):
+    """q of length K^-1/2 and k of length 1 a head, log decays around
+    -e^-2 (a few a sequence near -3), betas over the whole of (0, 2)."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    q, k = jax.random.normal(ks[0], (B, S, H, K)), jax.random.normal(ks[1], (B, S, H, K))
+    q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) / K ** 0.5
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    v = jax.random.normal(ks[2], (B, S, H, V))
+    g = -jnp.exp(1.5 * jax.random.normal(ks[3], (B, S, H, K)) - 2.0)
+    beta = 2.0 * jax.nn.sigmoid(2.0 * jax.random.normal(ks[4], (B, S, H)))
+    return q, k, v, g, beta
+
+
+def _close(a, b, tol=TOL):
+    np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("S", [3 * la.CHUNK + 37, la.CHUNK, 21])
+def test_the_chunked_form_the_scan_and_the_step_agree_in_float32(S):
+    """Three chunks and a ragged tail (and one whole chunk, and less than
+    one), betas above 1 among them: the chunk's arithmetic, the rule a
+    position at a time here and in the plain reference, and the one-token
+    step fed a position at a time."""
+    q, k, v, g, beta = args = _inputs(2, S, 3, 32, 32, seed=S)
+    assert float(beta.max()) > 1.5 and float(beta.min()) < 0.5
+    o_scan, s_scan = la.kda_scan_reference(*args)
+    o_chunk, s_chunk = la.kda_chunk_reference(*args)
+    _close(o_chunk, o_scan), _close(s_chunk, s_scan)
+    o_ref, s_ref = ref.delta_rule(*args)
+    _close(o_ref, o_scan), _close(s_ref, s_scan)
+    pool = jnp.zeros((2, 2, 3, 32, 32), jnp.float32)  # two layers' states; layer 1 is stepped
+    live, outs = jnp.ones(2, bool), []
+    for t in range(S):
+        o, pool = la.kda_step_reference(q[:, t], k[:, t], v[:, t], g[:, t], beta[:, t], pool, 1, live)
+        outs.append(o)
+    _close(jnp.stack(outs, axis=1), o_scan), _close(pool[1], s_scan)
+    assert not np.asarray(pool[0]).any()
+
+
+def test_a_prompt_continues_from_a_state_and_a_masked_tail_leaves_it_alone():
+    """The first 70 positions, then the rest from the state they left, give
+    the whole's outputs and state; positions with beta 0 and g 0 behind a
+    length change neither (how a bucket's padding is masked)."""
+    q, k, v, g, beta = args = _inputs(1, 150, 2, 32, 32, seed=5)
+    o_all, s_all = la.kda_chunk_reference(*args)
+    o_a, s_a = la.kda_chunk_reference(*(a[:, :70] for a in args))
+    o_b, s_b = la.kda_chunk_reference(*(a[:, 70:] for a in args), state=s_a)
+    _close(jnp.concatenate([o_a, o_b], axis=1), o_all), _close(s_b, s_all)
+    real = (jnp.arange(150) < 70)[None, :, None]
+    o_m, s_m = la.kda_chunk_reference(q, k, v, jnp.where(real[..., None], g, 0.0), jnp.where(real, beta, 0.0))
+    _close(s_m, s_a, 1e-6), _close(o_m[:, :70], o_a, 1e-6)
+
+
+def test_strong_decays_underflow_and_do_not_overflow():
+    """A channel that decays by e^-2.5 a position (e^-160 a chunk, e^-40 a
+    sub-block: the most the repo's weights reach) is exact in the chunked
+    form: the split around a sub-block's start keeps every factor finite."""
+    q, k, v, g, beta = _inputs(1, 2 * la.CHUNK, 2, 32, 32, seed=7)
+    g = -2.5 * jax.random.uniform(jax.random.PRNGKey(8), g.shape) ** 0.25  # most channels near -2.5 a position
+    args = (q, k, v, g, beta)
+    assert float(g.min()) < -2.45 and float(jnp.sum(g[:, :la.SUB], axis=1).min()) < -30
+    o_scan, s_scan = la.kda_scan_reference(*args)
+    o_chunk, s_chunk = la.kda_chunk_reference(*args)
+    assert np.isfinite(np.asarray(o_chunk)).all()
+    _close(o_chunk, o_scan), _close(s_chunk, s_scan)
+
+
+@pytest.mark.parametrize("S", [la.CHUNK + 9, 2 * la.CHUNK])
+def test_the_chunk_kernel_in_interpret_mode_matches_the_scan(S):
+    args = _inputs(2, S, 2, 128, 128, seed=11)
+    state = 0.1 * jax.random.normal(jax.random.PRNGKey(1), (2, 2, 128, 128))
+    o, s = la.kda_chunk(*args, state, interpret=True)
+    o_scan, s_scan = la.kda_scan_reference(*args, state)
+    assert o.shape == o_scan.shape and o.dtype == jnp.float32
+    _close(o, o_scan), _close(s, s_scan)
+    assert la.kda_chunk(*args, out_dtype=jnp.bfloat16, interpret=True)[0].dtype == jnp.bfloat16
+
+
+def test_the_step_kernel_takes_no_step_for_an_empty_slot_and_leaves_its_state_bit_for_bit():
+    """Slots 1 and 3 hold no request: the grid is the two live slots' (the
+    kernel's scalar-prefetched list leads with them), their states of layer 1
+    move as the reference moves them, and every other block of the pool,
+    the empty slots' and the other layers', is bit for bit what it was."""
+    B, H, K = 4, 4, 128
+    q, k, v, g, beta = (a[:, 0] for a in _inputs(B, 1, H, K, K, seed=3))
+    pool = jax.random.normal(jax.random.PRNGKey(9), (3, B, H, K, K))
+    live = jnp.array([True, False, True, False])
+    o_ref, pool_ref = la.kda_step_reference(q, k, v, g, beta, pool, 1, live)
+    o, pool_new = la.kda_step(q, k, v, g, beta, pool, 1, live, interpret=True)
+    _close(o, o_ref, 1e-5), _close(pool_new, pool_ref, 1e-5)
+    assert not np.asarray(o[1]).any() and not np.asarray(o[3]).any()
+    untouched = np.ones((3, B), bool)
+    untouched[1, [0, 2]] = False
+    assert (np.asarray(pool_new)[untouched] == np.asarray(pool)[untouched]).all()
+    assert (np.asarray(pool_ref)[untouched] == np.asarray(pool)[untouched]).all()
+    with pytest.raises(RuntimeError, match="kda_step needs a TPU backend"):
+        la.kda_step(q, k, v, g, beta, pool, 1, live)
+
+
+def test_a_state_or_decays_in_bfloat16_are_outside_the_tolerance():
+    """What the float32 state and decays buy, in the units of the first
+    test's tolerance (1e-4): the rule with its decays rounded to bfloat16, and
+    with its state rounded to bfloat16 after every position, over 229
+    positions: 2.5e-4 and 1.2e-3 of outputs no larger than 0.5, 2.5 and 12
+    tolerances off."""
+    q, k, v, g, beta = args = _inputs(2, 3 * la.CHUNK + 37, 3, 32, 32, seed=229)
+    o_scan, _ = la.kda_scan_reference(*args)
+    rounded = lambda a: a.astype(jnp.bfloat16).astype(jnp.float32)
+    o_g, _ = la.kda_scan_reference(q, k, v, rounded(g), beta)
+
+    def one(s, x):
+        q_t, k_t, v_t, g_t, b_t = x
+        s = s * jnp.exp(g_t)[..., None]
+        s = s + k_t[..., None] * (b_t[..., None] * (v_t - jnp.einsum("bhkv,bhk->bhv", s, k_t)))[..., None, :]
+        s = rounded(s)
+        return s, jnp.einsum("bhkv,bhk->bhv", s, q_t)
+
+    _, o_s = jax.lax.scan(one, jnp.zeros((2, 3, 32, 32)), tuple(jnp.moveaxis(a, 1, 0) for a in args))
+    off_g = float(jnp.abs(o_g - o_scan).max())
+    off_s = float(jnp.abs(jnp.moveaxis(o_s, 0, 1) - o_scan).max())
+    assert off_g > 2 * TOL and off_s > 10 * TOL, (off_g, off_s)
