@@ -12,10 +12,11 @@ which a model that allows negative eigenvalues reaches, is nothing special
 here. A position with beta 0 and g 0 leaves the state as it was: how a
 caller masks the padding behind a prompt's length.
 
-``kda_chunk`` takes a prompt 64 positions a grid step (compute-bound: matrix
-products, triangular inside a chunk). With G the running sum of g inside the
-chunk, the chunk's pseudo-values U solve (I + A) U = beta (V - (K e^G) S0),
-A[i, j] = beta_i sum_c k_ic k_jc e^(G_ic - G_jc) below the diagonal, and
+``kda_chunk`` takes a prompt 64 positions of several heads a grid step
+(compute-bound: matrix products, triangular inside a chunk). With G the
+running sum of g inside the chunk, the chunk's pseudo-values U solve
+(I + A) U = beta (V - (K e^G) S0), A[i, j] = beta_i sum_c k_ic k_jc e^(G_ic - G_jc)
+below the diagonal, and
 
     o = (Q e^G) S0 + P U,  P[i, j] = sum_c q_ic k_jc e^(G_ic - G_jc), j <= i
     S1 = Diag(e^(G_last)) S0 + (K e^(G_last - G))^T U.
@@ -27,10 +28,28 @@ sub-block: a decay however strong underflows to the 0 it is. Inside a
 sub-block the second factor is e^(what the sub-block's earlier rows decayed),
 cut at e^80: a channel that decays by more than e^-80 inside 16 positions is
 the one case the split gets wrong (the weights this repo makes stay under
-e^-40). (I + A)^-1 is a product of matrix powers: the 16 x 16 diagonal blocks
-D are nilpotent, (I + D)^-1 = (I - D)(I + D^2)(I + D^4)(I + D^8), and what is
-left, M = (I + D)^-1 (A - D), is nilpotent by blocks. Everything is float32,
-matrix products at the highest precision.
+e^-40). U is solved a row block of 16 at a time, U_i = (I + D_i)^-1 (R_i -
+A_i U) over the blocks before i: the 16 x 16 diagonal blocks D of A are
+nilpotent, (I + D)^-1 = (I - D)(I + D^2)(I + D^4)(I + D^8), and the four of a
+chunk go side by side as one [16, 64] operand, so that a product of the
+inverse or of the solve takes 16 rows through the MXU and not 64.
+
+Everything is float32, matrix products at the highest precision (six bfloat16
+passes on the MXU; three would leave out 2^-17 of a term, which six delta
+layers of a float32 model show, PERF.md section 6, PR 45). The one product
+that takes fewer rounds nothing: the running sum is the triangle, exact in
+bfloat16, times g's three bfloat16 parts, a pass each.
+
+A grid step of ``kda_chunk`` is one chunk of 8 heads, (B, H / 8, chunks) with
+the chunks in order and the heads' states [heads, K, V] in VMEM between them.
+q, k, v, g and o are blocks [64, heads, width] of the arrays as the mixer
+writes and reads them ([B, S, H, width], whatever their dtypes), turned by
+head inside the kernel, and beta [B, S, H] is multiplied in there: nothing is
+copied by head in HBM on either side of the call. The heads are a block's
+second-minor dimension, so they are whole tiles of 8 or, where H is no
+multiple of 8, all of H in one step. They are the batch of every
+``dot_general``, so one head's products run while another's wait, and the body
+is as long at 8 heads as at 1.
 
 ``kda_step`` takes one token a live slot (bound by reading and writing a
 slot's 64 x 128 x 128 float32 a layer): the state pool [L, slots, H, K, V]
@@ -39,8 +58,9 @@ to the output, and the grid is the live slots' (a runtime value): a slot
 without a request has no step, and its state is bit for bit what it was.
 
 Each call has its ``jax.numpy`` form beside it (``kda_chunk_reference`` runs
-the chunk's own arithmetic under ``vmap``; ``kda_scan_reference`` is the rule
-a position at a time), which other backends run.
+the chunk's own arithmetic, ``_chunk``, a sequence's heads at once under
+``vmap``; ``kda_scan_reference`` is the rule a position at a time), which
+other backends run.
 """
 from __future__ import annotations
 
@@ -54,87 +74,117 @@ CHUNK = 64  # positions a grid step of kda_chunk
 SUB = 16  # rows of a sub-block, whose decays share a reference point
 CLAMP = 80.0  # the largest exponent inside a sub-block
 HEADS_A_STEP = 16  # heads of one slot a grid step of kda_step: 1 MB of state in, 1 MB out
-F32 = jnp.float32
+HEADS_A_CHUNK = 8  # heads of one chunk a grid step of kda_chunk: a float32 tile's rows, since the heads are a block's second-minor dimension
+F32, BF16 = jnp.float32, jnp.bfloat16
+
+_NN = ((2,), (1,))  # a @ b, a head (the leading dimension of both)
+_NT = ((2,), (2,))  # a @ b^T
+_TN = ((1,), (1,))  # a^T @ b
 
 
-def _dot(a, b, dims=((1,), (0,))):
-    return lax.dot_general(a, b, (dims, ((), ())), precision=lax.Precision.HIGHEST, preferred_element_type=F32)
+def _pass(a, b, dims):
+    """One pass of the MXU a head: bfloat16 operands, float32 sums."""
+    return lax.dot_general(a, b, (dims, ((0,), (0,))), preferred_element_type=F32)
 
 
-_NT = ((1,), (1,))  # a @ b^T
-_TN = ((0,), (0,))  # a^T @ b
+def _dot(a, b, dims=_NN):
+    """a @ b a head, float32 at the highest precision (six passes on the MXU)."""
+    return lax.dot_general(a, b, (dims, ((0,), (0,))), precision=lax.Precision.HIGHEST, preferred_element_type=F32)
 
 
 def _column(row):
-    """row [1, n] -> [n, 1] without a transpose: the diagonal of its
+    """row [..., 1, n] -> [..., n, 1] without a transpose: the diagonal of its
     broadcast, summed along the lanes."""
-    n = row.shape[1]
+    n = row.shape[-1]
     eye = lax.broadcasted_iota(jnp.int32, (n, n), 0) == lax.broadcasted_iota(jnp.int32, (n, n), 1)
-    return jnp.sum(jnp.where(eye, row, 0.0), axis=1, keepdims=True)
+    return jnp.sum(jnp.where(eye, row, 0.0), axis=-1, keepdims=True)
 
 
-def _chunk(q, k, kb, vb, g, s0):
-    """One chunk of one head, float32: q, k, kb (= beta k), g [C, K], vb
-    (= beta v) [C, V], s0 [K, V] -> (o [C, V], s1 [K, V]). Written with what
-    both ``jax.numpy`` under vmap and a Mosaic kernel's body can run."""
-    C, K = k.shape
+def _chunk(q, k, v, g, beta, s0):
+    """One chunk of N heads: q, k, g [N, C, K] float32, v [N, C, V], beta
+    [N, C, 1], s0 [N, K, V] float32 -> (o [N, C, V], s1 [N, K, V]), float32.
+    Every product is a ``dot_general`` with the heads as its batch, so the
+    heads' chains are independent of each other inside one body. Written with
+    what both ``jax.numpy`` under vmap and a Mosaic kernel's body can run."""
+    N, C, K = k.shape
     sub = min(SUB, C)
+    blocks = C // sub
+    kb, vb = k * beta, v.astype(F32) * beta
     row = lax.broadcasted_iota(jnp.int32, (C, C), 0)
     col = lax.broadcasted_iota(jnp.int32, (C, C), 1)
-    G = _dot((row >= col).astype(F32), g)  # the running sum, its own position counted
-    at = lax.broadcasted_iota(jnp.int32, (C, K), 0)
-    rows_a, rows_p = [], []
-    for i in range(0, C, sub):
-        ref = G[i - 1:i] if i else jnp.zeros((1, K), F32)
-        near = jnp.exp(G[i:i + sub] - ref)  # at most 1
-        far = jnp.where(at < i + sub, k * jnp.exp(jnp.minimum(ref - G, CLAMP)), 0.0)
-        both = _dot(jnp.concatenate([kb[i:i + sub] * near, q[i:i + sub] * near], axis=0), far, _NT)  # [2 sub, C]
-        rows_a.append(both[:sub])
-        rows_p.append(both[sub:])
-    A = jnp.where(row > col, jnp.concatenate(rows_a, axis=0), 0.0)
-    P = jnp.where(row >= col, jnp.concatenate(rows_p, axis=0), 0.0)
-    eye = (row == col).astype(F32)
-    D = jnp.where(row // sub == col // sub, A, 0.0)
-    T, X, n = eye - D, D, 2
-    while n < sub:  # (I - D)(I + D^2)(I + D^4) ...: D^sub = 0
-        X = _dot(X, X)
-        T = _dot(T, eye + X)
+    # the running sum, its own position counted: the triangle is exact in bfloat16 and g is its three parts
+    g_hi = g.astype(BF16)
+    g_mid = (g - g_hi.astype(F32)).astype(BF16)
+    g_lo = (g - g_hi.astype(F32) - g_mid.astype(F32)).astype(BF16)
+    tri = jnp.broadcast_to((row >= col).astype(BF16), (N, C, C))
+    G = _pass(tri, g_hi, _NN) + (_pass(tri, g_mid, _NN) + _pass(tri, g_lo, _NN))
+    # a sub-block's reference point: the sum before its first row
+    refs = [jnp.zeros((N, 1, K), F32)] + [G[:, i - 1:i] for i in range(sub, C, sub)]
+    near = jnp.exp(G - jnp.concatenate([jnp.broadcast_to(r, (N, sub, K)) for r in refs], axis=1))  # at most 1
+    # row block i of A and P against the columns up to its own: [N x blocks, 2 sub, K] x [N x blocks, C, K]
+    by_block = lambda a: a.reshape(N * blocks, sub, K)
+    lhs = jnp.concatenate([by_block(kb * near), by_block(q * near)], axis=1)
+    far = []
+    for i, ref in enumerate(refs):
+        n = (i + 1) * sub  # the rows a sub-block keeps
+        far.append(k[:, :n] * jnp.exp(jnp.minimum(ref - G[:, :n], CLAMP)))
+        if n < C:
+            far[i] = jnp.concatenate([far[i], jnp.zeros((N, C - n, K), F32)], axis=1)
+    both = _dot(lhs, jnp.stack(far, axis=1).reshape(N * blocks, C, K), _NT).reshape(N, blocks, 2 * sub, C)
+    A = jnp.where(row > col, both[:, :, :sub].reshape(N, C, C), 0.0)
+    P = jnp.where(row >= col, both[:, :, sub:].reshape(N, C, C), 0.0)
+    # (I + D)^-1 of A's diagonal blocks D, which are nilpotent: (I - D)(I + D^2)(I + D^4) ..., D^sub = 0. A matrix
+    # of diagonal blocks alone goes as [N, sub, C], its blocks side by side, and a product from it takes sub rows
+    own = row // sub == col // sub
+    beside = lambda X: functools.reduce(jnp.add, [X[:, i:i + sub] for i in range(0, C, sub)])
+    diagonal = lambda X: jnp.where(own, jnp.concatenate([X] * blocks, axis=1), 0.0)
+    Xd = jnp.where(own, A, 0.0)
+    T, X, n = beside((row == col).astype(F32) - Xd), beside(Xd), 2
+    while n < sub:
+        X = _dot(X, Xd)
+        Xd = diagonal(X)
+        T = T + _dot(T, Xd)
         n *= 2
-    if C > sub:
-        M = _dot(T, A - D)
-        Tm, X, n = eye - M, M, 2
-        while n < C // sub:  # M^(C / sub) = 0
-            X = _dot(X, X)
-            Tm = _dot(Tm, eye + X)
-            n *= 2
-        T = _dot(Tm, T)
     decayed = jnp.exp(G)
-    U = _dot(T, vb - _dot(kb * decayed, s0))
-    o = _dot(q * decayed, s0) + _dot(P, U)
-    last = G[C - 1:C]
+    from_s0 = _dot(jnp.concatenate([kb * decayed, q * decayed], axis=1), s0)  # [N, 2 C, V]
+    # (I + A) U = R a row block at a time, U_i = T_i (R_i - A_i U) with the blocks before i in U and zeros after
+    # them; a block's rows stand at their own place among zeros, where T's blocks side by side meet their own
+    R = vb - from_s0[:, :C]
+    zeros = lambda rows: [jnp.zeros((N, rows, R.shape[2]), F32)] if rows else []
+    placed = lambda x, i: jnp.concatenate(zeros(i) + [x] + zeros(C - sub - i), axis=1)
+    U = placed(_dot(T, placed(R[:, :sub], 0)), 0)
+    for i in range(sub, C, sub):
+        U = U + placed(_dot(T, placed(R[:, i:i + sub] - _dot(A[:, i:i + sub], U), i)), i)
+    o = from_s0[:, C:] + _dot(P, U)
+    last = G[:, C - 1:C]
     s1 = s0 * _column(jnp.exp(last)) + _dot(k * jnp.exp(last - G), U, _TN)
     return o, s1
 
 
-def _by_head(x):
-    """[B, S, H, W] -> [B, H, S, W] in float32."""
-    return jnp.swapaxes(x, 1, 2).astype(F32)
-
-
-def _chunk_operands(q, k, v, g, beta, chunk):
-    """The chunk call's operands, by head and padded to whole chunks with
-    positions that leave the state alone (beta 0, g 0)."""
-    S = q.shape[1]
-    pad = -S % chunk
-    b = beta.astype(F32)[..., None]
-    ops = [_by_head(q), _by_head(k), _by_head(k.astype(F32) * b), _by_head(v.astype(F32) * b), _by_head(g)]
-    if pad:
-        ops = [jnp.pad(a, ((0, 0), (0, 0), (0, pad), (0, 0))) for a in ops]
-    return ops
+def _whole_chunks(q, k, v, g, beta, chunk):
+    """The operands as they are, padded to whole chunks with positions that
+    leave the state alone (beta 0, g 0)."""
+    pad = -q.shape[1] % chunk
+    if not pad:
+        return q, k, v, g, beta
+    return tuple(jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2)) for a in (q, k, v, g, beta))
 
 
 def _initial(state, B, H, K, V):
     return jnp.zeros((B, H, K, V), F32) if state is None else state.astype(F32)
+
+
+def _heads_a_step(H, limit):
+    """The most heads a grid step takes: H's largest divisor up to `limit`."""
+    return next(n for n in range(min(limit, H), 0, -1) if H % n == 0)
+
+
+def _heads_a_chunk(H):
+    """The heads of a grid step of ``kda_chunk``, the second-minor dimension
+    of its blocks [CHUNK, heads, width]: whole tiles of 8 rows, or all of H
+    where those do not divide it (a block may span a whole dimension whatever
+    its length)."""
+    return HEADS_A_CHUNK if H % HEADS_A_CHUNK == 0 else H
 
 
 # ---------------------------------------------------------------------------
@@ -161,24 +211,24 @@ def kda_scan_reference(q, k, v, g, beta, state=None):
 
 
 def kda_chunk_reference(q, k, v, g, beta, state=None, *, out_dtype=F32, chunk=CHUNK):
-    """``kda_chunk`` in ``jax.numpy``: the chunk's arithmetic (``_chunk``)
-    under vmap over sequences and heads, a scan over chunks. Arguments and
-    results as ``kda_scan_reference``; o in `out_dtype`."""
+    """``kda_chunk`` in ``jax.numpy``: the chunk's arithmetic (``_chunk``, every
+    head of a sequence at once) under vmap over sequences, a scan over chunks.
+    Arguments and results as ``kda_scan_reference``; o in `out_dtype`."""
     B, S, H, K = q.shape
     V = v.shape[-1]
     chunk = min(chunk, -(-S // SUB) * SUB)
-    ops = _chunk_operands(q, k, v, g, beta, chunk)
-    n = ops[0].shape[2] // chunk
-    xs = tuple(jnp.moveaxis(a.reshape(B, H, n, chunk, a.shape[-1]), 2, 0) for a in ops)
-    every_head = jax.vmap(jax.vmap(_chunk))
+    ops = _whole_chunks(q.astype(F32), k.astype(F32), v, g.astype(F32), beta.astype(F32)[..., None], chunk)
+    n = ops[0].shape[1] // chunk
+    xs = tuple(jnp.transpose(a.reshape(B, n, chunk, H, a.shape[-1]), (1, 0, 3, 2, 4)) for a in ops)  # [n, B, H, chunk, W]
+    every_sequence = jax.vmap(_chunk)
 
     def one(s, x):
-        o, s = every_head(*x, s)
+        o, s = every_sequence(*x, s)
         return s, o
 
     s, o = lax.scan(one, _initial(state, B, H, K, V), xs)  # o [n, B, H, chunk, V]
-    o = jnp.moveaxis(o, 0, 2).reshape(B, H, n * chunk, V)[:, :, :S]
-    return jnp.swapaxes(o, 1, 2).astype(out_dtype), s
+    o = jnp.transpose(o, (1, 0, 3, 2, 4)).reshape(B, n * chunk, H, V)[:, :S]
+    return o.astype(out_dtype), s
 
 
 def kda_step_reference(q, k, v, g, beta, pool, layer, live):
@@ -208,17 +258,27 @@ def _needs_tpu(what: str, interpret: bool) -> None:
                            f"{jax.default_backend()!r}")
 
 
-def _chunk_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, s0_ref, o_ref, s_ref, s_scr):
+def _chunk_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, s0_ref, o_ref, s_ref, s_scr):
+    """Grid (B, H / heads, chunks): chunk ``n`` of ``heads`` heads. The
+    operands are blocks [CHUNK, heads, width] of the arrays as the mixer wrote
+    them, turned by head here, and beta [CHUNK, H], of which the step's heads
+    are picked by a mask; ``s_scr`` [heads, K, V] carries the heads' states
+    from a chunk to the next."""
     from jax.experimental import pallas as pl
 
-    n = pl.program_id(2)
+    j, n = pl.program_id(1), pl.program_id(2)
+    heads = s_scr.shape[0]
 
     @pl.when(n == 0)
     def _first_chunk():
         s_scr[...] = s0_ref[...]
 
-    o, s = _chunk(q_ref[...], k_ref[...], kb_ref[...], vb_ref[...], g_ref[...], s_scr[...])
-    o_ref[...] = o.astype(o_ref.dtype)
+    by_head = lambda ref: jnp.swapaxes(ref[...].astype(F32), 0, 1)  # [CHUNK, heads, W] -> [heads, CHUNK, W]
+    b = jnp.broadcast_to(beta_ref[...].astype(F32), (heads, *beta_ref.shape))  # [heads, CHUNK, H]
+    mine = lax.broadcasted_iota(jnp.int32, b.shape, 2) == lax.broadcasted_iota(jnp.int32, b.shape, 0) + j * heads
+    beta = jnp.sum(jnp.where(mine, b, 0.0), axis=2, keepdims=True)
+    o, s = _chunk(by_head(q_ref), by_head(k_ref), by_head(v_ref), by_head(g_ref), beta, s_scr[...])
+    o_ref[...] = jnp.swapaxes(o, 0, 1).astype(o_ref.dtype)
     s_scr[...] = s
 
     @pl.when(n == pl.num_programs(2) - 1)
@@ -227,34 +287,39 @@ def _chunk_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, s0_ref, o_ref, s_ref, s_s
 
 
 def kda_chunk(q, k, v, g, beta, state=None, *, out_dtype=None, interpret=False):
-    """The rule over a prompt, CHUNK positions a grid step (the Pallas
-    kernel; arguments and results as ``kda_scan_reference``, o in `out_dtype`
-    or q's). Grid (B, H, chunks), the chunks in order with the head's state
-    in VMEM between them. Runs on a TPU backend, or anywhere with
-    interpret=True, and raises elsewhere."""
+    """The rule over a prompt, CHUNK positions of several heads a grid step
+    (the Pallas kernel; arguments and results as ``kda_scan_reference``, o in
+    `out_dtype` or q's). Grid (B, H / heads, chunks), the chunks in order with
+    the heads' states in VMEM between them; q, k, v, g, beta and o are read
+    and written where they lie, in whatever dtypes they have. Runs on a TPU
+    backend, or anywhere with interpret=True, and raises elsewhere."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     _needs_tpu("kda_chunk", interpret)
     B, S, H, K = q.shape
     V = v.shape[-1]
-    ops = _chunk_operands(q, k, v, g, beta, CHUNK)
-    n = ops[0].shape[2] // CHUNK
-    rows = lambda W: pl.BlockSpec((None, None, CHUNK, W), lambda b, h, c: (b, h, c, 0))
-    whole = lambda: pl.BlockSpec((None, None, K, V), lambda b, h, c: (b, h, 0, 0))
+    heads = _heads_a_chunk(H)
+    q, k, v, g, beta = _whole_chunks(q, k, v, g, beta, CHUNK)
+    n = q.shape[1] // CHUNK
+    rows = lambda W: pl.BlockSpec((None, CHUNK, heads, W), lambda b, j, c: (b, c, j, 0))
+    whole = lambda: pl.BlockSpec((None, heads, K, V), lambda b, j, c: (b, j, 0, 0))
     o, s = pl.pallas_call(
         _chunk_kernel,
-        grid=(B, H, n),
-        in_specs=[rows(K), rows(K), rows(K), rows(V), rows(K), whole()],
+        grid=(B, H // heads, n),
+        in_specs=[rows(K), rows(K), rows(V), rows(K), pl.BlockSpec((None, CHUNK, H), lambda b, j, c: (b, c, 0)), whole()],
         out_specs=[rows(V), whole()],
-        out_shape=[jax.ShapeDtypeStruct((B, H, n * CHUNK, V), out_dtype or q.dtype),
+        out_shape=[jax.ShapeDtypeStruct((B, n * CHUNK, H, V), out_dtype or q.dtype),
                    jax.ShapeDtypeStruct((B, H, K, V), F32)],
-        scratch_shapes=[pltpu.VMEM((K, V), F32)],
-        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "arbitrary")),
+        scratch_shapes=[pltpu.VMEM((heads, K, V), F32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=32 * 1024 * 1024,  # two blocks of every operand and of the states, beside the heads' temporaries
+        ),
         interpret=interpret,
         name="kda_chunk",
-    )(*ops, _initial(state, B, H, K, V))
-    return jnp.swapaxes(o[:, :, :S], 1, 2), s
+    )(q, k, v, g, beta, _initial(state, B, H, K, V))
+    return o[:, :S], s
 
 
 # rows of a head's operand tile in kda_step: q, k, v, g and beta (along the lanes), the rest unused
@@ -291,7 +356,7 @@ def kda_step(q, k, v, g, beta, pool, layer, live, *, interpret=False):
     B, H, K = q.shape
     if v.shape[-1] != K:
         raise ValueError(f"kda_step packs a head's operands into one tile: key and value widths differ ({K}, {v.shape[-1]})")
-    heads = next(n for n in range(min(HEADS_A_STEP, H), 0, -1) if H % n == 0)
+    heads = _heads_a_step(H, HEADS_A_STEP)
     rows = [a.astype(F32) for a in (q, k, v, g, jnp.broadcast_to(beta[..., None], q.shape))]
     x = jnp.stack(rows + [jnp.zeros_like(rows[0])] * (_TILE - len(rows)), axis=2)  # [B, H, 8, K]
     # the live slots first (a stable sort on one bit), and how many they are

@@ -216,13 +216,18 @@ def test_the_delta_rules_two_calls_lower_for_a_v5e(one_chip, no_compile_cache, m
     """ops/linear_attention.py at the serve cell's shapes. ``kda_step``: 128
     slots of 64 heads of 128 x 128 float32, 3 layers' states in one pool of
     1.6 GB that is aliased and not copied, the grid a runtime value.
-    ``kda_chunk``: one prompt of 2,048 positions of 64 heads, float32 matrix
-    products at the highest precision, a [128, 64] operand contracted over
-    its rows."""
+    ``kda_chunk``: one prompt of 2,048 and of 8,192 positions (the cell's
+    largest bucket) of 64 heads, the operands in the mixer's dtypes (q, k, g
+    float32, v bfloat16): blocks of [64, 8 heads, 128] turned by head inside,
+    float32 products at the highest precision a head, a [128, 64] operand
+    contracted over its rows; and of 12, 3 and 8 heads, where a block spans
+    every head. The call reads and writes the arrays where they lie: a float32
+    copy of ONE operand by head (67 MB at 2,048 positions; PR 44's call made
+    five) would be more than all it may hold beside them."""
     from ray_tpu.ops import linear_attention as la
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    B, H, K, L, S = 128, 64, 128, 3, 2048
+    B, H, K, L = 128, 64, 128, 3
 
     def arr(dims, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
@@ -234,12 +239,14 @@ def test_the_delta_rules_two_calls_lower_for_a_v5e(one_chip, no_compile_cache, m
     assert text.count("tpu_custom_call") == 1 and "kda_step" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 40 << 20  # the rows' operands, a tile a head: 34 MB
 
-    seq = arr((1, S, H, K))
-    compiled = jax.jit(lambda q, k, v, g, beta: la.kda_chunk(q, k, v, g, beta, out_dtype=jnp.bfloat16)).lower(
-        seq, seq, arr((1, S, H, K), jnp.bfloat16), seq, arr((1, S, H))).compile()
-    text = compiled.as_text()
-    assert text.count("tpu_custom_call") == 1 and "kda_chunk" in text
-    assert compiled.memory_analysis().temp_size_in_bytes < 5 * S * H * K * 4 + (64 << 20)  # its five operands by head
+    # 12 and 3 heads are no whole tiles of 8: the block spans every head. 8 heads: v's block is half a bfloat16 tile.
+    for S, H in ((2048, 64), (8192, 64), (512, 12), (512, 3), (512, 8)):
+        seq = arr((1, S, H, K))
+        compiled = jax.jit(lambda q, k, v, g, beta: la.kda_chunk(q, k, v, g, beta, out_dtype=jnp.bfloat16)).lower(
+            seq, seq, arr((1, S, H, K), jnp.bfloat16), seq, arr((1, S, H))).compile()
+        text = compiled.as_text()
+        assert text.count("tpu_custom_call") == 1 and "kda_chunk" in text
+        assert compiled.memory_analysis().temp_size_in_bytes < S * H * K * 4  # not one operand by head in float32
 
 
 def test_the_decode_program_of_a_model_with_delta_layers_compiles_for_a_v5e(one_chip, no_compile_cache, monkeypatch):

@@ -92,6 +92,62 @@ def test_the_chunk_kernel_in_interpret_mode_matches_the_scan(S):
     assert la.kda_chunk(*args, out_dtype=jnp.bfloat16, interpret=True)[0].dtype == jnp.bfloat16
 
 
+@pytest.mark.parametrize("H,groups", [(8, 1), (16, 2), (3, 1), (12, 1)],
+                         ids=["one_group", "two_groups_of_8", "3_heads_the_limit_does_not_divide", "12_heads_no_whole_tiles"])
+def test_the_chunk_kernel_takes_several_heads_a_step_from_the_operands_as_the_mixer_writes_them(H, groups):
+    """A grid step is one chunk of ``groups``' worth of heads: q, k and g in
+    float32, v in bfloat16 and beta [B, S, H] go in as ``_delta_mixer`` leaves
+    them, a state is carried in, and S is no whole number of chunks. Against
+    the rule a position at a time on the same (rounded) v; among this many
+    draws a decay passes e^-80 a position, outside what the split around a
+    sub-block's start takes (the module's docstring), so they stop at e^-4."""
+    S = la.CHUNK + 9
+    q, k, v, g, beta = _inputs(2, S, H, 128, 128, seed=H)
+    v, g = v.astype(jnp.bfloat16), jnp.maximum(g, -4.0)
+    assert H // la._heads_a_chunk(H) == groups
+    state = 0.1 * jax.random.normal(jax.random.PRNGKey(2), (2, H, 128, 128))
+    o, s = la.kda_chunk(q, k, v, g, beta, state, out_dtype=jnp.float32, interpret=True)
+    o_scan, s_scan = la.kda_scan_reference(q, k, v, g, beta, state)
+    assert o.shape == (2, S, H, 128) and s.shape == state.shape
+    _close(o, o_scan), _close(s, s_scan)
+
+
+def _equations(jaxpr):
+    """Equations of a jaxpr, those of its inner jaxprs counted once each."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += 1
+        for value in eqn.params.values():
+            for inner in value if isinstance(value, (list, tuple)) else [value]:
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    n += _equations(inner)
+    return n
+
+
+def test_the_chunk_kernels_body_stays_small_at_every_count_of_heads_a_step():
+    """What every start of a replica pays, compile cache or not: a prefill
+    program traces and lowers this body once a call, three calls a program,
+    so its size is `setup_warmup_s`. PR 42 paid 5 s a call a program for a
+    body of over 2,000 equations (the paged kernel's) and was refused for its
+    set-up alone; PR 43 got the same gain at 332-340. This body counted 196 at
+    commit 8e31e4e (one head a step). The heads of a step are the batch of
+    its ``dot_general``s, so the count is the same whatever ``kda_chunk``
+    chooses for H: 8 heads a step, or all 12, 3 or 1 of an H that is no whole
+    tiles of 8."""
+    def body(H):
+        seq = jnp.zeros((1, 512, H, 128), jnp.float32)
+        jaxpr = jax.make_jaxpr(lambda q, k, v, g, beta: la.kda_chunk(q, k, v, g, beta, out_dtype=v.dtype, interpret=True))(
+            seq, seq, seq.astype(jnp.bfloat16), seq, jnp.zeros((1, 512, H), jnp.float32))
+        calls = [eqn for eqn in jaxpr.jaxpr.eqns if eqn.primitive.name == "pallas_call"]
+        assert len(calls) == 1 and calls[0].params["grid_mapping"].grid[1] == H // la._heads_a_chunk(H)
+        return _equations(calls[0].params["jaxpr"])
+
+    sizes = {H: body(H) for H in (64, 12, 3, 1)}
+    assert max(sizes.values()) < 400, sizes
+    assert len(set(sizes.values())) == 1, sizes
+
+
 def test_the_step_kernel_takes_no_step_for_an_empty_slot_and_leaves_its_state_bit_for_bit():
     """Slots 1 and 3 hold no request: the grid is the two live slots' (the
     kernel's scalar-prefetched list leads with them), their states of layer 1
