@@ -1,0 +1,6 @@
+"""tpot_p50_ms, under a name of its own: the lists of its `.backlog` twin
+are held to their members by a test, and this cell cannot join them."""
+
+
+def read(ctx):
+    return ctx.same_as("tpot_p50_ms")

@@ -176,7 +176,7 @@ def phase_kernels(sz: dict, rehearsal: bool) -> dict:
 
     from ray_tpu.accel.device import device_report, enable_compile_cache
     from ray_tpu.ops.attention import flash_attention, mha_reference
-    from ray_tpu.ops.paged_attention import paged_attention, paged_attention_reference
+    from ray_tpu.ops.paged_attention import kv_row_width, paged_attention, paged_attention_reference
 
     cache_dir = enable_compile_cache()
     out = {**device_report(), "compile_cache_dir": cache_dir}
@@ -246,8 +246,10 @@ def phase_kernels(sz: dict, rehearsal: bool) -> dict:
         n_pages, n_layers = sum(used) + 1, 3
         kq, kk, kv_, kn, vn = jax.random.split(jax.random.PRNGKey(1), 5)
         q = jax.random.normal(kq, (B, H, D), jnp.bfloat16)
-        kp = jax.random.normal(kk, (n_layers, KV, n_pages, ps, D), jnp.bfloat16)
-        vp = jax.random.normal(kv_, (n_layers, KV, n_pages, ps, D), jnp.bfloat16)
+        # a head under a lane tile lies in rows of a whole one, zeros behind it, as the engine's pools do
+        pad = ((0, 0),) * 4 + ((0, kv_row_width(D) - D),)
+        kp = jnp.pad(jax.random.normal(kk, (n_layers, KV, n_pages, ps, D), jnp.bfloat16), pad)
+        vp = jnp.pad(jax.random.normal(kv_, (n_layers, KV, n_pages, ps, D), jnp.bfloat16), pad)
         k_new = jax.random.normal(kn, (B, KV, D), jnp.bfloat16)
         v_new = jax.random.normal(vn, (B, KV, D), jnp.bfloat16)
         table = np.zeros((B, ppseq), np.int32)

@@ -391,9 +391,10 @@ class LLMServer:
         from ray_tpu.accel import device as _device
 
         eng = self.engine
-        # the last layers' stack (of a model with a layer pattern: its first kind's)
-        stack = eng.params["layers"] if "layers" in eng.params else next(iter(eng.params["kind_layers"].values()))
-        wq = stack["wq_b" if eng.cfg.latent else "wq"]  # heads on axis 2 either way
+        # the last layers' stack (of a model with a layer pattern: its first kind's that attends with queries)
+        name = "wq_b" if eng.cfg.latent else "wq"  # heads on axis 2 either way
+        stacks = [eng.params["layers"]] if "layers" in eng.params else eng.params["kind_layers"].values()
+        wq = next(stack[name] for stack in stacks if name in stack)
         per_device = {
             "wq": list(wq.sharding.shard_shape(wq.shape)),
             # the first pool: a head's K rows, or a latent layer's one pool

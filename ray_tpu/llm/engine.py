@@ -66,16 +66,18 @@ llm/_internal/serve/engines/vllm/vllm_engine.py:174):
   pages. Admission budgets pages of the layers that keep every token. A
   prefix hit or a chunk of a prompt cannot restore a ring from pages, so both
   are refused for such a model.
-- The third rule, a state kept by slot (a kind with ``mixer="delta"``,
-  ops/linear_attention.py): such a layer keeps no rows of tokens. Its two
-  pools are addressed by the slot and do not grow with the context: a state
-  ``[the kind's layers, max_slots, heads, head_dim, head_dim]`` in float32
-  and the last conv_size - 1 inputs of its short convolutions. Prefill leaves
+- The third rule, a state kept by slot (a recurrent kind: ``mixer="delta"``,
+  ops/linear_attention.py, or ``mixer="ssd"``, ops/ssd.py; one branch here
+  for both, on ``kind.recurrent``): such a layer keeps no rows of tokens. Its
+  two pools are addressed by the slot and do not grow with the context: a
+  state in float32 and the last conv_size - 1 inputs of its short
+  convolution, each ``[the kind's layers, max_slots, ...]`` with the shapes
+  the kind gives (models/transformer.py ``slot_state_shapes``). Prefill leaves
   both as they stand at the prompt's own length, not at its bucket's end
   (positions behind the length leave the state alone, and the tail is cut at
   the length); decode carries both through its loops like the other pools,
-  the state updated in place by the ``kda_step`` call its output aliases, one
-  grid step a live slot and none for an empty one, whose state stays bit for
+  the state updated in place by the step call its output aliases
+  (``kda_step``, ``ssd_step``), one grid step a live slot and none for an empty one, whose state stays bit for
   bit. Admission budgets pages for the layers that keep every token, as for
   window layers. A page copy cannot restore a state and a chunk of a prompt
   would have to start from one, so prefix hits, chunked prefill and a mesh are
@@ -116,13 +118,13 @@ from jax.sharding import NamedSharding, PartitionSpec as _P
 
 from ray_tpu.llm.sampling import SamplingParams, sample_batch
 from ray_tpu.models.transformer import (
-    TransformerConfig, _rms_norm, decoder_block, init_params, lane_padded, latent_absorb, latent_expand,
-    latent_scale, latent_values, pad_last, param_logical_axes, run_layers,
+    TransformerConfig, _rms_norm, decoder_block, embed_tokens, hidden_logits, init_params, lane_padded,
+    latent_absorb, latent_expand, latent_scale, latent_values, pad_last, param_logical_axes, recurrence, run_layers,
+    slot_state_shapes,
 )
 from ray_tpu.ops.latent_attention import latent_attention_reference, latent_paged_attention, latent_row_width
-from ray_tpu.ops.linear_attention import delta_rule
 from ray_tpu.ops.paged_attention import (
-    group_pages, live_pages, page_groups, paged_attention, paged_attention_reference, ring_pages,
+    group_pages, kv_row_width, live_pages, page_groups, paged_attention, paged_attention_reference, ring_pages,
     window_attention_reference,
 )
 from ray_tpu.util import tracing as _tracing
@@ -195,7 +197,7 @@ class EngineConfig:
     # stall per step is bounded by one chunk's compute. Must be a multiple
     # of page_size. 0 = off (whole-prompt prefill). Refused for a model with
     # window layers (a chunk attends pages a ring no longer holds) and for one
-    # with delta layers (a chunk would have to start from the state the one
+    # with recurrent layers (a chunk would have to start from the state the one
     # before left: ROADMAP M2, M4).
     chunked_prefill: int = 0
     # Prefix KV cache (reference: vLLM automatic prefix caching +
@@ -217,7 +219,7 @@ class EngineConfig:
     #   pages, then a chunked TAIL prefill embeds only the new tokens,
     #   attending to the cached pages gathered from the pool — prefill
     #   compute scales with the tail, not the prompt.
-    # Refused for a model with window layers or delta layers: a hit copies
+    # Refused for a model with window layers or recurrent layers: a hit copies
     # pages, which restore neither a ring nor a state (ROADMAP M2, M4).
     prefix_cache: bool = False
 
@@ -253,16 +255,20 @@ class _Block:
     """A decode block the device has been handed and the host has not fetched:
     what ``LLMEngine._absorb`` needs to walk it a step later."""
     toks: Any  # [n, max_slots], on the device
-    counts: Any  # held experts' (pairs, tiles) and delta layers' rewritten states, int32 on the device; None without either
+    counts: Any  # held experts' (pairs, tiles) and recurrent layers' rewritten states, int32 on the device; None without either
     n: int
     rec: dict  # the dispatching step's record (the ring holds this dict: counts land in it)
     rows: list  # (slot index, the _Slot that held it at dispatch) of every active row
 
 
-def _kv_rows(kv, dtype):
+def _kv_rows(kv, dtype, width=None):
     """One request's K or V of a layer, [1, P, KV, Hd], as the paged pool
-    stores it: [KV, P, Hd] in the pool's dtype."""
-    return kv[0].transpose(1, 0, 2).astype(dtype)
+    stores it: [KV, P, Hd] in the pool's dtype, zero-padded to the pool's row
+    where that is wider than a head (ops/paged_attention.py ``kv_row_width``)."""
+    rows = kv[0].transpose(1, 0, 2).astype(dtype)
+    # padded rows are told their layout: without it the TPU compiler kept them tokens-minor and turned both pools
+    # round to match, in and out of every prefill program (four copies of a pool a call; my chip run, PR 46)
+    return rows if width in (None, rows.shape[-1]) else _row_major(pad_last(rows, width))
 
 
 def _latent_rows(c, k_rope, width, dtype):
@@ -378,18 +384,19 @@ class LLMEngine:
                 "chunked_prefill is not written for window layers: a chunk attends the earlier chunks' "
                 "pages, and a window layer keeps no pages behind its ring (ROADMAP M2)")
         self._recurrent = tuple(kind for kind in cfg.kinds if kind.recurrent)  # kinds that keep a state a slot
+        such = " and ".join(sorted({kind.mixer for kind in self._recurrent})) + " layers"
         for option, on, why in (
                 ("prefix_cache", self.ec.prefix_cache,
-                 "a hit copies pages, and a page copy cannot restore the state a delta layer keeps of a prefix"),
+                 "a hit copies pages, and a page copy cannot restore the state such a layer keeps of a prefix"),
                 ("chunked_prefill", self.ec.chunked_prefill,
                  "a chunk would have to start from the state and the convolution tail the chunk before left, "
                  "and the prefill programs start from an empty one"),
                 ("tensor_parallel > 1", self.ec.tensor_parallel > 1,
                  "the state pool is addressed by the slot and its kernels run on one chip")):
             if self._recurrent and on:
-                raise ValueError(f"{option} is not written for delta layers: {why} (ROADMAP M4)")
+                raise ValueError(f"{option} is not written for {such}: {why} (ROADMAP M4)")
         if self._recurrent and self._window:
-            raise ValueError("window layers beside delta layers are not written: a prefill is told its slot's "
+            raise ValueError(f"window layers beside {such} are not written: a prefill is told its slot's "
                              "ring or its slot (ROADMAP M4)")
         if self.ec.max_seq <= 0:
             self.ec = dataclasses.replace(self.ec, max_seq=cfg.max_seq_len)
@@ -481,9 +488,11 @@ class LLMEngine:
         # By layer kind (``self._kind_pools``: a kind's pools in the tuple): a
         # kind without a window holds P_total pages for the page tables to
         # share out; one with a window holds a ring of pages a slot and
-        # nothing behind it, B x ring pages whatever the contexts; a delta kind
-        # holds no tokens: a state a slot in float32 [its layers, B, H, Hd, Hd]
-        # and its convolutions' last inputs [its layers, B, T - 1, 3, H, Hd].
+        # nothing behind it, B x ring pages whatever the contexts; a recurrent
+        # kind holds no tokens: a state a slot in float32 and its convolution's
+        # last inputs, [its layers, B, ...] each, the rest of the shape the
+        # kind's (slot_state_shapes). A head's row in a paged pool is
+        # ``kv_row_width`` columns, the paged kernel's rule.
         if cfg.latent:
             self._row_width = latent_row_width(cfg.kv_lora_rank, cfg.qk_rope_head_dim)
             pools = [((L, P_total * ps, self._row_width), _P(None, None, None))]
@@ -491,15 +500,16 @@ class LLMEngine:
             self._kind_pools = {cfg.kinds[0].name: slice(0, 1)}
         else:
             pools, self._kind_pools = [], {}
+            self._kv_width = kv_row_width(cfg.head_dim)
             for kind in cfg.kinds:
                 self._kind_pools[kind.name] = slice(len(pools), len(pools) + 2)
                 if kind.recurrent:
-                    per_slot = (cfg.layers_of(kind), B, kind.n_heads, cfg.head_dim)
-                    pools += [((*per_slot, cfg.head_dim), _P(), jnp.float32),
-                              ((*per_slot[:2], kind.conv_size - 1, 3, *per_slot[2:]), _P())]
+                    state, tail = slot_state_shapes(cfg, kind)
+                    pools += [((cfg.layers_of(kind), B, *state), _P(), jnp.float32),
+                              ((cfg.layers_of(kind), B, *tail), _P())]
                     continue
                 tokens = (B * ring_pages(kind.window, ps) if kind.window else P_total) * ps
-                pools += [((cfg.layers_of(kind), cfg.kv_heads, tokens, cfg.head_dim),
+                pools += [((cfg.layers_of(kind), cfg.kv_heads, tokens, self._kv_width),
                            _P(None, "tensor", None, None))] * 2
             self._tok_axis = 2
         self.cache = tuple(_pool_zeros(*pool) for pool in pools)
@@ -511,7 +521,7 @@ class LLMEngine:
         # layer's window (0: none), from a page's K and V as a device holds
         # them; the latent kernel takes one, and a step for an empty slot too.
         self._group = {0: 1} if cfg.latent else {
-            w: group_pages(cfg.kv_heads // max(tp, 1), ps, cfg.head_dim, self.cache[0].dtype.itemsize, self.ppseq, w)
+            w: group_pages(cfg.kv_heads // max(tp, 1), ps, self._kv_width, self.cache[0].dtype.itemsize, self.ppseq, w)
             for w in {0, self._window}}
         self.pool_bytes = {name: sum(pool.nbytes for pool in self.cache[sl]) for name, sl in self._kind_pools.items()}
         self.free_pages: deque = deque(range(1, P_total))  # page 0 = dead sink
@@ -693,7 +703,7 @@ class LLMEngine:
         layer kind: pages of its page table for a kind that keeps every token
         (``_write_pages``), its last window into the slot's ring for a kind
         with a window (``_write_ring``; ``place`` the slot's first ring
-        page), and for a delta kind the state and the convolution tail the
+        page), and for a recurrent kind the state and the convolution tail the
         prompt left, every layer's, into its slot (``place``) of the two
         pools: one in-place ``dynamic_update_slice`` a pool."""
         if not self._window and not self._recurrent:
@@ -729,26 +739,28 @@ class LLMEngine:
         keeps of them for the pools: the K and V rows of a head (attended
         inside the kind's window where it has one), or a latent layer's
         [c | k_rope] rows (expanded to keys and values here, for the prompt
-        alone), or for a delta layer the state and the convolution's last
+        alone), or for a recurrent layer the state and the convolution's last
         inputs as they stand at the prompt's ``length``: the bucket's padding
-        behind it leaves the state alone (beta 0, no decay)."""
+        behind it leaves the state alone (no step, no decay)."""
         cfg = self.cfg
         if kind.recurrent:
-            def rule(q, k, v, g, beta, window):
-                real = (seg == 0)[..., None]  # [1, P, 1]
-                with jax.named_scope("kda_chunk"):
-                    o, state = delta_rule()[0](q, k, v, jnp.where(real[..., None], g, 0.0),
-                                               jnp.where(real, beta, 0.0), out_dtype=v.dtype)
+            name, over_a_prompt, _ = recurrence(kind)
+
+            def rule(ops, window):
+                def real(a):  # the log decay or the step size [1, P, ...], zero behind the prompt's length
+                    return jnp.where((seg == 0).reshape(seg.shape + (1,) * (a.ndim - 2)), a, 0.0)
+                with jax.named_scope(f"{name}_chunk"):
+                    o, state = over_a_prompt(*ops[:-2], real(ops[-2]), real(ops[-1]), out_dtype=cfg.dtype)
                 # positions length - (T - 1) .. length - 1: the window leads with the T - 1 before position 0
                 tail = jax.lax.dynamic_slice_in_dim(window[0], length, kind.conv_size - 1, axis=0)
-                return o, (state[0], tail)
+                return o, (state[0], tail.reshape(slot_state_shapes(cfg, kind)[1]))
             return None, rule
         if not cfg.latent:
             k_dtype, v_dtype = dtypes[self._kind_pools[kind.name]]
 
             def attend(q, k, v):
                 o = _prompt_attention(q, k, v, seg, self.mesh, window=kind.window)
-                return o, (_kv_rows(k, k_dtype), _kv_rows(v, v_dtype))
+                return o, (_kv_rows(k, k_dtype, self._kv_width), _kv_rows(v, v_dtype, self._kv_width))
             return attend
 
         def attend(q, c, k_rope):
@@ -760,8 +772,8 @@ class LLMEngine:
     def _prefill_impl(self, params, cache, tokens, length, page_idxs, key, temp, top_p, top_k, place=None):
         """tokens: [P] (padded to the bucket); page_idxs: [P // ps] page ids
         (trailing entries may be 0 = dead sink); place: the slot's first ring
-        page, for a model with window layers, or the slot, for one with delta
-        layers. Returns the pools with the
+        page, for a model with window layers, or the slot, for one with
+        recurrent layers. Returns the pools with the
         prompt's pages written and the first generated token. Attention
         runs on the layer's fresh K/V, so the layer scan never sees a pool:
         it hands out every layer's rows as ``ys`` and the pages are written
@@ -769,7 +781,7 @@ class LLMEngine:
         cfg = self.cfg
         P = tokens.shape[0]
         with jax.named_scope("embed"):
-            x = params["embed"].astype(cfg.dtype)[tokens][None]  # [1,P,D]
+            x = embed_tokens(params, tokens, cfg)[None]  # [1,P,D]
         pos = jnp.arange(P, dtype=jnp.int32)[None]
         seg = (pos >= length).astype(jnp.int32)  # pads = their own segment
         dtypes = [pool.dtype for pool in cache]
@@ -783,7 +795,7 @@ class LLMEngine:
         with jax.named_scope("lm_head"):
             x = _rms_norm(x, params["final_norm"], cfg.norm_eps)
             last = jax.lax.dynamic_index_in_dim(x[0], length - 1, axis=0, keepdims=False)
-            logits = last @ params["lm_head"].astype(cfg.dtype)
+            logits = hidden_logits(params, last, cfg)
         with jax.named_scope("sample"):
             tok = sample_batch(logits.astype(jnp.float32)[None], temp[None], top_p[None],
                                top_k[None], key, cap=self.ec.sample_topk_cap)[0]
@@ -804,13 +816,15 @@ class LLMEngine:
         if kind.recurrent:
             state, tails = pools[sl]
             live = seen > 0
-            tail = jax.lax.dynamic_index_in_dim(tails, layer, 0, keepdims=False)  # [B, T - 1, 3, H, Hd]
+            tail = jax.lax.dynamic_index_in_dim(tails, layer, 0, keepdims=False)  # [B, T - 1, ...]
+            name, _, one_token = recurrence(kind)
 
-            def rule(q, k, v, g, beta, window):
+            def rule(ops, window):
                 # a slot without a request keeps its tail and its state as they were
-                kept = jnp.where(live[:, None, None, None, None], window[:, 1:].astype(tails.dtype), tail)
-                with jax.named_scope("kda_step"):
-                    o, new_state = delta_rule()[1](q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], state, layer, live)
+                kept = jnp.where(live.reshape((-1,) + (1,) * (tail.ndim - 1)),
+                                 window[:, 1:].astype(tails.dtype).reshape(tail.shape), tail)
+                with jax.named_scope(f"{name}_step"):
+                    o, new_state = one_token(*(a[:, 0] for a in ops), state, layer, live)
                 new_tails = jax.lax.dynamic_update_slice(tails, kept[None], (layer,) + (0,) * (tails.ndim - 1))
                 return o[:, None], pools[:sl.start] + (new_state, new_tails) + pools[sl.stop:]
             return tail, rule
@@ -828,7 +842,8 @@ class LLMEngine:
                 with jax.named_scope("window_attn" if kind.window else "paged_attn"):
                     # writes k_new / v_new at position lens of each slot's
                     # pages (page_tables[b, lens // ps], offset lens % ps; of
-                    # its ring, in a layer with a window)
+                    # its ring, in a layer with a window); the call pads q
+                    # and the token's rows where the pools' rows are wider
                     o, kp2, vp2 = paged_attend(
                         q[:, 0], k_new[:, 0], v_new[:, 0], *pools[sl], seen, page_tables, layer,
                     )  # o: [B, H, Hd]
@@ -857,11 +872,11 @@ class LLMEngine:
         """n_steps tokens for every slot in ONE device program (outer scan
         over steps, inner scan over layers): one host round trip per block.
         Returns (cache, toks [n_steps, B], last', lengths', counts): counts
-        is None for a model without held experts or delta layers. Held
+        is None for a model without held experts or recurrent layers. Held
         experts give two int32, the routed (token, expert) pairs that landed
         on held experts and the live tiles of the grouped matmul (a tile
         reads its expert's matrices), both summed over the block's steps and
-        the routed layers; delta layers one more behind them, the slots whose
+        the routed layers; recurrent layers one more behind them, the slots whose
         state a step rewrote (in each such layer), summed over the steps.
 
         How the pools are threaded (the rule: where the pools are made):
@@ -898,7 +913,7 @@ class LLMEngine:
                 walks = ({w: page_groups(seen, page_tables, ps, w, n) for w, n in sorted(self._group.items())}
                          if on_tpu else None)
             with jax.named_scope("embed"):
-                x = params["embed"].astype(cfg.dtype)[last][:, None, :]  # [B,1,D]
+                x = embed_tokens(params, last, cfg)[:, None, :]  # [B,1,D]
 
             def scan_fn(carry, lp, kind, layer):
                 h, pools = carry
@@ -913,12 +928,12 @@ class LLMEngine:
                 if aux is not None:
                     counts = jnp.sum(aux, axis=0) if counts is None else counts + jnp.sum(aux, axis=0)
             if self._recurrent:
-                # the slots whose state this step rewrote in every delta layer: the kda_step calls' grid
+                # the slots whose state this step rewrote in every recurrent layer: the step calls' grid
                 rows = jnp.sum(seen > 0, dtype=jnp.int32).reshape(1)
                 counts = rows if counts is None else jnp.concatenate([counts, rows])
             with jax.named_scope("lm_head"):
                 x = _rms_norm(x, params["final_norm"], cfg.norm_eps)
-                logits = jnp.einsum("bsd,dv->bv", x, params["lm_head"].astype(cfg.dtype))
+                logits = hidden_logits(params, x[:, 0], cfg)
             with jax.named_scope("sample"):
                 toks = sample_batch(logits.astype(jnp.float32), temps, top_ps, top_ks,
                                     step_key, cap=self.ec.sample_topk_cap)
@@ -950,7 +965,7 @@ class LLMEngine:
         request writes its pages into them in place (_write_pages).
         tokens: [k, P]; page_rows: [k, P // ps], each request's pages; places:
         [k], each request's slot's first ring page (a model with window
-        layers) or its slot (one with delta layers); None without either."""
+        layers) or its slot (one with recurrent layers); None without either."""
         keys = jax.random.split(key, tokens.shape[0])
 
         def scan_req(cache, xs):
@@ -981,7 +996,7 @@ class LLMEngine:
         ps = self.ec.page_size
         Tb = tokens.shape[0]
         C = ctx_pages.shape[0]
-        x = params["embed"].astype(cfg.dtype)[tokens][None]  # [1,Tb,D]
+        x = embed_tokens(params, tokens, cfg)[None]  # [1,Tb,D]
         tpos = jnp.arange(Tb, dtype=jnp.int32)
         pos = (start + tpos)[None]  # [1,Tb] absolute positions
         # Key-validity mask [Tb, C*ps + Tb]: context keys are valid iff
@@ -1000,10 +1015,10 @@ class LLMEngine:
             group = kind.n_heads // KV
 
             def attend(q, k_new, v_new):
-                kt = _kv_rows(k_new, dtypes[0])  # [KV,Tb,Hd]
-                vt = _kv_rows(v_new, dtypes[1])
-                kall = jnp.concatenate([ctx_k, kt], axis=1)  # [KV, C*ps+Tb, Hd]
-                vall = jnp.concatenate([ctx_v, vt], axis=1)
+                kt = _kv_rows(k_new, dtypes[0], self._kv_width)  # [KV,Tb,Hd], or as wide as the pool's rows
+                vt = _kv_rows(v_new, dtypes[1], self._kv_width)
+                kall = jnp.concatenate([ctx_k, kt], axis=1)[..., :Hd]  # [KV, C*ps+Tb, Hd]
+                vall = jnp.concatenate([ctx_v, vt], axis=1)[..., :Hd]
                 qg = q[0].reshape(Tb, KV, group, Hd)
                 scores = jnp.einsum("tkgh,ksh->tkgs", qg, kall).astype(jnp.float32)
                 scores = scores / math.sqrt(Hd)
@@ -1039,7 +1054,7 @@ class LLMEngine:
         cache = self._write_pages(cache, rows, tail_pages)
         x = _rms_norm(x, params["final_norm"], cfg.norm_eps)
         last = jax.lax.dynamic_index_in_dim(x[0], length - 1 - start, axis=0, keepdims=False)
-        logits = last @ params["lm_head"].astype(cfg.dtype)
+        logits = hidden_logits(params, last, cfg)
         toks = sample_batch(logits.astype(jnp.float32)[None], temp, top_p, top_k, key,
                             cap=self.ec.sample_topk_cap)
         return cache, toks  # toks: [1]
@@ -1142,7 +1157,7 @@ class LLMEngine:
                 )
                 if self._window or self._recurrent:
                     # slot 0's ring takes the dummy rows: a length masks whatever a ring held before;
-                    # slot 0's state, which the prefill of the slot's next request replaces
+                    # slot 0's state and tail, which the prefill of the slot's next request replaces
                     args += (jnp.zeros(k, jnp.int32),)
                 entry = {"program": "prefill", "bucket": b, "k": k}
                 if "prefill" not in self.mosaic:
@@ -1580,7 +1595,7 @@ class LLMEngine:
                 places = ()
                 if self._window:  # each slot's first ring page, the same in every window kind's pools
                     places = (jnp.asarray(np.asarray(idxs, np.int32) * self._ring_pages),)
-                elif self._recurrent:  # each request's slot, where a delta kind's pools keep its state
+                elif self._recurrent:  # each request's slot, where a recurrent kind's pools keep its state
                     places = (idx_arr,)
                     ph.rec["states_written"] += k
                 self.cache, toks_dev = self._prefill(bucket, k)(
@@ -1794,7 +1809,7 @@ class LLMEngine:
         to("decode_fetch")
         if blk.counts is None:
             block_toks = np.asarray(jax.device_get(blk.toks))  # [n, B]
-        else:  # held experts' counts, then delta layers': they ride the same fetch
+        else:  # held experts' counts, then recurrent layers': they ride the same fetch
             block_toks, counts = jax.device_get((blk.toks, blk.counts))
             if self.cfg.experts_held:
                 blk.rec["expert_pairs"], blk.rec["expert_tiles"] = int(counts[0]), int(counts[1])

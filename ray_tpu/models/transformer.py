@@ -34,9 +34,10 @@ from ray_tpu.parallel.sharding import with_logical_constraint as wlc
 @dataclasses.dataclass(frozen=True)
 class LayerKind:
     """One kind of layer in a model whose layers are not all alike: what mixes
-    a layer's tokens (softmax attention over cached rows, or a delta rule over
-    a state a head), its heads, its window and its rope. Two layers of one
-    kind have weights of one shape and cache what the same rule keeps."""
+    a layer's tokens (softmax attention over cached rows, or a recurrence over
+    a state a head: a delta rule, a selective state-space scan), its heads,
+    its window and its rope. Two layers of one kind have weights of one shape
+    and cache what the same rule keeps."""
     name: str  # the key of this kind's stack in params["kind_layers"], of its pools in the engine
     n_heads: int
     # "attention": softmax attention, query heads grouped over the model's KV
@@ -47,10 +48,21 @@ class LayerKind:
     # head in float32 and no token rows; the decay and the output gate
     # projected through low_rank columns; beta_scale 2 lets a step's
     # eigenvalue reach -1. No window, no rope, none of the model's attn_gate.
+    # "ssd": the selective state-space recurrence with a scalar decay a head
+    # (Mamba-2, ops/ssd.py): n_heads heads of head_width columns behind ONE
+    # input projection [z | x B C | dt], a causal depthwise convolution of
+    # conv_size taps with a bias over x, B and C together and SiLU; B and C
+    # [state_size] shared by the heads of each of n_groups groups; a state
+    # [state_size, heads x head_width] a layer in float32 and no token rows;
+    # a skip D x a head, the gate silu(z) applied before an RMS norm over all
+    # the heads' columns. No window, no rope, none of the model's attn_gate.
     mixer: str = "attention"
     conv_size: int = 0
     low_rank: int = 0
     beta_scale: float = 1.0
+    head_width: int = 0
+    state_size: int = 0
+    n_groups: int = 0
     # Position i sees j with i - window < j <= i (its own among them); 0: every j <= i.
     window: int = 0
     rope_theta: float = 10_000.0
@@ -71,7 +83,7 @@ class LayerKind:
     @property
     def recurrent(self) -> bool:
         """Keeps a state a slot that does not grow with the context, and no rows of tokens."""
-        return self.mixer == "delta"
+        return self.mixer in ("delta", "ssd")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -148,6 +160,18 @@ class TransformerConfig:
     # input, before the output projection: "per_head" one scalar a head (Wg
     # [D, H]), "elementwise" one a column (Wg [D, H, head_dim]).
     attn_gate: str = ""
+    # Four scalars of a model parametrised for width-independent learning
+    # rates, each off at its default (the program is then what it was):
+    # the embedding times embed_multiplier, every sublayer's output times
+    # residual_multiplier before it joins the residual, attention scores times
+    # attention_multiplier in place of head_dim^-1/2 (0: that), logits divided
+    # by logits_divisor. tie_embeddings: the head is the embedding, one array
+    # (no params["lm_head"]).
+    embed_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: float = 0.0
+    logits_divisor: float = 1.0
+    tie_embeddings: bool = False
 
     @property
     def kv_heads(self) -> int:
@@ -195,9 +219,14 @@ class TransformerConfig:
             assert len({k.name for k in self.kinds}) == len(self.kinds), "two kinds under one name"
             assert all(k.n_heads % self.kv_heads == 0 for k in self.kinds if not k.recurrent)
             for k in self.kinds:
-                assert k.mixer in ("attention", "delta"), k.mixer
-                assert not k.recurrent or (k.conv_size > 1 and k.low_rank > 0 and not k.window), (
+                assert k.mixer in ("attention", "delta", "ssd"), k.mixer
+                assert k.mixer != "delta" or (k.conv_size > 1 and k.low_rank > 0 and not k.window), (
                     f"a delta layer has a short convolution, low-rank sizes and no window: {k}")
+                assert k.mixer != "ssd" or (
+                    k.conv_size > 1 and k.head_width > 0 and k.state_size > 0 and k.n_groups > 0
+                    and k.n_heads % k.n_groups == 0 and not k.window), (
+                    f"an ssd layer has a short convolution, a head width, a state size, groups that divide its "
+                    f"heads and no window: {k}")
             assert len({self.kind_of(l) for l in range(self.n_dense_layers)}) <= 1, (
                 "the leading dense layers are one stack: of one kind")
             if (self.n_layers - self.n_dense_layers) % p:
@@ -240,7 +269,24 @@ def _init_stack(key: jax.Array, cfg: TransformerConfig, L: int, routed: bool, ki
     kind = kind or cfg.kinds[0]
     k = iter(jax.random.split(key, 24 if kind.recurrent else 16))
     D, F, H = cfg.d_model, cfg.d_ff, kind.n_heads
-    if kind.recurrent:
+    if kind.mixer == "ssd":
+        I, C, T = H * kind.head_width, ssd_conv_channels(kind), kind.conv_size
+        layer = {
+            "attn_norm": jnp.ones((L, D), pd),
+            # [z | x B C | dt] from D columns: the outputs' axis before the inputs', so that the minor dimension
+            # is whole lane tiles (as [D, 8512] the TPU compiler turned the stack round on every call, a copy of it)
+            "w_in": _dense_init(next(k), (L, I + C + H, D), pd, in_axis=2),
+            "conv": _dense_init(next(k), (L, T, C), pd, in_axis=1),  # the oldest input's tap first
+            "conv_bias": jax.random.uniform(next(k), (L, C), jnp.float32, -T ** -0.5, T ** -0.5).astype(pd),
+            "dt_bias": _inverse_softplus(jnp.exp(jax.random.uniform(
+                next(k), (L, H), jnp.float32, math.log(0.001), math.log(0.1)))).astype(pd),
+            "a_log": jnp.log(jax.random.uniform(next(k), (L, H), jnp.float32, 1.0, 16.0)).astype(pd),
+            "d_skip": jnp.ones((L, H), pd),
+            "o_norm": jnp.ones((L, I), pd),
+            "wo": _dense_init(next(k), (L, H, kind.head_width, D), pd, in_axis=(1, 2)),
+            "ffn_norm": jnp.ones((L, D), pd),
+        }
+    elif kind.recurrent:
         Hd, R, T = cfg.head_dim, kind.low_rank, kind.conv_size
         layer = {
             "attn_norm": jnp.ones((L, D), pd),
@@ -340,12 +386,13 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> dict:
     else:
         layer, k = _init_stack(key, cfg, cfg.n_layers - cfg.n_dense_layers, routed)
         stacks = {"layers": layer}
-    params = {
-        "embed": _dense_init(next(k), (cfg.vocab_size, D), pd) * (D ** 0.5),
-        **stacks,
-        "final_norm": jnp.ones((D,), pd),
-        "lm_head": _dense_init(next(k), (D, cfg.vocab_size), pd, in_axis=0),
-    }
+    if cfg.tie_embeddings:
+        # one array for both ends: a row as long as a head's column, which embed_multiplier is there to scale up
+        ends = {"embed": _dense_init(next(k), (cfg.vocab_size, D), pd, in_axis=1)}
+    else:
+        ends = {"embed": _dense_init(next(k), (cfg.vocab_size, D), pd) * (D ** 0.5),
+                "lm_head": _dense_init(next(k), (D, cfg.vocab_size), pd, in_axis=0)}
+    params = {"embed": ends.pop("embed"), **stacks, "final_norm": jnp.ones((D,), pd), **ends}
     if cfg.n_dense_layers:
         params["dense_layers"], _ = _init_stack(
             jax.random.fold_in(key, 1), cfg, cfg.n_dense_layers, routed=False, kind=cfg.kind_of(0))
@@ -354,6 +401,63 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> dict:
 
 HELD_EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
 L2_EPS = 1e-6  # under the root of a delta layer's query and key norms
+
+
+def ssd_conv_channels(kind: LayerKind) -> int:
+    """The columns an ssd layer's convolution runs over: x, B and C side by side."""
+    return kind.n_heads * kind.head_width + 2 * kind.n_groups * kind.state_size
+
+
+def slot_state_shapes(cfg: TransformerConfig, kind: LayerKind) -> tuple:
+    """What a layer of a recurrent kind keeps of a sequence, whatever its
+    length: (its state, float32; the last conv_size - 1 inputs of its short
+    convolution, in the activations' dtype). The engine's two pools of the
+    kind are these behind [the kind's layers, slots]. An ssd layer's T - 1
+    inputs lie end to end in one row: as [T - 1, channels] behind the slots a
+    pool's minor tile would be 3 rows of a tile's 8 or 16, and the TPU
+    compiler turned the whole pool round and back in every layer of a decode
+    step (my chip run, PR 46); the mixer folds the row."""
+    T = kind.conv_size
+    if kind.mixer == "ssd":
+        from ray_tpu.ops.ssd import state_shape
+
+        return state_shape(kind.n_heads, kind.head_width, kind.state_size), ((T - 1) * ssd_conv_channels(kind),)
+    return (kind.n_heads, cfg.head_dim, cfg.head_dim), (T - 1, 3, kind.n_heads, cfg.head_dim)
+
+
+def recurrence(kind: LayerKind) -> tuple:
+    """A recurrent kind's rule as (its name in a trace, over a prompt, one
+    token a slot): the kernels on a TPU backend, their ``jax.numpy`` forms
+    elsewhere. Both take the operands the kind's mixer hands its ``attend``
+    (the last two are the log decay and the step size: zero in both leaves a
+    state as it was), then a state or a pool."""
+    if kind.mixer == "ssd":
+        from ray_tpu.ops.ssd import ssd_rule
+
+        return ("ssd", *ssd_rule())
+    from ray_tpu.ops.linear_attention import delta_rule
+
+    return ("kda", *delta_rule())
+
+
+def embed_tokens(params: dict, tokens, cfg: TransformerConfig):
+    """tokens [...] int32 -> their embeddings [..., D] in cfg.dtype, as every program starts."""
+    x = params["embed"].astype(cfg.dtype)[tokens]
+    return x * cfg.embed_multiplier if cfg.embed_multiplier != 1.0 else x
+
+
+def head_matrix(params: dict, cfg: TransformerConfig):
+    """The head [D, V] in cfg.dtype: its own array, or the embedding turned."""
+    return (params["embed"].T if cfg.tie_embeddings else params["lm_head"]).astype(cfg.dtype)
+
+
+def hidden_logits(params: dict, x, cfg: TransformerConfig):
+    """Final-normed hidden states [..., D] -> logits [..., V], as every program ends."""
+    if cfg.tie_embeddings:
+        logits = jnp.einsum("...d,vd->...v", x, params["embed"].astype(cfg.dtype))
+    else:
+        logits = x @ params["lm_head"].astype(cfg.dtype)
+    return logits / cfg.logits_divisor if cfg.logits_divisor != 1.0 else logits
 
 
 def scan_stack(body, carry, stack: dict, cfg: TransformerConfig, *xs):
@@ -462,7 +566,14 @@ def run_layers(body, carry, params: dict, cfg: TransformerConfig, xs: dict | Non
 
 def _stack_logical_axes(cfg: TransformerConfig, routed: bool, kind: LayerKind | None = None) -> dict:
     """A stack's logical axes (attention kinds differ in sizes only)."""
-    if kind is not None and kind.recurrent:
+    if kind is not None and kind.mixer == "ssd":
+        layer = {
+            "attn_norm": ("layers", "embed"), "w_in": ("layers", None, "embed"), "conv": ("layers", None, None),
+            "conv_bias": ("layers", None), "dt_bias": ("layers", "heads"), "a_log": ("layers", "heads"),
+            "d_skip": ("layers", "heads"), "o_norm": ("layers", None),
+            "wo": ("layers", "heads", "head_dim", "embed"), "ffn_norm": ("layers", "embed"),
+        }
+    elif kind is not None and kind.recurrent:
         heads = ("layers", "embed", "heads", "head_dim")
         low = ("layers", None, "heads", "head_dim")
         layer = {
@@ -535,7 +646,7 @@ def param_logical_axes(cfg: TransformerConfig) -> dict:
         **({"kind_layers": {kind.name: _stack_logical_axes(cfg, routed, kind) for kind in dict.fromkeys(cfg.period)}}
            if cfg.layer_pattern else {"layers": _stack_logical_axes(cfg, routed)}),
         "final_norm": ("embed",),
-        "lm_head": ("embed", "vocab"),
+        **({} if cfg.tie_embeddings else {"lm_head": ("embed", "vocab")}),
     }
     if cfg.n_dense_layers:
         axes["dense_layers"] = _stack_logical_axes(cfg, routed=False, kind=cfg.kind_of(0))
@@ -882,7 +993,8 @@ def _delta_mixer(h, lp, cfg: TransformerConfig, kind: LayerKind, attend):
     ``attend`` is the program's side, a pair (tail, rule). tail: the T - 1
     inputs of the convolution before position 0, [B, T - 1, 3, H, Hd] (None:
     zeros, a sequence's start). rule(q, k, v, g, beta, window) -> (o
-    [B, S, H, Hd], kept): the delta rule over these positions on whatever
+    [B, S, H, Hd], kept), taken as rule((q, k, v, g, beta), window): the delta
+    rule over these positions on whatever
     state the program keeps (ops/linear_attention.py); window
     [B, T - 1 + S, 3, H, Hd] is the tail and the convolution's inputs behind
     it, of which a program keeps its next tail."""
@@ -907,10 +1019,54 @@ def _delta_mixer(h, lp, cfg: TransformerConfig, kind: LayerKind, attend):
         g = -jnp.exp(lp["a_log"].astype(jnp.float32))[:, None] * jax.nn.softplus(
             f.astype(jnp.float32) + lp["dt_bias"].astype(jnp.float32))
         beta = kind.beta_scale * jax.nn.sigmoid(jnp.einsum("bsd,dh->bsh", h, lp["wb"].astype(dt)).astype(jnp.float32))
-    o, kept = rule(q, k, v, g, beta, window)
+    o, kept = rule((q, k, v, g, beta), window)
     with jax.named_scope("out_gate"):
         gate = jnp.einsum("bsr,rhk->bshk", jnp.einsum("bsd,dr->bsr", h, lp["wg_a"].astype(dt)), lp["wg_b"].astype(dt))
         o = _rms_norm(o.astype(dt), lp["o_norm"], cfg.norm_eps) * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(dt)
+    return o, kept
+
+
+def _ssd_mixer(h, lp, cfg: TransformerConfig, kind: LayerKind, attend):
+    """An ssd layer's mixer on its normed input h [B, S, D]: one projection to
+    [z | x B C | dt], the short convolution with its bias and SiLU over x, B
+    and C together, dt = softplus(dt + dt_bias) and the log decay g =
+    -exp(a_log) dt a head in float32, the rule, the skip d_skip x, then the
+    gate silu(z) and an RMS norm over all the heads' columns (the gate first).
+    Returns (o [B, S, H, P] before the output projection, kept).
+
+    ``attend`` is the program's side, a pair (tail, rule), as for a delta
+    layer. tail: the T - 1 inputs of the convolution before position 0,
+    [B, T - 1, channels] or end to end as the engine's pool keeps them
+    [B, (T - 1) x channels] (None: zeros). rule((x, B, C, g, dt), window) -> (y
+    [B, S, H, P], kept): ops/ssd.py over these positions on whatever state the
+    program keeps; window [B, T - 1 + S, channels] is the tail and the
+    convolution's inputs behind it."""
+    dt_, T = h.dtype, kind.conv_size
+    B_, S = h.shape[:2]
+    H, P, G, N = kind.n_heads, kind.head_width, kind.n_groups, kind.state_size
+    I = H * P
+    tail, rule = attend
+    with jax.named_scope("in_proj"):
+        zxd = jnp.einsum("bsd,cd->bsc", h, lp["w_in"].astype(dt_))
+        z, u, dt = zxd[..., :I], zxd[..., I:-H], zxd[..., -H:]
+    with jax.named_scope("short_conv"):
+        if tail is None:
+            tail = jnp.zeros((B_, T - 1, u.shape[-1]), dt_)
+        window = jnp.concatenate([tail.astype(dt_).reshape(B_, T - 1, u.shape[-1]), u], axis=1)
+        taps = lp["conv"].astype(jnp.float32)
+        y = sum(window[:, j:j + S].astype(jnp.float32) * taps[j] for j in range(T)) + lp["conv_bias"].astype(jnp.float32)
+        y = jax.nn.silu(y).astype(dt_)
+        x = y[..., :I].reshape(B_, S, H, P)
+        Bm = y[..., I:I + G * N].reshape(B_, S, G, N)
+        Cm = y[..., I + G * N:].reshape(B_, S, G, N)
+    with jax.named_scope("decay"):
+        dt = jax.nn.softplus(dt.astype(jnp.float32) + lp["dt_bias"].astype(jnp.float32))
+        g = -jnp.exp(lp["a_log"].astype(jnp.float32)) * dt
+    o, kept = rule((x, Bm, Cm, g, dt), window)
+    with jax.named_scope("out_gate"):
+        o = o.astype(jnp.float32) + lp["d_skip"].astype(jnp.float32)[:, None] * x.astype(jnp.float32)
+        o = (o.reshape(B_, S, I) * jax.nn.silu(z.astype(jnp.float32))).astype(dt_)
+        o = _rms_norm(o, lp["o_norm"], cfg.norm_eps).reshape(B_, S, H, P)
     return o, kept
 
 
@@ -931,15 +1087,21 @@ def decoder_block(x, lp, cfg: TransformerConfig, positions, attend, kind: LayerK
     what such a layer caches; the attention side expands them over a prompt
     (``latent_expand``) or absorbs the projections in decode. ``kept`` is
     whatever the attention side wants handed out of the layer (a prompt's
-    rows, the carried pools, None). A "delta" kind's ``attend`` is a pair
-    (``_delta_mixer`` says of what). Returns (x, aux, kept): aux is the MoE
+    rows, the carried pools, None). A recurrent kind's ``attend`` is a pair
+    (``_delta_mixer`` and ``_ssd_mixer`` say of what). Returns (x, aux, kept): aux is the MoE
     balance term of a training layer, a zero for a dense one, and the
     [pairs, live tiles] counts of a layer that serves held experts."""
     eps = cfg.norm_eps
     dt = x.dtype
     kind = kind or cfg.kinds[0]
+
+    def joined(x, out):
+        """x + the sublayer's output, times the model's multiplier."""
+        return x + (out * cfg.residual_multiplier if cfg.residual_multiplier != 1.0 else out)
+
     if kind.recurrent:
-        o, kept = _delta_mixer(_rms_norm(x, lp["attn_norm"], eps), lp, cfg, kind, attend)
+        mixer = _ssd_mixer if kind.mixer == "ssd" else _delta_mixer
+        o, kept = mixer(_rms_norm(x, lp["attn_norm"], eps), lp, cfg, kind, attend)
     elif cfg.latent:
         q, k, v = _latent_qkv(_rms_norm(x, lp["attn_norm"], eps), lp, cfg, positions)
     else:
@@ -952,6 +1114,10 @@ def decoder_block(x, lp, cfg: TransformerConfig, positions, attend, kind: LayerK
             k = wlc(k, ("batch", "seq", "kv_heads", "head_dim"))
             q = _rope_kind(q, positions, kind)
             k = _rope_kind(k, positions, kind)
+            if cfg.attention_multiplier:
+                # every attention here scales by head_dim^-1/2: the rest of the model's own scale rides on q
+                # (1/8 for a multiplier of 1/64 on heads of 64: a power of two, exact in any dtype)
+                q = q * jnp.asarray(cfg.attention_multiplier * math.sqrt(cfg.head_dim), q.dtype)
     if not kind.recurrent:
         o, kept = attend(q, k, v)
     if cfg.attn_gate and not kind.recurrent:
@@ -967,7 +1133,7 @@ def decoder_block(x, lp, cfg: TransformerConfig, positions, attend, kind: LayerK
         a = jnp.einsum("bshk,hkd->bsd", o, lp["wo"].astype(dt))
         if cfg.sandwich_norm:
             a = _rms_norm(a, lp["post_attn_norm"], eps)
-        x = x + a
+        x = joined(x, a)
     with jax.named_scope("ffn"):
         h = _rms_norm(x, lp["ffn_norm"], eps)
         if "router" not in lp:
@@ -978,20 +1144,23 @@ def decoder_block(x, lp, cfg: TransformerConfig, positions, attend, kind: LayerK
             ffn_out, aux = _moe_ffn(h, lp, cfg)
         if cfg.sandwich_norm:
             ffn_out = _rms_norm(ffn_out, lp["post_ffn_norm"], eps)
-        x = x + ffn_out
+        x = joined(x, ffn_out)
     x = wlc(x, ("batch", "seq", "embed"))
     return x, aux, kept
 
 
-def _whole_sequence_rule(q, k, v, g, beta, _window, cfg: TransformerConfig):
-    """A delta layer's rule over whole sequences from an empty state, nothing
-    kept: the chunked kernel where the configured implementation is a kernel's
-    and the backend a TPU's (forward only: the kernel has no backward pass),
-    its ``jax.numpy`` form for "reference" and everywhere else."""
-    from ray_tpu.ops.linear_attention import kda_chunk, kda_chunk_reference
+def _whole_sequence_rule(ops, _window, cfg: TransformerConfig, kind: LayerKind):
+    """A recurrent layer's rule over whole sequences from an empty state,
+    nothing kept: the chunked kernel where the configured implementation is a
+    kernel's and the backend a TPU's (forward only: the kernels have no
+    backward pass), its ``jax.numpy`` form for "reference" and everywhere else."""
+    if kind.mixer == "ssd":
+        from ray_tpu.ops.ssd import ssd_chunk as chunk, ssd_chunk_reference as chunk_reference
+    else:
+        from ray_tpu.ops.linear_attention import kda_chunk as chunk, kda_chunk_reference as chunk_reference
 
     kernel = cfg.attention_impl in ("auto", "flash") and jax.default_backend() == "tpu"
-    return (kda_chunk if kernel else kda_chunk_reference)(q, k, v, g, beta, out_dtype=v.dtype)[0], None
+    return (chunk if kernel else chunk_reference)(*ops, out_dtype=cfg.dtype)[0], None
 
 
 def _layer(x, lp, cfg: TransformerConfig, positions, segment_ids=None, kind: LayerKind | None = None):
@@ -1008,9 +1177,9 @@ def _layer(x, lp, cfg: TransformerConfig, positions, segment_ids=None, kind: Lay
     if kind is not None and kind.recurrent:
         if segment_ids is not None:
             raise NotImplementedError(
-                "packed sequences are not written for a delta layer: its state and its convolution would have to "
-                "start again at each document's first position (ROADMAP M4)")
-        attend = (None, functools.partial(_whole_sequence_rule, cfg=cfg))
+                f"packed sequences are not written for a {kind.mixer} layer: its state and its convolution would "
+                "have to start again at each document's first position (ROADMAP M4)")
+        attend = (None, functools.partial(_whole_sequence_rule, cfg=cfg, kind=kind))
     x, aux, _ = decoder_block(x, lp, cfg, positions, attend, kind)
     # A layer that serves held experts hands out counts, not a loss term.
     return x, (jnp.zeros((), jnp.float32) if cfg.experts_held else aux)
@@ -1021,7 +1190,7 @@ def forward_hidden(params: dict, tokens: jax.Array, cfg: TransformerConfig,
     """tokens [B, S] int32 -> (final-norm hidden states [B, S, D], moe_aux).
     The shared trunk of forward() and the chunked-CE training loss."""
     B, S = tokens.shape
-    x = params["embed"].astype(cfg.dtype)[tokens]
+    x = embed_tokens(params, tokens, cfg)
     x = wlc(x, ("batch", "seq", "embed"))
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
@@ -1049,7 +1218,7 @@ def forward(params: dict, tokens: jax.Array, cfg: TransformerConfig,
     segments) and per-segment-restarting ``positions`` [B, S] for RoPE.
     """
     x, aux = forward_hidden(params, tokens, cfg, segment_ids, positions)
-    logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"].astype(cfg.dtype))
+    logits = hidden_logits(params, x, cfg)
     logits = wlc(logits, ("batch", "seq", "vocab"))
     # Keep logits in activation dtype: at vocab=32k the fp32 copy alone is
     # O(GBs) of HBM; the loss upcasts per-reduction instead.
@@ -1135,9 +1304,9 @@ def cross_entropy_loss(params, batch, cfg: TransformerConfig):
             segment_ids=None if segs is None else segs[:, :-1],
             positions=None if pos is None else pos[:, :-1],
         )
-        loss = _ce_chunked(
-            x, params["lm_head"].astype(cfg.dtype), targets, mask, cfg.ce_chunk
-        )
+        head = head_matrix(params, cfg)
+        loss = _ce_chunked(x, head / cfg.logits_divisor if cfg.logits_divisor != 1.0 else head, targets, mask,
+                           cfg.ce_chunk)
     else:
         logits, aux = forward(
             params, inputs, cfg,
@@ -1240,7 +1409,7 @@ def make_pipeline_train_step(cfg: TransformerConfig, mesh, n_micro: int, optimiz
         B, S = inputs.shape
         if B % n_micro:
             raise ValueError(f"batch {B} not divisible by n_micro={n_micro}")
-        x = params["embed"].astype(cfg.dtype)[inputs]
+        x = embed_tokens(params, inputs, cfg)
         mb = B // n_micro
         xm = x.reshape(n_micro, mb, S, x.shape[-1])
 
@@ -1256,7 +1425,7 @@ def make_pipeline_train_step(cfg: TransformerConfig, mesh, n_micro: int, optimiz
         )
         h = h.reshape(B, S, -1)
         h = _rms_norm(h, params["final_norm"], cfg.norm_eps)
-        logits = jnp.einsum("bsd,dv->bsv", h, params["lm_head"].astype(cfg.dtype))
+        logits = hidden_logits(params, h, cfg)
         mask = batch.get("mask")
         return _ce_from_logits(logits, targets, None if mask is None else mask[:, 1:])
 

@@ -104,7 +104,10 @@ def paged_attention_reference(q, k_new, v_new, k_pages, v_pages, lengths, page_i
     sequence); page_indices: [B, pages_per_seq]; layer: scalar index into L
     -> (o [B, H, D], k_pages, v_pages) with the token written at position
     lengths - 1 of each sequence, and for a row of length 0 nothing written
-    and zeros returned."""
+    and zeros returned. A pool's rows may be wider than a head
+    (``kv_row_width``)."""
+    Hd = q.shape[-1]
+    q, k_new, v_new, scale = _to_pool_rows(q, k_new, v_new, k_pages, scale)
     B, H, D = q.shape
     _, KV, _, ps, _ = k_pages.shape
     group = H // KV
@@ -125,7 +128,7 @@ def paged_attention_reference(q, k_new, v_new, k_pages, v_pages, lengths, page_i
     valid = (jnp.arange(ppseq * ps)[None, :] < lengths[:, None])[:, None, None, :]
     s = jnp.where(valid, s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
-    o = jnp.einsum("bkgs,bksd->bkgd", p, v).reshape(B, H, D)
+    o = _head_columns(jnp.einsum("bkgs,bksd->bkgd", p, v).reshape(B, H, D), Hd)
     return jnp.where((lengths > 0)[:, None, None], o, 0), k_pages, v_pages
 
 
@@ -160,6 +163,8 @@ def window_attention_reference(q, k_new, v_new, k_pages, v_pages, lengths, layer
     b * ring ..; the token written at ring row (lengths - 1) % (ring * ps),
     then every ring row attended whose position, the newest one congruent to
     it at most lengths - 1, lies inside the window. -> (o, k_pages, v_pages)."""
+    Hd = q.shape[-1]
+    q, k_new, v_new, scale = _to_pool_rows(q, k_new, v_new, k_pages, scale)
     B, H, D = q.shape
     _, KV, n_ring, ps, _ = k_pages.shape
     rows = n_ring // B * ps  # a sequence's ring, in rows
@@ -178,7 +183,7 @@ def window_attention_reference(q, k_new, v_new, k_pages, v_pages, lengths, layer
     s = jnp.einsum("bkgd,kbsd->bkgs", q.reshape(B, KV, group, D), k).astype(jnp.float32) * scale
     s = jnp.where(valid[:, None, None, :], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
-    o = jnp.einsum("bkgs,kbsd->bkgd", p, v).reshape(B, H, D)
+    o = _head_columns(jnp.einsum("bkgs,kbsd->bkgd", p, v).reshape(B, H, D), Hd)
     return jnp.where((lengths > 0)[:, None, None], o, 0), k_pages, v_pages
 
 
@@ -263,6 +268,38 @@ def group_pages(kv: int, page_size: int, head_dim: int, itemsize: int, n_pages: 
     size at every G."""
     page_bytes = 2 * kv * page_size * (head_dim + -head_dim % 128) * itemsize
     return max(1, min(GROUP_MOST, reach_pages(n_pages, page_size, window), GROUP_VMEM_BYTES // (2 * page_bytes)))
+
+
+def kv_row_width(head_dim: int) -> int:
+    """The columns a caller gives a head's K or V row in the pools: whole lane
+    tiles of 128 on a TPU backend, where the kernel copies whole pages out of
+    HBM and a row there is a whole number of lane tiles; the head's own
+    elsewhere, where the references take any width. A head of 64 then lies in
+    rows of 128, at twice the bytes, and the kernel takes the pool as it lies
+    (padding a narrow pool at the call moved both pools on every call). The
+    padding columns hold zeros: the three calls of this module pad q and the
+    token's rows to the pool's rows with zeros, so no score moves, and cut the
+    output back to the head."""
+    return head_dim + -head_dim % 128 if jax.default_backend() == "tpu" else head_dim
+
+
+def _to_pool_rows(q, k_new, v_new, k_pages, scale):
+    """(q, k_new, v_new, scale) for pools whose rows are wider than a head
+    (``kv_row_width``): the three zero-padded to the pool's rows, and the
+    head's own scale told; as they came where the widths agree."""
+    D, width = q.shape[-1], k_pages.shape[-1]
+    if width == D:
+        return q, k_new, v_new, scale
+
+    def wide(a):
+        return jnp.pad(a, ((0, 0),) * (a.ndim - 1) + ((0, width - D),))
+
+    return wide(q), wide(k_new), wide(v_new), scale if scale is not None else 1.0 / math.sqrt(D)
+
+
+def _head_columns(o, head_dim: int):
+    """o cut back from the pool's rows to the head's columns."""
+    return o if o.shape[-1] == head_dim else o[..., :head_dim]
 
 
 def _chunk_pages(kv: int, group: int) -> int:
@@ -572,7 +609,9 @@ def paged_attention(q, k_new, v_new, k_pages, v_pages, lengths, page_indices, la
     (``group_pages``); built here without it; window:
     the layer's attention window (0: none), whose pools hold rings
     ([L, KV, B * ring_pages(window, page_size), page_size, D]: the module's
-    docstring) and of whose page_indices the width alone is read.
+    docstring) and of whose page_indices the width alone is read. The pools'
+    rows are ``kv_row_width`` of q's D columns wide (a narrower pool is
+    refused: padding it here moved both pools on every call).
 
     Returns (o [B, H, D], k_pages, v_pages): the token's K/V lies at position
     lengths - 1 of each sequence's pages in the returned pools, which alias
@@ -591,6 +630,8 @@ def paged_attention(q, k_new, v_new, k_pages, v_pages, lengths, page_indices, la
     0). Without the explicit map jax refuses to lower the call: GSPMD cannot
     partition a Mosaic kernel.
     """
+    Hd = q.shape[-1]
+    q, k_new, v_new, scale = _to_pool_rows(q, k_new, v_new, k_pages, scale)
     shards = mesh.shape.get(head_axis, 1) if mesh is not None else 1
     if walk is None:
         _, kv, _, ps, d = k_pages.shape
@@ -606,7 +647,7 @@ def paged_attention(q, k_new, v_new, k_pages, v_pages, lengths, page_indices, la
             return paged_attention(*args[:8], scale=scale, interpret=interpret, walk=args[8:])
 
         heads, pool = P(None, head_axis, None), P(None, head_axis, None, None, None)
-        return jax.shard_map(
+        o, k_pages, v_pages = jax.shard_map(
             inner,
             mesh=mesh,
             in_specs=(heads, heads, heads, pool, pool, P(None), P(None, None), P(),
@@ -615,6 +656,7 @@ def paged_attention(q, k_new, v_new, k_pages, v_pages, lengths, page_indices, la
             check_vma=False,
         )(q, k_new, v_new, k_pages, v_pages, lengths, page_indices,
           jnp.asarray(layer, jnp.int32), *walk)
+        return _head_columns(o, Hd), k_pages, v_pages
     B, H, D = q.shape
     KV = k_pages.shape[1]
     if H % KV:
@@ -626,18 +668,10 @@ def paged_attention(q, k_new, v_new, k_pages, v_pages, lengths, page_indices, la
             f"paged_attention needs a TPU backend (or interpret=True); this "
             f"process runs on {jax.default_backend()!r}"
         )
-    if D % 128:
-        # The kernel copies whole pages out of HBM, where a row is a whole
-        # number of lane tiles: a smaller head is attended zero-padded to
-        # one. That moves both pools, as XLA's relayout of a narrow pool did
-        # for the call before PR 42 (chip_smoke's model; no serve cell).
-        def lanes(x):
-            return jnp.pad(x, ((0, 0),) * (x.ndim - 1) + ((0, -D % 128),))
-
-        o, k_pages, v_pages = paged_attention(
-            lanes(q), lanes(k_new), lanes(v_new), lanes(k_pages), lanes(v_pages), lengths, page_indices,
-            layer, scale=scale, interpret=interpret, walk=walk, window=window)
-        return o[..., :D], k_pages[..., :D], v_pages[..., :D]
+    if D % 128 and not interpret:
+        raise ValueError(
+            f"paged_attention: the pools' rows are {D} columns, not whole lane tiles of 128, which the kernel "
+            f"copies out of HBM: allocate them kv_row_width(head_dim) wide")
     # Sublane-pad the group axis up to a multiple of 8, the rows of the
     # kernel's f32 score and accumulator tiles (a group of 9 takes two). q
     # itself may be bf16 (tile 16 rows): Mosaic compiles the 8-row block as
@@ -657,4 +691,4 @@ def paged_attention(q, k_new, v_new, k_pages, v_pages, lengths, page_indices, la
     )
     # a row without a sequence had no step and was never written
     o = jnp.where((lengths > 0)[:, None, None], o[:, :, :group].reshape(B, H, D), 0)
-    return o, k_pages, v_pages
+    return _head_columns(o, Hd), k_pages, v_pages

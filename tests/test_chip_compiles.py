@@ -70,17 +70,20 @@ def test_the_paged_call_lowers_for_a_v5e(shape, group, one_chip, no_compile_cach
     def arr(dims, dtype):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
-    pool = arr((L, KV, P_total, ps, D), jnp.bfloat16)
+    # a head under a lane tile lies in rows of a whole one from allocation (kv_row_width), q and the token's rows
+    # alone are padded by the call; a pool as narrow as such a head is refused
+    pool = arr((L, KV, P_total, ps, pa.kv_row_width(D)), jnp.bfloat16)
     args = (arr((B, H, D), jnp.bfloat16), arr((B, KV, D), jnp.bfloat16), arr((B, KV, D), jnp.bfloat16),
             pool, pool, arr((B,), jnp.int32), arr((B, n_pages), jnp.int32), arr((), jnp.int32))
     compiled = jax.jit(_call_with_group(group), donate_argnums=(3, 4)).lower(*args).compile()
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == 1  # one Mosaic call, the walk beside it plain XLA
-    if D % 128 == 0:
-        # nothing but the call's operands: no copy of a pool (a pool is
-        # 0.3-2.4 GB here). A head size under a lane tile is padded up to
-        # one by the call (by its operand layout before PR 42), pools and all.
-        assert compiled.memory_analysis().temp_size_in_bytes < 4 << 20
+    # nothing but the call's operands: no copy of a pool (a pool is 0.1-2.4 GB here)
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 << 20
+    if D % 128:
+        narrow = arr((L, KV, P_total, ps, D), jnp.bfloat16)
+        with pytest.raises(ValueError, match="allocate them kv_row_width"):
+            jax.jit(_call_with_group(group)).lower(*args[:3], narrow, narrow, *args[5:])
 
 
 @pytest.mark.parametrize("group", [None, 1], ids=["its_own_group", "a_page_a_step"])
@@ -282,3 +285,92 @@ def test_the_decode_program_of_a_model_with_delta_layers_compiles_for_a_v5e(one_
     state, tails = eng.cache[2:]
     assert state.shape == (3, 16, 8, 128, 128) and state.dtype == jnp.float32 and state.nbytes == 25_165_824
     assert compiled.memory_analysis().temp_size_in_bytes < state.nbytes // 3  # not one layer's states
+
+
+def test_the_state_space_rules_two_calls_lower_for_a_v5e(one_chip, no_compile_cache, monkeypatch):
+    """ops/ssd.py at the serve cell's shapes. ``ssd_step``: 64 slots of 64
+    heads of 64 columns and a state size of 128, 36 layers' states in one pool
+    of 4.8 GB that is aliased and not copied, the grid a runtime value.
+    ``ssd_chunk``: one prompt of 512 and of 1,536 positions (the cell's largest
+    bucket), x, B and C in bfloat16 and the decays float32, 32 heads a grid
+    step, two a lane tile; and of 8 heads of 128 columns, one a lane tile."""
+    from ray_tpu.ops import ssd
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    B, H, P, N, L = 64, 64, 64, 128, 36
+
+    def arr(dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    shared = arr((B, 1, N), jnp.bfloat16)
+    compiled = jax.jit(ssd.ssd_step, donate_argnums=(5,)).lower(
+        arr((B, H, P), jnp.bfloat16), shared, shared, arr((B, H)), arr((B, H)), arr((L, B, N, H * P)),
+        arr((), jnp.int32), arr((B,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1 and "ssd_step" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 << 20  # the rows' operands: 2 MB each; a slot's state is 2 MB
+
+    for S, H, P in ((512, 64, 64), (1536, 64, 64), (256, 8, 128)):
+        compiled = jax.jit(ssd.ssd_chunk).lower(
+            arr((1, S, H, P), jnp.bfloat16), arr((1, S, 1, N), jnp.bfloat16), arr((1, S, 1, N), jnp.bfloat16),
+            arr((1, S, H)), arr((1, S, H))).compile()
+        text = compiled.as_text()
+        assert text.count("tpu_custom_call") == 1 and "ssd_chunk" in text
+        assert compiled.memory_analysis().temp_size_in_bytes < S * H * P * 4  # not x by head in float32
+
+
+def test_the_decode_program_of_a_model_with_state_space_layers_compiles_for_a_v5e(one_chip, no_compile_cache,
+                                                                                  monkeypatch):
+    """llm/engine.py ``_decode_impl`` of a model with four state-space layers
+    around one softmax layer of 64-wide heads a period, two periods, at the
+    serve cell's mixer widths and otherwise small ones: the period scan around
+    one paged call and four ``ssd_step`` calls. The page pools' rows are a
+    whole lane tile wide from allocation (ops ``kv_row_width`` asks the backend,
+    which is steered before the engine is made), so the paged call takes them
+    as they lie; the state pool (2 MB a layer a slot) and the page pools are
+    carried and aliased, and no copy of either is among the temporaries; the
+    input projection's stack is no operand of a copy (stored [outputs, D])."""
+    from ray_tpu.llm.engine import EngineConfig, LLMEngine
+    from ray_tpu.models.transformer import LayerKind, TransformerConfig, init_params
+
+    attention = LayerKind("attention", 32, rope_share=0.0)
+    mamba = LayerKind("mamba", 64, mixer="ssd", conv_size=4, head_width=64, state_size=128, n_groups=1)
+    cfg = TransformerConfig(
+        vocab_size=1024, d_model=2048, n_layers=10, n_heads=32, n_kv_heads=8, head_dim=64, d_ff=512, max_seq_len=2048,
+        param_dtype=jnp.bfloat16, norm_eps=1e-5, layer_pattern=(mamba, mamba, attention, mamba, mamba),
+        embed_multiplier=12.0, residual_multiplier=0.22, attention_multiplier=1 / 64, logits_divisor=8.0,
+        tie_embeddings=True)
+
+    def on_chip(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    params = jax.tree.map(on_chip, jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the engine asks how wide a pool's rows are
+    eng = LLMEngine(cfg, params=params, engine_config=EngineConfig(
+        max_slots=8, max_seq=2048, page_size=128, total_pages=40, prefill_buckets=(512,), decode_block=8))
+    state, tails, k_pages, v_pages = eng.cache
+    assert state.shape == (8, 8, 128, 4096) and state.dtype == jnp.float32 and tails.shape == (8, 8, 3 * 4352)
+    assert k_pages.shape == v_pages.shape == (2, 8, 40 * 128, 128)  # a head of 64 in rows of 128
+    B = eng.ec.max_slots
+    ints, floats = on_chip(jnp.zeros(B, jnp.int32)), on_chip(jnp.zeros(B, jnp.float32))
+    compiled = eng._decode_jit.lower(
+        params, tuple(on_chip(pool) for pool in eng.cache), ints, ints, on_chip(eng.d_page_tables),
+        on_chip(jax.random.PRNGKey(0)), 8, floats, floats, ints).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1 + 4 and "ssd_step" in text and "paged_attn" in text
+    # the state pool is 537 MB, a page pool 21 MB, the input projections' stack 279 MB
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
+    # the prefill program of the one bucket: each of a period's four chunked calls is an instruction of its own (a
+    # call fused into the consumer that stacks the layers' states is no kernel to a trace's reader), the flash call
+    # beside them, and the padded K and V rows are written into pools that are not turned round to meet them
+    i32 = lambda *dims: on_chip(jnp.zeros(dims, jnp.int32))
+    f32 = lambda *dims: on_chip(jnp.zeros(dims, jnp.float32))
+    compiled = eng._prefill(512, 1).lower(
+        params, tuple(on_chip(pool) for pool in eng.cache), i32(1, 512), i32(1), i32(1, 4), on_chip(jax.random.PRNGKey(0)),
+        f32(1), f32(1), i32(1), i32(1)).compile()
+    lines = compiled.as_text().splitlines()
+    assert sum(" custom-call(" in line and "%ssd_chunk" in line.split("=")[0] for line in lines) == 4
+    assert sum("tpu_custom_call" in line for line in lines) == 4 + 1
+    pool = "bf16[2,8,5120,128]"
+    assert not [line for line in lines if " copy(" in line and line.split("=")[1].lstrip().startswith(pool)]
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20

@@ -462,3 +462,36 @@ def test_the_window_kernel_matches_its_reference_at_every_page_group(lengths, gr
             for pool_out, pool_in in zip(got[1:], pools):
                 np.testing.assert_array_equal(np.asarray(pool_out[:, :, b * ring:(b + 1) * ring]),
                                               np.asarray(pool_in[:, :, b * ring:(b + 1) * ring]))
+
+
+@pytest.mark.parametrize("window", [0, 40], ids=["every_page", "a_ring"])
+def test_pools_whose_rows_are_wider_than_a_head_give_the_narrow_pools_answer(window):
+    """``kv_row_width``: a head of 64 in rows of 128, zeros behind it, as a
+    TPU's pools lie from allocation. The kernel and both references pad q and
+    the token's rows themselves, keep the head's own scale (64^-1/2, not
+    128^-1/2), write the padded row and return the head's 64 columns: the
+    narrow pools' output, and the narrow pools' rows with zeros behind them."""
+    rng = np.random.default_rng(7 + window)
+    lengths = (5, 40, 150, 0)
+    B, KV, H, D, ps, wide = len(lengths), 2, 4, 64, 16, 128
+    n_pages = B * ring_pages(window, ps) if window else 1 + B * 10
+    q = jnp.asarray(rng.normal(size=(B, H, D)), jnp.float32)
+    new = jnp.asarray(rng.normal(size=(2, B, KV, D)), jnp.float32)
+    pools = jnp.asarray(rng.normal(size=(2, L, KV, n_pages, ps, D)), jnp.float32)
+    padded = jnp.pad(pools, ((0, 0),) * 5 + ((0, wide - D),))
+    lens = jnp.asarray(lengths, jnp.int32)
+    table = jnp.asarray([[0] * 10 if not n else list(range(1 + 10 * b, 11 + 10 * b)) for b, n in enumerate(lengths)],
+                        jnp.int32)
+    if window:
+        want = window_attention_reference(q, *new, *pools, lens, 1, window)
+        reference = window_attention_reference(q, *new, *padded, lens, 1, window)
+    else:
+        want = paged_attention_reference(q, *new, *pools, lens, table, 1)
+        reference = paged_attention_reference(q, *new, *padded, lens, table, 1)
+    kernel = paged_attention(q, *new, *padded, lens, table, 1, window=window, interpret=True)
+    for got, tol in ((reference, 1e-6), (kernel, 2e-3)):
+        assert got[0].shape == (B, H, D)
+        _assert_same(got[:1], want[:1], tol)
+        for pool_got, pool_want in zip(got[1:], want[1:]):
+            np.testing.assert_array_equal(np.asarray(pool_got[..., :D]), np.asarray(pool_want))
+            assert not np.asarray(pool_got[..., D:]).any()
