@@ -124,7 +124,7 @@ from ray_tpu.models.transformer import (
 )
 from ray_tpu.ops.latent_attention import latent_attention_reference, latent_paged_attention, latent_row_width
 from ray_tpu.ops.paged_attention import (
-    group_pages, kv_row_width, live_pages, page_groups, paged_attention, paged_attention_reference, ring_pages,
+    group_pages, kv_row_width, page_groups, paged_attention, paged_attention_reference, ring_pages,
     window_attention_reference,
 )
 from ray_tpu.util import tracing as _tracing
@@ -517,12 +517,12 @@ class LLMEngine:
         self._slot_pools = {i for kind in self._recurrent for i in range(len(pools))[self._kind_pools[kind.name]]}
         # stats()["startup"]: what each kind's pools take
         self._ring_pages = ring_pages(self._window, ps) if self._window else 0
-        # The pages of a sequence the paged kernel takes a grid step, by the
-        # layer's window (0: none), from a page's K and V as a device holds
-        # them; the latent kernel takes one, and a step for an empty slot too.
-        self._group = {0: 1} if cfg.latent else {
-            w: group_pages(cfg.kv_heads // max(tp, 1), ps, self._kv_width, self.cache[0].dtype.itemsize, self.ppseq, w)
-            for w in {0, self._window}}
+        # The pages of a sequence a paged kernel takes a grid step, by the
+        # layer's window (0: none), from a page as a device holds it: K and V
+        # rows of its KV heads, or a latent layer's one row.
+        heads, width = (1, self._row_width) if cfg.latent else (cfg.kv_heads // max(tp, 1), self._kv_width)
+        self._group = {w: group_pages(heads, ps, width, self.cache[0].dtype.itemsize, self.ppseq, w)
+                       for w in {0, self._window}}
         self.pool_bytes = {name: sum(pool.nbytes for pool in self.cache[sl]) for name, sl in self._kind_pools.items()}
         self.free_pages: deque = deque(range(1, P_total))  # page 0 = dead sink
         self.page_tables = np.zeros((B, self.ppseq), np.int32)
@@ -900,18 +900,13 @@ class LLMEngine:
             # lengths change between steps and not between layers.
             seen = lens + 1  # the kernel's lengths count the step's own token
             on_tpu = jax.default_backend() == "tpu"
-            if cfg.latent:
-                # the latent kernel's walk, a page a step: a slot with no pages
-                # is held at one step on dead page 0
-                walks = {0: live_pages(seen, page_tables, ps)} if on_tpu else None
-            else:
-                # 0 for a slot with no pages (empty, or masked while it
-                # prefills): no grid step, no row written, zeros attended.
-                # Two walks at most: the layers that keep every token (0) and
-                # the window layers.
-                seen = jnp.where(page_tables[:, 0] > 0, seen, 0)
-                walks = ({w: page_groups(seen, page_tables, ps, w, n) for w, n in sorted(self._group.items())}
-                         if on_tpu else None)
+            # 0 for a slot with no pages (empty, or masked while it
+            # prefills): no grid step, no row written, zeros attended.
+            # Two walks at most: the layers that keep every token (0) and
+            # the window layers.
+            seen = jnp.where(page_tables[:, 0] > 0, seen, 0)
+            walks = ({w: page_groups(seen, page_tables, ps, w, n) for w, n in sorted(self._group.items())}
+                     if on_tpu else None)
             with jax.named_scope("embed"):
                 x = embed_tokens(params, last, cfg)[:, None, :]  # [B,1,D]
 
@@ -1869,16 +1864,14 @@ class LLMEngine:
         ceil(length / page_size) pages at each step, the step's own token
         counted, in ceil(pages / group) steps of up to ``group`` pages each
         (``ops/paged_attention.group_pages``); a slot that is not ``active``
-        (empty, or masked while it prefills) is walked by nobody, but by the
-        latent kernel, which takes a page a step and holds such a slot at one
-        step on dead page 0. Pages over n x max_slots x (max_seq / page_size)
-        is the share of the page table the kernel walks, pages over steps how
-        full a grid step is. From the host's mirror of the lengths as they
-        stood when the block was dispatched."""
+        (empty, or masked while it prefills) is walked by nobody. Pages over
+        n x max_slots x (max_seq / page_size) is the share of the page table
+        the kernel walks, pages over steps how full a grid step is. From the
+        host's mirror of the lengths as they stood when the block was
+        dispatched."""
         seen = self.lengths[active][None, :] + np.arange(1, n + 1)[:, None]
         pages = np.minimum(-(-seen // self.ec.page_size), self.ppseq)
-        held = (self.ec.max_slots - len(active)) * n if self.cfg.latent else 0
-        return int(pages.sum()) + held, int((-(-pages // self._group[0])).sum()) + held
+        return int(pages.sum()), int((-(-pages // self._group[0])).sum())
 
     def _window_walk(self, active: list[int], n: int) -> tuple:
         """(pages, positions attended) of ONE window layer in a decode block

@@ -50,7 +50,8 @@ Before PR 33 the grid was the whole table, (B, pages_per_seq) = 32 x 16 or
 PR 43 a step was one page of one sequence through a BlockSpec, and a slot
 without a request took one on dead page 0 so that its output row was written:
 1.4-1.6 us a page step for 0.64 us of bytes, and in the open-loop cell 29 of a
-call's 39 steps were empty slots'.
+call's 39 steps were empty slots'; the latent kernel kept that walk until
+PR 47.
 
 A layer with an attention window (``window`` > 0: position i sees j with
 i - window < j <= i) keeps no page table. Its pool holds, a sequence, a ring
@@ -140,8 +141,8 @@ def _page_range(length, ps, n_pages, window=0):
     stays inside its last page); ``first`` is 0, or with an attention window
     the page of position length - window, the oldest the current one sees
     (the kernel masks that page's older columns). Never empty, whatever
-    `length`: ``live_pages`` gives every row a step; ``page_groups`` gives a
-    row of length 0 none and does not ask."""
+    `length`: ``page_groups`` gives a row of length 0 no step and does not
+    ask."""
     first = jnp.zeros_like(length)
     last = jnp.clip((length - 1) // ps, first, n_pages - 1)
     if window:
@@ -192,54 +193,6 @@ def window_attention_reference(q, k_new, v_new, k_pages, v_pages, lengths, layer
 # ---------------------------------------------------------------------------
 
 WINDOW_ROWS = 16  # of the token's page, stored back: one packed tile of bf16
-
-
-def live_pages(lengths, page_indices, page_size, window=0):
-    """The kernel's walk, from lengths [B] (the current token counted) and
-    the page table [B, n_pages]: six int32 arrays, the first five of
-    B * n_pages entries. With a window the table's width alone is read (how
-    many pages a sequence may reach) and a page's place in the pool is its
-    place in its sequence's ring. Entry t < count is the t-th page step:
-
-    - ``slots[t]``, ``pages[t]``: page ``pages[t]`` of sequence ``slots[t]``'s
-      table, sequences in order and each one's pages ascending over its
-      ``_page_range``;
-    - ``where[t]``: that page in the pool;
-    - ``win_page[t]``, ``win_row[t]``: where the sequence's current token
-      goes, as the pool page and the block of WINDOW_ROWS rows in it;
-    - ``count`` [1]: the number of steps.
-
-    Everything an index map needs is an entry here, so a grid step's address
-    arithmetic is a handful of scalar loads (worth 5-14% of a call beside
-    maps that derive it from lengths and table; PERF.md section 6, PR 33).
-    Plain jnp, and the same for every layer of a decode step: a caller with
-    several calls on the same lengths and table builds it once and hands it
-    to each."""
-    B, n_pages = page_indices.shape
-    lengths, table = lengths.astype(jnp.int32), page_indices.astype(jnp.int32)
-    first, last = _page_range(lengths, page_size, n_pages, window)
-    j = jnp.arange(n_pages, dtype=jnp.int32)[None, :]
-    if window:
-        ring = ring_pages(window, page_size)
-        table = jnp.arange(B, dtype=jnp.int32)[:, None] * ring + j % ring
-    live = (first[:, None] <= j) & (j <= last[:, None])  # [B, n_pages]
-    win_page = jnp.sum(jnp.where(j == last[:, None], table, 0), axis=1)  # table[b, last[b]]
-    win_row = (lengths - 1) % page_size // min(page_size, WINDOW_ROWS)
-
-    def of_its_sequence(x):  # [B] -> an entry a table entry
-        return jnp.broadcast_to(x[:, None], table.shape).reshape(-1)
-
-    # The table's live entries first, in the table's order (a stable sort on
-    # one bit, the lists riding along: no gather, which costs a TPU program
-    # megabytes of temporaries for arrays this small). Past count come the
-    # dead entries, which nothing visits and which are valid all the same.
-    _, entry, where, win_page, win_row = jax.lax.sort(
-        (jnp.where(live, 0, 1).reshape(-1), jnp.arange(B * n_pages, dtype=jnp.int32),
-         table.reshape(-1), of_its_sequence(win_page), of_its_sequence(win_row)),
-        num_keys=1, is_stable=True,
-    )
-    count = jnp.sum(live, dtype=jnp.int32).reshape(1)
-    return entry // n_pages, entry % n_pages, where, win_page, win_row, count
 
 
 def reach_pages(n_pages: int, page_size: int, window: int = 0) -> int:
@@ -339,8 +292,7 @@ def page_groups(lengths, page_indices, page_size, window=0, group=1):
     derive it from lengths and table; PERF.md section 6, PR 33). Plain jnp,
     and the same for every layer of a decode step: a caller with several
     calls on the same lengths and table builds it once and hands it to each.
-    ``live_pages`` is the walk a page a step, with a step for every row: the
-    latent kernel's (ops/latent_attention.py)."""
+    The latent kernel's walk too (ops/latent_attention.py)."""
     B, n_pages = page_indices.shape
     n = group
     lengths, table = lengths.astype(jnp.int32), page_indices.astype(jnp.int32)

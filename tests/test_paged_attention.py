@@ -11,7 +11,6 @@ import pytest
 
 from ray_tpu.ops.paged_attention import (
     group_pages,
-    live_pages,
     page_groups,
     paged_attention,
     paged_attention_reference,
@@ -340,34 +339,6 @@ def test_the_serve_cells_tables(ppseq, H, KV, layer):
         B=6, H=H, KV=KV, D=64, ps=ps, ppseq=ppseq, lengths=lengths, layer=layer, seed=7,
         empty=(0, 2, 4, 5),
     ), layer)
-
-
-@pytest.mark.parametrize("ps,n_pages", [(16, 4), (128, 16), (128, 32)])
-def test_the_walk_a_page_a_step_gives_every_row_a_step(ps, n_pages):
-    """``live_pages``, the latent kernel's walk and the paged kernel's until
-    PR 43: ceil(length / page_size) steps a sequence, in sequence order and
-    each one's pages ascending, one that ran past its table its whole table,
-    none without a step (its row of the output would stay unwritten); with
-    every step, where its page lies and where the sequence's token goes."""
-    lengths = np.array([1, 2, ps - 1, ps, ps + 1, 2 * ps, 3 * ps - 1, n_pages * ps - 1,
-                        n_pages * ps, n_pages * ps + 5, 0], np.int32)
-    B = len(lengths)
-    table = np.random.default_rng(0).permutation(B * n_pages).reshape(B, n_pages).astype(np.int32)
-    walk = live_pages(jnp.asarray(lengths), jnp.asarray(table), ps)
-    slots, pages, where, win_page, win_row, count = (np.asarray(x) for x in walk)
-    steps = [min(max(math.ceil(n / ps), 1), n_pages) for n in lengths]
-    inside = (lengths >= 1) & (lengths <= n_pages * ps)
-    assert count.tolist() == [sum(steps)]
-    assert sum(s for s, ok in zip(steps, inside) if ok) == sum(math.ceil(n / ps) for n in lengths[inside])
-    want = [(b, j) for b in range(B) for j in range(steps[b])]
-    n = count[0]
-    assert list(zip(slots[:n], pages[:n])) == want
-    assert where[:n].tolist() == [table[b, j] for b, j in want]
-    assert win_page[:n].tolist() == [table[b, steps[b] - 1] for b, _ in want]
-    assert win_row[:n].tolist() == [(lengths[b] - 1) % ps // min(ps, 16) for b, _ in want]
-    # past the count nothing is visited, and every entry is still inside the table
-    for x, hi in ((slots, B), (pages, n_pages), (where, B * n_pages), (win_page, B * n_pages)):
-        assert x.shape == (B * n_pages,) and (0 <= x).all() and (x < hi).all()
 
 
 def _walk_in_plain_python(lengths, table, ps, window, group):
