@@ -138,12 +138,36 @@ STEP_PHASES = (
 # Finished requests and ended steps kept for LLMServer.stats(): a benchmark
 # run whole (a 51 s window with ramp and drain is ~300 requests, ~250 steps).
 TRACE_RING = 2048
+# The most tokens a prefill group of k > 1 requests may hold (k * bucket): 8 up
+# to 256 tokens, 4 up to 512, 2 up to 1,024, single requests above. The k
+# requests of a group run one after another inside its program
+# (_prefill_batch_impl scans them), so what a group saves is a dispatch
+# (0.6 ms) and a few ms of host work a request: something beside a prefill of
+# a few ms, nothing beside one of 30-280 ms, and every (bucket, k) is a
+# program that set-up traces, lowers and loads (PERF.md section 6, PR 48).
+GROUP_TOKENS = 2048
+
+
+def bucket_ladder(prefill_buckets, page_size: int, max_seq: int) -> tuple:
+    """The prefill buckets an engine pads prompts to: the configured lengths
+    rounded up to whole pages, those over max_seq dropped, max_seq itself, and
+    between any two neighbours lo < hi with hi >= 2 * lo the page-aligned
+    midpoint (between neighbours one page apart it is hi itself: no rung)."""
+    ps, S = page_size, max_seq
+    named = sorted({min(ps * math.ceil(b / ps), S) for b in prefill_buckets if b <= S} | {S})
+    mids = {ps * math.ceil((lo + hi) / 2 / ps) for lo, hi in zip(named, named[1:]) if hi >= 2 * lo}
+    return tuple(sorted(mids.union(named)))
 
 
 @dataclasses.dataclass
 class EngineConfig:
     max_slots: int = 8
     max_seq: int = 0  # 0 -> model max_seq_len
+    # The prompt lengths a deployment expects; a prompt is padded to the
+    # shortest bucket that holds it. The engine rounds these to whole pages,
+    # adds max_seq, and adds a rung halfway between any two a doubling or more
+    # apart (bucket_ladder); it prefills requests of one bucket in groups of
+    # 8, 4 or 2 only while a group holds at most 2,048 tokens (GROUP_TOKENS).
     prefill_buckets: tuple = (128, 256, 512, 1024, 2048)
     temperature: float = 0.0  # 0 => greedy
     eos_id: int = -1  # -1 => never stop on a token; set to the tokenizer's id
@@ -599,12 +623,13 @@ class LLMEngine:
         self._carry: dict[str, dict] = {}
         self._gone: list[int] = []
         self._drop_rows_jit = jax.jit(self._drop_rows_impl)
-        # Buckets: page-size multiples only (a prefill writes whole pages).
-        self.buckets = tuple(sorted(
-            {min(ps * math.ceil(b / ps), S) for b in self.ec.prefill_buckets if b <= S} | {S}
-        ))
-        # Prefill group sizes, largest-first (greedy grouping caps the
-        # number of compiled (bucket, k) programs at |buckets| x |k_buckets|).
+        # Buckets: page-size multiples only (a prefill writes whole pages). The
+        # configured lengths, max_seq, and a rung between any two neighbours a
+        # doubling or more apart, so that a prompt's padding is at most a third
+        # of its bucket and not a half.
+        self.buckets = bucket_ladder(self.ec.prefill_buckets, ps, S)
+        # Prefill group sizes, largest-first: the sizes formed at the shortest
+        # bucket (group_sizes says which of them a longer bucket keeps).
         self.k_buckets = (8, 4, 2, 1)
         # Decode block sizes: full (empty queue) and short (queue pressure —
         # waiting requests reach prefill sooner between shorter blocks).
@@ -1070,7 +1095,7 @@ class LLMEngine:
         (tail_bucket, ctx_bucket). Returns (the sampled token [1], still on
         the device; the tail bucket)."""
         ps = self.ec.page_size
-        tb = next(b for b in self.buckets if b >= len(tail))
+        tb = self._bucket_for(len(tail))
         j = start // ps
         C = next(c for c in self.c_buckets if c >= max(j, 1))
         padded = np.zeros(tb, np.int32)
@@ -1092,6 +1117,10 @@ class LLMEngine:
         )
         return toks_dev, tb
 
+    def _bucket_for(self, n_tokens: int) -> int:
+        """The bucket that many tokens are padded to: the shortest that holds them."""
+        return next(b for b in self.buckets if b >= n_tokens)
+
     def _prefill(self, bucket: int, k: int):
         fn = self._prefill_jit.get((bucket, k))
         if fn is None:
@@ -1100,12 +1129,23 @@ class LLMEngine:
             )
         return fn
 
+    def group_sizes(self, bucket: int) -> tuple:
+        """The group sizes a prefill of this bucket is dispatched in, largest
+        first: those of k_buckets whose group holds at most GROUP_TOKENS
+        tokens, and 1. What warmup compiles and what step forms both come
+        from here."""
+        return tuple(k for k in self.k_buckets if k == 1 or k * bucket <= GROUP_TOKENS)
+
     def warmup(self, buckets=None, k_values=None):
-        """Compile every (bucket, k) prefill program and both decode block
-        sizes before serving (the vLLM-style startup warmup): a cold compile
-        costs seconds and would otherwise land inside the first loaded
-        requests' TTFT. Executes each program once against the dead page
-        (page 0), then resets the device mirrors it dirtied.
+        """Compile every (bucket, k) prefill program step can dispatch and both
+        decode block sizes before serving (the vLLM-style startup warmup): a
+        cold compile costs seconds and would otherwise land inside the first
+        loaded requests' TTFT. Executes each program once against the dead
+        page (page 0), then resets the device mirrors it dirtied. ``buckets``
+        names lengths (a deployment's configured buckets, a raw prompt
+        length): every engine bucket from the one the shortest of them pads
+        to up to the one the longest pads to is warmed, the rungs between
+        them too, each at its ``group_sizes`` (those in ``k_values``, if given).
 
         Also records, once, in ``self.mosaic`` whether the first prefill
         program and the full decode block, compiled, hold a Mosaic custom
@@ -1118,14 +1158,12 @@ class LLMEngine:
         if buckets is None:
             buckets = self.buckets
         else:
-            # Snap caller lengths (e.g. a raw prompt length) to the buckets
-            # admit actually selects — warming a bucket step() never uses
-            # while leaving the real one cold would defeat the purpose.
-            buckets = tuple(
-                sorted({next(b for b in self.buckets if b >= min(x, self.buckets[-1]))
-                        for x in buckets})
-            )
-        k_values = tuple(k_values) if k_values is not None else self.k_buckets
+            # Snap the caller's shortest and longest length to the buckets
+            # admit selects for them, and warm those and every bucket between:
+            # a prompt between two named lengths may pad to a rung the caller
+            # cannot name, and a cold one would compile inside a request's TTFT.
+            lo, hi = (self._bucket_for(min(x, self.buckets[-1])) for x in (min(buckets), max(buckets)))
+            buckets = tuple(b for b in self.buckets if lo <= b <= hi)
         ps = self.ec.page_size
         key = jax.random.PRNGKey(0)
         on_tpu = jax.default_backend() == "tpu"
@@ -1140,7 +1178,7 @@ class LLMEngine:
 
         log = self.warmup_log
         for b in buckets:
-            for k in k_values:
+            for k in (k for k in self.group_sizes(b) if k_values is None or k in k_values):
                 t0 = time.monotonic()
                 toks = jnp.zeros((k, b), jnp.int32)
                 lens = jnp.ones(k, jnp.int32)
@@ -1426,7 +1464,7 @@ class LLMEngine:
         each `ph.to(...)` below ends one phase and starts the next, and the
         step's record goes to the ring that LLMServer.stats() returns."""
         ph = self._phases
-        ph.begin("admit", waiting=len(self.waiting), n_admitted=0, n_prefill=0,
+        ph.begin("admit", waiting=len(self.waiting), n_admitted=0, n_prefill=0, prefill_tokens=0, prefill_padded=0,
                  pages_reserved=0, block=0, ahead=0, dropped_rows=0, active=0, sampled=0, live_pages=0, grid_steps=0,
                  expert_pairs=0, expert_tiles=0,
                  **({"window_pages": 0, "window_tokens": 0} if self._window else {}),
@@ -1551,7 +1589,7 @@ class LLMEngine:
                 if use_cache:
                     self.prefix_misses += 1
                 self.lengths[i] = P
-                bucket = life["bucket"] = next(b for b in self.buckets if b >= P)
+                bucket = life["bucket"] = self._bucket_for(P)
                 admitted.append((i, req_id, tokens, bucket, sp.max_tokens, arrived))
         # the pages slots hold once the step has admitted: what is neither free nor the prefix cache's
         ph.rec["pages_reserved"] = self.ec.total_pages - 1 - len(self.free_pages) - len(self._page_refs)
@@ -1575,7 +1613,7 @@ class LLMEngine:
         for bucket, group in by_bucket.items():
             n_pg = bucket // ps
             while group:
-                k = next(kb for kb in self.k_buckets if kb <= len(group))
+                k = next(kb for kb in self.group_sizes(bucket) if kb <= len(group))
                 chunk, group = group[:k], group[k:]
                 idxs = [it[0] for it in chunk]
                 padded = np.zeros((k, bucket), np.int32)
@@ -1609,6 +1647,8 @@ class LLMEngine:
                 self.d_last = self.d_last.at[idx_arr].set(toks_dev)
                 ph.to("prefill_dispatch")
                 ph.rec["n_prefill"] += 1
+                ph.rec["prefill_tokens"] += int(lens.sum())
+                ph.rec["prefill_padded"] += k * bucket
                 dispatched.append((chunk, toks_dev))
         # Partial-prefix hits: per-request tail prefill over the cached
         # context pages.

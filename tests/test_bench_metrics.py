@@ -304,3 +304,44 @@ def test_host_and_wait_metric_is_in_the_manifest_as_the_table_says(bench, name):
     assert set(cells) <= set(listed) and len(set(listed)) == len(listed)  # later cells may join the list
     moved = next(m for m in manifest["end_to_end"] if m["name"] == moves)
     assert set(listed) <= set(moved.get("workloads", listed)), "a cell that does not report what the metric moves"
+
+
+# -- the reader of what PR 48 added to the step record ------------------------
+@pytest.mark.parametrize("case,want", [
+    ("counted", 25.0),  # 100 + 700 + 400 tokens in 256 + 768 + 2 x 288 rows; the step before the window is not read
+    ("no prefill in the window", None),
+    ("a record without the counters", None),  # the parent's: the line leaves the metric out
+    ("no record", None),
+    ("a drop inside the window", None),
+])
+def test_prefill_padding_share_reads_the_steps_own_counters(bench, case, want):
+    cellspec, context = bench
+    record = _record()
+    trace = record["stats"]["trace"]
+    counted = [(5000, 8192), (100 + 700, 256 + 768), (400, 2 * 288)]
+    for s, (tokens, padded) in zip(trace["steps"], counted):
+        s.update(prefill_tokens=tokens, prefill_padded=padded)
+    if case == "no prefill in the window":
+        for s in trace["steps"][1:]:
+            s.update(prefill_tokens=0, prefill_padded=0)
+    elif case == "a record without the counters":
+        del trace["steps"][2]["prefill_padded"]
+    elif case == "no record":
+        record["stats"] = None
+    elif case == "a drop inside the window":
+        trace["steps"], trace["dropped"]["steps"] = trace["steps"][2:], 1
+    value = cellspec.load_metric("prefill_padding_share")(context.Context(record, 1))
+    assert value == (pytest.approx(want) if want is not None else None)
+
+
+def test_prefill_padding_share_is_the_manifests_last_word_on_the_scheduler(bench):
+    import json
+
+    with open(os.path.join(os.path.dirname(BENCH_DIR), "BENCHMARK.json")) as f:
+        manifest = json.load(f)
+    entry = next(m for m in manifest["per_layer"] if m["name"] == "prefill_padding_share")
+    moved = next(m for m in manifest["end_to_end"] if m["name"] == "serve_out_tokens_per_s")
+    listed = entry.pop("workloads")
+    assert entry == {"name": "prefill_padding_share", "unit": "%", "better": "lower", "source": "program_counter",
+                     "layer": "scheduler", "moves": "serve_out_tokens_per_s"}
+    assert listed[:6] == moved["workloads"][:6] and set(listed) <= set(moved["workloads"])  # later cells may join

@@ -184,6 +184,24 @@ def test_prefill_then_decode_through_state_and_pages_matches_the_full_forward_f3
     assert eng.pool_bytes["mamba"] == 6 * 2 * 32 * 64 * 4 + 6 * 2 * 3 * 128 * 4  # the three inputs end to end in one row
 
 
+@pytest.mark.parametrize("n_prompt,bucket", [(40, 48), (90, 96)])
+def test_a_prompt_on_a_rung_between_doublings_leaves_the_state_of_its_own_length(n_prompt, bucket, logits_spy):
+    """Buckets of 3 and of 6 pages, the rungs the engine puts between 32, 64 and
+    128: the prefill's token and 12 decoded ones against the reference's full
+    forward, so the state and the convolution tail the prefill left are those
+    of the prompt's own length, not the bucket's."""
+    params, prompt = _params(), _tokens(n_prompt, seed=n_prompt)
+    eng = LLMEngine(CFG, params=params, engine_config=EngineConfig(**{**ENGINE_KW, "prefill_buckets": (32, 64)}))
+    assert eng.buckets == (32, 48, 64, 96, 128)
+    toks = eng.generate(prompt, max_tokens=13)["tokens"]
+    jax.effects_barrier()
+    assert eng.trace_snapshot()["requests"][0]["bucket"] == bucket
+    got = np.stack([r[0] for r in logits_spy][:13]).astype(np.float32)
+    full = jnp.asarray([list(prompt) + toks[:-1]])
+    want = np.asarray(ref.logits(params, full, MODEL))[0, n_prompt - 1:]
+    np.testing.assert_allclose(got, want, atol=SERVED, rtol=SERVED)
+
+
 def test_a_state_rounded_to_bfloat16_misses_the_tolerance(logits_spy):
     """Why the pool is float32, seen from the logits: the state a prefill left
     rounded to bfloat16 once, and the decoded logits miss the reference's by

@@ -274,7 +274,8 @@ def test_first_prefill_entry_of_warmup_log_carries_temp_bytes():
     eng = _big_pool_engine()
     eng.warmup(k_values=(2, 1))
     prefill = [p for p in eng.warmup_log if p["program"] == "prefill"]
-    assert [(p["bucket"], p["k"]) for p in prefill] == [(32, 2), (32, 1), (64, 2), (64, 1)]
+    # 32 and max_seq, and the rung between them (engine.bucket_ladder)
+    assert [(p["bucket"], p["k"]) for p in prefill] == [(32, 2), (32, 1), (48, 2), (48, 1), (64, 2), (64, 1)]
     assert isinstance(prefill[0]["temp_bytes"], int)
     assert 0 <= prefill[0]["temp_bytes"] < eng.cache[0].nbytes // 2, prefill[0]
     assert all("temp_bytes" not in p for p in prefill[1:]), prefill

@@ -142,6 +142,25 @@ def test_prefill_then_decode_through_state_and_pages_matches_the_full_forward_f3
     assert eng.pool_bytes["kda"] == 6 * 2 * 4 * 16 * 16 * 4 + 6 * 2 * 3 * 3 * 4 * 16 * 4
 
 
+@pytest.mark.parametrize("n_prompt,bucket", [(40, 48), (90, 96)])
+def test_a_prompt_on_a_rung_between_doublings_leaves_the_state_of_its_own_length(n_prompt, bucket, logits_spy):
+    """Buckets of 3 and of 6 pages, the rungs the engine puts between 32, 64 and
+    128 (48 is no whole chunk of the delta kernel's 64 positions, 96 is one and
+    a half): the prefill's token and 12 decoded ones against the reference's
+    full forward, so the state and the convolution tail the prefill left are
+    those of the prompt's own length, not the bucket's."""
+    params, prompt = _params(), _tokens(n_prompt, seed=n_prompt)
+    eng = LLMEngine(CFG, params=params, engine_config=EngineConfig(**{**ENGINE_KW, "prefill_buckets": (32, 64)}))
+    assert eng.buckets == (32, 48, 64, 96, 128)
+    toks = eng.generate(prompt, max_tokens=13)["tokens"]
+    jax.effects_barrier()
+    assert eng.trace_snapshot()["requests"][0]["bucket"] == bucket
+    got = np.stack([r[0] for r in logits_spy][:13]).astype(np.float32)
+    full = jnp.asarray([list(prompt) + toks[:-1]])
+    want = np.asarray(ref.logits(params, full, MODEL, held=HELD))[0, n_prompt - 1:]
+    np.testing.assert_allclose(got, want, atol=1e-4, rtol=1e-4)
+
+
 def test_two_requests_of_unequal_length_share_decode_blocks_and_a_third_takes_a_left_slot(logits_spy):
     """Two slots, three requests: a prompt of 66 and one of 7 decode in the
     same blocks, each on its own state; the short one ends first and the

@@ -128,6 +128,21 @@ def test_prefill_then_decode_through_both_kinds_of_cache_matches_the_full_forwar
     assert k_win.shape == (6, 2, 2 * ring_pages(WINDOW, PS) * PS, 16) and ring_pages(WINDOW, PS) == 3
 
 
+@pytest.mark.parametrize("n_prompt,bucket", [(40, 48), (90, 96)])
+def test_a_prompt_on_a_rung_between_doublings_leaves_the_ring_of_its_own_length(n_prompt, bucket, logits_spy):
+    """Buckets of 3 and of 6 pages, the rungs the engine puts between 32, 64 and
+    128: one turn of the ring's 3 pages and two. The prefill's token and 12
+    decoded ones against the reference's full forward, so the ring holds the
+    window that ends at the prompt's own length, not at the bucket's."""
+    params, prompt = _params(), _tokens(n_prompt, seed=n_prompt)
+    got, toks, eng = _served_logits(CFG, params, prompt, 13, logits_spy, prefill_buckets=(32, 64))
+    assert eng.buckets == (32, 48, 64, 96, 128)
+    assert eng.trace_snapshot()["requests"][0]["bucket"] == bucket
+    full = jnp.asarray([list(prompt) + toks[:-1]])
+    want = np.asarray(ref.logits(params, full, MODEL, held=HELD))[0, n_prompt - 1:]
+    np.testing.assert_allclose(got, want, atol=1e-4, rtol=1e-4)
+
+
 def test_the_served_geometry_a_window_of_512_over_rings_of_5_pages_of_128(logits_spy):
     """Toy widths, the served cache geometry: a prompt of 2,680 tokens (5
     windows, 4 turns of a ring's 640 rows, so ``_write_ring`` keeps pages
