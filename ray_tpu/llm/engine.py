@@ -3,85 +3,37 @@ fused decode blocks.
 
 TPU-first design (vs the reference's delegation to vLLM,
 llm/_internal/serve/engines/vllm/vllm_engine.py:174):
-- Static shapes everywhere: the KV cache is a linear page pool
-  [L, KV, total_pages*page_size, Hd]; prompts prefill through a few
-  length-bucketed jitted programs; decoding is ONE jitted block over all
-  slots per iteration — XLA sees a handful of programs total, not a shape
-  per batch composition.
+- Static shapes everywhere: the cache is a few pools made once; prompts
+  prefill through a few length-bucketed jitted programs; decoding is ONE
+  jitted block over all slots per iteration — XLA sees a handful of programs
+  total, not a shape per batch composition.
 - Paged KV (vLLM's core idea, re-expressed for XLA): each sequence owns a
-  page list; prefill scatters K/V into its pages; decode carries the pool
-  whole through its loops, and the Pallas paged-attention kernel
-  (ops/paged_attention.py — no materialized gather, no slice of the pool)
-  writes one token's row in place at (layer, page[len // ps], len % ps) and
-  attends through the page table. A step of its grid is up to 8 consecutive
-  live pages of one sequence (a page group), a list the decode step builds
-  once from the lengths and hands to every layer (``page_groups``), so a
-  call costs the tokens in the cache and a slot without a request has no
-  step; from PR 33 to PR 43 a step was one page and an empty slot took one,
-  before PR 33 the grid was the whole table, slots × pages a slot, live or
-  dead. Memory scales with reserved pages, not slots × max_seq, and
-  admission is page-budgeted.
+  page list, and decode carries the pools whole through its loops while the
+  kernels read and write pages where they lie (no materialized gather, no
+  slice of a pool). A decode step builds its walks of live pages once from
+  the lengths and hands them to every layer, so a call costs the tokens in
+  the cache and a slot without a request has no grid step. Memory scales
+  with reserved pages, not slots × max_seq, and admission is page-budgeted.
 - Continuous batching is the host loop: finished slots retire (their pages
   return to the free list) and queued requests prefill into free slots.
   Prefill groups are dispatched back-to-back asynchronously and fetched in
   order, so a request's TTFT is its own group's completion, not the whole
   admission wave's.
-- The host is one decode block behind the device and never more: a step
-  enqueues its block BEFORE it fetches and walks the tokens of the block the
-  step before enqueued (``_Block``, ``_absorb``), so while block s runs the
-  host absorbs block s-1, hands its events to the serving loop and does the
-  next step's admission, prefill dispatch and mirror updates. A block's
-  outputs (last tokens, lengths, the pools) are the next block's inputs on
-  the device; the host's ``lengths`` advance at dispatch and so are the
-  device's. A request that ends by a token is found out a block late: its
-  row rides that block and the walk throws the row away.
-- Admission-aware decode: under queue pressure the decode block shrinks
-  (fewer fused steps per host round trip) so waiting requests reach a
-  prefill slot sooner; with an empty queue full blocks amortize the
-  per-dispatch latency (0.55-0.61 ms an awaited dispatch on a directly
-  attached chip, against 11-18 ms a decode step: PERF.md section 6).
-- GQA cache: K/V stored at kv-head count (the HBM saving is what makes long
-  contexts fit); the paged kernel reads grouped heads directly.
-- Latent cache (a model with ``attention_kind="latent"``): one pool of one
-  row a token a layer, ``[c | k_rope]`` (the normed low-rank latent and the
-  roped key all heads share), in place of two pools of head rows. ``__init__``
-  derives the pool's row from the model in one place (``self.cache``, the
-  tuple of pools) and the four programs take their cache through it; page
-  tables, lengths, admission and the prefix cache's digests are the same. A
-  prompt expands its own rows to keys and values for the flash kernel; decode
-  absorbs the two up-projections into the query and the output and attends
-  the rows as they lie (ops/latent_attention.py). A model's stacks of layers
-  (leading dense layers, then the rest) are scanned one after the other, and
-  a layer that serves a chip's share of routed experts hands its counts out
-  of the decode program beside the tokens (``expert_pairs``,
-  ``expert_tiles`` of a step's record).
-- The cache by layer kind (a model with a ``layer_pattern``): each LayerKind
-  has two pools of its own. A kind without a window is paged from
-  ``total_pages`` through the page tables, as above. A kind with a window
-  keeps, a slot, a ring of ``ring_pages(window, page_size)`` pages and nothing
-  behind it (ops/paged_attention.py says how a page finds its place in the
-  ring): its pools are max_slots x ring pages whatever the contexts, prefill
-  writes a prompt's last window of rows into the slot's ring
-  (``_write_ring``), and decode's ``window_attn`` call walks the window's
-  pages. Admission budgets pages of the layers that keep every token. A
-  prefix hit or a chunk of a prompt cannot restore a ring from pages, so both
-  are refused for such a model.
-- The third rule, a state kept by slot (a recurrent kind: ``mixer="delta"``,
-  ops/linear_attention.py, or ``mixer="ssd"``, ops/ssd.py; one branch here
-  for both, on ``kind.recurrent``): such a layer keeps no rows of tokens. Its
-  two pools are addressed by the slot and do not grow with the context: a
-  state in float32 and the last conv_size - 1 inputs of its short
-  convolution, each ``[the kind's layers, max_slots, ...]`` with the shapes
-  the kind gives (models/transformer.py ``slot_state_shapes``). Prefill leaves
-  both as they stand at the prompt's own length, not at its bucket's end
-  (positions behind the length leave the state alone, and the tail is cut at
-  the length); decode carries both through its loops like the other pools,
-  the state updated in place by the step call its output aliases
-  (``kda_step``, ``ssd_step``), one grid step a live slot and none for an empty one, whose state stays bit for
-  bit. Admission budgets pages for the layers that keep every token, as for
-  window layers. A page copy cannot restore a state and a chunk of a prompt
-  would have to start from one, so prefix hits, chunked prefill and a mesh are
-  refused for such a model (ROADMAP M4).
+- The host is one decode block behind the device and never more (``step``,
+  ``_Block``, ``_absorb``): while block s runs the host absorbs block s-1,
+  hands its events to the serving loop and does the next step's admission,
+  prefill dispatch and mirror updates. A block's outputs (last tokens,
+  lengths, the pools) are the next block's inputs on the device; the host's
+  ``lengths`` advance at dispatch and so are the device's. A request that
+  ends by a token is found out a block late: its row rides that block and
+  the walk throws the row away.
+- Admission-aware decode (``_fit``): under queue pressure the decode block
+  shrinks so waiting requests reach a prefill slot sooner; with an empty
+  queue full blocks are what the host's work hides under.
+- What a layer keeps of a sequence is its cache rule's (llm/cache_rules.py:
+  K and V rows in pages, a ring a slot, a state a slot, latent rows in
+  pages): the engine holds one rule a LayerKind (``self.rules``), hands each
+  its own pools of ``self.cache`` and asks; the programs here loop over them.
 - Tensor-parallel serving (EngineConfig.tensor_parallel > 1): params shard
   Megatron-style and the KV pools shard by kv_heads over a `tensor` mesh
   axis (parallel/), so a model bigger than one chip's HBM serves from a
@@ -104,7 +56,6 @@ the kernel walks no page and writes no row for it.
 from __future__ import annotations
 
 import dataclasses
-import functools
 import hashlib
 import math
 import time
@@ -116,16 +67,11 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as _P
 
+from ray_tpu.llm.cache_rules import ONE_CHIP, rule_for
 from ray_tpu.llm.sampling import SamplingParams, sample_batch
 from ray_tpu.models.transformer import (
-    TransformerConfig, _rms_norm, decoder_block, embed_tokens, hidden_logits, init_params, lane_padded,
-    latent_absorb, latent_expand, latent_scale, latent_values, pad_last, param_logical_axes, recurrence, run_layers,
-    slot_state_shapes,
-)
-from ray_tpu.ops.latent_attention import latent_attention_reference, latent_paged_attention, latent_row_width
-from ray_tpu.ops.paged_attention import (
-    group_pages, kv_row_width, page_groups, paged_attention, paged_attention_reference, ring_pages,
-    window_attention_reference,
+    TransformerConfig, _rms_norm, decoder_block, embed_tokens, hidden_logits, init_params, param_logical_axes,
+    run_layers,
 )
 from ray_tpu.util import tracing as _tracing
 
@@ -172,18 +118,14 @@ class EngineConfig:
     temperature: float = 0.0  # 0 => greedy
     eos_id: int = -1  # -1 => never stop on a token; set to the tokenizer's id
     seed: int = 0
-    # Decode steps fused into one device program per host round trip. 8 was
-    # chosen on an earlier remote-chip stack where the call's latency
-    # dominated. On a directly attached v5e an awaited dispatch costs
-    # 0.55-0.61 ms (PR 21) and a decode step 6-21 ms (PERF.md section 5).
-    # Since PR 39 the block is what the host hides under: a step's 20-40 ms
-    # of admission, prefill dispatch, mirror updates and token walk run while
-    # the block before it decodes, so a block has to last about as long as
-    # they do (8 steps: 51-167 ms; the short block of 2 under queue pressure
-    # hides only part). Cost: admissions happen between blocks, a request
-    # that ends by a token keeps its row one block longer on the device, and
-    # a slot finishing mid-block discards its tail tokens. The first token no
-    # longer waits for its own step's block (ROADMAP S0).
+    # Decode steps fused into one device program per host round trip. On a
+    # directly attached v5e an awaited dispatch costs 0.55-0.61 ms and a
+    # decode step 6-21 ms (PERF.md section 5), so the block is not there for
+    # the dispatch: a step's 20-40 ms of admission, prefill dispatch, mirror
+    # updates and token walk run while the block before it decodes, so a block
+    # has to last about as long as they do (8 steps: 51-167 ms; the short
+    # block of 2 under queue pressure hides only part). Cost: admissions
+    # happen between blocks, and a slot finishing mid-block discards its tail.
     decode_block: int = 8
     # Retired key: the block-paged pool is the one KV layout (the dense
     # per-slot cache went in PR 30). Configurations under benchmarks/ still
@@ -199,15 +141,10 @@ class EngineConfig:
     # ceil((prompt + max_tokens + decode_block)/page_size) pages per request
     # and queues when the pool is dry.
     total_pages: int = 0
-    # Tensor-parallel serving degree. >1 shards the model AND the KV cache
-    # over a `tensor` mesh axis of that many local devices (reference: TP
-    # degree -> placement-group bundle mapping, vllm_models.py:233-238; the
-    # sharded execution itself lives in vLLM — here it is native): params
-    # shard by heads/ffn/vocab (Megatron split, parallel/sharding.py tp()),
-    # KV pools shard by kv_heads, page tables/lengths/sampling state stay
-    # replicated, and the host-side scheduler is unchanged. Serving capacity
-    # becomes k chips' HBM instead of one. Requires n_heads, kv_heads, d_ff
-    # and vocab_size divisible by the degree.
+    # Tensor-parallel serving degree (the module docstring says how): >1
+    # shards the model and the KV pools over a `tensor` mesh axis of that many
+    # local devices; page tables, lengths and sampling state stay replicated.
+    # Requires n_heads, kv_heads, d_ff and vocab_size divisible by the degree.
     tensor_parallel: int = 1
     # Candidate cap for truncated (top-k/top-p) sampling rows; see
     # sampling.TOPK_CAP for the nucleus-width caveat. Raise for workloads
@@ -219,10 +156,8 @@ class EngineConfig:
     # step, interleaved with the decode blocks — a 512-token prefill can no
     # longer head-of-line-stall decoding slots for its whole length; decode
     # stall per step is bounded by one chunk's compute. Must be a multiple
-    # of page_size. 0 = off (whole-prompt prefill). Refused for a model with
-    # window layers (a chunk attends pages a ring no longer holds) and for one
-    # with recurrent layers (a chunk would have to start from the state the one
-    # before left: ROADMAP M2, M4).
+    # of page_size. 0 = off (whole-prompt prefill). Refused by a cache rule
+    # that cannot attend a tail over cached pages (llm/cache_rules.py).
     chunked_prefill: int = 0
     # Prefix KV cache (reference: vLLM automatic prefix caching +
     # PrefixCacheAffinityRouter, prefix_aware_router.py:39). A retired
@@ -243,8 +178,7 @@ class EngineConfig:
     #   pages, then a chunked TAIL prefill embeds only the new tokens,
     #   attending to the cached pages gathered from the pool — prefill
     #   compute scales with the tail, not the prompt.
-    # Refused for a model with window layers or recurrent layers: a hit copies
-    # pages, which restore neither a ring nor a state (ROADMAP M2, M4).
+    # Refused by a cache rule that pages cannot restore (llm/cache_rules.py).
     prefix_cache: bool = False
 
     def __post_init__(self):
@@ -279,70 +213,10 @@ class _Block:
     """A decode block the device has been handed and the host has not fetched:
     what ``LLMEngine._absorb`` needs to walk it a step later."""
     toks: Any  # [n, max_slots], on the device
-    counts: Any  # held experts' (pairs, tiles) and recurrent layers' rewritten states, int32 on the device; None without either
+    counts: Any  # held experts' (pairs, tiles), then the rules' device counts, int32 on the device; None without either
     n: int
     rec: dict  # the dispatching step's record (the ring holds this dict: counts land in it)
     rows: list  # (slot index, the _Slot that held it at dispatch) of every active row
-
-
-def _kv_rows(kv, dtype, width=None):
-    """One request's K or V of a layer, [1, P, KV, Hd], as the paged pool
-    stores it: [KV, P, Hd] in the pool's dtype, zero-padded to the pool's row
-    where that is wider than a head (ops/paged_attention.py ``kv_row_width``)."""
-    rows = kv[0].transpose(1, 0, 2).astype(dtype)
-    # padded rows are told their layout: without it the TPU compiler kept them tokens-minor and turned both pools
-    # round to match, in and out of every prefill program (four copies of a pool a call; my chip run, PR 46)
-    return rows if width in (None, rows.shape[-1]) else _row_major(pad_last(rows, width))
-
-
-def _latent_rows(c, k_rope, width, dtype):
-    """What a latent layer caches of tokens: c [..., R] and k_rope [..., rope]
-    side by side, zero-padded to the pool's row width, in its dtype."""
-    return pad_last(jnp.concatenate([c, k_rope], axis=-1), width).astype(dtype)
-
-
-def _row_major(rows):
-    """``rows`` held to the layout the pool has, last axis minor. A prompt's
-    latent rows are put together from a 64 wide roped key, which the TPU
-    compiler lays token-minor, and a loop that carries the pool then takes the
-    rows' layout for the pool: a transposed copy of the whole pool into the
-    request scan and one out of it (2.9 GB each, as compiled for a v5e)."""
-    if jax.default_backend() != "tpu":
-        return rows
-    from jax.experimental.layout import Layout, with_layout_constraint
-
-    return with_layout_constraint(rows, Layout(major_to_minor=tuple(range(rows.ndim))))
-
-
-def _prompt_attention(q, k, v, seg, mesh, scale=None, window=0):
-    """Causal attention of a (padded) prompt over its own fresh K/V. seg
-    masks pad columns (pad tokens are their own segment). scale: a latent
-    layer's (its keys are wider than its values, so the flash kernel gets
-    both zero-padded to one lane multiple); None is 1 / sqrt(head width).
-    window: a sliding layer's (the flash kernel visits the band's blocks only).
-
-    mesh: tensor-parallel serving — heads are sharded over mesh["tensor"],
-    so the Pallas flash kernel runs per-shard under shard_map (GSPMD cannot
-    partition a Mosaic kernel; jax refuses to lower a bare pallas_call
-    there); the einsum reference path is GSPMD-partitionable as-is."""
-    from ray_tpu.ops.attention import flash_attention, flash_supported, mha_reference
-
-    def flash(q_, k_, v_, seg_):
-        if scale is None:
-            return flash_attention(q_, k_, v_, causal=True, segment_ids=seg_, window=window)
-        return flash_attention(*lane_padded(q_, k_, v_), causal=True, segment_ids=seg_,
-                               scale=scale)[..., :v_.shape[-1]]
-
-    with jax.named_scope("flash_attn"):
-        if not flash_supported(q.shape[1]):
-            return mha_reference(q, k, v, causal=True, segment_ids=seg, scale=scale, window=window)
-        if mesh is not None and mesh.shape.get("tensor", 1) > 1:
-            hs = _P(None, None, "tensor", None)
-            flash = jax.shard_map(
-                flash, mesh=mesh, in_specs=(hs, hs, hs, _P(None, None)), out_specs=hs,
-                check_vma=False,
-            )
-        return flash(q, k, v, seg)
 
 
 # A traced request's phases inside the replica, as child spans of the trace
@@ -387,46 +261,12 @@ class LLMEngine:
                 "expert computed for every token, is not served")
         self.cfg = cfg
         self.ec = engine_config or EngineConfig()
-        if (cfg.latent or cfg.experts_held) and self.ec.tensor_parallel > 1:
-            raise ValueError(
-                "tensor_parallel > 1 is not written for a latent cache or held experts: their "
-                "kernels run on one chip (ROADMAP M1, M3)")
-        self._window = max((kind.window for kind in cfg.kinds), default=0)  # 0: every layer keeps every token
-        if any(kind.window not in (0, self._window) for kind in cfg.kinds):
-            raise ValueError(f"window layers of one window are written; the pattern has {cfg.kinds}")
-        if self._window and self.ec.tensor_parallel > 1:
-            raise ValueError(
-                "tensor_parallel > 1 is not written for window layers: a slot's ring of pages is "
-                "addressed by the slot, not through the page table the sharded kernel walks (ROADMAP M2)")
-        if self._window and self.ec.prefix_cache:
-            raise ValueError(
-                "prefix_cache is not written for window layers: a hit copies pages, and a window layer "
-                "keeps a slot's last window of rows in a ring, which cached pages cannot restore; a "
-                "partial hit's tail prefill would attend a context those layers no longer hold (ROADMAP M2)")
-        if self._window and self.ec.chunked_prefill:
-            raise ValueError(
-                "chunked_prefill is not written for window layers: a chunk attends the earlier chunks' "
-                "pages, and a window layer keeps no pages behind its ring (ROADMAP M2)")
-        self._recurrent = tuple(kind for kind in cfg.kinds if kind.recurrent)  # kinds that keep a state a slot
-        such = " and ".join(sorted({kind.mixer for kind in self._recurrent})) + " layers"
-        for option, on, why in (
-                ("prefix_cache", self.ec.prefix_cache,
-                 "a hit copies pages, and a page copy cannot restore the state such a layer keeps of a prefix"),
-                ("chunked_prefill", self.ec.chunked_prefill,
-                 "a chunk would have to start from the state and the convolution tail the chunk before left, "
-                 "and the prefill programs start from an empty one"),
-                ("tensor_parallel > 1", self.ec.tensor_parallel > 1,
-                 "the state pool is addressed by the slot and its kernels run on one chip")):
-            if self._recurrent and on:
-                raise ValueError(f"{option} is not written for {such}: {why} (ROADMAP M4)")
-        if self._recurrent and self._window:
-            raise ValueError(f"window layers beside {such} are not written: a prefill is told its slot's "
-                             "ring or its slot (ROADMAP M4)")
+        tp = self.ec.tensor_parallel
+        if cfg.experts_held and tp > 1:
+            raise ValueError(ONE_CHIP)
         if self.ec.max_seq <= 0:
             self.ec = dataclasses.replace(self.ec, max_seq=cfg.max_seq_len)
         S = self.ec.max_seq
-        # Read only by benchmarks/harness/replica.py (ROADMAP D14).
-        self.paged = True
         ps = self.ec.page_size
         if S % ps:
             raise ValueError(f"max_seq {S} must be a multiple of page_size {ps}")
@@ -434,10 +274,19 @@ class LLMEngine:
             self.ec = dataclasses.replace(
                 self.ec, total_pages=self.ec.max_slots * (S // ps) + 1
             )
-        # Tensor-parallel mesh: params shard Megatron-style, KV pools shard
-        # by kv_heads; everything else (page tables, lengths, sampling state)
-        # is replicated, so the host scheduler below is layout-oblivious.
-        tp = self.ec.tensor_parallel
+        # One cache rule a LayerKind, in the pools' order (llm/cache_rules.py).
+        # Every rule is asked about every option that is on and about every
+        # rule beside it; what one cannot serve is refused in its own words.
+        self.rules: list = []
+        for kind in cfg.kinds:
+            self.rules.append(rule_for(cfg, kind, self.ec, first=self.rules[-1].sl.stop if self.rules else 0))
+        options = [name for name, on in (("tensor_parallel > 1", tp > 1), ("prefix_cache", self.ec.prefix_cache),
+                                         ("chunked_prefill", self.ec.chunked_prefill)) if on]
+        for rule in self.rules:
+            for why in (*map(rule.refuses, options), *map(rule.beside, self.rules)):
+                if why:
+                    raise ValueError(why)
+        # The tensor-parallel mesh; what is not sharded over it is replicated.
         self.mesh = None
         param_shardings = None
         if tp > 1:
@@ -476,78 +325,24 @@ class LLMEngine:
             )()
         else:
             self.params = init_params(jax.random.PRNGKey(self.ec.seed), cfg)
-        L = cfg.n_layers
         B = self.ec.max_slots
-
-        def _pool_zeros(shape, pool_spec, dtype=cfg.dtype):
-            if self.mesh is None:
-                return jnp.zeros(shape, dtype)
-            # Allocate directly sharded: a replicated-then-device_put pool
-            # would materialize the full multi-GB buffer on one chip first.
-            return jax.jit(
-                lambda: jnp.zeros(shape, dtype),
-                out_shardings=NamedSharding(self.mesh, pool_spec),
-            )()
-
         P_total = self.ec.total_pages
         self.ppseq = S // ps  # page-table width (max pages per sequence)
-        # Linear page pool: position (page, offset) lives at page*ps + offset.
-        # The rule for every program that takes the two pools (each donates
+        # ``self.cache`` is every rule's pools, one after the other
+        # (``rule.sl``); position (page, offset) lives at page*ps + offset.
+        # The rule for every program that takes the pools (each donates
         # them): a pool that is CARRIED whole (argument -> loop carry ->
         # result) and updated with dynamic_update_slice, or aliased to a Mosaic
         # call's outputs, is updated in place; a pool, or a layer's slice of
         # one, passed through a scan as xs and taken back as ys is copied,
-        # sliced out and stacked back every layer (70% of the decode program
-        # before PR 25, 65% of the prefill program before PR 29, two pools and
-        # more of temporaries each: PERF.md section 6), and so is a pool a scan
-        # only reads, if its consumer prefers another layout. So decode carries
-        # the pools through both its scans with the layer index in xs, and the
-        # layer scans of prefill see a prompt's K/V and never a pool
-        # (_write_pages, _copy_pages_impl).
-        # What a layer caches of a token, the one place that says it: a head's
-        # K and V rows in two pools [L, KV, tokens, Hd], or for a latent layer
-        # one pool [L, tokens, W] of [c | k_rope] rows (W a lane multiple).
-        # Every program takes ``self.cache``, the tuple of pools, whole, and
-        # slices tokens along ``self._tok_axis``.
-        # By layer kind (``self._kind_pools``: a kind's pools in the tuple): a
-        # kind without a window holds P_total pages for the page tables to
-        # share out; one with a window holds a ring of pages a slot and
-        # nothing behind it, B x ring pages whatever the contexts; a recurrent
-        # kind holds no tokens: a state a slot in float32 and its convolution's
-        # last inputs, [its layers, B, ...] each, the rest of the shape the
-        # kind's (slot_state_shapes). A head's row in a paged pool is
-        # ``kv_row_width`` columns, the paged kernel's rule.
-        if cfg.latent:
-            self._row_width = latent_row_width(cfg.kv_lora_rank, cfg.qk_rope_head_dim)
-            pools = [((L, P_total * ps, self._row_width), _P(None, None, None))]
-            self._tok_axis = 1
-            self._kind_pools = {cfg.kinds[0].name: slice(0, 1)}
-        else:
-            pools, self._kind_pools = [], {}
-            self._kv_width = kv_row_width(cfg.head_dim)
-            for kind in cfg.kinds:
-                self._kind_pools[kind.name] = slice(len(pools), len(pools) + 2)
-                if kind.recurrent:
-                    state, tail = slot_state_shapes(cfg, kind)
-                    pools += [((cfg.layers_of(kind), B, *state), _P(), jnp.float32),
-                              ((cfg.layers_of(kind), B, *tail), _P())]
-                    continue
-                tokens = (B * ring_pages(kind.window, ps) if kind.window else P_total) * ps
-                pools += [((cfg.layers_of(kind), cfg.kv_heads, tokens, self._kv_width),
-                           _P(None, "tensor", None, None))] * 2
-            self._tok_axis = 2
-        self.cache = tuple(_pool_zeros(*pool) for pool in pools)
-        # the pools addressed by the slot, which hold no pages of tokens
-        self._slot_pools = {i for kind in self._recurrent for i in range(len(pools))[self._kind_pools[kind.name]]}
+        # sliced out and stacked back every layer (PERF.md section 6, PR 25 and
+        # PR 29), and so is a pool a scan only reads, if its consumer prefers
+        # another layout. So decode carries the pools through both its scans
+        # with the layer index in xs, and the layer scans of prefill see a
+        # prompt's rows and never a pool.
+        self.cache = tuple(pool for rule in self.rules for pool in rule.allocate(self.mesh))
         # stats()["startup"]: what each kind's pools take
-        self._ring_pages = ring_pages(self._window, ps) if self._window else 0
-        # The pages of a sequence a paged kernel takes a grid step, by the
-        # layer's window (0: none), from a page as a device holds it: K and V
-        # rows of its KV heads, or a latent layer's one row.
-        heads, width = (1, self._row_width) if cfg.latent else (cfg.kv_heads // max(tp, 1), self._kv_width)
-        self._group = {w: group_pages(heads, ps, width, self.cache[0].dtype.itemsize, self.ppseq, w)
-                       for w in {0, self._window}}
-        self.pool_bytes = {name: sum(pool.nbytes for pool in self.cache[sl]) for name, sl in self._kind_pools.items()}
+        self.pool_bytes = {rule.name: sum(pool.nbytes for pool in self.cache[rule.sl]) for rule in self.rules}
         self.free_pages: deque = deque(range(1, P_total))  # page 0 = dead sink
         self.page_tables = np.zeros((B, self.ppseq), np.int32)
         # A mirror is replicated over the mesh whoever wrote it last (a decode
@@ -580,12 +375,8 @@ class LLMEngine:
         self._key = jax.random.PRNGKey(self.ec.seed + 1)
         self._prefill_jit: dict[int, Any] = {}
         self.mosaic: dict[str, bool] = {}  # filled by warmup()
-        # Prefix KV cache: chained digests — sha1(tokens[:n]) -> {"pages":
-        # (...), "prompt_len": n} for every page-aligned prefix n of a
-        # retired prompt plus its full length, LRU-ordered. Pages are shared
-        # across the chain entries of one prompt and refcounted
-        # (_page_refs); a page returns to the free list only when its last
-        # referencing entry is evicted.
+        # Prefix KV cache (_cache_insert): sha1(tokens[:n]) -> {"pages": (...),
+        # "prompt_len": n}, LRU-ordered; _page_refs counts the entries a page is in.
         self._prefix_cache: "OrderedDict[bytes, dict]" = OrderedDict()
         self._page_refs: dict[int, int] = {}
         self.prefix_hits = 0
@@ -596,11 +387,7 @@ class LLMEngine:
                 f"chunked_prefill {self.ec.chunked_prefill} must be a "
                 f"multiple of page_size {ps}"
             )
-        # Slots mid chunked-prefill: slot index -> full prompt tokens. Their
-        # DEVICE length/page-table rows stay zeroed until the final chunk
-        # lands (the decode block walks and writes nothing for them), so
-        # decode interleaves with an in-progress prefill without scribbling
-        # on the pages the chunks are filling.
+        # Slots mid chunked-prefill: slot index -> full prompt tokens (_masked).
         self._prefilling: dict[int, np.ndarray] = {}
         # Padded rows copy page 0 onto itself (the dead sink) — static [ppseq]
         # shape, one compiled program for any hit size.
@@ -608,12 +395,8 @@ class LLMEngine:
         # Context-page buckets for the tail-prefill program (partial prefix
         # hits): powers of two up to the page-table width, so the
         # compiled-program count stays |buckets| x log(ppseq).
-        cs, c = [], 1
-        while c < self.ppseq:
-            cs.append(c)
-            c *= 2
-        cs.append(self.ppseq)
-        self.c_buckets = tuple(sorted(set(cs)))
+        self.c_buckets = tuple(sorted({2 ** i for i in range(self.ppseq.bit_length()) if 2 ** i < self.ppseq}
+                                      | {self.ppseq}))
         self._tail_jit: dict[tuple, Any] = {}
         self._decode_jit = jax.jit(self._decode_impl, donate_argnums=(1,), static_argnames=("n_steps",))
         # The one-block look-ahead (step()): the block the device was handed
@@ -623,16 +406,12 @@ class LLMEngine:
         self._carry: dict[str, dict] = {}
         self._gone: list[int] = []
         self._drop_rows_jit = jax.jit(self._drop_rows_impl)
-        # Buckets: page-size multiples only (a prefill writes whole pages). The
-        # configured lengths, max_seq, and a rung between any two neighbours a
-        # doubling or more apart, so that a prompt's padding is at most a third
-        # of its bucket and not a half.
+        # Page-size multiples only (a prefill writes whole pages).
         self.buckets = bucket_ladder(self.ec.prefill_buckets, ps, S)
         # Prefill group sizes, largest-first: the sizes formed at the shortest
         # bucket (group_sizes says which of them a longer bucket keeps).
         self.k_buckets = (8, 4, 2, 1)
-        # Decode block sizes: full (empty queue) and short (queue pressure —
-        # waiting requests reach prefill sooner between shorter blocks).
+        # Decode block sizes: full, and short under queue pressure (_fit).
         self.block_sizes = tuple(sorted({self.ec.decode_block, max(1, self.ec.decode_block // 4)}))
 
     # -- page accounting ---------------------------------------------------
@@ -661,6 +440,33 @@ class LLMEngine:
         return m
 
     # -- jitted programs ---------------------------------------------------
+    def _rule(self, kind):
+        """The cache rule of a layer's kind, as ``run_layers`` names it to a scan body."""
+        return self.rules[self.cfg.kinds.index(kind)]
+
+    def _rule_pools(self, cache, fn) -> tuple:
+        """The pools with each rule's own replaced by ``fn(rule, its pools)``."""
+        cache = list(cache)
+        for rule in self.rules:
+            cache[rule.sl] = fn(rule, cache[rule.sl])
+        return tuple(cache)
+
+    def _device_counts(self, of) -> dict:
+        """name -> ``of(rule)``'s entry for it, over the rules' ``device_counts``
+        in the pools' order; rules of one class count the same thing once."""
+        return {name: c for rule in self.rules for name, c in zip(rule.device_counts, of(rule))}
+
+    def _places(self, slots) -> tuple:
+        """What a prefill group is told of its requests' ``slots`` [k] beside
+        their pages, as the programs' last operand: the place of the first
+        rule that wants one (``__init__`` has refused rules whose places
+        differ), nothing where none does."""
+        for rule in self.rules:
+            place = rule.place(slots)
+            if place is not None:
+                return (jnp.asarray(place),)
+        return ()
+
     def _prompt_layers(self, params, x, scan_fn, ctx=None):
         """A prompt's hidden state through every layer, by ``scan_fn(h, lp,
         kind, *a layer's slice of each of ctx) -> (h, rows)``; ctx: every
@@ -668,137 +474,25 @@ class LLMEngine:
         rows, one array a pool, in the pools' order: [a kind's layers, ...])."""
         xs = None
         if ctx is not None:
-            xs = {name: tuple(ctx[sl]) for name, sl in self._kind_pools.items()}
+            xs = {rule.name: tuple(ctx[rule.sl]) for rule in self.rules}
         x, rows = run_layers(lambda h, lp, kind, _index, *c: scan_fn(h, lp, kind, *c), x, params, self.cfg, xs)
-        return x, [r for name in self._kind_pools for r in rows[name]]
-
-    def _read_pages(self, pool, page_idxs):
-        """Pages ``page_idxs`` [n] of every layer, side by side along the
-        pool's token axis: [L, KV, n * ps, Hd] or [L, n * ps, W] (unrolled: n
-        is small and static)."""
-        ps, ax = self.ec.page_size, self._tok_axis
-        page = pool.shape[:ax] + (ps,) + pool.shape[ax + 1:]
-        zeros = (0,) * pool.ndim
-        return jnp.concatenate(
-            [jax.lax.dynamic_slice(pool, zeros[:ax] + (page_idxs[i] * ps,) + zeros[ax + 1:], page)
-             for i in range(page_idxs.shape[0])], axis=ax)
-
-    def _write_pages(self, cache, rows, page_idxs):
-        """A prompt's fresh rows, one array a pool (``rows[i]`` as the pool
-        but n * ps tokens long, in its dtype), into pages ``page_idxs`` [n] of
-        the carried pools: one in-place ``dynamic_update_slice`` of a page's
-        rows a page and pool (the rule: where the pools are made,
-        ``__init__``). Trailing page ids 0 send a bucket's padding to the
-        dead sink."""
-        ps, ax = self.ec.page_size, self._tok_axis
-        cache = list(cache)
-        with jax.named_scope("kv_write"):
-            for p in range(page_idxs.shape[0]):
-                for i, new in enumerate(rows):
-                    at = (0,) * ax + (page_idxs[p] * ps,) + (0,) * (new.ndim - ax - 1)
-                    cache[i] = jax.lax.dynamic_update_slice(
-                        cache[i], jax.lax.slice_in_dim(new, p * ps, (p + 1) * ps, axis=ax), at)
-        return tuple(cache)
-
-    def _write_ring(self, pools, rows, ring, length):
-        """A prompt's last window of rows into a slot's ring, for one window
-        kind: ``pools`` its two pools, ``rows`` the prompt's fresh rows of its
-        layers (as the pools but the bucket's tokens long), ``ring`` the
-        slot's first ring page, ``length`` the prompt's. The pages that hold
-        positions length - window .. length - 1 are at most the ring's, and
-        page j goes to ring page j % ring; a prompt of fewer pages writes its
-        first page again where it has no further one. What the bucket padded
-        behind ``length`` lands in the last page's later rows, which a
-        query's length masks until decode has overwritten them, as in a full
-        layer's page."""
-        ps, n_ring = self.ec.page_size, self._ring_pages
-        pools = list(pools)
-        last = (length - 1) // ps
-        with jax.named_scope("kv_write"):
-            for r in range(n_ring):
-                j = jnp.maximum(last - r, 0)
-                for i, new in enumerate(rows):
-                    pools[i] = jax.lax.dynamic_update_slice(
-                        pools[i], jax.lax.dynamic_slice_in_dim(new, j * ps, ps, axis=2),
-                        (0, 0, (ring + j % n_ring) * ps, 0))
-        return pools
-
-    def _write_prompt(self, cache, rows, page_idxs, place, length):
-        """A prompt's fresh rows (one array a pool) into the carried pools, by
-        layer kind: pages of its page table for a kind that keeps every token
-        (``_write_pages``), its last window into the slot's ring for a kind
-        with a window (``_write_ring``; ``place`` the slot's first ring
-        page), and for a recurrent kind the state and the convolution tail the
-        prompt left, every layer's, into its slot (``place``) of the two
-        pools: one in-place ``dynamic_update_slice`` a pool."""
-        if not self._window and not self._recurrent:
-            return self._write_pages(cache, rows, page_idxs)
-        cache = list(cache)
-        for kind in self.cfg.kinds:
-            sl = self._kind_pools[kind.name]
-            if kind.recurrent:
-                with jax.named_scope("state_write"):
-                    cache[sl] = [jax.lax.dynamic_update_slice(pool, new[:, None].astype(pool.dtype),
-                                                              (0, place) + (0,) * (pool.ndim - 2))
-                                 for pool, new in zip(cache[sl], rows[sl])]
-            elif kind.window:
-                cache[sl] = self._write_ring(cache[sl], rows[sl], place, length)
-            else:
-                cache[sl] = self._write_pages(cache[sl], rows[sl], page_idxs)
-        return tuple(cache)
+        return x, [r for rule in self.rules for r in rows[rule.name]]
 
     def _copy_pages_impl(self, cache, src, dst):
         """A prefix-cache hit's pages ``src`` copied onto ``dst`` ([ppseq]
         each). Every source page is read before the first is written: a
         hit's source and target pages never overlap, but padded rows all
-        name page 0. On an earlier stack a looped or gathered form made XLA
-        copy the whole pool a page (~450-570 ms on v5e against ~24 ms
-        unrolled); on the current one (jax 0.9.0) a carried, donated pool is
-        updated in place (the rule: where the pools are made), and compiled
-        for a v5e this program holds under a megabyte beside the pools,
-        kv_heads-sharded or on one chip (PERF.md section 6, PR 29)."""
-        return self._write_pages(cache, [self._read_pages(pool, src) for pool in cache], dst)
-
-    def _prompt_attend(self, lp, seg, dtypes, kind, length):
-        """The ``attend`` of a prompt over its own fresh rows, and what it
-        keeps of them for the pools: the K and V rows of a head (attended
-        inside the kind's window where it has one), or a latent layer's
-        [c | k_rope] rows (expanded to keys and values here, for the prompt
-        alone), or for a recurrent layer the state and the convolution's last
-        inputs as they stand at the prompt's ``length``: the bucket's padding
-        behind it leaves the state alone (no step, no decay)."""
-        cfg = self.cfg
-        if kind.recurrent:
-            name, over_a_prompt, _ = recurrence(kind)
-
-            def rule(ops, window):
-                def real(a):  # the log decay or the step size [1, P, ...], zero behind the prompt's length
-                    return jnp.where((seg == 0).reshape(seg.shape + (1,) * (a.ndim - 2)), a, 0.0)
-                with jax.named_scope(f"{name}_chunk"):
-                    o, state = over_a_prompt(*ops[:-2], real(ops[-2]), real(ops[-1]), out_dtype=cfg.dtype)
-                # positions length - (T - 1) .. length - 1: the window leads with the T - 1 before position 0
-                tail = jax.lax.dynamic_slice_in_dim(window[0], length, kind.conv_size - 1, axis=0)
-                return o, (state[0], tail.reshape(slot_state_shapes(cfg, kind)[1]))
-            return None, rule
-        if not cfg.latent:
-            k_dtype, v_dtype = dtypes[self._kind_pools[kind.name]]
-
-            def attend(q, k, v):
-                o = _prompt_attention(q, k, v, seg, self.mesh, window=kind.window)
-                return o, (_kv_rows(k, k_dtype, self._kv_width), _kv_rows(v, v_dtype, self._kv_width))
-            return attend
-
-        def attend(q, c, k_rope):
-            k, v = latent_expand(lp, c, k_rope, c.dtype)
-            o = _prompt_attention(jnp.concatenate(q, axis=-1), k, v, seg, self.mesh, latent_scale(cfg))
-            return o, (_row_major(_latent_rows(c[0], k_rope[0], self._row_width, dtypes[0])),)
-        return attend
+        name page 0. A carried, donated pool is updated in place (the rule:
+        where the pools are made): compiled for a v5e this program holds
+        under a megabyte beside the pools, kv_heads-sharded or on one chip
+        (PERF.md section 6, PR 29)."""
+        return self._rule_pools(cache, lambda rule, pools: rule.write_pages(
+            pools, [rule.read_pages(pool, src) for pool in pools], dst))
 
     def _prefill_impl(self, params, cache, tokens, length, page_idxs, key, temp, top_p, top_k, place=None):
         """tokens: [P] (padded to the bucket); page_idxs: [P // ps] page ids
-        (trailing entries may be 0 = dead sink); place: the slot's first ring
-        page, for a model with window layers, or the slot, for one with
-        recurrent layers. Returns the pools with the
+        (trailing entries may be 0 = dead sink); place: what ``_places`` tells
+        the program of the request's slot. Returns the pools with the
         prompt's pages written and the first generated token. Attention
         runs on the layer's fresh K/V, so the layer scan never sees a pool:
         it hands out every layer's rows as ``ys`` and the pages are written
@@ -809,14 +503,14 @@ class LLMEngine:
             x = embed_tokens(params, tokens, cfg)[None]  # [1,P,D]
         pos = jnp.arange(P, dtype=jnp.int32)[None]
         seg = (pos >= length).astype(jnp.int32)  # pads = their own segment
-        dtypes = [pool.dtype for pool in cache]
 
         def scan_fn(h, lp, kind):
-            h, _aux, rows = decoder_block(h, lp, cfg, pos, self._prompt_attend(lp, seg, dtypes, kind, length), kind)
+            h, _aux, rows = decoder_block(h, lp, cfg, pos, self._rule(kind).prompt_attend(lp, seg, length), kind)
             return h, rows
 
-        x, rows = self._prompt_layers(params, x, scan_fn)  # rows[i]: [L,KV,P,Hd] or [L,P,W]
-        cache = self._write_prompt(cache, rows, page_idxs, place, length)
+        x, rows = self._prompt_layers(params, x, scan_fn)  # rows[i]: as pool i but the bucket's tokens long
+        cache = self._rule_pools(cache, lambda rule, pools: rule.write_prompt(
+            pools, rows[rule.sl], page_idxs, place, length))
         with jax.named_scope("lm_head"):
             x = _rms_norm(x, params["final_norm"], cfg.norm_eps)
             last = jax.lax.dynamic_index_in_dim(x[0], length - 1, axis=0, keepdims=False)
@@ -826,120 +520,49 @@ class LLMEngine:
                                top_k[None], key, cap=self.ec.sample_topk_cap)[0]
         return cache, tok
 
-    def _decode_attend(self, lp, kind, pools, seen, page_tables, layer, walks):
-        """The ``attend`` of one decode step in one layer of ``kind``, the
-        ``layer``-th of its kind: the step's rows written at position
-        seen - 1 of each slot's pages (of its ring, in a layer with a window)
-        and the queries attended over them, by the kernel where it can run
-        (on the step's walk of the kind's live pages) and by the einsum
-        reference elsewhere, which GSPMD partitions as-is under TP. A latent
-        layer absorbs its key and value projections into the query and the
-        output here. Hands on every pool, the kind's own replaced."""
-        cfg = self.cfg
-        on_tpu = walks is not None  # decided once a program, where the walks are built
-        sl = self._kind_pools[kind.name]
-        if kind.recurrent:
-            state, tails = pools[sl]
-            live = seen > 0
-            tail = jax.lax.dynamic_index_in_dim(tails, layer, 0, keepdims=False)  # [B, T - 1, ...]
-            name, _, one_token = recurrence(kind)
-
-            def rule(ops, window):
-                # a slot without a request keeps its tail and its state as they were
-                kept = jnp.where(live.reshape((-1,) + (1,) * (tail.ndim - 1)),
-                                 window[:, 1:].astype(tails.dtype).reshape(tail.shape), tail)
-                with jax.named_scope(f"{name}_step"):
-                    o, new_state = one_token(*(a[:, 0] for a in ops), state, layer, live)
-                new_tails = jax.lax.dynamic_update_slice(tails, kept[None], (layer,) + (0,) * (tails.ndim - 1))
-                return o[:, None], pools[:sl.start] + (new_state, new_tails) + pools[sl.stop:]
-            return tail, rule
-        if not cfg.latent:
-            if not on_tpu:
-                paged_attend = paged_attention_reference if not kind.window else (
-                    lambda q, k, v, kp, vp, seen, _table, layer:
-                    window_attention_reference(q, k, v, kp, vp, seen, layer, kind.window))
-            elif kind.window:
-                paged_attend = functools.partial(paged_attention, walk=walks[kind.window], window=kind.window)
-            else:
-                paged_attend = functools.partial(paged_attention, mesh=self.mesh, walk=walks[0])
-
-            def attend(q, k_new, v_new):
-                with jax.named_scope("window_attn" if kind.window else "paged_attn"):
-                    # writes k_new / v_new at position lens of each slot's
-                    # pages (page_tables[b, lens // ps], offset lens % ps; of
-                    # its ring, in a layer with a window); the call pads q
-                    # and the token's rows where the pools' rows are wider
-                    o, kp2, vp2 = paged_attend(
-                        q[:, 0], k_new[:, 0], v_new[:, 0], *pools[sl], seen, page_tables, layer,
-                    )  # o: [B, H, Hd]
-                return o[:, None], pools[:sl.start] + (kp2, vp2) + pools[sl.stop:]
-            return attend
-
-        walk = walks[0] if on_tpu else None
-        latent_attend = functools.partial(
-            latent_paged_attention, walk=walk) if on_tpu else latent_attention_reference
-
-        def attend(q, c, k_rope):
-            q_nope, q_rope = q
-            dt = c.dtype
-            with jax.named_scope("latent_attn"):
-                qt = latent_absorb(lp, q_nope[:, 0], dt)
-                q_row = _latent_rows(qt, q_rope[:, 0], self._row_width, dt)  # [B, H, W]
-                row = _latent_rows(c[:, 0], k_rope[:, 0], self._row_width, dt)  # [B, W]
-                ctx, pool = latent_attend(
-                    q_row, row, pools[0], seen, page_tables, layer,
-                    v_width=cfg.kv_lora_rank, scale=latent_scale(cfg))  # ctx: [B, H, R]
-                o = latent_values(lp, ctx, dt)
-            return o[:, None], (pool,)
-        return attend
-
     def _decode_impl(self, params, cache, last_tokens, lengths, page_tables, key, n_steps, temps, top_ps, top_ks):
         """n_steps tokens for every slot in ONE device program (outer scan
         over steps, inner scan over layers): one host round trip per block.
         Returns (cache, toks [n_steps, B], last', lengths', counts): counts
-        is None for a model without held experts or recurrent layers. Held
-        experts give two int32, the routed (token, expert) pairs that landed
-        on held experts and the live tiles of the grouped matmul (a tile
-        reads its expert's matrices), both summed over the block's steps and
-        the routed layers; recurrent layers one more behind them, the slots whose
-        state a step rewrote (in each such layer), summed over the steps.
+        is None for a model without held experts whose rules count nothing.
+        Held experts give two int32, the routed (token, expert) pairs that
+        landed on held experts and the live tiles of the grouped matmul (a
+        tile reads its expert's matrices), both summed over the block's steps
+        and the routed layers; behind them what the rules count on the device
+        (``device_counts``), summed over the steps.
 
-        How the pools are threaded (the rule: where the pools are made):
-        both scans CARRY the pools whole (the token axis split into pages and
-        rows, a free reshape); the layer scan's ``xs`` are the layer's weights and
-        its index. The one Mosaic call of a layer is told the layer by an
-        operand, reads the pages where they lie, and writes each slot's new
-        row itself, into the pool its outputs alias: no operation of
-        this program but that call has a pool, or a layer's slice of one,
-        for operand or result (11-14 ms a step and an eighth of a pool of
-        temporaries; PERF.md section 6, PR 25)."""
+        Both scans CARRY the pools whole (the rule: where the pools are
+        made), each in its rule's ``in_pages`` shape; the layer scan's ``xs``
+        are the layer's weights and its index. The one Mosaic call of a layer
+        is told the layer by an operand and writes each slot's new row itself,
+        into the pool its outputs alias: no other operation of this program
+        has a pool, or a layer's slice of one, for operand or result."""
         cfg = self.cfg
-        ps, ax = self.ec.page_size, self._tok_axis
         flat = [pool.shape for pool in cache]  # as every other program has them
-        paged = [shape if i in self._slot_pools else shape[:ax] + (-1, ps) + shape[ax + 1:]
-                 for i, shape in enumerate(flat)]
+        paged = [rule.in_pages(shape) for rule in self.rules for shape in flat[rule.sl]]
+        walkers = {rule.walk_key: rule for rule in self.rules if rule.walk_key is not None}
 
         def one_step(carry, step_key):
             pools, last, lens = carry
-            # The step's walk of live pages, built here, once for all layers:
-            # lengths change between steps and not between layers.
             seen = lens + 1  # the kernel's lengths count the step's own token
             on_tpu = jax.default_backend() == "tpu"
             # 0 for a slot with no pages (empty, or masked while it
             # prefills): no grid step, no row written, zeros attended.
-            # Two walks at most: the layers that keep every token (0) and
-            # the window layers.
             seen = jnp.where(page_tables[:, 0] > 0, seen, 0)
-            walks = ({w: page_groups(seen, page_tables, ps, w, n) for w, n in sorted(self._group.items())}
-                     if on_tpu else None)
+            # The step's walks of live pages, built here, once for all layers
+            # (lengths change between steps and not between layers), one for
+            # the rules that share a key; None where no kernel runs.
+            walks = {key: walkers[key].walk(seen, page_tables) for key in sorted(walkers)} if on_tpu else None
             with jax.named_scope("embed"):
                 x = embed_tokens(params, last, cfg)[:, None, :]  # [B,1,D]
 
             def scan_fn(carry, lp, kind, layer):
                 h, pools = carry
-                h, aux, pools = decoder_block(
+                rule = self._rule(kind)
+                h, aux, kept = decoder_block(
                     h, lp, cfg, lens[:, None],
-                    self._decode_attend(lp, kind, pools, seen, page_tables, layer, walks), kind)
+                    rule.decode_attend(lp, pools[rule.sl], seen, page_tables, layer, walks), kind)
+                pools = pools[:rule.sl.start] + tuple(kept) + pools[rule.sl.stop:]
                 return (h, pools), (aux if cfg.experts_held and "router" in lp else None)
 
             (x, pools), auxes = run_layers(scan_fn, (x, pools), params, cfg)
@@ -947,9 +570,7 @@ class LLMEngine:
             for aux in auxes.values():  # of a kind's routed layers; None where it has none
                 if aux is not None:
                     counts = jnp.sum(aux, axis=0) if counts is None else counts + jnp.sum(aux, axis=0)
-            if self._recurrent:
-                # the slots whose state this step rewrote in every recurrent layer: the step calls' grid
-                rows = jnp.sum(seen > 0, dtype=jnp.int32).reshape(1)
+            for rows in self._device_counts(lambda rule: rule.step_counts(seen)).values():
                 counts = rows if counts is None else jnp.concatenate([counts, rows])
             with jax.named_scope("lm_head"):
                 x = _rms_norm(x, params["final_norm"], cfg.norm_eps)
@@ -982,10 +603,9 @@ class LLMEngine:
         prefill, so the group saves host work (``prefill_dispatch``), not
         device time: the k requests run one after another and each reads
         the weights. The request scan carries the donated pools; each
-        request writes its pages into them in place (_write_pages).
+        request writes its rows into them in place (``write_prompt``).
         tokens: [k, P]; page_rows: [k, P // ps], each request's pages; places:
-        [k], each request's slot's first ring page (a model with window
-        layers) or its slot (one with recurrent layers); None without either."""
+        [k], what ``_places`` tells of each request's slot; None: nothing."""
         keys = jax.random.split(key, tokens.shape[0])
 
         def scan_req(cache, xs):
@@ -1006,8 +626,8 @@ class LLMEngine:
         sampled first token is that of a cold full prefill (in f32; in bf16
         this is einsum attention where the cold prefill runs the flash
         kernel — not compared on the chip) while prefill compute scales
-        with the tail length. A latent layer expands context and tail rows
-        alike to keys and values (the plain path; no absorbed form here).
+        with the tail length. Only a rule that keeps pages has the three
+        methods this program calls.
 
         tokens: [Tb] padded tail; start/length: scalars (start page-aligned);
         ctx_pages: [C] context page ids (trailing 0 = dead, masked by
@@ -1028,50 +648,17 @@ class LLMEngine:
         )
         tail_mask = (tpos[None, :] <= tpos[:, None]) & ((start + tpos)[None, :] < length)
         mask = jnp.concatenate([ctx_mask, tail_mask], axis=1)
-        dtypes = [pool.dtype for pool in cache]
-
-        def heads_attend(kind, ctx_k, ctx_v):
-            KV, Hd = cfg.kv_heads, cfg.head_dim
-            group = kind.n_heads // KV
-
-            def attend(q, k_new, v_new):
-                kt = _kv_rows(k_new, dtypes[0], self._kv_width)  # [KV,Tb,Hd], or as wide as the pool's rows
-                vt = _kv_rows(v_new, dtypes[1], self._kv_width)
-                kall = jnp.concatenate([ctx_k, kt], axis=1)[..., :Hd]  # [KV, C*ps+Tb, Hd]
-                vall = jnp.concatenate([ctx_v, vt], axis=1)[..., :Hd]
-                qg = q[0].reshape(Tb, KV, group, Hd)
-                scores = jnp.einsum("tkgh,ksh->tkgs", qg, kall).astype(jnp.float32)
-                scores = scores / math.sqrt(Hd)
-                scores = jnp.where(mask[:, None, None, :], scores, -1e30)
-                pr = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-                o = jnp.einsum("tkgs,ksh->tkgh", pr, vall).reshape(1, Tb, kind.n_heads, Hd)
-                return o, (kt, vt)
-            return attend
-
-        def latent_attend(lp, ctx_rows):
-            R, rope = cfg.kv_lora_rank, cfg.qk_rope_head_dim
-
-            def attend(q, c, k_rope):
-                rows = _latent_rows(c[0], k_rope[0], self._row_width, dtypes[0])  # [Tb, W]
-                every = jnp.concatenate([ctx_rows, rows], axis=0)[None]  # [1, C*ps+Tb, W]
-                k, v = latent_expand(lp, every[..., :R], every[..., R:R + rope], c.dtype)
-                scores = jnp.einsum("bthk,bshk->bhts", jnp.concatenate(q, axis=-1), k).astype(jnp.float32)
-                scores = jnp.where(mask[None, None], scores * latent_scale(cfg), -1e30)
-                pr = jax.nn.softmax(scores, axis=-1).astype(c.dtype)
-                return jnp.einsum("bhts,bshk->bthk", pr, v), (rows,)
-            return attend
-
         def scan_fn(h, lp, kind, *ctx):
-            attend = latent_attend(lp, *ctx) if cfg.latent else heads_attend(kind, *ctx)
-            h, _aux, rows = decoder_block(h, lp, cfg, pos, attend, kind)
+            h, _aux, rows = decoder_block(h, lp, cfg, pos, self._rule(kind).tail_attend(lp, mask, *ctx), kind)
             return h, rows
 
         # The cached context of every layer, gathered once from the whole
         # pools, so that the layer scan sees a prompt's rows and never a pool.
-        x, rows = self._prompt_layers(params, x, scan_fn, [self._read_pages(pool, ctx_pages) for pool in cache])
+        x, rows = self._prompt_layers(params, x, scan_fn, [
+            rule.read_pages(pool, ctx_pages) for rule in self.rules for pool in cache[rule.sl]])
         # The gather above reads positions < start and this lands on the
         # tail's pages, so the write can follow the scan.
-        cache = self._write_pages(cache, rows, tail_pages)
+        cache = self._rule_pools(cache, lambda rule, pools: rule.write_pages(pools, rows[rule.sl], tail_pages))
         x = _rms_norm(x, params["final_norm"], cfg.norm_eps)
         last = jax.lax.dynamic_index_in_dim(x[0], length - 1 - start, axis=0, keepdims=False)
         logits = hidden_logits(params, last, cfg)
@@ -1188,10 +775,9 @@ class LLMEngine:
                     jnp.zeros(k, jnp.float32), jnp.ones(k, jnp.float32),
                     jnp.zeros(k, jnp.int32),
                 )
-                if self._window or self._recurrent:
-                    # slot 0's ring takes the dummy rows: a length masks whatever a ring held before;
-                    # slot 0's state and tail, which the prefill of the slot's next request replaces
-                    args += (jnp.zeros(k, jnp.int32),)
+                # slot 0 takes the dummy rows: a length masks whatever its ring held before, and the
+                # prefill of its next request replaces its state and tail
+                args += self._places(np.zeros(k, np.int32))
                 entry = {"program": "prefill", "bucket": b, "k": k}
                 if "prefill" not in self.mosaic:
                     # The first program only: compiling all of them ahead
@@ -1466,9 +1052,7 @@ class LLMEngine:
         ph = self._phases
         ph.begin("admit", waiting=len(self.waiting), n_admitted=0, n_prefill=0, prefill_tokens=0, prefill_padded=0,
                  pages_reserved=0, block=0, ahead=0, dropped_rows=0, active=0, sampled=0, live_pages=0, grid_steps=0,
-                 expert_pairs=0, expert_tiles=0,
-                 **({"window_pages": 0, "window_tokens": 0} if self._window else {}),
-                 **({"state_rows": 0, "states_written": 0} if self._recurrent else {}))
+                 expert_pairs=0, expert_tiles=0, **{key: 0 for rule in self.rules for key in rule.zeroes})
         try:
             return self._step(ph)
         finally:
@@ -1625,19 +1209,15 @@ class LLMEngine:
                     pgs[j] = self.page_tables[i, :n_pg]  # trailing zeros -> dead sink
                 idx_arr = jnp.asarray(np.asarray(idxs, np.int32))
                 self._key, sub = jax.random.split(self._key)
-                places = ()
-                if self._window:  # each slot's first ring page, the same in every window kind's pools
-                    places = (jnp.asarray(np.asarray(idxs, np.int32) * self._ring_pages),)
-                elif self._recurrent:  # each request's slot, where a recurrent kind's pools keep its state
-                    places = (idx_arr,)
-                    ph.rec["states_written"] += k
+                for key, c in {key: c for rule in self.rules for key, c in rule.prefill_counts(k).items()}.items():
+                    ph.rec[key] += c
                 self.cache, toks_dev = self._prefill(bucket, k)(
                     self.params, self.cache,
                     jnp.asarray(padded), jnp.asarray(lens), jnp.asarray(pgs), sub,
                     jnp.asarray(self.samp_temps[idxs]),
                     jnp.asarray(self.samp_top_ps[idxs]),
                     jnp.asarray(self.samp_top_ks[idxs]),
-                    *places,
+                    *self._places(np.asarray(idxs, np.int32)),
                 )
                 enqueued = time.monotonic()
                 for i in idxs:
@@ -1664,15 +1244,11 @@ class LLMEngine:
             ph.rec["n_prefill"] += 1
             dispatched.append(([(i, req_id, tokens, None, _mt, arrived)], toks_dev))
         # 2c. chunked prefill: advance every mid-prefill slot by ONE chunk —
-        # the interleave contract is at most one chunk of prefill compute
-        # PER IN-FLIGHT PREFILL between consecutive decode blocks, so a
-        # 512-token prompt arriving while others decode costs them
-        # chunk-sized stalls, not a full-prompt stall (a burst of N long
-        # prompts stalls decode N chunks per step — still bounded and
-        # spread, vs N whole prompts back to back). The final chunk samples
-        # the request's first token and installs the slot's device mirrors
-        # (until then its device rows stay zeroed: decode writes nothing for
-        # it).
+        # at most one chunk of prefill compute PER IN-FLIGHT PREFILL between
+        # consecutive decode blocks (a burst of N long prompts stalls decode N
+        # chunks per step: bounded and spread, vs N whole prompts back to
+        # back). The final chunk samples the request's first token and
+        # installs the slot's device mirrors (_masked: zeroed until then).
         chunk_dispatched = bool(self._prefilling)
         for i in sorted(self._prefilling):
             slot = self.slots[i]
@@ -1802,9 +1378,9 @@ class LLMEngine:
             self.d_lengths, self.d_page_tables, sub, n,
             self.d_temps, self.d_top_ps, self.d_top_ks,
         )
-        rec["live_pages"], rec["grid_steps"] = self._live_pages(active, n)
-        if self._window:
-            rec["window_pages"], rec["window_tokens"] = self._window_walk(active, n)
+        lengths = self.lengths[active]  # as the block was dispatched
+        for rule in self.rules:
+            rec.update(rule.block_counts(lengths, n))
         self.lengths[active] += n
         for i in active:
             self.slots[i].n_generated += n
@@ -1844,12 +1420,10 @@ class LLMEngine:
         to("decode_fetch")
         if blk.counts is None:
             block_toks = np.asarray(jax.device_get(blk.toks))  # [n, B]
-        else:  # held experts' counts, then recurrent layers': they ride the same fetch
+        else:  # held experts' counts, then the rules': they ride the same fetch
             block_toks, counts = jax.device_get((blk.toks, blk.counts))
-            if self.cfg.experts_held:
-                blk.rec["expert_pairs"], blk.rec["expert_tiles"] = int(counts[0]), int(counts[1])
-            if self._recurrent:
-                blk.rec["state_rows"] = int(counts[-1])
+            names = ("expert_pairs", "expert_tiles") if self.cfg.experts_held else ()
+            blk.rec.update(zip((*names, *self._device_counts(lambda rule: rule.device_counts)), map(int, counts)))
         to("emit")
         rows = [(i, slot) for i, slot in blk.rows if self.slots[i] is slot]
         for step_i in range(blk.n):
@@ -1897,32 +1471,6 @@ class LLMEngine:
         self._gone.clear()
         self.d_lengths, self.d_page_tables = self._drop_rows_jit(
             self.d_lengths, self.d_page_tables, jnp.asarray(gone))
-
-    def _live_pages(self, active: list[int], n: int) -> tuple:
-        """(pages, grid steps) the paged kernel walks in a decode block of
-        ``n`` steps, a layer that keeps every token: a slot's
-        ceil(length / page_size) pages at each step, the step's own token
-        counted, in ceil(pages / group) steps of up to ``group`` pages each
-        (``ops/paged_attention.group_pages``); a slot that is not ``active``
-        (empty, or masked while it prefills) is walked by nobody. Pages over
-        n x max_slots x (max_seq / page_size) is the share of the page table
-        the kernel walks, pages over steps how full a grid step is. From the
-        host's mirror of the lengths as they stood when the block was
-        dispatched."""
-        seen = self.lengths[active][None, :] + np.arange(1, n + 1)[:, None]
-        pages = np.minimum(-(-seen // self.ec.page_size), self.ppseq)
-        return int(pages.sum()), int((-(-pages // self._group[0])).sum())
-
-    def _window_walk(self, active: list[int], n: int) -> tuple:
-        """(pages, positions attended) of ONE window layer in a decode block
-        of ``n`` steps, as ``_live_pages`` counts a full layer's: a slot
-        walks the pages from the one that holds position seen - window to
-        the current one and attends min(seen, window) positions, seen its
-        length with the step's own token."""
-        ps, w = self.ec.page_size, self._window
-        seen = self.lengths[active][None, :] + np.arange(1, n + 1)[:, None]
-        pages = (seen - 1) // ps - np.maximum(seen - w, 0) // ps + 1
-        return int(pages.sum()), int(np.minimum(seen, w).sum())
 
     def _maybe_finish(self, i: int, events: dict) -> bool:
         slot = self.slots[i]

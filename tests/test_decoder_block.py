@@ -76,3 +76,56 @@ def test_engine_has_no_layer_mathematics_of_its_own():
                     and any(r.slice.value == "wq" for a in call.args for r in _weight_reads(a))):
                 wq_einsums.append(f"{path.relative_to(RAY_TPU)}:{call.lineno}")
     assert len(wq_einsums) == 1 and wq_einsums[0].startswith("models/transformer.py"), wq_einsums
+
+
+RULE_READS = {"latent", "recurrent", "window", "mixer"}  # what says which cache rule a kind of layer has
+GONE_FROM_THE_ENGINE = {
+    "_window", "_recurrent", "_kind_pools", "_slot_pools", "_tok_axis", "_row_width", "_kv_width", "_ring_pages",
+    "_group", "_write_prompt", "_write_ring", "_prompt_attend", "_decode_attend", "_live_pages", "_window_walk",
+}
+
+
+def _imports(tree):
+    """Every module a file imports, `from a import b` as both a and a.b."""
+    mods = set()
+    for n in ast.walk(tree):
+        if isinstance(n, ast.Import):
+            mods |= {a.name for a in n.names}
+        elif isinstance(n, ast.ImportFrom):
+            mods |= {n.module or ""} | {f"{n.module}.{a.name}" for a in n.names}
+    return mods
+
+
+def test_engine_knows_no_cache_rule_of_its_own():
+    """llm/engine.py asks its rules (PR 49): it reads no attribute that says
+    which rule a layer has, names no rule class, keeps none of the attributes
+    and dispatchers that spelled the rules by `if`; llm/cache_rules.py imports
+    nothing of the engine, and reads what a kind is in ``rule_for`` alone."""
+    from ray_tpu.llm import cache_rules
+    from ray_tpu.llm.engine import LLMEngine
+
+    tree = ast.parse((RAY_TPU / "llm" / "engine.py").read_text())
+    attrs = {n.attr for n in ast.walk(tree) if isinstance(n, ast.Attribute)}
+    names = attrs | {n.id for n in ast.walk(tree) if isinstance(n, ast.Name)}
+    names |= {a.name for n in ast.walk(tree) if isinstance(n, ast.ImportFrom) for a in n.names}
+    names |= {n.name for n in ast.walk(tree) if isinstance(n, (ast.FunctionDef, ast.ClassDef))}
+    assert not attrs & RULE_READS, attrs & RULE_READS
+    rule_classes = {name for name, obj in vars(cache_rules).items()
+                    if isinstance(obj, type) and obj.__module__ == cache_rules.__name__}
+    assert {"PagedRows", "SlotRing", "SlotState", "LatentRows"} <= rule_classes
+    assert not names & rule_classes, names & rule_classes
+    assert not names & GONE_FROM_THE_ENGINE, names & GONE_FROM_THE_ENGINE
+    assert not [name for name in GONE_FROM_THE_ENGINE | {"paged"} if hasattr(LLMEngine, name)]
+    assert "paged" not in attrs  # nothing read LLMEngine.paged but a test
+    assert "rule_for" in names
+
+    rules = ast.parse((RAY_TPU / "llm" / "cache_rules.py").read_text())
+    assert not [m for m in _imports(rules) if m.startswith("ray_tpu.llm.engine") or m == "ray_tpu.llm"], \
+        _imports(rules)
+    picker = next(n for n in rules.body if isinstance(n, ast.FunctionDef) and n.name == "rule_for")
+    inside = {id(n) for n in ast.walk(picker)}
+    outside = [n for n in ast.walk(rules) if isinstance(n, ast.Attribute) and n.attr in RULE_READS
+               and id(n) not in inside]
+    # a rule may keep the window it was told as its own attribute, and read another rule's
+    assert [ast.unparse(n) for n in outside if not (
+        n.attr == "window" and isinstance(n.value, ast.Name) and n.value.id in ("self", "other"))] == []

@@ -31,7 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.llm import engine as engine_mod
+from ray_tpu.llm import cache_rules, engine as engine_mod
 from ray_tpu.llm.engine import EngineConfig, LLMEngine
 from ray_tpu.models import reference_ssm_hybrid as ref
 from ray_tpu.models.transformer import (
@@ -326,7 +326,7 @@ def test_pool_rows_wider_than_a_head_give_the_same_tokens(monkeypatch):
     params, prompt = _params(), _tokens(37, seed=5)
     narrow = LLMEngine(CFG, params=params, engine_config=EngineConfig(**ENGINE_KW))
     want = narrow.generate(prompt, max_tokens=14)["tokens"]
-    monkeypatch.setattr(engine_mod, "kv_row_width", lambda head_dim: 2 * head_dim)
+    monkeypatch.setattr(cache_rules, "kv_row_width", lambda head_dim: 2 * head_dim)
     wide = LLMEngine(CFG, params=params, engine_config=EngineConfig(**ENGINE_KW))
     assert wide.cache[2].shape[-1] == 32 and narrow.cache[2].shape[-1] == 16
     assert wide.generate(prompt, max_tokens=14)["tokens"] == want

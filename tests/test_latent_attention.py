@@ -11,7 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.llm import engine as engine_mod
+from ray_tpu.llm import cache_rules
 from ray_tpu.llm.engine import EngineConfig, LLMEngine
 from ray_tpu.models.transformer import TransformerConfig
 from ray_tpu.ops import grouped_matmul
@@ -227,13 +227,13 @@ def test_a_latent_model_through_the_kernel_with_empty_and_retiring_slots_emits_t
     want = [toks[: toks.index(eos) + 1] if eos in toks else toks for toks in solos]
 
     eng = LLMEngine(CFG, params=solo.params, engine_config=EngineConfig(**ENGINE_KW, eos_id=eos))
-    group = eng._group[0]
-    assert group == group_pages(1, 16, eng._row_width, 4, 8) == 8  # the rule every pool's group comes from
+    group = eng.rules[0].group
+    assert group == group_pages(1, 16, eng.rules[0].width, 4, 8) == 8  # the rule every pool's group comes from
 
     def as_on_a_tpu(*args):
         with monkeypatch.context() as m:  # while the program is traced, and no longer
             m.setattr(jax, "default_backend", lambda: "tpu")
-            m.setattr(engine_mod, "latent_paged_attention", functools.partial(latent_paged_attention, interpret=True))
+            m.setattr(cache_rules, "latent_paged_attention", functools.partial(latent_paged_attention, interpret=True))
             m.setattr(grouped_matmul, "expert_matmul", lambda: grouped_matmul.expert_gmm_reference)  # not under test
             return eng._decode_impl(*args)
 

@@ -330,8 +330,7 @@ def test_kv_layout_other_than_paged_is_refused():
     silently serve from another."""
     with pytest.raises(ValueError, match="removed in PR 30"):
         EngineConfig(max_slots=2, max_seq=128, kv_layout="dense")
-    assert LLMEngine(CFG, engine_config=EngineConfig(
-        max_slots=2, max_seq=128, page_size=16, kv_layout="paged")).paged is True
+    LLMEngine(CFG, engine_config=EngineConfig(max_slots=2, max_seq=128, page_size=16, kv_layout="paged"))
 
 
 # ---------------------------------------------------------------------------
@@ -635,7 +634,7 @@ def test_step_record_counts_the_grid_steps_the_decode_block_walks():
     blocks = [s for s in steps if s["block"]]
     assert len(blocks) == len(handed) > 3
     assert sorted(handed[0][0]) == [0, 3, ps, 2 * ps - 1]  # the fourth slot is empty
-    group = eng._group[0]
+    group = eng.rules[0].group
     assert group > 1  # so that a step holds more than a page
     for rec, (lens, tables, n) in zip(blocks, handed):
         live = tables[:, 0] > 0
@@ -809,17 +808,17 @@ def test_the_kernels_page_groups_with_empty_and_retiring_slots_emit_the_referenc
     pages, and pages of its active slots alone."""
     import functools
 
-    from ray_tpu.llm import engine as engine_mod
+    from ray_tpu.llm import cache_rules
     from ray_tpu.ops.paged_attention import paged_attention
 
     eos = _mid_block_eos(ahead_solos)
     eng = LLMEngine(CFG, engine_config=EngineConfig(**AHEAD_KW, eos_id=eos))
-    assert eng._group[0] > 1
+    assert eng.rules[0].group > 1
 
     def as_on_a_tpu(*args):
         with monkeypatch.context() as m:  # while the program is traced, and no longer
             m.setattr(jax, "default_backend", lambda: "tpu")
-            m.setattr(engine_mod, "paged_attention", functools.partial(paged_attention, interpret=True))
+            m.setattr(cache_rules, "paged_attention", functools.partial(paged_attention, interpret=True))
             return eng._decode_impl(*args)
 
     eng._decode_jit = jax.jit(as_on_a_tpu, donate_argnums=(1,), static_argnums=(6,))

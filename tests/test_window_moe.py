@@ -222,7 +222,7 @@ def test_the_step_record_counts_one_window_layers_walk_and_positions():
     steps = [s for s in eng.trace_snapshot()["steps"] if s["block"]]
     first = steps[0]  # the first block of 4 steps, lengths 71..74, all inside page 4
     assert first["block"] == 4
-    assert first["live_pages"] == 4 * 5 and first["grid_steps"] == 4 * -(-5 // eng._group[0])
+    assert first["live_pages"] == 4 * 5 and first["grid_steps"] == 4 * -(-5 // eng.rules[0].group)
     assert first["window_pages"] == 4 * 3 and first["window_tokens"] == 4 * 32
     dense = LLMEngine(TransformerConfig(vocab_size=64, d_model=32, n_layers=1, n_heads=4, d_ff=32),
                       engine_config=EngineConfig(max_slots=2, max_seq=64, page_size=16, prefill_buckets=(32,)))
