@@ -1,0 +1,8 @@
+"""expert_gmm_time_share (the grouped matmul's device time in the decode program, three calls a routed
+layer, over the device's busy time in the traced window), under a name of its own in the cell whose
+routed layers hold every expert: the lists it could join are held to their members by tests a PR
+that adds a cell may not edit (PERF.md section 7 asks the next benchmark PR to fold the names)."""
+
+
+def read(ctx):
+    return ctx.same_as("expert_gmm_time_share")

@@ -309,7 +309,7 @@ class SlotRing(CacheRule):
         # a prefill is told ONE place: a ring's first page (slot x ring pages) or the slot (ROADMAP M4 (e))
         if isinstance(other, SlotRing) and other.window != self.window:
             return f"window layers of one window are written; the pattern has {self.cfg.kinds}"
-        if isinstance(other, SlotState):
+        if isinstance(other, _BySlot):
             return (f"window layers beside {other.such} are not written: a prefill is told its slot's "
                     "ring or its slot (ROADMAP M4)")
         return None
@@ -358,7 +358,35 @@ class SlotRing(CacheRule):
         return {"window_pages": int(pages.sum()), "window_tokens": int(np.minimum(seen, w).sum())}
 
 
-class SlotState(CacheRule):
+class _BySlot(CacheRule):
+    """Pools addressed by the slot, [the kind's layers, max_slots, ...] each, that do not grow with the context: a
+    prefill is told its slot and leaves there what its prompt left, one in-place update a pool. A subclass says in
+    ``why`` what each option it refuses would need of it."""
+    tok_axis = None
+
+    def __init__(self, cfg, kind, ec, first, such):
+        super().__init__(cfg, kind, ec, first)
+        self.such = such  # the model's recurrent layers, as a refusal names them
+
+    def write_prompt(self, pools, rows, page_idxs, place, length):
+        """What the prompt left, every layer's, into slot ``place``: one in-place update a pool."""
+        with jax.named_scope("state_write"):
+            return [jax.lax.dynamic_update_slice(pool, new[:, None].astype(pool.dtype),
+                                                 (0, place) + (0,) * (pool.ndim - 2))
+                    for pool, new in zip(pools, rows)]
+
+    def place(self, slots):
+        return np.asarray(slots, np.int32)  # the slot itself
+
+    def refuses(self, option):
+        return f"{option} is not written for {self.such}: {self.why[option]} (ROADMAP M4)"
+
+    def step_counts(self, seen):
+        # the slots this step rewrote in every layer of the kind: the slots with pages (a step call's grid)
+        return (jnp.sum(seen > 0, dtype=jnp.int32).reshape(1),)
+
+
+class SlotState(_BySlot):
     """A state kept by slot, for both recurrences (``mixer="delta"``, ops/linear_attention.py; ``mixer="ssd"``,
     ops/ssd.py: the kind gives the shapes, ``slot_state_shapes``, and the two calls, ``recurrence``): such a layer
     keeps no rows of tokens. Its two pools are addressed by the slot and do not grow with the context: a state in
@@ -369,24 +397,16 @@ class SlotState(CacheRule):
     one grid step a live slot and none for an empty one, whose state stays bit for bit. Admission budgets pages for
     the layers that keep every token. A page copy cannot restore a state and a chunk of a prompt would have to
     start from one: no method restores (ROADMAP M4)."""
-    tok_axis = None
     zeroes = ("state_rows", "states_written")
     device_counts = ("state_rows",)
 
-    def __init__(self, cfg, kind, ec, first, such):
-        super().__init__(cfg, kind, ec, first)
-        self.such = such  # the model's recurrent layers, as a refusal names them
-
-    def refuses(self, option):
-        why = {
-            "prefix_cache":
-                "a hit copies pages, and a page copy cannot restore the state such a layer keeps of a prefix",
-            "chunked_prefill":
-                "a chunk would have to start from the state and the convolution tail the chunk before left, "
-                "and the prefill programs start from an empty one",
-            "tensor_parallel > 1": "the state pool is addressed by the slot and its kernels run on one chip",
-        }[option]
-        return f"{option} is not written for {self.such}: {why} (ROADMAP M4)"
+    why = {
+        "prefix_cache": "a hit copies pages, and a page copy cannot restore the state such a layer keeps of a prefix",
+        "chunked_prefill":
+            "a chunk would have to start from the state and the convolution tail the chunk before left, "
+            "and the prefill programs start from an empty one",
+        "tensor_parallel > 1": "the state pool is addressed by the slot and its kernels run on one chip",
+    }
 
     def pools(self):
         state, tail = slot_state_shapes(self.cfg, self.kind)
@@ -409,13 +429,6 @@ class SlotState(CacheRule):
             return o, (state[0], tail.reshape(slot_state_shapes(cfg, kind)[1]))
         return None, rule
 
-    def write_prompt(self, pools, rows, page_idxs, place, length):
-        """The state and the tail the prompt left, every layer's, into slot ``place``: one in-place update a pool."""
-        with jax.named_scope("state_write"):
-            return [jax.lax.dynamic_update_slice(pool, new[:, None].astype(pool.dtype),
-                                                 (0, place) + (0,) * (pool.ndim - 2))
-                    for pool, new in zip(pools, rows)]
-
     def decode_attend(self, lp, pools, seen, page_tables, layer, walks):
         state, tails = pools
         live = seen > 0
@@ -432,15 +445,47 @@ class SlotState(CacheRule):
             return o[:, None], (new_state, new_tails)
         return tail, rule
 
-    def place(self, slots):
-        return np.asarray(slots, np.int32)  # the slot itself
-
-    def step_counts(self, seen):
-        # the slots whose state this step rewrote in every recurrent layer: the step calls' grid
-        return (jnp.sum(seen > 0, dtype=jnp.int32).reshape(1),)
-
     def prefill_counts(self, k):
         return {"states_written": k}
+
+
+class SlotTail(_BySlot):
+    """A convolution's tail kept by slot and nothing beside it (``mixer="conv"``): the last conv_size - 1 rows of
+    what the layer's short convolution runs over, end to end in ONE pool [the kind's layers, max_slots, (T - 1) x
+    d_model] in the activations' dtype. Prefill cuts the tail at the prompt's own length, not at its bucket's end;
+    decode shifts a live slot's tail by the step's row and leaves an empty slot's bit for bit. No kernel, no float32.
+    No page holds a tail and a chunk of a prompt would have to start from one: no method restores (ROADMAP M4)."""
+    n_pools = 1
+    zeroes = ("tail_rows", "tails_written")
+    device_counts = ("tail_rows",)
+
+    why = {
+        "prefix_cache": "a hit copies pages, and no page holds the convolution tail such a layer keeps of a prefix",
+        "chunked_prefill":
+            "a chunk would have to start from the convolution tail the chunk before left, and the prefill "
+            "programs start from zeros",
+        "tensor_parallel > 1": "the tail pool is addressed by the slot, not through the page table a shard walks",
+    }
+
+    def pools(self):
+        return [((self.cfg.layers_of(self.kind), self.ec.max_slots, *slot_state_shapes(self.cfg, self.kind)[1]), _P())]
+
+    def prompt_attend(self, lp, seg, length):
+        def keep(window):  # rows length - (T - 1) .. length - 1: the window leads with the T - 1 before position 0
+            return (jax.lax.dynamic_slice_in_dim(window[0], length, self.kind.conv_size - 1, axis=0).reshape(-1),)
+        return None, keep
+
+    def decode_attend(self, lp, pools, seen, page_tables, layer, walks):
+        (tails,) = pools
+        tail = jax.lax.dynamic_index_in_dim(tails, layer, 0, keepdims=False)  # [B, (T - 1) x D]
+
+        def keep(window):  # [B, T, D]: a slot without a request keeps its tail as it was
+            kept = jnp.where((seen > 0)[:, None], window[:, 1:].astype(tails.dtype).reshape(tail.shape), tail)
+            return (jax.lax.dynamic_update_slice(tails, kept[None], (layer, 0, 0)),)
+        return tail, keep
+
+    def prefill_counts(self, k):
+        return {"tails_written": k}
 
 
 class LatentRows(_PageTable):
@@ -510,14 +555,15 @@ def rule_for(cfg: TransformerConfig, kind: LayerKind, ec, first: int) -> CacheRu
     """The rule of one LayerKind of ``cfg``, its pools from ``first`` on in the engine's ``cache``. ``ec``:
     the EngineConfig with max_seq and total_pages filled in."""
     # What a page's bytes are counted in when a walk's group is sized: the dtype of the cache's FIRST pool, as
-    # before the rules. For a model whose first kind is recurrent that is the float32 state's, and its attention
-    # layers walk half the pages a step their bfloat16 pages would allow (PERF.md section 7).
-    itemsize = jnp.dtype(jnp.float32 if cfg.kinds[0].recurrent else cfg.dtype).itemsize
+    # before the rules. For a model whose first kind keeps a state that is the float32 state's, and its attention
+    # layers walk half the pages a step their bfloat16 pages would allow (PERF.md section 7); a first kind that
+    # keeps a tail alone has no float32 pool.
+    itemsize = jnp.dtype(jnp.float32 if cfg.kinds[0].state else cfg.dtype).itemsize
     if cfg.latent:
         return LatentRows(cfg, kind, ec, first, itemsize)
     if kind.recurrent:
         such = " and ".join(sorted({k.mixer for k in cfg.kinds if k.recurrent})) + " layers"
-        return SlotState(cfg, kind, ec, first, such)
+        return (SlotState if kind.state else SlotTail)(cfg, kind, ec, first, such)
     if kind.window:
         return SlotRing(cfg, kind, ec, first, itemsize, kind.window)
     return PagedRows(cfg, kind, ec, first, itemsize)

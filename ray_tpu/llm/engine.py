@@ -31,9 +31,10 @@ llm/_internal/serve/engines/vllm/vllm_engine.py:174):
   shrinks so waiting requests reach a prefill slot sooner; with an empty
   queue full blocks are what the host's work hides under.
 - What a layer keeps of a sequence is its cache rule's (llm/cache_rules.py:
-  K and V rows in pages, a ring a slot, a state a slot, latent rows in
-  pages): the engine holds one rule a LayerKind (``self.rules``), hands each
-  its own pools of ``self.cache`` and asks; the programs here loop over them.
+  K and V rows in pages, a ring a slot, a state a slot, a convolution's tail
+  a slot, latent rows in pages): the engine holds one rule a LayerKind
+  (``self.rules``), hands each its own pools of ``self.cache`` and asks; the
+  programs here loop over them.
 - Tensor-parallel serving (EngineConfig.tensor_parallel > 1): params shard
   Megatron-style and the KV pools shard by kv_heads over a `tensor` mesh
   axis (parallel/), so a model bigger than one chip's HBM serves from a

@@ -4,6 +4,15 @@ Models are pure-JAX pytrees with *logical axis* annotations
 (ray_tpu.parallel.sharding): the same model code runs under any
 ShardingStrategy (DP/FSDP/TP/SP/EP) — the strategy decides how each logical
 axis maps onto the device mesh and XLA compiles in the collectives.
+
+Beside transformer.py, one plain float32 reference a family of served layers
+(no kernel, cache or batching; the tests hold the program to them):
+reference_mla_moe.py (latent attention, a shared expert and held experts),
+reference_window_moe.py (window and full attention layers, held experts),
+reference_linear_moe.py (delta-rule layers beside gated NoPE attention, held
+experts), reference_ssm_hybrid.py (Mamba-2 layers beside NoPE attention, a tied
+head), reference_conv_moe.py (gated short-convolution layers beside QK-normed
+roped attention, every expert held, a router's selection bias).
 """
 from ray_tpu.models.transformer import (
     Transformer,

@@ -34,10 +34,11 @@ from ray_tpu.parallel.sharding import with_logical_constraint as wlc
 @dataclasses.dataclass(frozen=True)
 class LayerKind:
     """One kind of layer in a model whose layers are not all alike: what mixes
-    a layer's tokens (softmax attention over cached rows, or a recurrence over
-    a state a head: a delta rule, a selective state-space scan), its heads,
-    its window and its rope. Two layers of one kind have weights of one shape
-    and cache what the same rule keeps."""
+    a layer's tokens (softmax attention over cached rows, a recurrence over a
+    state a head: a delta rule, a selective state-space scan, or a gated short
+    convolution with no state but its tail), its heads, its window and its
+    rope. Two layers of one kind have weights of one shape and cache what the
+    same rule keeps."""
     name: str  # the key of this kind's stack in params["kind_layers"], of its pools in the engine
     n_heads: int
     # "attention": softmax attention, query heads grouped over the model's KV
@@ -56,6 +57,12 @@ class LayerKind:
     # [state_size, heads x head_width] a layer in float32 and no token rows;
     # a skip D x a head, the gate silu(z) applied before an RMS norm over all
     # the heads' columns. No window, no rope, none of the model's attn_gate.
+    # "conv": a gated short convolution and nothing else: one input projection
+    # to [B | C | u] (three times d_model columns), z = B u, a causal depthwise
+    # convolution of conv_size taps over z with no bias and no activation, the
+    # gate C on its output, one output projection [d_model, d_model]. No heads
+    # (n_heads 0), no state: what a sequence keeps is its last conv_size - 1
+    # rows of z. No window, no rope, none of the model's attn_gate.
     mixer: str = "attention"
     conv_size: int = 0
     low_rank: int = 0
@@ -81,9 +88,15 @@ class LayerKind:
         return self.rope_share == 1.0 and not self.yarn_factor and self.attention_factor == 1.0
 
     @property
-    def recurrent(self) -> bool:
-        """Keeps a state a slot that does not grow with the context, and no rows of tokens."""
+    def state(self) -> bool:
+        """Keeps a float32 state a slot beside its convolution's tail, rewritten by a kernel of its own."""
         return self.mixer in ("delta", "ssd")
+
+    @property
+    def recurrent(self) -> bool:
+        """Keeps by slot what does not grow with the context (a state and a
+        tail, or a tail alone), and no rows of tokens."""
+        return self.state or self.mixer == "conv"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -147,6 +160,11 @@ class TransformerConfig:
     n_shared_experts: int = 0
     routed_scaling: float = 1.0
     router_score: str = "softmax"  # softmax | sigmoid, over the router's logits in float32
+    # Held experts chosen by score + a bias a scored expert (a float32 leaf
+    # "router_bias" [n_experts] a routed layer, no part of the weight) and
+    # weighed by the score alone, s_e / (the chosen scores' sum + 1e-6). Off:
+    # no leaf, the choice by the score, no 1e-6.
+    router_bias: bool = False
     # A head's width; 0 -> d_model // n_heads.
     head_dim: int = 0
     # Layers of more than one kind ("gqa" only): the LayerKinds of one period,
@@ -160,6 +178,10 @@ class TransformerConfig:
     # input, before the output projection: "per_head" one scalar a head (Wg
     # [D, H]), "elementwise" one a column (Wg [D, H, head_dim]).
     attn_gate: str = ""
+    # A "gqa" layer's queries and keys RMS-normed over a head's columns before
+    # the rope, one weight of head_dim for all query heads ("q_norm") and one
+    # for all key heads ("k_norm"), eps norm_eps. Off: no such leaves.
+    qk_norm: bool = False
     # Four scalars of a model parametrised for width-independent learning
     # rates, each off at its default (the program is then what it was):
     # the embedding times embed_multiplier, every sublayer's output times
@@ -219,7 +241,9 @@ class TransformerConfig:
             assert len({k.name for k in self.kinds}) == len(self.kinds), "two kinds under one name"
             assert all(k.n_heads % self.kv_heads == 0 for k in self.kinds if not k.recurrent)
             for k in self.kinds:
-                assert k.mixer in ("attention", "delta", "ssd"), k.mixer
+                assert k.mixer in ("attention", "delta", "ssd", "conv"), k.mixer
+                assert k.mixer != "conv" or (k.conv_size > 1 and not k.n_heads and not k.window), (
+                    f"a conv layer has a short convolution, no heads and no window: {k}")
                 assert k.mixer != "delta" or (k.conv_size > 1 and k.low_rank > 0 and not k.window), (
                     f"a delta layer has a short convolution, low-rank sizes and no window: {k}")
                 assert k.mixer != "ssd" or (
@@ -237,6 +261,9 @@ class TransformerConfig:
         assert self.attention_kind in ("gqa", "latent"), self.attention_kind
         assert 0 <= self.n_dense_layers <= self.n_layers
         assert self.router_score in ("softmax", "sigmoid"), self.router_score
+        assert not self.router_bias or self.experts_held, (
+            "a selection bias is written for held experts (_held_experts_pass)")
+        assert not (self.qk_norm and self.latent), "a head norm on q and k, on gqa layers (a latent layer has its own)"
         if self.experts_held:
             assert self.first_expert + self.experts_held <= self.n_experts
             assert self.expert_d_ff > 0
@@ -254,6 +281,20 @@ def _dense_init(key, shape, dtype, in_axis=0):
         fan_in *= shape[a]
     scale = 1.0 / (fan_in ** 0.5)
     return jax.random.normal(key, shape, dtype) * scale
+
+
+def _conv_taps_init(key, shape, dtype):
+    """A conv layer's taps [L, T, D], the oldest input's first: normal, the
+    newest input's tap 4 times the others' in amplitude, a channel's T
+    variances summing to 1 as T equal taps of variance 1 / T would (T = 3:
+    1/18, 1/18, 8/9). With equal taps two thirds of a position's output is its
+    two neighbours', and whatever differs at one position (a router's near-tie
+    that bfloat16 resolves the other way) reaches every later position within
+    two a layer at full size; PERF.md section 6, PR 50, has what that did to
+    the serve check over 8 routed layers and what this split reads."""
+    amplitude = jnp.asarray([0.25] * (shape[1] - 1) + [1.0], jnp.float32)
+    amplitude = amplitude / jnp.sqrt(jnp.sum(amplitude ** 2))
+    return (jax.random.normal(key, shape, jnp.float32) * amplitude[:, None]).astype(dtype)
 
 
 def _inverse_softplus(y):
@@ -284,6 +325,14 @@ def _init_stack(key: jax.Array, cfg: TransformerConfig, L: int, routed: bool, ki
             "d_skip": jnp.ones((L, H), pd),
             "o_norm": jnp.ones((L, I), pd),
             "wo": _dense_init(next(k), (L, H, kind.head_width, D), pd, in_axis=(1, 2)),
+            "ffn_norm": jnp.ones((L, D), pd),
+        }
+    elif kind.mixer == "conv":
+        layer = {
+            "attn_norm": jnp.ones((L, D), pd),
+            "w_in": _dense_init(next(k), (L, D, 3 * D), pd, in_axis=1),  # [B | C | u], in that order of thirds
+            "conv": _conv_taps_init(next(k), (L, kind.conv_size, D), pd),
+            "w_out": _dense_init(next(k), (L, D, D), pd, in_axis=1),
             "ffn_norm": jnp.ones((L, D), pd),
         }
     elif kind.recurrent:
@@ -334,6 +383,8 @@ def _init_stack(key: jax.Array, cfg: TransformerConfig, L: int, routed: bool, ki
         }
         if cfg.attn_gate:
             layer["wg"] = _dense_init(next(k), (L, D, H) + ((Hd,) if cfg.attn_gate == "elementwise" else ()), pd, in_axis=1)
+        if cfg.qk_norm:
+            layer.update({"q_norm": jnp.ones((L, Hd), pd), "k_norm": jnp.ones((L, Hd), pd)})
     if cfg.sandwich_norm:
         layer.update({"post_attn_norm": jnp.ones((L, D), pd), "post_ffn_norm": jnp.ones((L, D), pd)})
     if routed:
@@ -356,6 +407,9 @@ def _init_stack(key: jax.Array, cfg: TransformerConfig, L: int, routed: bool, ki
                     "ws_down": _dense_init(next(k), (L, SF, D), pd, in_axis=1),
                 }
             )
+        if cfg.router_bias:
+            # small beside a sigmoid's scores, and enough to change the chosen set for a real share of tokens
+            layer["router_bias"] = jax.random.uniform(next(k), (L, cfg.n_experts), jnp.float32, -0.05, 0.05)
     else:
         layer.update(
             {
@@ -416,8 +470,11 @@ def slot_state_shapes(cfg: TransformerConfig, kind: LayerKind) -> tuple:
     inputs lie end to end in one row: as [T - 1, channels] behind the slots a
     pool's minor tile would be 3 rows of a tile's 8 or 16, and the TPU
     compiler turned the whole pool round and back in every layer of a decode
-    step (my chip run, PR 46); the mixer folds the row."""
+    step (my chip run, PR 46); the mixer folds the row. A conv layer has no
+    state (None) and its T - 1 rows of z = B u lie end to end alike."""
     T = kind.conv_size
+    if kind.mixer == "conv":
+        return None, ((T - 1) * cfg.d_model,)
     if kind.mixer == "ssd":
         from ray_tpu.ops.ssd import state_shape
 
@@ -426,8 +483,8 @@ def slot_state_shapes(cfg: TransformerConfig, kind: LayerKind) -> tuple:
 
 
 def recurrence(kind: LayerKind) -> tuple:
-    """A recurrent kind's rule as (its name in a trace, over a prompt, one
-    token a slot): the kernels on a TPU backend, their ``jax.numpy`` forms
+    """A kind's rule on its state (``kind.state``) as (its name in a trace,
+    over a prompt, one token a slot): the kernels on a TPU backend, their ``jax.numpy`` forms
     elsewhere. Both take the operands the kind's mixer hands its ``attend``
     (the last two are the log decay and the step size: zero in both leaves a
     state as it was), then a state or a pool."""
@@ -573,6 +630,11 @@ def _stack_logical_axes(cfg: TransformerConfig, routed: bool, kind: LayerKind | 
             "d_skip": ("layers", "heads"), "o_norm": ("layers", None),
             "wo": ("layers", "heads", "head_dim", "embed"), "ffn_norm": ("layers", "embed"),
         }
+    elif kind is not None and kind.mixer == "conv":
+        layer = {
+            "attn_norm": ("layers", "embed"), "w_in": ("layers", "embed", None), "conv": ("layers", None, None),
+            "w_out": ("layers", None, "embed"), "ffn_norm": ("layers", "embed"),
+        }
     elif kind is not None and kind.recurrent:
         heads = ("layers", "embed", "heads", "head_dim")
         low = ("layers", None, "heads", "head_dim")
@@ -608,6 +670,8 @@ def _stack_logical_axes(cfg: TransformerConfig, routed: bool, kind: LayerKind | 
         }
         if cfg.attn_gate:
             layer["wg"] = ("layers", "embed", "heads") + (("head_dim",) if cfg.attn_gate == "elementwise" else ())
+        if cfg.qk_norm:
+            layer.update({"q_norm": ("layers", "head_dim"), "k_norm": ("layers", "head_dim")})
     if cfg.sandwich_norm:
         layer.update({"post_attn_norm": ("layers", "embed"), "post_ffn_norm": ("layers", "embed")})
     if routed:
@@ -627,6 +691,8 @@ def _stack_logical_axes(cfg: TransformerConfig, routed: bool, kind: LayerKind | 
                     "ws_down": ("layers", "mlp", "embed"),
                 }
             )
+        if cfg.router_bias:
+            layer["router_bias"] = ("layers", None)
     else:
         layer.update(
             {
@@ -938,8 +1004,9 @@ def _held_experts_ffn(x, p, cfg: TransformerConfig):
 def _held_experts_pass(x, p, cfg: TransformerConfig):
     """A routed FFN as the chip that holds experts first_expert ..
     first_expert + experts_held - 1 serves it. Every token is scored over all
-    n_experts (router logits in float32) and takes its expert_top_k best,
-    weights normalised over all of them and scaled; the pairs that landed on
+    n_experts (router logits in float32) and takes its expert_top_k best (by
+    score plus ``router_bias`` where the model has one, which the weights
+    leave out), weights normalised over all of them and scaled; the pairs that landed on
     experts held here are sorted by expert and multiplied by a grouped matmul
     (ops/grouped_matmul.py), none dropped whatever the imbalance; what the
     absent experts would have added is left out. Beside it the shared expert,
@@ -955,8 +1022,13 @@ def _held_experts_pass(x, p, cfg: TransformerConfig):
         logits = jnp.einsum("td,de->te", xt.astype(jnp.float32), p["router"].astype(jnp.float32),
                             precision=lax.Precision.HIGHEST)
         score = jax.nn.sigmoid(logits) if cfg.router_score == "sigmoid" else jax.nn.softmax(logits, axis=-1)
-        top_s, top_e = lax.top_k(score, cfg.expert_top_k)  # [T, K]
-        top_w = top_s / jnp.sum(top_s, axis=-1, keepdims=True) * cfg.routed_scaling
+        if cfg.router_bias:  # chosen by score + bias, weighed by the score alone
+            _, top_e = lax.top_k(score + p["router_bias"].astype(jnp.float32), cfg.expert_top_k)
+            top_s = jnp.take_along_axis(score, top_e, axis=-1)
+            top_w = top_s / (jnp.sum(top_s, axis=-1, keepdims=True) + 1e-6) * cfg.routed_scaling
+        else:
+            top_s, top_e = lax.top_k(score, cfg.expert_top_k)  # [T, K]
+            top_w = top_s / jnp.sum(top_s, axis=-1, keepdims=True) * cfg.routed_scaling
         tm = _expert_tile(B * S, cfg)
         plan = group_rows(top_e, cfg.first_expert, cfg.experts_held, tm)
     with jax.named_scope("experts/gmm"):
@@ -1070,6 +1142,35 @@ def _ssd_mixer(h, lp, cfg: TransformerConfig, kind: LayerKind, attend):
     return o, kept
 
 
+def _conv_mixer(h, lp, cfg: TransformerConfig, kind: LayerKind, attend):
+    """A conv layer's mixer on its normed input h [B, S, D]: one projection to
+    [B | C | u], z = B u, the causal depthwise convolution of T taps over z in
+    float32 (no bias, no activation), the gate C on its output. Returns (y
+    [B, S, D] before the output projection, kept).
+
+    ``attend`` is the program's side, a pair (tail, keep), as for the other
+    recurrent kinds but with no rule to run. tail: the T - 1 rows of z before
+    position 0, [B, T - 1, D] or end to end as the engine's pool keeps them
+    [B, (T - 1) x D] (None: zeros, a sequence's start). keep(window) -> kept:
+    window [B, T - 1 + S, D] is the tail and these positions' z behind it, of
+    which a program keeps its next tail."""
+    dt, T = h.dtype, kind.conv_size
+    B_, S, D = h.shape
+    tail, keep = attend
+    with jax.named_scope("in_proj"):
+        bcu = jnp.einsum("bsd,dc->bsc", h, lp["w_in"].astype(dt))
+        b, c, u = bcu[..., :D], bcu[..., D:2 * D], bcu[..., 2 * D:]
+    with jax.named_scope("short_conv"):
+        if tail is None:
+            tail = jnp.zeros((B_, T - 1, D), dt)
+        window = jnp.concatenate([tail.astype(dt).reshape(B_, T - 1, D), b * u], axis=1)
+        taps = lp["conv"].astype(jnp.float32)
+        y = sum(window[:, j:j + S].astype(jnp.float32) * taps[j] for j in range(T))
+    with jax.named_scope("out_gate"):
+        y = (c.astype(jnp.float32) * y).astype(dt)
+    return y, keep(window)
+
+
 def decoder_block(x, lp, cfg: TransformerConfig, positions, attend, kind: LayerKind | None = None):
     """The one decoder block that training, prefill and decode all run: what
     the model is (norms, projections, rope, the attention's and the FFN's
@@ -1088,8 +1189,8 @@ def decoder_block(x, lp, cfg: TransformerConfig, positions, attend, kind: LayerK
     (``latent_expand``) or absorbs the projections in decode. ``kept`` is
     whatever the attention side wants handed out of the layer (a prompt's
     rows, the carried pools, None). A recurrent kind's ``attend`` is a pair
-    (``_delta_mixer`` and ``_ssd_mixer`` say of what). Returns (x, aux, kept): aux is the MoE
-    balance term of a training layer, a zero for a dense one, and the
+    (``_delta_mixer``, ``_ssd_mixer`` and ``_conv_mixer`` say of what).
+    Returns (x, aux, kept): aux is the MoE balance term of a training layer, a zero for a dense one, and the
     [pairs, live tiles] counts of a layer that serves held experts."""
     eps = cfg.norm_eps
     dt = x.dtype
@@ -1100,7 +1201,7 @@ def decoder_block(x, lp, cfg: TransformerConfig, positions, attend, kind: LayerK
         return x + (out * cfg.residual_multiplier if cfg.residual_multiplier != 1.0 else out)
 
     if kind.recurrent:
-        mixer = _ssd_mixer if kind.mixer == "ssd" else _delta_mixer
+        mixer = {"ssd": _ssd_mixer, "delta": _delta_mixer, "conv": _conv_mixer}[kind.mixer]
         o, kept = mixer(_rms_norm(x, lp["attn_norm"], eps), lp, cfg, kind, attend)
     elif cfg.latent:
         q, k, v = _latent_qkv(_rms_norm(x, lp["attn_norm"], eps), lp, cfg, positions)
@@ -1112,6 +1213,9 @@ def decoder_block(x, lp, cfg: TransformerConfig, positions, attend, kind: LayerK
             v = jnp.einsum("bsd,dhk->bshk", h, lp["wv"].astype(dt))
             q = wlc(q, ("batch", "seq", "heads", "head_dim"))
             k = wlc(k, ("batch", "seq", "kv_heads", "head_dim"))
+            if cfg.qk_norm:
+                with jax.named_scope("qk_norm"):
+                    q, k = _rms_norm(q, lp["q_norm"], eps), _rms_norm(k, lp["k_norm"], eps)
             q = _rope_kind(q, positions, kind)
             k = _rope_kind(k, positions, kind)
             if cfg.attention_multiplier:
@@ -1129,8 +1233,11 @@ def decoder_block(x, lp, cfg: TransformerConfig, positions, attend, kind: LayerK
                 gate = jax.nn.sigmoid(jnp.einsum("bsd,dh->bsh", h, lp["wg"].astype(dt)).astype(jnp.float32))
                 o = o * gate[..., None].astype(dt)
     with jax.named_scope("attn_out"):
-        o = wlc(o, ("batch", "seq", "heads", "head_dim"))
-        a = jnp.einsum("bshk,hkd->bsd", o, lp["wo"].astype(dt))
+        if kind.mixer == "conv":  # no heads: the gated columns through one [D, D] projection
+            a = jnp.einsum("bsc,cd->bsd", o, lp["w_out"].astype(dt))
+        else:
+            o = wlc(o, ("batch", "seq", "heads", "head_dim"))
+            a = jnp.einsum("bshk,hkd->bsd", o, lp["wo"].astype(dt))
         if cfg.sandwich_norm:
             a = _rms_norm(a, lp["post_attn_norm"], eps)
         x = joined(x, a)
@@ -1177,9 +1284,11 @@ def _layer(x, lp, cfg: TransformerConfig, positions, segment_ids=None, kind: Lay
     if kind is not None and kind.recurrent:
         if segment_ids is not None:
             raise NotImplementedError(
-                f"packed sequences are not written for a {kind.mixer} layer: its state and its convolution would "
+                f"packed sequences are not written for a {kind.mixer} layer: "
+                f"{'its state and its convolution' if kind.state else 'its convolution'} would "
                 "have to start again at each document's first position (ROADMAP M4)")
-        attend = (None, functools.partial(_whole_sequence_rule, cfg=cfg, kind=kind))
+        attend = (None, functools.partial(_whole_sequence_rule, cfg=cfg, kind=kind) if kind.state
+                  else lambda _window: None)
     x, aux, _ = decoder_block(x, lp, cfg, positions, attend, kind)
     # A layer that serves held experts hands out counts, not a loss term.
     return x, (jnp.zeros((), jnp.float32) if cfg.experts_held else aux)

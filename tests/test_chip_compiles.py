@@ -374,3 +374,69 @@ def test_the_decode_program_of_a_model_with_state_space_layers_compiles_for_a_v5
     pool = "bf16[2,8,5120,128]"
     assert not [line for line in lines if " copy(" in line and line.split("=")[1].lstrip().startswith(pool)]
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+@pytest.mark.parametrize("K,N", [(2048, 1536), (1536, 2048)])
+def test_the_grouped_matmul_lowers_with_every_expert_of_eight_layers_for_a_v5e(K, N, one_chip, no_compile_cache,
+                                                                               monkeypatch):
+    """ops/grouped_matmul.py where a chip holds ALL 64 experts of 8 routed layers at widths 2048 and 1536: a decode
+    step's 512 pairs in tiles of 16 rows (8 pairs an expert expected, a tile or two each), the whole stack of 3.2 GB
+    an operand and no copy of it among the temporaries."""
+    from ray_tpu.ops import grouped_matmul as gm
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    M = gm.plan_rows(128 * 4, 64, 16)
+    assert M == 1536
+
+    def arr(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def call(x, w, layer, tile_expert, n_tiles):
+        return gm.expert_gmm(x, w, layer, tile_expert, n_tiles, tm=16)
+
+    compiled = jax.jit(call).lower(arr((M, K), jnp.bfloat16), arr((8, 64, K, N), jnp.bfloat16), arr((), jnp.int32),
+                                   arr((M // 16,), jnp.int32), arr((1,), jnp.int32)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1 and "expert_gmm" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 << 20
+
+
+def test_the_decode_program_of_a_model_with_conv_layers_and_every_expert_held_compiles_for_a_v5e(
+        one_chip, no_compile_cache, monkeypatch):
+    """llm/engine.py ``_decode_impl`` of a leading dense conv layer and two periods of (attention with a head norm,
+    conv, conv, conv) at the serve cell's widths, all 64 experts of each routed layer here and a small vocabulary:
+    the period scan around one paged call and four layers' three grouped matmuls, no kernel for a conv layer. The
+    page pools' rows are a lane tile wide from allocation; the tails' one pool [7, slots, 2 x 2048] has no float32
+    pool beside it, a walk takes 8 pages a grid step (pages counted in bfloat16), and the experts' stacks (2.4 GB a
+    kind's gate matrices) and the pools are operands of no copy inside the loops: the temporaries stay small."""
+    from ray_tpu.llm.engine import EngineConfig, LLMEngine
+    from ray_tpu.models.transformer import LayerKind, TransformerConfig, init_params
+
+    conv = LayerKind("conv", 0, mixer="conv", conv_size=3)
+    attention = LayerKind("attention", 32, rope_theta=1e6)
+    cfg = TransformerConfig(
+        vocab_size=1024, d_model=2048, n_layers=9, n_heads=32, n_kv_heads=8, head_dim=64, d_ff=11776, max_seq_len=3712,
+        param_dtype=jnp.bfloat16, norm_eps=1e-5, layer_pattern=(conv, attention, conv, conv), n_dense_layers=1,
+        n_experts=64, expert_top_k=4, experts_held=64, expert_d_ff=1536, router_score="sigmoid", router_bias=True,
+        qk_norm=True, tie_embeddings=True)
+
+    def on_chip(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    params = jax.tree.map(on_chip, jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    assert params["kind_layers"]["conv"]["w_gate"].shape == (6, 64, 2048, 1536)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the engine asks how wide a pool's rows are
+    eng = LLMEngine(cfg, params=params, engine_config=EngineConfig(
+        max_slots=128, max_seq=3712, page_size=128, total_pages=40, prefill_buckets=(512,), decode_block=8))
+    tails, k_pages, v_pages = eng.cache
+    assert tails.shape == (7, 128, 2 * 2048) and tails.dtype == jnp.bfloat16
+    assert k_pages.shape == v_pages.shape == (2, 8, 40 * 128, 128) and eng.rules[1].group == 8
+    B = eng.ec.max_slots
+    ints, floats = on_chip(jnp.zeros(B, jnp.int32)), on_chip(jnp.zeros(B, jnp.float32))
+    compiled = eng._decode_jit.lower(
+        params, tuple(on_chip(pool) for pool in eng.cache), ints, ints, on_chip(eng.d_page_tables),
+        on_chip(jax.random.PRNGKey(0)), 8, floats, floats, ints).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1 + 4 * 3 and "expert_gmm" in text and "paged_attn" in text
+    for scope in ("in_proj", "short_conv", "out_gate", "qk_norm", "experts/route"):
+        assert scope in text, scope
+    assert compiled.memory_analysis().temp_size_in_bytes < 96 << 20  # 51.9 MB as read; an expert stack is 1.2 GB
