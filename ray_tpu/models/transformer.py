@@ -125,9 +125,9 @@ class TransformerConfig:
     # S=2048/hd=64 measures fastest at 1024x1024 (PROFILES.md round 4).
     attention_block_q: int = 0
     attention_block_k: int = 0
-    # Training-loss chunking: compute CE over sequence chunks of this size
-    # so the full [B, S, V] logits never materialize (0 = off). Requires
-    # chunk | (S-1 of the train batch); big win at large vocab (PROFILES.md).
+    # The loss head's chunk of the sequence (head_loss: the full [B, S, V]
+    # logits never materialize). 0: read off the shapes, the fewest equal
+    # chunks whose logits fit a byte budget; > 0: this many positions a chunk.
     ce_chunk: int = 0
     norm_eps: float = 1e-6
     # Attention kind. "gqa": a head's K and V projected from the hidden state
@@ -1345,43 +1345,102 @@ def _ce_from_logits(logits, targets, mask=None):
     return jnp.mean(nll)
 
 
-def _ce_chunked(x, lm_head, targets, mask, chunk: int):
-    """Fused-style CE: the [B, S, V] logits are never materialized — a
-    rematted scan computes each sequence chunk's logits [B, c, V], reduces
-    to (sum nll, count), and the bwd recomputes them per chunk. At vocab
-    32k / B16 / S2048 this removes a 2+ GB bf16 logits tensor (plus its bwd
-    twin) from HBM, which is what lets batch 24 fit on one v5e and shaves
-    the fwd/bwd logits traffic (PROFILES.md round 4)."""
-    B, S, D = x.shape
-    n = S // chunk
-    if mask is None:
-        mask = jnp.ones((B, S), jnp.float32)
-    mask = mask.astype(jnp.float32)
+# The loss head walks the sequence in chunks whose logits [B, chunk, V] stay
+# under this many bytes, and in at most so many: the walk is unrolled, and a
+# warm start reads, deserialises and loads every chunk's fusions. Chosen on the
+# train cell, [3, 4096] tokens x 32768 in bfloat16 (805 MB) beside two layers'
+# backward under remat "dots", TPU v5e, jax 0.9.0, 2026-10-03 (PERF.md section
+# 6, PR 52): 4 chunks of 201 MB read 34,635 tokens/s, 8 read 34,785 on a step
+# program of 242 fusions where 4 make 196 and the whole logits, which the
+# compiler computed twice, 163 at 32,141. A ROLLED walk keeps the program at
+# 160 fusions whatever the count, and is slower: lax.scan over chunk-major
+# inputs 33,533 (4 chunks) and 33,701 (8), fori_loop over dynamic slices
+# 33,196, 3.2-4.2% of the step under the unrolled form of the same chunks (not
+# the 6x an older JAX measured of a rematted scan); the head's three products
+# run as fast inside the loop, the rest of the step does not.
+_LOSS_HEAD_CHUNK_BYTES = 192 << 20
+_LOSS_HEAD_MAX_CHUNKS = 32
 
-    @jax.checkpoint
-    def body(xc, tc, mc):
-        logits = jnp.einsum("bcd,dv->bcv", xc, lm_head)
-        lse = jax.scipy.special.logsumexp(logits.astype(jnp.float32), axis=-1)
-        picked = jnp.take_along_axis(logits, tc[..., None], axis=-1)[..., 0]
-        nll = lse - picked.astype(jnp.float32)
-        return jnp.sum(nll * mc), jnp.sum(mc)
 
-    # Unrolled chunk loop (n is small): a lax.scan here measured 6x SLOWER
-    # on v5e (the scanned body pessimizes the [D, V] matmul layout). The
-    # optimization_barrier chains each chunk's input on the previous chunk's
-    # sum — without it XLA overlaps all n matmul islands and every chunk's
-    # logits are live at once (OOM, the exact thing chunking exists to fix).
-    tot = jnp.float32(0.0)
-    cnt = jnp.float32(0.0)
-    for i in range(n):
-        sl = slice(i * chunk, (i + 1) * chunk)
-        x_i = x[:, sl]
-        if i:
-            x_i, tot = lax.optimization_barrier((x_i, tot))
-        s_i, c_i = body(x_i, targets[:, sl], mask[:, sl])
-        tot += s_i
-        cnt += c_i
-    return tot / jnp.maximum(cnt, 1.0)
+def _loss_head_chunk(B: int, S: int, V: int, itemsize: int, ce_chunk: int) -> int:
+    """The chunk's length: ``ce_chunk`` where it is given, else S over the
+    fewest chunks whose logits fit _LOSS_HEAD_CHUNK_BYTES (at most
+    _LOSS_HEAD_MAX_CHUNKS), rounded up: the last chunk may be shorter."""
+    if ce_chunk > 0:
+        return min(ce_chunk, S)
+    return -(-S // min(-(-B * S * V * itemsize // _LOSS_HEAD_CHUNK_BYTES), _LOSS_HEAD_MAX_CHUNKS))
+
+
+def _head_walk(x, head, targets, mask, chunk: int, divisor: float, dims: str, grads: bool):
+    """The mean NLL of targets [B, S] under logits x @ head / divisor, over the
+    positions mask [B, S] keeps (None: all), one chunk of the sequence at a
+    time: a chunk's logits [B, chunk, V] in x's dtype, its logsumexp and picked
+    logit in float32, and nothing of [B, S, V] ever whole. The head lies as
+    ``dims`` says, "dv" or a tied embedding's "vd". With ``grads`` also
+    (d loss / d x [B, S, D] in x's dtype, d loss / d head as head lies and in
+    its dtype), made while a chunk's logits are there: dlogits = (softmax -
+    onehot) * mask / count rounded to x's dtype (what a bf16 dot_general's
+    cotangent is), dx's chunk = dlogits @ head^T, dW += x_chunk^T @ dlogits in
+    float32."""
+    B, S, _ = x.shape
+    w = head.astype(x.dtype)
+    mask = jnp.ones((B, S), jnp.float32) if mask is None else mask.astype(jnp.float32)
+    count = jnp.maximum(jnp.sum(mask), 1.0)
+    vocab = jnp.arange(head.shape[dims.index("v")], dtype=targets.dtype)
+    tot = jnp.zeros((), jnp.float32)
+    dW = jnp.zeros(head.shape, jnp.float32) if grads else None
+    dx = []
+    # The optimization_barrier chains each chunk's input on the chunk before:
+    # without it XLA overlaps the chunks' matmul islands and every chunk's
+    # logits are live at once, the exact thing chunking exists to prevent.
+    for lo in range(0, S, chunk):
+        at = slice(lo, lo + chunk)
+        x_c = x[:, at]
+        if lo:
+            x_c, tot, dW = lax.optimization_barrier((x_c, tot, dW))
+        logits = wlc(jnp.einsum(f"bcd,{dims}->bcv", x_c, w), ("batch", "seq", "vocab"))
+        logits = (logits / divisor if divisor != 1.0 else logits).astype(jnp.float32)
+        lse = jax.scipy.special.logsumexp(logits, axis=-1)
+        hit = targets[:, at, None] == vocab
+        tot += jnp.sum((lse - jnp.sum(jnp.where(hit, logits, 0.0), axis=-1)) * mask[:, at])
+        if grads:
+            dlogits = (jnp.exp(logits - lse[..., None]) - hit) * (mask[:, at] / (count * divisor))[..., None]
+            dlogits = dlogits.astype(x.dtype)
+            dx.append(jnp.einsum(f"bcv,{dims}->bcd", dlogits, w))
+            dW += jnp.einsum(f"bcd,bcv->{dims}", x_c, dlogits, preferred_element_type=jnp.float32)
+    loss = tot / count
+    return (loss, jnp.concatenate(dx, axis=1), dW.astype(head.dtype)) if grads else loss
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _head_loss(x, head, targets, mask, chunk: int, divisor: float, dims: str):
+    return _head_walk(x, head, targets, mask, chunk, divisor, dims, grads=False)
+
+
+def _head_loss_fwd(x, head, targets, mask, chunk, divisor, dims):
+    loss, dx, dW = _head_walk(x, head, targets, mask, chunk, divisor, dims, grads=True)
+    return loss, (dx, dW)
+
+
+def _head_loss_bwd(chunk, divisor, dims, grads, g):
+    # targets are whole numbers and nothing is asked of the mask: no cotangent.
+    return (*((d * g).astype(d.dtype) for d in grads), None, None)
+
+
+_head_loss.defvjp(_head_loss_fwd, _head_loss_bwd)
+
+
+def head_loss(params: dict, x, targets, mask, cfg: TransformerConfig):
+    """Final-normed hidden states x [B, S, D] -> the mean NLL of targets [B, S]
+    over the positions mask keeps. Differentiated, the head makes its gradients
+    where it makes its logits (three matmuls of the head's size a step, no
+    second pass over the logits: _head_walk). The head comes in as the
+    parameter it is and lies, so a tied embedding's two gradients are summed
+    by autodiff and nothing is turned."""
+    head, dims = (params["embed"], "vd") if cfg.tie_embeddings else (params["lm_head"], "dv")
+    chunk = _loss_head_chunk(*targets.shape, cfg.vocab_size, jnp.dtype(x.dtype).itemsize, cfg.ce_chunk)
+    with jax.named_scope("loss_head"):
+        return _head_loss(x, head, targets, mask, chunk, float(cfg.logits_divisor), dims)
 
 
 def cross_entropy_loss(params, batch, cfg: TransformerConfig):
@@ -1398,32 +1457,12 @@ def cross_entropy_loss(params, batch, cfg: TransformerConfig):
         # composes with any provided padding mask.
         boundary = (segs[:, 1:] == segs[:, :-1]).astype(jnp.float32)
         mask = boundary if mask is None else mask * boundary
-    if cfg.ce_chunk and inputs.shape[1] % cfg.ce_chunk:
-        import warnings
-
-        warnings.warn(
-            f"ce_chunk={cfg.ce_chunk} does not divide the train seq length "
-            f"{inputs.shape[1]}; falling back to MATERIALIZED logits "
-            f"([B,S,V] in HBM) — a run sized around chunked CE may OOM here",
-            stacklevel=2,
-        )
-    if cfg.ce_chunk and inputs.shape[1] % cfg.ce_chunk == 0:
-        x, aux = forward_hidden(
-            params, inputs, cfg,
-            segment_ids=None if segs is None else segs[:, :-1],
-            positions=None if pos is None else pos[:, :-1],
-        )
-        head = head_matrix(params, cfg)
-        loss = _ce_chunked(x, head / cfg.logits_divisor if cfg.logits_divisor != 1.0 else head, targets, mask,
-                           cfg.ce_chunk)
-    else:
-        logits, aux = forward(
-            params, inputs, cfg,
-            segment_ids=None if segs is None else segs[:, :-1],
-            positions=None if pos is None else pos[:, :-1],
-        )
-        loss = _ce_from_logits(logits, targets, mask)
-    return loss + 0.01 * aux
+    x, aux = forward_hidden(
+        params, inputs, cfg,
+        segment_ids=None if segs is None else segs[:, :-1],
+        positions=None if pos is None else pos[:, :-1],
+    )
+    return head_loss(params, x, targets, mask, cfg) + 0.01 * aux
 
 
 # ---------------------------------------------------------------------------

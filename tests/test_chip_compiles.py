@@ -440,3 +440,59 @@ def test_the_decode_program_of_a_model_with_conv_layers_and_every_expert_held_co
     for scope in ("in_proj", "short_conv", "out_gate", "qk_norm", "experts/route"):
         assert scope in text, scope
     assert compiled.memory_analysis().temp_size_in_bytes < 96 << 20  # 51.9 MB as read; an expert stack is 1.2 GB
+
+
+def test_the_train_step_at_the_train_cells_shapes_computes_no_product_twice_and_stays_small_for_a_v5e(
+        one_chip, no_compile_cache, monkeypatch):
+    """models/transformer.py ``make_train_step`` at benchmarks/configs/mistral-7b-v0.3-l2.json's widths and its train
+    settings (3 rows of 4096 + 1 tokens, remat ``dots``, ce_chunk 0). The loss head makes its gradients where it makes
+    its logits, so the compiler has no [3, 4096, 32768] logits to compute again for the head's weight gradient
+    (``fusion.284.remat`` before: 4.7% of a step; PERF.md section 6, PR 52): no rematerialised instruction is a
+    matmul (a TPU's ``convolution``) or a fusion around one (what is rematerialised here is one copy, ``copy.241``,
+    a relayout of q). And the head's walk is unrolled over FEW chunks, 4 of [3, 1024] positions, so the step stays
+    near the program it was: a warm start reads, deserialises and loads the whole compiled step before its first
+    step returns, and the form that unrolled 8 chunks was refused for its ``setup_s`` (ROADMAP S12 (e)). The bounds
+    are what was read here plus a tenth: 546 equations in the step's jaxpr (425 before the head; 706 with 8 chunks),
+    196 fusion instructions (163; 242)."""
+    import json
+    import os
+    import re
+
+    from ray_tpu.models import transformer as T
+
+    with open(os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                           "benchmarks", "configs", "mistral-7b-v0.3-l2.json")) as f:
+        model = json.load(f)
+    train = dict(model["train"])
+    rows, seq = train.pop("batch_rows"), 4096
+    cfg = T.TransformerConfig(
+        vocab_size=model["vocab_size"], d_model=model["hidden_size"], n_layers=model["num_hidden_layers"],
+        n_heads=model["num_attention_heads"], n_kv_heads=model["num_key_value_heads"], d_ff=model["intermediate_size"],
+        max_seq_len=seq, rope_theta=model["rope_theta"], **train)
+    assert (rows, cfg.vocab_size, cfg.ce_chunk, cfg.remat_policy) == (3, 32768, 0, "dots")
+    assert T._loss_head_chunk(rows, seq, cfg.vocab_size, 2, cfg.ce_chunk) == seq // 4
+
+    def on_chip(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # attention_impl "auto" asks: the flash kernel
+    init_state, train_step, _ = T.make_train_step(cfg)
+    state = jax.tree.map(on_chip, jax.eval_shape(init_state, jax.random.PRNGKey(0)))
+    batch = {c: on_chip(jnp.zeros((rows, seq + 1), jnp.int32)) for c in ("tokens", "segment_ids", "positions", "mask")}
+    traced = jax.jit(train_step, donate_argnums=(0,)).trace(state, batch)
+    assert len(traced.jaxpr.eqns) <= 600
+    text = traced.lower().compile().as_text()
+    bodies, name = {}, None
+    for line in text.splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            name = line.split()[1 if line.startswith("ENTRY") else 0].lstrip("%")
+            bodies[name] = []
+        elif name:
+            bodies[name].append(line)
+    products = {name for name, body in bodies.items() if any(re.search(r" (convolution|dot)\(", line) for line in body)}
+    for line in text.splitlines():
+        if re.search(r"%[\w.\-]*\.remat[\w.\-]* = ", line):
+            assert not re.search(r" (convolution|dot)\(", line), line
+            assert not set(re.findall(r"calls=%([\w.\-]+)", line)) & products, line
+    assert len(re.findall(r"= [^=\n]*? fusion\(", text)) <= 215
+    assert "loss_head" in text and text.count("tpu_custom_call") >= 4  # the flash forward twice under "dots", its backward's two

@@ -450,47 +450,132 @@ def test_ulysses_attention_head_indivisible_falls_back_to_ring():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5)
 
 
-def test_chunked_ce_matches_unchunked():
-    """ce_chunk computes the same loss AND gradients as the materialized
-    path (it exists so [B,S,V] logits never hit HBM — PROFILES.md round 4)."""
+def _autodiff_loss(params, batch, cfg):
+    """What cross_entropy_loss was before the head made its own gradients:
+    forward()'s whole [B, S, V] logits under _ce_from_logits, left to autodiff."""
+    from ray_tpu.models.transformer import _ce_from_logits
+
+    tokens, segs = batch["tokens"], batch.get("segment_ids")
+    mask = None if batch.get("mask") is None else batch["mask"][:, 1:].astype(jnp.float32)
+    if segs is not None:
+        boundary = (segs[:, 1:] == segs[:, :-1]).astype(jnp.float32)
+        mask = boundary if mask is None else mask * boundary
+    logits, aux = forward(params, tokens[:, :-1], cfg, segment_ids=None if segs is None else segs[:, :-1])
+    return _ce_from_logits(logits, tokens[:, 1:], mask) + 0.01 * aux
+
+
+_HEAD_MODELS = {
+    "dense": {},
+    "tied": {"tie_embeddings": True},
+    "divisor": {"logits_divisor": 3.0},
+    "tied_divisor": {"tie_embeddings": True, "logits_divisor": 8.0},
+    # At bfloat16 activations the logits' cotangent is rounded once here and
+    # in two parts by autodiff: the gradients agree to bfloat16's precision.
+    "bfloat16": {"dtype": jnp.bfloat16},
+    "bfloat16_tied_divisor": {"dtype": jnp.bfloat16, "tie_embeddings": True, "logits_divisor": 8.0},
+}
+# A walk: (positions, ce_chunk, the chooser's budget as a share of the whole
+# logits' bytes (None: the module's own), the chunks it then takes).
+_HEAD_WALKS = {
+    "one_chunk_chosen": (32, 0, None, 1),
+    "two_chunks_chosen": (32, 0, 1 / 2, 2),
+    "eight_chunks_chosen": (32, 0, 1 / 8, 8),
+    "four_chunks_given": (32, 8, None, 4),
+    "a_given_chunk_that_does_not_divide": (32, 5, None, 7),
+    "a_prime_length_whose_last_chunk_is_short": (31, 0, 1 / 4, 4),
+}
+_HEAD_CASES = [("dense", kept, walk) for kept in ("all", "mask", "segment_ids", "mask_and_segment_ids") for walk in _HEAD_WALKS] + [
+    ("tied", "mask", "one_chunk_chosen"), ("tied", "segment_ids", "two_chunks_chosen"), ("tied", "all", "a_given_chunk_that_does_not_divide"),
+    ("divisor", "all", "eight_chunks_chosen"), ("divisor", "mask", "four_chunks_given"),
+    ("tied_divisor", "segment_ids", "a_prime_length_whose_last_chunk_is_short"), ("tied_divisor", "mask", "eight_chunks_chosen"),
+    ("bfloat16", "all", "two_chunks_chosen"), ("bfloat16", "mask_and_segment_ids", "four_chunks_given"),
+    ("bfloat16_tied_divisor", "mask", "a_given_chunk_that_does_not_divide"), ("bfloat16_tied_divisor", "all", "eight_chunks_chosen"),
+]
+
+
+@pytest.mark.parametrize("model,kept,walk", _HEAD_CASES, ids=["-".join(c) for c in _HEAD_CASES])
+def test_loss_head_matches_autodiff_of_the_whole_logits(model, kept, walk, monkeypatch):
+    """The head that makes its gradients where it makes its logits
+    (cross_entropy_loss -> head_loss) gives the loss AND every leaf's gradient
+    that autodiff gives of forward() + _ce_from_logits, whatever the walk, and
+    its primal alone the same loss."""
     import dataclasses
 
-    from ray_tpu.models.transformer import cross_entropy_loss
+    from ray_tpu.models import transformer as T
 
-    cfg = TransformerConfig(
-        vocab_size=128, d_model=64, n_layers=2, n_heads=4, d_ff=128,
-        max_seq_len=64, dtype=jnp.float32, attention_impl="reference",
-    )
+    positions, ce_chunk, share, chunks = _HEAD_WALKS[walk]
+    cfg = dataclasses.replace(CFG, n_layers=1, ce_chunk=ce_chunk, **_HEAD_MODELS[model])
+    itemsize = jnp.dtype(cfg.dtype).itemsize
+    if share is not None:
+        monkeypatch.setattr(T, "_LOSS_HEAD_CHUNK_BYTES", int(3 * positions * cfg.vocab_size * itemsize * share))
+    assert -(-positions // T._loss_head_chunk(3, positions, cfg.vocab_size, itemsize, ce_chunk)) == chunks
     params = init_params(jax.random.PRNGKey(0), cfg)
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (3, 33), 0, 128)
-    mask = (jax.random.uniform(jax.random.PRNGKey(2), (3, 33)) > 0.3).astype(jnp.float32)
-    cfgc = dataclasses.replace(cfg, ce_chunk=8)
-    for batch in ({"tokens": tokens}, {"tokens": tokens, "mask": mask}):
-        l0 = float(cross_entropy_loss(params, batch, cfg))
-        l1 = float(cross_entropy_loss(params, batch, cfgc))
-        np.testing.assert_allclose(l0, l1, rtol=1e-5)
-        g0 = jax.grad(lambda p: cross_entropy_loss(p, batch, cfg))(params)
-        g1 = jax.grad(lambda p: cross_entropy_loss(p, batch, cfgc))(params)
-        for k in ("lm_head", "embed"):
-            np.testing.assert_allclose(
-                np.asarray(g0[k]), np.asarray(g1[k]), rtol=2e-4, atol=1e-6
-            )
+    shape = (3, positions + 1)
+    batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), shape, 0, 128)}
+    if "mask" in kept:
+        batch["mask"] = (jax.random.uniform(jax.random.PRNGKey(2), shape) > 0.3).astype(jnp.float32)
+    if "segment_ids" in kept:
+        batch["segment_ids"] = jnp.cumsum(jax.random.uniform(jax.random.PRNGKey(3), shape) > 0.8, axis=1).astype(jnp.int32)
+    want, want_grads = jax.value_and_grad(_autodiff_loss)(params, batch, cfg)
+    got, got_grads = jax.jit(jax.value_and_grad(lambda p, b: cross_entropy_loss(p, b, cfg)))(params, batch)
+    exact = cfg.dtype == jnp.float32
+    np.testing.assert_allclose(float(got), float(want), atol=2e-6 if exact else 1e-5)
+    np.testing.assert_allclose(float(cross_entropy_loss(params, batch, cfg)), float(want), atol=2e-6 if exact else 1e-5)
+    assert jax.tree.structure(got_grads) == jax.tree.structure(want_grads)
+    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got_grads), jax.tree.leaves(want_grads)):
+        assert g.dtype == w.dtype and g.shape == w.shape, path
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=1e-6 if exact else 1e-3, err_msg=str(path))
 
 
-def test_ce_chunk_falls_back_when_not_divisible():
-    """A seq length the chunk doesn't divide silently uses the materialized
-    path (same value) instead of failing."""
+@pytest.mark.parametrize("B,S,V,itemsize,ce_chunk,chunk", [
+    (3, 4096, 32768, 2, 0, 1024),  # the train cell's 805 MB of logits: 4 chunks of 201 MB (PERF.md section 6, PR 52)
+    (1, 1024, 32768, 2, 0, 1024),  # its check's one row, 67 MB: whole
+    (3, 4099, 32768, 2, 0, 820),  # a prime length, a little over 4 chunks' budget: 4 chunks of 820 and one of 819
+    (3, 3000, 32768, 2, 0, 1000),  # 590 MB: 3 chunks
+    (64, 4096, 131072, 4, 0, 128),  # 137 GB of float32 logits: the unrolled walk's most chunks, 32, of 4.3 GB each
+    (3, 4096, 32768, 2, 1000, 1000),  # a given chunk as given, the last one of 96
+    (3, 100, 32768, 2, 1000, 100),  # and no longer than the sequence
+], ids=["the_train_cell", "its_check", "a_prime_length", "three_chunks", "the_most_chunks", "given", "given_over_the_length"])
+def test_loss_head_reads_its_chunk_off_the_shapes(B, S, V, itemsize, ce_chunk, chunk):
+    """ce_chunk 0: the fewest chunks whose logits fit the budget (no more than
+    the walk may unroll), equal but for a last one that may be shorter; a
+    given chunk as given."""
+    from ray_tpu.models import transformer as T
+
+    assert T._LOSS_HEAD_CHUNK_BYTES == 192 << 20
+    assert T._loss_head_chunk(B, S, V, itemsize, ce_chunk) == chunk
+    chunks = -(-S // chunk)
+    if not ce_chunk and 1 < chunks < T._LOSS_HEAD_MAX_CHUNKS:  # they fit, and one chunk fewer would not
+        assert B * chunk * V * itemsize <= T._LOSS_HEAD_CHUNK_BYTES < B * -(-S // (chunks - 1)) * V * itemsize
+    assert chunks <= T._LOSS_HEAD_MAX_CHUNKS or ce_chunk
+
+
+def _largest_with(jaxpr, width: int) -> int:
+    """The most elements of any array with a dimension of `width` that an
+    equation of a jaxpr makes, its inner jaxprs' equations too."""
+    most = 0
+    for eqn in jaxpr.eqns:
+        most = max([most] + [int(np.prod(v.aval.shape)) for v in eqn.outvars if width in getattr(v.aval, "shape", ())])
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            most = max(most, _largest_with(inner, width))
+    return most
+
+
+@pytest.mark.parametrize("differentiated", [False, True], ids=["primal", "value_and_grad"])
+def test_loss_head_never_builds_the_whole_logits(differentiated):
+    """Neither the primal alone (a cell's check, evaluation) nor the
+    differentiated loss holds anything of [B, S, V]: the largest array with
+    the vocabulary's width that either makes is a chunk's logits, or the
+    head's own gradient."""
     import dataclasses
 
-    from ray_tpu.models.transformer import cross_entropy_loss
-
-    cfg = TransformerConfig(
-        vocab_size=128, d_model=64, n_layers=1, n_heads=4, d_ff=128,
-        max_seq_len=64, dtype=jnp.float32, attention_impl="reference",
-    )
+    B, S, chunk = 4, 64, 8
+    cfg = dataclasses.replace(CFG, n_layers=1, vocab_size=512, max_seq_len=S, ce_chunk=chunk)
     params = init_params(jax.random.PRNGKey(0), cfg)
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 30), 0, 128)  # S=29, not %8
-    l0 = float(cross_entropy_loss(params, {"tokens": tokens}, cfg))
-    l1 = float(cross_entropy_loss(
-        params, {"tokens": tokens}, dataclasses.replace(cfg, ce_chunk=8)))
-    np.testing.assert_allclose(l0, l1, rtol=1e-6)
+    batch = {"tokens": jnp.zeros((B, S + 1), jnp.int32), "mask": jnp.ones((B, S + 1), jnp.float32)}
+
+    def loss(p, b):
+        return cross_entropy_loss(p, b, cfg)
+
+    most = _largest_with(jax.make_jaxpr(jax.value_and_grad(loss) if differentiated else loss)(params, batch).jaxpr, cfg.vocab_size)
+    assert most == max(B * chunk, cfg.d_model if differentiated else 0) * cfg.vocab_size < B * S * cfg.vocab_size
