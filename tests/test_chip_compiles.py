@@ -496,3 +496,36 @@ def test_the_train_step_at_the_train_cells_shapes_computes_no_product_twice_and_
             assert not set(re.findall(r"calls=%([\w.\-]+)", line)) & products, line
     assert len(re.findall(r"= [^=\n]*? fusion\(", text)) <= 215
     assert "loss_head" in text and text.count("tpu_custom_call") >= 4  # the flash forward twice under "dots", its backward's two
+
+
+FLASH_SHAPES = {
+    # B, S, H, KV, D, blocks, window, with the backward
+    "the train cell's call: four sub-tiles a block": (3, 4096, 32, 8, 128, 1024, 0, True),
+    "a sliding layer's prompt of 8,192 tokens": (1, 8192, 48, 8, 128, 512, 512, False),
+    "a full layer's prompt at a head size under a lane tile": (2, 2048, 16, 4, 64, 512, 0, False),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(FLASH_SHAPES))
+def test_the_flash_calls_lower_for_a_v5e(shape, one_chip, no_compile_cache, monkeypatch):
+    """The walk of a block's live sub-tiles (PR 53) by the TPU's own compiler: slices of a block's refs at a loop's
+    index along sublanes and along lanes, scalar tables read in the index maps and in the body, a value a row
+    repeated along the lanes, dk/dv's transposed scores; with ids, as every caller passes them."""
+    from ray_tpu.ops.attention import flash_attention
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    B, S, H, KV, D, blocks, window, backward = FLASH_SHAPES[shape]
+
+    def arr(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def call(q, k, v, ids):
+        return flash_attention(q, k, v, segment_ids=ids, window=window, block_q=blocks, block_k=blocks)
+
+    if backward:
+        call = jax.grad(lambda q, k, v, ids, f=call: jnp.sum(f(q, k, v, ids).astype(jnp.float32)), argnums=(0, 1, 2))
+    args = (arr((B, S, H, D), jnp.bfloat16), arr((B, S, KV, D), jnp.bfloat16), arr((B, S, KV, D), jnp.bfloat16),
+            arr((B, S), jnp.int32))
+    text = jax.jit(call).lower(*args).compile().as_text()
+    assert "flash_attn_fwd" in text and text.count("tpu_custom_call") == (3 if backward else 1)
+    assert ("flash_attn_dq" in text and "flash_attn_dkv" in text) == backward
