@@ -1,0 +1,7 @@
+"""decode_ms_per_step, under a name of its own in the cell that serves a latent-attention layer beside gated-delta-rule
+layers in one model: the decode program's device time a step, steps counted from `kda_step`'s four calls. The lists it could join are held to their members by tests a PR that
+adds a cell may not edit (PERF.md section 7 asks the next benchmark PR to fold the names)."""
+
+
+def read(ctx):
+    return ctx.same_as("decode_ms_per_step")

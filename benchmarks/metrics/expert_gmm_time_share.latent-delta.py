@@ -1,0 +1,7 @@
+"""expert_gmm_time_share, under a name of its own in the cell that serves a latent-attention layer beside gated-delta-rule
+layers in one model: the grouped matmul's share of busy time, 16 of 256 experts in each of four routed layers. The lists it could join are held to their members by tests a PR that
+adds a cell may not edit (PERF.md section 7 asks the next benchmark PR to fold the names)."""
+
+
+def read(ctx):
+    return ctx.same_as("expert_gmm_time_share")

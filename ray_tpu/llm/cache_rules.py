@@ -489,8 +489,9 @@ class SlotTail(_BySlot):
 
 
 class LatentRows(_PageTable):
-    """One row a token a layer, ``[c | k_rope]`` (the normed low-rank latent and the roped key all heads share), in
-    ONE pool [layers, total_pages * page_size, ``latent_row_width``] in place of two pools of head rows; page
+    """One row a token a layer of the kind, ``[c | k_rope]`` (the normed low-rank latent and the roped key all heads
+    share), in ONE pool [the kind's layers, total_pages * page_size, ``latent_row_width``] in place of two pools of
+    head rows; page
     tables, lengths, admission and the prefix cache's digests are the paged rule's. A prompt expands its own rows
     to keys and values for the flash kernel; decode absorbs the two up-projections into the query and the output
     and attends the rows as they lie (``latent_attn``, ops/latent_attention.py); a tail expands context and tail
@@ -507,14 +508,15 @@ class LatentRows(_PageTable):
         return ONE_CHIP if option == "tensor_parallel > 1" else None
 
     def pools(self):
-        return [((self.cfg.n_layers, self.ec.total_pages * self.ec.page_size, self.width), _P(None, None, None))]
+        return [((self.cfg.layers_of(self.kind), self.ec.total_pages * self.ec.page_size, self.width),
+                 _P(None, None, None))]
 
     def prompt_attend(self, lp, seg, length):
         cfg = self.cfg
 
         def attend(q, c, k_rope):
             k, v = latent_expand(lp, c, k_rope, c.dtype)
-            o = _prompt_attention(jnp.concatenate(q, axis=-1), k, v, seg, self.mesh, latent_scale(cfg))
+            o = _prompt_attention(jnp.concatenate(q, axis=-1), k, v, seg, self.mesh, latent_scale(cfg, self.kind))
             return o, (_row_major(_latent_rows(c[0], k_rope[0], self.width, cfg.dtype)),)
         return attend
 
@@ -531,7 +533,7 @@ class LatentRows(_PageTable):
                 q_row = _latent_rows(qt, q_rope[:, 0], self.width, dt)  # [B, H, W]
                 row = _latent_rows(c[:, 0], k_rope[:, 0], self.width, dt)  # [B, W]
                 ctx, pool = call(q_row, row, pools[0], seen, page_tables, layer,
-                                 v_width=cfg.kv_lora_rank, scale=latent_scale(cfg))  # ctx: [B, H, R]
+                                 v_width=cfg.kv_lora_rank, scale=latent_scale(cfg, self.kind))  # ctx: [B, H, R]
                 o = latent_values(lp, ctx, dt)
             return o[:, None], (pool,)
         return attend
@@ -545,7 +547,7 @@ class LatentRows(_PageTable):
             every = jnp.concatenate([ctx_rows, rows], axis=0)[None]  # [1, C*ps+Tb, W]
             k, v = latent_expand(lp, every[..., :R], every[..., R:R + rope], c.dtype)
             scores = jnp.einsum("bthk,bshk->bhts", jnp.concatenate(q, axis=-1), k).astype(jnp.float32)
-            scores = jnp.where(mask[None, None], scores * latent_scale(cfg), -1e30)
+            scores = jnp.where(mask[None, None], scores * latent_scale(cfg, self.kind), -1e30)
             pr = jax.nn.softmax(scores, axis=-1).astype(c.dtype)
             return jnp.einsum("bhts,bshk->bthk", pr, v), (rows,)
         return attend
@@ -559,8 +561,8 @@ def rule_for(cfg: TransformerConfig, kind: LayerKind, ec, first: int) -> CacheRu
     # layers walk half the pages a step their bfloat16 pages would allow (PERF.md section 7); a first kind that
     # keeps a tail alone has no float32 pool.
     itemsize = jnp.dtype(jnp.float32 if cfg.kinds[0].state else cfg.dtype).itemsize
-    if cfg.latent:
-        return LatentRows(cfg, kind, ec, first, itemsize)
+    if kind.mixer == "latent":  # its walk counts pages in its own pool's dtype, whatever kind comes first
+        return LatentRows(cfg, kind, ec, first, jnp.dtype(cfg.dtype).itemsize)
     if kind.recurrent:
         such = " and ".join(sorted({k.mixer for k in cfg.kinds if k.recurrent})) + " layers"
         return (SlotState if kind.state else SlotTail)(cfg, kind, ec, first, such)

@@ -34,7 +34,10 @@ llm/_internal/serve/engines/vllm/vllm_engine.py:174):
   K and V rows in pages, a ring a slot, a state a slot, a convolution's tail
   a slot, latent rows in pages): the engine holds one rule a LayerKind
   (``self.rules``), hands each its own pools of ``self.cache`` and asks; the
-  programs here loop over them.
+  programs here loop over them. Rules compose: a model whose layers are of
+  two kinds has two (a state a slot beside K and V rows in pages, or beside
+  latent rows in pages: ``SlotState`` and ``LatentRows`` in one engine), and
+  nothing here names either.
 - Tensor-parallel serving (EngineConfig.tensor_parallel > 1): params shard
   Megatron-style and the KV pools shard by kv_heads over a `tensor` mesh
   axis (parallel/), so a model bigger than one chip's HBM serves from a
@@ -71,7 +74,7 @@ from jax.sharding import NamedSharding, PartitionSpec as _P
 from ray_tpu.llm.cache_rules import ONE_CHIP, rule_for
 from ray_tpu.llm.sampling import SamplingParams, sample_batch
 from ray_tpu.models.transformer import (
-    TransformerConfig, _rms_norm, decoder_block, embed_tokens, hidden_logits, init_params, param_logical_axes,
+    TransformerConfig, decoder_block, embed_tokens, hidden_logits, init_params, model_norm, param_logical_axes,
     run_layers,
 )
 from ray_tpu.util import tracing as _tracing
@@ -513,7 +516,7 @@ class LLMEngine:
         cache = self._rule_pools(cache, lambda rule, pools: rule.write_prompt(
             pools, rows[rule.sl], page_idxs, place, length))
         with jax.named_scope("lm_head"):
-            x = _rms_norm(x, params["final_norm"], cfg.norm_eps)
+            x = model_norm(x, params["final_norm"], cfg)
             last = jax.lax.dynamic_index_in_dim(x[0], length - 1, axis=0, keepdims=False)
             logits = hidden_logits(params, last, cfg)
         with jax.named_scope("sample"):
@@ -574,7 +577,7 @@ class LLMEngine:
             for rows in self._device_counts(lambda rule: rule.step_counts(seen)).values():
                 counts = rows if counts is None else jnp.concatenate([counts, rows])
             with jax.named_scope("lm_head"):
-                x = _rms_norm(x, params["final_norm"], cfg.norm_eps)
+                x = model_norm(x, params["final_norm"], cfg)
                 logits = hidden_logits(params, x[:, 0], cfg)
             with jax.named_scope("sample"):
                 toks = sample_batch(logits.astype(jnp.float32), temps, top_ps, top_ks,
@@ -660,7 +663,7 @@ class LLMEngine:
         # The gather above reads positions < start and this lands on the
         # tail's pages, so the write can follow the scan.
         cache = self._rule_pools(cache, lambda rule, pools: rule.write_pages(pools, rows[rule.sl], tail_pages))
-        x = _rms_norm(x, params["final_norm"], cfg.norm_eps)
+        x = model_norm(x, params["final_norm"], cfg)
         last = jax.lax.dynamic_index_in_dim(x[0], length - 1 - start, axis=0, keepdims=False)
         logits = hidden_logits(params, last, cfg)
         toks = sample_batch(logits.astype(jnp.float32)[None], temp, top_p, top_k, key,

@@ -12,7 +12,10 @@ reference_window_moe.py (window and full attention layers, held experts),
 reference_linear_moe.py (delta-rule layers beside gated NoPE attention, held
 experts), reference_ssm_hybrid.py (Mamba-2 layers beside NoPE attention, a tied
 head), reference_conv_moe.py (gated short-convolution layers beside QK-normed
-roped attention, every expert held, a router's selection bias).
+roped attention, every expert held, a router's selection bias),
+reference_latent_delta_moe.py (one gated latent-attention layer in four beside
+gated-delta-rule layers with grouped key heads and a decay a head, gated norms
+before and after every sublayer, a clamped SwiGLU, held experts).
 """
 from ray_tpu.models.transformer import (
     Transformer,

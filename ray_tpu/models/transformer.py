@@ -63,10 +63,28 @@ class LayerKind:
     # gate C on its output, one output projection [d_model, d_model]. No heads
     # (n_heads 0), no state: what a sequence keeps is its last conv_size - 1
     # rows of z. No window, no rope, none of the model's attn_gate.
+    # "latent": softmax attention through low-rank query and key/value
+    # projections with their inner norms (the model's q_lora_rank, kv_lora_rank,
+    # qk_nope_head_dim, qk_rope_head_dim, v_head_dim): what a token caches is
+    # the kv_lora_rank latent and one roped key all n_heads heads share. The
+    # roped columns turn by the kind's frequencies (YaRN's where it has a
+    # factor), the softmax scale is times softmax_factor, and the model's
+    # attn_gate lies on the heads' v_head_dim-wide outputs. No window.
+    # ``attention_kind="latent"`` is a model whose one kind is this.
     mixer: str = "attention"
     conv_size: int = 0
+    # A delta layer's decay and output gate through this many columns; 0: the
+    # decay a HEAD from one matrix [D, H] (g = -exp(a_log) softplus(h wa +
+    # dt_bias), the same for a head's every channel) and the gate full-rank,
+    # [D, H, head_dim].
     low_rank: int = 0
     beta_scale: float = 1.0
+    # A delta layer's queries and keys on this many heads (0: n_heads), each
+    # convolved and normed there and then serving n_heads / n_key_heads
+    # neighbouring value heads.
+    n_key_heads: int = 0
+    gate_scale: float = 1.0  # a delta layer's output gate is gate_scale sigmoid(.)
+    softmax_factor: float = 1.0  # a latent layer's softmax scale times this (YaRN's mscale^2 where a model asks it)
     head_width: int = 0
     state_size: int = 0
     n_groups: int = 0
@@ -97,6 +115,10 @@ class LayerKind:
         """Keeps by slot what does not grow with the context (a state and a
         tail, or a tail alone), and no rows of tokens."""
         return self.state or self.mixer == "conv"
+
+    @property
+    def key_heads(self) -> int:
+        return self.n_key_heads or self.n_heads
 
 
 @dataclasses.dataclass(frozen=True)
@@ -142,6 +164,14 @@ class TransformerConfig:
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+    # Every RMS norm's scale is norm_gating sigmoid(w) of its weight w (2: a
+    # weight of zeros is a scale of one; weights are then drawn N(0, 1/4), so
+    # that a program that multiplies by w itself is far off); 0: the scale is
+    # w. A delta layer's head norm keeps its plain weight.
+    norm_gating: float = 0.0
+    # SwiGLU with its two branches clamped, silu(min(gate, limit)) *
+    # clip(up, -limit, limit), in dense, shared and routed experts alike; 0: no clamp.
+    swiglu_limit: float = 0.0
     # A norm after each sublayer as well as before it:
     # h = x + N2(Attn(N1(x))), y = h + N4(FFN(N3(h))).
     sandwich_norm: bool = False
@@ -167,7 +197,8 @@ class TransformerConfig:
     router_bias: bool = False
     # A head's width; 0 -> d_model // n_heads.
     head_dim: int = 0
-    # Layers of more than one kind ("gqa" only): the LayerKinds of one period,
+    # Layers of more than one kind: the LayerKinds of one period (a latent
+    # kind among them where attention_kind is "gqa": the kind says it),
     # layer l being of kind layer_pattern[l % len]. The leading dense layers
     # are of one kind and lie in params["dense_layers"]; the layers after them
     # are whole periods, each kind's in one stack of params["kind_layers"],
@@ -176,7 +207,8 @@ class TransformerConfig:
     layer_pattern: tuple = ()
     # An attention layer's output times sigmoid(h Wg), h the layer's normed
     # input, before the output projection: "per_head" one scalar a head (Wg
-    # [D, H]), "elementwise" one a column (Wg [D, H, head_dim]).
+    # [D, H]), "elementwise" one a column (Wg [D, H, head_dim]; a latent
+    # layer's [D, H, v_head_dim], and "elementwise" alone).
     attn_gate: str = ""
     # A "gqa" layer's queries and keys RMS-normed over a head's columns before
     # the rope, one weight of head_dim for all query heads ("q_norm") and one
@@ -201,13 +233,15 @@ class TransformerConfig:
 
     @property
     def latent(self) -> bool:
-        return self.attention_kind == "latent"
+        """Some kind of the model's layers is latent attention."""
+        return any(k.mixer == "latent" for k in self.kinds)
 
     @property
     def kinds(self) -> tuple:
         """The model's distinct layer kinds, in the order layers first meet them."""
         if not self.layer_pattern:
-            return (LayerKind("layers", self.n_heads, rope_theta=self.rope_theta),)
+            mixer = "latent" if self.attention_kind == "latent" else "attention"
+            return (LayerKind("layers", self.n_heads, mixer=mixer, rope_theta=self.rope_theta),)
         return tuple(dict.fromkeys(self.layer_pattern))
 
     def kind_of(self, layer: int) -> LayerKind:
@@ -232,20 +266,23 @@ class TransformerConfig:
             assert self.d_model % self.n_heads == 0
             object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
         assert self.n_heads % self.kv_heads == 0
-        assert self.attn_gate in ("", "per_head", "elementwise") and not (self.attn_gate and self.latent), (
-            "an output gate, on gqa layers")
+        assert self.attn_gate in ("", "per_head", "elementwise")
         if self.layer_pattern:
             object.__setattr__(self, "layer_pattern", tuple(self.layer_pattern))
             p = len(self.layer_pattern)
-            assert not self.latent, "a layer pattern is written for gqa layers"
+            assert self.attention_kind == "gqa", (
+                "a model that is latent throughout has no layer pattern: its kinds say it")
             assert len({k.name for k in self.kinds}) == len(self.kinds), "two kinds under one name"
-            assert all(k.n_heads % self.kv_heads == 0 for k in self.kinds if not k.recurrent)
+            assert all(k.n_heads % self.kv_heads == 0 for k in self.kinds if k.mixer == "attention")
             for k in self.kinds:
-                assert k.mixer in ("attention", "delta", "ssd", "conv"), k.mixer
+                assert k.mixer in ("attention", "latent", "delta", "ssd", "conv"), k.mixer
+                assert k.mixer != "latent" or not k.window, f"a latent layer has no window: {k}"
                 assert k.mixer != "conv" or (k.conv_size > 1 and not k.n_heads and not k.window), (
                     f"a conv layer has a short convolution, no heads and no window: {k}")
-                assert k.mixer != "delta" or (k.conv_size > 1 and k.low_rank > 0 and not k.window), (
-                    f"a delta layer has a short convolution, low-rank sizes and no window: {k}")
+                assert k.mixer != "delta" or (
+                    k.conv_size > 1 and k.low_rank >= 0 and k.n_heads % k.key_heads == 0 and not k.window), (
+                    f"a delta layer has a short convolution, low-rank sizes or none, key heads that divide its "
+                    f"heads and no window: {k}")
                 assert k.mixer != "ssd" or (
                     k.conv_size > 1 and k.head_width > 0 and k.state_size > 0 and k.n_groups > 0
                     and k.n_heads % k.n_groups == 0 and not k.window), (
@@ -264,6 +301,7 @@ class TransformerConfig:
         assert not self.router_bias or self.experts_held, (
             "a selection bias is written for held experts (_held_experts_pass)")
         assert not (self.qk_norm and self.latent), "a head norm on q and k, on gqa layers (a latent layer has its own)"
+        assert not self.latent or self.attn_gate in ("", "elementwise"), "a latent layer's output gate is elementwise"
         if self.experts_held:
             assert self.first_expert + self.experts_held <= self.n_experts
             assert self.expert_d_ff > 0
@@ -301,6 +339,15 @@ def _inverse_softplus(y):
     return y + jnp.log(-jnp.expm1(-y))
 
 
+def _norm_init(cfg: "TransformerConfig", shape, key=None):
+    """A norm's weights: ones, or under ``norm_gating`` N(0, 1/4) from ``key``,
+    whose scale norm_gating sigmoid(w) lies about one (0.5 .. 1.5 for most
+    columns at 2)."""
+    if not cfg.norm_gating:
+        return jnp.ones(shape, cfg.param_dtype)
+    return (0.5 * jax.random.normal(key, shape, jnp.float32)).astype(cfg.param_dtype)
+
+
 def _init_stack(key: jax.Array, cfg: TransformerConfig, L: int, routed: bool, kind: LayerKind | None = None) -> tuple:
     """One stack of L identical layers of `kind` (None: the model's one kind;
     leading 'layers' dim on every leaf); `routed`: the FFN is experts behind
@@ -310,10 +357,16 @@ def _init_stack(key: jax.Array, cfg: TransformerConfig, L: int, routed: bool, ki
     kind = kind or cfg.kinds[0]
     k = iter(jax.random.split(key, 24 if kind.recurrent else 16))
     D, F, H = cfg.d_model, cfg.d_ff, kind.n_heads
+    # keys beside k's, which a plain norm (ones) never asked for
+    norm_keys = iter(jax.random.split(jax.random.fold_in(key, 54), 8)) if cfg.norm_gating else None
+
+    def norm_w(*shape):
+        return _norm_init(cfg, (L, *shape), next(norm_keys) if norm_keys else None)
+
     if kind.mixer == "ssd":
         I, C, T = H * kind.head_width, ssd_conv_channels(kind), kind.conv_size
         layer = {
-            "attn_norm": jnp.ones((L, D), pd),
+            "attn_norm": norm_w(D),
             # [z | x B C | dt] from D columns: the outputs' axis before the inputs', so that the minor dimension
             # is whole lane tiles (as [D, 8512] the TPU compiler turned the stack round on every call, a copy of it)
             "w_in": _dense_init(next(k), (L, I + C + H, D), pd, in_axis=2),
@@ -325,68 +378,84 @@ def _init_stack(key: jax.Array, cfg: TransformerConfig, L: int, routed: bool, ki
             "d_skip": jnp.ones((L, H), pd),
             "o_norm": jnp.ones((L, I), pd),
             "wo": _dense_init(next(k), (L, H, kind.head_width, D), pd, in_axis=(1, 2)),
-            "ffn_norm": jnp.ones((L, D), pd),
+            "ffn_norm": norm_w(D),
         }
     elif kind.mixer == "conv":
         layer = {
-            "attn_norm": jnp.ones((L, D), pd),
+            "attn_norm": norm_w(D),
             "w_in": _dense_init(next(k), (L, D, 3 * D), pd, in_axis=1),  # [B | C | u], in that order of thirds
             "conv": _conv_taps_init(next(k), (L, kind.conv_size, D), pd),
             "w_out": _dense_init(next(k), (L, D, D), pd, in_axis=1),
-            "ffn_norm": jnp.ones((L, D), pd),
+            "ffn_norm": norm_w(D),
         }
     elif kind.recurrent:
-        Hd, R, T = cfg.head_dim, kind.low_rank, kind.conv_size
+        Hd, R, T, Hk = cfg.head_dim, kind.low_rank, kind.conv_size, kind.key_heads
+        heads = lambda: _dense_init(next(k), (L, D, H, Hd), pd, in_axis=1)
+        key_heads = lambda: _dense_init(next(k), (L, D, Hk, Hd), pd, in_axis=1)
+        a_step = lambda *shape: _inverse_softplus(jnp.exp(jax.random.uniform(
+            next(k), (L, *shape), jnp.float32, math.log(0.001), math.log(0.1)))).astype(pd)
         layer = {
-            "attn_norm": jnp.ones((L, D), pd),
-            "wq": _dense_init(next(k), (L, D, H, Hd), pd, in_axis=1),
-            "wk": _dense_init(next(k), (L, D, H, Hd), pd, in_axis=1),
-            "wv": _dense_init(next(k), (L, D, H, Hd), pd, in_axis=1),
-            "conv": _dense_init(next(k), (L, T, 3, H, Hd), pd, in_axis=1),  # q's, k's and v's taps, the oldest first
-            "wf_a": _dense_init(next(k), (L, D, R), pd, in_axis=1),
-            "wf_b": _dense_init(next(k), (L, R, H, Hd), pd, in_axis=1),
-            # a step's size before the projection moves it: softplus^-1 of 0.001 .. 0.1, log-uniform
-            "dt_bias": _inverse_softplus(jnp.exp(jax.random.uniform(
-                next(k), (L, H, Hd), jnp.float32, math.log(0.001), math.log(0.1)))).astype(pd),
+            "attn_norm": norm_w(D),
+            "wq": key_heads(), "wk": key_heads(), "wv": heads(),
+            # q's, k's and v's taps, the oldest first; with fewer key heads the three lie along one axis of heads
+            "conv": _dense_init(next(k), (L, T, 3, H, Hd) if Hk == H else (L, T, 2 * Hk + H, Hd), pd, in_axis=1),
+        }
+        if R:
+            layer.update({
+                "wf_a": _dense_init(next(k), (L, D, R), pd, in_axis=1),
+                "wf_b": _dense_init(next(k), (L, R, H, Hd), pd, in_axis=1),
+                # a step's size before the projection moves it: softplus^-1 of 0.001 .. 0.1, log-uniform
+                "dt_bias": a_step(H, Hd),
+            })
+        else:
+            layer.update({"wa": _dense_init(next(k), (L, D, H), pd, in_axis=1), "dt_bias": a_step(H)})
+        layer.update({
             "a_log": jnp.log(jax.random.uniform(next(k), (L, H), jnp.float32, 1.0, 16.0)).astype(pd),
             "wb": _dense_init(next(k), (L, D, H), pd, in_axis=1),
-            "wg_a": _dense_init(next(k), (L, D, R), pd, in_axis=1),
-            "wg_b": _dense_init(next(k), (L, R, H, Hd), pd, in_axis=1),
+        })
+        if R:
+            layer.update({"wg_a": _dense_init(next(k), (L, D, R), pd, in_axis=1),
+                          "wg_b": _dense_init(next(k), (L, R, H, Hd), pd, in_axis=1)})
+        else:
+            layer["wz"] = heads()
+        layer.update({
             "o_norm": jnp.ones((L, Hd), pd),
             "wo": _dense_init(next(k), (L, H, Hd, D), pd, in_axis=(1, 2)),
-            "ffn_norm": jnp.ones((L, D), pd),
-        }
-    elif cfg.latent:
+            "ffn_norm": norm_w(D),
+        })
+    elif kind.mixer == "latent":
         R, Rq = cfg.kv_lora_rank, cfg.q_lora_rank
         nope, rope, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
         layer = {
-            "attn_norm": jnp.ones((L, D), pd),
+            "attn_norm": norm_w(D),
             "wq_a": _dense_init(next(k), (L, D, Rq), pd, in_axis=1),
-            "q_norm": jnp.ones((L, Rq), pd),
+            "q_norm": norm_w(Rq),
             "wq_b": _dense_init(next(k), (L, Rq, H, nope + rope), pd, in_axis=1),
             "wkv_a": _dense_init(next(k), (L, D, R + rope), pd, in_axis=1),
-            "kv_norm": jnp.ones((L, R), pd),
+            "kv_norm": norm_w(R),
             "wk_b": _dense_init(next(k), (L, R, H, nope), pd, in_axis=1),
             "wv_b": _dense_init(next(k), (L, R, H, vd), pd, in_axis=1),
             "wo": _dense_init(next(k), (L, H, vd, D), pd, in_axis=(1, 2)),
-            "ffn_norm": jnp.ones((L, D), pd),
+            "ffn_norm": norm_w(D),
         }
+        if cfg.attn_gate:
+            layer["wg"] = _dense_init(next(k), (L, D, H, vd), pd, in_axis=1)
     else:
         KV, Hd = cfg.kv_heads, cfg.head_dim
         layer = {
-            "attn_norm": jnp.ones((L, D), pd),
+            "attn_norm": norm_w(D),
             "wq": _dense_init(next(k), (L, D, H, Hd), pd, in_axis=1),
             "wk": _dense_init(next(k), (L, D, KV, Hd), pd, in_axis=1),
             "wv": _dense_init(next(k), (L, D, KV, Hd), pd, in_axis=1),
             "wo": _dense_init(next(k), (L, H, Hd, D), pd, in_axis=(1, 2)),
-            "ffn_norm": jnp.ones((L, D), pd),
+            "ffn_norm": norm_w(D),
         }
         if cfg.attn_gate:
             layer["wg"] = _dense_init(next(k), (L, D, H) + ((Hd,) if cfg.attn_gate == "elementwise" else ()), pd, in_axis=1)
         if cfg.qk_norm:
-            layer.update({"q_norm": jnp.ones((L, Hd), pd), "k_norm": jnp.ones((L, Hd), pd)})
+            layer.update({"q_norm": norm_w(Hd), "k_norm": norm_w(Hd)})
     if cfg.sandwich_norm:
-        layer.update({"post_attn_norm": jnp.ones((L, D), pd), "post_ffn_norm": jnp.ones((L, D), pd)})
+        layer.update({"post_attn_norm": norm_w(D), "post_ffn_norm": norm_w(D)})
     if routed:
         E = cfg.experts_held or cfg.n_experts
         EF = cfg.expert_d_ff or F
@@ -446,7 +515,8 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> dict:
     else:
         ends = {"embed": _dense_init(next(k), (cfg.vocab_size, D), pd) * (D ** 0.5),
                 "lm_head": _dense_init(next(k), (D, cfg.vocab_size), pd, in_axis=0)}
-    params = {"embed": ends.pop("embed"), **stacks, "final_norm": jnp.ones((D,), pd), **ends}
+    params = {"embed": ends.pop("embed"), **stacks,
+              "final_norm": _norm_init(cfg, (D,), jax.random.fold_in(key, 54) if cfg.norm_gating else None), **ends}
     if cfg.n_dense_layers:
         params["dense_layers"], _ = _init_stack(
             jax.random.fold_in(key, 1), cfg, cfg.n_dense_layers, routed=False, kind=cfg.kind_of(0))
@@ -479,7 +549,8 @@ def slot_state_shapes(cfg: TransformerConfig, kind: LayerKind) -> tuple:
         from ray_tpu.ops.ssd import state_shape
 
         return state_shape(kind.n_heads, kind.head_width, kind.state_size), ((T - 1) * ssd_conv_channels(kind),)
-    return (kind.n_heads, cfg.head_dim, cfg.head_dim), (T - 1, 3, kind.n_heads, cfg.head_dim)
+    H, Hk = kind.n_heads, kind.key_heads
+    return (H, cfg.head_dim, cfg.head_dim), (T - 1, *((3, H) if Hk == H else (2 * Hk + H,)), cfg.head_dim)
 
 
 def recurrence(kind: LayerKind) -> tuple:
@@ -623,30 +694,35 @@ def run_layers(body, carry, params: dict, cfg: TransformerConfig, xs: dict | Non
 
 def _stack_logical_axes(cfg: TransformerConfig, routed: bool, kind: LayerKind | None = None) -> dict:
     """A stack's logical axes (attention kinds differ in sizes only)."""
-    if kind is not None and kind.mixer == "ssd":
+    kind = kind or cfg.kinds[0]
+    if kind.mixer == "ssd":
         layer = {
             "attn_norm": ("layers", "embed"), "w_in": ("layers", None, "embed"), "conv": ("layers", None, None),
             "conv_bias": ("layers", None), "dt_bias": ("layers", "heads"), "a_log": ("layers", "heads"),
             "d_skip": ("layers", "heads"), "o_norm": ("layers", None),
             "wo": ("layers", "heads", "head_dim", "embed"), "ffn_norm": ("layers", "embed"),
         }
-    elif kind is not None and kind.mixer == "conv":
+    elif kind.mixer == "conv":
         layer = {
             "attn_norm": ("layers", "embed"), "w_in": ("layers", "embed", None), "conv": ("layers", None, None),
             "w_out": ("layers", None, "embed"), "ffn_norm": ("layers", "embed"),
         }
-    elif kind is not None and kind.recurrent:
+    elif kind.recurrent:
         heads = ("layers", "embed", "heads", "head_dim")
         low = ("layers", None, "heads", "head_dim")
+        grouped = kind.key_heads != kind.n_heads
         layer = {
             "attn_norm": ("layers", "embed"), "wq": heads, "wk": heads, "wv": heads,
-            "conv": ("layers", None, None, "heads", "head_dim"),
-            "wf_a": ("layers", "embed", None), "wf_b": low, "dt_bias": ("layers", "heads", "head_dim"),
-            "a_log": ("layers", "heads"), "wb": ("layers", "embed", "heads"),
-            "wg_a": ("layers", "embed", None), "wg_b": low, "o_norm": ("layers", "head_dim"),
+            "conv": ("layers", None, "heads", "head_dim") if grouped else ("layers", None, None, "heads", "head_dim"),
+            "a_log": ("layers", "heads"), "wb": ("layers", "embed", "heads"), "o_norm": ("layers", "head_dim"),
             "wo": ("layers", "heads", "head_dim", "embed"), "ffn_norm": ("layers", "embed"),
         }
-    elif cfg.latent:
+        if kind.low_rank:
+            layer.update({"wf_a": ("layers", "embed", None), "wf_b": low, "dt_bias": ("layers", "heads", "head_dim"),
+                          "wg_a": ("layers", "embed", None), "wg_b": low})
+        else:
+            layer.update({"wa": ("layers", "embed", "heads"), "dt_bias": ("layers", "heads"), "wz": heads})
+    elif kind.mixer == "latent":
         layer = {
             "attn_norm": ("layers", "embed"),
             "wq_a": ("layers", "embed", None),
@@ -659,6 +735,8 @@ def _stack_logical_axes(cfg: TransformerConfig, routed: bool, kind: LayerKind | 
             "wo": ("layers", "heads", "head_dim", "embed"),
             "ffn_norm": ("layers", "embed"),
         }
+        if cfg.attn_gate:
+            layer["wg"] = ("layers", "embed", "heads", "head_dim")
     else:
         layer = {
             "attn_norm": ("layers", "embed"),
@@ -723,9 +801,17 @@ def param_logical_axes(cfg: TransformerConfig) -> dict:
 # Forward
 # ---------------------------------------------------------------------------
 
-def _rms_norm(x, w, eps=1e-6):
+def _rms_norm(x, w, eps=1e-6, gating=0.0):
+    """x / rms(x) times w, or times gating sigmoid(w) (TransformerConfig.norm_gating)."""
     var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
+    if gating:
+        w = gating * jax.nn.sigmoid(w.astype(jnp.float32))
     return (x * lax.rsqrt(var + eps).astype(x.dtype)) * w.astype(x.dtype)
+
+
+def model_norm(x, w, cfg: TransformerConfig):
+    """The model's norm: every sublayer's, the latent projections' inner ones and the final one."""
+    return _rms_norm(x, w, cfg.norm_eps, cfg.norm_gating)
 
 
 def _rope(x, positions, theta):
@@ -863,9 +949,27 @@ def _attention(q, k, v, cfg: TransformerConfig, positions=None, segment_ids=None
     return mha_reference(q, k, v, causal=True, segment_ids=segment_ids, scale=scale, window=window)
 
 
-def _dense_ffn(x, p):
+def _clamped(gate, up, limit: float):
+    """SwiGLU's two branches under ``swiglu_limit``: the gate from above, the other from both sides."""
+    if not limit:
+        return gate, up
+    with jax.named_scope("swiglu_clamp"):
+        return jnp.minimum(gate, limit), jnp.clip(up, -limit, limit)
+
+
+def _swiglu_product(project, w_gate, w_up, limit: float):
+    """silu(project(w_gate)) * project(w_up), clamped under ``swiglu_limit``; without a limit the operations
+    and their order are what the held experts' pass traced before the limit was there."""
+    if not limit:
+        return jax.nn.silu(project(w_gate)) * project(w_up)
+    gate, up = _clamped(project(w_gate), project(w_up), limit)
+    return jax.nn.silu(gate) * up
+
+
+def _dense_ffn(x, p, limit: float = 0.0):
     gate = jnp.einsum("bsd,df->bsf", x, p["w_gate"].astype(x.dtype))
     up = jnp.einsum("bsd,df->bsf", x, p["w_up"].astype(x.dtype))
+    gate, up = _clamped(gate, up, limit)
     h = jax.nn.silu(gate) * up
     h = wlc(h, ("batch", "seq", "mlp"))
     return jnp.einsum("bsf,fd->bsd", h, p["w_down"].astype(x.dtype))
@@ -895,6 +999,7 @@ def _moe_ffn(x, p, cfg: TransformerConfig):
     # bench; for large E the EP strategy shards the E dim across chips.
     gate = jnp.einsum("bsd,edf->ebsf", x, p["w_gate"].astype(x.dtype))
     up = jnp.einsum("bsd,edf->ebsf", x, p["w_up"].astype(x.dtype))
+    gate, up = _clamped(gate, up, cfg.swiglu_limit)
     h = jax.nn.silu(gate) * up
     h = wlc(h, ("experts", "batch", "seq", "expert_mlp"))
     out = jnp.einsum("ebsf,efd->ebsd", h, p["w_down"].astype(x.dtype))
@@ -912,9 +1017,9 @@ def _load_balance_loss(weights, top_idx, n_experts):
     return n_experts * jnp.sum(me * ce)
 
 
-def latent_scale(cfg: TransformerConfig) -> float:
-    """Softmax scale of a latent layer: over the whole query/key width."""
-    return 1.0 / math.sqrt(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
+def latent_scale(cfg: TransformerConfig, kind: LayerKind | None = None) -> float:
+    """Softmax scale of a latent layer: over the whole query/key width, times the kind's softmax_factor."""
+    return (kind.softmax_factor if kind else 1.0) / math.sqrt(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
 
 
 def latent_expand(lp, c, k_rope, dt):
@@ -952,20 +1057,20 @@ def lane_padded(q, k, v):
     return pad_last(q, wide), pad_last(k, wide), pad_last(v, wide)
 
 
-def _latent_qkv(h, lp, cfg: TransformerConfig, positions):
+def _latent_qkv(h, lp, cfg: TransformerConfig, positions, kind: LayerKind):
     """-> (q_nope [B,S,H,nope], q_rope [B,S,H,rope]) roped, c [B,S,R] normed,
-    k_rope [B,S,rope] roped."""
-    dt, eps, R = h.dtype, cfg.norm_eps, cfg.kv_lora_rank
+    k_rope [B,S,rope] roped; the roped columns by the kind's frequencies."""
+    dt, R = h.dtype, cfg.kv_lora_rank
     with jax.named_scope("mla_q"):
-        cq = _rms_norm(jnp.einsum("bsd,dr->bsr", h, lp["wq_a"].astype(dt)), lp["q_norm"], eps)
+        cq = model_norm(jnp.einsum("bsd,dr->bsr", h, lp["wq_a"].astype(dt)), lp["q_norm"], cfg)
         q = jnp.einsum("bsr,rhk->bshk", cq, lp["wq_b"].astype(dt))
         q = wlc(q, ("batch", "seq", "heads", "head_dim"))
         q_nope, q_rope = q[..., :cfg.qk_nope_head_dim], q[..., cfg.qk_nope_head_dim:]
-        q_rope = _rope(q_rope, positions, cfg.rope_theta)
+        q_rope = _rope_kind(q_rope, positions, kind)
     with jax.named_scope("mla_kv"):
         ckr = jnp.einsum("bsd,dr->bsr", h, lp["wkv_a"].astype(dt))
-        c = _rms_norm(ckr[..., :R], lp["kv_norm"], eps)
-        k_rope = _rope(ckr[:, :, None, R:], positions, cfg.rope_theta)[:, :, 0]
+        c = model_norm(ckr[..., :R], lp["kv_norm"], cfg)
+        k_rope = _rope_kind(ckr[:, :, None, R:], positions, kind)[:, :, 0]
     return (q_nope, q_rope), c, k_rope
 
 
@@ -1041,7 +1146,7 @@ def _held_experts_pass(x, p, cfg: TransformerConfig):
             tile_expert=plan.tile_expert, n_tiles=plan.n_tiles, tm=tm)
         w_gate, w_up, w_down = (p[k] if stacked else p[k][None] for k in HELD_EXPERT_WEIGHTS)
         xs = xt[plan.token_of_row]  # [M, D]
-        h = jax.nn.silu(gmm(xs, w_gate)) * gmm(xs, w_up)
+        h = _swiglu_product(lambda w: gmm(xs, w), w_gate, w_up, cfg.swiglu_limit)
         y = gmm(h, w_down)  # [M, D]; rows past the live tiles hold nothing
         pair = y[plan.row_of_pair].astype(jnp.float32) * top_w[..., None]  # [T, K, D]
         routed = jnp.sum(jnp.where(plan.held[..., None], pair, 0.0), axis=1)
@@ -1049,18 +1154,22 @@ def _held_experts_pass(x, p, cfg: TransformerConfig):
     with jax.named_scope("experts/shared"):
         if cfg.n_shared_experts:
             shared = {"w_gate": p["ws_gate"], "w_up": p["ws_up"], "w_down": p["ws_down"]}
-            routed = routed + _dense_ffn(x, shared)
+            routed = routed + _dense_ffn(x, shared, cfg.swiglu_limit)
     return routed, jnp.stack([jnp.sum(plan.sizes), plan.n_tiles[0]]).astype(jnp.int32)
 
 
 def _delta_mixer(h, lp, cfg: TransformerConfig, kind: LayerKind, attend):
     """A delta layer's mixer on its normed input h [B, S, D]: projections to
-    q~, k~, v~ [B, S, H, Hd], the short convolution and SiLU on each, q and k
-    normed to length 1 a head (q times Hd^-1/2 besides), the decay a channel
-    g = -exp(a_log) softplus(low-rank f(h) + dt_bias) and the step size beta =
-    beta_scale sigmoid(h wb) in float32, the rule, then a head's output
-    RMS-normed and times sigmoid(low-rank g(h)). Returns (o [B, S, H, Hd]
-    before the output projection, kept).
+    q~, k~ [B, S, Hk, Hd] and v~ [B, S, H, Hd] (Hk = H unless the kind has
+    fewer key heads), the short convolution and SiLU on each, q and k normed to
+    length 1 a head (q times Hd^-1/2 besides) and, with fewer key heads, each
+    repeated over the H / Hk value heads it serves, the decay g =
+    -exp(a_log) softplus(f(h) + dt_bias) (a channel through the low-rank f; a
+    head where the kind has no low rank, the same in a head's every channel)
+    and the step size beta = beta_scale sigmoid(h wb) in float32, the rule,
+    then a head's output RMS-normed and times gate_scale sigmoid(z(h)), z
+    low-rank or whole as f. Returns (o [B, S, H, Hd] before the output
+    projection, kept).
 
     ``attend`` is the program's side, a pair (tail, rule). tail: the T - 1
     inputs of the convolution before position 0, [B, T - 1, 3, H, Hd] (None:
@@ -1069,32 +1178,54 @@ def _delta_mixer(h, lp, cfg: TransformerConfig, kind: LayerKind, attend):
     rule over these positions on whatever
     state the program keeps (ops/linear_attention.py); window
     [B, T - 1 + S, 3, H, Hd] is the tail and the convolution's inputs behind
-    it, of which a program keeps its next tail."""
+    it, of which a program keeps its next tail. With fewer key heads q~, k~
+    and v~ lie along ONE axis of 2 Hk + H heads, in tail, taps and window
+    alike ([B, T - 1, 2 Hk + H, Hd])."""
     dt, T = h.dtype, kind.conv_size
     S, Hd = h.shape[1], cfg.head_dim
+    H, Hk = kind.n_heads, kind.key_heads
     tail, rule = attend
     with jax.named_scope("qkv"):
-        u = jnp.stack([jnp.einsum("bsd,dhk->bshk", h, lp[w].astype(dt)) for w in ("wq", "wk", "wv")], axis=2)
-        u = wlc(u, ("batch", "seq", None, "heads", "head_dim"))
+        u = [jnp.einsum("bsd,dhk->bshk", h, lp[w].astype(dt)) for w in ("wq", "wk", "wv")]
+        if Hk == H:
+            u = wlc(jnp.stack(u, axis=2), ("batch", "seq", None, "heads", "head_dim"))
+        else:
+            u = wlc(jnp.concatenate(u, axis=2), ("batch", "seq", "heads", "head_dim"))
     with jax.named_scope("short_conv"):
         if tail is None:
             tail = jnp.zeros((u.shape[0], T - 1, *u.shape[2:]), dt)
         window = jnp.concatenate([tail.astype(dt), u], axis=1)
         taps = lp["conv"].astype(jnp.float32)
         y = sum(window[:, j:j + S].astype(jnp.float32) * taps[j] for j in range(T))
-        q, k, v = (y[:, :, i] for i in range(3))
+        q, k, v = (y[:, :, i] for i in range(3)) if Hk == H else (y[:, :, :Hk], y[:, :, Hk:2 * Hk], y[:, :, 2 * Hk:])
         q, k, v = jax.nn.silu(q), jax.nn.silu(k), jax.nn.silu(v).astype(dt)
         q = q * lax.rsqrt(jnp.sum(q * q, axis=-1, keepdims=True) + L2_EPS) * Hd ** -0.5
         k = k * lax.rsqrt(jnp.sum(k * k, axis=-1, keepdims=True) + L2_EPS)
+        if Hk != H:  # key head j serves value heads j H / Hk .. (j + 1) H / Hk - 1
+            q, k = jnp.repeat(q, H // Hk, axis=2), jnp.repeat(k, H // Hk, axis=2)
     with jax.named_scope("decay"):
-        f = jnp.einsum("bsr,rhk->bshk", jnp.einsum("bsd,dr->bsr", h, lp["wf_a"].astype(dt)), lp["wf_b"].astype(dt))
-        g = -jnp.exp(lp["a_log"].astype(jnp.float32))[:, None] * jax.nn.softplus(
-            f.astype(jnp.float32) + lp["dt_bias"].astype(jnp.float32))
+        if kind.low_rank:
+            f = jnp.einsum("bsr,rhk->bshk", jnp.einsum("bsd,dr->bsr", h, lp["wf_a"].astype(dt)), lp["wf_b"].astype(dt))
+            g = -jnp.exp(lp["a_log"].astype(jnp.float32))[:, None] * jax.nn.softplus(
+                f.astype(jnp.float32) + lp["dt_bias"].astype(jnp.float32))
+        else:
+            f = jnp.einsum("bsd,dh->bsh", h, lp["wa"].astype(dt))
+            g = -jnp.exp(lp["a_log"].astype(jnp.float32)) * jax.nn.softplus(
+                f.astype(jnp.float32) + lp["dt_bias"].astype(jnp.float32))
+            # the rule takes a decay a channel (ops/linear_attention.py): a head's, in each of its key channels
+            g = jnp.broadcast_to(g[..., None], (*g.shape, Hd))
         beta = kind.beta_scale * jax.nn.sigmoid(jnp.einsum("bsd,dh->bsh", h, lp["wb"].astype(dt)).astype(jnp.float32))
     o, kept = rule((q, k, v, g, beta), window)
     with jax.named_scope("out_gate"):
-        gate = jnp.einsum("bsr,rhk->bshk", jnp.einsum("bsd,dr->bsr", h, lp["wg_a"].astype(dt)), lp["wg_b"].astype(dt))
-        o = _rms_norm(o.astype(dt), lp["o_norm"], cfg.norm_eps) * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(dt)
+        if kind.low_rank:
+            gate = jnp.einsum("bsr,rhk->bshk", jnp.einsum("bsd,dr->bsr", h, lp["wg_a"].astype(dt)), lp["wg_b"].astype(dt))
+        else:
+            gate = jnp.einsum("bsd,dhk->bshk", h, lp["wz"].astype(dt))
+        o = _rms_norm(o.astype(dt), lp["o_norm"], cfg.norm_eps)
+        gate = jax.nn.sigmoid(gate.astype(jnp.float32))
+        if kind.gate_scale != 1.0:
+            gate = kind.gate_scale * gate
+        o = o * gate.astype(dt)
     return o, kept
 
 
@@ -1180,10 +1311,11 @@ def decoder_block(x, lp, cfg: TransformerConfig, positions, attend, kind: LayerK
     x: [B, S, D] in cfg.dtype; lp: one layer's parameters (its FFN is routed
     if they hold a router, dense otherwise); positions: [B, S]; kind: the
     layer's LayerKind (None: the model's one kind), which says how its "gqa"
-    heads are roped; its window is the ``attend``'s to keep.
+    heads (or a "latent" kind's roped columns) are roped; its window is the
+    ``attend``'s to keep.
     attend(q, k, v) -> (o [B,S,H,v width], kept). "gqa": q [B,S,H,Hd],
     k and v [B,S,KV,Hd], q and k roped, grouped K/V as they are (native GQA).
-    "latent": q = (q_nope [B,S,H,nope], q_rope [B,S,H,rope]), k = the normed
+    "latent" (the kind's mixer): q = (q_nope [B,S,H,nope], q_rope [B,S,H,rope]), k = the normed
     latent c [B,S,R], v = the roped shared key k_rope [B,S,rope], which is
     what such a layer caches; the attention side expands them over a prompt
     (``latent_expand``) or absorbs the projections in decode. ``kept`` is
@@ -1192,9 +1324,9 @@ def decoder_block(x, lp, cfg: TransformerConfig, positions, attend, kind: LayerK
     (``_delta_mixer``, ``_ssd_mixer`` and ``_conv_mixer`` say of what).
     Returns (x, aux, kept): aux is the MoE balance term of a training layer, a zero for a dense one, and the
     [pairs, live tiles] counts of a layer that serves held experts."""
-    eps = cfg.norm_eps
     dt = x.dtype
     kind = kind or cfg.kinds[0]
+    norm = functools.partial(model_norm, cfg=cfg)
 
     def joined(x, out):
         """x + the sublayer's output, times the model's multiplier."""
@@ -1202,12 +1334,13 @@ def decoder_block(x, lp, cfg: TransformerConfig, positions, attend, kind: LayerK
 
     if kind.recurrent:
         mixer = {"ssd": _ssd_mixer, "delta": _delta_mixer, "conv": _conv_mixer}[kind.mixer]
-        o, kept = mixer(_rms_norm(x, lp["attn_norm"], eps), lp, cfg, kind, attend)
-    elif cfg.latent:
-        q, k, v = _latent_qkv(_rms_norm(x, lp["attn_norm"], eps), lp, cfg, positions)
+        o, kept = mixer(norm(x, lp["attn_norm"]), lp, cfg, kind, attend)
+    elif kind.mixer == "latent":
+        h = norm(x, lp["attn_norm"])
+        q, k, v = _latent_qkv(h, lp, cfg, positions, kind)
     else:
         with jax.named_scope("qkv"):
-            h = _rms_norm(x, lp["attn_norm"], eps)
+            h = norm(x, lp["attn_norm"])
             q = jnp.einsum("bsd,dhk->bshk", h, lp["wq"].astype(dt))
             k = jnp.einsum("bsd,dhk->bshk", h, lp["wk"].astype(dt))
             v = jnp.einsum("bsd,dhk->bshk", h, lp["wv"].astype(dt))
@@ -1215,7 +1348,7 @@ def decoder_block(x, lp, cfg: TransformerConfig, positions, attend, kind: LayerK
             k = wlc(k, ("batch", "seq", "kv_heads", "head_dim"))
             if cfg.qk_norm:
                 with jax.named_scope("qk_norm"):
-                    q, k = _rms_norm(q, lp["q_norm"], eps), _rms_norm(k, lp["k_norm"], eps)
+                    q, k = norm(q, lp["q_norm"]), norm(k, lp["k_norm"])
             q = _rope_kind(q, positions, kind)
             k = _rope_kind(k, positions, kind)
             if cfg.attention_multiplier:
@@ -1239,18 +1372,18 @@ def decoder_block(x, lp, cfg: TransformerConfig, positions, attend, kind: LayerK
             o = wlc(o, ("batch", "seq", "heads", "head_dim"))
             a = jnp.einsum("bshk,hkd->bsd", o, lp["wo"].astype(dt))
         if cfg.sandwich_norm:
-            a = _rms_norm(a, lp["post_attn_norm"], eps)
+            a = norm(a, lp["post_attn_norm"])
         x = joined(x, a)
     with jax.named_scope("ffn"):
-        h = _rms_norm(x, lp["ffn_norm"], eps)
+        h = norm(x, lp["ffn_norm"])
         if "router" not in lp:
-            ffn_out, aux = _dense_ffn(h, lp), jnp.zeros((), jnp.float32)
+            ffn_out, aux = _dense_ffn(h, lp, cfg.swiglu_limit), jnp.zeros((), jnp.float32)
         elif cfg.experts_held:
             ffn_out, aux = _held_experts_ffn(h, lp, cfg)
         else:
             ffn_out, aux = _moe_ffn(h, lp, cfg)
         if cfg.sandwich_norm:
-            ffn_out = _rms_norm(ffn_out, lp["post_ffn_norm"], eps)
+            ffn_out = norm(ffn_out, lp["post_ffn_norm"])
         x = joined(x, ffn_out)
     x = wlc(x, ("batch", "seq", "embed"))
     return x, aux, kept
@@ -1274,14 +1407,16 @@ def _layer(x, lp, cfg: TransformerConfig, positions, segment_ids=None, kind: Lay
     """The block as training runs it: attention over the layer's own K/V by
     the configured implementation (inside the kind's window where it has
     one), nothing kept. x: [B, S, D] in cfg.dtype."""
+    kind = kind or cfg.kinds[0]
+
     def attend(q, k, v):
-        if not cfg.latent:
-            return _attention(q, k, v, cfg, positions, segment_ids, window=kind.window if kind else 0), None
+        if kind.mixer != "latent":
+            return _attention(q, k, v, cfg, positions, segment_ids, window=kind.window), None
         k, v = latent_expand(lp, k, v, x.dtype)
         q = jnp.concatenate(q, axis=-1)
-        return _attention(q, k, v, cfg, positions, segment_ids, scale=latent_scale(cfg)), None
+        return _attention(q, k, v, cfg, positions, segment_ids, scale=latent_scale(cfg, kind)), None
 
-    if kind is not None and kind.recurrent:
+    if kind.recurrent:
         if segment_ids is not None:
             raise NotImplementedError(
                 f"packed sequences are not written for a {kind.mixer} layer: "
@@ -1316,7 +1451,7 @@ def forward_hidden(params: dict, tokens: jax.Array, cfg: TransformerConfig,
 
     x, auxes = run_layers(lambda h, lp, kind, _index: body_of(kind)(h, lp), x, params, cfg)
     aux = functools.reduce(lambda a, b: a + b, [jnp.sum(a) for a in auxes.values()])
-    return _rms_norm(x, params["final_norm"], cfg.norm_eps), aux
+    return model_norm(x, params["final_norm"], cfg), aux
 
 
 def forward(params: dict, tokens: jax.Array, cfg: TransformerConfig,
@@ -1572,7 +1707,7 @@ def make_pipeline_train_step(cfg: TransformerConfig, mesh, n_micro: int, optimiz
             stage_fn, params["layers"], xm, mesh=mesh, axis_name=axis_name, x_spec=x_spec
         )
         h = h.reshape(B, S, -1)
-        h = _rms_norm(h, params["final_norm"], cfg.norm_eps)
+        h = model_norm(h, params["final_norm"], cfg)
         logits = hidden_logits(params, h, cfg)
         mask = batch.get("mask")
         return _ce_from_logits(logits, targets, None if mask is None else mask[:, 1:])

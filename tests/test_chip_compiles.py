@@ -287,6 +287,50 @@ def test_the_decode_program_of_a_model_with_delta_layers_compiles_for_a_v5e(one_
     assert compiled.memory_analysis().temp_size_in_bytes < state.nbytes // 3  # not one layer's states
 
 
+def test_the_decode_program_of_a_model_with_a_latent_layer_beside_delta_layers_compiles_for_a_v5e(
+        one_chip, no_compile_cache, monkeypatch):
+    """llm/engine.py ``_decode_impl`` of a model with a leading dense delta
+    layer and a period of one latent layer and three delta layers (half as many
+    key heads as value heads, a decay a head), at heads of 128, latent rows of
+    512 + 64 and otherwise small widths: the dense stack's scan around one
+    ``kda_step``, the period scan around one ``latent_attn`` call, three
+    ``kda_step`` calls and three grouped matmuls a layer; both rules' pools (a
+    state of 4 MB a slot and layer, latent rows in pages) are carried and
+    aliased, and no copy of either is among the temporaries."""
+    from ray_tpu.llm.engine import EngineConfig, LLMEngine
+    from ray_tpu.models.transformer import LayerKind, TransformerConfig, init_params
+
+    latent = LayerKind("latent", 8, mixer="latent", rope_theta=1e5, yarn_factor=8.0, yarn_original_len=32768,
+                       softmax_factor=1.459)
+    delta = LayerKind("delta", 8, mixer="delta", conv_size=4, n_key_heads=4, gate_scale=2.0)
+    cfg = TransformerConfig(
+        vocab_size=1024, d_model=256, n_layers=5, n_heads=8, head_dim=128, d_ff=512, max_seq_len=2048,
+        param_dtype=jnp.bfloat16, layer_pattern=(delta, latent, delta, delta), n_dense_layers=1,
+        attn_gate="elementwise", sandwich_norm=True, norm_gating=2.0, swiglu_limit=10.0,
+        q_lora_rank=192, kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        n_experts=32, expert_top_k=4, experts_held=8, expert_d_ff=256, n_shared_experts=1, routed_scaling=2.5,
+        router_score="sigmoid")
+
+    def on_chip(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    params = jax.tree.map(on_chip, jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    eng = LLMEngine(cfg, params=params, engine_config=EngineConfig(
+        max_slots=16, max_seq=2048, page_size=128, total_pages=40, prefill_buckets=(512,), decode_block=8))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the program asks which attend to trace
+    B = eng.ec.max_slots
+    ints, floats = on_chip(jnp.zeros(B, jnp.int32)), on_chip(jnp.zeros(B, jnp.float32))
+    compiled = eng._decode_jit.lower(
+        params, tuple(on_chip(pool) for pool in eng.cache), ints, ints, on_chip(eng.d_page_tables),
+        on_chip(jax.random.PRNGKey(0)), 8, floats, floats, ints).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == (1 + 3) + 1 + 3 * 4  # kda_step, latent_attn, expert_gmm
+    state, tails, rows = eng.cache
+    assert state.shape == (4, 16, 8, 128, 128) and state.dtype == jnp.float32 and state.nbytes == 33_554_432
+    assert tails.shape == (4, 16, 3, 16, 128) and rows.shape == (1, 40 * 128, 640) and rows.dtype == jnp.bfloat16
+    assert compiled.memory_analysis().temp_size_in_bytes < state.nbytes // 4  # not one layer's states
+
+
 def test_the_state_space_rules_two_calls_lower_for_a_v5e(one_chip, no_compile_cache, monkeypatch):
     """ops/ssd.py at the serve cell's shapes. ``ssd_step``: 64 slots of 64
     heads of 64 columns and a state size of 128, 36 layers' states in one pool
