@@ -524,7 +524,6 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> dict:
 
 
 HELD_EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
-L2_EPS = 1e-6  # under the root of a delta layer's query and key norms
 
 
 def ssd_conv_channels(kind: LayerKind) -> int:
@@ -1181,6 +1180,8 @@ def _delta_mixer(h, lp, cfg: TransformerConfig, kind: LayerKind, attend):
     it, of which a program keeps its next tail. With fewer key heads q~, k~
     and v~ lie along ONE axis of 2 Hk + H heads, in tail, taps and window
     alike ([B, T - 1, 2 Hk + H, Hd])."""
+    from ray_tpu.ops.linear_attention import delta_prep, delta_prep_reference
+
     dt, T = h.dtype, kind.conv_size
     S, Hd = h.shape[1], cfg.head_dim
     H, Hk = kind.n_heads, kind.key_heads
@@ -1195,12 +1196,9 @@ def _delta_mixer(h, lp, cfg: TransformerConfig, kind: LayerKind, attend):
         if tail is None:
             tail = jnp.zeros((u.shape[0], T - 1, *u.shape[2:]), dt)
         window = jnp.concatenate([tail.astype(dt), u], axis=1)
-        taps = lp["conv"].astype(jnp.float32)
-        y = sum(window[:, j:j + S].astype(jnp.float32) * taps[j] for j in range(T))
-        q, k, v = (y[:, :, i] for i in range(3)) if Hk == H else (y[:, :, :Hk], y[:, :, Hk:2 * Hk], y[:, :, 2 * Hk:])
-        q, k, v = jax.nn.silu(q), jax.nn.silu(k), jax.nn.silu(v).astype(dt)
-        q = q * lax.rsqrt(jnp.sum(q * q, axis=-1, keepdims=True) + L2_EPS) * Hd ** -0.5
-        k = k * lax.rsqrt(jnp.sum(k * k, axis=-1, keepdims=True) + L2_EPS)
+        # one pass over a prompt where there is a kernel to make it (ops/linear_attention.py); a decode step's one
+        # position, every other backend and the "reference" implementation take the jax.numpy lines
+        q, k, v = (delta_prep if S > 1 and _runs_kernels(cfg) else delta_prep_reference)(window, lp["conv"], Hk)
         if Hk != H:  # key head j serves value heads j H / Hk .. (j + 1) H / Hk - 1
             q, k = jnp.repeat(q, H // Hk, axis=2), jnp.repeat(k, H // Hk, axis=2)
     with jax.named_scope("decay"):
@@ -1389,6 +1387,12 @@ def decoder_block(x, lp, cfg: TransformerConfig, positions, attend, kind: LayerK
     return x, aux, kept
 
 
+def _runs_kernels(cfg: TransformerConfig) -> bool:
+    """Whether a recurrent layer's Pallas kernels run: the configured
+    implementation is a kernel's and the backend a TPU's."""
+    return cfg.attention_impl in ("auto", "flash") and jax.default_backend() == "tpu"
+
+
 def _whole_sequence_rule(ops, _window, cfg: TransformerConfig, kind: LayerKind):
     """A recurrent layer's rule over whole sequences from an empty state,
     nothing kept: the chunked kernel where the configured implementation is a
@@ -1399,8 +1403,7 @@ def _whole_sequence_rule(ops, _window, cfg: TransformerConfig, kind: LayerKind):
     else:
         from ray_tpu.ops.linear_attention import kda_chunk as chunk, kda_chunk_reference as chunk_reference
 
-    kernel = cfg.attention_impl in ("auto", "flash") and jax.default_backend() == "tpu"
-    return (chunk if kernel else chunk_reference)(*ops, out_dtype=cfg.dtype)[0], None
+    return (chunk if _runs_kernels(cfg) else chunk_reference)(*ops, out_dtype=cfg.dtype)[0], None
 
 
 def _layer(x, lp, cfg: TransformerConfig, positions, segment_ids=None, kind: LayerKind | None = None):

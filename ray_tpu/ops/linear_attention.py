@@ -1,5 +1,7 @@
 """The gated delta rule with a decay a channel (Kimi delta attention), for TPU:
-a chunked call for prompts and a one-token call for decode.
+a chunked call for prompts, a one-token call for decode, and the call that
+prepares a prompt's q, k and v for the first (``kda_chunk``, ``kda_step``,
+``delta_prep``).
 
 A head keeps a state S [K, V] in float32 and no token rows. A token with
 query q and key k [K], value v [V], log decay g [K] (<= 0) and step size beta
@@ -57,10 +59,27 @@ stays in HBM, the layer is an operand of the index maps, the pool is aliased
 to the output, and the grid is the live slots' (a runtime value): a slot
 without a request has no step, and its state is bit for bit what it was.
 
+``delta_prep`` is what a delta layer does between its projections and the
+rule over a prompt, as ONE pass (bound by reading the window once, 2 bytes a
+value, and writing q, k in float32 and v: 201 + 335 MB a layer of 64 heads at
+4,096 positions; 1.03 ms a call in the serve cell's prefill programs on a v5e,
+where the ``jax.numpy`` lines' three fusions took 3.8, PERF.md section 6, PR
+56): the short convolution's T taps, SiLU, q and k normed to length 1 a head. A grid step is a block of
+positions of EVERY head, (B, blocks), so the window [B, T - 1 + S, heads..,
+Hd] is read where it lies, whichever of its two forms it has (q~, k~, v~
+stacked, or along one axis of 2 Hk + H heads), and a position's T inputs are T
+rows from its own on: the positions are a major dimension of a block, so a
+shift by a tap is an address and no shuffle. The T - 1 rows behind a block
+come as a second, small block of the same array. Inside, a loop takes
+PREP_ROWS positions a trip through float32 and never holds more: the
+convolution's result, which the lines wrote once in float32 and read twice
+more, stays in registers.
+
 Each call has its ``jax.numpy`` form beside it (``kda_chunk_reference`` runs
 the chunk's own arithmetic, ``_chunk``, a sequence's heads at once under
-``vmap``; ``kda_scan_reference`` is the rule a position at a time), which
-other backends run.
+``vmap``; ``kda_scan_reference`` is the rule a position at a time;
+``delta_prep_reference`` is the mixer's own lines, which a decode step's one
+position keeps on every backend), which other backends run.
 """
 from __future__ import annotations
 
@@ -75,6 +94,9 @@ SUB = 16  # rows of a sub-block, whose decays share a reference point
 CLAMP = 80.0  # the largest exponent inside a sub-block
 HEADS_A_STEP = 16  # heads of one slot a grid step of kda_step: 1 MB of state in, 1 MB out
 HEADS_A_CHUNK = 8  # heads of one chunk a grid step of kda_chunk: a float32 tile's rows, since the heads are a block's second-minor dimension
+PREP_BLOCK = 32  # positions of every head a grid step of delta_prep
+PREP_ROWS = 4  # positions a trip of the loop inside it: what a trip holds stays near the register file
+L2_EPS = 1e-6  # under the root of a delta layer's query and key norms
 F32, BF16 = jnp.float32, jnp.bfloat16
 
 _NN = ((2,), (1,))  # a @ b, a head (the leading dimension of both)
@@ -248,6 +270,37 @@ def kda_step_reference(q, k, v, g, beta, pool, layer, live):
     return jnp.where(live[:, None, None], o, 0.0), pool
 
 
+def _prep_parts(window, key_heads):
+    """(Hk, H, what picks q~, k~ and v~ out of the head axes of a window or of
+    its taps): the middle one of [.., 3, H, Hd], or ranges of the one axis of
+    2 Hk + H heads in [.., 2 Hk + H, Hd]."""
+    if window.ndim == 5:
+        return window.shape[3], window.shape[3], tuple((i,) for i in range(3))
+    Hk, G = key_heads, window.shape[2]
+    return Hk, G - 2 * Hk, ((slice(0, Hk),), (slice(Hk, 2 * Hk),), (slice(2 * Hk, G),))
+
+
+def _unit_heads(y):
+    """y [..., Hd] float32, each head divided by its length."""
+    return y * lax.rsqrt(jnp.sum(y * y, axis=-1, keepdims=True) + L2_EPS)
+
+
+def delta_prep_reference(window, taps, key_heads):
+    """What a delta layer does between its projections and its rule, in
+    ``jax.numpy``: the short convolution, SiLU and the head norms. window: the
+    convolution's inputs q~, k~, v~ behind the T - 1 before position 0,
+    [B, T - 1 + S, 3, H, Hd], or [B, T - 1 + S, 2 Hk + H, Hd] where
+    `key_heads` = Hk key heads serve H value heads; taps [T, ...] alike, the
+    oldest input's first -> (q, k [B, S, Hk, Hd] float32, each head of length
+    1 and q times Hd^-1/2 besides; v [B, S, H, Hd] in the window's dtype)."""
+    T, S, Hd = taps.shape[0], window.shape[1] - taps.shape[0] + 1, window.shape[-1]
+    taps = taps.astype(F32)
+    y = sum(window[:, j:j + S].astype(F32) * taps[j] for j in range(T))
+    q, k, v = (y[(slice(None), slice(None), *part)] for part in _prep_parts(window, key_heads)[2])
+    q, k, v = jax.nn.silu(q), jax.nn.silu(k), jax.nn.silu(v).astype(window.dtype)
+    return _unit_heads(q) * Hd ** -0.5, _unit_heads(k), v
+
+
 # ---------------------------------------------------------------------------
 # Pallas kernels
 # ---------------------------------------------------------------------------
@@ -320,6 +373,80 @@ def kda_chunk(q, k, v, g, beta, state=None, *, out_dtype=None, interpret=False):
         name="kda_chunk",
     )(q, k, v, g, beta, _initial(state, B, H, K, V))
     return o[:, :S], s
+
+
+def _prep_kernel(x_ref, behind_ref, taps_ref, q_ref, k_ref, v_ref, rows, *, parts, scale):
+    """Grid (B, blocks of positions): one block of every head. ``x_ref``
+    [block, heads.., Hd] are the window's rows from the block's first position
+    on and ``behind_ref`` the few behind them, of which the block's last T - 1
+    positions read theirs; ``rows`` holds both end to end, so that a
+    position's T inputs are T rows from its own on. A trip of the loop takes
+    PREP_ROWS positions of q~, of k~ and of v~ through the taps, SiLU and the
+    norm in float32, in the order ``delta_prep_reference`` does."""
+    from jax.experimental import pallas as pl
+
+    block, T = x_ref.shape[0], taps_ref.shape[0]
+    rows[:block] = x_ref[...]
+    rows[block:] = behind_ref[...]
+
+    def trip(i, carry):
+        at = pl.multiple_of(i * PREP_ROWS, PREP_ROWS)
+        for part, out in zip(parts, (q_ref, k_ref, v_ref)):
+            x = rows[(pl.ds(at, PREP_ROWS + T - 1), *part)].astype(F32)
+            y = functools.reduce(jnp.add, [x[j:j + PREP_ROWS] * taps_ref[(j, *part)] for j in range(T)])
+            y = jax.nn.silu(y)
+            if out is not v_ref:
+                y = _unit_heads(y)
+            if out is q_ref:
+                y = y * scale
+            out[pl.ds(at, PREP_ROWS)] = y.astype(out.dtype)
+        return carry
+
+    lax.fori_loop(0, block // PREP_ROWS, trip, 0)
+
+
+def delta_prep(window, taps, key_heads, *, interpret=False):
+    """``delta_prep_reference`` as ONE pass over a prompt (the Pallas kernel;
+    arguments and results as there): the window is read once, where it lies
+    and in its own dtype, a block of positions of every head a grid step with
+    the T - 1 rows behind it, and q, k (float32) and v are written as
+    ``kda_chunk`` reads them; the float32 convolution, its two further reads
+    for the norms and for SiLU and the casts never reach HBM. Runs on a TPU
+    backend, or anywhere with interpret=True, and raises elsewhere."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    _needs_tpu("delta_prep", interpret)
+    B, W, Hd = window.shape[0], window.shape[1], window.shape[-1]
+    T = taps.shape[0]
+    S = W - T + 1
+    Hk, H, parts = _prep_parts(window, key_heads)
+    heads = window.shape[2:-1]  # (3, H) or (2 Hk + H,)
+    behind = max(PREP_ROWS, 1 << (T - 2).bit_length())  # rows of the block behind: a power of two that holds T - 1
+    block = min(PREP_BLOCK, -(-S // behind) * behind)
+    n = -(-S // block)
+    last = (W - 1) // behind  # the last block of `behind` rows that starts inside the window
+    zeros = (0,) * (len(heads) + 1)
+    out = lambda Hn: pl.BlockSpec((None, block, Hn, Hd), lambda b, i: (b, i, 0, 0))
+    q, k, v = pl.pallas_call(
+        functools.partial(_prep_kernel, parts=parts, scale=Hd ** -0.5),
+        grid=(B, n),
+        in_specs=[pl.BlockSpec((None, block, *heads, Hd), lambda b, i: (b, i, *zeros)),
+                  pl.BlockSpec((None, behind, *heads, Hd),
+                               lambda b, i: (b, jnp.minimum((i + 1) * (block // behind), last), *zeros)),
+                  pl.BlockSpec(taps.shape, lambda b, i: (0, *zeros))],
+        out_specs=[out(Hk), out(Hk), out(H)],
+        out_shape=[jax.ShapeDtypeStruct((B, n * block, Hk, Hd), F32), jax.ShapeDtypeStruct((B, n * block, Hk, Hd), F32),
+                   jax.ShapeDtypeStruct((B, n * block, H, Hd), window.dtype)],
+        scratch_shapes=[pltpu.VMEM((block + behind, *heads, Hd), window.dtype)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=32 * 1024 * 1024,  # two blocks of the window and of q, k and v, beside the rows
+        ),
+        interpret=interpret,
+        name="delta_prep",
+    )(window, window, taps.astype(F32))
+    return q[:, :S], k[:, :S], v[:, :S]
 
 
 # rows of a head's operand tile in kda_step: q, k, v, g and beta (along the lanes), the rest unused

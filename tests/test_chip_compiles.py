@@ -252,6 +252,130 @@ def test_the_delta_rules_two_calls_lower_for_a_v5e(one_chip, no_compile_cache, m
         assert compiled.memory_analysis().temp_size_in_bytes < S * H * K * 4  # not one operand by head in float32
 
 
+@pytest.mark.parametrize("cell,window,taps,key_heads", [
+    ("solar-open2-250b-ep8, bucket 8192", (1, 8195, 3, 64, 128), (4, 3, 64, 128), 64),
+    ("gigachat3.5-432b-ep16, bucket 2048", (1, 2051, 128, 128), (4, 128, 128), 32),
+    ("12 heads: no whole tiles of 16", (1, 515, 3, 12, 128), (4, 3, 12, 128), 12),
+    ("4 key heads for 8 heads, a prompt under a block", (1, 27, 16, 128), (4, 16, 128), 4),
+])
+def test_delta_prep_lowers_for_a_v5e(cell, window, taps, key_heads, one_chip, no_compile_cache, monkeypatch):
+    """ops/linear_attention.py ``delta_prep`` at both serve cells' head counts
+    and largest buckets (three stacked sets of 64 heads; 32 + 32 + 64 heads
+    along one axis) and at head counts that are no whole bfloat16 tiles: one
+    Mosaic call named for the trace, which reads the window where it lies and
+    holds nothing beside its operands and results, and whose body (what a
+    warm start lowers anew, ROADMAP S12) is one small loop."""
+    from ray_tpu.ops import linear_attention as la
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def arr(dims):
+        return jax.ShapeDtypeStruct(dims, jnp.bfloat16, sharding=one_chip)
+
+    traced = jax.jit(lambda w, t: la.delta_prep(w, t, key_heads)).trace(arr(window), arr(taps))
+    (call,) = [eqn for eqn in traced.jaxpr.eqns if eqn.primitive.name == "pallas_call"]
+    assert len(call.params["jaxpr"].eqns) <= 12  # the rows' two copies and the loop; tests/test_linear_attention.py counts inside it
+    compiled = traced.lower().compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1 and "delta_prep" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20  # the window is 25-403 MB at the cells' shapes
+
+
+_HLO_BYTES = {"bf16": 2, "f16": 2, "f32": 4, "s32": 4, "u32": 4, "pred": 1, "s8": 1, "u8": 1}
+
+
+def _entry_instructions(text):
+    """{name: (opcode, result bytes, operand names, op_name)} of a compiled
+    module's entry computation, read off its text: a result's bytes are its
+    type's (a tuple's: every part's), operands are the distinct ``%names``
+    inside the instruction's brackets."""
+    import math
+    import re
+
+    def nbytes(types):
+        found = re.findall(r"\b(" + "|".join(_HLO_BYTES) + r")\[([\d,]*)\]", types)
+        return sum(_HLO_BYTES[t] * math.prod(int(d) for d in dims.split(",") if d) for t, dims in found)
+
+    lines = text[text.index("\nENTRY "):].splitlines()[2:]
+    out = {}
+    for line in lines:
+        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (.*?) ([a-z][a-z0-9\-]*)\((.*)$", line)
+        if not m:
+            continue
+        name, result, opcode, rest = m.groups()
+        depth, end = 1, len(rest)
+        for i, c in enumerate(rest):
+            depth += (c == "(") - (c == ")")
+            if not depth:
+                end = i
+                break
+        scope = re.search(r'op_name="([^"]*)"', line)
+        out[name] = (opcode, nbytes(result), list(dict.fromkeys(re.findall(r"%([\w.\-]+)", rest[:end]))),
+                     scope.group(1) if scope else "")
+    return out
+
+
+def test_solars_prefill_program_prepares_a_delta_layers_q_k_v_in_one_pass_for_a_v5e(one_chip, no_compile_cache, monkeypatch):
+    """llm/engine.py's prefill program of bucket 4096 at
+    benchmarks/configs/solar-open2-250b-ep8.json's published widths (one
+    softmax layer and three delta layers of 64 heads of 128; small pools: the
+    program between a delta layer's projections and its rule does not see
+    them), compiled as the cell's replica compiles it. What moves between the
+    three projections and ``kda_chunk``, counted as operand plus result bytes
+    of the entry computation's instructions (the request's scan and the
+    period's are flattened into it) under ``short_conv/`` (the new call among
+    them; an array it is handed twice counted once), ``qkv/broadcast_in_dim``
+    (q~, k~ and v~ turned from the products' heads-first layout to positions
+    first: three copies), ``qkv/concatenate`` (the window) and the decay's
+    ``kda_chunk/jit(_where)/select_n``: 1.54 GB a delta layer as read here
+    (0.40 the copies, 0.40 the window, 0.54 ``delta_prep``, 0.20 the decay),
+    where the jax.numpy lines moved 2.76 (the convolution's result in float32
+    written once and read twice more: 0.60 + 0.41 + 0.74). One ``delta_prep``
+    a delta layer, beside the three ``kda_chunk`` and the flash call."""
+    import importlib.util
+    import json
+    import os
+    import re
+
+    from ray_tpu.llm.engine import EngineConfig, LLMEngine
+    from ray_tpu.models.transformer import TransformerConfig, init_params
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmarks")
+    spec = importlib.util.spec_from_file_location("bench_solar_open2", os.path.join(bench, "architectures", "solar_open2.py"))
+    arch = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(arch)
+    with open(os.path.join(bench, "configs", "solar-open2-250b-ep8.json")) as f:
+        model = json.load(f)
+    cfg = TransformerConfig(**{**arch.transformer_kwargs(model), "param_dtype": jnp.bfloat16})
+    assert (cfg.d_model, cfg.n_layers, cfg.head_dim) == (4096, 4, 128) and [k.n_heads for k in cfg.kinds] == [64, 64]
+
+    def on_chip(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    params = jax.tree.map(on_chip, jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the engine asks how wide a pool's rows are, the mixer which lines to trace
+    bucket = 4096
+    eng = LLMEngine(cfg, params=params, engine_config=EngineConfig(
+        max_slots=8, max_seq=bucket + 128, page_size=128, total_pages=68, prefill_buckets=(bucket,), decode_block=8))
+    i32 = lambda *dims: on_chip(jnp.zeros(dims, jnp.int32))
+    f32 = lambda *dims: on_chip(jnp.zeros(dims, jnp.float32))
+    compiled = eng._prefill(bucket, 1).lower(
+        params, tuple(on_chip(pool) for pool in eng.cache), i32(1, bucket), i32(1), i32(1, bucket // 128),
+        on_chip(jax.random.PRNGKey(0)), f32(1), f32(1), i32(1), i32(1)).compile()
+    text = compiled.as_text()
+    instructions = _entry_instructions(text)
+    calls = {kernel: sorted(n for n, (opcode, *_) in instructions.items() if opcode == "custom-call" and n.startswith(kernel))
+             for kernel in ("delta_prep", "kda_chunk", "flash_attn_fwd")}
+    assert [len(calls[k]) for k in ("delta_prep", "kda_chunk", "flash_attn_fwd")] == [3, 3, 1], calls
+    between = re.compile(r"/short_conv/|/qkv/(concatenate|broadcast_in_dim)$|/kda_chunk/jit\(_where\)/select_n$")
+    moved = {name: result + sum(instructions[o][1] for o in operands if o in instructions)
+             for name, (opcode, result, operands, scope) in instructions.items()
+             if between.search(scope) and opcode not in ("get-tuple-element", "bitcast", "constant", "parameter")}
+    a_layer = sum(moved.values()) / 3
+    assert all(call in moved for call in calls["delta_prep"])
+    assert 1.2e9 < a_layer <= 1.6e9, (a_layer, sorted(moved.items(), key=lambda kv: -kv[1])[:12])
+
+
 def test_the_decode_program_of_a_model_with_delta_layers_compiles_for_a_v5e(one_chip, no_compile_cache, monkeypatch):
     """llm/engine.py ``_decode_impl`` of a model with one softmax layer and
     three delta layers a period, at heads of 128 and otherwise small widths:

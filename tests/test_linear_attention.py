@@ -148,6 +148,91 @@ def test_the_chunk_kernels_body_stays_small_at_every_count_of_heads_a_step():
     assert len(set(sizes.values())) == 1, sizes
 
 
+def _mixer_lines(window, taps, Hk, H):
+    """models/transformer.py ``_delta_mixer``'s own lines between its
+    projections and its rule as they stood before ``delta_prep`` (commit
+    4f75096, `:1198-1203`), kept here word for word."""
+    S, Hd, dt = window.shape[1] - taps.shape[0] + 1, window.shape[-1], window.dtype
+    taps = taps.astype(jnp.float32)
+    y = sum(window[:, j:j + S].astype(jnp.float32) * taps[j] for j in range(taps.shape[0]))
+    q, k, v = (y[:, :, i] for i in range(3)) if Hk == H else (y[:, :, :Hk], y[:, :, Hk:2 * Hk], y[:, :, 2 * Hk:])
+    q, k, v = jax.nn.silu(q), jax.nn.silu(k), jax.nn.silu(v).astype(dt)
+    q = q * jax.lax.rsqrt(jnp.sum(q * q, axis=-1, keepdims=True) + la.L2_EPS) * Hd ** -0.5
+    k = k * jax.lax.rsqrt(jnp.sum(k * k, axis=-1, keepdims=True) + la.L2_EPS)
+    return q, k, v
+
+
+PREP_CASES = {
+    # Hk, H (Hk = H: three stacked; fewer key heads: one axis of 2 Hk + H), S, T, a tail that is given, dtype
+    "stacked_three_blocks_from_a_sequences_start": (4, 4, 3 * la.PREP_BLOCK, 4, False, jnp.bfloat16),
+    "stacked_S_no_whole_blocks_behind_a_tail": (4, 4, la.PREP_BLOCK + 5, 4, True, jnp.bfloat16),
+    "stacked_S_under_a_block": (3, 3, 21, 4, True, jnp.bfloat16),
+    "stacked_S_under_T_minus_1_behind_a_tail": (4, 4, 2, 4, True, jnp.bfloat16),
+    "stacked_float32_two_taps": (2, 2, la.PREP_BLOCK + 1, 2, True, jnp.float32),
+    "one_axis_two_blocks_from_a_sequences_start": (2, 4, 2 * la.PREP_BLOCK, 4, False, jnp.bfloat16),
+    "one_axis_S_no_whole_blocks_behind_a_tail": (2, 8, 2 * la.PREP_BLOCK + 13, 4, True, jnp.bfloat16),
+    "one_axis_S_under_T_minus_1_behind_a_tail": (2, 4, 1, 4, True, jnp.bfloat16),
+    "one_axis_six_taps": (4, 8, la.PREP_BLOCK + 7, 6, True, jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PREP_CASES))
+def test_delta_prep_in_interpret_mode_is_the_mixers_lines_to_the_last_bit(case):
+    """``delta_prep`` (interpret mode) and ``delta_prep_reference`` against
+    the lines ``_delta_mixer`` had: q, k (float32) and v EQUAL TO THE LAST BIT
+    (a zero's sign aside: the lines start their sum of taps at +0), here on
+    the CPU, where kernel and lines run the same float32 operations in the
+    same order: the T taps oldest first, SiLU, the sum of squares over a
+    head's Hd channels, L2_EPS under the root, q times Hd^-1/2 last. That
+    holds for bfloat16 inputs, as the serve cells' are: an input times a tap
+    is then exact in float32, so it is the same whether a compiler contracts
+    it with the sum behind it or not. With float32 inputs that is the
+    compiler's choice a fusion, and the one such case is held to 1e-7 of
+    heads of length 1. Both window forms, a sequence's start (zeros in front)
+    and a tail that is given, S no whole number of blocks, under a block and
+    under T - 1."""
+    Hk, H, S, T, given, dtype = PREP_CASES[case]
+    Hd, B = 128, 2
+    heads = (3, H) if Hk == H else (2 * Hk + H,)
+    ks = jax.random.split(jax.random.PRNGKey(S + T), 3)
+    u = (2.0 * jax.random.normal(ks[0], (B, S, *heads, Hd))).astype(dtype)
+    tail = jax.random.normal(ks[1], (B, T - 1, *heads, Hd)).astype(dtype) if given else jnp.zeros((B, T - 1, *heads, Hd), dtype)
+    window = jnp.concatenate([tail, u], axis=1)
+    taps = (0.5 * jax.random.normal(ks[2], (T, *heads, Hd))).astype(dtype)
+    want = _mixer_lines(window, taps, Hk, H)
+    for got in (la.delta_prep(window, taps, Hk, interpret=True),
+                la.delta_prep_reference(window, taps, Hk)):
+        for a, b, heads_out, dt in zip(got, want, (Hk, Hk, H), (jnp.float32, jnp.float32, dtype)):
+            assert a.shape == b.shape == (B, S, heads_out, Hd) and a.dtype == b.dtype == dt
+            a, b = np.asarray(a.astype(jnp.float32)), np.asarray(b.astype(jnp.float32))
+            if dtype == jnp.bfloat16:
+                np.testing.assert_array_equal(a, b)
+            else:
+                np.testing.assert_allclose(a, b, atol=1e-7, rtol=1e-6)
+    norms = np.linalg.norm(np.asarray(want[1]), axis=-1)
+    assert abs(norms - 1).max() < 1e-3 and abs(np.linalg.norm(np.asarray(want[0]), axis=-1) * Hd ** 0.5 - 1).max() < 1e-3
+    with pytest.raises(RuntimeError, match="delta_prep needs a TPU backend"):
+        la.delta_prep(window, taps, Hk)
+
+
+def test_delta_preps_body_stays_small_at_both_window_forms():
+    """What a warm start pays a prefill program for each delta layer's call
+    (ROADMAP S12): the body is one trip of a loop over PREP_ROWS positions,
+    q~, k~ and v~ in turn, whatever the heads, the block and the prompt: 97
+    equations as counted here (``kda_chunk``'s body: 332-340), bounded a tenth
+    above."""
+    def body(window, taps, Hk):
+        jaxpr = jax.make_jaxpr(lambda w, t: la.delta_prep(w, t, Hk, interpret=True))(window, taps)
+        calls = [eqn for eqn in jaxpr.jaxpr.eqns if eqn.primitive.name == "pallas_call"]
+        assert len(calls) == 1 and calls[0].params["grid_mapping"].grid == (1, 4096 // la.PREP_BLOCK)
+        return _equations(calls[0].params["jaxpr"])
+
+    bf = jnp.bfloat16
+    sizes = {"solar": body(jnp.zeros((1, 4099, 3, 64, 128), bf), jnp.zeros((4, 3, 64, 128), bf), 64),
+             "gigachat": body(jnp.zeros((1, 4099, 128, 128), bf), jnp.zeros((4, 128, 128), bf), 32)}
+    assert max(sizes.values()) <= 107 and len(set(sizes.values())) == 1, sizes
+
+
 def test_the_step_kernel_takes_no_step_for_an_empty_slot_and_leaves_its_state_bit_for_bit():
     """Slots 1 and 3 hold no request: the grid is the two live slots' (the
     kernel's scalar-prefetched list leads with them), their states of layer 1
