@@ -11,6 +11,7 @@ from __future__ import annotations
 import math
 import os
 import sys
+import threading
 import time
 
 from ray_tpu.util.tracing import Ring
@@ -19,17 +20,31 @@ COMPILE_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
 _CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 # The path is part of the cache key, so it is fixed: never a temp name.
 DEFAULT_COMPILE_CACHE_DIR = os.path.join(_CHECKOUT, ".jax_compile_cache")
-# What jax.monitoring reports once for each program the backend compiles (a
-# program found in the persistent cache is not compiled and not reported).
+# What jax.monitoring reports of a program on its way to the device, each as the stage ends and with the
+# function's name: its trace (Python over the function's body; a jit called inside reports its own trace
+# first, inside its caller's), its lowering (jaxpr to MLIR, each pallas_call through Mosaic's lowering), and
+# the backend, once for each executable it STARTS: compiled, or read from the persistent cache and loaded, which
+# reports the seconds of the read a moment before. (So a hit is reported too, jax 0.9.0: on the chip a warm start
+# of a serve replica counts its 100-150 executables cold or warm, a fifth of them hits at 0.3-1.0 s each, the
+# rest programs under JAX's threshold for caching, compiled again every start: PERF.md section 5, PR 57.)
+TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-_compiles = Ring(512)  # (time.monotonic() at the end of a compile, its seconds)
+RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+_compiles = Ring(512)  # (time.monotonic() at the end of a backend event, its seconds)
 _compile_counter = None  # the metrics plane's jax.compiles, once listening
+# This process's seconds and counts by stage since enable_compile_cache (compile_stages). Any thread may trace
+# or compile: the totals under a lock, a trace's depth and a cache read's verdict by thread.
+_stages = {"trace_s": 0.0, "lower_s": 0.0, "backend_s": 0.0, "miss_s": 0.0, "retrieval_s": 0.0,
+           "hits": 0, "misses": 0, "executables": 0}
+_stages_lock = threading.Lock()
+_in_thread = threading.local()  # depth: traces begun and not ended; hit: the cache held the executable on its way
 
 
 def enable_compile_cache() -> str:
     """Turn on JAX's persistent compilation cache; returns the directory.
-    From here on this process also counts its compilations
-    (``compile_events``).
+    From here on this process also counts what its programs' stages cost
+    (``compile_events``, ``compile_stages``).
 
     Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and this
     sets nothing — the operator placed the cache. Otherwise the cache sits at
@@ -46,7 +61,7 @@ def enable_compile_cache() -> str:
 
 
 def _count_compiles() -> None:
-    """Register, once, this process's listener for backend compilations."""
+    """Register, once, this process's listeners for what JAX reports of a program's stages."""
     global _compile_counter
     if _compile_counter is not None:
         return
@@ -55,21 +70,58 @@ def _count_compiles() -> None:
     from ray_tpu.util import metrics
 
     _compile_counter = metrics.Counter(
-        "jax.compiles", "programs this process's JAX backend compiled")
+        "jax.compiles", "executables this process's JAX backend started: compiled, or read from the cache")
+
+    def add(**stages):
+        with _stages_lock:
+            for key, value in stages.items():
+                _stages[key] += value
+
+    def on_start(name, _stamp, **_kw):
+        # JAX reports a stage's start as a scalar (the stamp) under the stage's name
+        if name == TRACE_EVENT:
+            _in_thread.depth = getattr(_in_thread, "depth", 0) + 1
 
     def on_duration(name, seconds, **_kw):
-        if name == COMPILE_EVENT:
+        if name == TRACE_EVENT:
+            _in_thread.depth = depth = max(getattr(_in_thread, "depth", 0) - 1, 0)
+            if not depth:  # a trace inside another is seconds of that one: counted once, with it
+                add(trace_s=seconds)
+        elif name == LOWER_EVENT:
+            add(lower_s=seconds)
+        elif name == RETRIEVAL_EVENT:  # inside the backend event that follows, and only on a hit
+            _in_thread.hit = True
+            add(retrieval_s=seconds)
+        elif name == COMPILE_EVENT:
             _compiles.push((time.monotonic(), seconds))
             _compile_counter.inc()
+            if getattr(_in_thread, "hit", False):
+                _in_thread.hit = False
+                add(backend_s=seconds, executables=1, hits=1)
+            else:  # the cache did not hold it, or was not asked: compiled
+                add(backend_s=seconds, executables=1, misses=1, miss_s=seconds)
 
+    jax.monitoring.register_scalar_listener(on_start)
     jax.monitoring.register_event_duration_secs_listener(on_duration)
 
 
 def compile_events() -> dict:
-    """Compilations since ``enable_compile_cache``: their count, and the most
-    recent as (time.monotonic() stamp at the end of the compile, seconds).
-    A compile inside a serving window is a request that waited for it."""
+    """Executables the backend started since ``enable_compile_cache``, compiled or read from the persistent
+    cache (a read reports a backend event too): their count, and the most recent as (time.monotonic() stamp
+    at the event's end, seconds). One inside a serving window is a request that waited for it."""
     return {"count": _compiles.total, "recent": _compiles.snapshot()}
+
+
+def compile_stages() -> dict:
+    """This process's cumulative seconds and counts by stage since ``enable_compile_cache``: ``trace_s``
+    (a nested jit's trace counted once, inside its caller's), ``lower_s``, ``backend_s`` (inside the backend
+    events, whatever the cache said), ``miss_s`` (the part of ``backend_s`` whose executable the cache did not
+    hold or was not asked for: a compile), ``retrieval_s`` (the cache reads of the hits, part of
+    ``backend_s``), ``hits``, ``misses`` and ``executables`` (backend events: hits + misses). JAX writes to
+    the cache only what took ``jax_persistent_cache_min_compile_time_secs`` to compile, so a warm start still
+    counts its small programs as misses. Two readings' difference is what lay between them."""
+    with _stages_lock:
+        return dict(_stages)
 
 
 def backend_initialized() -> bool:

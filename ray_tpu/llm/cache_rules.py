@@ -173,10 +173,6 @@ class CacheRule:
         """What a decode step counts on the device, int32 [1] each, named by ``device_counts``."""
         return ()
 
-    def prefill_counts(self, k: int) -> dict:
-        """What a prefill group of k requests adds to the step's record."""
-        return {}
-
     def block_counts(self, lengths, n: int) -> dict:
         """What a decode block of ``n`` steps over rows of ``lengths`` (the host's mirror as the block was
         dispatched) sets in the step's record."""
@@ -397,7 +393,7 @@ class SlotState(_BySlot):
     one grid step a live slot and none for an empty one, whose state stays bit for bit. Admission budgets pages for
     the layers that keep every token. A page copy cannot restore a state and a chunk of a prompt would have to
     start from one: no method restores (ROADMAP M4)."""
-    zeroes = ("state_rows", "states_written")
+    zeroes = ("state_rows",)
     device_counts = ("state_rows",)
 
     why = {
@@ -445,9 +441,6 @@ class SlotState(_BySlot):
             return o[:, None], (new_state, new_tails)
         return tail, rule
 
-    def prefill_counts(self, k):
-        return {"states_written": k}
-
 
 class SlotTail(_BySlot):
     """A convolution's tail kept by slot and nothing beside it (``mixer="conv"``): the last conv_size - 1 rows of
@@ -456,7 +449,7 @@ class SlotTail(_BySlot):
     decode shifts a live slot's tail by the step's row and leaves an empty slot's bit for bit. No kernel, no float32.
     No page holds a tail and a chunk of a prompt would have to start from one: no method restores (ROADMAP M4)."""
     n_pools = 1
-    zeroes = ("tail_rows", "tails_written")
+    zeroes = ("tail_rows",)
     device_counts = ("tail_rows",)
 
     why = {
@@ -483,9 +476,6 @@ class SlotTail(_BySlot):
             kept = jnp.where((seen > 0)[:, None], window[:, 1:].astype(tails.dtype).reshape(tail.shape), tail)
             return (jax.lax.dynamic_update_slice(tails, kept[None], (layer, 0, 0)),)
         return tail, keep
-
-    def prefill_counts(self, k):
-        return {"tails_written": k}
 
 
 class LatentRows(_PageTable):

@@ -39,6 +39,7 @@ class LLMServer:
     def __init__(self, model_config: dict, engine_config: Optional[dict] = None,
                  warmup_buckets: Optional[tuple] = None, params=None,
                  weights_channel: Optional[str] = None):
+        ctor_began = time.monotonic()
         import ray_tpu as rt
         from ray_tpu.accel import device as _device
 
@@ -56,6 +57,7 @@ class LLMServer:
         # The process has its imports and its backend (require_tpu starts it):
         # from here on the constructor is timed (stats()["startup"]).
         init_began = time.monotonic()
+        before = _device.compile_stages()  # the process's totals here, then after LLMEngine(...), after warmup
         cfg = TransformerConfig(**model_config)
         ec = EngineConfig(**(engine_config or {}))
         # train->serve weight handoff: `params` may be an ObjectRef to a
@@ -73,12 +75,14 @@ class LLMServer:
         t1 = time.perf_counter()
         self.engine = LLMEngine(cfg, params=params, engine_config=ec)
         t2 = time.perf_counter()
+        built = _device.compile_stages()
         if warmup_buckets:
             # Compile prefill/decode programs before the replica reports
             # healthy (vLLM-style startup warmup): cold compiles belong to
             # startup, never to a request's TTFT.
             self.engine.warmup(buckets=tuple(warmup_buckets))
         self._warmup_s = time.perf_counter() - t2
+        warmed = _device.compile_stages()
         # Where start-up went (stats()["startup"]): fetching the params,
         # building the engine (weights made or resharded, KV pool), and each
         # warmed program with its seconds (a cold compile or a cache read);
@@ -86,8 +90,16 @@ class LLMServer:
         # of the lifecycle stamps and of a client on this machine: a client's
         # set-up is what went before the first (the process, its imports, the
         # backend), the three durations, and what came after the second.
+        # ctor_began is the constructor's first statement: from it to
+        # init_began the compile cache was placed, the chip claimed and the
+        # engine's modules imported. "stages" says of what JAX traced,
+        # lowered and handed its backend (accel/device.compile_stages) how
+        # much lay before init_began, inside engine_init_s and inside warmup_s.
         self._startup = {
-            "init_began": init_began, "init_ended": None,
+            "ctor_began": ctor_began, "init_began": init_began, "init_ended": None,
+            "stages": {"before": before,
+                       "engine_init": {key: built[key] - before[key] for key in before},
+                       "warmup": {key: warmed[key] - built[key] for key in before}},
             "fetch_params_s": t1 - t0, "engine_init_s": t2 - t1, "warmup_s": self._warmup_s,
             "programs": self.engine.warmup_log,
             # the KV pools' bytes, by layer kind (one kind for a model whose layers are alike)
@@ -358,11 +370,25 @@ class LLMServer:
         the lifecycle records of the finished requests and the phase records
         of the ended steps still in their rings (engine.TRACE_RING each; what
         fell off is counted in "dropped"), cumulative seconds by step phase,
-        and this process's compilations. "startup" says where start-up went:
-        three durations between the stamps init_began (the process has its
-        imports and its backend) and init_ended (the constructor's last
-        statement) on the same clock, so a client that timed its own set-up
-        finds what went before the first and what came after the second.
+        and the executables this process's backend started (compiled, or read
+        from the compile cache). "startup" says where start-up went: three
+        durations between the stamps init_began (the process has its imports
+        and its backend) and init_ended (the constructor's last statement) on
+        the same clock, so a client that timed its own set-up finds what went
+        before the first and what came after the second; ctor_began, the
+        constructor's first statement, from which to init_began the compile
+        cache was placed, the chip claimed and the engine imported; "stages",
+        what JAX reported of tracing, lowering and its backend
+        (accel/device.compile_stages: trace_s, lower_s, backend_s, of it
+        miss_s compiling what the cache did not hold, retrieval_s, hits,
+        misses, executables) "before" init_began, inside "engine_init" and
+        inside "warmup"; and "programs", an entry a warmed program in warm-up's
+        order: t, its start, seconds, and of the seconds trace_s, lower_s,
+        backend_s and miss_s with the executables it started and the misses
+        among them. What is left of an entry's seconds is the program's first
+        run, its arrays and its fetch. A warm start reads misses only for the
+        programs under JAX's threshold for caching (a second of compiling);
+        more misses than the start before is a cache that lost entries.
         Reads only; takes no lock."""
         from ray_tpu.accel import device as _device
 

@@ -71,6 +71,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as _P
 
+from ray_tpu.accel.device import compile_stages
 from ray_tpu.llm.cache_rules import ONE_CHIP, rule_for
 from ray_tpu.llm.sampling import SamplingParams, sample_batch
 from ray_tpu.models.transformer import (
@@ -96,6 +97,8 @@ TRACE_RING = 2048
 # a few ms, nothing beside one of 30-280 ms, and every (bucket, k) is a
 # program that set-up traces, lowers and loads (PERF.md section 6, PR 48).
 GROUP_TOKENS = 2048
+# Of accel/device.compile_stages, what a warmed program's entry of warmup_log carries beside its seconds.
+WARMUP_STAGES = ("trace_s", "lower_s", "backend_s", "miss_s", "misses", "executables")
 
 
 def bucket_ladder(prefill_buckets, page_size: int, max_seq: int) -> tuple:
@@ -375,7 +378,7 @@ class LLMEngine:
             "llm.step", STEP_PHASES, TRACE_RING, annotation=jax.profiler.TraceAnnotation
         )
         self.request_ring = _tracing.Ring(TRACE_RING)
-        self.warmup_log: list[dict] = []  # one entry a warmed program, with its seconds
+        self.warmup_log: list[dict] = []  # one entry a warmed program: its seconds, and of them each stage's
         self._key = jax.random.PRNGKey(self.ec.seed + 1)
         self._prefill_jit: dict[int, Any] = {}
         self.mosaic: dict[str, bool] = {}  # filled by warmup()
@@ -745,7 +748,12 @@ class LLMEngine:
         each decode entry of ``warmup_log`` carry ``temp_bytes``, what the
         compiled program holds on the device beside its arguments, on any
         backend: a program that moved the KV pools would need room for them
-        there."""
+        there. Every entry carries, beside its ``seconds``, ``t`` (its start
+        on time.monotonic()) and ``WARMUP_STAGES``: what JAX reported inside
+        those seconds of tracing, lowering and its backend, with the
+        executables started and how many of them the compile cache did not
+        hold. A prefill entry brackets its program and its per-k mirror
+        updates, a decode entry the compile ahead AND the call."""
         if buckets is None:
             buckets = self.buckets
         else:
@@ -760,17 +768,27 @@ class LLMEngine:
         on_tpu = jax.default_backend() == "tpu"
 
         def compiled_ahead(jitted, args):
-            # Ahead of the jitted call, so that call finds this compile in
-            # the persistent cache instead of compiling a second time.
+            # Ahead of the jitted call, which then starts nothing: on the chip a decode entry,
+            # bracketed over both, reads ONE lowering and ONE executable (PERF.md section 5, PR 57).
             return jitted.lower(*args).compile()
 
         def holds_mosaic(compiled) -> bool:
             return "tpu_custom_call" in compiled.as_text()
 
         log = self.warmup_log
+
+        def note(entry, t0, before):
+            # An entry's start and seconds and, of the seconds, what JAX reported of each stage between the
+            # two readings (accel/device.compile_stages): Python over the program's body, jaxpr to MLIR,
+            # the backend (a compile, or the cache's read and its load). The rest is the program's first
+            # run, its arrays and its fetch.
+            after = compile_stages()
+            log.append({**entry, "t": t0, "seconds": time.monotonic() - t0,
+                        **{key: after[key] - before[key] for key in WARMUP_STAGES}})
+
         for b in buckets:
             for k in (k for k in self.group_sizes(b) if k_values is None or k in k_values):
-                t0 = time.monotonic()
+                t0, before = time.monotonic(), compile_stages()
                 toks = jnp.zeros((k, b), jnp.int32)
                 lens = jnp.ones(k, jnp.int32)
                 page_rows = jnp.zeros((k, b // ps), jnp.int32)  # writes -> dead page
@@ -784,8 +802,8 @@ class LLMEngine:
                 args += self._places(np.zeros(k, np.int32))
                 entry = {"program": "prefill", "bucket": b, "k": k}
                 if "prefill" not in self.mosaic:
-                    # The first program only: compiling all of them ahead
-                    # would lower each a second time inside set-up.
+                    # The first program only, which is all ``mosaic`` and ``temp_bytes`` need. (It was kept to
+                    # one for fear of a second lowering: the record shows none, jax 0.9.0, PERF.md section 5.)
                     compiled = compiled_ahead(self._prefill(b, k), args)
                     self.mosaic["prefill"] = on_tpu and holds_mosaic(compiled)
                     entry["temp_bytes"] = compiled.memory_analysis().temp_size_in_bytes
@@ -797,9 +815,9 @@ class LLMEngine:
                 self.d_lengths = self.d_lengths.at[idxs].set(lens)
                 self.d_last = self.d_last.at[idxs].set(td)
                 jax.device_get(td)
-                log.append({**entry, "seconds": time.monotonic() - t0})
+                note(entry, t0, before)
         for n in self.block_sizes:
-            t0 = time.monotonic()
+            t0, before = time.monotonic(), compile_stages()
             args = (self.params, self.cache, self.d_last, self.d_lengths,
                     self.d_page_tables, key, n, self.d_temps, self.d_top_ps, self.d_top_ks)
             compiled = compiled_ahead(self._decode_jit, args)
@@ -809,24 +827,23 @@ class LLMEngine:
             out = self._decode_jit(*args)
             self.cache = out[0]
             jax.device_get(out[1])
-            log.append({"program": "decode", "block": n, "temp_bytes": temp_bytes,
-                        "seconds": time.monotonic() - t0})
+            note({"program": "decode", "block": n, "temp_bytes": temp_bytes}, t0, before)
         # A retire's per-row write of the mirrors, against the lengths as a
         # decode block leaves them and as the host wrote them (one layout,
         # _mirror, so one compile), and the step's key split.
-        t0 = time.monotonic()
+        t0, before = time.monotonic(), compile_stages()
         nobody = jnp.zeros(self.ec.max_slots, bool)
         for lens in (out[3], self.d_lengths):
             jax.block_until_ready(self._drop_rows_jit(lens, self.d_page_tables, nobody))
         _key, _sub = jax.random.split(self._key)  # split and unpacked, as a dispatch does
-        log.append({"program": "drop_rows", "seconds": time.monotonic() - t0})
+        note({"program": "drop_rows"}, t0, before)
         if self.ec.prefix_cache:
             # Compile the prefix-cache page copy (padded rows hit page 0).
-            t0 = time.monotonic()
+            t0, before = time.monotonic(), compile_stages()
             z = jnp.zeros(self.ppseq, jnp.int32)
             self.cache = self._copy_pages_jit(self.cache, z, z)
             jax.block_until_ready(self.cache)
-            log.append({"program": "copy_pages", "seconds": time.monotonic() - t0})
+            note({"program": "copy_pages"}, t0, before)
         # Reset device mirrors dirtied by the dummy executions.
         self.d_lengths = self._mirror(self.lengths)
         self.d_last = self._mirror(np.zeros(self.ec.max_slots, np.int32))
@@ -1213,8 +1230,6 @@ class LLMEngine:
                     pgs[j] = self.page_tables[i, :n_pg]  # trailing zeros -> dead sink
                 idx_arr = jnp.asarray(np.asarray(idxs, np.int32))
                 self._key, sub = jax.random.split(self._key)
-                for key, c in {key: c for rule in self.rules for key, c in rule.prefill_counts(k).items()}.items():
-                    ph.rec[key] += c
                 self.cache, toks_dev = self._prefill(bucket, k)(
                     self.params, self.cache,
                     jnp.asarray(padded), jnp.asarray(lens), jnp.asarray(pgs), sub,

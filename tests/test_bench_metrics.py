@@ -345,3 +345,81 @@ def test_prefill_padding_share_is_the_manifests_last_word_on_the_scheduler(bench
     assert entry == {"name": "prefill_padding_share", "unit": "%", "better": "lower", "source": "program_counter",
                      "layer": "scheduler", "moves": "serve_out_tokens_per_s"}
     assert listed[:6] == moved["workloads"][:6] and set(listed) <= set(moved["workloads"])  # later cells may join
+
+
+# -- the readers of a start's stages (PR 57) ----------------------------------
+# stats()["startup"] gained `ctor_began` and `stages`: what JAX traced, lowered
+# and handed its backend before `init_began`, inside `engine_init` and inside
+# `warmup`. One parametrised test a property, a case a metric.
+SERVE_CELLS = [CHAT] + BACKLOGS + ["solar-open2-250b-ep8.backlog-long-ctx", "granite-4.0-h-micro.backlog-chat",
+                                   "lfm2-24b-a2b.backlog-long-out", "gigachat3.5-432b-ep16.backlog-long-out"]
+STAGE_READERS = {  # name: (answer, source, unit)
+    "setup_warmup_trace_s": (12.5, "program_span", "s"),
+    "setup_warmup_lower_s": (9.25, "program_span", "s"),
+    "setup_warmup_backend_s": (4.0, "program_span", "s"),     # 21 cache reads and loads, 3 small compiles
+    "setup_warmup_run_s": (7.75, "program_span", "s"),        # 33.5 less 12.5 + 9.25 + 4.0
+    "setup_weights_jit_s": (0.5 + 0.25 + 2.0, "program_span", "s"),
+    "setup_replica_backend_s": (6.5, "program_span", "s"),    # the constructor's first statement 76.5 s before the window
+    "setup_cache_misses": (2.0 + 5.0 + 3.0, "program_counter", "count"),
+}
+
+
+def _stages(trace_s, lower_s, backend_s, miss_s, hits, misses):
+    return {"trace_s": trace_s, "lower_s": lower_s, "backend_s": backend_s, "miss_s": miss_s, "retrieval_s": 0.0,
+            "hits": hits, "misses": misses, "executables": hits + misses}
+
+
+def _record_with_stages():
+    record = _record_with_both_clocks()
+    record["stats"]["startup"].update(
+        ctor_began=W0 - 76.5,
+        stages={"before": _stages(0.125, 0.0625, 0.25, 0.25, 0, 2),
+                "engine_init": _stages(0.5, 0.25, 2.0, 1.5, 4, 5),
+                "warmup": _stages(12.5, 9.25, 4.0, 0.5, 21, 3)})
+    return record
+
+
+@pytest.mark.parametrize("name", sorted(STAGE_READERS))
+def test_stage_reader_gives_the_hand_count(bench, name):
+    cellspec, context = bench
+    value = cellspec.load_metric(name)(context.Context(_record_with_stages(), 1))
+    assert value == pytest.approx(STAGE_READERS[name][0], abs=1e-9)
+
+
+@pytest.mark.parametrize("name", sorted(STAGE_READERS))
+def test_stage_reader_is_silent_on_the_parents_record(bench, name):
+    """The parent's start-up record has the stamps and durations and neither
+    `ctor_began` nor `stages`; an older one has no stamps; a run may have no
+    stats at all: None each time, and nothing raised."""
+    cellspec, context = bench
+    bare = _record()
+    bare["stats"] = None
+    no_stamps = _record_with_stages()
+    del no_stamps["stats"]["startup"]["init_ended"]
+    half = _record_with_stages()
+    del half["stats"]["startup"]["ctor_began"]
+    for record in (_record_with_both_clocks(), _record(), bare, no_stamps, half):
+        assert cellspec.load_metric(name)(context.Context(record, 1)) is None
+
+
+def test_the_four_parts_of_warm_up_add_up_to_it(bench):
+    cellspec, context = bench
+    ctx = context.Context(_record_with_stages(), 1)
+    parts = [cellspec.load_metric(f"setup_warmup_{part}_s")(ctx) for part in ("trace", "lower", "backend", "run")]
+    assert sum(parts) == pytest.approx(cellspec.load_metric("setup_warmup_s")(ctx)) and parts[-1] >= 0
+
+
+@pytest.mark.parametrize("name", sorted(STAGE_READERS))
+def test_stage_metric_is_in_the_manifest_with_the_serve_cells(bench, name):
+    import json
+
+    with open(os.path.join(os.path.dirname(BENCH_DIR), "BENCHMARK.json")) as f:
+        manifest = json.load(f)
+    entry = next(m for m in manifest["per_layer"] if m["name"] == name)
+    _answer, source, unit = STAGE_READERS[name]
+    listed = entry.pop("workloads")
+    assert entry == {"name": name, "unit": unit, "better": "lower", "source": source, "layer": "serve path",
+                     "moves": "setup_s"}
+    # the cells of the four outside parts, which these lie inside; later serve cells may join
+    outside = next(m for m in manifest["per_layer"] if m["name"] == "setup_warmup_s")["workloads"]
+    assert listed[:9] == SERVE_CELLS == outside[:9] and len(set(listed)) == len(listed)

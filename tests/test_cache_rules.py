@@ -58,7 +58,7 @@ def test_a_rule_answers_every_question_of_the_contract(name):
     # what it counts: every key starts a step's record at zero
     eng.generate(np.arange(1, 20) % cfg.vocab_size, max_tokens=6)
     steps = eng.trace_snapshot()["steps"]
-    counted = {**rule.block_counts(np.array([5, 17]), 4), **rule.prefill_counts(2)}
+    counted = rule.block_counts(np.array([5, 17]), 4)
     assert all(isinstance(v, int) for v in counted.values())
     assert set(rule.zeroes) | set(counted) | set(rule.device_counts) <= set(steps[0])
     assert set(counted) | set(rule.device_counts) <= set(rule.zeroes) | {"live_pages", "grid_steps"}

@@ -322,19 +322,18 @@ def test_an_empty_slots_state_tail_and_pages_are_bit_for_bit_what_they_were_afte
     steps = eng.trace_snapshot()["steps"]
     blocks = [s for s in steps if s["block"]]
     assert blocks and all(s["state_rows"] == s["block"] * s["active"] == s["block"] for s in blocks)
-    assert sum(s["states_written"] for s in steps) == 1
+    assert sum(s["n_prefill"] for s in steps) == 1
 
 
 def test_the_step_records_carry_both_rules_counters_together():
-    """One record a step holds the state rule's counts (state_rows,
-    states_written), the latent walk's (live_pages, grid_steps) and the held
+    """One record a step holds the state rule's count (state_rows), the latent walk's (live_pages, grid_steps) and the held
     experts' (expert_pairs, expert_tiles), each under the name it has."""
     eng = LLMEngine(CFG, params=_params(), engine_config=EngineConfig(**ENGINE_KW))
     eng.add_request("a", _tokens(40, seed=1), max_tokens=9)
     eng.add_request("b", _tokens(3, seed=2), max_tokens=9)
     _run(eng)
     steps = eng.trace_snapshot()["steps"]
-    keys = {"state_rows", "states_written", "live_pages", "grid_steps", "expert_pairs", "expert_tiles"}
+    keys = {"state_rows", "live_pages", "grid_steps", "expert_pairs", "expert_tiles"}
     assert all(keys <= set(s) for s in steps)
     block = next(s for s in steps if s["block"] and s["active"] == 2)
     n = block["block"]
@@ -342,7 +341,7 @@ def test_the_step_records_carry_both_rules_counters_together():
     # ONE latent layer's walk: a slot's ceil(length / page) pages at each step, each in one grid step here
     assert block["live_pages"] >= 2 * n and block["grid_steps"] == 2 * n
     assert 0 < block["expert_pairs"] <= 4 * n * 3 * CFG.expert_top_k and block["expert_tiles"] > 0
-    assert sum(s["states_written"] for s in steps) == 2
+    assert sum(s["n_prefill"] for s in steps) == 2
     assert not {"window_pages", "tail_rows"} & set(steps[0])  # no rule of this model counts those
 
 

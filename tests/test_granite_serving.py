@@ -305,7 +305,7 @@ def test_an_empty_slots_state_is_bit_for_bit_what_it_was_after_decode_blocks():
     steps = eng.trace_snapshot()["steps"]
     blocks = [s for s in steps if s["block"]]
     assert blocks and all(s["state_rows"] == s["block"] * 1 == s["block"] * s["active"] for s in blocks)
-    assert sum(s["states_written"] for s in steps) == 1
+    assert sum(s["n_prefill"] for s in steps) == 1
 
 
 def test_admission_budgets_pages_for_the_layers_that_keep_every_token():

@@ -402,12 +402,12 @@ def test_an_empty_slots_tail_is_bit_for_bit_what_it_was_and_the_records_count_ta
     assert (np.asarray(eng.cache[0][:, 1]) == want).all()
     assert np.asarray(eng.cache[0][:, 0]).any()  # slot 0's moved
     steps = eng.trace_snapshot()["steps"]
-    assert all({"tail_rows", "tails_written", "expert_pairs", "expert_tiles"} <= set(s) for s in steps)
-    assert not any("state_rows" in s or "states_written" in s for s in steps)
+    assert all({"tail_rows", "expert_pairs", "expert_tiles"} <= set(s) for s in steps)
+    assert not any("state_rows" in s for s in steps)
     blocks = [s for s in steps if s["block"]]
     assert blocks and all(s["tail_rows"] == s["block"] * 1 == s["block"] * s["active"] for s in blocks)
     assert all(s["expert_pairs"] == s["block"] * 2 * 2 * 4 for s in blocks)  # both rows of the batch are routed
-    assert sum(s["tails_written"] for s in steps) == 1 and sum(s["tail_rows"] for s in steps) >= 12
+    assert sum(s["n_prefill"] for s in steps) == 1 and sum(s["tail_rows"] for s in steps) >= 12
 
 
 @pytest.mark.parametrize("engine_kw,message", [

@@ -252,7 +252,7 @@ def test_an_empty_slots_state_is_bit_for_bit_what_it_was_after_decode_blocks():
     steps = eng.trace_snapshot()["steps"]
     blocks = [s for s in steps if s["block"]]
     assert blocks and all(s["state_rows"] == s["block"] * 1 == s["block"] * s["active"] for s in blocks)
-    assert sum(s["states_written"] for s in steps) == 1
+    assert sum(s["n_prefill"] for s in steps) == 1
     dense = LLMEngine(TransformerConfig(vocab_size=64, d_model=32, n_layers=1, n_heads=4, d_ff=32),
                       engine_config=EngineConfig(max_slots=2, max_seq=64, page_size=16, prefill_buckets=(32,)))
     dense.generate([1, 2, 3], max_tokens=2)
