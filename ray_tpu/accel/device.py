@@ -31,12 +31,17 @@ TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
 LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+# The function ``pl.pallas_call`` traces a kernel's body in: a jit of its own, made anew at every call (jax 0.9.0,
+# jax/_src/pallas/pallas_call.py ``wrapped``), so each of its TRACE_EVENTs is a body really traced. Not so a
+# TRACE_EVENT as such: JAX reports one for every call of a jit inside a trace, those its trace cache served too
+# (``pjit._trace_for_jit`` brackets the cached ``trace_to_jaxpr``), and a model's every jax.numpy call is one.
+KERNEL_TRACE_FUN = "wrapped"
 _compiles = Ring(512)  # (time.monotonic() at the end of a backend event, its seconds)
 _compile_counter = None  # the metrics plane's jax.compiles, once listening
 # This process's seconds and counts by stage since enable_compile_cache (compile_stages). Any thread may trace
 # or compile: the totals under a lock, a trace's depth and a cache read's verdict by thread.
 _stages = {"trace_s": 0.0, "lower_s": 0.0, "backend_s": 0.0, "miss_s": 0.0, "retrieval_s": 0.0,
-           "hits": 0, "misses": 0, "executables": 0}
+           "traces": 0, "hits": 0, "misses": 0, "executables": 0}
 _stages_lock = threading.Lock()
 _in_thread = threading.local()  # depth: traces begun and not ended; hit: the cache held the executable on its way
 
@@ -82,11 +87,13 @@ def _count_compiles() -> None:
         if name == TRACE_EVENT:
             _in_thread.depth = getattr(_in_thread, "depth", 0) + 1
 
-    def on_duration(name, seconds, **_kw):
+    def on_duration(name, seconds, fun_name=None, **_kw):
         if name == TRACE_EVENT:
             _in_thread.depth = depth = max(getattr(_in_thread, "depth", 0) - 1, 0)
             if not depth:  # a trace inside another is seconds of that one: counted once, with it
                 add(trace_s=seconds)
+            if fun_name == KERNEL_TRACE_FUN:
+                add(traces=1)
         elif name == LOWER_EVENT:
             add(lower_s=seconds)
         elif name == RETRIEVAL_EVENT:  # inside the backend event that follows, and only on a hit
@@ -114,7 +121,9 @@ def compile_events() -> dict:
 
 def compile_stages() -> dict:
     """This process's cumulative seconds and counts by stage since ``enable_compile_cache``: ``trace_s``
-    (a nested jit's trace counted once, inside its caller's), ``lower_s``, ``backend_s`` (inside the backend
+    (a nested jit's trace counted once, inside its caller's), ``traces`` (kernel bodies traced, each inside
+    ``trace_s``: a kernel whose call JAX's trace cache served is none, so one traced once a shape signature
+    counts once and one traced at every call once a call), ``lower_s``, ``backend_s`` (inside the backend
     events, whatever the cache said), ``miss_s`` (the part of ``backend_s`` whose executable the cache did not
     hold or was not asked for: a compile), ``retrieval_s`` (the cache reads of the hits, part of
     ``backend_s``), ``hits``, ``misses`` and ``executables`` (backend events: hits + misses). JAX writes to

@@ -98,7 +98,7 @@ TRACE_RING = 2048
 # program that set-up traces, lowers and loads (PERF.md section 6, PR 48).
 GROUP_TOKENS = 2048
 # Of accel/device.compile_stages, what a warmed program's entry of warmup_log carries beside its seconds.
-WARMUP_STAGES = ("trace_s", "lower_s", "backend_s", "miss_s", "misses", "executables")
+WARMUP_STAGES = ("trace_s", "lower_s", "backend_s", "miss_s", "traces", "misses", "executables")
 
 
 def bucket_ladder(prefill_buckets, page_size: int, max_seq: int) -> tuple:
@@ -326,10 +326,12 @@ class LLMEngine:
             # Init DIRECTLY sharded: the whole point of TP serving is a model
             # bigger than one chip's HBM — materializing the full tree on one
             # device before resharding would OOM exactly that model.
+            # The key is an ARGUMENT: a seed inside the program would make every seed
+            # another program, compiled anew at every start (35 s of a four-chip start,
+            # PERF.md section 6, PR 58); as it is the compile cache holds one for all.
             self.params = jax.jit(
-                lambda: init_params(jax.random.PRNGKey(self.ec.seed), cfg),
-                out_shardings=param_shardings,
-            )()
+                lambda key: init_params(key, cfg), out_shardings=param_shardings,
+            )(jax.random.PRNGKey(self.ec.seed))
         else:
             self.params = init_params(jax.random.PRNGKey(self.ec.seed), cfg)
         B = self.ec.max_slots

@@ -120,9 +120,9 @@ def _mask_scores(s, q_start, k_start, causal, seg_q, seg_k, window=0, q_axis=0):
 SUB_TILE = 512
 
 
-def _sub_tile(block_q, block_k):
-    """The sub-tile of a call's blocks: `SUB_TILE`, cut to what divides both."""
-    return math.gcd(math.gcd(block_q, block_k), SUB_TILE)
+def _sub_tile(block_q, block_k, sub_tile):
+    """The sub-tile of a call's blocks: `sub_tile`, cut to what divides both."""
+    return math.gcd(math.gcd(block_q, block_k), sub_tile)
 
 
 def _segment_ranges(segment_ids, sub: int):
@@ -151,7 +151,7 @@ def live_tiles(segment_ids, S, block_q, block_k, window=0):
     """(live, causal): the sub-tiles the causal kernels compute for these ids ([B, S] numpy, or None) at these
     blocks, and the sub-tiles on or under the diagonal, both summed over the rows. The kernels' tables are built
     from the same `_live_sub_tiles`, so this is the record of what they visit."""
-    sub = _sub_tile(min(block_q, S), min(block_k, S))
+    sub = _sub_tile(min(block_q, S), min(block_k, S), SUB_TILE)
     ids = None if segment_ids is None else np.asarray(segment_ids)
     causal = _live_sub_tiles(None, S, sub, 0).sum() * (1 if ids is None else len(ids))
     return int(_live_sub_tiles(ids, S, sub, window).sum()), int(causal)
@@ -297,12 +297,12 @@ def _first_band_block(qi, block_q, block_k, window):
     return _div(lax.max(qi * block_q - window + 1, jnp.int32(0)), block_k)
 
 
-def _walk_of(causal, seg, S, block_q, block_k, window, hold):
+def _walk_of(causal, seg, S, block_q, block_k, window, hold, sub_tile):
     """(sub_q, sub_k, tables) of a call: a causal call's sub-tile and scalar tables; a call that is not causal
     skips nothing, so its sub-tile is the block and it has no table."""
     if not causal:
         return block_q, block_k, ()
-    sub = _sub_tile(block_q, block_k)
+    sub = _sub_tile(block_q, block_k, sub_tile)
     return sub, sub, _walk_tables(seg, S, block_q, block_k, sub, window, hold)
 
 
@@ -386,7 +386,10 @@ def _fwd_kernel(*refs, scale, block_q, block_k, sub_q, sub_k, n_k, causal, has_s
         lse_ref[0, :, :] = jnp.broadcast_to(lse[None, :], lse_ref.shape[1:])
 
 
-def _fwd_pallas(q, k, v, seg, *, causal, scale, block_q, block_k, group, H, interpret, window=0):
+# one trace a shape signature: ops/__init__.py
+@functools.partial(jax.jit, inline=True, static_argnames=(
+    "causal", "scale", "block_q", "block_k", "group", "H", "interpret", "window", "sub_tile"))
+def _fwd_pallas(q, k, v, seg, *, causal, scale, block_q, block_k, group, H, interpret, sub_tile, window=0):
     """q: [BH, S, D]; k,v: [BKV, S, D]; seg: [B, 8, S] i32 or None
     -> (o [BH, S, D], lse [BH, S] f32)."""
     from jax.experimental import pallas as pl
@@ -398,7 +401,7 @@ def _fwd_pallas(q, k, v, seg, *, causal, scale, block_q, block_k, group, H, inte
     n_q = pl.cdiv(S, block_q)
     n_k = band_blocks(S, block_q, block_k, window)  # the grid's k steps
     has_seg = seg is not None
-    sub_q, sub_k, tables = _walk_of(causal, seg, S, block_q, block_k, window, "k")
+    sub_q, sub_k, tables = _walk_of(causal, seg, S, block_q, block_k, window, "k", sub_tile)
 
     kernel = functools.partial(
         _fwd_kernel, scale=scale, block_q=block_q, block_k=block_k, sub_q=sub_q, sub_k=sub_k, n_k=n_k,
@@ -591,7 +594,10 @@ def _bwd_dq_kernel(*refs, scale, block_q, block_k, sub_q, sub_k, n_k, causal, ha
         dq_ref[0, :, :] = dq_scr[:, :].astype(dq_ref.dtype)
 
 
-def _bwd_pallas(res, g, *, causal, scale, block_q, block_k, group, H, KV, interpret, window=0):
+# one trace a shape signature: ops/__init__.py
+@functools.partial(jax.jit, inline=True, static_argnames=(
+    "causal", "scale", "block_q", "block_k", "group", "H", "KV", "interpret", "window", "sub_tile"))
+def _bwd_pallas(res, g, *, causal, scale, block_q, block_k, group, H, KV, interpret, sub_tile, window=0):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -604,8 +610,8 @@ def _bwd_pallas(res, g, *, causal, scale, block_q, block_k, group, H, KV, interp
     n_q = pl.cdiv(S, block_q)
     n_k = pl.cdiv(S, block_k)
     has_seg = seg is not None
-    sub_q, sub_k, k_tables = _walk_of(causal, seg, S, block_q, block_k, window, "k")
-    q_tables = _walk_of(causal, seg, S, block_q, block_k, window, "q")[2]
+    sub_q, sub_k, k_tables = _walk_of(causal, seg, S, block_q, block_k, window, "k", sub_tile)
+    q_tables = _walk_of(causal, seg, S, block_q, block_k, window, "q", sub_tile)[2]
     walk = dict(scale=scale, block_q=block_q, block_k=block_k, sub_q=sub_q, sub_k=sub_k, causal=causal,
                 has_seg=has_seg, n_tables=len(k_tables), S=S, window=window)
 
@@ -706,27 +712,27 @@ def _bwd_pallas(res, g, *, causal, scale, block_q, block_k, group, H, KV, interp
 # Public API with custom VJP
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10, 11, 12))
-def _flash_folded(q, k, v, seg, causal, scale, block_q, block_k, group, H, KV, interpret, window):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10, 11, 12, 13))
+def _flash_folded(q, k, v, seg, causal, scale, block_q, block_k, group, H, KV, interpret, window, sub_tile):
     o, _ = _fwd_pallas(
-        q, k, v, seg, causal=causal, scale=scale, block_q=block_q,
-        block_k=block_k, group=group, H=H, interpret=interpret, window=window,
+        q, k, v, seg, causal=causal, scale=scale, block_q=block_q, block_k=block_k,
+        group=group, H=H, interpret=interpret, window=window, sub_tile=sub_tile,
     )
     return o
 
 
-def _flash_fwd(q, k, v, seg, causal, scale, block_q, block_k, group, H, KV, interpret, window):
+def _flash_fwd(q, k, v, seg, causal, scale, block_q, block_k, group, H, KV, interpret, window, sub_tile):
     o, lse = _fwd_pallas(
-        q, k, v, seg, causal=causal, scale=scale, block_q=block_q,
-        block_k=block_k, group=group, H=H, interpret=interpret, window=window,
+        q, k, v, seg, causal=causal, scale=scale, block_q=block_q, block_k=block_k,
+        group=group, H=H, interpret=interpret, window=window, sub_tile=sub_tile,
     )
     return o, (q, k, v, o, lse, seg)
 
 
-def _flash_bwd(causal, scale, block_q, block_k, group, H, KV, interpret, window, res, g):
+def _flash_bwd(causal, scale, block_q, block_k, group, H, KV, interpret, window, sub_tile, res, g):
     dq, dk, dv = _bwd_pallas(
         res, g, causal=causal, scale=scale, block_q=block_q, block_k=block_k,
-        group=group, H=H, KV=KV, interpret=interpret, window=window,
+        group=group, H=H, KV=KV, interpret=interpret, window=window, sub_tile=sub_tile,
     )
     seg = res[5]
     dseg = None if seg is None else np.zeros(seg.shape, jax.dtypes.float0)
@@ -786,8 +792,9 @@ def flash_attention(q, k, v, causal=True, scale=None, segment_ids=None,
         seg = jnp.broadcast_to(
             segment_ids.astype(jnp.int32)[:, None, :], (B, 8, S)
         )  # sublane-tiled like lse
+    # SUB_TILE is read HERE, at the call: what a trace depends on is in its key, or the cache serves another's
     o = _flash_folded(
         fold(q), fold(k), fold(v), seg, causal, scale, block_q, block_k,
-        group, H, KV, interpret, int(window),
+        group, H, KV, interpret, int(window), SUB_TILE,
     )
     return o.reshape(B, H, S, D).transpose(0, 2, 1, 3)

@@ -146,6 +146,8 @@ def _block(dim: int, target: int) -> int:
     return best or dim
 
 
+# one trace a shape signature: ops/__init__.py
+@functools.partial(jax.jit, inline=True, static_argnames=("tm", "block_k", "block_n", "interpret"))
 def expert_gmm(x, w, layer, tile_expert, n_tiles, *, tm, block_k=2048, block_n=1024, interpret=False):
     """The grouped matmul (the Pallas kernel; arguments as
     ``expert_gmm_reference``; rows of tiles past n_tiles are not written).

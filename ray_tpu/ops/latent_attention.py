@@ -225,6 +225,8 @@ def _latent_kernel(lens_ref, layer_ref, seqs_ref, first_ref, live_ref, where_ref
         window_copy.wait()  # before the step after next fetches into this buffer
 
 
+# one trace a shape signature: ops/__init__.py
+@functools.partial(jax.jit, inline=True, static_argnames=("v_width", "scale", "interpret"))
 def latent_paged_attention(q, row_new, pool, lengths, page_indices, layer, *, v_width, scale,
                            walk=None, interpret=False):
     """Latent paged decode attention (the Pallas kernel; arguments and result

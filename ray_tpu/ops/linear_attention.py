@@ -339,6 +339,8 @@ def _chunk_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, s0_ref, o_ref, s_ref, s_
         s_ref[...] = s
 
 
+# one trace a shape signature: ops/__init__.py
+@functools.partial(jax.jit, inline=True, static_argnames=("out_dtype", "interpret"))
 def kda_chunk(q, k, v, g, beta, state=None, *, out_dtype=None, interpret=False):
     """The rule over a prompt, CHUNK positions of several heads a grid step
     (the Pallas kernel; arguments and results as ``kda_scan_reference``, o in
@@ -405,6 +407,8 @@ def _prep_kernel(x_ref, behind_ref, taps_ref, q_ref, k_ref, v_ref, rows, *, part
     lax.fori_loop(0, block // PREP_ROWS, trip, 0)
 
 
+# one trace a shape signature: ops/__init__.py
+@functools.partial(jax.jit, inline=True, static_argnames=("key_heads", "interpret"))
 def delta_prep(window, taps, key_heads, *, interpret=False):
     """``delta_prep_reference`` as ONE pass over a prompt (the Pallas kernel;
     arguments and results as there): the window is read once, where it lies
@@ -471,6 +475,8 @@ def _step_kernel(layer_ref, slots_ref, x_ref, _pool_in, o_ref, s_ref, *, heads):
     lax.fori_loop(0, heads, one, 0)
 
 
+# one trace a shape signature: ops/__init__.py
+@functools.partial(jax.jit, inline=True, static_argnames=("interpret",))
 def kda_step(q, k, v, g, beta, pool, layer, live, *, interpret=False):
     """One token a live slot (the Pallas kernel; arguments and results as
     ``kda_step_reference``). The pool is aliased to the call's output and

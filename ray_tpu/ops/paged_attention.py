@@ -482,6 +482,8 @@ def _paged_kernel(lens_ref, layer_ref, seqs_ref, first_ref, live_ref, where_ref,
             copy.wait()
 
 
+# one trace a shape signature: ops/__init__.py
+@functools.partial(jax.jit, inline=True, static_argnames=("n_pages", "scale", "interpret", "window"))
 def _paged_pallas(q, k_new, v_new, k_pages, v_pages, lengths, n_pages, layer, walk,
                   *, scale, interpret, window=0):
     """q: [B, KV, Gp, D] (Gp >= 8, sublane-padded); k_new/v_new: f32

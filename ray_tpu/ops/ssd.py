@@ -240,6 +240,8 @@ def _chunk_kernel(x_ref, b_ref, c_ref, dt_ref, a_col_ref, a_row_ref, s0_ref, y_r
         s_ref[...] = s_scr[...]
 
 
+# one trace a shape signature: ops/__init__.py
+@functools.partial(jax.jit, inline=True, static_argnames=("out_dtype", "interpret"))
 def ssd_chunk(x, Bm, Cm, g, dt, state=None, *, out_dtype=None, interpret=False):
     """The rule over a prompt, CHUNK positions of several heads a grid step
     (the Pallas kernel; arguments and results as ``ssd_scan_reference``, y in
@@ -313,6 +315,8 @@ def _columns_a_step(M: int, N: int) -> int:
     return next((n for n in range(limit // COLUMNS_A_PASS * COLUMNS_A_PASS, 0, -COLUMNS_A_PASS) if M % n == 0), M)
 
 
+# one trace a shape signature: ops/__init__.py
+@functools.partial(jax.jit, inline=True, static_argnames=("interpret",))
 def ssd_step(x, Bm, Cm, g, dt, pool, layer, live, *, interpret=False):
     """One token a live slot (the Pallas kernel; arguments and results as
     ``ssd_step_reference``). The pool is aliased to the call's output and

@@ -193,7 +193,7 @@ def test_rows_whose_first_sub_tiles_are_dead_stay_finite(small_sub_tiles):
     fold = lambda x: x.transpose(0, 2, 1, 3).reshape(-1, S, D)
     o, lse = attention._fwd_pallas(
         fold(q), fold(k), fold(v), jnp.broadcast_to(ids[:, None, :], (B, 8, S)), causal=True, scale=D ** -0.5,
-        block_q=64, block_k=64, group=1, H=2, interpret=True)
+        block_q=64, block_k=64, group=1, H=2, interpret=True, sub_tile=attention.SUB_TILE)
     assert np.isfinite(np.asarray(o)).all() and np.isfinite(np.asarray(lse)).all()
     assert float(jnp.min(lse)) > -1e3  # no row's statistics were left at NEG_INF
     ref = mha_reference(q, k, v, segment_ids=ids)

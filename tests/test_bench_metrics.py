@@ -361,21 +361,22 @@ STAGE_READERS = {  # name: (answer, source, unit)
     "setup_weights_jit_s": (0.5 + 0.25 + 2.0, "program_span", "s"),
     "setup_replica_backend_s": (6.5, "program_span", "s"),    # the constructor's first statement 76.5 s before the window
     "setup_cache_misses": (2.0 + 5.0 + 3.0, "program_counter", "count"),
+    "setup_warmup_traces": (61.0, "program_counter", "count"),  # PR 58: kernel bodies traced, the calls the trace cache did not serve
 }
 
 
-def _stages(trace_s, lower_s, backend_s, miss_s, hits, misses):
+def _stages(trace_s, lower_s, backend_s, miss_s, hits, misses, traces=0):
     return {"trace_s": trace_s, "lower_s": lower_s, "backend_s": backend_s, "miss_s": miss_s, "retrieval_s": 0.0,
-            "hits": hits, "misses": misses, "executables": hits + misses}
+            "traces": traces, "hits": hits, "misses": misses, "executables": hits + misses}
 
 
 def _record_with_stages():
     record = _record_with_both_clocks()
     record["stats"]["startup"].update(
         ctor_began=W0 - 76.5,
-        stages={"before": _stages(0.125, 0.0625, 0.25, 0.25, 0, 2),
-                "engine_init": _stages(0.5, 0.25, 2.0, 1.5, 4, 5),
-                "warmup": _stages(12.5, 9.25, 4.0, 0.5, 21, 3)})
+        stages={"before": _stages(0.125, 0.0625, 0.25, 0.25, 0, 2, traces=2),
+                "engine_init": _stages(0.5, 0.25, 2.0, 1.5, 4, 5, traces=11),
+                "warmup": _stages(12.5, 9.25, 4.0, 0.5, 21, 3, traces=61)})
     return record
 
 
@@ -400,6 +401,18 @@ def test_stage_reader_is_silent_on_the_parents_record(bench, name):
     del half["stats"]["startup"]["ctor_began"]
     for record in (_record_with_both_clocks(), _record(), bare, no_stamps, half):
         assert cellspec.load_metric(name)(context.Context(record, 1)) is None
+
+
+def test_setup_warmup_traces_is_silent_on_a_record_whose_stages_count_no_traces(bench):
+    """PR 57's record has `stages` without `traces` (the counter is PR 58's): the
+    other stage readers read it, this one reads None and raises nothing."""
+    cellspec, context = bench
+    record = _record_with_stages()
+    for part in record["stats"]["startup"]["stages"].values():
+        del part["traces"]
+    ctx = context.Context(record, 1)
+    assert cellspec.load_metric("setup_warmup_traces")(ctx) is None
+    assert cellspec.load_metric("setup_warmup_trace_s")(ctx) == pytest.approx(12.5)
 
 
 def test_the_four_parts_of_warm_up_add_up_to_it(bench):

@@ -15,7 +15,7 @@ from ray_tpu.llm import EngineConfig, LLMEngine
 from ray_tpu.llm.engine import WARMUP_STAGES
 from ray_tpu.models import TransformerConfig
 
-STAGES = {"trace_s", "lower_s", "backend_s", "miss_s", "retrieval_s", "hits", "misses", "executables"}
+STAGES = {"trace_s", "lower_s", "backend_s", "miss_s", "retrieval_s", "traces", "hits", "misses", "executables"}
 MODEL_KW = dict(vocab_size=97, d_model=64, n_layers=2, n_heads=4, n_kv_heads=2, d_ff=128,
                 max_seq_len=128, dtype=jnp.float32, attention_impl="reference")
 ENGINE_KW = dict(max_slots=4, max_seq=128, prefill_buckets=(16, 32), page_size=16, prefix_cache=True)
@@ -56,7 +56,7 @@ def test_compile_stages_has_its_keys_and_compile_events_keeps_its_shape():
     took = _since(before)
     assert set(took) == STAGES
     assert took["executables"] == took["hits"] + took["misses"] == 1
-    assert took["trace_s"] > 0 and took["lower_s"] > 0 and took["backend_s"] > 0
+    assert took["trace_s"] > 0 and took["lower_s"] > 0 and took["backend_s"] > 0 and took["traces"] == 0
     assert 0 <= took["miss_s"] <= took["backend_s"] + EPS and 0 <= took["retrieval_s"] <= took["backend_s"] + EPS
     events = device.compile_events()
     assert set(events) == {"count", "recent"} and events["count"] == counted + 1
@@ -96,6 +96,45 @@ def test_a_nested_jits_trace_is_counted_once_with_its_callers():
     assert len(reported) >= 2 and max(reported) == reported[-1] >= 0.05  # the caller's ends last, around the rest
     assert took["trace_s"] == pytest.approx(reported[-1]) and took["trace_s"] < sum(reported)
     assert took["executables"] == 1  # one program: the inner function is part of it
+    # JAX reports an event for the inner function's second call too, which its trace cache served, and for
+    # whatever of jax.numpy the two called: events are no count of traces, and none of these is a kernel's
+    assert len(reported) >= 3 and took["traces"] == 0
+
+
+def _toy_kernel(v):
+    """A pallas_call as ray_tpu/ops makes them, in interpret mode: the body doubles a block."""
+    from jax.experimental import pallas as pl
+
+    def body(x_ref, o_ref):
+        o_ref[...] = x_ref[...] * 2.0
+
+    return pl.pallas_call(body, out_shape=jax.ShapeDtypeStruct(v.shape, v.dtype), interpret=True, name="toy")(v)
+
+
+def test_traces_counts_the_kernel_bodies_the_trace_cache_did_not_serve():
+    """Two readings' difference is the kernel bodies traced between them,
+    nested as they always are inside a program's trace: a call inside a jit
+    with one identity is traced once a shape signature however often and from
+    whatever program it is made, the bare call every time, and a function
+    that holds no kernel never counts, traced or served."""
+    device._count_compiles()
+    kept = jax.jit(_toy_kernel, inline=True)
+
+    def program(op):
+        return jax.jit(lambda v: op(op(op(v))))
+
+    x, other = jnp.ones((8, 128)), jnp.ones((16, 128))
+
+    def traced(op, operand):
+        before = device.compile_stages()
+        program(op).lower(operand)
+        return _since(before)["traces"]
+
+    assert traced(kept, x) == 1  # three calls, one body
+    assert traced(kept, x) == 0  # another program of equal shapes: served
+    assert traced(kept, other) == 1  # another shape: the body again
+    assert traced(_toy_kernel, x) == 3  # the bare call: a trace a call
+    assert traced(jax.jit(jnp.tanh, inline=True), x) == 0  # no kernel
 
 
 def test_a_second_call_of_a_compiled_program_adds_nothing():

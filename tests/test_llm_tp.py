@@ -209,3 +209,46 @@ def test_tp_a_block_ahead_staggered_requests_and_reused_slots_match_their_solo_r
     assert device.compile_events()["count"] == compiled
     for mirror in (eng.d_lengths, eng.d_last, eng.d_page_tables, eng.d_temps):
         assert mirror.sharding.is_equivalent_to(eng._replicated, mirror.ndim)
+
+
+def test_a_sharded_replicas_weights_are_one_program_for_every_seed(monkeypatch):
+    """The key is the program's ARGUMENT (PR 58): on four devices two seeds
+    lower the same text, so the compile cache holds one executable for every
+    seed and not one a seed, compiled anew at each start; and the weights are
+    leaf for leaf, bit for bit, those of the program with the seed inside
+    (the engine's line before), which are ``init_params`` of the seed's key
+    (to a rounding: a fused multiply-add of the compiled form)."""
+    import dataclasses
+
+    import jax
+
+    from ray_tpu.models.transformer import init_params
+
+    cfg = dataclasses.replace(CFG, n_kv_heads=4)
+    jit, texts = jax.jit, []
+
+    def spying(fun, **kw):
+        jitted = jit(fun, **kw)
+        if not isinstance(kw.get("out_shardings"), dict):  # the weights' tree of shardings
+            return jitted
+
+        def lowered_and_called(*args):
+            texts.append(jitted.lower(*args).as_text())
+            return jitted(*args)
+        return lowered_and_called
+
+    monkeypatch.setattr(jax, "jit", spying)
+    engines = {seed: LLMEngine(cfg, engine_config=EngineConfig(**ENGINE_KW, tensor_parallel=4, seed=seed))
+               for seed in (0, 20250601)}
+    monkeypatch.undo()
+    assert len(texts) == 2 and texts[0] == texts[1]
+    for seed, eng in engines.items():
+        assert len(eng.params["layers"]["wq"].sharding.device_set) == 4
+        before = jax.jit(lambda: init_params(jax.random.PRNGKey(seed), cfg), out_shardings=eng._param_shardings)()
+        same = jax.tree.map(lambda a, b: a.dtype == b.dtype and bool((np.asarray(a) == np.asarray(b)).all()),
+                            eng.params, before)
+        assert all(jax.tree.leaves(same)), same
+        jax.tree.map(lambda a, b: np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6, atol=1e-7),
+                     eng.params, init_params(jax.random.PRNGKey(seed), cfg))
+    a, b = (np.asarray(eng.params["layers"]["wq"]) for eng in engines.values())
+    assert (a != b).any()  # and the seeds' weights are not each other's

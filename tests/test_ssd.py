@@ -127,6 +127,62 @@ def test_the_step_kernel_leaves_a_dead_slot_bit_for_bit():
     assert (np.asarray(new[0]) == np.asarray(pool[0])).all() and (np.asarray(new[2]) == np.asarray(pool[2])).all()
 
 
+def _equations(jaxpr):
+    """Equations of a jaxpr, those of its inner jaxprs counted once each."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += 1
+        for value in eqn.params.values():
+            for inner in value if isinstance(value, (list, tuple)) else [value]:
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    n += _equations(inner)
+    return n
+
+
+def _body(call, *operands):
+    """(equations of the one pallas_call's body, its grid) in a call's jaxpr."""
+    calls = [eqn for eqn in jax.make_jaxpr(call)(*operands).jaxpr.eqns if eqn.primitive.name == "pallas_call"]
+    assert len(calls) == 1
+    return _equations(calls[0].params["jaxpr"]), calls[0].params["grid_mapping"].grid
+
+
+@pytest.mark.parametrize("S", [512, 4096])
+def test_the_chunk_kernels_body_does_not_grow_with_the_prompt(S):
+    """What a start pays once a shape signature (PR 58: the call is a jit with
+    one identity, so the nine equal layers of a period and a bucket's group
+    sizes share ONE trace of this body) and every prefill program still pays
+    a call in lowering: at `granite`'s widths (64 heads of 64 columns, 128 of
+    state, bfloat16) a step takes 32 heads, two to a lane tile, whose 16 tiles
+    are unrolled: 1,262 equations as counted here, whatever the prompt, and 338
+    at 8 heads a step. Bounded a tenth above."""
+    def chunk(H):
+        x, bc = jnp.zeros((1, S, H, 64), jnp.bfloat16), jnp.zeros((1, S, 1, 128), jnp.bfloat16)
+        h = jnp.zeros((1, S, H), jnp.float32)
+        n, grid = _body(lambda x, bc, h: ssd.ssd_chunk(x, bc, bc, h, h, interpret=True), x, bc, h)
+        assert grid == (1, H // ssd._heads_a_chunk(H, 64), S // ssd.CHUNK)
+        return n
+
+    assert chunk(64) <= 1390 and chunk(8) <= 372
+
+
+def test_the_step_kernels_body_is_a_pass_a_block_of_columns():
+    """A decode block traces this body once a block size: `granite`'s slot
+    state (128 x 4,096 float32, 2 MB) is one grid step of 8 passes over 512
+    columns, 110 equations as counted here, whatever the slots and the layers;
+    a toy state of one pass counts 33."""
+    def step(B, H, P, N, L):
+        x, bc = jnp.zeros((B, H, P), jnp.bfloat16), jnp.zeros((B, 1, N), jnp.bfloat16)
+        h, pool = jnp.zeros((B, H), jnp.float32), jnp.zeros((L, B, N, H * P), jnp.float32)
+        n, grid = _body(lambda x, bc, h, pool, layer, live: ssd.ssd_step(x, bc, bc, h, h, pool, layer, live,
+                                                                         interpret=True),
+                        x, bc, h, pool, jnp.int32(1), jnp.ones(B, bool))
+        assert grid[1] == H * P // ssd._columns_a_step(H * P, N)
+        return n
+
+    assert step(32, 64, 64, 128, 36) == step(4, 64, 64, 128, 2) <= 121 and step(4, 4, 16, 32, 3) <= 37
+
+
 def test_the_kernels_refuse_another_backend_without_interpret():
     args = _inputs(1, 8, 4, 16, 1, 32)
     with pytest.raises(RuntimeError, match="ssd_chunk needs a TPU backend"):
