@@ -107,8 +107,9 @@ def attention(h, lp, rope: dict, window: int, positions, allowed):
     s = jnp.einsum("bqhk,bthk->bhqt", q, k) / math.sqrt(q.shape[-1])
     p = jax.nn.softmax(jnp.where(allowed[:, None], s, -jnp.inf), axis=-1)
     a = jnp.einsum("bhqt,bthk->bqhk", p, v)
-    gate = jax.nn.sigmoid(h @ lp["wg"].astype(F32))  # [B,S,H]
-    return jnp.einsum("bshk,hkd->bsd", a * gate[..., None], lp["wo"].astype(F32))
+    if "wg" in lp:  # a tree without the gate's weight is a model without the gate (reference_window_softmax_moe.py)
+        a = a * jax.nn.sigmoid(h @ lp["wg"].astype(F32))[..., None]  # [B,S,H], one scalar a head
+    return jnp.einsum("bshk,hkd->bsd", a, lp["wo"].astype(F32))
 
 
 def routed_ffn(x, lp, model: dict, held=None, shared: bool = True):

@@ -183,13 +183,20 @@ class TransformerConfig:
     # best, the chip holds experts first_expert .. first_expert +
     # experts_held - 1 (each a SwiGLU of width expert_d_ff) and computes the
     # part of the result those give, beside n_shared_experts every token
-    # passes. experts_held = 0: the training form (_moe_ffn), every expert here.
+    # passes. Served, the layer hands out its [pairs, live tiles] counts;
+    # trained (_layer), it differentiates through the grouped matmul's custom
+    # VJP and hands out the balance term over all n_experts beside them.
+    # experts_held = 0: every expert here and every expert on every token
+    # (_moe_ffn), the form for a handful of experts.
     experts_held: int = 0
     first_expert: int = 0
     expert_d_ff: int = 0
     n_shared_experts: int = 0
     routed_scaling: float = 1.0
     router_score: str = "softmax"  # softmax | sigmoid, over the router's logits in float32
+    # The training loss is the mean NLL + router_aux_coef x the routed layers'
+    # balance terms summed (cross_entropy_loss).
+    router_aux_coef: float = 0.01
     # Held experts chosen by score + a bias a scored expert (a float32 leaf
     # "router_bias" [n_experts] a routed layer, no part of the weight) and
     # weighed by the score alone, s_e / (the chosen scores' sum + 1e-6). Off:
@@ -1008,7 +1015,10 @@ def _moe_ffn(x, p, cfg: TransformerConfig):
 
 
 def _load_balance_loss(weights, top_idx, n_experts):
-    """Switch-transformer aux loss: mean_prob * mean_assignment per expert."""
+    """Switch-transformer aux loss: mean_prob * mean_assignment per expert,
+    the assignment a token's FIRST choice alone: _moe_ffn's loss stays what
+    its configurations have trained on. A held layer's term counts every
+    choice (``_balance_term``)."""
     me = jnp.mean(weights, axis=(0, 1))  # [E]
     ce = jnp.mean(
         jax.nn.one_hot(top_idx[..., 0], n_experts, dtype=jnp.float32), axis=(0, 1)
@@ -1082,9 +1092,69 @@ def _expert_tile(tokens: int, cfg: TransformerConfig) -> int:
 
 
 PAIRS_A_PASS = 32_768  # (token, expert) pairs one pass of _held_experts_ffn lays out
+EXPERT_ROWS = "expert_rows"  # the name a training pass gives the grouped matmuls' results, for its remat policy
 
 
-def _held_experts_ffn(x, p, cfg: TransformerConfig):
+@jax.custom_vjp
+def _token_rows(xt, token_of_row, row_of_pair, held):
+    """xt[token_of_row], the rows a training pass lays out for the grouped
+    matmul, with a backward that gathers too: a token's cotangent is the sum
+    of its held pairs' rows' (``row_of_pair`` is the layout's inverse), where
+    autodiff would scatter-add 36,864 rows of 2304 into 4,096 (7.9 ms a layer
+    on a v5e where the gather takes 5.6; the other gather's scatter 14).
+    Padding rows (token 0's) are read by no pair."""
+    return xt[token_of_row]
+
+
+def _token_rows_fwd(xt, token_of_row, row_of_pair, held):
+    return xt[token_of_row], (row_of_pair, held)
+
+
+def _token_rows_bwd(res, g):
+    row_of_pair, held = res
+    mine = jnp.where(held[..., None], g[row_of_pair].astype(jnp.float32), 0.0)  # [T, K, D]
+    return jnp.sum(mine, axis=1).astype(g.dtype), None, None, None
+
+
+_token_rows.defvjp(_token_rows_fwd, _token_rows_bwd)
+
+
+@jax.custom_vjp
+def _pair_rows(y, row_of_pair, pair_of_row):
+    """y[row_of_pair] [T, K, D], a pair's result read back from its row, with
+    a backward that gathers: a row's cotangent is its pair's
+    (``pair_of_row`` [M], -1 for a row that holds none), zeros for padding.
+    A pair that is not held reads row 0 and is masked by its reader: its
+    cotangent is zero and is left out here."""
+    return y[row_of_pair]
+
+
+def _pair_rows_fwd(y, row_of_pair, pair_of_row):
+    return y[row_of_pair], pair_of_row
+
+
+def _pair_rows_bwd(pair_of_row, g):
+    rows = g.reshape(-1, g.shape[-1])[jnp.maximum(pair_of_row, 0)]
+    return jnp.where((pair_of_row >= 0)[:, None], rows, jnp.zeros((), g.dtype)), None, None
+
+
+_pair_rows.defvjp(_pair_rows_fwd, _pair_rows_bwd)
+
+
+def _balance_term(chosen, score_sum, tokens: int, cfg: TransformerConfig):
+    """n_experts x sum_i f_i P_i over ALL n_experts: f_i the share of the
+    tokens' (token, choice) pairs that chose expert i, every one of the
+    expert_top_k choices counted (``chosen`` [n_experts], the pairs on each),
+    P_i the mean over the tokens of the router's normalised score of i
+    (``score_sum`` [n_experts], its sum over them): 1 where the routing is
+    uniform. The gradient is P's: a count has none. Every chip of an
+    expert-parallel deployment computes it whole for its own tokens, since
+    the router is whole everywhere."""
+    f = chosen / (tokens * cfg.expert_top_k)
+    return cfg.n_experts * jnp.sum(f * score_sum / tokens)
+
+
+def _held_experts_ffn(x, p, cfg: TransformerConfig, balance: bool = False):
     """``_held_experts_pass`` over x [B, S, D], a prompt of many tokens in
     passes of at most PAIRS_A_PASS pairs: the rows laid out for the grouped
     matmul hold every pair a pass could send here (all of them), so one pass
@@ -1093,19 +1163,53 @@ def _held_experts_ffn(x, p, cfg: TransformerConfig):
     in that prefill program, as compiled for a v5e). The tokens are padded
     with zero rows to whole passes, whose results are dropped; the counts are
     summed over the passes (padding's pairs among them: counts are read of
-    decode steps, which are one pass)."""
+    decode steps, which are one pass).
+    ``balance`` (a layer that trains): the second result is float32 [3], the
+    balance term over all experts and all of the B x S tokens (the passes'
+    sums joined before the product, padding left out), then the two counts."""
     B, S, D = x.shape
     T = B * S
     size = 1 << ((PAIRS_A_PASS // cfg.expert_top_k).bit_length() - 1)  # tokens a pass, a power of two
+    a_pass = _held_experts_pass
+    if balance:
+        if p["w_gate"].ndim == 4:
+            # Float32 stacks against bfloat16 rows: THIS layer's experts cast once a layer pass, [1, E, ...] handed
+            # to the passes as a stack of one (0.20 GB of bfloat16 at 16 experts of 2304 x 896, there while the layer
+            # runs; 0.6 GB of traffic a layer pass, forward and made again at 4 layers 4.8 GB a step: 6 ms of a
+            # v5e's bandwidth). The grouped matmul then streams 2 bytes a parameter each of the dozen times a step a
+            # tile reads its expert, and the passes' loop sums a gradient of one layer's shape, which autodiff
+            # widens and lays into the stack's once. The two other places for the cast: float32 blocks cast in VMEM
+            # keep no copy and double every one of those reads (23 ms a step at this size); one copy of all the
+            # held stacks a step (0.79 GB, 3 ms) makes each layer's loop over passes carry a gradient of the whole
+            # stack's shape: that step compiled to 17.06 GB for a v5e beside two rows, this one to 14.28.
+            layer = {k: lax.dynamic_index_in_dim(p[k], p["expert_layer"], 0, keepdims=True).astype(x.dtype)
+                     for k in HELD_EXPERT_WEIGHTS}
+            p = {**p, **layer, "expert_layer": 0}
+        # What a pass keeps for its backward is its tokens, the rows gathered for the grouped matmul, the gate's and
+        # the up product's rows and the pairs' results read back (EXPERT_ROWS: 0.45 GB a pass of 4,096 tokens at
+        # widths 2304 / 896); the routing, the row plan, the hidden rows and the weighted pairs in float32 (0.37 GB
+        # more) are made again from them, and neither a product nor a gather is: a layer's four passes gather
+        # 36,864 rows of 2304 each in 5.6 ms on a v5e, twice what its three products take (PERF.md section 6, PR 59).
+        a_pass = jax.checkpoint(_held_experts_pass, static_argnums=(2, 3),
+                                policy=jax.checkpoint_policies.save_only_these_names(EXPERT_ROWS))
     if T <= size:
-        return _held_experts_pass(x, p, cfg)
-    n = -(-T // size)
-    xt = jnp.pad(x.reshape(T, D), ((0, n * size - T), (0, 0))).reshape(n, 1, size, D)
-    out, counts = lax.map(lambda chunk: _held_experts_pass(chunk, p, cfg), xt)
-    return out.reshape(n * size, D)[:T].reshape(B, S, D), jnp.sum(counts, axis=0)
+        out, counts, *stats = a_pass(x, p, cfg, balance)
+    else:
+        n = -(-T // size)
+        xt = jnp.pad(x.reshape(T, D), ((0, n * size - T), (0, 0))).reshape(n, 1, size, D)
+        if balance:
+            real = (jnp.arange(n * size) < T).astype(jnp.float32).reshape(n, size)
+            out, counts, *stats = lax.map(lambda chunk: a_pass(chunk[0], p, cfg, True, chunk[1]), (xt, real))
+        else:
+            out, counts, *stats = lax.map(lambda chunk: a_pass(chunk, p, cfg), xt)
+        out = out.reshape(n * size, D)[:T].reshape(B, S, D)
+        counts, stats = jnp.sum(counts, axis=0), [jnp.sum(s, axis=0) for s in stats]
+    if not balance:
+        return out, counts
+    return out, jnp.stack([_balance_term(*stats, T, cfg), *counts.astype(jnp.float32)])
 
 
-def _held_experts_pass(x, p, cfg: TransformerConfig):
+def _held_experts_pass(x, p, cfg: TransformerConfig, balance: bool = False, real=None):
     """A routed FFN as the chip that holds experts first_expert ..
     first_expert + experts_held - 1 serves it. Every token is scored over all
     n_experts (router logits in float32) and takes its expert_top_k best (by
@@ -1116,7 +1220,15 @@ def _held_experts_pass(x, p, cfg: TransformerConfig):
     absent experts would have added is left out. Beside it the shared expert,
     which every token passes.
     Returns (out [B,S,D], int32 [2]: the pairs on held experts, and the live
-    tiles of the grouped matmul, each of which reads its expert's matrices)."""
+    tiles of the grouped matmul, each of which reads its expert's matrices).
+    ``balance``: two more, float32 [n_experts] each, for ``_balance_term``:
+    the pairs that chose each of ALL the experts, and the tokens' normalised
+    scores summed, over the tokens ``real`` [B x S] weighs 1 (None: all).
+    Differentiable: through the normalised weights to the router, through the
+    two gathers to x, through the grouped matmuls to the experts; the row
+    plan is whole numbers and has no cotangent."""
+    from jax.ad_checkpoint import checkpoint_name
+
     from ray_tpu.ops.grouped_matmul import expert_matmul, group_rows
 
     B, S, D = x.shape
@@ -1139,22 +1251,40 @@ def _held_experts_pass(x, p, cfg: TransformerConfig):
         # One layer's matrices [E, ...] (layer 0 of a stack of one: a free
         # reshape), or the whole stack with this layer's index (scan_stack).
         stacked = p["w_gate"].ndim == 4
-        gmm = functools.partial(
+        product = functools.partial(
             expert_matmul(),
             layer=p["expert_layer"] if stacked else 0,
             tile_expert=plan.tile_expert, n_tiles=plan.n_tiles, tm=tm)
+        # a pass that trains names what its remat policy keeps (_held_experts_ffn): the gathered rows, the gate's
+        # and the up product's rows, the pairs' results read back
+        kept = (lambda a: checkpoint_name(a, EXPERT_ROWS)) if balance else (lambda a: a)
+        gmm = lambda rows, w: kept(product(rows, w))  # noqa: E731
         w_gate, w_up, w_down = (p[k] if stacked else p[k][None] for k in HELD_EXPERT_WEIGHTS)
-        xs = xt[plan.token_of_row]  # [M, D]
+        if balance:  # the two gathers with backwards that gather (_token_rows, _pair_rows); the layout's inverse
+            M = plan.token_of_row.shape[0]
+            pair_of_row = jnp.full(M, -1, jnp.int32).at[jnp.where(plan.held, plan.row_of_pair, M).reshape(-1)].set(
+                jnp.arange(top_e.size, dtype=jnp.int32), mode="drop", unique_indices=True)
+            xs = kept(_token_rows(xt, plan.token_of_row, plan.row_of_pair, plan.held))
+        else:
+            xs = xt[plan.token_of_row]  # [M, D]
         h = _swiglu_product(lambda w: gmm(xs, w), w_gate, w_up, cfg.swiglu_limit)
-        y = gmm(h, w_down)  # [M, D]; rows past the live tiles hold nothing
-        pair = y[plan.row_of_pair].astype(jnp.float32) * top_w[..., None]  # [T, K, D]
+        y = product(h, w_down)  # [M, D]; rows past the live tiles hold nothing
+        picked = kept(_pair_rows(y, plan.row_of_pair, pair_of_row)) if balance else y[plan.row_of_pair]
+        pair = picked.astype(jnp.float32) * top_w[..., None]  # [T, K, D]
         routed = jnp.sum(jnp.where(plan.held[..., None], pair, 0.0), axis=1)
         routed = routed.astype(dt).reshape(B, S, D)
     with jax.named_scope("experts/shared"):
         if cfg.n_shared_experts:
             shared = {"w_gate": p["ws_gate"], "w_up": p["ws_up"], "w_down": p["ws_down"]}
             routed = routed + _dense_ffn(x, shared, cfg.swiglu_limit)
-    return routed, jnp.stack([jnp.sum(plan.sizes), plan.n_tiles[0]]).astype(jnp.int32)
+    counts = jnp.stack([jnp.sum(plan.sizes), plan.n_tiles[0]]).astype(jnp.int32)
+    if not balance:
+        return routed, counts
+    with jax.named_scope("experts/balance"):
+        real = jnp.ones(B * S, jnp.float32) if real is None else real
+        share = score / jnp.sum(score, axis=-1, keepdims=True) if cfg.router_score == "sigmoid" else score
+        chosen = jnp.sum(jax.nn.one_hot(top_e, cfg.n_experts, dtype=jnp.float32) * real[:, None, None], axis=(0, 1))
+    return routed, counts, chosen, jnp.sum(share * real[:, None], axis=0)
 
 
 def _delta_mixer(h, lp, cfg: TransformerConfig, kind: LayerKind, attend):
@@ -1300,7 +1430,8 @@ def _conv_mixer(h, lp, cfg: TransformerConfig, kind: LayerKind, attend):
     return y, keep(window)
 
 
-def decoder_block(x, lp, cfg: TransformerConfig, positions, attend, kind: LayerKind | None = None):
+def decoder_block(x, lp, cfg: TransformerConfig, positions, attend, kind: LayerKind | None = None,
+                  balance: bool = False):
     """The one decoder block that training, prefill and decode all run: what
     the model is (norms, projections, rope, the attention's and the FFN's
     kind) lives here, what a program does with what a layer caches is its
@@ -1321,7 +1452,8 @@ def decoder_block(x, lp, cfg: TransformerConfig, positions, attend, kind: LayerK
     rows, the carried pools, None). A recurrent kind's ``attend`` is a pair
     (``_delta_mixer``, ``_ssd_mixer`` and ``_conv_mixer`` say of what).
     Returns (x, aux, kept): aux is the MoE balance term of a training layer, a zero for a dense one, and the
-    [pairs, live tiles] counts of a layer that serves held experts."""
+    [pairs, live tiles] counts of a layer that serves held experts; with ``balance`` (a layer that trains) a
+    layer of held experts hands out float32 [balance term, pairs, live tiles] (``_held_experts_ffn``)."""
     dt = x.dtype
     kind = kind or cfg.kinds[0]
     norm = functools.partial(model_norm, cfg=cfg)
@@ -1377,7 +1509,7 @@ def decoder_block(x, lp, cfg: TransformerConfig, positions, attend, kind: LayerK
         if "router" not in lp:
             ffn_out, aux = _dense_ffn(h, lp, cfg.swiglu_limit), jnp.zeros((), jnp.float32)
         elif cfg.experts_held:
-            ffn_out, aux = _held_experts_ffn(h, lp, cfg)
+            ffn_out, aux = _held_experts_ffn(h, lp, cfg, balance)
         else:
             ffn_out, aux = _moe_ffn(h, lp, cfg)
         if cfg.sandwich_norm:
@@ -1406,10 +1538,13 @@ def _whole_sequence_rule(ops, _window, cfg: TransformerConfig, kind: LayerKind):
     return (chunk if _runs_kernels(cfg) else chunk_reference)(*ops, out_dtype=cfg.dtype)[0], None
 
 
-def _layer(x, lp, cfg: TransformerConfig, positions, segment_ids=None, kind: LayerKind | None = None):
-    """The block as training runs it: attention over the layer's own K/V by
+def _layer(x, lp, cfg: TransformerConfig, positions, segment_ids=None, kind: LayerKind | None = None,
+           stats: bool = False):
+    """The block over whole sequences: attention over the layer's own K/V by
     the configured implementation (inside the kind's window where it has
-    one), nothing kept. x: [B, S, D] in cfg.dtype."""
+    one), nothing kept. x: [B, S, D] in cfg.dtype. Returns (x, the layer's
+    balance term), or with ``stats``, as the training loss asks, (x, float32
+    [balance term, pairs on held experts, live tiles])."""
     kind = kind or cfg.kinds[0]
 
     def attend(q, k, v):
@@ -1427,15 +1562,19 @@ def _layer(x, lp, cfg: TransformerConfig, positions, segment_ids=None, kind: Lay
                 "have to start again at each document's first position (ROADMAP M4)")
         attend = (None, functools.partial(_whole_sequence_rule, cfg=cfg, kind=kind) if kind.state
                   else lambda _window: None)
-    x, aux, _ = decoder_block(x, lp, cfg, positions, attend, kind)
-    # A layer that serves held experts hands out counts, not a loss term.
+    x, aux, _ = decoder_block(x, lp, cfg, positions, attend, kind, balance=stats)
+    if stats:  # a layer of held experts hands out all three; a layer of _moe_ffn or a dense one its term and no counts
+        return x, (aux if aux.ndim else jnp.pad(aux[None], (0, 2)))
+    # Outside the training loss a layer of held experts hands out counts, not a loss term.
     return x, (jnp.zeros((), jnp.float32) if cfg.experts_held else aux)
 
 
-def forward_hidden(params: dict, tokens: jax.Array, cfg: TransformerConfig,
-                   segment_ids=None, positions=None):
-    """tokens [B, S] int32 -> (final-norm hidden states [B, S, D], moe_aux).
-    The shared trunk of forward() and the chunked-CE training loss."""
+def _hidden_and_stats(params: dict, tokens: jax.Array, cfg: TransformerConfig, segment_ids=None, positions=None,
+                      stats: bool = True):
+    """tokens [B, S] int32 -> (final-norm hidden states [B, S, D], float32 [3]:
+    the routed layers' balance terms, pairs on held experts and live tiles,
+    each summed over the layers; without ``stats`` the balance terms of the
+    layers of _moe_ffn alone, a scalar: ``forward_hidden``)."""
     B, S = tokens.shape
     x = embed_tokens(params, tokens, cfg)
     x = wlc(x, ("batch", "seq", "embed"))
@@ -1443,7 +1582,7 @@ def forward_hidden(params: dict, tokens: jax.Array, cfg: TransformerConfig,
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
 
     def body_of(kind):
-        body = functools.partial(_layer, cfg=cfg, positions=positions, segment_ids=segment_ids, kind=kind)
+        body = functools.partial(_layer, cfg=cfg, positions=positions, segment_ids=segment_ids, kind=kind, stats=stats)
         if not cfg.remat:
             return body
         if cfg.remat_policy == "dots":
@@ -1453,8 +1592,15 @@ def forward_hidden(params: dict, tokens: jax.Array, cfg: TransformerConfig,
         raise ValueError(f"unknown remat_policy {cfg.remat_policy!r} (full|dots)")
 
     x, auxes = run_layers(lambda h, lp, kind, _index: body_of(kind)(h, lp), x, params, cfg)
-    aux = functools.reduce(lambda a, b: a + b, [jnp.sum(a) for a in auxes.values()])
+    aux = functools.reduce(lambda a, b: a + b, [jnp.sum(a, axis=0) if stats else jnp.sum(a) for a in auxes.values()])
     return model_norm(x, params["final_norm"], cfg), aux
+
+
+def forward_hidden(params: dict, tokens: jax.Array, cfg: TransformerConfig,
+                   segment_ids=None, positions=None):
+    """tokens [B, S] int32 -> (final-norm hidden states [B, S, D], moe_aux).
+    The shared trunk of forward() and the chunked-CE training loss."""
+    return _hidden_and_stats(params, tokens, cfg, segment_ids, positions, stats=False)
 
 
 def forward(params: dict, tokens: jax.Array, cfg: TransformerConfig,
@@ -1581,10 +1727,13 @@ def head_loss(params: dict, x, targets, mask, cfg: TransformerConfig):
         return _head_loss(x, head, targets, mask, chunk, float(cfg.logits_divisor), dims)
 
 
-def cross_entropy_loss(params, batch, cfg: TransformerConfig):
+def loss_and_metrics(params, batch, cfg: TransformerConfig):
     """batch: {"tokens": [B, S+1] int32, optional "mask"/"segment_ids"/
-    "positions"} -> scalar mean NLL (+ MoE aux). segment_ids enable packed-
-    sequence training (attention + loss respect example boundaries)."""
+    "positions"} -> (scalar mean NLL + router_aux_coef x the routed layers'
+    balance terms, {"nll", "balance_loss" (the terms summed, before the
+    coefficient), "expert_pairs", "expert_live_tiles" (held experts' counts
+    summed over the routed layers; 0 without any)}). segment_ids enable
+    packed-sequence training (attention + loss respect example boundaries)."""
     tokens = batch["tokens"]
     inputs, targets = tokens[:, :-1], tokens[:, 1:]
     segs = batch.get("segment_ids")
@@ -1595,12 +1744,19 @@ def cross_entropy_loss(params, batch, cfg: TransformerConfig):
         # composes with any provided padding mask.
         boundary = (segs[:, 1:] == segs[:, :-1]).astype(jnp.float32)
         mask = boundary if mask is None else mask * boundary
-    x, aux = forward_hidden(
+    x, stats = _hidden_and_stats(
         params, inputs, cfg,
         segment_ids=None if segs is None else segs[:, :-1],
         positions=None if pos is None else pos[:, :-1],
     )
-    return head_loss(params, x, targets, mask, cfg) + 0.01 * aux
+    nll = head_loss(params, x, targets, mask, cfg)
+    return nll + cfg.router_aux_coef * stats[0], {
+        "nll": nll, "balance_loss": stats[0], "expert_pairs": stats[1], "expert_live_tiles": stats[2]}
+
+
+def cross_entropy_loss(params, batch, cfg: TransformerConfig):
+    """``loss_and_metrics``' loss alone."""
+    return loss_and_metrics(params, batch, cfg)[0]
 
 
 # ---------------------------------------------------------------------------
@@ -1622,7 +1778,7 @@ def make_train_step(cfg: TransformerConfig, optimizer=None):
         return {"params": params, "opt": optimizer.init(params), "step": jnp.zeros((), jnp.int32)}
 
     def train_step(state, batch):
-        loss, grads = jax.value_and_grad(cross_entropy_loss)(
+        (loss, metrics), grads = jax.value_and_grad(loss_and_metrics, has_aux=True)(
             state["params"], batch, cfg
         )
         updates, opt = optimizer.update(grads, state["opt"], state["params"])
@@ -1630,7 +1786,7 @@ def make_train_step(cfg: TransformerConfig, optimizer=None):
         gnorm = optax.global_norm(grads)
         return (
             {"params": params, "opt": opt, "step": state["step"] + 1},
-            {"loss": loss, "grad_norm": gnorm, "step": state["step"] + 1},
+            {"loss": loss, **metrics, "grad_norm": gnorm, "step": state["step"] + 1},
         )
 
     def state_logical_axes(state):

@@ -53,6 +53,9 @@ from jax import lax
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 512
 NEG_INF = -1e30
+# A windowed call's three kernels carry this behind their names (`flash_attn_fwd_win`), so that a trace of a
+# model with window and full layers tells the two kinds of call apart.
+_WINDOWED = "_win"
 
 
 # ---------------------------------------------------------------------------
@@ -455,7 +458,7 @@ def _fwd_pallas(q, k, v, seg, *, causal, scale, block_q, block_k, group, H, inte
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-        name="flash_attn_fwd",
+        name="flash_attn_fwd" + _WINDOWED * bool(window),
     )(*tables, *inputs)
 
 
@@ -670,7 +673,7 @@ def _bwd_pallas(res, g, *, causal, scale, block_q, block_k, group, H, KV, interp
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-        name="flash_attn_dkv",
+        name="flash_attn_dkv" + _WINDOWED * bool(window),
     )(*q_tables, *dkv_inputs)
     dk, dv = dkv
 
@@ -703,7 +706,7 @@ def _bwd_pallas(res, g, *, causal, scale, block_q, block_k, group, H, KV, interp
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-        name="flash_attn_dq",
+        name="flash_attn_dq" + _WINDOWED * bool(window),
     )(*k_tables, *dq_inputs)
     return dq, dk, dv
 

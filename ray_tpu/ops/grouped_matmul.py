@@ -1,9 +1,10 @@
 """Grouped matmul over the experts a chip holds: rows sorted by expert, each
 row multiplied by its own expert's matrix.
 
-The serving path of a routed FFN (models/transformer.py ``_held_experts_ffn``)
-sorts its (token, expert) pairs by expert and multiplies each group by that
-expert's weights: none dropped, whatever the imbalance. ``group_rows`` lays the
+A routed FFN over held experts (models/transformer.py ``_held_experts_ffn``,
+served and trained) sorts its (token, expert) pairs by expert and multiplies
+each group by that expert's weights: none dropped, whatever the imbalance.
+``group_rows`` lays the
 pairs out so that every tile of ``tm`` rows belongs to one expert (a group
 starts on a tile boundary; the rows between a group's end and the next
 boundary are padding nobody reads back), and ``expert_gmm`` walks the live
@@ -13,6 +14,14 @@ weights are read once a tile of its rows.
 Kernel shape: grid (live tiles, N blocks, K blocks), the first a runtime
 value; the tile's expert comes through scalar prefetch and picks the weight
 block; f32 accumulator in VMEM across the K blocks.
+
+Trained, ``expert_gmm`` has a custom VJP: dx is the same walk with the weight
+block contracted over its columns (``expert_gmm_dx``: dy of a tile times its
+expert's matrix transposed, rows of dead tiles zeros), and dW is a second
+kernel (``expert_tgmm``: grid (K blocks, N blocks, live tiles), an expert's
+consecutive tiles' x^T dy summed in float32 in VMEM and written once an
+expert, zeros for an expert no pair chose). ``expert_gmm_reference`` is
+differentiated by JAX and gives the same gradients.
 """
 from __future__ import annotations
 
@@ -22,6 +31,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 
 logger = logging.getLogger(__name__)
@@ -120,7 +130,7 @@ def expert_matmul():
 # Pallas kernel
 # ---------------------------------------------------------------------------
 
-def _gmm_kernel(layer_ref, tile_expert_ref, x_ref, w_ref, o_ref, acc_scr, *, n_k):
+def _gmm_kernel(layer_ref, tile_expert_ref, x_ref, w_ref, o_ref, acc_scr, *, n_k, transposed=False):
     from jax.experimental import pallas as pl
 
     k = pl.program_id(2)
@@ -129,7 +139,11 @@ def _gmm_kernel(layer_ref, tile_expert_ref, x_ref, w_ref, o_ref, acc_scr, *, n_k
     def _init():
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    acc_scr[...] += jnp.dot(x_ref[...], w_ref[...], preferred_element_type=jnp.float32)
+    if transposed:  # the block [tk, tn] as it lies, contracted over its columns
+        acc_scr[...] += lax.dot_general(x_ref[...], w_ref[...], (((1,), (1,)), ((), ())),
+                                        preferred_element_type=jnp.float32)
+    else:
+        acc_scr[...] += jnp.dot(x_ref[...], w_ref[...], preferred_element_type=jnp.float32)
 
     @pl.when(k == n_k - 1)
     def _store():
@@ -147,6 +161,140 @@ def _block(dim: int, target: int) -> int:
 
 
 # one trace a shape signature: ops/__init__.py
+@functools.partial(jax.jit, inline=True, static_argnames=("tm", "block_k", "block_n", "interpret", "transposed"))
+def _gmm_call(x, w, layer, tile_expert, n_tiles, *, tm, block_k, block_n, interpret, transposed=False):
+    """One grouped product over the live tiles. Forward: x [M, K] times
+    w[layer, e] [K, N]. ``transposed``: x is a cotangent [M, N] and the weight
+    block is read as it lies and contracted over its columns, x times
+    w[layer, e]^T -> [M, K] (``expert_gmm_dx``): the same walk, the same DMAs."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    M = x.shape[0]
+    K, N = w.shape[2], w.shape[3]
+    tk, tn = _block(K, block_k), _block(N, block_n)
+    if transposed:  # grid (tiles, K blocks of the result, N blocks summed over)
+        blocks, n_sum, out_cols, out_block = (K // tk, N // tn), N // tn, K, tk
+        x_spec = pl.BlockSpec((tm, tn), lambda i, k, j, layer, te: (i, j))
+        w_spec = pl.BlockSpec((None, None, tk, tn), lambda i, k, j, layer, te: (layer[0], te[i], k, j))
+        out_spec = pl.BlockSpec((tm, tk), lambda i, k, j, layer, te: (i, k))
+    else:  # grid (tiles, N blocks of the result, K blocks summed over)
+        blocks, n_sum, out_cols, out_block = (N // tn, K // tk), K // tk, N, tn
+        x_spec = pl.BlockSpec((tm, tk), lambda i, j, k, layer, te: (i, k))
+        w_spec = pl.BlockSpec((None, None, tk, tn), lambda i, j, k, layer, te: (layer[0], te[i], k, j))
+        out_spec = pl.BlockSpec((tm, tn), lambda i, j, k, layer, te: (i, j))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(n_tiles[0], *blocks),  # the first a runtime value
+        in_specs=[x_spec, w_spec],
+        out_specs=out_spec,
+        scratch_shapes=[pltpu.VMEM((tm, out_block), jnp.float32)],
+    )
+    return pl.pallas_call(
+        functools.partial(_gmm_kernel, n_k=n_sum, transposed=transposed),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((M, out_cols), x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=48 * 1024 * 1024,  # two weight blocks in flight, beside the tiles
+        ),
+        interpret=interpret,
+        name="expert_gmm_dx" if transposed else "expert_gmm",
+    )(jnp.asarray(layer, jnp.int32).reshape(1), tile_expert, x, w)
+
+
+def _tgmm_kernel(tile_expert_ref, n_tiles_ref, x_ref, dy_ref, o_ref, acc_scr):
+    """One tile's x^T dy added to its expert's sum, which is stored when the
+    expert's last tile has been added: the tiles of an expert are consecutive."""
+    from jax.experimental import pallas as pl
+
+    t = pl.program_id(2)
+    mine = tile_expert_ref[t]
+    last = n_tiles_ref[0] - 1
+
+    @pl.when((t == 0) | (tile_expert_ref[jnp.maximum(t - 1, 0)] != mine))
+    def _init():
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    acc_scr[...] += lax.dot_general(x_ref[...], dy_ref[...], (((0,), (0,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+
+    @pl.when((t == last) | (tile_expert_ref[jnp.minimum(t + 1, last)] != mine))
+    def _store():
+        o_ref[...] = acc_scr[...].astype(o_ref.dtype)
+
+
+# one trace a shape signature: ops/__init__.py
+@functools.partial(jax.jit, inline=True, static_argnames=("n_experts", "tm", "block_k", "block_n", "interpret"))
+def expert_tgmm(x, dy, tile_expert, n_tiles, n_experts: int, *, tm, block_k, block_n, interpret):
+    """dW [E, K, N] of one layer: expert e's x_tile^T dy_tile summed over its
+    live tiles, a float32 sum in VMEM across them, written once an expert. An
+    expert with no live tile is not written: the caller lays zeros there."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    K, N = x.shape[1], dy.shape[1]
+    tk, tn = _block(K, block_k), _block(N, block_n)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(K // tk, N // tn, n_tiles[0]),  # the last a runtime value: an expert's tiles follow one another
+        in_specs=[
+            pl.BlockSpec((tm, tk), lambda k, j, t, te, nt: (t, k)),
+            pl.BlockSpec((tm, tn), lambda k, j, t, te, nt: (t, j)),
+        ],
+        out_specs=pl.BlockSpec((None, tk, tn), lambda k, j, t, te, nt: (te[t], k, j)),
+        scratch_shapes=[pltpu.VMEM((tk, tn), jnp.float32)],
+    )
+    return pl.pallas_call(
+        _tgmm_kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((n_experts, K, N), x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=48 * 1024 * 1024,
+        ),
+        interpret=interpret,
+        name="expert_tgmm",
+    )(tile_expert, n_tiles, x, dy)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _gmm(x, w, layer, tile_expert, n_tiles, tm, block_k, block_n, interpret):
+    return _gmm_call(x, w, layer, tile_expert, n_tiles, tm=tm, block_k=block_k, block_n=block_n, interpret=interpret)
+
+
+def _gmm_fwd(x, w, layer, tile_expert, n_tiles, tm, block_k, block_n, interpret):
+    y = _gmm_call(x, w, layer, tile_expert, n_tiles, tm=tm, block_k=block_k, block_n=block_n, interpret=interpret)
+    return y, (x, w, layer, tile_expert, n_tiles)
+
+
+def _gmm_bwd(tm, block_k, block_n, interpret, res, dy):
+    """dx over the live tiles through the expert's matrix transposed, the rows
+    of dead tiles zeros (the kernel leaves them unwritten, and a caller's
+    gather that laid the rows out may scatter every row back, token 0's
+    padding among them); dW an expert at a time over its tiles, zeros for an expert none
+    chose, laid into zeros of the whole stack's shape at this layer (XLA adds
+    that slice to the layer loop's running sum in place: it adds no stack).
+    A padding row inside a live tile holds token 0's row going in and a zero
+    cotangent coming back, since nothing read its result: it adds nothing."""
+    x, w, layer, tile_expert, n_tiles = res
+    blocks = dict(tm=tm, block_k=block_k, block_n=block_n, interpret=interpret)
+    live = jnp.arange(x.shape[0], dtype=jnp.int32) < n_tiles[0] * tm
+    dx = _gmm_call(dy, w, layer, tile_expert, n_tiles, transposed=True, **blocks)
+    dx = jnp.where(live[:, None], dx, jnp.zeros((), dx.dtype))
+    E = w.shape[1]
+    tile = jnp.arange(tile_expert.shape[0], dtype=jnp.int32)
+    chosen = jnp.zeros(E, jnp.bool_).at[jnp.where(tile < n_tiles[0], tile_expert, E)].set(True, mode="drop")
+    dw = expert_tgmm(x, dy, tile_expert, n_tiles, E, **blocks)
+    dw = jnp.where(chosen[:, None, None], dw, jnp.zeros((), dw.dtype)).astype(w.dtype)
+    dw = lax.dynamic_update_index_in_dim(jnp.zeros(w.shape, w.dtype), dw, jnp.asarray(layer, jnp.int32).reshape(()), 0)
+    return dx, dw, None, None, None
+
+
+_gmm.defvjp(_gmm_fwd, _gmm_bwd)
+
+
+# one trace a shape signature: ops/__init__.py
 @functools.partial(jax.jit, inline=True, static_argnames=("tm", "block_k", "block_n", "interpret"))
 def expert_gmm(x, w, layer, tile_expert, n_tiles, *, tm, block_k=2048, block_n=1024, interpret=False):
     """The grouped matmul (the Pallas kernel; arguments as
@@ -156,36 +304,15 @@ def expert_gmm(x, w, layer, tile_expert, n_tiles, *, tm, block_k=2048, block_n=1
     whole stack and no slice of it.
     Weight blocks of up to block_k x block_n (3.9 MB of bf16 at the defaults) keep
     the stream of an expert's matrix in few, large DMAs. Runs on a TPU
-    backend, or anywhere with interpret=True, and raises elsewhere."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
+    backend, or anywhere with interpret=True, and raises elsewhere.
+    Differentiable in x and w (``_gmm_bwd``: ``expert_gmm_dx`` and
+    ``expert_tgmm``); w's gradient has the shape of the stack handed over and
+    x's dtype, so a caller whose parameters are wider than its rows casts
+    what it hands over once, outside the loop of its calls, and autodiff
+    widens the gradient once (models/transformer.py ``_held_experts_ffn``)."""
     if not interpret and jax.default_backend() != "tpu":
         raise RuntimeError(
             f"expert_gmm needs a TPU backend (or interpret=True); this process runs on "
             f"{jax.default_backend()!r}"
         )
-    M, K = x.shape
-    N = w.shape[3]
-    tk, tn = _block(K, block_k), _block(N, block_n)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(n_tiles[0], N // tn, K // tk),  # the first a runtime value
-        in_specs=[
-            pl.BlockSpec((tm, tk), lambda i, j, k, layer, te: (i, k)),
-            pl.BlockSpec((None, None, tk, tn), lambda i, j, k, layer, te: (layer[0], te[i], k, j)),
-        ],
-        out_specs=pl.BlockSpec((tm, tn), lambda i, j, k, layer, te: (i, j)),
-        scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)],
-    )
-    return pl.pallas_call(
-        functools.partial(_gmm_kernel, n_k=K // tk),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
-            vmem_limit_bytes=48 * 1024 * 1024,  # two weight blocks in flight, beside the tiles
-        ),
-        interpret=interpret,
-        name="expert_gmm",
-    )(jnp.asarray(layer, jnp.int32).reshape(1), tile_expert, x, w.astype(x.dtype))
+    return _gmm(x, w.astype(x.dtype), layer, tile_expert, n_tiles, tm, block_k, block_n, interpret)

@@ -328,9 +328,10 @@ def test_the_flash_bodies_stay_small(blocks, window, with_ids):
     """The train cell's call (blocks of 1024: four sub-tiles of 512 a block, ids) and the serve cells' (blocks of
     512, one sub-tile; a window or none)."""
     found = _flash_bodies(blocks, window, with_ids)
-    assert set(found) == set(FLASH_BODIES)
+    suffix = "_win" if window else ""  # a windowed call's kernels say so in their names
+    assert set(found) == {name + suffix for name in FLASH_BODIES}
     for name, n in found.items():
-        assert n <= FLASH_BODIES[name], (name, n)
+        assert n <= FLASH_BODIES[name.removesuffix(suffix) if suffix else name], (name, n)
 
 
 def test_the_flash_bodies_do_not_grow_with_the_sub_tiles_of_a_block(monkeypatch):

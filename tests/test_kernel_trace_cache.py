@@ -123,6 +123,13 @@ def _gmm(ops, index):
     return grouped_matmul.expert_gmm(x, w, index, tile_expert, n_tiles, tm=8, interpret=True)
 
 
+def _gmm_grads(ops, index):
+    x, w, tile_expert = ops
+    n_tiles = jnp.reshape(index + 1, 1).astype(jnp.int32)
+    return jax.grad(lambda x, w: jnp.sum(grouped_matmul.expert_gmm(x, w, index, tile_expert, n_tiles, tm=8,
+                                                                    interpret=True)), argnums=(0, 1))(x, w)
+
+
 def _paged_operands(size, window=0):
     B, H, KV, D, ps, n_pages = 2 * size, 4, 2, 128, 16, 4
     pages = B * paged_attention.ring_pages(window, ps) if window else 1 + B * n_pages
@@ -151,6 +158,7 @@ def _latent(ops, index):
 
 
 _FLASH = (attention, ("_fwd_pallas", "_bwd_pallas"), _flash_operands)
+_GMM = (grouped_matmul, ("expert_gmm", "_gmm_call", "expert_tgmm"), _gmm_operands)
 WINDOW = 24
 SITES = [
     Site("flash_attn_fwd", *_FLASH, _flash),
@@ -161,7 +169,9 @@ SITES = [
     Site("kda_chunk", linear_attention, ("kda_chunk",), _kda_chunk_operands, _kda_chunk),
     Site("delta_prep", linear_attention, ("delta_prep",), _prep_operands, _delta_prep),
     Site("kda_step", linear_attention, ("kda_step",), _kda_step_operands, _kda_step),
-    Site("expert_gmm", grouped_matmul, ("expert_gmm",), _gmm_operands, _gmm),
+    Site("expert_gmm", *_GMM, _gmm),
+    Site("expert_gmm_dx", *_GMM, _gmm_grads),  # the custom VJP's two kernels
+    Site("expert_tgmm", *_GMM, _gmm_grads),
     Site("paged_attn", paged_attention, ("_paged_pallas",), _paged_operands, _paged(0)),
     # the paged site again under its other name: a window is a static, and a layer kind of its own
     Site("window_attn", paged_attention, ("_paged_pallas",), lambda size: _paged_operands(size, WINDOW),
