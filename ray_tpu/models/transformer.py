@@ -1095,50 +1095,161 @@ PAIRS_A_PASS = 32_768  # (token, expert) pairs one pass of _held_experts_ffn lay
 EXPERT_ROWS = "expert_rows"  # the name a training pass gives the grouped matmuls' results, for its remat policy
 
 
-@jax.custom_vjp
-def _token_rows(xt, token_of_row, row_of_pair, held):
-    """xt[token_of_row], the rows a training pass lays out for the grouped
-    matmul, with a backward that gathers too: a token's cotangent is the sum
-    of its held pairs' rows' (``row_of_pair`` is the layout's inverse), where
-    autodiff would scatter-add 36,864 rows of 2304 into 4,096 (7.9 ms a layer
-    on a v5e where the gather takes 5.6; the other gather's scatter 14).
-    Padding rows (token 0's) are read by no pair."""
-    return xt[token_of_row]
+LAYOUT_CHUNK = 2048  # rows a step of the way back's loop over live rows moves: whole tiles (a power of two up to 256)
+LEVEL_CHUNK = 512  # tokens a step of a sum by token gathers for
 
 
-def _token_rows_fwd(xt, token_of_row, row_of_pair, held):
-    return xt[token_of_row], (row_of_pair, held)
+def _layout_chunk(rows: int, cfg: TransformerConfig) -> int:
+    """Whether the copies between token space and the grouped matmul's row
+    space follow the pairs held (LAYOUT_CHUNK) or move every row of the plan
+    and every pair of T x K (0), by the pass's shapes: a plan (``plan_rows``:
+    every pair the routing COULD send here) of four chunks on, of which at
+    most half can be live. A pass of 4,096 tokens x 8 choices with 16 of 64
+    experts held plans 36,864 rows and fills about 11,000, a prompt's pass
+    with an eighth or a sixteenth held less still: there a sum by token over
+    the held pairs takes a v5e 0.7 ms where the gather of all 32,768 takes
+    2.0. A decode step's few hundred to 1,792 rows and a short prompt's are
+    moved whole (a loop's start costs more than its dead rows), and so is a
+    pass that holds every expert (nearly every row is live and every pair is
+    held: the loops read 2.8 ms where the plain forms read 1.9)."""
+    return LAYOUT_CHUNK if rows >= 4 * LAYOUT_CHUNK and 2 * cfg.experts_held <= cfg.n_experts else 0
 
 
-def _token_rows_bwd(res, g):
-    row_of_pair, held = res
-    mine = jnp.where(held[..., None], g[row_of_pair].astype(jnp.float32), 0.0)  # [T, K, D]
-    return jnp.sum(mine, axis=1).astype(g.dtype), None, None, None
+def _summed_by_token(rows, weights, plan, chunk: int):
+    """float32 [T, D]: token t's held pairs' rows of ``rows`` [M, D] summed in
+    float32 in the order of its choices, each times its weight first
+    (``weights`` [T, K] float32; None: as it is). Without a ``chunk`` a gather
+    of all T x K pairs, masked (a pair that is not held reads row 0); with
+    one, ``_summed_by_level`` over the held pairs only."""
+    if chunk:
+        return _summed_by_level(rows, weights, plan.row_of_pair, plan.held, size=min(LEVEL_CHUNK, plan.held.shape[0]))
+    pair = rows[plan.row_of_pair].astype(jnp.float32)  # [T, K, D]
+    pair = pair if weights is None else pair * weights[..., None]
+    return jnp.sum(jnp.where(plan.held[..., None], pair, 0.0), axis=1)
+
+
+# One trace a shape signature for the life of the process, as the kernels' calls are (ops/__init__.py): a prompt's
+# passes are 2,048 or 4,096 tokens whatever its bucket, and a warm start traces every prefill program again (the
+# body is a hundred jax.numpy calls: 0.16 s a program where it was traced once a kind of layer and program).
+@functools.partial(jax.jit, static_argnames=("size",))
+def _summed_by_level(rows, weights, row_of_pair, held, *, size: int):
+    """``_summed_by_token`` with gathers of the held pairs only and no scatter
+    (a scatter-add of a float32 row of 2304 takes a v5e 244 ns, a gather 43):
+    a token's held pairs are numbered 0, 1, .. (levels), the tokens are put in
+    the order of how many they hold, most first, so that level j is the first
+    n_j tokens of that order, gathered in chunks of ``size`` tokens and added
+    to a prefix of the sum, which is read back into the tokens' own order at
+    the end. The trip count is the levels' chunks, a runtime value: the rows
+    moved are the held pairs, a chunk's rounding a level, and T."""
+    (T, K), D = held.shape, rows.shape[1]
+    holds = held.astype(jnp.int32)
+    count = jnp.sum(holds, axis=1)  # [T]
+    levels = jnp.arange(K, dtype=jnp.int32)
+    # [T, pair, level]: the pair is its token's level-th held one
+    onto = ((jnp.cumsum(holds, axis=1) - holds)[:, :, None] == levels) & held[:, :, None]
+    level_tokens = jnp.sum((count[:, None] > levels).astype(jnp.int32), axis=0)  # [K]: n_j
+    # a token's place in the order by count, ties in the tokens' order: a counting sort
+    same = (count[:, None] == jnp.arange(K + 1, dtype=jnp.int32)).astype(jnp.int32)  # [T, K + 1]
+    place = (jnp.concatenate([level_tokens, jnp.zeros(1, jnp.int32)])[count]  # the tokens that hold more
+             + jnp.sum((jnp.cumsum(same, axis=0) - same) * same, axis=1))  # the earlier tokens that hold as many
+
+    def by_level(of_pair):  # [T, K] of the pairs -> [K, T]: level j of the token at each place
+        of_level = jnp.sum(jnp.where(onto, of_pair[:, :, None], 0), axis=1)
+        return jnp.zeros_like(of_level).at[place].set(of_level, unique_indices=True).T
+
+    level_rows = by_level(row_of_pair)
+    level_weights = None if weights is None else by_level(weights)
+    chunks = lax.div(level_tokens + (size - 1), size)
+    ends = jnp.cumsum(chunks)
+
+    def body(step, acc):
+        level = jnp.sum((step >= ends).astype(jnp.int32))
+        first = (step - (ends[level] - chunks[level])) * size
+        start = jnp.minimum(first, T - size)  # a level's last chunk starts early, and adds no token twice
+        at = start + jnp.arange(size, dtype=jnp.int32)
+        mine = (at >= first) & (at < level_tokens[level])
+        part = rows[jnp.where(mine, lax.dynamic_slice(level_rows, (level, start), (1, size))[0], 0)].astype(jnp.float32)
+        if level_weights is not None:
+            part = part * lax.dynamic_slice(level_weights, (level, start), (1, size))[0][:, None]
+        part = lax.dynamic_slice(acc, (start, 0), (size, D)) + jnp.where(mine[:, None], part, 0.0)
+        return lax.dynamic_update_slice(acc, part, (start, 0))
+
+    return lax.fori_loop(0, ends[-1], body, jnp.zeros((T, D), jnp.float32))[place]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _token_rows(xt, plan, chunk: int):
+    """The rows laid out for the grouped matmul, [M, D]: row r holds
+    xt[plan.token_of_row[r]] (a padding row token 0's, which nothing reads
+    back), one gather of every row of the plan: out of 4,096 tokens it runs at
+    the speed the rows are written (0.30 ms for 36,864 of 2304 on a v5e; a
+    loop over the live chunks reads 0.67). The backward sums a token's held
+    pairs' rows' cotangents in float32 (``_summed_by_token``), where autodiff
+    would scatter-add every row of the plan."""
+    return xt[plan.token_of_row]
+
+
+def _token_rows_fwd(xt, plan, chunk):
+    return _token_rows(xt, plan, chunk), plan
+
+
+def _token_rows_bwd(chunk, plan, g):
+    with jax.named_scope("experts/layout"):
+        return _summed_by_token(g, None, plan, chunk).astype(g.dtype), None
 
 
 _token_rows.defvjp(_token_rows_fwd, _token_rows_bwd)
 
 
-@jax.custom_vjp
-def _pair_rows(y, row_of_pair, pair_of_row):
-    """y[row_of_pair] [T, K, D], a pair's result read back from its row, with
-    a backward that gathers: a row's cotangent is its pair's
-    (``pair_of_row`` [M], -1 for a row that holds none), zeros for padding.
-    A pair that is not held reads row 0 and is masked by its reader: its
-    cotangent is zero and is left out here."""
-    return y[row_of_pair]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _row_tokens(y, top_w, plan, tm: int, chunk: int):
+    """The pairs' results brought back to their tokens, [T, D] in y's dtype:
+    token t's held pairs' rows of y [M, D], each times its weight (``top_w``
+    [T, K] float32), summed in float32 (``_summed_by_token``). The backward
+    goes by row: a row's cotangent is its token's times its weight, a weight's
+    the dot product of its row and its token's cotangent in float32. With a
+    ``chunk`` (``_layout_chunk``) over the live rows only, ``plan.n_tiles``
+    tiles of ``tm``, in chunks of that many rows, the last of which may reach
+    into dead tiles (a plan that is no whole number of chunks has its last
+    chunk start early, at M - chunk, and writes rows again what it wrote):
+    the rows of dead tiles past it get nothing (``lax.empty``: a TPU's buffer
+    as it was allocated, zeros elsewhere), as the kernels' own results hold
+    nothing there, and the grouped matmul's backward, a walk of the live
+    tiles, never reads them. Without one, once over every row of the plan."""
+    return _summed_by_token(y, top_w, plan, chunk).astype(y.dtype)
 
 
-def _pair_rows_fwd(y, row_of_pair, pair_of_row):
-    return y[row_of_pair], pair_of_row
+def _row_tokens_fwd(y, top_w, plan, tm, chunk):
+    return _row_tokens(y, top_w, plan, tm, chunk), (y, top_w, plan)
 
 
-def _pair_rows_bwd(pair_of_row, g):
-    rows = g.reshape(-1, g.shape[-1])[jnp.maximum(pair_of_row, 0)]
-    return jnp.where((pair_of_row >= 0)[:, None], rows, jnp.zeros((), g.dtype)), None, None
+def _row_tokens_bwd(tm, chunk, res, g):
+    y, top_w, plan = res
+    M, D = y.shape
+    weights = top_w.reshape(-1)
+
+    def rows(start, size):
+        pair = lax.dynamic_slice(plan.pair_of_row, (start,), (size,))
+        theirs = g[lax.dynamic_slice(plan.token_of_row, (start,), (size,))].astype(jnp.float32)  # its token's cotangent
+        weight = jnp.where(pair >= 0, weights[jnp.maximum(pair, 0)], 0.0)
+        mine = lax.dynamic_slice(y, (start, 0), (size, D)).astype(jnp.float32)
+        return (theirs * weight[:, None]).astype(y.dtype), jnp.sum(mine * theirs, axis=1)
+
+    def body(i, carry):
+        start = jnp.minimum(i * chunk, M - chunk)
+        dy, dots = rows(start, chunk)
+        return lax.dynamic_update_slice(carry[0], dy, (start, 0)), lax.dynamic_update_slice(carry[1], dots, (start,))
+
+    with jax.named_scope("experts/layout"):
+        if chunk:
+            dy, dots = lax.fori_loop(0, lax.div(plan.n_tiles[0] * tm + (chunk - 1), chunk), body,
+                                     (lax.empty((M, D), y.dtype), jnp.zeros(M, jnp.float32)))
+        else:
+            dy, dots = rows(0, M)
+        return dy, jnp.where(plan.held, dots[plan.row_of_pair], 0.0).astype(top_w.dtype), None
 
 
-_pair_rows.defvjp(_pair_rows_fwd, _pair_rows_bwd)
+_row_tokens.defvjp(_row_tokens_fwd, _row_tokens_bwd)
 
 
 def _balance_term(chosen, score_sum, tokens: int, cfg: TransformerConfig):
@@ -1185,11 +1296,13 @@ def _held_experts_ffn(x, p, cfg: TransformerConfig, balance: bool = False):
             layer = {k: lax.dynamic_index_in_dim(p[k], p["expert_layer"], 0, keepdims=True).astype(x.dtype)
                      for k in HELD_EXPERT_WEIGHTS}
             p = {**p, **layer, "expert_layer": 0}
-        # What a pass keeps for its backward is its tokens, the rows gathered for the grouped matmul, the gate's and
-        # the up product's rows and the pairs' results read back (EXPERT_ROWS: 0.45 GB a pass of 4,096 tokens at
-        # widths 2304 / 896); the routing, the row plan, the hidden rows and the weighted pairs in float32 (0.37 GB
-        # more) are made again from them, and neither a product nor a gather is: a layer's four passes gather
-        # 36,864 rows of 2304 each in 5.6 ms on a v5e, twice what its three products take (PERF.md section 6, PR 59).
+        # What a pass keeps for its backward is its tokens, the rows laid out for the grouped matmul and the three
+        # products' rows (EXPERT_ROWS: 0.47 GB a pass of 4,096 tokens at widths 2304 / 896: the down product's
+        # 36,864 rows, which the way back to the tokens reads again for the weights' cotangents, where PR 59 kept
+        # the 32,768 pairs read back); the routing, the row plan, the hidden rows and the sums by token are made
+        # again from them, and no product is. One gather of 32,768 or 36,864 rows of 2304 OUT OF as many rows (151-170
+        # MB read and as much written, the source in HBM) is 1.42 ms on a v5e, 43 ns a row, 5.7 ms a layer's four
+        # passes; the same rows out of a pass's 4,096 tokens (19 MB) 0.30-0.36 ms (PERF.md section 6, PR 60).
         a_pass = jax.checkpoint(_held_experts_pass, static_argnums=(2, 3),
                                 policy=jax.checkpoint_policies.save_only_these_names(EXPERT_ROWS))
     if T <= size:
@@ -1232,7 +1345,6 @@ def _held_experts_pass(x, p, cfg: TransformerConfig, balance: bool = False, real
     from ray_tpu.ops.grouped_matmul import expert_matmul, group_rows
 
     B, S, D = x.shape
-    dt = x.dtype
     xt = x.reshape(B * S, D)
     with jax.named_scope("experts/route"):
         logits = jnp.einsum("td,de->te", xt.astype(jnp.float32), p["router"].astype(jnp.float32),
@@ -1247,6 +1359,13 @@ def _held_experts_pass(x, p, cfg: TransformerConfig, balance: bool = False, real
             top_w = top_s / jnp.sum(top_s, axis=-1, keepdims=True) * cfg.routed_scaling
         tm = _expert_tile(B * S, cfg)
         plan = group_rows(top_e, cfg.first_expert, cfg.experts_held, tm)
+    # a pass that trains names what its remat policy keeps (_held_experts_ffn): the rows laid out, the gate's and
+    # the up product's rows, the down product's rows (which the way back to the tokens reads again for the
+    # weights' cotangents)
+    kept = (lambda a: checkpoint_name(a, EXPERT_ROWS)) if balance else (lambda a: a)
+    chunk = _layout_chunk(plan.pair_of_row.shape[0], cfg)
+    with jax.named_scope("experts/layout"):
+        xs = kept(_token_rows(xt, plan, chunk))  # [M, D]
     with jax.named_scope("experts/gmm"):
         # One layer's matrices [E, ...] (layer 0 of a stack of one: a free
         # reshape), or the whole stack with this layer's index (scan_stack).
@@ -1255,24 +1374,12 @@ def _held_experts_pass(x, p, cfg: TransformerConfig, balance: bool = False, real
             expert_matmul(),
             layer=p["expert_layer"] if stacked else 0,
             tile_expert=plan.tile_expert, n_tiles=plan.n_tiles, tm=tm)
-        # a pass that trains names what its remat policy keeps (_held_experts_ffn): the gathered rows, the gate's
-        # and the up product's rows, the pairs' results read back
-        kept = (lambda a: checkpoint_name(a, EXPERT_ROWS)) if balance else (lambda a: a)
         gmm = lambda rows, w: kept(product(rows, w))  # noqa: E731
         w_gate, w_up, w_down = (p[k] if stacked else p[k][None] for k in HELD_EXPERT_WEIGHTS)
-        if balance:  # the two gathers with backwards that gather (_token_rows, _pair_rows); the layout's inverse
-            M = plan.token_of_row.shape[0]
-            pair_of_row = jnp.full(M, -1, jnp.int32).at[jnp.where(plan.held, plan.row_of_pair, M).reshape(-1)].set(
-                jnp.arange(top_e.size, dtype=jnp.int32), mode="drop", unique_indices=True)
-            xs = kept(_token_rows(xt, plan.token_of_row, plan.row_of_pair, plan.held))
-        else:
-            xs = xt[plan.token_of_row]  # [M, D]
         h = _swiglu_product(lambda w: gmm(xs, w), w_gate, w_up, cfg.swiglu_limit)
-        y = product(h, w_down)  # [M, D]; rows past the live tiles hold nothing
-        picked = kept(_pair_rows(y, plan.row_of_pair, pair_of_row)) if balance else y[plan.row_of_pair]
-        pair = picked.astype(jnp.float32) * top_w[..., None]  # [T, K, D]
-        routed = jnp.sum(jnp.where(plan.held[..., None], pair, 0.0), axis=1)
-        routed = routed.astype(dt).reshape(B, S, D)
+        y = gmm(h, w_down)  # [M, D]; rows past the live tiles hold nothing
+    with jax.named_scope("experts/layout"):
+        routed = _row_tokens(y, top_w, plan, tm, chunk).reshape(B, S, D)
     with jax.named_scope("experts/shared"):
         if cfg.n_shared_experts:
             shared = {"w_gate": p["ws_gate"], "w_up": p["ws_up"], "w_down": p["ws_down"]}

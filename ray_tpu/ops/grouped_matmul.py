@@ -47,13 +47,16 @@ class RowPlan(NamedTuple):
     - ``tile_expert`` [M // tm]: the local expert of each tile (a dead tile
       names a valid expert all the same);
     - ``n_tiles`` [1]: the live tiles, which come first;
-    - ``sizes`` [E]: pairs on each held expert."""
+    - ``sizes`` [E]: pairs on each held expert;
+    - ``pair_of_row`` [M]: the pair a row holds, t * K + k (-1 for a padding
+      row): the inverse of ``row_of_pair`` over the held pairs."""
     token_of_row: jax.Array
     row_of_pair: jax.Array
     held: jax.Array
     tile_expert: jax.Array
     n_tiles: jax.Array
     sizes: jax.Array
+    pair_of_row: jax.Array
 
 
 def plan_rows(n_pairs: int, n_held: int, tm: int) -> int:
@@ -79,13 +82,13 @@ def group_rows(experts, first: int, n_held: int, tm: int) -> RowPlan:
     tile_end = jnp.cumsum(tiles)
     row_start = (tile_end - tiles) * tm
     row = jnp.sum(onehot * row_start[None, :], axis=1) + rank
-    token = jnp.arange(T * K, dtype=jnp.int32) // K
-    token_of_row = jnp.zeros(M, jnp.int32).at[jnp.where(held, row, M)].set(
-        token, mode="drop", unique_indices=True)
+    pair_of_row = jnp.full(M, -1, jnp.int32).at[jnp.where(held, row, M)].set(
+        jnp.arange(T * K, dtype=jnp.int32), mode="drop", unique_indices=True)
+    token_of_row = lax.div(jnp.maximum(pair_of_row, 0), K)
     tile = jnp.arange(M // tm, dtype=jnp.int32)
     tile_expert = jnp.minimum(jnp.sum(tile[:, None] >= tile_end[None, :], axis=1), n_held - 1)
     return RowPlan(token_of_row, jnp.where(held, row, 0).reshape(T, K), held.reshape(T, K),
-                   tile_expert.astype(jnp.int32), tile_end[-1:].astype(jnp.int32), sizes)
+                   tile_expert.astype(jnp.int32), tile_end[-1:].astype(jnp.int32), sizes, pair_of_row)
 
 
 # ---------------------------------------------------------------------------

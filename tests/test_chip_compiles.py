@@ -666,6 +666,95 @@ def test_the_train_step_at_the_train_cells_shapes_computes_no_product_twice_and_
     assert "loss_head" in text and text.count("tpu_custom_call") >= 4  # the flash forward twice under "dots", its backward's two
 
 
+def _bench_config(architecture, config):
+    """(the architecture file's module, the configuration) of benchmarks/, loaded by path."""
+    import importlib.util
+    import json
+    import os
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmarks")
+    spec = importlib.util.spec_from_file_location("bench_" + architecture, os.path.join(bench, "architectures", architecture + ".py"))
+    arch = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(arch)
+    with open(os.path.join(bench, "configs", config + ".json")) as f:
+        return arch, json.load(f)
+
+
+def _gathered_rows(text, width):
+    """The rows of every ``gather`` instruction of a compiled module (loop bodies and fusions among them) whose
+    result is rows of ``width`` columns: {rows: how many instructions}."""
+    import collections
+    import math
+    import re
+
+    found = collections.Counter()
+    for dims in re.findall(r"= \w+\[([\d,]+)\]\S* gather\(", text):
+        dims = [int(d) for d in dims.split(",")]
+        if len(dims) >= 2 and dims[-1] == width:
+            found[math.prod(dims[:-1])] += 1
+    return dict(found)
+
+
+def test_a_routed_pass_moves_the_pairs_it_holds_in_the_train_step_and_a_laguna_prompt_for_a_v5e(
+        one_chip, no_compile_cache, monkeypatch):
+    """models/transformer.py ``_token_rows`` / ``_row_tokens`` at two cells' shapes, as the TPU's compiler leaves
+    them. ``make_train_step`` at benchmarks/configs/mellum2-12b-a2.5b-ep4-l4.json (2 rows of 8,192 + 1 tokens,
+    passes of 4,096 tokens x 8 choices, 16 of 64 experts held: a plan of 36,864 rows of 2304): no gather's result
+    is the pass's 32,768 pairs (the parent's twelve: the pairs read back, forward and again under remat, and the
+    two backwards); the sums by token gather LEVEL_CHUNK rows a step of a loop and the tokens' 4,096 once, the
+    way back gathers LAYOUT_CHUNK rows a step into a buffer nobody zeroes (``AllocateBuffer``); the one gather of
+    the plan's 36,864 rows left is the forward's, out of the pass's 4,096 tokens (it runs at the speed its rows
+    are written, PERF.md section 6, PR 60). The step holds 15.304 GB as compiled here, its parent's 15.218: the
+    kept rows of the down product [36,864, 2304] stand where the pairs read back [32,768, 2304] stood, 19 MB a
+    pass and 75 a layer's four, and the sums' float32 [4096, 2304] beside them. llm/engine.py's prefill program
+    of bucket 2048 at benchmarks/configs/laguna-s-2.1-ep8.json (one pass of 2,048 tokens x 10 choices, 32 of
+    256 held: a plan of 28,672 rows of 3072): no gather of the pass's 20,480 pairs, a loop's of LEVEL_CHUNK."""
+    from ray_tpu.llm.engine import EngineConfig, LLMEngine
+    from ray_tpu.models import transformer as T
+    from ray_tpu.ops.grouped_matmul import plan_rows
+
+    def on_chip(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the flash and grouped-matmul kernels, a pool's rows
+    arch, model = _bench_config("mellum2", "mellum2-12b-a2.5b-ep4-l4")
+    train = dict(model["train"])
+    rows, seq = train.pop("batch_rows"), 8192
+    cfg = T.TransformerConfig(**arch.transformer_kwargs(model), **train)
+    assert (rows, cfg.d_model, cfg.expert_top_k, cfg.experts_held, cfg.n_experts) == (2, 2304, 8, 16, 64)
+    plan = plan_rows(T.PAIRS_A_PASS, cfg.experts_held, T._expert_tile(4096, cfg))
+    assert plan == 36_864 and T._layout_chunk(plan, cfg) == T.LAYOUT_CHUNK
+    init_state, train_step, _ = T.make_train_step(cfg)
+    state = jax.tree.map(on_chip, jax.eval_shape(init_state, jax.random.PRNGKey(0)))
+    batch = {c: on_chip(jnp.zeros((rows, seq + 1), jnp.int32)) for c in ("tokens", "segment_ids", "positions", "mask")}
+    compiled = jax.jit(train_step, donate_argnums=(0,)).lower(state, batch).compile()
+    text = compiled.as_text()
+    gathered = _gathered_rows(text, cfg.d_model)  # the embedding's rows are the batch's 16,384
+    assert set(gathered) == {T.LEVEL_CHUNK, 4096, T.LAYOUT_CHUNK, plan, rows * seq}, gathered
+    assert "experts/layout" in text and "AllocateBuffer" in text
+    m = compiled.memory_analysis()
+    held = m.argument_size_in_bytes + m.output_size_in_bytes + m.temp_size_in_bytes - m.alias_size_in_bytes
+    assert held <= 15.31e9, held  # 15,303,832,576 as read here; the parent's step 15,217,817,600
+
+    arch, model = _bench_config("laguna", "laguna-s-2.1-ep8")
+    cfg = T.TransformerConfig(**{**arch.transformer_kwargs(model), "param_dtype": jnp.bfloat16})
+    bucket = 2048
+    assert (cfg.d_model, cfg.expert_top_k, cfg.experts_held, cfg.n_experts) == (3072, 10, 32, 256)
+    plan = plan_rows(bucket * cfg.expert_top_k, cfg.experts_held, T._expert_tile(bucket, cfg))
+    assert plan == 28_672 and T._layout_chunk(plan, cfg) == T.LAYOUT_CHUNK
+    params = jax.tree.map(on_chip, jax.eval_shape(lambda: T.init_params(jax.random.PRNGKey(0), cfg)))
+    eng = LLMEngine(cfg, params=params, engine_config=EngineConfig(
+        max_slots=8, max_seq=bucket + 128, page_size=128, total_pages=40, prefill_buckets=(bucket,), decode_block=8))
+    i32 = lambda *dims: on_chip(jnp.zeros(dims, jnp.int32))  # noqa: E731
+    f32 = lambda *dims: on_chip(jnp.zeros(dims, jnp.float32))  # noqa: E731
+    text = eng._prefill(bucket, 1).lower(
+        params, tuple(on_chip(pool) for pool in eng.cache), i32(1, bucket), i32(1), i32(1, bucket // 128),
+        on_chip(jax.random.PRNGKey(0)), f32(1), f32(1), i32(1), i32(1)).compile().as_text()
+    gathered = _gathered_rows(text, cfg.d_model)  # the prompt's 2,048 tokens: the embedding's rows and the sums' own order
+    assert set(gathered) == {T.LEVEL_CHUNK, bucket, plan}, gathered
+    assert "experts/layout" in text
+
+
 FLASH_SHAPES = {
     # B, S, H, KV, D, blocks, window, with the backward
     "the train cell's call: four sub-tiles a block": (3, 4096, 32, 8, 128, 1024, 0, True),

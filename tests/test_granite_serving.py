@@ -357,7 +357,9 @@ _KDA = LayerKind("kda", 4, mixer="delta", conv_size=4, low_rank=16, beta_scale=2
 _EXPERTS = dict(n_experts=8, experts_held=4, expert_d_ff=16, n_shared_experts=1, router_score="sigmoid",
                 attention_impl="reference")
 # the toy forms of the configurations the benchmark had before this kind, each with the primitives of its forward's
-# jaxpr as the commit before this kind counted them (sha1 of the sorted counts, and their sum)
+# jaxpr as the commit before this kind counted them (sha1 of the sorted counts, and their sum); the three with held
+# experts as PR 60 left them (a routed layer's two copies are two custom-VJP calls and the row plan divides by
+# ``lax.div``: 8 primitives fewer a routed layer, the arithmetic what it was)
 OTHER_MODELS = {
     "dense_f32": (TransformerConfig(vocab_size=96, d_model=32, n_layers=3, n_heads=4, n_kv_heads=2, d_ff=48,
                                     max_seq_len=128, **_F32), "81b910a0bb0b", 129),
@@ -366,15 +368,15 @@ OTHER_MODELS = {
     "latent_experts": (TransformerConfig(
         vocab_size=96, d_model=32, n_layers=3, n_heads=4, d_ff=48, max_seq_len=128, attention_kind="latent",
         q_lora_rank=16, kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=8, v_head_dim=8, sandwich_norm=True,
-        n_dense_layers=1, expert_top_k=2, **_EXPERTS, **_F32), "5446bb569560", 683),
+        n_dense_layers=1, expert_top_k=2, **_EXPERTS, **_F32), "28038231f2d1", 675),
     "window_experts": (TransformerConfig(
         vocab_size=96, d_model=32, n_layers=5, n_heads=4, n_kv_heads=2, head_dim=16, d_ff=48, max_seq_len=128,
         layer_pattern=(_FULL, _SLIDING, _SLIDING, _SLIDING), attn_gate="per_head", n_dense_layers=1, expert_top_k=2,
-        **_EXPERTS, **_F32), "45b2666fe077", 2235),
+        **_EXPERTS, **_F32), "e1e8af932c33", 2203),
     "delta_experts": (TransformerConfig(
         vocab_size=96, d_model=32, n_layers=4, n_heads=4, n_kv_heads=2, head_dim=16, d_ff=48, max_seq_len=128,
         layer_pattern=(_GQA, _KDA, _KDA, _KDA), attn_gate="elementwise", expert_top_k=3, first_expert=2, norm_eps=1e-5,
-        **_EXPERTS, **_F32), "b7c49783e7f9", 3046),
+        **_EXPERTS, **_F32), "9f94454d8ec2", 3014),
 }
 
 

@@ -259,6 +259,108 @@ def test_a_held_layer_trains_through_the_kernels_as_through_the_jax_numpy_form(m
 
 
 # ---------------------------------------------------------------------------
+# the two copies between token space and the grouped matmul's row space (``_token_rows``, ``_row_tokens``)
+# ---------------------------------------------------------------------------
+
+def _even(rng):
+    return np.stack([rng.permutation(8)[:3] for _ in range(24)])
+
+
+ROUTINGS = {  # [T, K] choices among 8 experts, of which 2 .. 5 are held, in tiles of 8 rows
+    "even": _even,
+    "no pair held": lambda rng: np.stack([rng.permutation([0, 1, 6, 7])[:3] for _ in range(24)]),
+    # 17, 17, 17 and 21 pairs: every held expert's last tile is partly filled, 12 live tiles of the plan's 13
+    "every pair held": lambda rng: np.stack([np.delete([2, 3, 4, 5], min(t // 7, 3)) for t in range(24)]),
+    "one expert takes every pair": lambda rng: np.full((24, 1), 3),
+    "a last tile partly padded": lambda rng: np.full((21, 1), 2),
+}
+
+
+@pytest.mark.parametrize("chunk", [0, 40, 16], ids=["whole", "chunks_of_40", "chunks_of_16"])
+@pytest.mark.parametrize("routing", sorted(ROUTINGS))
+def test_the_layouts_two_copies_and_their_vjps_are_the_plain_gathers_on_live_rows(routing, chunk, monkeypatch):
+    """Forward and VJP of both copies against ``jax.vjp`` of the plain gathers (every row of the plan gathered by
+    token, every pair of T x K gathered by row): whole, and with the way back's rows in chunks that do and do not
+    divide the plan (the last chunk then starts early and writes rows twice) and the sums by token in levels of 8
+    tokens a step (a level's last chunk starts early too and adds no token twice). What dead tiles and padding
+    rows hold reaches nothing: they are NaN here in everything the copies are handed."""
+    monkeypatch.setattr(transformer, "LEVEL_CHUNK", 8)
+    tm, D = 8, 16
+    rng = np.random.default_rng(7)
+    top = jnp.asarray(ROUTINGS[routing](rng).astype(np.int32))
+    (T, K), plan = top.shape, group_rows(top, 2, 4, tm)
+    M, live_rows = plan.pair_of_row.shape[0], int(plan.n_tiles[0]) * tm
+    real = np.asarray(plan.pair_of_row) >= 0
+    assert real.sum() == int(np.asarray(plan.held).sum()) and not real[live_rows:].any() and chunk <= M
+    if routing == "every pair held":  # as many live tiles as 72 pairs on 4 experts can fill: the worst case
+        assert real.sum() == T * K and live_rows == M - tm
+    if routing == "a last tile partly padded":
+        assert live_rows == 24 and real.sum() == 21
+    assert (live_rows == 0) == (routing == "no pair held")
+    xt = jnp.asarray(rng.normal(size=(T, D)), jnp.float32)
+    top_w = jnp.asarray(rng.uniform(0.1, 1.0, size=(T, K)), jnp.float32)
+    y, ct_rows = (jnp.asarray(rng.normal(size=(M, D)), jnp.float32) for _ in range(2))
+    ct_tokens = jnp.asarray(rng.normal(size=(T, D)), jnp.float32)
+    poisoned = lambda a: jnp.where(real[:, None], a, jnp.nan)  # noqa: E731
+    zeroed = lambda a: jnp.where(real[:, None], a, 0.0)  # noqa: E731
+
+    rows, back = jax.vjp(lambda xt: transformer._token_rows(xt, plan, chunk), xt)
+    want_rows, want_back = jax.vjp(lambda xt: xt[plan.token_of_row], xt)
+    np.testing.assert_array_equal(rows, want_rows)
+    np.testing.assert_allclose(back(poisoned(ct_rows))[0], want_back(zeroed(ct_rows))[0], atol=1e-5)
+
+    def plain(y, top_w):
+        pair = y[plan.row_of_pair] * top_w[..., None]
+        return jnp.sum(jnp.where(plan.held[..., None], pair, 0.0), axis=1)
+
+    out, back = jax.vjp(lambda y, w: transformer._row_tokens(y, w, plan, tm, chunk), poisoned(y), top_w)
+    want_out, want_back = jax.vjp(plain, zeroed(y), top_w)
+    np.testing.assert_allclose(out, want_out, atol=1e-5)
+    (dy, dw), (want_dy, want_dw) = back(ct_tokens), want_back(ct_tokens)
+    np.testing.assert_allclose(dy[:live_rows], want_dy[:live_rows], atol=1e-5)  # zeros for a live tile's padding rows
+    assert not chunk or not np.asarray(dy)[-(-live_rows // chunk) * chunk:].any()  # off a TPU: zeros past the chunks
+    np.testing.assert_allclose(dw, want_dw, atol=1e-5)
+
+
+def test_a_plan_of_four_chunks_on_follows_its_held_pairs_unless_every_expert_is_held():
+    def cfg(held):
+        return TransformerConfig(**{**CFG.__dict__, "n_experts": 64, "experts_held": held, "first_expert": 0})
+
+    assert [transformer._layout_chunk(rows, cfg(16)) for rows in (1792, 8191, 8192, 36864)] == [0, 0, 2048, 2048]
+    assert [transformer._layout_chunk(49152, cfg(held)) for held in (8, 32, 33, 64)] == [2048, 2048, 0, 0]
+    assert transformer.LAYOUT_CHUNK % 256 == 0  # whole tiles, whatever _expert_tile picks
+
+
+def test_a_trained_layer_gives_the_same_in_chunks_as_whole(monkeypatch):
+    """One routed layer in two passes, output, balance term and gradients: its plans (136 rows of 8) with the way
+    back in chunks of 32 rows and the sums by token in levels of 8 tokens a step, against the same plans moved whole."""
+    monkeypatch.setattr(transformer, "PAIRS_A_PASS", 96)
+    lp = {k: v[1] for k, v in _params()["kind_layers"]["sliding_attention"].items()}
+    x = jax.random.normal(jax.random.PRNGKey(3), (2, 32, CFG.d_model), jnp.float32)
+    ct = jax.random.normal(jax.random.PRNGKey(4), x.shape, jnp.float32)
+
+    def loss(x, lp):
+        out, aux = _held_experts_ffn(x, lp, CFG, balance=True)
+        return jnp.sum(out * ct) + 0.3 * aux[0], out
+
+    def run():
+        jax.clear_caches()  # jax.checkpoint keeps a pass's trace, and the chunk is read inside it
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1), has_aux=True))(x, lp)
+
+    (want, want_out), want_grads = run()
+    monkeypatch.setattr(transformer, "_layout_chunk", lambda rows, cfg: 32 if rows >= 128 else 0)
+    monkeypatch.setattr(transformer, "LEVEL_CHUNK", 8)
+    summed, sums = [], transformer._summed_by_token
+    monkeypatch.setattr(transformer, "_summed_by_token", lambda *a: (summed.append(a[3]), sums(*a))[1])
+    (got, out), grads = run()
+    assert len(summed) >= 2 and all(c == 32 for c in summed)
+    assert float(got) == pytest.approx(float(want), rel=1e-6)
+    assert _rel(out, want_out) < 1e-6
+    for g, w in zip(jax.tree.leaves(grads), jax.tree.leaves(want_grads)):
+        assert _rel(g, w) < 1e-5
+
+
+# ---------------------------------------------------------------------------
 # the shares of an expert-parallel layer
 # ---------------------------------------------------------------------------
 
